@@ -8,7 +8,7 @@ RUN apt-get update && apt-get install -y --no-install-recommends \
 
 WORKDIR /workspace/fleetx-tpu
 COPY requirements.txt setup.py ./
-RUN pip install --no-cache-dir "jax[tpu]" \
+RUN pip install --no-cache-dir "jax[tpu]==0.9.0" \
         -f https://storage.googleapis.com/jax-releases/libtpu_releases.html && \
     pip install --no-cache-dir -r requirements.txt
 

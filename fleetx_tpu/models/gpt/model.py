@@ -36,6 +36,8 @@ from flax import linen as nn
 from flax import struct
 from jax.ad_checkpoint import checkpoint_name
 
+from fleetx_tpu.utils.log import logger
+
 _NEG_INF_F32 = -1e30  # finite stand-in for -inf (keeps exp/grad NaN-free)
 
 param_with_axes = nn.with_logical_partitioning
@@ -406,8 +408,12 @@ class MultiHeadAttention(nn.Module):
                          kv_chunk=cfg.ring_kv_chunk)
         elif cfg.use_flash_attention:
             from fleetx_tpu.ops import flash_attention
+            from fleetx_tpu.parallel.mesh import current_mesh
+
+            mesh = current_mesh()
             rate = 0.0 if deterministic else cfg.attention_probs_dropout_prob
-            if flash_attention.supported(q, k) and (
+            if flash_attention.supported(q, k) and \
+                    flash_attention.sharded_supported(q, mesh) and (
                     rate == 0.0 or flash_attention.dropout_supported()):
                 kwargs = dict(causal=True, fused_bwd=cfg.flash_fused_bwd)
                 if rate > 0.0:
@@ -417,9 +423,12 @@ class MultiHeadAttention(nn.Module):
                         jnp.iinfo(jnp.int32).max, dtype=jnp.int32)
                     kwargs.update(dropout_rate=rate, dropout_seed=seed)
                 # mesh-aware: run the kernel per-device (GSPMD cannot
-                # partition the Mosaic custom call); falls back to the plain
-                # call off-mesh
-                fn = partial(flash_attention.flash_attention_sharded, **kwargs)
+                # partition the Mosaic custom call)
+                fn = partial(flash_attention.flash_attention_sharded,
+                             mesh=mesh, **kwargs)
+            else:
+                logger.info("flash attention does not admit q %s (dropout "
+                            "%s): einsum attention", q.shape, rate)
         if cfg.use_recompute and cfg.recompute_granularity == "core_attn":
             fn = jax.checkpoint(fn)
         return fn(q, k, v)
@@ -512,13 +521,23 @@ class LayerNorm(nn.Module):
         bias = self.param("bias", param_with_axes(nn.initializers.zeros, ("norm",)),
                           (cfg.hidden_size,), cfg.param_dtype)
         from fleetx_tpu.ops import fused_norm
+        from fleetx_tpu.parallel.mesh import current_mesh
 
-        if cfg.fused_residual_norm and \
-                fused_norm.fused_norm_supported(x, residual):
-            out, s = fused_norm.fused_residual_norm(
-                x, scale, bias, residual=residual,
-                eps=cfg.layer_norm_epsilon, out_dtype=cfg.dtype)
-            return out if residual is None else (out, s)
+        if cfg.fused_residual_norm:
+            # where the activations lie on the mesh — the constraint every
+            # block ends on — so the kernel runs on each device's rows
+            mesh = current_mesh()
+            spec = nn.logical_to_mesh_axes(
+                ("batch", "act_seq", "act_embed")) if x.ndim == 3 else None
+            if fused_norm.fused_norm_supported(x, residual, mesh=mesh,
+                                               spec=spec):
+                out, s = fused_norm.fused_residual_norm(
+                    x, scale, bias, residual=residual,
+                    eps=cfg.layer_norm_epsilon, out_dtype=cfg.dtype,
+                    mesh=mesh, spec=spec)
+                return out if residual is None else (out, s)
+            logger.info("fused norm does not admit x %s %s: unfused "
+                        "LayerNorm", x.shape, x.dtype)
         s = x if residual is None else residual + x
         x32 = s.astype(jnp.float32)
         mean = x32.mean(-1, keepdims=True)

@@ -2,9 +2,8 @@
 
 Mechanizes the hand-done "Step-time decomposition from the committed
 trace" analysis in BENCHMARKS.md (ROADMAP item 3): given the
-Chrome-trace/Perfetto JSON a ``jax.profiler`` window dumps (the same
-artifact ``tools/tpu_watch.py`` commits as ``trace_gpt.tar.gz``), this
-module
+Chrome-trace/Perfetto JSON a ``jax.profiler`` window dumps (the artifact
+committed as ``bench_artifacts/trace_gpt.tar.gz``), this module
 
 - classifies every device XLA-op event into a small category taxonomy
   (matmul / flash kernel / dynamic-update-slice traffic / copy /

@@ -36,13 +36,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu only importable on TPU-enabled builds; interpret mode needs it too
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from fleetx_tpu import ops
+from fleetx_tpu.parallel.rules import activation_spec
+
+_VMEM = pltpu.VMEM
 
 # Block sizes default to the largest of these that tiles the sequence:
 # 512x512 measured 3.6x faster than 128x128 on v5e (fwd, seq 1024, d 64) —
@@ -67,15 +66,11 @@ def pick_block(seq: int, head_dim: int = 64) -> int:
 _NEG_INF = -1e30
 
 
-def _interpret() -> bool:
-    return jax.default_backend() == "cpu"
-
-
 def dropout_supported() -> bool:
     """In-kernel dropout needs the TPU PRNG (``pltpu.prng_seed``), which has
     no interpret-mode lowering — so it's available exactly when we're NOT
     interpreting. CPU callers fall back to the naive-attention dropout path."""
-    return pltpu is not None and not _interpret()
+    return not ops.interpret()
 
 
 def supported(q: jax.Array, k: jax.Array | None = None,
@@ -87,8 +82,6 @@ def supported(q: jax.Array, k: jax.Array | None = None,
     predicate never selects a call that then raises. ``block_q``/``block_k``
     default to ``pick_block`` of the respective seq length, matching
     ``flash_attention``'s own defaulting."""
-    if pltpu is None:
-        return False
     if q.ndim != 4:
         return False
     seq, head_dim = q.shape[1], q.shape[3]
@@ -113,10 +106,11 @@ def supported(q: jax.Array, k: jax.Array | None = None,
 
 
 #: VMEM budget for the fused backward's full-sequence f32 dq accumulator
-#: window (plus the two per-block dk/dv scratches). 4 MiB leaves the
-#: q/k/v/do blocks, the f32 score tile and Mosaic's double buffering
-#: comfortable headroom under the ~16 MB core budget: seq 16384 at
-#: head_dim 64, 8192 at 128.
+#: window (plus the two per-block dk/dv scratches), counted at the 128-lane
+#: width a narrower head pads to. Mosaic double-buffers the window, so
+#: 4 MiB here is 8 MiB of the core's 16 MiB scoped limit, and the q/k/v/do
+#: blocks with the f32 score tiles take ~2.3 MiB more (v5e compile, PERF.md):
+#: seq 7168 at any head_dim <= 128.
 _FUSED_DQ_SCRATCH_BYTES = 4 * 1024 * 1024
 
 
@@ -137,7 +131,7 @@ def fused_backward_supported(q: jax.Array, k: jax.Array | None = None,
         return False
     sk = k.shape[1] if k is not None else seq
     bk = pick_block(sk, head_dim) if block_k is None else min(block_k, sk)
-    scratch = (seq + 2 * bk) * head_dim * 4
+    scratch = (seq + 2 * bk) * max(head_dim, 128) * 4
     return scratch <= _FUSED_DQ_SCRATCH_BYTES
 
 
@@ -255,7 +249,8 @@ def _fwd(q3, k3, v3, seed, *, scale, causal, block_q, block_k, dropout_rate):
             _VMEM((block_q, 128), jnp.float32),
             _VMEM((block_q, 128), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=ops.interpret(),
+        name="flash_fwd",
     )(q3, k3, v3, seed)
     return out, lse[..., 0]
 
@@ -391,7 +386,8 @@ def _bwd_dq(q3, k3, v3, do, lse3, delta3, seed, *, scale, causal,
         out_specs=pl.BlockSpec((1, bq, d), lambda h, i, j: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bn, sq, d), q3.dtype),
         scratch_shapes=[_VMEM((bq, d), jnp.float32)],
-        interpret=_interpret(),
+        interpret=ops.interpret(),
+        name="flash_bwd_dq",
     )(q3, k3, v3, do, lse3, delta3, seed)
 
 
@@ -424,7 +420,8 @@ def _bwd_dkv(q3, k3, v3, do, lse3, delta3, seed, *, scale, causal,
             jax.ShapeDtypeStruct((bn, sk, d), v3.dtype),
         ],
         scratch_shapes=[_VMEM((bk, d), jnp.float32), _VMEM((bk, d), jnp.float32)],
-        interpret=_interpret(),
+        interpret=ops.interpret(),
+        name="flash_bwd_dkv",
     )(q3, k3, v3, do, lse3, delta3, seed)
 
 
@@ -546,7 +543,8 @@ def _bwd_fused(q3, k3, v3, do, lse3, delta3, seed, *, scale, causal,
             _VMEM((bk, d), jnp.float32),
             _VMEM((bk, d), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=ops.interpret(),
+        name="flash_bwd_fused",
     )(q3, k3, v3, do, lse3, delta3, seed)
     return dq32.astype(q3.dtype), dk, dv
 
@@ -652,72 +650,61 @@ def reference_attention(q, k, v, *, causal: bool = True,
     return jnp.einsum("bnqk,bknd->bqnd", p.astype(q.dtype), v)
 
 
+#: how attention operands ``[batch, seq, heads, head_dim]`` lie over the
+#: mesh: batch over ``(data, fsdp)``, heads over ``tensor``; ``seq`` stays
+#: whole here — a context-sharded sequence is ring attention's case
+_QKV_SPEC = activation_spec("batch", None, "act_heads", "act_kv")
+
+
 def sharded_supported(q: jax.Array, mesh) -> bool:
-    """True when the per-device shards still satisfy the kernel contract:
-    batch divides the data axes, heads divide the tensor axis, and the seq
-    axis is not context-sharded (ring attention owns that case)."""
-    if mesh is None or q.ndim != 4:
+    """True when ``flash_attention_sharded`` can run ``q`` under ``mesh``:
+    no mesh or one device (the plain call), else batch divides the data
+    axes, heads divide the tensor axis, and the seq axis is not
+    context-sharded (ring attention owns that case)."""
+    if mesh is None or mesh.size == 1:
+        return True
+    if q.ndim != 4 or mesh.shape.get("seq", 1) != 1:
         return False
-    shape = dict(mesh.shape)
-    dp = shape.get("data", 1) * shape.get("fsdp", 1)
-    tp = shape.get("tensor", 1)
-    if shape.get("seq", 1) != 1:
-        return False
-    b, _, n, _ = q.shape
-    return b % dp == 0 and n % tp == 0
+    return ops.local_shape(q.shape, _QKV_SPEC, mesh) is not None
 
 
 def flash_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array, *,
                             mesh=None, causal: bool = True,
                             **kwargs) -> jax.Array:
     """Mesh-aware flash attention: the kernel is a Mosaic custom call GSPMD
-    cannot partition, so under a multi-device mesh the operands would be
-    all-gathered and the kernel run replicated. This wrapper runs it
-    per-device instead — batch sharded over ``(data, fsdp)``, heads over
-    ``tensor`` — via a partial-manual ``shard_map`` (attention is
-    embarrassingly parallel over both dims; remaining axes stay automatic).
+    cannot partition, so under a multi-device mesh it runs per-device —
+    batch sharded over ``(data, fsdp)``, heads over ``tensor`` — inside a
+    ``shard_map`` that is manual over EVERY mesh axis (the only context in
+    which a Mosaic call lowers under a mesh; attention is embarrassingly
+    parallel over both dims). Callers gate on ``sharded_supported``.
+
+    Under pipeline parallelism this wrapper is reached through the stage
+    ``nn.vmap(spmd_axis_name="pipe")`` (parallel/pipeline.py): ``pipe`` being
+    manual here lets the vmap batching rule shard the stage dim over it.
 
     The in-kernel dropout seed is folded with the device's linear index so
     shards draw independent masks.
     """
-    from functools import partial as _partial
-
-    from jax.sharding import PartitionSpec as _P
-
     if mesh is None:
         from fleetx_tpu.parallel.mesh import current_mesh
 
         mesh = current_mesh()
-    if mesh is None or not sharded_supported(q, mesh):
+    if mesh is None or mesh.size == 1:
         return flash_attention(q, k, v, causal=causal, **kwargs)
-
-    manual = tuple(a for a in ("data", "fsdp", "tensor")
-                   if mesh.shape.get(a, 1) > 1)
-    # Under pipeline parallelism this wrapper is reached through the stage
-    # nn.vmap (``spmd_axis_name="pipe"``, parallel/pipeline.py): declaring
-    # ``pipe`` manual here lets the vmap batching rule shard the stage dim
-    # over ``pipe`` — without it, sdy refuses the composition and GSPMD
-    # would all-gather the Mosaic call's operands across stages. Outside
-    # that vmap the extra manual axis just asserts pipe-replication, which
-    # holds for every non-pipelined caller (decode, single-stack training).
-    if mesh.shape.get("pipe", 1) > 1:
-        manual = manual + ("pipe",)
-    if not manual:
-        return flash_attention(q, k, v, causal=causal, **kwargs)
-    batch_axes = tuple(a for a in ("data", "fsdp") if a in manual)
-    head_axis = "tensor" if "tensor" in manual else None
-    spec = _P(batch_axes or None, None, head_axis, None)
+    if not sharded_supported(q, mesh):
+        raise ValueError(
+            f"flash_attention_sharded: q {q.shape} does not lay out over "
+            f"mesh {dict(mesh.shape)} as {_QKV_SPEC}")
 
     def body(q, k, v):
         kw = dict(kwargs)
         if kw.get("dropout_seed") is not None:
             ix = jnp.int32(0)
-            for a in manual:
+            for a in ("data", "fsdp", "tensor", "pipe"):
                 ix = ix * mesh.shape[a] + jax.lax.axis_index(a)
             kw["dropout_seed"] = kw["dropout_seed"] + ix
         return flash_attention(q, k, v, causal=causal, **kw)
 
-    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec, axis_names=frozenset(manual),
-                       check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(_QKV_SPEC,) * 3,
+                       out_specs=_QKV_SPEC, check_vma=False)
     return fn(q, k, v)

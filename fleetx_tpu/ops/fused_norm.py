@@ -40,11 +40,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec as P
 
-try:  # pltpu only importable on TPU-enabled builds; interpret mode needs it too
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from fleetx_tpu import ops
 
 #: VMEM budget for one row-block's live buffers (x/residual/s/out blocks,
 #: the f32 upcast + centred-row temps, stats, double buffering). 4 MiB
@@ -60,13 +58,10 @@ _BYTES_PER_ELEMENT = 28
 _ROW_BLOCK_CANDIDATES = (512, 256, 128, 64, 32, 16, 8)
 
 
-def _interpret() -> bool:
-    return jax.default_backend() == "cpu"
-
-
 def _pick_rows_block(rows: int, hidden: int) -> int:
     """Largest sublane-aligned candidate that tiles ``rows`` and keeps the
-    block's live VMEM under `_FUSED_NORM_VMEM_BYTES`."""
+    block's live VMEM under `_FUSED_NORM_VMEM_BYTES`. (8-row blocks hold
+    for bf16 too: the v5e compiles and computes them right, PERF.md.)"""
     for b in _ROW_BLOCK_CANDIDATES:
         if rows % b == 0 and b * hidden * _BYTES_PER_ELEMENT <= \
                 _FUSED_NORM_VMEM_BYTES:
@@ -74,21 +69,30 @@ def _pick_rows_block(rows: int, hidden: int) -> int:
     return 0
 
 
-def fused_norm_supported(x: jax.Array, residual: jax.Array | None = None
-                         ) -> bool:
+def _whole_array(shape) -> bool:
+    """True when the array is one block under the VMEM budget."""
+    total = 1
+    for d in shape:
+        total *= d
+    return total * _BYTES_PER_ELEMENT <= _FUSED_NORM_VMEM_BYTES
+
+
+def fused_norm_supported(x: jax.Array, residual: jax.Array | None = None,
+                         *, mesh=None, spec: P | None = None) -> bool:
     """True when the fused kernel applies to this activation shape: hidden
     dim lane-aligned (multiple of 128), the second-minor (seq) dim tiling
     into a sublane-aligned block that fits the VMEM budget, and a float
-    compute dtype. Shapes this rejects keep the unfused jnp path —
-    today's behavior, never silence.
+    compute dtype. Under a multi-device ``mesh`` the shape judged is the
+    per-device one ``spec`` leaves (`fused_residual_norm` runs the kernel
+    per shard); a dim that does not divide its mesh axes is rejected.
+    Shapes this rejects keep the unfused jnp path — today's behavior,
+    never silence.
 
     The kernel blocks the *native-rank* array over its ``-2`` axis
     (leading dims become grid dims) rather than flattening to
     ``[rows, hidden]``: a rank change perturbs XLA's reduce codegen by an
     ulp, which would break the bitwise-f32 contract with the fallback.
     """
-    if pltpu is None:
-        return False
     if x.ndim < 2:
         return False
     if residual is not None and (residual.shape != x.shape
@@ -96,15 +100,20 @@ def fused_norm_supported(x: jax.Array, residual: jax.Array | None = None
         return False
     if x.dtype not in (jnp.float32, jnp.bfloat16, jnp.float16):
         return False
-    hidden = x.shape[-1]
+    shape = x.shape
+    if mesh is not None and mesh.size > 1:
+        spec = spec or P()
+        if len(spec) == x.ndim and spec[-1] is not None:
+            return False  # the norm reduces over hidden: it stays whole
+        shape = ops.local_shape(shape, spec, mesh)
+        if shape is None:
+            return False
+    hidden = shape[-1]
     if hidden < 128 or hidden % 128:
         return False
-    total_rows = 1
-    for d in x.shape[:-1]:
-        total_rows *= d
-    if total_rows * hidden * _BYTES_PER_ELEMENT <= _FUSED_NORM_VMEM_BYTES:
+    if _whole_array(shape):
         return True  # whole array in one block (also the bitwise-pin path)
-    return _pick_rows_block(x.shape[-2], hidden) > 0
+    return _pick_rows_block(shape[-2], hidden) > 0
 
 
 def _fwd_kernel(*refs, eps: float, have_residual: bool):
@@ -184,10 +193,7 @@ def _specs(shape, hidden):
     codegen too. Larger arrays block the ``-2`` (seq) axis into
     sublane-aligned rows with the leading dims as grid dims."""
     nd = len(shape)
-    total_rows = 1
-    for d in shape[:-1]:
-        total_rows *= d
-    if total_rows * hidden * _BYTES_PER_ELEMENT <= _FUSED_NORM_VMEM_BYTES:
+    if _whole_array(shape):
         grid = (1,)
         row_spec = pl.BlockSpec(shape, lambda i: (0,) * nd)
         stat_spec = pl.BlockSpec(shape[:-1] + (1,), lambda i: (0,) * nd)
@@ -230,7 +236,7 @@ def _fwd_call(x, r, scale_v, bias_v, eps, out_dtype):
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=_interpret(),
+        interpret=ops.interpret(),
         name="fused_norm_fwd",
     )(*operands)
     if have_residual:
@@ -260,7 +266,7 @@ def _bwd_call(s, scale_v, mean, var, do, eps, ds_in=None):
         in_specs=in_specs,
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct(shape, s.dtype),
-        interpret=_interpret(),
+        interpret=ops.interpret(),
         name="fused_norm_bwd",
     )(*operands)
     return dx
@@ -331,7 +337,8 @@ _fused_norm.defvjp(_fused_norm_fwd, _fused_norm_bwd)
 def fused_residual_norm(x: jax.Array, scale: jax.Array, bias: jax.Array,
                         residual: jax.Array | None = None, *,
                         eps: float = 1e-5,
-                        out_dtype=jnp.float32):
+                        out_dtype=jnp.float32,
+                        mesh=None, spec: P | None = None):
     """Fused (residual-add +) f32 LayerNorm + cast; the public entry point.
 
     Returns ``(out, s)`` where ``s = residual + x`` (or ``x`` when
@@ -339,7 +346,27 @@ def fused_residual_norm(x: jax.Array, scale: jax.Array, bias: jax.Array,
     ``out = LayerNorm_f32(s).astype(out_dtype)``. Callers must gate on
     `fused_norm_supported` first; this function assumes the shape was
     admitted.
+
+    Under a multi-device ``mesh`` the kernel runs per shard inside a
+    ``shard_map`` manual over every mesh axis (GSPMD cannot partition a
+    Mosaic call): ``spec`` says how the activations lie over the mesh; the
+    op is row-wise, so any sharding of the leading dims is legal, and
+    ``scale``/``bias`` enter replicated (their cotangents are summed over
+    the mesh by the ``shard_map`` transpose).
     """
+    eps = float(eps)
     if residual is None:
-        return _fused_norm(x, scale, bias, float(eps), out_dtype), x
-    return _fused_add_norm(x, residual, scale, bias, float(eps), out_dtype)
+        def norm(x, scale, bias):
+            return _fused_norm(x, scale, bias, eps, out_dtype)
+    else:
+        def norm(x, residual, scale, bias):
+            return _fused_add_norm(x, residual, scale, bias, eps, out_dtype)
+    rows = (x,) if residual is None else (x, residual)
+    if mesh is not None and mesh.size > 1:
+        spec = spec or P()
+        out_specs = spec if residual is None else (spec, spec)
+        norm = jax.shard_map(
+            norm, mesh=mesh, in_specs=(spec,) * len(rows) + (P(), P()),
+            out_specs=out_specs, check_vma=False)
+    out = norm(*rows, scale, bias)
+    return (out, x) if residual is None else out

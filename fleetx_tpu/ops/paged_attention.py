@@ -26,7 +26,7 @@ Contract mirrors ``ops/flash_attention.py`` exactly:
   today's gather — degrade, never break (``serving/decode.py`` makes the
   choice ONCE at ``make_step_fns`` time so the jit cache still holds one
   entry).
-- CPU runs the kernel in interpret mode (``_interpret()``), which is how
+- CPU runs the kernel in interpret mode (``ops.interpret()``), which is how
   the serving parity suite pins token-identity without a TPU.
 - Under a multi-device mesh the kernel is a Mosaic custom call GSPMD
   cannot partition, so ``paged_attention_sharded`` runs it per-device via
@@ -45,14 +45,11 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu only importable on TPU-enabled builds; interpret mode needs it
-    from jax.experimental.pallas import tpu as pltpu
+from fleetx_tpu import ops
 
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover - exercised on minimal builds
-    pltpu = None
-    _VMEM = None
+_VMEM = pltpu.VMEM
 
 _NEG_INF = -1e30
 
@@ -66,36 +63,23 @@ NULL_PAGE = 0
 #: page_size × head_dim configs rather than anything a serving YAML ships.
 _PAGED_VMEM_BUDGET_BYTES = 2 * 1024 * 1024
 
-#: head-block candidates: largest divisor of the (per-shard) head count,
-#: capped small — decode attention is DMA-bound, wider head blocks only
-#: grow the K/V tile without feeding the MXU any better.
-_HEAD_BLOCK_CANDIDATES = (8, 4, 2, 1)
+#: widest head block: decode attention is DMA-bound, so a wider block only
+#: grows the K/V tile — but each grid step costs a fixed overhead, so the
+#: block is as wide as this cap allows
+_MAX_HEAD_BLOCK = 16
 
 
-def _interpret() -> bool:
-    return jax.default_backend() == "cpu"
-
-
-def pick_head_block(num_heads: int) -> int:
-    """Largest head-block candidate dividing ``num_heads`` (≥ 1 always)."""
-    for hb in _HEAD_BLOCK_CANDIDATES:
-        if num_heads % hb == 0:
+def pick_head_block(num_heads: int, dtype: Any = jnp.float32) -> int:
+    """Widest head block ≤ `_MAX_HEAD_BLOCK` dividing ``num_heads`` that
+    Mosaic can address: heads are the second-minor dim of the K/V tile
+    ``[page_size, heads, head_dim]``, so the block is a multiple of the
+    dtype's sublane tile (8 rows of 4 bytes, 16 of 2) or all the heads.
+    0 when no block qualifies."""
+    sublanes = 8 * 4 // jnp.dtype(dtype).itemsize
+    for hb in range(min(num_heads, _MAX_HEAD_BLOCK), 0, -1):
+        if num_heads % hb == 0 and (hb % sublanes == 0 or hb == num_heads):
             return hb
-    return 1
-
-
-def _shard_map_fn():
-    """Feature-detect a usable ``shard_map`` (None when this jax has
-    neither the stable nor the experimental API)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn
-    try:
-        from jax.experimental.shard_map import shard_map as _sm
-
-        return _sm
-    except ImportError:  # pragma: no cover - every pinned jax has one
-        return None
+    return 0
 
 
 def paged_attention_supported(*, num_heads: int, head_dim: int,
@@ -105,12 +89,12 @@ def paged_attention_supported(*, num_heads: int, head_dim: int,
 
     Consulted ONCE per engine (``serving/decode.py:make_step_fns``) —
     shapes it rejects take the dense gather path, today's behavior, never
-    silence. Bounds are alignment (f32 sublane-friendly ``head_dim``) and
-    the VMEM tile budget; Mosaic pads small tiles, so the gate is about
-    staying a sensible kernel rather than about lowering at all.
+    silence. ``num_heads`` is what ONE device holds (the kernel runs per
+    shard). Bounds are alignment (sublane-friendly ``head_dim``, a head
+    block the dtype's tile can address) and the VMEM tile budget. The
+    shipped geometry — 16 and 8 heads × 64, page 16 — compiles and decodes
+    right on the v5e in bf16 and f32 (PERF.md).
     """
-    if pltpu is None:
-        return False
     if num_heads < 1 or pages_per_req < 1 or page_size < 1:
         return False
     if head_dim < 8 or head_dim % 8 or head_dim > 256:
@@ -118,21 +102,23 @@ def paged_attention_supported(*, num_heads: int, head_dim: int,
     if jnp.dtype(dtype) not in (jnp.dtype(jnp.float32),
                                 jnp.dtype(jnp.bfloat16)):
         return False
-    hb = pick_head_block(num_heads)
+    hb = pick_head_block(num_heads, dtype)
+    if hb == 0:
+        return False
     esize = jnp.dtype(dtype).itemsize
     # double-buffered K+V page tiles + f32 acc/m/l scratch
     tile = 2 * 2 * page_size * hb * head_dim * esize
-    scratch = hb * head_dim * 4 + 2 * hb * 128 * 4
+    scratch = hb * head_dim * 4 + 2 * hb * 128 * 4  # (hb, 1) pads to lanes
     return tile + scratch <= _PAGED_VMEM_BUDGET_BYTES
 
 
 def paged_sharded_supported(mesh: Any, *, num_heads: int,
                             num_pages: int) -> bool:
-    """True when the per-device ``shard_map`` wrapping applies: a
-    ``shard_map`` API exists, the pool's page dim splits evenly over
-    ``fsdp`` and its head dim over ``tensor`` (the ``serving_kv``
-    placement), and decode is not running under sequence parallelism."""
-    if mesh is None or _shard_map_fn() is None:
+    """True when the per-device ``shard_map`` wrapping applies: the pool's
+    page dim splits evenly over ``fsdp`` and its head dim over ``tensor``
+    (the ``serving_kv`` placement), and decode is not running under
+    sequence or pipeline parallelism."""
+    if mesh is None:
         return False
     shape = dict(mesh.shape)
     if shape.get("seq", 1) != 1 or shape.get("pipe", 1) != 1:
@@ -171,32 +157,33 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref,
 
     @pl.when(run)
     def _compute():
+        # One query row per head: a matrix-vector product, so the VPU does
+        # it in the pool's own layout. The tile keeps pages leading and
+        # (heads, head_dim) on (sublanes, lanes) throughout — scores reduce
+        # over lanes with the dim kept, the page reduction adds whole
+        # vregs — where an MXU ``dot_general`` would want the head batch
+        # dim leading on K/V, which the page-major pool does not give.
         q = q_ref[0].astype(jnp.float32)                  # [hb, hd]
         k = k_ref[0].astype(jnp.float32)                  # [ps, hb, hd]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32) * scale   # [hb, ps]
-        pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = (k * q[None]).sum(axis=-1, keepdims=True) * scale  # [ps, hb, 1]
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         s = jnp.where(pos <= q_pos, s, _NEG_INF)
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
+        m_prev = m_ref[...]                                # [hb, 1]
+        m_new = jnp.maximum(m_prev, s.max(axis=0))
         alpha = jnp.exp(m_prev - m_new)
-        pexp = jnp.exp(s - m_new[:, None])
-        l_ref[:, 0] = l_ref[:, 0] * alpha + pexp.sum(axis=1)
-        m_ref[:, 0] = m_new
+        pexp = jnp.exp(s - m_new[None])                   # [ps, hb, 1]
+        l_ref[...] = l_ref[...] * alpha + pexp.sum(axis=0)
+        m_ref[...] = m_new
         v = v_ref[0].astype(jnp.float32)                  # [ps, hb, hd]
-        pv = jax.lax.dot_general(
-            pexp, v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)           # [hb, hd]
-        acc_ref[0] = acc_ref[0] * alpha[:, None] + pv
+        acc_ref[0] = acc_ref[0] * alpha + (pexp * v).sum(axis=0)
 
     @pl.when(p == np_ - 1)
     def _finish():
         # m/l laid out [B, nh, 1]: a (hb, 1) store satisfies Mosaic's
         # last-two-dims tiling where a 2D (1, hb) block does not — the
         # flash kernel's lse idiom.
-        m_out_ref[0] = m_ref[:, 0][:, None]
-        l_out_ref[0] = l_ref[:, 0][:, None]
+        m_out_ref[0] = m_ref[...]
+        l_out_ref[0] = l_ref[...]
 
 
 def _paged_call(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
@@ -213,7 +200,7 @@ def _paged_call(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     B, nh, hd = q.shape
     ps = pool_k.shape[1]
     pages_per_req = tables.shape[1]
-    hb = pick_head_block(nh)
+    hb = pick_head_block(nh, pool_k.dtype)
     scale = 1.0 / math.sqrt(hd)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -234,8 +221,8 @@ def _paged_call(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
             pl.BlockSpec((1, hb, 1), lambda b, h, p, t, l: (b, h, 0)),
         ],
         scratch_shapes=[
-            _VMEM((hb, 128), jnp.float32),
-            _VMEM((hb, 128), jnp.float32),
+            _VMEM((hb, 1), jnp.float32),
+            _VMEM((hb, 1), jnp.float32),
         ],
     )
     acc, m, l = pl.pallas_call(
@@ -246,7 +233,8 @@ def _paged_call(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
             jax.ShapeDtypeStruct((B, nh, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, nh, 1), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=ops.interpret(),
+        name="paged_decode",
     )(tables, lens, q, pool_k, pool_v)
     return acc, m[..., 0], l[..., 0]
 
@@ -291,18 +279,14 @@ def paged_attention_sharded(q: jax.Array, pool_k: jax.Array,
     slice; partial (acc, m, l) triples are merged with the standard
     flash-decoding combine (global running max over ``fsdp``, rescaled
     numerator/denominator psum). Callers must have gated on
-    :func:`paged_sharded_supported`; with no mesh (or a trivial one) this
+    :func:`paged_sharded_supported`; with no mesh (or one device) this
     is the single-shard call.
     """
     from jax.sharding import PartitionSpec as _P
 
     from fleetx_tpu.parallel.rules import kv_pool_spec
 
-    manual = ()
-    if mesh is not None:
-        manual = tuple(a for a in ("fsdp", "tensor")
-                       if dict(mesh.shape).get(a, 1) > 1)
-    if not manual:
+    if mesh is None or mesh.size == 1:
         return paged_attention(q, pool_k, pool_v, block_tables, lens)
 
     # per-layer pool spec = the registry's 5D serving_kv spec minus the
@@ -310,19 +294,14 @@ def paged_attention_sharded(q: jax.Array, pool_k: jax.Array,
     # (PartitionSpec drops trailing Nones, hence the re-pad to 4 dims)
     entries = (tuple(kv_pool_spec())[1:] + (None, None, None, None))[:4]
     pages_ax, _, heads_ax, _ = entries
-    pages_ax = pages_ax if pages_ax in manual else None
-    heads_ax = heads_ax if heads_ax in manual else None
     pool_spec = _P(pages_ax, None, heads_ax, None)
     q_spec = _P(None, heads_ax, None)
-    fsdp = dict(mesh.shape).get(pages_ax, 1) if pages_ax else 1
-    local_pages = pool_k.shape[0] // fsdp
+    local_pages = pool_k.shape[0] // mesh.shape[pages_ax]
 
     def body(q, pk, pv, tabs, lens):
-        lo = jax.lax.axis_index(pages_ax) * local_pages if pages_ax else 0
+        lo = jax.lax.axis_index(pages_ax) * local_pages
         tabs = _localize_tables(tabs, lo, local_pages)
         acc, m, l = _paged_call(q, pk, pv, tabs, lens)
-        if pages_ax is None:
-            return _normalize(acc, l, q.dtype)
         # flash-decoding combine across the page shards: rescale every
         # shard's numerator/denominator to the global running max, sum
         m_g = jax.lax.pmax(m, pages_ax)
@@ -331,17 +310,11 @@ def paged_attention_sharded(q: jax.Array, pool_k: jax.Array,
         den = jax.lax.psum(l * w, pages_ax)
         return _normalize(num, den, q.dtype)
 
-    # FULL-manual mapping (every mesh axis): ``axis_index`` — the page-slice
-    # localizer — lowers to a PartitionId XLA cannot place under the
-    # partial-manual mode, and decode has no other tensor the remaining
-    # axes could stay automatic for. The stable ``jax.shard_map`` and the
-    # experimental API spell the replication-check kwarg differently.
-    sm = _shard_map_fn()
-    in_specs = (q_spec, pool_spec, pool_spec, _P(None, None), _P(None))
-    try:
-        fn = sm(body, mesh=mesh, in_specs=in_specs, out_specs=q_spec,
-                check_vma=False)
-    except TypeError:
-        fn = sm(body, mesh=mesh, in_specs=in_specs, out_specs=q_spec,
-                check_rep=False)
+    # manual over EVERY mesh axis: the only context in which a Mosaic call
+    # lowers under a mesh, and decode has no other tensor the remaining
+    # axes could stay automatic for
+    fn = jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(q_spec, pool_spec, pool_spec, _P(None, None), _P(None)),
+        out_specs=q_spec, check_vma=False)
     return fn(q, pool_k, pool_v, block_tables, lens)

@@ -10,11 +10,13 @@ attention where K/V blocks rotate around the ``seq`` ring via
 block into an online-softmax accumulator — flash attention's streaming
 update, distributed.
 
-Written as a *partial-manual* ``jax.shard_map``: only ``seq`` is manual, so
-GSPMD still handles dp/fsdp/tensor sharding of the same operands inside the
-body. Causality with contiguous block sharding means block ``j`` contributes
-to queries of block ``i`` only when ``j <= i``; later blocks are masked (the
-compute is uniform across ring steps — the standard ring-attention bubble).
+Written as a ``jax.shard_map`` manual over EVERY mesh axis — the per-block
+Pallas kernels are Mosaic calls, which lower under a mesh in no other
+context — with batch over ``(data, fsdp)``, the sequence over ``seq`` and
+heads over ``tensor``; only ``seq`` carries collectives. Causality with
+contiguous block sharding means block ``j`` contributes to queries of block
+``i`` only when ``j <= i``; later blocks are masked (the compute is uniform
+across ring steps — the standard ring-attention bubble).
 """
 
 from __future__ import annotations
@@ -25,6 +27,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
+
+from fleetx_tpu import ops
+from fleetx_tpu.parallel.rules import activation_spec
 
 __all__ = ["ring_attention", "ring_attention_local", "ring_flash_local",
            "flash_ring_supported"]
@@ -47,8 +52,7 @@ def ring_attention_local(q: jax.Array, k: jax.Array, v: jax.Array, *,
     of f32 scores per ring step. Exact (online softmax), differentiable
     (plain ``lax.scan``); must divide the local block length.
     """
-    ring = lax.static_axis_size(axis_name) if hasattr(lax, "static_axis_size") \
-        else lax.axis_size(axis_name)
+    ring = lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     b, s_loc, n, d = q.shape
     scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
@@ -108,14 +112,29 @@ def ring_attention_local(q: jax.Array, k: jax.Array, v: jax.Array, *,
 # ---------------------------------------------------------------------------
 
 
-def flash_ring_supported(q: jax.Array, ring: int) -> bool:
-    """True when each device's local block (global seq / ``ring``) satisfies
-    the Pallas kernel contract."""
-    from fleetx_tpu.ops import flash_attention as fa
+def _qkv_spec(shape: tuple, mesh) -> P:
+    """Operand layout ``[batch, seq, heads, head_dim]`` over the mesh: batch
+    over ``(data, fsdp)``, the sequence on the ring axis, heads over
+    ``tensor``. A batch or head count that does not divide its axes stays
+    whole — every device then holds all of it (the init-time dummy batch
+    of 1)."""
+    batch, seq, heads = activation_spec("batch", "act_seq", "act_heads")
+    if ops.local_shape(shape[:1], P(batch), mesh) is None:
+        batch = None
+    if ops.local_shape(shape[2:3], P(heads), mesh) is None:
+        heads = None
+    return P(batch, seq, heads)
 
-    if fa.pltpu is None or q.ndim != 4 or q.shape[1] % max(ring, 1):
+
+def flash_ring_supported(q: jax.Array, mesh) -> bool:
+    """True when each device's local block (seq / ring) satisfies the Pallas
+    kernel contract."""
+    if q.ndim != 4:
         return False
-    s_loc, d = q.shape[1] // ring, q.shape[3]
+    local = ops.local_shape(q.shape, _qkv_spec(q.shape, mesh), mesh)
+    if local is None:
+        return False
+    s_loc, d = local[1], local[3]
     return s_loc >= 128 and s_loc % 128 == 0 and d in (64, 128, 256)
 
 
@@ -254,14 +273,14 @@ _ring_flash3.defvjp(_ring_flash3_fwd, _ring_flash3_bwd)
 
 
 def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                   causal: bool = True, axis_name: str = "seq",
+                   causal: bool = True,
                    kv_chunk: int | None = None, mesh=None,
                    use_flash: bool | None = None) -> jax.Array:
     """Sequence-parallel attention: q/k/v ``[b, s, n, d]`` with ``s`` sharded
-    over ``axis_name``. Must run inside jit under the mesh context (the
-    engine's ``_ctx``); all other axes stay GSPMD-automatic. ``kv_chunk``
-    bounds per-ring-step score memory on the einsum path
-    (see ``ring_attention_local``).
+    over ``seq``, batch over ``(data, fsdp)`` and heads over ``tensor``.
+    Must run inside jit under the mesh context (the engine's ``_ctx``).
+    ``kv_chunk`` bounds per-ring-step score memory on the einsum path (see
+    ``ring_attention_local``).
 
     ``use_flash`` None (auto) routes causal calls whose local block fits the
     Pallas contract through ``ring_flash_local`` — per-block attention on
@@ -272,14 +291,12 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
         mesh = current_mesh()
     assert mesh is not None, "ring_attention needs an ambient or explicit mesh"
-    ring = mesh.shape.get(axis_name, 1)
+    spec = _qkv_spec(q.shape, mesh)
     if use_flash is None:
-        use_flash = causal and flash_ring_supported(q, ring)
-    body = (partial(ring_flash_local, axis_name=axis_name) if use_flash
-            else partial(ring_attention_local, axis_name=axis_name,
+        use_flash = causal and flash_ring_supported(q, mesh)
+    body = (partial(ring_flash_local, axis_name=spec[1]) if use_flash
+            else partial(ring_attention_local, axis_name=spec[1],
                          causal=causal, kv_chunk=kv_chunk))
-    spec = P(None, axis_name)
-    fn = jax.shard_map(
-        body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        axis_names=frozenset({axis_name}), check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
     return fn(q, k, v)

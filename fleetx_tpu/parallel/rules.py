@@ -63,7 +63,8 @@ __all__ = [
     "STACK_MARKERS", "REPLICATED", "SpecLayout", "match_partition_rules",
     "registry_specs", "named_shardings", "tree_leaf_names", "spec_for",
     "canonicalize", "first_free_divisible_dim", "with_fsdp_axis",
-    "stage_shards", "kv_pool_spec", "batch_spec", "audit_leaves",
+    "stage_shards", "kv_pool_spec", "batch_spec", "activation_spec",
+    "audit_leaves",
     "registry_fingerprint", "family_fingerprint", "families", "family_of",
 ]
 
@@ -496,16 +497,13 @@ def first_free_divisible_dim(shape: Iterable[int], spec: Iterable[Any],
 
 
 def with_fsdp_axis(shape: tuple, spec: Iterable[Any], size: int,
-                   axis: str = "fsdp",
-                   only_if_replicated: bool = False) -> tuple:
-    """Augment a canonical spec with the ZeRO axis.
-
-    ``only_if_replicated`` is the optimizer-state mode (stage 1/2
-    ``zero_sharding``): a leaf already carrying ANY mesh axis keeps its
-    spec untouched. Otherwise (gradient mode, ``zero_grad_specs``) the
-    existing entries are kept and ``axis`` lands on the first free
-    divisible dim — unless it is already used by the param's own spec.
-    Returns the canonical (no-trailing-None) tuple either way.
+                   axis: str = "fsdp") -> tuple:
+    """Augment a canonical spec with the ZeRO axis: the existing entries
+    (tensor-parallel / stage-3 dims) are kept and ``axis`` lands on the
+    first free divisible dim — unless the param's own spec already uses
+    it. One placement for Adam moments (``zero_sharding``) and gradients
+    (``zero_grad_specs``), so the sharded update reads both from the same
+    shard. Returns the canonical (no-trailing-None) tuple.
     """
     entries = list(spec)
     entries += [None] * (len(shape) - len(entries))
@@ -514,11 +512,7 @@ def with_fsdp_axis(shape: tuple, spec: Iterable[Any], size: int,
         for a in (entry if isinstance(entry, (tuple, list)) else (entry,)):
             if a is not None:
                 used.add(a)
-    if only_if_replicated and used:
-        return canonicalize(entries)
     if size > 1 and axis not in used:
-        if only_if_replicated:
-            entries = [None] * len(shape)
         dim = first_free_divisible_dim(shape, entries, size)
         if dim is not None:
             entries[dim] = axis
@@ -553,6 +547,15 @@ def batch_spec():
     from jax.sharding import PartitionSpec as P
 
     return P(*canonicalize((SpecLayout().mesh_entry("batch"),)))
+
+
+def activation_spec(*logical: Optional[str]):
+    """Mesh placement of an activation annotated with ``logical`` axis
+    names under the default layout — what the Pallas kernels' ``shard_map``
+    wrappers (``ops/``) name as their in/out specs."""
+    from jax.sharding import PartitionSpec as P
+
+    return P(*SpecLayout().to_mesh(logical))
 
 
 def registry_fingerprint() -> str:

@@ -65,7 +65,7 @@ def shard_logical(x: jax.Array, logical_axes: tuple[str | None, ...],
     return jax.lax.with_sharding_constraint(x, spec)
 
 
-def _fsdp_leaf_fn(mesh: Mesh, axis: str, only_if_replicated: bool):
+def _fsdp_leaf_fn(mesh: Mesh, axis: str):
     """The ONE ZeRO per-leaf placement closure shared by
     ``zero_sharding`` (optimizer state, stage 1/2) and ``zero_grad_specs``
     (gradients, stage 2) — policy lives in ``rules.with_fsdp_axis``."""
@@ -75,9 +75,8 @@ def _fsdp_leaf_fn(mesh: Mesh, axis: str, only_if_replicated: bool):
         shape = tuple(getattr(leaf, "shape", ()))
         spec = tuple(getattr(existing, "spec", P())) if existing is not None \
             else ()
-        return NamedSharding(mesh, P(*with_fsdp_axis(
-            shape, spec, size, axis=axis,
-            only_if_replicated=only_if_replicated)))
+        return NamedSharding(mesh, P(*with_fsdp_axis(shape, spec, size,
+                                                     axis=axis)))
 
     return leaf_spec
 
@@ -88,12 +87,13 @@ def zero_sharding(tree: Any, mesh: Mesh, axis: str = "fsdp",
 
     The reference's sharding stage 1/2 (``group_sharded_parallel`` with
     ``level="os_g"``, ``eager_engine.py:228-242``) shards optimizer state while
-    keeping params replicated.  Here: for each optimizer-state leaf, shard the
-    first dimension divisible by the fsdp axis size; leaves with no divisible
-    dimension (scalars, small vectors) stay replicated.  Leaves that already
-    carry a non-replicated param sharding (stage 3 / tensor parallel) keep it.
+    keeping params replicated.  Here: each optimizer-state leaf keeps its
+    param's spec (tensor parallel / stage 3) and additionally shards the
+    first still-replicated dimension divisible by the fsdp axis size — the
+    placement ``zero_grad_specs`` gives the gradients; leaves with no such
+    dimension (scalars, small vectors) keep the param spec.
     """
-    leaf_spec = _fsdp_leaf_fn(mesh, axis, only_if_replicated=True)
+    leaf_spec = _fsdp_leaf_fn(mesh, axis)
     if param_shardings is not None:
         return jax.tree.map(leaf_spec, tree, param_shardings)
     return jax.tree.map(leaf_spec, tree)
@@ -112,14 +112,9 @@ def zero_grad_specs(tree: Any, mesh: Mesh, axis: str = "fsdp",
     Cross-Replica Sharding of Weight Update in Data-Parallel Training"
     (PAPERS.md).
 
-    Per leaf: keep the param's existing spec (tensor-parallel / stage-3
-    dims stay where they are) and additionally shard the first
-    still-replicated dimension divisible by the ``fsdp`` size.  Leaves with
-    no such dimension (scalars, tiny vectors) keep the param spec — GSPMD
+    Same per-leaf placement as ``zero_sharding``.  Leaves with no free
+    divisible dimension (scalars, tiny vectors) keep the param spec — GSPMD
     falls back to the plain allreduce for those few bytes.  Specs are
     canonical (no trailing ``None``).
     """
-    leaf_spec = _fsdp_leaf_fn(mesh, axis, only_if_replicated=False)
-    if param_shardings is not None:
-        return jax.tree.map(leaf_spec, tree, param_shardings)
-    return jax.tree.map(leaf_spec, tree)
+    return zero_sharding(tree, mesh, axis, param_shardings)

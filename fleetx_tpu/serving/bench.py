@@ -86,9 +86,9 @@ def run_serving_bench(engine: ServingEngine, *, n_requests: int = 32,
     engine.run_until_drained()
     engine.reset_stats()
 
-    # the watcher's traced re-run (tools/tpu_watch.py _traced_sweep):
-    # profile the measured window only — warmup compiles stay off the
-    # trace, same stance as bench.py's armed window
+    # FLEETX_BENCH_TRACE names a trace directory: profile the measured
+    # window only — warmup compiles stay off the trace, same stance as
+    # bench.py's armed window
     trace_dir = os.environ.get("FLEETX_BENCH_TRACE")
     if trace_dir:
         import jax

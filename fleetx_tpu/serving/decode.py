@@ -60,25 +60,21 @@ def paged_kernel_enabled(cfg: Any, *, page_size: int, num_pages: int,
     """Static kernel-vs-gather decision for one engine's geometry.
 
     True when the Pallas page-walk kernel serves decode: the shape
-    predicate admits the (heads, head_dim, page) tiling, and — under a
-    mesh that actually shards the pool — the per-device ``shard_map``
-    wrapping applies too. Consulted once per engine; the result is baked
+    predicate admits one shard's (heads, head_dim, page) tiling, and —
+    under a multi-device mesh — the per-device ``shard_map`` wrapping
+    applies too. Consulted once per engine; the result is baked
     into the decode program so the no-retrace pin is untouched.
     """
-    if not PA.paged_attention_supported(
-            num_heads=cfg.num_attention_heads, head_dim=cfg.head_dim,
-            page_size=page_size, pages_per_req=pages_per_req,
-            dtype=cfg.dtype):
-        return False
-    if pool_sharding is not None:
+    heads = cfg.num_attention_heads
+    if pool_sharding is not None and pool_sharding.mesh.size > 1:
         mesh = pool_sharding.mesh
-        sharded = any(dict(mesh.shape).get(a, 1) > 1
-                      for a in ("fsdp", "tensor"))
-        if sharded and not PA.paged_sharded_supported(
-                mesh, num_heads=cfg.num_attention_heads,
-                num_pages=num_pages):
+        if not PA.paged_sharded_supported(mesh, num_heads=heads,
+                                          num_pages=num_pages):
             return False
-    return True
+        heads //= mesh.shape["tensor"]  # the kernel sees one shard's heads
+    return PA.paged_attention_supported(
+        num_heads=heads, head_dim=cfg.head_dim, page_size=page_size,
+        pages_per_req=pages_per_req, dtype=cfg.dtype)
 
 
 @dataclasses.dataclass(frozen=True)

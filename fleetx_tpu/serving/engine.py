@@ -58,6 +58,7 @@ from fleetx_tpu.serving.decode import (SamplingParams, make_step_fns,
                                        paged_kernel_enabled)
 from fleetx_tpu.serving.paged_cache import (NULL_PAGE, PageAllocator,
                                             init_pool, pool_shardings)
+from fleetx_tpu.utils.env import log_compile
 from fleetx_tpu.utils.log import logger
 
 #: request lifecycle states
@@ -345,6 +346,8 @@ class ServingEngine:
             quantize=bool(sc.quantize_decode), pool_sharding=sharding,
             paged_kernel=self.paged_kernel_active)
 
+        self._compiled: set = set()  # programs whose compile was logged
+
         # host-side scheduler state
         self._slots: list = [None] * sc.max_batch
         self._block_tables = np.full((sc.max_batch, self.pages_per_req),
@@ -544,6 +547,15 @@ class ServingEngine:
             flight.note("serving", "admit", id=req.id, slot=slot,
                         pages=need)
 
+    def _call(self, name: str, *args):
+        """Run one of the two device programs; its first call says what it
+        compiles — seconds and Mosaic kernels by name."""
+        fn = self._fns[name]
+        if name not in self._compiled:
+            self._compiled.add(name)
+            log_compile(f"serving {name}", fn, *args)
+        return fn(*args)
+
     def _next_rng(self) -> jax.Array:
         self._rng, sub = jax.random.split(self._rng)
         return sub
@@ -561,9 +573,9 @@ class ServingEngine:
         tokens[0, :n_valid] = chunk
         table = self._block_tables[req.slot:req.slot + 1]
         with self.metrics.timer("serving_prefill_step"):
-            self.pool_k, self.pool_v, tok, _ = self._fns["prefill"](
-                self.params, self.pool_k, self.pool_v, tokens, table,
-                np.int32(pos), np.int32(n_valid), self._next_rng())
+            self.pool_k, self.pool_v, tok, _ = self._call(
+                "prefill", self.params, self.pool_k, self.pool_v, tokens,
+                table, np.int32(pos), np.int32(n_valid), self._next_rng())
             req.prefill_pos = pos + n_valid
             self.timelines.note(req.id, "prefill_chunk",
                                 chunk=pos // max(sc.prefill_chunk, 1),
@@ -748,9 +760,10 @@ class ServingEngine:
         if not running:
             return False
         with self.metrics.timer("serving_decode_step"):
-            self.pool_k, self.pool_v, toks, _ = self._fns["decode"](
-                self.params, self.pool_k, self.pool_v, self._last_tokens,
-                self._block_tables, self._lens, self._next_rng())
+            self.pool_k, self.pool_v, toks, _ = self._call(
+                "decode", self.params, self.pool_k, self.pool_v,
+                self._last_tokens, self._block_tables, self._lens,
+                self._next_rng())
             toks = jax.device_get(toks)
             now = time.monotonic()
             for req in running:

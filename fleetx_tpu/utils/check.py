@@ -1,52 +1,37 @@
-"""Startup environment checks (reference ``utils/check.py:250-277`` +
-``utils/version.py:18-21`` — paddle-version / GPU checks become jax-version /
-device checks)."""
+"""Startup device check (reference ``utils/check.py:250-277`` — the GPU
+check becomes a device check; the supported jax is the one
+``requirements.txt`` pins)."""
 
 from __future__ import annotations
 
+import os
+
 from fleetx_tpu.utils.log import logger
 
-MIN_JAX = (0, 4, 35)
 
+def check_devices(expect_tpu: bool = False) -> None:
+    """Log the device inventory and refuse a platform nobody asked for.
 
-def check_version() -> bool:
-    """Warn when the installed jax predates the supported minimum."""
-    import jax
-
-    parts = tuple(int(p) for p in jax.__version__.split(".")[:3])
-    ok = parts >= MIN_JAX
-    if not ok:
-        logger.warning("jax %s < required %s", jax.__version__,
-                       ".".join(map(str, MIN_JAX)))
-    return ok
-
-
-def check_devices(expect_tpu: bool = False) -> bool:
-    """Log the device inventory; warn when a TPU config runs on CPU.
-
-    A check must diagnose, not crash: backend-init failures (e.g. an
-    unreachable TPU plugin) are reported as a failed check, not raised.
+    A backend that does not initialise raises (``jax.devices()`` does). A
+    config that names ``device: tpu`` on another platform raises too —
+    unless the operator asked for the CPU by name (``JAX_PLATFORMS=cpu``,
+    which is how the tests and every CPU recipe run): a run that quietly
+    lands on the CPU trains at a thousandth of the speed and says nothing.
     """
     import jax
 
-    try:
-        devices = jax.devices()
-    except RuntimeError as e:
-        logger.warning("backend initialization failed: %s", e)
-        return False
+    devices = jax.devices()
     platform = devices[0].platform
     logger.info("devices: %d x %s (%s)", len(devices), platform,
-                getattr(devices[0], "device_kind", "?"))
-    if expect_tpu and platform != "tpu":
-        logger.warning("config requests device: tpu but backend is %s — "
-                       "continuing (dev mode)", platform)
-        return False
-    return True
+                devices[0].device_kind)
+    if expect_tpu and platform != "tpu" and \
+            os.environ.get("JAX_PLATFORMS", "").lower() != "cpu":
+        raise RuntimeError(
+            f"config requests device: tpu but the backend is {platform!r}; "
+            f"set JAX_PLATFORMS=cpu to run on the CPU on purpose")
 
 
-def check_config(cfg: dict) -> bool:
-    """Run all startup checks for a parsed config."""
-    ok = check_version()
+def check_config(cfg: dict) -> None:
+    """Run the startup checks for a parsed config."""
     glb = dict(cfg.get("Global") or {})
-    ok &= check_devices(expect_tpu=str(glb.get("device", "")).lower() == "tpu")
-    return ok
+    check_devices(expect_tpu=str(glb.get("device", "")).lower() == "tpu")

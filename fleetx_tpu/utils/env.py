@@ -13,7 +13,11 @@ trackers (``env.py:41-46``).
 
 from __future__ import annotations
 
+import collections
+import json
 import os
+import re
+import time
 
 import jax
 
@@ -66,6 +70,44 @@ def init_dist_env(coordinator_address: str | None = None,
                     jax.process_index(), jax.process_count())
     _initialized = distributed
     return _initialized
+
+
+def init_compile_cache() -> None:
+    """Point JAX's persistent compilation cache at a path that never moves.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set the operator chose the place
+    and JAX reads it itself: nothing is touched. Otherwise the cache lives
+    at ``<checkout>/.jax_cache`` — the path is part of every entry's key, so
+    it carries no temp name, pid or time. Every entry point that compiles
+    (train, serve, eval, finetune) calls this once, next to
+    ``init_dist_env``.
+    """
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    jax.config.update("jax_compilation_cache_dir",
+                      os.path.join(root, ".jax_cache"))
+
+
+def mosaic_kernels(hlo_text: str) -> dict[str, int]:
+    """Mosaic (Pallas TPU) kernel launches in a lowered program's text, by
+    the ``name=`` each ``pallas_call`` carries (``ops/``)."""
+    return dict(sorted(collections.Counter(
+        re.findall(r'kernel_name = "([^"]+)"', hlo_text)).items()))
+
+
+def log_compile(what: str, jitted, *args) -> None:
+    """Compile ``jitted`` for ``args`` ahead of its first call and log the
+    seconds it took and the Mosaic kernels in it — one line a reader of
+    the log (``chip_smoke.py``) can parse. The call that follows reuses
+    the executable, so nothing compiles twice."""
+    t0 = time.time()
+    lowered = jitted.lower(*args)
+    kernels = mosaic_kernels(lowered.as_text())
+    lowered.compile()
+    logger.info("compiled %s in %.1fs; Mosaic kernels: %s", what,
+                time.time() - t0, json.dumps(kernels))
 
 
 def set_seed(seed: int) -> jax.Array:

@@ -139,25 +139,7 @@ print(f"traced step: params={n_params/1e9:.1f}B fwd+bwd ok")
 """
 
 
-# recipes whose traced step reaches a jax.shard_map call (ring attention's
-# seq ring, the pipeline stage schedule via the flash kernel's partial-manual
-# wrapper) — promoted to the public namespace after this build's 0.4.x line
-_NEEDS_SHARD_MAP = ("175B_mp8_pp16", "1.3B_seq8k_ring")
-
-
-def _has_shard_map() -> bool:
-    import jax
-
-    return hasattr(jax, "shard_map")
-
-
-@pytest.mark.parametrize(
-    "recipe",
-    [pytest.param(r, marks=pytest.mark.skipif(
-        r in _NEEDS_SHARD_MAP and not _has_shard_map(),
-        reason="this jax build lacks jax.shard_map (ring/pipeline paths)"))
-     for r in sorted(RECIPES)],
-    ids=sorted(RECIPES))
+@pytest.mark.parametrize("recipe", sorted(RECIPES))
 def test_flagship_recipe_traces(recipe):
     yaml_path, n_devices, batch, (lo, hi), degrees = RECIPES[recipe]
     env = dict(os.environ)

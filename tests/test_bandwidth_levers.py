@@ -5,8 +5,8 @@ The levers target the round-5 trace decomposition (BENCHMARKS.md): the
 backward layer scan pays ~1.8 ms/layer of dynamic-update-slice HBM traffic
 moving scan-stacked remat residuals. These tests pin the *semantics* on the
 CPU mesh — loss parity within tolerance, residual dtypes, config plumbing,
-and prefetch ordering/sharding/shutdown — so the on-chip A/B captures
-(tools/tpu_watch.py ``gpt_unroll`` / ``gpt_bf16res``) only have to measure.
+and prefetch ordering/sharding/shutdown — so the on-chip A/B runs
+(ROADMAP S2) only have to measure.
 """
 
 import threading

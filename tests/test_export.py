@@ -15,14 +15,6 @@ from fleetx_tpu.core.module import GPTGenerationModule, GPTModule
 from fleetx_tpu.models.gpt import generation as G
 from fleetx_tpu.utils.export import export_model, load_exported
 
-# the exporter serializes through the jax.export module, promoted to the
-# public namespace after this build's 0.4.x line — feature-detect so the
-# timeout-bound tier-1 window records skips, not known-red failures
-pytestmark = pytest.mark.skipif(
-    not hasattr(jax, "export"),
-    reason="this jax build lacks jax.export (utils/export.py serializes "
-           "through it)")
-
 CFG = {
     "Model": dict(vocab_size=128, hidden_size=32, num_layers=2,
                   num_attention_heads=4, max_position_embeddings=32,

@@ -1,7 +1,8 @@
 """Mesh-aware flash attention: per-device kernel execution under dp/tp.
 
 The Pallas kernel is a custom call GSPMD cannot partition;
-``flash_attention_sharded`` runs it inside a partial-manual shard_map.
+``flash_attention_sharded`` runs it inside a shard_map manual over every
+mesh axis.
 Interpret mode makes this testable on the CPU mesh.
 """
 
@@ -13,17 +14,6 @@ import pytest
 from fleetx_tpu.ops import flash_attention as fa
 from fleetx_tpu.parallel.mesh import build_mesh
 
-pytestmark = pytest.mark.skipif(fa.pltpu is None,
-                                reason="pallas tpu module unavailable")
-
-# the sharded wrapper builds a partial-manual jax.shard_map, promoted to
-# the public namespace after this build's 0.4.x line; the fallback and
-# mesh-gating tests below don't reach it and keep running
-_requires_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="this jax build lacks jax.shard_map (flash_attention_sharded's "
-           "partial-manual partition needs it)")
-
 
 def _qkv(b=4, s=256, n=4, d=64, seed=0):
     rng = np.random.RandomState(seed)
@@ -31,7 +21,6 @@ def _qkv(b=4, s=256, n=4, d=64, seed=0):
     return mk(), mk(), mk()
 
 
-@_requires_shard_map
 def test_sharded_matches_reference_dp_tp(devices8):
     q, k, v = _qkv()
     assert fa.supported(q, k)
@@ -47,7 +36,6 @@ def test_sharded_matches_reference_dp_tp(devices8):
                                rtol=2e-3, atol=2e-3)
 
 
-@_requires_shard_map
 def test_sharded_gradients_match(devices8):
     q, k, v = _qkv(b=2, s=256, n=2, d=64, seed=1)
 

@@ -73,10 +73,10 @@ def test_flash_ring_matches_reference(devices8, ring):
     q = jnp.asarray(rng.randn(b, s, n, d), jnp.float32)
     k = jnp.asarray(rng.randn(b, s, n, d), jnp.float32)
     v = jnp.asarray(rng.randn(b, s, n, d), jnp.float32)
-    assert flash_ring_supported(q, ring)
     want = fa.reference_attention(q, k, v, causal=True)
 
     mesh = build_mesh({"seq_degree": ring}, devices=devices8[:ring])
+    assert flash_ring_supported(q, mesh)
     with mesh:
         got = jax.jit(lambda q, k, v: ring_attention(
             q, k, v, causal=True, use_flash=True))(q, k, v)

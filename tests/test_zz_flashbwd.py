@@ -104,6 +104,11 @@ def test_fused_predicate_rejects_unsupported_shapes():
     long = jnp.zeros((1, 16384, 1, 128))
     assert FA.supported(long, long)
     assert not FA.fused_backward_supported(long, long)
+    # a 64-wide head pads to 128 lanes in VMEM: seq 15360 is 4 MiB by
+    # element count, but the v5e Mosaic compile refuses it (17.25 of 16 MiB)
+    padded = jnp.zeros((1, 15360, 1, 64))
+    assert FA.supported(padded, padded)
+    assert not FA.fused_backward_supported(padded, padded)
     # an explicit non-tiling block override refuses like supported()
     assert not FA.fused_backward_supported(ok, ok, block_q=96)
 
@@ -443,38 +448,6 @@ def test_decomposition_reports_one_fused_backward_pass():
 
     assert perf.summary(fused)["bwd_flash_passes_per_layer"] == 1.0
     assert perf.summary(split)["bwd_flash_passes_per_layer"] == 3.0
-
-
-def test_traced_sweep_promotes_fused_gate_rows(monkeypatch):
-    """The gpt_fusedbwd capture's traced re-run must land
-    flash_bwd_passes / perf_bwd_ms_per_layer at the ENTRY's top level —
-    tools/perf_gate.py resolves metrics by top-level dotted path in the
-    baseline entry, so values left only under 'traced' would make the
-    exact-match row skip forever (review finding)."""
-    import tools.tpu_watch as tw
-
-    def fake_bench_sweep(state, key, variants, script="bench.py"):
-        state[key] = {"value": 100.0, "batch_size": 8,
-                      "_env": dict(variants[0][1])}
-
-    def fake_run_child(name, argv, env, timeout=1200.0):
-        return {"value": 99.0, "device_kind": "TPU v5 lite",
-                "decomposition": {"bwd_flash_passes_per_layer": 1.0},
-                "flash_bwd_passes": 1.0, "perf_bwd_ms_per_layer": 4.9,
-                "flash_fused_bwd": True, "hbm_stats": "ok"}, None
-
-    monkeypatch.setattr(tw, "_bench_sweep", fake_bench_sweep)
-    monkeypatch.setattr(tw, "run_child", fake_run_child)
-    state = {}
-    tw._traced_sweep(state, "gpt_fusedbwd_testonly",
-                     [("", {"FLEETX_BENCH_FUSED_BWD": "1"}, {})])
-    res = state["gpt_fusedbwd_testonly"]
-    assert res["value"] == 100.0                     # headline stays untraced
-    assert res["flash_bwd_passes"] == 1.0            # promoted for the gate
-    assert res["perf_bwd_ms_per_layer"] == 4.9
-    assert res["traced"]["flash_bwd_passes"] == 1.0  # and in the audit view
-    assert res["traced"]["flash_fused_bwd"] is True
-    assert "_trace_dir" not in res                   # finalize cleaned up
 
 
 def test_perf_gate_exact_matches_pass_count(tmp_path):

@@ -11,8 +11,7 @@ fallback-predicate units, the model-level dispatch/fallback jaxpr pins
 overlap jaxpr position pin (the param allgather lands BEFORE the first
 matmul of the step), fit-loop loss parity with every lever on, the
 memory-model overlap term, config round-trips, and the mechanized
-evidence chain through observability/perf.py, tools/tpu_watch.py and
-tools/perf_gate.py.
+evidence chain through observability/perf.py and tools/perf_gate.py.
 
 zz-sorted per the tier-1 convention so the timeout-bound gate keeps its
 seed dots.
@@ -590,37 +589,6 @@ def test_decomposition_moves_elementwise_to_fused_norm():
 
     assert perf.summary(reports[True])["norm_fused"] == 1
     assert perf.summary(reports[False])["norm_fused"] == 0
-
-
-def test_traced_sweep_promotes_norm_and_overlap_rows(monkeypatch):
-    """The gpt_fusednorm / gpt_overlap_update captures' traced re-run
-    must land norm_fused / update_overlapped / perf_elementwise_ms at
-    the ENTRY's top level — tools/perf_gate.py resolves metrics by
-    top-level dotted path, so values left only under 'traced' would make
-    the exact-match rows skip forever."""
-    import tools.tpu_watch as tw
-
-    def fake_bench_sweep(state, key, variants, script="bench.py"):
-        state[key] = {"value": 100.0, "batch_size": 8,
-                      "_env": dict(variants[0][1])}
-
-    def fake_run_child(name, argv, env, timeout=1200.0):
-        return {"value": 99.0, "device_kind": "TPU v5 lite",
-                "norm_fused": 1, "update_overlapped": 1,
-                "perf_elementwise_ms": 3.2, "hbm_stats": "ok"}, None
-
-    monkeypatch.setattr(tw, "_bench_sweep", fake_bench_sweep)
-    monkeypatch.setattr(tw, "run_child", fake_run_child)
-    state = {}
-    tw._traced_sweep(state, "gpt_fusednorm_testonly",
-                     [("", {"FLEETX_BENCH_FUSED_NORM": "1"}, {})])
-    res = state["gpt_fusednorm_testonly"]
-    assert res["value"] == 100.0                # headline stays untraced
-    assert res["norm_fused"] == 1               # promoted for the gate
-    assert res["update_overlapped"] == 1
-    assert res["perf_elementwise_ms"] == 3.2
-    assert res["traced"]["norm_fused"] == 1     # and in the audit view
-    assert "_trace_dir" not in res              # finalize cleaned up
 
 
 def test_perf_gate_rows_for_norm_and_overlap():

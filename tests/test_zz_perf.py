@@ -592,92 +592,6 @@ def test_perf_sink_is_rank_suffixed(tmp_path, monkeypatch):
     assert not os.path.exists(tmp_path / "perf.jsonl")
 
 
-def test_tpu_watch_traced_sweep_keeps_timing_untraced(tmp_path,
-                                                      monkeypatch):
-    """The A/B stance: timing children run WITHOUT the profiler armed
-    (its ~1% must not land on one side of the delta); the winner re-runs
-    once traced and its decomposition attaches under 'traced'."""
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import tpu_watch
-    finally:
-        sys.path.pop(0)
-    art = tmp_path / "bench_artifacts"
-    art.mkdir()
-    monkeypatch.setattr(tpu_watch, "ART", str(art))
-    monkeypatch.setattr(tpu_watch, "LOG", str(art / "watch.log"))
-    calls = []
-
-    def fake_run_child(name, argv, env_extra, timeout=1200.0):
-        calls.append((name, dict(env_extra)))
-        res = {"value": 100.0, "device_kind": "TPU v5 lite",
-               "batch_size": 8}
-        trace_dir = env_extra.get("FLEETX_BENCH_TRACE")
-        if trace_dir:
-            dump = os.path.join(trace_dir, "plugins", "profile", "x")
-            os.makedirs(dump)
-            with open(FIXTURE, "rb") as f:
-                open(os.path.join(dump, "vm.trace.json.gz"),
-                     "wb").write(f.read())
-            res["decomposition"] = {"step_ms": 251.2}
-        return res, None
-
-    monkeypatch.setattr(tpu_watch, "run_child", fake_run_child)
-    state = {}
-    tpu_watch._traced_sweep(
-        state, "gpt_policyfix",
-        [("", {"FLEETX_BENCH_RECOMPUTE": "dots"}, {})])
-    timing = [c for c in calls if c[0] == "gpt_policyfix"]
-    traced = [c for c in calls if c[0] == "gpt_policyfix_trace"]
-    assert len(timing) == 1 and len(traced) == 1
-    assert "FLEETX_BENCH_TRACE" not in timing[0][1]
-    assert "FLEETX_BENCH_TRACE" in traced[0][1]
-    res = state["gpt_policyfix"]
-    assert "_env" not in res and "_trace_dir" not in res
-    assert res["traced"]["decomposition"] == {"step_ms": 251.2}
-    assert res["trace"] == "bench_artifacts/trace_gpt_policyfix.tar.gz"
-    assert res["trace_report"] == \
-        "bench_artifacts/trace_gpt_policyfix.report.json"
-    assert not (art / "trace_gpt_policyfix").exists()
-
-
-def test_tpu_watch_finalize_trace(tmp_path, monkeypatch):
-    """The watcher satellite: a capture's raw profiler dump is tarred,
-    trace_report --json runs offline on it, and the raw dirs are removed
-    so commit_artifacts never stages loose xplane files."""
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import tpu_watch
-    finally:
-        sys.path.pop(0)
-    art = tmp_path / "bench_artifacts"
-    art.mkdir()
-    monkeypatch.setattr(tpu_watch, "ART", str(art))
-    monkeypatch.setattr(tpu_watch, "LOG", str(art / "watch.log"))
-    dump = art / "trace_gpt_policyfix" / "plugins" / "profile" / "x"
-    dump.mkdir(parents=True)
-    (dump / "vm.trace.json.gz").write_bytes(open(FIXTURE, "rb").read())
-    loser = art / "trace_gpt_policyfix_2"
-    loser.mkdir()
-    state = {"gpt_policyfix": {
-        "value": 1.0, "batch_size": 8,
-        "_trace_dir": str(art / "trace_gpt_policyfix")}}
-    tpu_watch._finalize_trace(state, "gpt_policyfix")
-    res = state["gpt_policyfix"]
-    assert "_trace_dir" not in res
-    assert res["trace"] == "bench_artifacts/trace_gpt_policyfix.tar.gz"
-    assert res["trace_report"] == \
-        "bench_artifacts/trace_gpt_policyfix.report.json"
-    rep = json.loads((art / "trace_gpt_policyfix.report.json").read_text())
-    assert rep["phases"]["bwd_scan"]["layers"] == 24
-    assert not (art / "trace_gpt_policyfix").exists()  # raw dirs removed
-    assert not loser.exists()
-    # a capture with no dump (failed child) is a clean no-op
-    state2 = {"gpt_unroll": {"value": 2.0}}
-    tpu_watch._finalize_trace(state2, "gpt_unroll")
-    assert state2["gpt_unroll"] == {"value": 2.0}
-
-
 def test_perf_summary_shape():
     rep = perf.analyze(FIXTURE, flops_per_step=_FLOPS_PER_STEP,
                        roofline=roofline("TPU v5 lite"))

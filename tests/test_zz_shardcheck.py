@@ -174,17 +174,18 @@ def test_audit_names_unmatched_leaf():
     assert "lora_a" in issues[0]["message"]
 
 
-def test_with_fsdp_axis_modes():
-    # grad mode: keep existing, add fsdp on first free divisible dim
+def test_with_fsdp_axis():
+    # moments and grads alike: keep existing, add fsdp on the first free
+    # divisible dim (a moment frozen at its param's tensor spec stayed
+    # whole on every fsdp shard: 14.5 GiB a chip for fsdp4 stage 2 at
+    # GPT-345M, above the one-chip 14.2 — PERF.md)
     assert R.with_fsdp_axis((8, 3), (), 4) == ("fsdp",)
     assert R.with_fsdp_axis((3, 8), (None, "tensor"), 4) == (None, "tensor")
     assert R.with_fsdp_axis((8, 8), (None, "tensor"), 4) == \
         ("fsdp", "tensor")
-    # optimizer mode: any existing axis freezes the spec
-    assert R.with_fsdp_axis((8, 8), (None, "tensor"), 4,
-                            only_if_replicated=True) == (None, "tensor")
-    assert R.with_fsdp_axis((8, 3), (), 4, only_if_replicated=True) == \
-        ("fsdp",)
+    # the param's own spec already uses the axis (stage 3): unchanged
+    assert R.with_fsdp_axis((8, 8), ("fsdp", "tensor"), 4) == \
+        ("fsdp", "tensor")
     # nothing divisible / degree 1 → canonical replicated
     assert R.with_fsdp_axis((3, 5), (), 4) == ()
     assert R.with_fsdp_axis((8, 8), (), 1) == ()

@@ -6,8 +6,7 @@ imagen_397M_text2im_64x64.yaml``). Trains the base stage on synthetic
 NHWC images + T5-width text embeds, same harness shape as
 ``tools/bench_vit.py``.
 
-Prints exactly ONE JSON line. Run as a fresh subprocess by
-``tools/tpu_watch.py`` (probe-gated) or by hand:
+Prints exactly ONE JSON line. Run as a fresh process:
 
     python tools/bench_imagen.py                  # 397M base64, bs from env
     FLEETX_IMAGEN_BS=32 python tools/bench_imagen.py

@@ -5,8 +5,7 @@ card (``/root/reference/ppfleetx/configs/vis/vit/
 ViT_base_patch16_224_pt_in1k_2n16c_dp_fp16o2.yaml:84-88``). VERDICT r4 asks
 for ViT-L/16 (fall back to ViT-B if HBM-bound) bf16 images/sec + MFU.
 
-Prints exactly ONE JSON line. Designed to be run as a fresh subprocess by
-``tools/tpu_watch.py`` (which gates on a backend liveness probe) or by hand:
+Prints exactly ONE JSON line. Run as a fresh process:
 
     python tools/bench_vit.py                      # ViT-L/16, bs from env
     FLEETX_VIT_NAME=ViT_base_patch16_224 python tools/bench_vit.py

@@ -62,6 +62,7 @@ def _offline_eval(cfg, module):
 def main():
     args = config_mod.parse_args("fleetx_tpu eval")
     env_mod.init_dist_env()
+    env_mod.init_compile_cache()
     cfg = config_mod.get_config(args.config, args.override, show=True)
 
     mesh = set_mesh(build_mesh(cfg.get("Distributed")))

@@ -49,6 +49,7 @@ def main() -> int:
     """CLI entry: config → engine → the end-to-end fine-tune recipe."""
     args = config_mod.parse_args("fleetx_tpu lora finetune")
     env_mod.init_dist_env()
+    env_mod.init_compile_cache()
     cfg = config_mod.get_config(args.config, args.override, show=True)
 
     mesh = set_mesh(build_mesh(cfg.get("Distributed")))

@@ -24,9 +24,8 @@ PASS, a synthetic 10% tokens/s regression must FAIL — so the gate itself
 is regression-tested on every run. Exit codes follow ``tools/lint.py``:
 0 clean, 1 regression (or self-check failure), 2 usage error.
 
-Updating baselines: commit a new capture via ``tools/tpu_watch.py``
-(which rewrites ``BENCH_SELF.json``) — never hand-edit a number to make
-the gate pass (docs/performance.md "Gate thresholds").
+Updating baselines: a baseline is a chip run — never hand-edit a number
+to make the gate pass (docs/performance.md "Gate thresholds").
 """
 
 import argparse

@@ -55,15 +55,6 @@ def _build_engine(cfg: dict):
         model_dict["qat_act_bits"] = int(quant["activation_bits"])
     model_cfg = config_from_dict(model_dict)
     serving = ServingConfig.from_dict(dict(cfg.get("Serving") or {}))
-    # A/B env knobs for tools/tpu_watch.py's gpt_paged_kernel capture:
-    # flip ONE engine-construction choice per child process without
-    # forking the YAML recipe (the FLEETX_BENCH_TRACE convention)
-    for env_key, field in (("FLEETX_BENCH_PAGED_KERNEL", "paged_kernel"),
-                           ("FLEETX_BENCH_LAZY_ALLOC", "lazy_alloc")):
-        val = os.environ.get(env_key)
-        if val is not None and val != "":
-            setattr(serving, field, val not in ("0", "false", "False"))
-
     gen = dict(cfg.get("Generation") or {})
     strategy = gen.get("decode_strategy") or "greedy_search"
     sampling = SamplingParams(
@@ -251,6 +242,11 @@ def main(argv=None) -> int:
     cfg = config_mod.parse_config(args.config)
     config_mod.override_config(cfg, args.override)
     config_mod.process_serving_config(cfg)
+    from fleetx_tpu.utils import env as env_mod
+    from fleetx_tpu.utils.check import check_config
+
+    env_mod.init_compile_cache()
+    check_config(cfg)
     if args.bench:
         return _run_bench(args, cfg)
     return _run_replica(args, cfg)

@@ -75,6 +75,27 @@ PREEMPTION_EXIT_CODE = 75
 PREFLIGHT_EXIT_CODE = 41
 
 
+def _chip_env(env: dict, member: int, num_procs: int) -> dict:
+    """The environment of member ``member`` of ``num_procs`` JAX children
+    started side by side on this host, each pinned to its own chip.
+
+    A chip belongs to one process: of N children that each reach for every
+    chip of the host, the second dies on libtpu's lockfile (four-chip v5e
+    host, PR 21). The TPU runtime's visible-chips variables give member
+    *i* chip *i* as a one-chip topology — two elastic serving replicas ran
+    so, each on its own chip; a ``jax.distributed`` training gang of
+    pinned one-chip processes on one host has not run on the chip (one
+    process drives every chip of a host through ``Distributed.*_degree``).
+    An operator who set ``TPU_VISIBLE_CHIPS`` already keeps their choice,
+    a lone child (``num_procs`` 1) keeps every chip, and on the CPU
+    backend the variables mean nothing."""
+    if num_procs <= 1 or "TPU_VISIBLE_CHIPS" in env:
+        return env
+    return dict(env, TPU_VISIBLE_CHIPS=str(member),
+                TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1",
+                TPU_PROCESS_BOUNDS="1,1,1")
+
+
 def _free_port() -> int:
     """An OS-assigned free TCP port for the gang's local coordinator."""
     with socket.socket() as s:
@@ -111,7 +132,7 @@ class Gang:
             env["FLEETX_NUM_PROCESSES"] = str(self.num_procs)
         self.procs = []
         for rank in range(self.num_procs):
-            child_env = dict(env)
+            child_env = _chip_env(dict(env), rank, self.num_procs)
             if self.num_procs > 1:
                 child_env["FLEETX_PROCESS_ID"] = str(rank)
             if self.flight_base:
@@ -209,9 +230,11 @@ class Member:
     """One elastic serving replica slot — launched, restarted and
     drained INDIVIDUALLY (never gang-killed with its siblings)."""
 
-    def __init__(self, cmd: list, rank: int, flight_base: str | None):
+    def __init__(self, cmd: list, rank: int, num_procs: int,
+                 flight_base: str | None):
         self.cmd = list(cmd)
         self.rank = int(rank)
+        self.num_procs = int(num_procs)  # side-by-side slots on this host
         self.flight_base = flight_base
         self.generation = -1
         self.proc: subprocess.Popen | None = None
@@ -224,7 +247,8 @@ class Member:
         its port offset (tools/serve.py) — NOT a jax gang id: elastic
         members never get a coordinator address."""
         self.generation += 1
-        env = dict(os.environ, FLEETX_PROCESS_ID=str(self.rank))
+        env = _chip_env(dict(os.environ, FLEETX_PROCESS_ID=str(self.rank)),
+                        self.rank, self.num_procs)
         if self.flight_base:
             env["FLEETX_FLIGHT_DIR"] = os.path.join(
                 self.flight_base, f"member{self.rank}",
@@ -524,7 +548,7 @@ def main(argv=None) -> int:
     if args.elastic:
         assert 1 <= args.min_healthy <= args.num_procs, \
             "--min-healthy must be within [1, --num-procs]"
-        members = [Member(cmd, rank, flight_base)
+        members = [Member(cmd, rank, args.num_procs, flight_base)
                    for rank in range(args.num_procs)]
         forwarded = {"sig": None}
 

@@ -2,7 +2,7 @@
 
 Usage::
 
-    python tools/train.py -c fleetx_tpu/configs/gpt/pretrain_gpt_345M_single_card.yaml \
+    python tools/train.py -c fleetx_tpu/configs/nlp/gpt/pretrain_gpt_345M_single_card.yaml \
         -o Engine.max_steps=100 -o Model.hidden_size=512
 
 The reference bootstraps NCCL groups via ``fleet.init``; here process
@@ -10,6 +10,7 @@ bootstrap is ``jax.distributed.initialize`` (multi-host) or nothing (single
 host), and the mesh is built from the ``Distributed`` config section.
 """
 
+import json
 import sys
 import os
 
@@ -21,6 +22,7 @@ from fleetx_tpu.core.checkpoint import peek_meta
 from fleetx_tpu.core.engine import EagerEngine
 from fleetx_tpu.data import build_dataloader
 from fleetx_tpu.models import build_module
+from fleetx_tpu.observability.memory import device_placement
 from fleetx_tpu.optims import build_lr_scheduler, build_optimizer
 from fleetx_tpu.parallel.mesh import build_mesh, set_mesh
 from fleetx_tpu.utils import config as config_mod
@@ -31,6 +33,7 @@ from fleetx_tpu.utils.log import logger
 def main(auto_layout: bool = False):
     args = config_mod.parse_args("fleetx_tpu train")
     env_mod.init_dist_env()
+    env_mod.init_compile_cache()
     cfg = config_mod.get_config(args.config, args.override, show=True,
                                 auto_layout=auto_layout)
 
@@ -77,6 +80,8 @@ def main(auto_layout: bool = False):
     engine._consumed_samples = consumed
     engine.fit(train_dl, valid_dl,
                epoch_num=int(cfg.get("Engine", {}).get("num_train_epochs", 1)))
+    logger.info("placement: %s", json.dumps(device_placement(
+        {"params": engine.state.params, "opt_state": engine.state.opt_state})))
     if engine.save_steps:
         engine.save()
 
