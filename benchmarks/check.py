@@ -1,0 +1,142 @@
+"""The comparison that decides ``correct``.
+
+What the timed path produced (the program's losses, gradient norms and
+parameter changes over its first steps; the tokens the window served) is
+held against the plain reference, each number with a limit of its own that
+the configuration's file states. Every number compared is printed beside
+its limit.
+"""
+
+from __future__ import annotations
+
+import statistics
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+
+def without_gradient_free(ref, name: str, x):
+    """``x`` with the slice zeroed that the architecture gives no gradient
+    (``GRADIENT_FREE`` of the reference)."""
+    where = getattr(ref, "GRADIENT_FREE", {}).get(name)
+    if where is None:
+        return x
+    idx = [slice(None)] * x.ndim
+    idx[where[0]] = where[1]
+    return x.at[tuple(idx)].set(0)
+
+
+def traced_leaf_norms(ref, tree: dict) -> dict:
+    return {k: jnp.sqrt(jnp.sum(jnp.square(without_gradient_free(
+        ref, k, v.astype(jnp.float32))))) for k, v in tree.items()}
+
+
+def leaf_norms(ref, tree: dict) -> dict:
+    """The norm of every leaf, in one device program."""
+    got = jax.jit(lambda t: traced_leaf_norms(ref, t))(tree)
+    return {k: float(v) for k, v in jax.device_get(got).items()}
+
+
+def train_reference(ref, adam, sizes: dict, opt: dict, weights: dict,
+                    batches: list, precision: str = "float32",
+                    rows_per_block: int = 1) -> dict:
+    """Follow the first ``len(batches)`` steps: each step's loss, the norm
+    of the first gradient as the optimizer gets it, and the norm of the
+    parameters' change after the last step, leaf by leaf."""
+    kinds = {k: kind for k, (_, kind) in ref.weight_spec(sizes).items()}
+    w, state = dict(weights), adam.init(weights)
+    losses, first_grad = [], None
+    for i, batch in enumerate(batches, start=1):
+        loss, grads = ref.loss_and_grads(w, sizes, batch, precision,
+                                         rows_per_block)
+        w, state, clipped = adam.step(w, state, grads, opt, kinds, i)
+        losses.append(float(loss))
+        if first_grad is None:
+            first_grad = leaf_norms(ref, clipped)
+    delta = leaf_norms(ref, {k: w[k] - weights[k] for k in w})
+    return {"losses": losses, "grad_norms": first_grad, "delta_norms": delta}
+
+
+def worst_leaf_gap(got: dict, want: dict) -> float:
+    """The gap between the two norms of a leaf (not the norm of their
+    difference), against the reference's norm of that leaf or of the
+    median leaf, whichever is larger: some gradients are all but zero."""
+    floor = statistics.median(want.values())
+    return max(abs(got[k] - want[k]) / max(want[k], floor, 1e-30)
+               for k in want)
+
+
+def train_numbers(program: dict, reference: dict) -> dict:
+    """The three numbers of a train cell: the program (or a control) against
+    the reference."""
+    steps = min(len(program["losses"]), len(reference["losses"]))
+    return {
+        "loss_rel_gap": max(abs(p - r) / abs(r) for p, r in zip(
+            program["losses"][:steps], reference["losses"][:steps])),
+        "grad_norm_worst_leaf_gap": worst_leaf_gap(
+            program["grad_norms"], reference["grad_norms"]),
+        "delta_norm_worst_leaf_gap": worst_leaf_gap(
+            program["delta_norms"], reference["delta_norms"]),
+    }
+
+
+def judge(numbers: dict, limits: dict, out=sys.stderr) -> bool:
+    """Print each number beside its limit; all have to sit inside."""
+    ok = True
+    for name, value in numbers.items():
+        limit = float(limits[name])
+        good = bool(np.isfinite(value)) and value <= limit
+        ok = ok and good
+        print(f"check: {name} = {value:.6g}  limit {limit:.6g}  "
+              f"{'ok' if good else 'NOT CORRECT'}", file=out)
+    return ok
+
+
+# ------------------------------------------------------------------ serving
+def sample_served(finished: list, seed: int, n: int) -> list:
+    """A seeded sample of the finished requests with the longest in it;
+    each item is ``(prompt, served_tokens)``."""
+    if not finished:
+        return []
+    order = sorted(range(len(finished)),
+                   key=lambda i: -(len(finished[i][0]) + len(finished[i][1])))
+    rng = np.random.default_rng(seed % (2 ** 32))
+    rest = [int(i) for i in rng.permutation(order[1:])[:max(n - 1, 0)]]
+    return [finished[i] for i in [order[0]] + rest]
+
+
+def served_logit_gaps(ref, sizes: dict, weights: dict, samples: list,
+                      pad_to: int, chooser: str | None = None) -> dict:
+    """Run the reference once over each prompt with its served tokens.
+
+    ``widest_gap``: the widest gap by which a served token's logit lies
+    below the reference's best at its position (valid for greedy tokens).
+    With ``chooser`` (a lower precision) the tokens judged are not the
+    served ones but those that precision puts first, at each position of
+    the same prompts and tokens — the control.
+    """
+    rows = np.zeros((len(samples), pad_to), np.int32)
+    spans = []
+    for i, (prompt, served) in enumerate(samples):
+        seq = list(prompt) + list(served)
+        rows[i, :len(seq)] = seq
+        spans.append((len(prompt), len(served)))
+    tokens = jnp.asarray(rows)
+    fwd = jax.jit(lambda w, t, p: ref.logits(w, sizes, t, p),
+                  static_argnums=2)
+    lg = fwd(weights, tokens, "float32")
+    best = lg.max(-1)
+    if chooser is None:
+        judged = jnp.roll(tokens, -1, axis=1)   # position p predicts p + 1
+    else:
+        judged = jnp.argmax(fwd(weights, tokens, chooser), axis=-1)
+    gap = np.asarray(best - jnp.take_along_axis(
+        lg, judged[..., None], axis=-1)[..., 0])
+    widest, n_tokens = 0.0, 0
+    for i, (plen, nserved) in enumerate(spans):
+        first = plen - 1 if chooser is None else 0
+        g = gap[i, first:plen - 1 + nserved]
+        widest, n_tokens = max(widest, float(g.max())), n_tokens + nserved
+    return {"widest_gap": widest, "tokens_compared": n_tokens}
