@@ -1185,6 +1185,26 @@ class EagerEngine(BasicEngine):
                 self.coord.barrier("rollback_exit")
                 return wrap_stream(bi), restored
 
+            # ``fit.log`` runs from the step's metrics on the host to the
+            # next ``data_fetch`` (or the loop's end), the loop's control
+            # code ahead of the fetch included: it is opened where the
+            # metrics arrive and closed here, not by a ``with`` block,
+            # because the stretch crosses the loop's iteration boundary.
+            # ``cleanup`` closes it on every other way out of the loop
+            # (``preemption_exit``, ``TrainingAborted``, a failed eval or
+            # save), so the span is recorded and no annotation stays open
+            log_span: list = []
+
+            def open_log_span():
+                log_span.append(self.obs.span("fit.log"))
+                log_span[-1].__enter__()
+
+            def close_log_span():
+                if log_span:
+                    log_span.pop().__exit__(None, None, None)
+
+            cleanup.callback(close_log_span)
+
             def fetch_item():
                 """One batch from the active source (device prefetcher when
                 armed, else the host iterator) under the ``data_fetch``
@@ -1192,6 +1212,7 @@ class EagerEngine(BasicEngine):
                 enclosing ``prefetcher``/``batch_iter`` bindings so a
                 rollback's pipeline rebuild is picked up transparently."""
                 src = prefetcher if prefetcher is not None else batch_iter
+                close_log_span()
                 with self.obs.timed_span("data_fetch"):
                     return next(src, None)
 
@@ -1388,7 +1409,9 @@ class EagerEngine(BasicEngine):
                     # instead of per-key float() round-trips (lint:
                     # host-sync-in-traced-code's loop-side cousin).
                     # `metrics` stays a device pytree for the profiler sync.
-                    host_metrics = jax.device_get(metrics)
+                    with self.obs.span("fit.fetch_metrics"):
+                        host_metrics = jax.device_get(metrics)
+                    open_log_span()
                     # resync with the device step counter: under the fp16
                     # scaler (and the guard's in-step skip), non-finite
                     # steps don't advance state.step
@@ -1492,6 +1515,7 @@ class EagerEngine(BasicEngine):
                     # is exactly the restart-with-resume behaviour under test
                     logger.error("fault injection: dying at step %d", step)
                     os._exit(17)
+            close_log_span()
             self.profiler.stop(sync=metrics.get("loss")
                                if isinstance(metrics, dict) else None)
             ckpt_lib.finalize_async_saves()

@@ -34,7 +34,8 @@ from fleetx_tpu.observability.metrics import (  # noqa: F401
 from fleetx_tpu.observability.sinks import (  # noqa: F401
     CsvSink, JsonlSink, PrometheusTextfileSink, Sink, build_sinks)
 from fleetx_tpu.observability.trace import (  # noqa: F401
-    ProfilerWindow, Tracer, _process_index, get_tracer, set_tracer, span)
+    HOT_LOOP_SPANS, ProfilerWindow, Tracer, _process_index, get_tracer,
+    set_tracer, span)
 from fleetx_tpu.utils.log import logger
 
 __all__ = [
@@ -145,16 +146,18 @@ class Observability:
 
     # -- spans ---------------------------------------------------------------
     def span(self, name: str, **args: Any):
-        """A recorded span when enabled, else a zero-cost null context."""
-        if not self.enabled:
-            return contextlib.nullcontext()
-        return span(name, **args)
+        """The one ``span``. Disabled, it leaves no flight note (and this
+        facade installed no tracer), so what is left is the profiler
+        annotation — a flag check while no session is live: the loop's
+        phases reach a trace whether or not telemetry is configured."""
+        return span(name, flight_note=self.enabled, **args)
 
     def timed_span(self, name: str, **args: Any):
         """Span composed with ``registry.timer``: one region feeds the trace,
-        the ``name`` histogram and the ``<name>_seconds_total`` counter."""
+        the ``name`` histogram and the ``<name>_seconds_total`` counter.
+        Disabled, it is ``span`` alone: no timer."""
         if not self.enabled:
-            return contextlib.nullcontext()
+            return self.span(name, **args)
         stack = contextlib.ExitStack()
         stack.enter_context(span(name, **args))
         stack.enter_context(self.registry.timer(name))

@@ -22,6 +22,7 @@ multi-millisecond train step.
 
 from __future__ import annotations
 
+import itertools
 import math
 import threading
 import time
@@ -92,6 +93,13 @@ class Histogram:
         self._window.append(v)
         self.total_count += 1
         self.total_sum += v
+
+    def last(self, n: int) -> list:
+        """The newest ``n`` samples of the window, oldest first (fewer when
+        the window holds fewer)."""
+        tail = list(itertools.islice(reversed(self._window), max(int(n), 0)))
+        tail.reverse()
+        return tail
 
     def quantile(self, q: float) -> Optional[float]:
         """Linear-interpolated quantile over the current window."""

@@ -107,6 +107,9 @@ SERVING_RECORD_SCHEMA: dict[str, tuple[tuple, bool]] = {
     # — the router pools these count-weighted into the fleet record
     "ttft": ((dict,), False),
     "itl": ((dict,), False),
+    # a first token's wait in its three parts (queue_wait, prefill_wait,
+    # prefill_run), each {p50, p95, count} in seconds
+    "first_token_waits": ((dict,), False),
     # fleet-economics context (PR 16): chips this replica occupies and
     # completions per chip; slo_attainment is null until a window fills
     "chips": ((int,), False),
@@ -168,8 +171,14 @@ FLEET_RECORD_SCHEMA: dict[str, tuple[tuple, bool]] = {
 #: registry metric names the serving runtime owns (docs/observability.md):
 #: request-latency histograms + scheduler gauges, all in the PR 1 registry
 SERVING_METRIC_NAMES = (
-    "serving_ttft", "serving_inter_token", "serving_prefill_step",
-    "serving_decode_step", "serving_queue_depth", "serving_active_requests",
+    "serving_ttft", "serving_inter_token",
+    # wall time of a tick that ran device work, top of step() to the device
+    # drained, and the same of the ticks that carried a prefill chunk (what
+    # deadline admission prices a chunk at); a first token's wait in three
+    # parts that sum to serving_ttft
+    "serving_tick", "serving_chunk_tick", "serving_queue_wait",
+    "serving_prefill_wait", "serving_prefill_run",
+    "serving_queue_depth", "serving_active_requests",
     "serving_page_occupancy", "serving_kv_fragmentation",
     "serving_requests_total", "serving_requests_completed",
     "serving_requests_refused", "serving_tokens_total",

@@ -119,6 +119,50 @@ def get_tracer() -> Optional[Tracer]:
     return _active_tracer
 
 
+#: Every span the two host loops open — ``ServingEngine.step`` and the step
+#: loop of ``EagerEngine.fit`` — with what it covers and whether the host is
+#: *working* in it or *waiting* on the device. The engines open no span
+#: outside this table (tests hold them to it) and the benchmark's readers
+#: (``benchmarks/program_spans.py``) import it. Spans nest strictly, on the
+#: loop's own thread: ``serve.tick`` holds every other ``serve.*`` span and
+#: ``serve.prefill`` holds ``serve.prefill.wait``; the ``fit`` spans follow
+#: each other.
+HOT_LOOP_SPANS: dict = {
+    "serve.tick": ("working", "one ServingEngine.step(): every serve.* "
+                              "span below lies inside it"),
+    "serve.admit": ("working", "waiting requests take a slot and pages"),
+    "serve.prefill": ("working", "build one prompt chunk and dispatch the "
+                                 "prefill program (rid=, chunk=); on a last "
+                                 "chunk also the first token's book-keeping"),
+    "serve.prefill.wait": ("waiting", "device_get of a last chunk's token: "
+                                      "the device runs this chunk and what "
+                                      "was queued before it"),
+    "serve.schedule": ("working", "shed expired requests, grow block tables "
+                                  "or preempt, list the running rows"),
+    "serve.decode": ("working", "dispatch the decode program"),
+    "serve.decode.wait": ("waiting", "device_get of the decoded tokens: the "
+                                     "device runs this tick's prefill chunk "
+                                     "and decode step"),
+    "serve.emit": ("working", "per running row: length, inter-token "
+                              "sample, timeline note, finish or next token"),
+    "serve.gauges": ("working", "queue, slot, page and fragmentation gauges"),
+    "data_fetch": ("working", "next batch from the loader or the device "
+                              "prefetcher"),
+    "shard_batch": ("working", "host batch onto the mesh (device_put)"),
+    "train_step": ("working", "the call of the jitted train step: dispatch, "
+                              "not device time (step=)"),
+    "sdc_sentinel": ("waiting", "a sentinel round's replay of the step and "
+                                "its comparison (Resilience, off by default)"),
+    "fit.fetch_metrics": ("waiting", "device_get of the step's metrics: the "
+                                     "device finishes the step"),
+    "fit.log": ("working", "from the metrics on the host to the next "
+                           "data_fetch: training_step_end, the train record, "
+                           "guard, eval and save triggers, and the loop's "
+                           "control ahead of data_fetch (the gang's vote, "
+                           "its idle rounds)"),
+}
+
+
 class span:
     """``with span("train_step", step=3): ...`` or ``@span("load")``.
 
@@ -126,19 +170,29 @@ class span:
     region under ``jax.profiler.TraceAnnotation`` so host work is visible
     inside XLA profiler windows. Nesting falls out of the trace-event model:
     an inner span's ``[ts, ts+dur]`` lies within its parent's on the same
-    tid, which Perfetto renders as a nested slice.
+    tid, which Perfetto renders as a nested slice. ``args`` ride on all
+    three: the profiler annotation, the Chrome event, the flight note.
+
+    ``flight_note=False`` keeps the span out of the flight ring: the
+    serving tick's nine spans would push the serving events out of a
+    replica's 512-event ring, and a loop whose telemetry is off notes
+    nothing. With no tracer and no recorder installed a span is the bare
+    annotation (a flag check while no profiler session is live) and two
+    clock reads.
     """
 
-    __slots__ = ("name", "args", "_t0", "_ts", "_annotation")
+    __slots__ = ("name", "args", "flight_note", "_t0", "_ts", "_annotation")
 
-    def __init__(self, name: str, **args: Any):
+    def __init__(self, name: str, *, flight_note: bool = True, **args: Any):
         self.name = name
         self.args = args or None
+        self.flight_note = flight_note
 
     def __enter__(self):
         import jax
 
-        self._annotation = jax.profiler.TraceAnnotation(self.name)
+        self._annotation = jax.profiler.TraceAnnotation(
+            self.name, **(self.args or {}))
         self._annotation.__enter__()
         # wall-clock anchor captured at ENTRY (multi-process traces share
         # the epoch, and an outer span's ts always precedes its children's);
@@ -158,7 +212,7 @@ class span:
         # recorder is installed — one None check). Span args ride NESTED:
         # span() accepts arbitrary keywords, and a user arg named "kind"
         # or "t" must not collide with the event's own fields.
-        if flight.get_recorder() is not None:
+        if self.flight_note and flight.get_recorder() is not None:
             extra = {"args": self.args} if self.args else {}
             flight.note("span", self.name,
                         dur_ms=round(dur * 1000.0, 3), **extra)
@@ -167,7 +221,8 @@ class span:
     def __call__(self, fn):
         @functools.wraps(fn)
         def wrapper(*a, **kw):
-            with span(self.name, **(self.args or {})):
+            with span(self.name, flight_note=self.flight_note,
+                      **(self.args or {})):
                 return fn(*a, **kw)
         return wrapper
 

@@ -30,7 +30,14 @@ Telemetry rides the PR 1 metrics registry (``serving_ttft`` /
 ``serving_inter_token`` histograms; queue-depth / active-request /
 page-occupancy gauges), serving events land in the PR 8 flight ring, and
 ``serving_snapshot()`` emits the record shape
-``observability/schema.py:SERVING_RECORD_SCHEMA`` validates.
+``observability/schema.py:SERVING_RECORD_SCHEMA`` validates. Every tick
+and each of its phases is a profiler annotation
+(``observability/trace.py:HOT_LOOP_SPANS``, ``serve.*``) and a
+``time.monotonic`` reading kept for the last tick (``last_tick``); a first
+token's wait is recorded in three parts (``serving_queue_wait`` +
+``serving_prefill_wait`` + ``serving_prefill_run`` = ``serving_ttft``).
+The engine knows a tick's wall time (``serving_tick``), never a program's
+device time: that is the trace's to say.
 """
 
 from __future__ import annotations
@@ -54,6 +61,7 @@ from fleetx_tpu.observability import flight, tsan
 from fleetx_tpu.observability.flight import EventRing
 from fleetx_tpu.observability.metrics import get_registry
 from fleetx_tpu.observability.slo import SLORegistry
+from fleetx_tpu.observability.trace import span
 from fleetx_tpu.serving.decode import (SamplingParams, make_step_fns,
                                        paged_kernel_enabled)
 from fleetx_tpu.serving.paged_cache import (NULL_PAGE, PageAllocator,
@@ -144,6 +152,10 @@ class ServingRequest:
     tokens: list = dataclasses.field(default_factory=list)
     error: Optional[str] = None
     submitted_at: float = 0.0
+    # the two marks between submission and first token (monotonic, stamped
+    # once: a preempted request keeps its first admission and first chunk)
+    admitted_at: Optional[float] = None
+    prefill_started_at: Optional[float] = None
     first_token_at: Optional[float] = None
     last_token_at: Optional[float] = None
     finished_at: Optional[float] = None
@@ -172,13 +184,14 @@ class ServingRequest:
 #: landing while the request was live, ``page_grow`` stamps each lazy
 #: block-table extension, and ``preempted`` marks a pool-pressure swap-out
 #: (the request loops back to ``admitted`` afterwards)
-TIMELINE_EVENTS = ("queued", "admitted", "prefill_chunk", "first_token",
-                   "decode_tick", "page_grow", "preempted", "finished",
-                   "refused", "drain", "deadline_shed")
+TIMELINE_EVENTS = ("queued", "admitted", "prefill_started", "prefill_chunk",
+                   "first_token", "decode_tick", "page_grow", "preempted",
+                   "finished", "refused", "drain", "deadline_shed")
 
 #: milestone events whose first timestamp is pinned outside the ring so
 #: attribution survives decode-tick eviction on long generations
-_MILESTONES = ("queued", "admitted", "first_token", "finished", "refused")
+_MILESTONES = ("queued", "admitted", "prefill_started", "first_token",
+               "finished", "refused")
 
 
 class RequestTimeline:
@@ -222,8 +235,11 @@ class RequestTimeline:
         ``queue_s`` (queued→admitted) + ``prefill_s`` (admitted→first
         token) = ``ttft_s``, then ``decode_s`` (first token→finished) —
         the request-path analogue of ``perf.py``'s step-time
-        decomposition: TTFT regressions name their phase. Spans whose
-        endpoints haven't happened are None, never a fake zero.
+        decomposition: TTFT regressions name their phase. ``prefill_s``
+        splits at the dispatch of the request's own first chunk into
+        ``prefill_wait_s`` (behind other prompts' chunks) and
+        ``prefill_run_s`` (its own chunks). Spans whose endpoints haven't
+        happened are None, never a fake zero.
         """
         t = self._marks
 
@@ -236,6 +252,8 @@ class RequestTimeline:
         return {
             "queue_s": span("queued", "admitted"),
             "prefill_s": span("admitted", "first_token"),
+            "prefill_wait_s": span("admitted", "prefill_started"),
+            "prefill_run_s": span("prefill_started", "first_token"),
             "decode_s": span("first_token", "finished"),
             "ttft_s": span("queued", "first_token"),
             "total_s": total,
@@ -301,6 +319,40 @@ class TimelineStore:
                     if tl.state == "open"]
 
 
+#: a tick this many times the mean of the last ``SLOW_TICK_WINDOW`` working
+#: ticks (at least ``SLOW_TICK_MIN`` of them) logs its phases
+SLOW_TICK_FACTOR, SLOW_TICK_WINDOW, SLOW_TICK_MIN = 5.0, 32, 8
+
+
+def _tick_span(name: str, **args: Any) -> span:
+    """A span of the tick: annotation and Chrome event, never a flight note
+    (nine a tick would push the serving events out of a replica's ring)."""
+    return span(name, flight_note=False, **args)
+
+
+class _Phase:
+    """One phase of a tick: the ``serve.<key>`` profiler annotation, and the
+    engine's clock read on both boundaries into the tick's phase seconds."""
+
+    __slots__ = ("_engine", "_key", "_span", "_t0")
+
+    def __init__(self, engine: "ServingEngine", key: str, **args: Any):
+        self._engine, self._key = engine, key
+        self._span = _tick_span("serve." + key, **args)
+
+    def __enter__(self):
+        self._span.__enter__()
+        self._t0 = self._engine._clock()
+        return self
+
+    def __exit__(self, *exc):
+        eng = self._engine
+        eng._phase_end = now = eng._clock()
+        eng.last_tick[self._key] = \
+            eng.last_tick.get(self._key, 0.0) + now - self._t0
+        return self._span.__exit__(*exc)
+
+
 class ServingEngine:
     """Request-level decode runtime (see module docstring for the loop)."""
 
@@ -361,6 +413,12 @@ class ServingEngine:
         self.steps = 0
         self._started_at = time.monotonic()
         self.metrics = get_registry()
+        # the tick's own clock (a test may replace it) and what it last
+        # read: seconds by phase of the LAST tick only, ``tick`` the whole
+        self._clock = time.monotonic
+        self.last_tick: dict = {}
+        self._phase_end = 0.0
+        self._drained_at: Optional[float] = None
         # monotonic id mint: never reset (reset_stats() zeroing the
         # request counter used to recycle ids across bench windows,
         # silently merging two requests' timelines and router bookkeeping)
@@ -470,14 +528,21 @@ class ServingEngine:
         """``(service_s, eta_s)`` estimate for a fresh submission.
 
         ``service_s`` is the request's own cost — prefill chunks at the
-        measured mean ``serving_prefill_step`` plus ``max_new`` tokens at
-        the measured mean inter-token latency. ``eta_s`` adds the queue
+        measured mean ``serving_chunk_tick`` (a chunk costs its request one
+        tick, whatever else rides in that tick: one chunk is forwarded a
+        tick; and a tick that carries a chunk is longer than one that only
+        decodes, so the mean is taken over the chunk-carrying ticks alone)
+        plus ``max_new`` tokens at the measured mean inter-token latency.
+        The engine knows wall time per tick, not device time per program;
+        the old per-program timers stopped at a ``device_get`` that a
+        chunk other than a prompt's last never reaches, and priced a chunk
+        three to six times too low (PERF.md, PR 23). ``eta_s`` adds the queue
         ahead of it: every waiting/prefilling request's own service
         estimate, divided by the decode batch width (decode is batched,
         so queued work drains ``max_batch``-wide, not serially). Both are
-        None until the engine has measured at least one prefill chunk and
-        one decode tick — admission never refuses on guesswork."""
-        pf = self._measured_mean("serving_prefill_step")
+        None until the engine has measured at least one chunk-carrying tick
+        and one decode tick — admission never refuses on guesswork."""
+        pf = self._measured_mean("serving_chunk_tick")
         itl = self._measured_mean("serving_inter_token")
         if pf is None or itl is None:
             return None, None
@@ -537,6 +602,8 @@ class ServingEngine:
             req.state, req.slot, req.pages = PREFILL, slot, pages
             req.admit_seq = self._admit_seq
             self._admit_seq += 1
+            if req.admitted_at is None:
+                req.admitted_at = time.monotonic()
             self._slots[slot] = req
             self._block_tables[slot] = NULL_PAGE
             self._block_tables[slot, :need] = pages
@@ -567,25 +634,30 @@ class ServingEngine:
         req = self._prefilling[0]
         sc = self.serving
         pos = req.prefill_pos
-        chunk = req.prompt[pos:pos + sc.prefill_chunk]
-        n_valid = len(chunk)
-        tokens = np.zeros((1, sc.prefill_chunk), np.int32)
-        tokens[0, :n_valid] = chunk
-        table = self._block_tables[req.slot:req.slot + 1]
-        with self.metrics.timer("serving_prefill_step"):
+        index = pos // max(sc.prefill_chunk, 1)
+        with _Phase(self, "prefill", rid=req.id, chunk=index):
+            chunk = req.prompt[pos:pos + sc.prefill_chunk]
+            n_valid = len(chunk)
+            tokens = np.zeros((1, sc.prefill_chunk), np.int32)
+            tokens[0, :n_valid] = chunk
+            table = self._block_tables[req.slot:req.slot + 1]
+            if req.prefill_started_at is None:
+                req.prefill_started_at = time.monotonic()
+                self.timelines.note(req.id, "prefill_started")
             self.pool_k, self.pool_v, tok, _ = self._call(
                 "prefill", self.params, self.pool_k, self.pool_v, tokens,
                 table, np.int32(pos), np.int32(n_valid), self._next_rng())
             req.prefill_pos = pos + n_valid
-            self.timelines.note(req.id, "prefill_chunk",
-                                chunk=pos // max(sc.prefill_chunk, 1),
+            self.timelines.note(req.id, "prefill_chunk", chunk=index,
                                 tokens=n_valid)
             if req.prefill_pos >= len(req.prompt):
-                first = int(jax.device_get(tok)[0])
+                with _Phase(self, "prefill.wait"):
+                    first = int(jax.device_get(tok)[0])
+                self._drained_at = self._phase_end
                 self._prefilling.popleft()
                 now = time.monotonic()
                 req.first_token_at = req.last_token_at = now
-                self.metrics.histogram("serving_ttft").record(req.ttft_s)
+                self._record_first_token(req)
                 self.timelines.note(req.id, "first_token", token=first)
                 self._emit(req, first)
                 if req.state != FINISHED:
@@ -594,6 +666,20 @@ class ServingEngine:
                     self._last_tokens[req.slot] = first
                 flight.note("serving", "first_token", id=req.id)
         return True
+
+    def _record_first_token(self, req: ServingRequest) -> None:
+        """``serving_ttft`` and its three parts, which sum to it: the
+        admission queue, the FIFO of other prompts' chunks, the request's
+        own chunks. One sample each per first token (a preempted request
+        reaches a second one; its marks stay those of its first pass)."""
+        m = self.metrics
+        m.histogram("serving_ttft").record(req.ttft_s)
+        m.histogram("serving_queue_wait").record(
+            req.admitted_at - req.submitted_at)
+        m.histogram("serving_prefill_wait").record(
+            req.prefill_started_at - req.admitted_at)
+        m.histogram("serving_prefill_run").record(
+            req.first_token_at - req.prefill_started_at)
 
     def _grow_or_preempt(self) -> None:
         """Extend each RUNNING request's block table to cover the token
@@ -752,19 +838,23 @@ class ServingEngine:
 
     def _decode_step(self) -> bool:
         """One token for every RUNNING slot (static batch; masked rows)."""
-        self._shed_expired()
-        if self.serving.lazy_alloc:
-            self._grow_or_preempt()
-        running = [r for r in self._slots
-                   if r is not None and r.state == RUNNING]
+        with _Phase(self, "schedule"):
+            self._shed_expired()
+            if self.serving.lazy_alloc:
+                self._grow_or_preempt()
+            running = [r for r in self._slots
+                       if r is not None and r.state == RUNNING]
         if not running:
             return False
-        with self.metrics.timer("serving_decode_step"):
+        with _Phase(self, "decode"):
             self.pool_k, self.pool_v, toks, _ = self._call(
                 "decode", self.params, self.pool_k, self.pool_v,
                 self._last_tokens, self._block_tables, self._lens,
                 self._next_rng())
+        with _Phase(self, "decode.wait"):
             toks = jax.device_get(toks)
+        self._drained_at = self._phase_end
+        with _Phase(self, "emit"):
             now = time.monotonic()
             for req in running:
                 tok = int(toks[req.slot])
@@ -812,13 +902,50 @@ class ServingEngine:
     def step(self) -> bool:
         """One scheduler iteration; True when any device work ran."""
         tsan.note_access(self, "step")
-        self._admit()
-        worked = self._prefill_step()
-        worked = self._decode_step() or worked
-        if worked:
-            self.steps += 1
-        self._update_gauges()
+        self.last_tick = {}
+        self._drained_at = None
+        t_top = self._clock()
+        with _tick_span("serve.tick", tick=self.steps):
+            with _Phase(self, "admit"):
+                self._admit()
+            chunk = self._prefill_step()
+            worked = self._decode_step() or chunk
+            # the device is drained at the tick's last device_get; a tick
+            # that only dispatched (a prompt's earlier chunk, nothing
+            # decoding) ends where its dispatch did
+            work_end = self._phase_end if self._drained_at is None \
+                else self._drained_at
+            if worked:
+                self.steps += 1
+            with _Phase(self, "gauges"):
+                self._update_gauges()
+        self._close_tick(t_top, work_end, worked, chunk)
         return worked
+
+    def _close_tick(self, t_top: float, work_end: float, worked: bool,
+                    chunk: bool) -> None:
+        """Finish the last tick's phase seconds; for a tick that ran device
+        work record ``serving_tick`` (top of ``step`` to the device drained;
+        under ``serving_chunk_tick`` too when it carried a prefill chunk:
+        what ``projected_completion_s`` prices a chunk at) and, first, say
+        so if it took many times the recent ones."""
+        phases = self.last_tick
+        if "prefill.wait" in phases:      # nested: keep the two disjoint
+            phases["prefill"] -= phases["prefill.wait"]
+        phases["tick"] = total = self._phase_end - t_top
+        if not worked:
+            return
+        hist = self.metrics.histogram("serving_tick")
+        recent = hist.last(SLOW_TICK_WINDOW)
+        if len(recent) >= SLOW_TICK_MIN and \
+                total > SLOW_TICK_FACTOR * sum(recent) / len(recent):
+            logger.warning("slow tick %.2f s: %s", total, ", ".join(
+                f"{k} {v:.2f}" for k, v in sorted(
+                    phases.items(), key=lambda kv: -kv[1]) if k != "tick"))
+        hist.record(work_end - t_top)
+        if chunk:
+            self.metrics.histogram("serving_chunk_tick").record(
+                work_end - t_top)
 
     def has_work(self) -> bool:
         """Anything queued, prefilling or decoding?"""
@@ -881,8 +1008,9 @@ class ServingEngine:
                      "serving_refusals_overloaded",
                      "serving_refusals_unmeetable"):
             self.metrics.counter(name).reset()
-        for name in ("serving_ttft", "serving_inter_token",
-                     "serving_prefill_step", "serving_decode_step"):
+        for name in ("serving_ttft", "serving_inter_token", "serving_tick",
+                     "serving_chunk_tick", "serving_queue_wait",
+                     "serving_prefill_wait", "serving_prefill_run"):
             h = self.metrics.histogram(name)
             h.reset()
             h.total_count = 0
@@ -930,6 +1058,10 @@ class ServingEngine:
             gauges = {"queue_depth": None, "active_requests": None,
                       "page_occupancy": None, "kv_fragmentation": None,
                       "scheduler_gauges": "unavailable"}
+        def wait_summary(part: str) -> dict:
+            h = m.histogram(f"serving_{part}").summary()
+            return {k: h.get(k) for k in ("p50", "p95", "count")}
+
         snap = {
             "ts": time.time(),
             "scope": "serving",
@@ -957,6 +1089,11 @@ class ServingEngine:
             # count-weighted into its fleet record
             "ttft": ttft,
             "itl": itl,
+            # where a first token's wait went: admission queue, the FIFO
+            # of other prompts' chunks, the request's own chunks
+            "first_token_waits": {
+                part: wait_summary(part) for part in
+                ("queue_wait", "prefill_wait", "prefill_run")},
             "chips": int(self.n_chips),
             "requests_per_chip": completed / max(self.n_chips, 1),
         }

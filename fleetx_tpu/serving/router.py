@@ -656,8 +656,15 @@ class Router:
             addr = _addr_str(backend.addr)
             events.extend({**e, "source": addr} for e in resp["events"])
             sources.append(addr)
-            if isinstance(resp.get("attribution"), dict):
-                attribution = resp["attribution"]
+            # a replica that held the id without serving it (a hedge's
+            # cancelled loser, a drain refusal) answers too, with no first
+            # token: it must not overwrite the attribution of the one
+            # that served
+            theirs = resp.get("attribution")
+            if isinstance(theirs, dict) and (
+                    attribution is None
+                    or attribution.get("ttft_s") is None):
+                attribution = theirs
         if not events:
             return {"id": rid, "error": "unknown request id"}
         events.sort(key=lambda e: e.get("t") or 0.0)
