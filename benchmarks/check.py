@@ -107,6 +107,34 @@ def sample_served(finished: list, seed: int, n: int) -> list:
     return [finished[i] for i in [order[0]] + rest]
 
 
+WIDTH_STEP = 128    # rows past ``pad_to`` grow by this: few widths to compile
+
+
+class TooLong(ValueError):
+    """A served request holds more tokens than the reference has positions."""
+
+
+def row_width(samples: list, pad_to: int, positions: int) -> int:
+    """How wide the token matrix has to be for ``samples``.
+
+    ``pad_to`` (the traffic file's ``check.pad_to``: longest prompt + longest
+    output) is the width for steady-state requests, and the width whenever
+    every sample fits it. A closed loop's first-round requests may need
+    more: ``traffic.ClosedLoop.first`` lengthens each by the prefill chunks
+    still queued behind it, and the longest finished request is always in
+    the sample. Then the width follows the samples, the smallest multiple
+    of ``WIDTH_STEP`` that holds the longest, and never more than the
+    reference's ``positions``: a request longer than those is ``TooLong``
+    (indexing past the position table would clamp, not fail)."""
+    longest = max(len(prompt) + len(served) for prompt, served in samples)
+    if longest > positions:
+        raise TooLong(f"request of {longest} tokens exceeds the reference's "
+                      f"{positions} positions")
+    if longest <= pad_to:
+        return int(pad_to)
+    return min(-(-longest // WIDTH_STEP) * WIDTH_STEP, positions)
+
+
 def served_logit_gaps(ref, sizes: dict, weights: dict, samples: list,
                       pad_to: int, chooser: str | None = None) -> dict:
     """Run the reference once over each prompt with its served tokens.
@@ -115,9 +143,13 @@ def served_logit_gaps(ref, sizes: dict, weights: dict, samples: list,
     below the reference's best at its position (valid for greedy tokens).
     With ``chooser`` (a lower precision) the tokens judged are not the
     served ones but those that precision puts first, at each position of
-    the same prompts and tokens — the control.
+    the same prompts and tokens — the control. Every served token of every
+    sample is compared: the rows are ``row_width`` wide (``width`` in the
+    result), which raises ``TooLong`` for a sample the configuration's
+    position table cannot hold.
     """
-    rows = np.zeros((len(samples), pad_to), np.int32)
+    width = row_width(samples, pad_to, int(sizes["max_position_embeddings"]))
+    rows = np.zeros((len(samples), width), np.int32)
     spans = []
     for i, (prompt, served) in enumerate(samples):
         seq = list(prompt) + list(served)
@@ -139,4 +171,5 @@ def served_logit_gaps(ref, sizes: dict, weights: dict, samples: list,
         first = plen - 1 if chooser is None else 0
         g = gap[i, first:plen - 1 + nserved]
         widest, n_tokens = max(widest, float(g.max())), n_tokens + nserved
-    return {"widest_gap": widest, "tokens_compared": n_tokens}
+    return {"widest_gap": widest, "tokens_compared": n_tokens,
+            "width": width}

@@ -20,6 +20,7 @@ import logging
 import os
 import re
 import sys
+import threading
 import time
 
 T_PROCESS_START = time.monotonic()
@@ -50,6 +51,13 @@ class _CompileLines(logging.Handler):
             self.kernels[m.group(1)] = json.loads(m.group(3))
 
 
+def _dump_stacks(err, seconds: float) -> None:
+    import faulthandler
+
+    print(f"stalled for {seconds:g} s:", file=err, flush=True)
+    faulthandler.dump_traceback(file=err, all_threads=True)
+
+
 class Context:
     """What one run hands to its cell code and to the readers."""
 
@@ -73,6 +81,7 @@ class Context:
         self.memory_peak_bytes = 0
         self.chip_start_s = 0.0
         self.marks: list = []
+        self._watchdog: threading.Timer | None = None
 
     # -- the files of this cell's configuration
     def reference(self):
@@ -162,15 +171,25 @@ class Context:
     def watch(self, seconds: float | None) -> None:
         """Arm (or, with None, disarm) a one-shot dump of every thread's
         stack to the log if the next ``seconds`` pass without a re-arm: a
-        tick or a step that stalls says where."""
-        import faulthandler
-
-        faulthandler.cancel_dump_traceback_later()
+        tick or a step that stalls says where. A Python thread makes the
+        dump, so it holds the interpreter lock while it walks the stacks.
+        ``faulthandler.dump_traceback_later`` walks them from a thread
+        without it, and on the chip's host that ended the run with SIGSEGV
+        whenever the main thread was running Python just then: with a warm
+        compile cache, a serving cell's first tick (PERF.md, PR 27)."""
+        if self._watchdog is not None:
+            self._watchdog.cancel()
+            self._watchdog = None
+        if seconds is None:
+            return
         try:
-            if seconds is not None:
-                faulthandler.dump_traceback_later(seconds, file=self.err)
+            self.err.fileno()
         except (AttributeError, OSError, ValueError):
-            pass        # a log without a file descriptor (the tests')
+            return      # a log without a file descriptor (the tests')
+        self._watchdog = threading.Timer(seconds, _dump_stacks,
+                                         (self.err, seconds))
+        self._watchdog.daemon = True
+        self._watchdog.start()
 
     def free_program(self):
         """Drop what the program left on the device before the reference runs."""

@@ -111,13 +111,38 @@ class Loop:
 
 
 def _counters(engine) -> dict:
-    m = engine.metrics
-    dec, pre = (m.histogram("serving_decode_step"),
-                m.histogram("serving_prefill_step"))
-    return {"tokens_total": m.counter("serving_tokens_total").value,
-            "decode_steps": dec.total_count, "decode_s": dec.total_sum,
-            "prefill_steps": pre.total_count, "prefill_s": pre.total_sum,
+    return {"tokens_total":
+            engine.metrics.counter("serving_tokens_total").value,
             "engine_steps": engine.steps}
+
+
+def _check_served(ctx, ref, spec, samples: list, pad_to: int) -> tuple:
+    """``(numbers, correct)`` of the sampled requests against the reference
+    (and, asked for, the control's numbers into ``ctx``). No sample, or
+    one the configuration's position table cannot hold, is judged not
+    correct; whatever else the reference raises ends the run."""
+    if not samples:
+        print("check: no request finished inside the window: nothing to "
+              "compare, NOT CORRECT", file=ctx.err)
+        return {}, False
+    sizes = ctx.config
+    w = weights.make(spec, ctx.seed)
+    try:
+        got = check.served_logit_gaps(ref, sizes, w, samples, pad_to)
+    except check.TooLong as e:
+        print(f"check: {e}  NOT CORRECT", file=ctx.err)
+        return {}, False
+    numbers = {"served_logit_widest_gap": got["widest_gap"]}
+    correct = check.judge(numbers, sizes["check"]["serve"], ctx.err)
+    longest = max(len(p) + len(s) for p, s in samples)
+    print(f"check: {got['tokens_compared']} served tokens of "
+          f"{len(samples)} requests compared (longest request {longest} "
+          f"tokens, rows {got['width']} wide)", file=ctx.err)
+    if ctx.control:
+        ctl = check.served_logit_gaps(ref, sizes, w, samples, pad_to,
+                                      chooser=ctx.control)
+        ctx.control_numbers = {"served_logit_widest_gap": ctl["widest_gap"]}
+    return numbers, correct
 
 
 def run(ctx) -> dict:
@@ -210,25 +235,8 @@ def run(ctx) -> dict:
     ctx.free_program()
     chk = mix["check"]
     samples = check.sample_served(finished, ctx.seed, int(chk["requests"]))
-    numbers, correct = {}, False
-    if samples:
-        w = weights.make(spec, ctx.seed)
-        got = check.served_logit_gaps(ref, sizes, w, samples,
-                                      int(chk["pad_to"]))
-        numbers = {"served_logit_widest_gap": got["widest_gap"]}
-        correct = check.judge(numbers, ctx.config["check"]["serve"],
-                              ctx.err)
-        print(f"check: {got['tokens_compared']} served tokens of "
-              f"{len(samples)} requests compared", file=ctx.err)
-        if ctx.control:
-            ctl = check.served_logit_gaps(ref, sizes, w, samples,
-                                          int(chk["pad_to"]),
-                                          chooser=ctx.control)
-            ctx.control_numbers = {
-                "served_logit_widest_gap": ctl["widest_gap"]}
-    else:
-        print("check: no request finished inside the window: nothing to "
-              "compare, NOT CORRECT", file=ctx.err)
+    numbers, correct = _check_served(ctx, ref, spec, samples,
+                                     int(chk["pad_to"]))
 
     window_s = t_close - t_open
     if tick_s:      # a stalled host shows here, not in a percentile

@@ -99,7 +99,10 @@ class ClosedLoop:
         request already decodes a token a tick, each residual is
         lengthened by the chunks behind it (``prefill_chunk`` tokens a
         chunk; 0 leaves that out). What is left when the last prompt is
-        in is then the evenly spaced residual itself."""
+        in is then the evenly spaced residual itself. A first-round
+        request can so hold more tokens than the mix's longest prompt +
+        longest output (its ``check.pad_to``), though never more than the
+        engine's ``max_seq_len`` (tested): ``check.row_width`` follows."""
         reqs = [self._make(c) for c in range(self.clients)]
         order = [int(i) for i in self._rng.permutation(self.clients)]
         if self.mix.get("stationary_start", True):

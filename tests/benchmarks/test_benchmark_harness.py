@@ -12,6 +12,8 @@ import io
 import json
 import os
 import sys
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -190,6 +192,51 @@ def test_stationary_start_allows_for_the_fill(real):
     assert max(len(r.prompt) + r.max_new for r in filled) <= 1024
 
 
+def _serving_override(cfg: dict, key: str) -> int:
+    return next(int(o.split("=")[1]) for o in cfg["serve"]["overrides"]
+                if o.startswith(f"Serving.{key}="))
+
+
+def _closed_cells() -> list:
+    """Every shipped cell whose traffic is a closed loop: a cell that a
+    later PR adds is held to the same rule without an edit here."""
+    m = Manifest(ROOT)
+    return sorted(name for name, w in m.cells.items()
+                  if m.traffic(w["traffic"])["kind"] == "closed_loop")
+
+
+# the longest request dealt over seeds 2,600,000,000 + 0..299 (first round
+# and 200 more): what ISSUE 27 reckoned by hand, pinned
+LONGEST_DEALT = {"gpt345m-serve-decode-closed": 876,
+                 "gpt345m-serve-prefill-closed": 550}
+
+
+@pytest.mark.parametrize("cell", _closed_cells())
+def test_every_request_dealt_fits_the_engine(real, cell, capsys):
+    """A first-round request is longer than prompt + drawn output (the
+    fill's chunks behind it), so it may pass ``check.pad_to``; it never
+    passes the engine's ``max_seq_len`` nor the reference's positions."""
+    cfg = real.config(real.cells[cell]["config"])
+    mix = real.traffic(real.cells[cell]["traffic"])
+    chunk = _serving_override(cfg, "prefill_chunk")
+    longest, over_pad = 0, 0
+    for seed in range(2600000000, 2600000300):
+        gen = traffic.ClosedLoop(mix, seed, vocab=100)
+        first = max(len(r.prompt) + r.max_new for r in gen.first(chunk))
+        later = max(len(r.prompt) + r.max_new for r in (
+            gen.next_for(i % gen.clients) for i in range(200)))
+        assert later <= mix["check"]["pad_to"]      # steady state fits it
+        longest = max(longest, first, later)
+        over_pad += first > mix["check"]["pad_to"]
+    with capsys.disabled():
+        print(f"\n{cell}: longest request dealt {longest} tokens; first "
+              f"round passes pad_to {mix['check']['pad_to']} on {over_pad} "
+              f"of 300 seeds")
+    assert longest <= _serving_override(cfg, "max_seq_len")
+    assert longest <= cfg["max_position_embeddings"]
+    assert longest == LONGEST_DEALT.get(cell, longest)
+
+
 def test_open_loop_plan_same_work_and_due_times():
     mix = {"rate_rps": 10.0, "burst": 2, "prompt_lengths": [8, 16],
            "output_lengths": [2, 4, 6]}
@@ -288,17 +335,51 @@ def test_refuses_fewer_chips_than_the_cell_asks(tmp_path, monkeypatch):
     assert "needs 4 chips" in str(e.value)
 
 
+def _busy(seconds):
+    t0 = time.monotonic()
+    while time.monotonic() - t0 < seconds:
+        sum(len(str(i)) for i in range(200))
+
+
+def test_a_stall_is_dumped_while_the_main_thread_runs_python(toy_root,
+                                                             tmp_path):
+    """The dump comes from a thread that holds the interpreter lock
+    (``dump_traceback_later``'s does not, and on the chip's host that
+    killed runs with SIGSEGV); a re-arm in time or a disarm prevents it."""
+    m = Manifest(toy_root)
+    with open(tmp_path / "log", "w+") as log:
+        ctx = run.Context(m, m.cells["toy-closed"], _args("toy-closed"), [],
+                          log, 0.0)
+        ctx.watch(0.05)
+        _busy(0.3)
+        ctx.watch(0.05)
+        ctx.watch(None)
+        time.sleep(0.15)
+        log.seek(0)
+        text = log.read()
+    assert text.count("stalled for 0.05 s:") == 1
+    assert "in _busy" in text and "most recent call first" in text
+    assert not [t for t in threading.enumerate()
+                if isinstance(t, threading.Timer)]
+
+
 _LINES: dict = {}
 
 
 def _rehearse(toy_root, cell, trace):
     key = (cell, trace)
     if key not in _LINES:
-        out = io.StringIO()
-        run.run_cell(_args(cell, trace), root=toy_root, platforms=("cpu",),
-                     out=out, err=io.StringIO())
+        out, err = io.StringIO(), io.StringIO()
+        run.run_cell(_args(cell, trace, seed=REHEARSAL_SEEDS.get(
+            cell, 3000000019)), root=toy_root, platforms=("cpu",), out=out,
+            err=err)
         _LINES[key] = json.loads(out.getvalue().strip().splitlines()[-1])
+        _LINES[key]["_log"] = err.getvalue()
     return _LINES[key]
+
+
+# a seed whose first round holds a request longer than the mix's pad_to
+REHEARSAL_SEEDS = {"toy-closed-tight": 3000000024}
 
 
 DEVICE_ONLY = ("roofline", "mfu", "hbm_peak", "pool_copy", "fwd_ms",
@@ -308,6 +389,7 @@ DEVICE_ONLY = ("roofline", "mfu", "hbm_peak", "pool_copy", "fwd_ms",
 
 @pytest.mark.parametrize("cell,trace", [("toy-train", 0), ("toy-train", 1),
                                         ("toy-closed", 0), ("toy-closed", 1),
+                                        ("toy-closed-tight", 0),
                                         ("toy-open", 0)])
 def test_rehearsal_prints_a_well_formed_line(toy_root, cell, trace):
     line = _rehearse(toy_root, cell, trace)
@@ -326,6 +408,22 @@ def test_rehearsal_prints_a_well_formed_line(toy_root, cell, trace):
     assert not [k for k in line["metrics"]
                 if any(d in k for d in DEVICE_ONLY)]
     assert "busy_s" not in line["device"]
+
+
+def test_rehearsal_checks_a_first_round_request_past_pad_to(toy_root):
+    """The mix's ``pad_to`` is its longest prompt + longest output; the
+    window is long enough for every first-round request to finish, and the
+    longest of them (lengthened by the fill's chunks behind it) is always
+    in the sample: the check widens its rows and compares all of it."""
+    mix = toy.TOY_TRAFFIC["toy-closed-tight"]
+    first = traffic.ClosedLoop(mix, REHEARSAL_SEEDS["toy-closed-tight"],
+                               512).first(prefill_chunk=32)
+    longest = max(len(r.prompt) + r.max_new for r in first)
+    assert mix["check"]["pad_to"] < longest <= 128
+    line = _rehearse(toy_root, "toy-closed-tight", 0)
+    assert line["correct"] is True and line["failed"] == 0
+    assert f"(longest request {longest} tokens, rows 128 wide)" \
+        in line["_log"]
 
 
 # ------------------------------------------------------------ trace reduction
@@ -503,8 +601,18 @@ def test_train_control_one_precision_lower_is_not_correct(seed):
         limits["grad_norm_worst_leaf_gap"]
 
 
-def _toy_serve_gap(chooser, seed=5):
-    from benchmarks import check, weights
+_TOY_SERVED: dict = {}
+
+
+def _toy_served(seed=5):
+    """``(ref, cfg, weights, samples)``: three requests of 64, 100 and 120
+    tokens whose served tokens are the reference's own greedy ones (they
+    stand for a sound program)."""
+    if seed in _TOY_SERVED:
+        return _TOY_SERVED[seed]
+    import jax
+    import jax.numpy as jnp
+    from benchmarks import weights
 
     m = Manifest(ROOT)
     cfg = toy.toy_config()
@@ -512,18 +620,31 @@ def _toy_serve_gap(chooser, seed=5):
     w = weights.make(ref.weight_spec(cfg), seed)
     rng = np.random.default_rng(seed)
     samples = []
-    import jax
-    import jax.numpy as jnp
-
     fwd = jax.jit(lambda t: ref.logits(w, cfg, t, "float32"))
     for plen, n in ((24, 40), (40, 60), (56, 64)):
-        # greedy tokens of the reference itself stand for a sound program
         seq = rng.integers(0, cfg["vocab_size"], plen).tolist()
         for _ in range(n):
             padded = jnp.asarray([seq + [0] * (128 - len(seq))])
             seq.append(int(jnp.argmax(fwd(padded)[0, len(seq) - 1])))
         samples.append((seq[:plen], seq[plen:]))
-    got = check.served_logit_gaps(ref, cfg, w, samples, 128, chooser=chooser)
+    _TOY_SERVED[seed] = (ref, cfg, w, samples)
+    return _TOY_SERVED[seed]
+
+
+def _toy_serve_gap(chooser, seed=5, pad_to=128, widths=None):
+    """The check over ``_toy_served``; ``widths`` collects the shape of
+    every token matrix the reference is traced with."""
+    import types
+    from benchmarks import check
+
+    ref, cfg, w, samples = _toy_served(seed)
+
+    def logits(w, sizes, tokens, precision):
+        if widths is not None:
+            widths.append(tuple(tokens.shape))
+        return ref.logits(w, sizes, tokens, precision)
+    got = check.served_logit_gaps(types.SimpleNamespace(logits=logits), cfg,
+                                  w, samples, pad_to, chooser=chooser)
     return got, cfg["check"]["serve"]
 
 
@@ -536,6 +657,91 @@ def test_serve_check_sound_tokens_have_no_gap():
 def test_serve_control_one_precision_lower_is_not_correct():
     got, limits = _toy_serve_gap("float8")
     assert got["widest_gap"] > limits["served_logit_widest_gap"]
+
+
+@pytest.mark.parametrize("pad_to,width", [(128, 128), (120, 120),
+                                          (100, 128), (64, 128)])
+def test_serve_check_rows_follow_the_samples(pad_to, width):
+    """Samples that all fit ``pad_to`` are compared at exactly that width
+    (the same reference program as before ISSUE 27); a longer one widens
+    the rows, and every served token is still compared."""
+    widths = []
+    got, _ = _toy_serve_gap(None, pad_to=pad_to, widths=widths)
+    assert widths == [(3, width)] and got["width"] == width
+    assert got["tokens_compared"] == 164
+    assert np.isfinite(got["widest_gap"]) and got["widest_gap"] <= 1e-4
+
+
+def test_serve_control_goes_through_the_same_width():
+    widths = []
+    got, limits = _toy_serve_gap("float8", pad_to=100, widths=widths)
+    assert widths == [(3, 128), (3, 128)]       # float32, then the chooser
+    assert got["widest_gap"] > limits["served_logit_widest_gap"]
+
+
+@pytest.mark.parametrize("samples,pad_to,positions,width", [
+    ([(384, 384)], 768, 1024, 768), ([(128, 1)], 768, 1024, 768),
+    ([(384, 385)], 768, 1024, 896), ([(384, 416), (128, 128)], 768, 1024, 896),
+    ([(384, 512)], 768, 1024, 896), ([(384, 513)], 768, 1024, 1024),
+    ([(384, 640)], 768, 1024, 1024), ([(384, 500)], 768, 1000, 896),
+    ([(384, 600)], 768, 1000, 1000), ([(512, 38)], 640, 1024, 640)])
+def test_row_width(samples, pad_to, positions, width):
+    from benchmarks import check
+
+    made = [([0] * p, [0] * s) for p, s in samples]
+    assert check.row_width(made, pad_to, positions) == width
+
+
+def test_a_sample_past_the_position_table_is_too_long():
+    from benchmarks import check
+
+    ref, cfg, w, samples = _toy_served()
+    prompt, served = samples[-1]
+    with pytest.raises(check.TooLong) as e:
+        check.served_logit_gaps(ref, cfg, w, samples[:2] + [
+            (prompt, served + [1] * 9)], 128)
+    assert str(e.value) == ("request of 129 tokens exceeds the reference's "
+                            "128 positions")
+
+
+def test_a_request_the_reference_cannot_hold_is_judged_not_raised(
+        toy_root, monkeypatch):
+    """A served request longer than the configuration's position table is
+    ``correct`` false with the reason in the log and the window's metrics
+    in the line; no exception."""
+    from benchmarks import check
+
+    sample = check.sample_served
+
+    def lengthened(finished, seed, n):
+        got = sample(finished, seed, n)
+        prompt, served = got[0]
+        return [(prompt, served + [1] * (129 - len(prompt) - len(served)))
+                ] + got[1:]
+    monkeypatch.setattr(check, "sample_served", lengthened)
+    out, err = io.StringIO(), io.StringIO()
+    run.run_cell(_args("toy-closed", seed=3000000021), root=toy_root,
+                 platforms=("cpu",), out=out, err=err)
+    line = json.loads(out.getvalue().strip().splitlines()[-1])
+    assert line["correct"] is False and line["check"] == {}
+    assert ("check: request of 129 tokens exceeds the reference's 128 "
+            "positions  NOT CORRECT") in err.getvalue()
+    assert line["metrics"]["serve_out_tokens_per_s"]["value"] > 0
+    assert line["metrics"]["setup_s"]["value"] > 0
+
+
+def test_an_error_of_the_reference_still_ends_the_run(toy_root, monkeypatch):
+    """Only ``TooLong`` is judged: anything else the check raises is not
+    caught."""
+    from benchmarks import check
+
+    def broken(*args, **kwargs):
+        raise ValueError("could not broadcast")
+    monkeypatch.setattr(check, "served_logit_gaps", broken)
+    with pytest.raises(ValueError, match="could not broadcast"):
+        run.run_cell(_args("toy-closed", seed=3000000022), root=toy_root,
+                     platforms=("cpu",), out=io.StringIO(),
+                     err=io.StringIO())
 
 
 BREAKS = ["step_returns_state_unchanged", "part_of_the_batch_left_out",

@@ -47,6 +47,14 @@ TOY_TRAFFIC = {
                    "output_lengths": [4, 6, 8, 10], "stationary_start": True,
                    "trace_seconds": 0.5,
                    "check": {"requests": 3, "pad_to": 128}},
+    # ``pad_to`` is longest prompt + longest output, as in the shipped
+    # mixes: a first-round request, lengthened by the fill's chunks behind
+    # it, can pass it (99 tokens for seed 3000000024)
+    "toy-closed-tight": {"kind": "closed_loop", "clients": 4,
+                         "prompt_lengths": [24, 56, 88],
+                         "output_lengths": [4, 6, 8, 10],
+                         "stationary_start": True, "trace_seconds": 0.5,
+                         "check": {"requests": 3, "pad_to": 98}},
     "toy-open": {"kind": "open_loop", "rate_rps": 6.0,
                  "prompt_lengths": [24, 40], "output_lengths": [3, 5],
                  "trace_seconds": 0.5, "check": {"requests": 3,
@@ -68,17 +76,14 @@ def make_root(tmp: str, chips: int = 1) -> str:
             json.dump(mix, f)
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         real = json.load(f)
-    cells = {"toy-train": "toy-train", "toy-closed": "toy-closed",
-             "toy-open": "toy-open"}
-    serve = ["toy-closed", "toy-open"]
+    serve = [name for name in TOY_TRAFFIC if name != "toy-train"]
     bench = dict(real)
     bench["configs"] = [{"name": "toy", "source": "tests", "reduced": [],
                          "file": "benchmarks/configs/toy.json",
                          "why": "toy widths for the CPU rehearsal"}]
-    bench["workloads"] = [{"name": n, "config": "toy", "traffic": t,
+    bench["workloads"] = [{"name": n, "config": "toy", "traffic": n,
                            "chips": chips if n == "toy-train" else 1,
-                           "why": "rehearsal"}
-                          for n, t in cells.items()]
+                           "why": "rehearsal"} for n in TOY_TRAFFIC]
 
     def remap(metric):
         m = dict(metric)
