@@ -302,9 +302,10 @@ PARTITION_RULES: dict[str, tuple] = {
     ),
     # the serving KV page pool (serving/paged_cache.py): pages over the
     # ZeRO axis (capacity scales with fsdp), heads over the Megatron axis
+    # (heads and head_dim share the pool's minor dim, heads major)
     "serving_kv": (
         (r"kv_pool/(k|v)$",
-         ("layers", "kv_pages", "page_slot", "heads", "kv")),
+         ("layers", "kv_pages", "page_slot", "heads")),
     ),
 }
 
@@ -537,7 +538,7 @@ def kv_pool_spec(layout: Optional[SpecLayout] = None):
     (family ``serving_kv``): pages over ``fsdp``, heads over ``tensor``."""
     from jax.sharding import PartitionSpec as P
 
-    return P(*spec_for("serving_kv", "kv_pool/k", (1, 2, 2, 2, 2),
+    return P(*spec_for("serving_kv", "kv_pool/k", (1, 2, 2, 2),
                        layout or SpecLayout()))
 
 

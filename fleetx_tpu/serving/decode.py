@@ -21,17 +21,33 @@ continuous batching is. Math is kept line-for-line parallel (f32
 layernorms, cfg-dtype matmuls, f32 softmax, gelu ``approximate=True``) so
 greedy decode is token-identical to one-shot ``generation.generate``.
 
+The KV pool (``[layers, pages, page_size, heads·head_dim]``, K and V)
+stays ONE buffer from each program's input to its output: it rides in
+the layer scan's CARRY beside the activations (the scan runs over the
+layer parameters and the layer index), a layer writes its block's K/V
+rows (``heads·head_dim`` contiguous values each) in place at ``[layer,
+page, offset]``, attention reads the pool by layer index, and the donated
+inputs alias the outputs. The pool is never
+a scanned input or a stacked output — that form makes XLA slice each
+layer (235 MB at the 345M serving geometry) out of the pool, copy it for
+the scatter, write it back into a fresh stack and copy the whole stack
+once more because a fresh buffer cannot alias the donated one: ~75 GB of
+HBM traffic a call to change 0.2 MB (PERF.md, PR 28).
+``tests/test_tpu_lowering.py`` pins the aliasing and the absence of any
+pool- or layer-shaped temporary in the compiled programs.
+
 Decode attention has two compiled forms, chosen ONCE at
 ``make_step_fns`` time (so the jit caches still hold one entry each):
 the ``ops/paged_attention.py`` Pallas kernel that walks block tables
-in-kernel (scalar-prefetched page ids, online-softmax f32 accumulation —
-no dense page view ever materialises), or — when
-``paged_kernel_enabled`` rejects the geometry — the original gathered
-view ``pool[block_tables] → [B, pages_per_req·page_size, heads,
-head_dim]`` fused by XLA. Prefill always takes the gather (its queries
-span a whole chunk, not one token). Host-side machinery is identical on
-both paths, and greedy decode is token-identical either way
-(``tests/test_zz_serving.py`` pins parity AND which path compiled).
+in-kernel (scalar-prefetched layer index and page ids, online-softmax
+f32 accumulation — no dense page view ever materialises), or — when
+``paged_kernel_enabled`` rejects the geometry — the gathered view
+``pool[layer, block_tables] → [B, pages_per_req·page_size, heads,
+head_dim]`` (one gather with the layer folded into its indices) fused by
+XLA. Prefill always takes the gather (its queries span a whole chunk,
+not one token). Host-side machinery is identical on both paths, and
+greedy decode is token-identical either way (``tests/test_zz_serving.py``
+pins parity AND which path compiled).
 
 Quantized decode (``ServingConfig.quantize_decode``): int8-style fake-quant
 on the decode activations (``Quantization.activation_bits`` →
@@ -135,16 +151,19 @@ def _forward(params: Any, cfg: Any, tokens: jax.Array, positions: jax.Array,
              ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Forward a ``[B, S]`` token block through the paged decode stack.
 
-    Writes the block's K/V into the pool (scatter by block table), then
-    runs attention per layer: the Pallas page-walk kernel when
-    ``paged_kernel`` is set (decode only — ``S == 1``), the gathered page
-    view otherwise. Returns ``(hidden [B, S, h], pool_k, pool_v)``.
+    The pools are carried through the layer scan as one buffer each.
+    Per layer: the block's K/V rows are scattered in place at ``[layer,
+    page, offset]`` (by block table), then attention reads that layer of
+    the pool by index — the Pallas page-walk kernel when ``paged_kernel``
+    is set (decode only — ``S == 1``), the gathered page view otherwise.
+    Returns ``(hidden [B, S, h], pool_k, pool_v)``.
     ``positions`` are absolute token positions (invalid slots must
     already be redirected to the null page via ``block_tables``-aware
     ``positions``/page math by the caller-built scatter indices below).
     """
     B, S = tokens.shape
     ps = pool_k.shape[2]
+    num_layers = pool_k.shape[0]
     gpt = params["gpt"]
     emb = gpt["embeddings"]
 
@@ -164,8 +183,9 @@ def _forward(params: Any, cfg: Any, tokens: jax.Array, positions: jax.Array,
     nh, hd = cfg.num_attention_heads, cfg.head_dim
     act_bits, w_bits = cfg.qat_act_bits, cfg.qat_bits
 
-    def layer(x, scanned):
-        lp, pk_l, pv_l = scanned
+    def layer(carry, scanned):
+        x, pool_k, pool_v = carry
+        lp, l = scanned
         residual = x
         y = _layer_norm(lp["ln1"], x, cfg)
 
@@ -176,20 +196,23 @@ def _forward(params: Any, cfg: Any, tokens: jax.Array, positions: jax.Array,
         qkv = qkv + lp["attn"]["qkv_bias"].astype(cfg.dtype)[:, None]
         q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]          # [B, S, nh, hd]
 
-        pk_l = pk_l.at[pages, offs].set(k)
-        pv_l = pv_l.at[pages, offs].set(v)
+        # in place on the carried pool: a [B, S] scatter of nh·hd-wide rows
+        pool_k = pool_k.at[l, pages, offs].set(k.reshape(B, S, nh * hd))
+        pool_v = pool_v.at[l, pages, offs].set(v.reshape(B, S, nh * hd))
         if paged_kernel and S == 1:
-            # in-kernel block-table walk (ops/paged_attention.py): the
-            # pool is read page-by-page via scalar-prefetched ids — the
-            # dense [B, pages_per_req·page_size, nh, hd] view is never
-            # materialised. positions[:, 0] is each row's query position
-            # (< 0 = inactive slot → all pages masked, exact-zero out).
+            # in-kernel block-table walk (ops/paged_attention.py): layer
+            # l of the pool is read page-by-page via scalar-prefetched
+            # ids — neither the layer nor the dense [B,
+            # pages_per_req·page_size, nh, hd] view is ever materialised.
+            # positions[:, 0] is each row's query position (< 0 =
+            # inactive slot → all pages masked, exact-zero out).
             attn = PA.paged_attention_sharded(
-                q[:, 0], pk_l, pv_l, block_tables, positions[:, 0],
+                q[:, 0], pool_k, pool_v, block_tables, positions[:, 0], l,
                 mesh=mesh)[:, None]
         else:
-            kd = pk_l[block_tables].reshape(B, -1, nh, hd)
-            vd = pv_l[block_tables].reshape(B, -1, nh, hd)
+            # one gather, the layer folded into its indices
+            kd = pool_k[l, block_tables].reshape(B, -1, nh, hd)
+            vd = pool_v[l, block_tables].reshape(B, -1, nh, hd)
             attn = _paged_attention(q, kd, vd, q_pos)
 
         attn = _quant(attn, act_bits, quantize)
@@ -213,11 +236,12 @@ def _forward(params: Any, cfg: Any, tokens: jax.Array, positions: jax.Array,
         y = jnp.einsum("bsm,mh->bsh", y, wo) + \
             lp["mlp"]["wo_bias"].astype(cfg.dtype)
         x = residual + y
-        return x, (pk_l, pv_l)
+        return (x, pool_k, pool_v), None
 
     x = x.astype(cfg.dtype)
-    x, (pool_k, pool_v) = jax.lax.scan(
-        layer, x, (gpt["layers"], pool_k, pool_v))
+    (x, pool_k, pool_v), _ = jax.lax.scan(
+        layer, (x, pool_k, pool_v),
+        (gpt["layers"], jnp.arange(num_layers, dtype=jnp.int32)))
     x = _layer_norm(gpt["ln_f"], x, cfg)
     return x, pool_k, pool_v
 

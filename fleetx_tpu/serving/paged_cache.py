@@ -13,7 +13,14 @@ O(1)-append blueprint this follows).
 
 Pool layout (K and V each)::
 
-    [layers, num_pages, page_size, heads, head_dim]
+    [layers, num_pages, page_size, heads * head_dim]
+
+Heads and head_dim share the minor dim on purpose: a TPU buffer is tiled
+(8, 128) over its two minor dims, so ``[..., heads, 64]`` either pads
+every row to 128 lanes or is stored page-dim-minor, where a page is not
+contiguous and every jitted step first transposes the whole pool
+(``ops/paged_attention.py``). ``heads * head_dim`` keeps a token's K (or
+V) row one contiguous line and the pool unpadded.
 
 Page 0 is the reserved **null page**: block-table filler slots and masked
 (inactive) batch rows point at it, so the jitted steps can scatter/gather
@@ -61,14 +68,14 @@ def init_pool(cfg: Any, num_pages: int, page_size: int,
     """
     dtype = dtype or cfg.dtype
     shape = (cfg.num_layers, int(num_pages), int(page_size),
-             cfg.num_attention_heads, cfg.head_dim)
+             cfg.num_attention_heads * cfg.head_dim)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 
 def pool_shardings(mesh: Mesh) -> NamedSharding:
     """The pool's mesh placement: pages over ``fsdp``, heads over ``tensor``.
 
-    Pool dims are ``(layers, pages, page_size, heads, head_dim)``; the
+    Pool dims are ``(layers, pages, page_size, heads * head_dim)``; the
     spec is the registry's ``serving_kv`` family rule
     (``parallel/rules.py:kv_pool_spec``) — the page dim shards over the
     ZeRO axis (capacity scales with fsdp degree) and the heads dim over

@@ -11,9 +11,18 @@ its rule that a Mosaic call under a mesh sits in a ``shard_map`` manual
 over every axis; the expected kernels must then be in the text by name.
 It is not the Mosaic compiler: what only the chip (or an ahead-of-time
 compile for its topology) can refuse — VMEM, tiling — is ``chip_smoke.py``.
+
+The serving programs are ALSO compiled, by the chip's own compiler against
+a described v5e topology (no chip attached), to pin the mechanism that
+keeps the KV pool one buffer: both pools aliased input to output, and no
+instruction that holds a second pool or a layer of it.
 """
 
+import os
+import re
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -83,8 +92,6 @@ def test_train_step_lowers_for_tpu(devices8, monkeypatch, case):
 @pytest.mark.parametrize("dist", [None, {"fsdp_degree": 2, "mp_degree": 2}],
                          ids=["one_device", "fsdp2_mp2"])
 def test_serving_decode_lowers_for_tpu(devices8, monkeypatch, dist):
-    import jax.numpy as jnp
-
     from fleetx_tpu.models.gpt.model import (GPTForPretraining,
                                              config_from_dict)
     from fleetx_tpu.serving.engine import ServingConfig, ServingEngine
@@ -105,3 +112,120 @@ def test_serving_decode_lowers_for_tpu(devices8, monkeypatch, dist):
         engine.pool_v, engine._last_tokens, engine._block_tables,
         engine._lens, engine._next_rng())
     assert set(found) == {"paged_decode"}, found
+
+
+# ------------------------------------------------- the pool stays one buffer
+
+@pytest.fixture(scope="module")
+def topo():
+    """A described (not attached) v5e 2x2: the chip's own XLA and Mosaic
+    compilers run against it in this process. Described here, inside a
+    fixture, so only the worker that runs this file loads libtpu."""
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here: nothing to compile with
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+#: opcodes that may carry the pool: what enters, the loop carry, and the
+#: in-place row scatter (alone, or as the root of its fusion)
+_POOL_CARRIERS = {"parameter", "get-tuple-element", "scatter", "fusion"}
+
+
+def _pool_holders(hlo: str, shape: tuple) -> list:
+    """``(opcode, line)`` of every instruction of a compiled program whose
+    result has the pool's (per-device) shape or the shape of one layer of
+    it, with or without the leading 1."""
+    pool = ",".join(map(str, shape))
+    layer = ",".join(map(str, shape[1:]))
+    want = re.compile(
+        r"= \w+\[(?:%s|1,%s|%s)\]\S* ([\w-]+)\(" % (pool, layer, layer))
+    return [(m.group(1), line.strip()) for line in hlo.splitlines()
+            if (m := want.search(line))]
+
+
+@pytest.mark.parametrize("dist", [None, {"fsdp_degree": 2, "mp_degree": 2}],
+                         ids=["one_device", "fsdp2_mp2"])
+@pytest.mark.parametrize("kernel", [True, False], ids=["kernel", "gather"])
+def test_serving_programs_keep_the_pool_one_buffer(topo, monkeypatch, dist,
+                                                   kernel):
+    """Compiled for the v5e, ``decode`` and ``prefill`` alias both pools
+    from input to output and hold no other buffer of the pool's shape or
+    of one layer's: no ``copy``, no ``dynamic-slice`` of a layer, no
+    stacked output. A pool that is a scanned input fails every clause."""
+    from flax.core import meta
+    from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
+
+    from fleetx_tpu.models.gpt.model import (GPTForPretraining,
+                                             config_from_dict)
+    from fleetx_tpu.serving.decode import (SamplingParams, make_step_fns,
+                                           paged_kernel_enabled)
+    from fleetx_tpu.serving.paged_cache import init_pool, pool_shardings
+
+    monkeypatch.setattr(ops, "interpret", lambda: False)
+    # a pool too large for the compiler to stage in on-chip memory, as the
+    # real one is (abstract shapes: nothing is allocated)
+    layers, pages, page, batch, per_req, chunk = 3, 4098, 16, 4, 4, 16
+    cfg = config_from_dict(dict(
+        vocab_size=VOCAB, hidden_size=256, num_layers=layers,
+        num_attention_heads=4, max_position_embeddings=64))
+    if dist:
+        mesh = build_mesh(dist, devices=topo.devices)
+        pool_sh, rep = pool_shardings(mesh), NamedSharding(
+            mesh, PartitionSpec())
+    else:
+        pool_sh, rep = None, SingleDeviceSharding(topo.devices[0])
+
+    def arr(shape, dtype, sharding=rep):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    params = jax.tree.map(
+        lambda a: arr(a.shape, a.dtype),
+        meta.unbox(jax.eval_shape(lambda: GPTForPretraining(cfg).init(
+            {"params": jax.random.PRNGKey(0)}, jnp.zeros((1, 8), jnp.int32),
+            None, deterministic=True)["params"])))
+    pool_k, _ = jax.eval_shape(lambda: init_pool(cfg, pages, page))
+    pool = arr(pool_k.shape, pool_k.dtype, pool_sh or rep)
+    local = pool.sharding.shard_shape(pool.shape)   # what one device holds
+    if kernel:
+        assert paged_kernel_enabled(cfg, page_size=page, num_pages=pages,
+                                    pages_per_req=per_req,
+                                    pool_sharding=pool_sh)
+    fns = make_step_fns(cfg, max_batch=batch, pages_per_req=per_req,
+                        prefill_chunk=chunk, sampling=SamplingParams(),
+                        pool_sharding=pool_sh, paged_kernel=kernel)
+    i32, rng = jnp.int32, arr((2,), jnp.uint32)
+    programs = {
+        "prefill": (params, pool, pool, arr((1, chunk), i32),
+                    arr((1, per_req), i32), arr((), i32), arr((), i32), rng),
+        "decode": (params, pool, pool, arr((batch,), i32),
+                   arr((batch, per_req), i32), arr((batch,), i32), rng),
+    }
+    n_params = len(jax.tree.leaves(params))
+    for name, args in programs.items():
+        hlo = fns[name].lower(*args).compile().as_text()
+        if name == "decode":
+            assert ("paged_decode" in hlo) == kernel, name
+        alias = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo)
+        assert alias, f"{name}: no input-output aliasing at all"
+        for out, arg in ((0, n_params), (1, n_params + 1)):
+            assert f"{{{out}}}: ({arg}, {{}}," in alias.group(1), \
+                (name, alias.group(1))
+        holders = _pool_holders(hlo, local)
+        assert holders, f"{name}: the pool {local} is not in the program"
+        stray = [h for h in holders if h[0] not in _POOL_CARRIERS]
+        assert not stray, (name, stray)
+        fused = [line for op, line in holders if op == "fusion"]
+        for line in fused:  # a fusion may hold the pool only to scatter
+            root = re.search(r"calls=(%[\w.-]+)", line).group(1)
+            body = hlo.split(f"{root} ", 1)[1].split("\n}", 1)[0]
+            assert re.search(r"ROOT \S+ = \S+ scatter\(", body), (name, line)
+        # one in-place write a pool a program, and no layer-sized result
+        assert len(fused) == 2, (name, fused)
+        layer_sized = ",".join(map(str, local[1:]))
+        assert not re.search(
+            r"= \w+\[(?:1,)?%s\]" % layer_sized, hlo), name

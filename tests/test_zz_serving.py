@@ -122,18 +122,34 @@ class TestPageAllocator:
 # decode parity (the serving acceptance contract)
 # ---------------------------------------------------------------------------
 
-@pytest.fixture(scope="module")
-def small_model():
-    """The tiny f32 GPT shared by every parity test (same recipe as
-    tests/test_generation.py)."""
+def _build_model(**over):
     from flax.core import meta
 
-    cfg = config_from_dict(MODEL_DICT)
+    cfg = config_from_dict(dict(MODEL_DICT, **over))
     model = GPTForPretraining(cfg)
     params = model.init({"params": jax.random.PRNGKey(0)},
                         jnp.zeros((1, 8), jnp.int32), None,
                         deterministic=True)["params"]
     return cfg, model, meta.unbox(params)
+
+
+@pytest.fixture(scope="module")
+def small_model():
+    """The tiny f32 GPT shared by every parity test (same recipe as
+    tests/test_generation.py)."""
+    return _build_model()
+
+
+@pytest.fixture(scope="module")
+def deep_model():
+    """Three layers, each with its own weights, so each layer of the pool
+    holds different keys and values: an attention that reads layer 0 (or
+    any one layer) every time decodes other tokens than the reference."""
+    return _build_model(num_layers=3)
+
+
+#: the parity tests run on both: ``request.getfixturevalue(which)``
+MODELS = ["small_model", "deep_model"]
 
 
 def one_shot(model, params, prompts, max_new):
@@ -244,13 +260,17 @@ def test_admission_oom_refusal_queueing_and_drain(small_model):
     assert eng.metrics.counter("serving_requests_refused").value == 3
 
 
-def test_quantized_decode_parity_bounded(small_model):
+@pytest.mark.parametrize("paged_kernel", [True, False],
+                         ids=["kernel", "gather"])
+@pytest.mark.parametrize("which", MODELS)
+def test_quantized_decode_parity_bounded(request, which, paged_kernel):
     """The int8-activation decode path (Quantization.qat_act_bits) stays
     within a bounded drift of the fp path — same stance as the PR 3 remat
     drift tests — and still decodes mostly the same greedy tokens on the
-    tiny model."""
-    cfg, _, params = small_model
-    qcfg = config_from_dict(dict(MODEL_DICT, qat_act_bits=8))
+    tiny model, through the layer-indexed kernel and through the gather."""
+    cfg, _, params = request.getfixturevalue(which)
+    qcfg = config_from_dict(dict(MODEL_DICT, num_layers=cfg.num_layers,
+                                 qat_act_bits=8))
     prompts = [[5, 9, 23, 41], [7, 3, 11]]
 
     def run(quantize):
@@ -258,8 +278,10 @@ def test_quantized_decode_parity_bounded(small_model):
             qcfg, params,
             ServingConfig(max_batch=2, page_size=4, num_pages=17,
                           max_seq_len=32, prefill_chunk=8,
-                          quantize_decode=quantize),
+                          quantize_decode=quantize,
+                          paged_kernel=paged_kernel),
             eos_token_id=EOS)
+        assert eng.paged_kernel_active == paged_kernel
         reqs = [eng.submit(p, 6, request_id=f"q{i}")
                 for i, p in enumerate(prompts)]
         eng.run_until_drained()
@@ -285,12 +307,13 @@ def test_quantized_decode_parity_bounded(small_model):
     assert agree >= len(fp_tokens[0]) // 2, (fp_tokens, q_tokens)
 
 
-def test_pool_sharded_over_mesh_keeps_parity(small_model, devices8):
+@pytest.mark.parametrize("which", MODELS)
+def test_pool_sharded_over_mesh_keeps_parity(request, which, devices8):
     """Pages shard over fsdp, heads over tensor: capacity scales with the
     mesh and greedy decode stays token-identical."""
     from fleetx_tpu.parallel.mesh import build_mesh
 
-    cfg, model, params = small_model
+    cfg, model, params = request.getfixturevalue(which)
     mesh = build_mesh({"fsdp_degree": 2, "mp_degree": 2})
     eng = ServingEngine(
         cfg, params,
@@ -299,10 +322,10 @@ def test_pool_sharded_over_mesh_keeps_parity(small_model, devices8):
         eos_token_id=EOS, mesh=mesh)
     def norm(spec):
         # PartitionSpec canonicalisation may drop trailing Nones
-        return (tuple(spec) + (None,) * 5)[:5]
+        return (tuple(spec) + (None,) * 4)[:4]
 
-    assert norm(eng.pool_k.sharding.spec) == \
-        (None, "fsdp", None, "tensor", None)
+    assert eng.pool_k.shape == (cfg.num_layers, 32, 4, 64)
+    assert norm(eng.pool_k.sharding.spec) == (None, "fsdp", None, "tensor")
     want = one_shot(model, params, [[5, 9, 23, 41], [7, 3]], 6)
     reqs = [eng.submit(p, 6, request_id=f"m{i}")
             for i, p in enumerate([[5, 9, 23, 41], [7, 3]])]
@@ -310,8 +333,7 @@ def test_pool_sharded_over_mesh_keeps_parity(small_model, devices8):
     for req, row in zip(reqs, want):
         check_parity(req, row)
     # the pool stays sharded through the donated-buffer step updates
-    assert norm(eng.pool_k.sharding.spec) == \
-        (None, "fsdp", None, "tensor", None)
+    assert norm(eng.pool_k.sharding.spec) == (None, "fsdp", None, "tensor")
 
 
 def test_registry_sharded_weights_compose_with_sharded_pool(devices8,
@@ -361,11 +383,10 @@ def test_registry_sharded_weights_compose_with_sharded_pool(devices8,
         check_parity(req, row)
 
     def norm(spec):
-        return (tuple(spec) + (None,) * 5)[:5]
+        return (tuple(spec) + (None,) * 4)[:4]
 
     # pool AND weights sharded simultaneously, through the whole run
-    assert norm(eng.pool_k.sharding.spec) == \
-        (None, "fsdp", None, "tensor", None)
+    assert norm(eng.pool_k.sharding.spec) == (None, "fsdp", None, "tensor")
 
 
 # ---------------------------------------------------------------------------
@@ -401,12 +422,13 @@ def test_paged_attention_support_predicate():
     assert PA.paged_attention_supported(**dict(ok), dtype=jnp.bfloat16)
 
 
-def test_kernel_vs_gather_parity_and_compiled_path_pinned(small_model):
+@pytest.mark.parametrize("which", MODELS)
+def test_kernel_vs_gather_parity_and_compiled_path_pinned(request, which):
     """The SAME prompts through a kernel engine and a forced-gather
     engine decode token-identically to the one-shot reference, and the
     jaxpr pins which attention path each engine compiled — a silent
     fallback (predicate regression) fails here, not in a perf chart."""
-    cfg, model, params = small_model
+    cfg, model, params = request.getfixturevalue(which)
     prompts = [[5, 9, 23, 41], [7, 3],
                [11, 2, 8, 4, 19, 33, 7, 6, 1, 2, 3]]  # chunked prefill
     want = one_shot(model, params, prompts, 6)
@@ -433,8 +455,17 @@ def test_kernel_vs_gather_parity_and_compiled_path_pinned(small_model):
     # path pin: exactly the requested attention compiled into decode
     assert "pallas_call" in _decode_jaxpr(eng_k)
     assert "pallas_call" not in _decode_jaxpr(eng_g)
-    # prefill stays gather on BOTH engines (S>1 chunks)
-    assert eng_k._fns["decode"]._cache_size() == 1  # no-retrace pin holds
+    # prefill stays gather on BOTH engines (S>1 chunks); no-retrace pin
+    for eng in (eng_k, eng_g):
+        assert eng._fns["decode"]._cache_size() == 1
+        assert eng._fns["prefill"]._cache_size() == 1
+    # both engines wrote the same rows into ONE pool, and every layer of
+    # it holds its own: reading layer 0 for layer l cannot pass above
+    pool_k, pool_g = np.asarray(eng_k.pool_k), np.asarray(eng_g.pool_k)
+    assert pool_k.shape == (cfg.num_layers, 33, 4, 64)
+    np.testing.assert_allclose(pool_k[:, 1:], pool_g[:, 1:], atol=1e-5)
+    for l in range(1, cfg.num_layers):
+        assert np.abs(pool_k[l, 1:] - pool_k[0, 1:]).max() > 0.1
 
 
 def test_kernel_predicate_rejects_config_and_falls_back(small_model):
@@ -461,13 +492,14 @@ def test_kernel_predicate_rejects_config_and_falls_back(small_model):
     assert "pallas_call" not in _decode_jaxpr(eng)
 
 
-def test_sharded_pool_runs_kernel_path(small_model, devices8):
+@pytest.mark.parametrize("which", MODELS)
+def test_sharded_pool_runs_kernel_path(request, which, devices8):
     """The fsdp/tensor-sharded pool admits the kernel (page and head
     counts divide the mesh) and compiles it — the sharded parity test
     above then covers its token output."""
     from fleetx_tpu.parallel.mesh import build_mesh
 
-    cfg, model, params = small_model
+    cfg, model, params = request.getfixturevalue(which)
     mesh = build_mesh({"fsdp_degree": 2, "mp_degree": 2})
     eng = ServingEngine(
         cfg, params,
@@ -480,6 +512,103 @@ def test_sharded_pool_runs_kernel_path(small_model, devices8):
     eng.run_until_drained()
     check_parity(req, want[0])
     assert "pallas_call" in _decode_jaxpr(eng)
+
+
+def _paged_case(dtype, layers=3, pages=60, page_size=4, heads=4, hd=16,
+                per_req=11):
+    """A pool whose layers differ, five rows: contexts that end inside a
+    page, on a page's edge and past a page group, one inactive row, and a
+    block table with an unallocated tail (null pages)."""
+    rng = np.random.default_rng(3)
+    shape = (layers, pages, page_size, heads * hd)
+    pool_k = jnp.asarray(rng.normal(size=shape), dtype)
+    pool_v = jnp.asarray(rng.normal(size=shape), dtype)
+    q = jnp.asarray(rng.normal(size=(5, heads, hd)), dtype)
+    lens = np.asarray([0, 5, 15, -1, 42], np.int32)
+    tables = np.zeros((5, per_req), np.int32)
+    ids = rng.permutation(np.arange(1, pages))
+    for r, n in enumerate(lens):
+        used = 0 if n < 0 else n // page_size + 1
+        tables[r, :used] = ids[r * per_req:r * per_req + used]
+    return q, pool_k, pool_v, jnp.asarray(tables), jnp.asarray(lens)
+
+
+def _dense_attention(q, pool_k, pool_v, tables, lens, layer):
+    """The gather path itself (``serving/decode.py``) on layer ``layer``,
+    in f32."""
+    from fleetx_tpu.serving.decode import _paged_attention
+
+    B, heads, hd = q.shape
+    f32 = jnp.float32
+    kd = pool_k[layer, tables].reshape(B, -1, heads, hd).astype(f32)
+    vd = pool_v[layer, tables].reshape(B, -1, heads, hd).astype(f32)
+    return _paged_attention(q[:, None].astype(f32), kd, vd,
+                            jnp.maximum(lens, 0)[:, None])[:, 0]
+
+
+@pytest.mark.parametrize("pages_per_step", [1, 2, 8])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_paged_call_reads_the_layer_it_is_given(monkeypatch, dtype,
+                                                pages_per_step):
+    """``_paged_call`` on the whole pool with layer ``l`` gives, to the
+    last bit, what it gives on that layer cut out and handed over as a
+    one-layer pool (the per-layer call the kernel used to get) — for every
+    layer, with one, two and eight pages folded a grid step (11 pages a
+    request: the last group is padded) — and matches the gather's
+    arithmetic; inactive rows come out as exact zeros."""
+    from fleetx_tpu.ops import paged_attention as PA
+
+    monkeypatch.setattr(PA, "_MAX_PAGES_PER_STEP", pages_per_step)
+    q, pool_k, pool_v, tables, lens = _paged_case(dtype)
+    local = PA._localize_tables(tables, 0, pool_k.shape[1])
+    outs = []
+    for l in range(pool_k.shape[0]):
+        whole = PA._paged_call(q, pool_k, pool_v, local, lens, jnp.int32(l))
+        alone = PA._paged_call(q, pool_k[l:l + 1], pool_v[l:l + 1], local,
+                               lens, jnp.int32(0))
+        for a, b in zip(whole, alone):
+            assert float(jnp.abs(a - b).max()) == 0.0, l
+        out = PA.paged_attention(q, pool_k, pool_v, tables, lens,
+                                 jnp.int32(l))
+        assert out.dtype == q.dtype
+        want = _dense_attention(q, pool_k, pool_v, tables, lens, l)
+        active = np.asarray(lens) >= 0
+        tol = 2e-5 if dtype == jnp.float32 else 2e-2
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32)[active], np.asarray(want)[active],
+            atol=tol, rtol=tol)
+        assert not np.asarray(out, np.float32)[~active].any()  # exact 0
+        outs.append(np.asarray(out, np.float32))
+    # the layers' answers differ: no layer stands in for another
+    assert np.abs(outs[1] - outs[0]).max() > 0.1
+    assert np.abs(outs[2] - outs[1]).max() > 0.1
+
+
+@pytest.mark.parametrize("pages_per_step", [1, 8])
+def test_paged_kernel_skips_pages_it_does_not_own(monkeypatch,
+                                                  pages_per_step):
+    """A ``-1`` in the middle of a block table (a page another shard
+    owns) is left out of the softmax, alone in its group or beside valid
+    pages; the (acc, m, l) triples of two disjoint halves of the pages
+    combine to the whole — the cross-shard contract."""
+    from fleetx_tpu.ops import paged_attention as PA
+
+    monkeypatch.setattr(PA, "_MAX_PAGES_PER_STEP", pages_per_step)
+    q, pool_k, pool_v, tables, lens = _paged_case(jnp.float32)
+    local = PA._localize_tables(tables, 0, pool_k.shape[1])
+    col = jnp.arange(local.shape[1])[None, :]
+    layer = jnp.int32(2)
+    parts = [PA._paged_call(q, pool_k, pool_v,
+                            jnp.where(keep, local, -1), lens, layer)
+             for keep in (col % 2 == 0, col % 2 == 1)]
+    m = jnp.maximum(parts[0][1], parts[1][1])
+    num = sum(a * jnp.exp(mi - m)[..., None] for a, mi, _ in parts)
+    den = sum(li * jnp.exp(mi - m) for _, mi, li in parts)
+    got = PA._normalize(num, den, jnp.float32)
+    want = PA.paged_attention(q, pool_k, pool_v, tables, lens, layer)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-6, rtol=2e-6)
 
 
 # ---------------------------------------------------------------------------
