@@ -39,6 +39,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from fleetx_tpu.core import checkpoint as ckpt_lib
 from fleetx_tpu.observability import MemoryMonitor, Observability, flight
 from fleetx_tpu.observability.trace import ProfilerWindow
+from fleetx_tpu.optims.optimizer import global_norm
 from fleetx_tpu.parallel import rules as rules_lib
 from fleetx_tpu.parallel.mesh import build_mesh
 from fleetx_tpu.parallel.sharding import zero_grad_specs, zero_sharding
@@ -540,7 +541,7 @@ class EagerEngine(BasicEngine):
                     updates, new_opt, grad_norm = optimizer.update(
                         grads, opt_state, params)
                 else:
-                    grad_norm = optax.global_norm(grads)
+                    grad_norm = global_norm(grads)
                     updates, new_opt = optimizer.update(
                         grads, opt_state, params, grad_norm=grad_norm)
                 if opt_dev_shardings is not None:  # device -> host
@@ -1429,6 +1430,7 @@ class EagerEngine(BasicEngine):
                         "lr": float(host_metrics.get("lr", 0.0)),
                     }
                     self.module.training_step_end(log_dict)
+                    self.module.record_step_metrics(host_metrics)
                     self._emit_train_record(log_dict, host_metrics)
                     if res.guard is not None:
                         fin = host_metrics.get("finite")

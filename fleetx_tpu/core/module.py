@@ -72,6 +72,12 @@ class BasicModule:
             log_dict.get("epoch", 0), log_dict["batch"], log_dict["loss"],
             log_dict.get("train_cost", 0.0))
 
+    def record_step_metrics(self, host_metrics: dict) -> None:
+        """What a family counts per step beyond the loss (expert loads, a
+        second loss): the step's metrics are on the host already, so a
+        module writes them into the ``MetricsRegistry`` here. Nothing by
+        default."""
+
     def validation_step_end(self, log_dict: dict) -> None:
         logger.info(
             "[eval] epoch: %d, batch: %d, loss: %.9f, avg_eval_cost: %.5f sec",

@@ -40,6 +40,11 @@ def get_registry():
         modules["ImagenModule"] = ImagenModule
     except ImportError:
         pass
+    try:
+        from fleetx_tpu.models.mla_moe.module import MLAMoEModule
+        modules["MLAMoEModule"] = MLAMoEModule
+    except ImportError:
+        pass
     return modules
 
 

@@ -1,14 +1,23 @@
-"""Mixture-of-Experts FFN with expert parallelism — beyond the reference.
+"""Mixture-of-Experts FFN for the GPT family — beyond the reference.
 
-FleetX has no expert parallelism anywhere (SURVEY.md §2.3: "EP/MoE absent");
-this is the stretch capability the TPU build adds. GShard/Switch-style
-top-k routing expressed entirely as dense einsums over a capacity-bounded
-dispatch tensor, so GSPMD shards it like any other computation:
+FleetX has no expert parallelism anywhere (SURVEY.md §2.3: "EP/MoE absent").
+This is the GPT block's own expert layer (``Model.moe_num_experts > 0``):
+GShard/Switch-style top-k routing expressed entirely as dense einsums over
+a capacity-bounded dispatch tensor, so GSPMD shards it like any other
+computation. It is the small-scale layer: the ``[tokens, experts,
+capacity]`` dispatch grows with all three and overflow is DROPPED. A model
+whose experts outnumber the chips, that routes without drops or that holds
+a share of its experts uses ``models/mla_moe/moe.py`` instead (sorted rows,
+grouped products over the experts held, a sigmoid router with a
+load-stepped bias, a shared expert); the two layers share nothing but the
+``expert`` logical axis.
 
-- expert weights carry the ``expert`` logical axis (→ ``tensor`` mesh axis
-  by default): expert parallelism rides the same high-bandwidth ICI ring as
-  Megatron TP, and the dispatch/combine einsums become the all-to-alls.
-- the router runs in f32 and is replicated (it is tiny).
+- expert weights carry the ``expert`` logical axis, which the layout maps
+  to the ``tensor`` mesh axis (``parallel/rules.py:SpecLayout``; ``mlp``
+  maps there too, so on an expert leaf ``expert`` takes it and ``mlp``
+  replicates): the dispatch/combine einsums become the all-to-alls.
+- the router runs in f32; its kernel is ``[embed, experts]`` with no
+  sharded dim below ZeRO stage 3 (``gpt_moe`` rule ``mlp/router_kernel``).
 - the load-balance auxiliary loss (Switch: ``E * Σ_e f_e·P_e``) is sown
   into the ``losses`` collection; ``GPTModule.training_loss`` adds it,
   eval ignores it.

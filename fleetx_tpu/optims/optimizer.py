@@ -16,7 +16,12 @@ so the interesting parts are:
   for the ``grad_norm`` metric.  ``clip_by_precomputed_norm`` accepts the
   norm as an optax extra arg so the caller threads ONE reduction through
   metric + clip; ``adamw(fused_clip=True)`` goes further and owns the norm
-  itself, returning ``(updates, opt_state, grad_norm)`` from ``update``.
+  itself, returning ``(updates, opt_state, grad_norm)`` from ``update``;
+- leaves moved by the step's load and not by Adam (``LOAD_STEPPED``: a
+  sparse-expert router's selection bias): their "gradient" is the experts'
+  load less its mean (``models/mla_moe/moe.py`` hands it over as the
+  leaf's cotangent), their update ``-rate * sign`` of it, and they stay
+  out of the global norm that the clip and the ``grad_norm`` metric use.
 """
 
 from __future__ import annotations
@@ -31,6 +36,46 @@ NO_DECAY_SUBSTRINGS = ("bias", "norm", "layernorm")
 NO_DECAY_EXACT = ("ln", "ln1", "ln2", "ln_f")
 
 
+#: last path key of a leaf whose update is ``-rate * sign(load excess)``
+LOAD_STEPPED = "selection_bias"
+
+
+def _keys(path: tuple) -> list:
+    return [str(getattr(p, "key", getattr(p, "name", p))) for p in path]
+
+
+def is_load_stepped_path(path: tuple) -> bool:
+    """True for a leaf the step's load moves (its last key is
+    ``LOAD_STEPPED``), wherever it sits in params or optimizer state."""
+    return bool(path) and _keys(path)[-1] == LOAD_STEPPED
+
+
+def global_norm(grads: Any) -> jax.Array:
+    """``optax.global_norm`` over the leaves that carry gradients: a
+    load-stepped leaf's cotangent is a count of rows, not a gradient."""
+    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+    return optax.global_norm([g for path, g in flat
+                              if not is_load_stepped_path(path)])
+
+
+def load_step(inner: optax.GradientTransformation,
+              rate: float) -> optax.GradientTransformationExtraArgs:
+    """``inner`` for every leaf but the load-stepped ones, whose update is
+    ``-rate * sign(incoming)``: an expert that took more than the mean
+    load has its selection bias lowered by ``rate``, one that took less
+    raised (the auxiliary-loss-free balancing of Wang et al. 2024)."""
+    inner = optax.with_extra_args_support(inner)
+
+    def update(grads, state, params=None, **extra):
+        updates, state = inner.update(grads, state, params, **extra)
+        updates = jax.tree_util.tree_map_with_path(
+            lambda path, u, g: (-rate * jnp.sign(g)).astype(u.dtype)
+            if is_load_stepped_path(path) else u, updates, grads)
+        return updates, state
+
+    return optax.GradientTransformationExtraArgs(inner.init, update)
+
+
 def is_no_decay_path(path: tuple) -> bool:
     """True if a param path should be excluded from weight decay.
 
@@ -38,8 +83,7 @@ def is_no_decay_path(path: tuple) -> bool:
     (``optimizer.py:100-105``) — applied to flax param tree paths. Norm params
     are named ``scale``/``bias`` under ``ln*`` modules here.
     """
-    keys = [getattr(p, "key", getattr(p, "name", str(p))).lower() for p in path]
-    for k in keys:
+    for k in (k.lower() for k in _keys(path)):
         if any(tok in k for tok in NO_DECAY_SUBSTRINGS) or k in NO_DECAY_EXACT:
             return True
     return False
@@ -70,7 +114,7 @@ def clip_by_precomputed_norm(max_norm: float) -> optax.GradientTransformationExt
     def update(updates, state, params=None, *, grad_norm=None, **extra):
         """Clip by ``grad_norm`` when threaded in, else compute the norm."""
         del params, extra
-        g_norm = optax.global_norm(updates) if grad_norm is None else grad_norm
+        g_norm = global_norm(updates) if grad_norm is None else grad_norm
         # stock optax semantics: scale only when the norm exceeds the cap,
         # propagating NaN norms into the updates (the engine's finite-guard
         # then skips the step)
@@ -104,7 +148,7 @@ class FusedClipOptimizer:
 
     def update(self, grads, opt_state, params=None):
         """One norm reduction: clip with it, return it with the updates."""
-        grad_norm = optax.global_norm(grads)
+        grad_norm = global_norm(grads)
         updates, new_state = self._inner.update(
             grads, opt_state, params, grad_norm=grad_norm)
         return updates, new_state, grad_norm
@@ -113,7 +157,8 @@ class FusedClipOptimizer:
 def adamw(learning_rate, *, beta1: float = 0.9, beta2: float = 0.999,
           epsilon: float = 1e-8, weight_decay: float = 0.01,
           grad_clip: float | None = 1.0,
-          multi_precision: bool = True, fused_clip: bool = False):
+          multi_precision: bool = True, fused_clip: bool = False,
+          selection_bias_rate: float = 0.001):
     """AdamW + global-norm clip + name-based decay mask.
 
     The decay mask is computed lazily from the param tree at ``init`` time via
@@ -121,6 +166,8 @@ def adamw(learning_rate, *, beta1: float = 0.9, beta2: float = 0.999,
     any model family.  ``fused_clip=True`` returns a ``FusedClipOptimizer``
     whose ``update`` is ``(updates, opt_state, grad_norm)`` — the single-pass
     norm owned by the optimizer instead of threaded in by the caller.
+    ``selection_bias_rate`` is the step of the load-stepped leaves
+    (``load_step``); a tree that has none is untouched by it.
     """
     chain = []
     if grad_clip is not None and grad_clip > 0:
@@ -131,7 +178,7 @@ def adamw(learning_rate, *, beta1: float = 0.9, beta2: float = 0.999,
     if weight_decay:
         chain.append(optax.add_decayed_weights(weight_decay, mask=decay_mask))
     chain.append(optax.scale_by_learning_rate(learning_rate))
-    tx = optax.chain(*chain)
+    tx = load_step(optax.chain(*chain), selection_bias_rate)
     return FusedClipOptimizer(tx) if fused_clip else tx
 
 
@@ -179,6 +226,7 @@ def build_optimizer(cfg: dict, lr_schedule) -> optax.GradientTransformation:
             grad_clip=clip_norm,
             multi_precision=bool(cfg.get("multi_precision", True)),
             fused_clip=fused,
+            selection_bias_rate=float(cfg.get("selection_bias_rate", 0.001)),
         )
     return sgd(lr_schedule, momentum=float(cfg.get("momentum", 0.9)),
                grad_clip=clip_norm, fused_clip=fused)

@@ -11,7 +11,7 @@ stage shards which term, and the serving KV pool hand-wired its own
 hardware. Here the whole mapping is *data*:
 
 - ``PARTITION_RULES``: per model family (``gpt``, ``gpt_moe``,
-  ``gpt_lora``, ``vision``, ``ernie``, ``imagen``, plus the serving KV
+  ``gpt_lora``, ``vision``, ``ernie``, ``imagen``, ``mla_moe``, plus the serving KV
   pool as ``serving_kv``), an
   ORDERED tuple of ``(regex, logical-axes template)`` rules matched against
   slash-joined parameter-tree paths, first match wins — the
@@ -300,6 +300,26 @@ PARTITION_RULES: dict[str, tuple] = {
     "imagen": (
         (r".", REPLICATED),
     ),
+    # latent-attention sparse-expert decoders (models/mla_moe): low-rank
+    # query and key-value projections whose rank dims replicate, heads
+    # over the Megatron axis, and an expert layer that holds a share of
+    # the experts (the held experts over ``expert``; the router and the
+    # selection bias span ALL experts and replicate)
+    "mla_moe": (
+        (r"attn/(q_a|kv_a)$", ("embed", None)),
+        (r"attn/(q_b|kv_b)$", (None, "heads", "kv")),
+        (r"attn/out$", ("heads", "kv", "embed")),
+        (r"attn/(q_norm|kv_norm)$", ("norm",)),
+        (r"(mlp/|moe/shared_)(gate|up)$", ("embed", "mlp")),
+        (r"(mlp/|moe/shared_)down$", ("mlp", "embed")),
+        (r"moe/router$", ("embed", None)),
+        (r"moe/selection_bias$", (None,)),
+        (r"moe/experts_(gate|up)$", ("expert", "embed", None)),
+        (r"moe/experts_down$", ("expert", None, "embed")),
+        (r"(embed/tokens|head/kernel)$", ("vocab", "embed")),
+        (r"mtp/proj$", (None, "embed")),
+        (r"(^|/)\w*norm/scale$", ("norm",)),
+    ),
     # the serving KV page pool (serving/paged_cache.py): pages over the
     # ZeRO axis (capacity scales with fsdp), heads over the Megatron axis
     # (heads and head_dim share the pool's minor dim, heads major)
@@ -317,6 +337,7 @@ STACK_MARKERS: dict[str, str] = {
     "gpt_lora": r"(^|/)layers/",
     "vision": r"(^|/)blocks/",
     "ernie": r"(^|/)layers/",
+    "mla_moe": r"(^|/)(dense_layers|moe_layers|mtp/layers)/",
 }
 
 #: families whose fully-replicated leaves are accepted at ANY size by the

@@ -103,6 +103,9 @@ def _sample_batch(module: Any, family: str) -> dict:
         s = int(module.model_cfg.max_position_embeddings)
         tok = np.zeros((1, s), np.int32)
         return {"tokens": tok, "position_ids": tok.copy()}
+    if family == "mla_moe":
+        tok = np.zeros((1, int(module.tokens_per_sample)), np.int32)
+        return {"tokens": tok, "position_ids": tok.copy()}
     if family == "ernie":
         s = int(module.model_cfg.max_position_embeddings)
         ids = np.zeros((1, s), np.int32)

@@ -229,3 +229,49 @@ def test_serving_programs_keep_the_pool_one_buffer(topo, monkeypatch, dist,
         layer_sized = ",".join(map(str, local[1:]))
         assert not re.search(
             r"= \w+\[(?:1,)?%s\]" % layer_sized, hlo), name
+
+
+def test_latent_attention_and_grouped_kernels_compile_for_the_v5e(
+        topo, monkeypatch):
+    """The kernels of the latent-attention sparse-expert family at the
+    widths of ``joyai-flash-train-b2s8192`` (128 + 64 scores, 128 values,
+    8,192 positions; 256-row tiles of 2,048 x 1,536 expert products)
+    through the chip's own Mosaic compiler: VMEM, tiling and the aliased
+    float32 accumulator are accepted, and the trace will find them by name."""
+    from jax.sharding import SingleDeviceSharding
+
+    from fleetx_tpu.ops import grouped_matmul, mla_attention
+
+    monkeypatch.setattr(ops, "interpret", lambda: False)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    heads, seq = 4, 8192
+    attn = jax.jit(jax.grad(
+        lambda *a: mla_attention.mla_flash_attention(
+            *a, scale=192 ** -0.5).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2, 3, 4))).lower(
+            arr((1, heads, seq, 128)), arr((1, heads // 2, seq, 128)),
+            arr((1, heads, seq, 128)), arr((1, seq, 64)),
+            arr((1, heads, seq, 128))).compile().as_text()
+    for name in ("mla_flash_fwd", "mla_flash_bwd_dq", "mla_flash_bwd_dkv"):
+        assert name in attn, name
+
+    rows, tile, held, h, f2 = 4096, 256, 16, 2048, 1536
+
+    def expert_products(xs, dy, w, acc, experts, n):
+        out = grouped_matmul.moe_gmm(xs, w, experts, n, tile=tile,
+                                     out_dtype=jnp.float32)
+        dxs = grouped_matmul.moe_gmm(dy, w, experts, n, tile=tile,
+                                     transpose_rhs=True)
+        return out, dxs, grouped_matmul.moe_tgmm(xs, dy, acc, experts, n,
+                                                 tile=tile)
+
+    text = jax.jit(expert_products, donate_argnums=(3,)).lower(
+        arr((rows, h)), arr((rows, f2)), arr((held, h, f2)),
+        arr((held, h, f2), jnp.float32), arr((rows // tile,), jnp.int32),
+        arr((), jnp.int32)).compile().as_text()
+    for name in ("moe_gmm", "moe_gmm_t", "moe_tgmm"):
+        assert name in text, name
