@@ -180,6 +180,9 @@ SERVING_METRIC_NAMES = (
     "serving_prefill_wait", "serving_prefill_run",
     "serving_queue_depth", "serving_active_requests",
     "serving_page_occupancy", "serving_kv_fragmentation",
+    # of the page groups in the block table, the share the last decode
+    # call's kernel folded (ops/paged_attention.py:page_groups_walked)
+    "serving_page_walk_share",
     "serving_requests_total", "serving_requests_completed",
     "serving_requests_refused", "serving_tokens_total",
     # deadline-admission plane (docs/serving.md "Fault tolerance"):

@@ -1,18 +1,19 @@
 """Pallas paged-attention decode kernel: block tables walked in-kernel,
-the KV pool read by layer index.
+as far as each row's own context reaches; the KV pool read by layer index.
 
 The kernel takes the WHOLE pool ``[layers, pages, page_size, heads·head_dim]``
-and never a layer of it: the layer index and the per-request page ids
-arrive as **scalar prefetch** operands (``pltpu.PrefetchScalarGridSpec``),
-and the K/V BlockSpec index maps read them to DMA page ``(layer,
-table[b, p])`` of the pool directly. So the serving layer scan can carry
-the pool as one buffer (``serving/decode.py``): no layer slice is cut out
-in front of the kernel, and no dense page view ``pool[layer, block_tables]
-→ [B, pages_per_req·page_size, heads, head_dim]`` materialises either. An
+and never a layer of it: both pools stay where they are (``pl.ANY``), the
+layer index and the per-request page ids arrive as **scalar prefetch**
+operands (``pltpu.PrefetchScalarGridSpec``), and the kernel copies page
+``(layer, table[b, p])`` of the pool into VMEM itself
+(``pltpu.make_async_copy``). So the serving layer scan can carry the pool
+as one buffer (``serving/decode.py``): no layer slice is cut out in front
+of the kernel, and no dense page view ``pool[layer, block_tables] → [B,
+pages_per_req·page_size, heads, head_dim]`` materialises either. An
 online-softmax accumulator in f32 VMEM scratch (the
 ``ops/flash_attention.py`` m/l/acc discipline) folds the pages into the
-output without ever holding more than ``pages_per_step`` ``[page_size,
-head_block·head_dim]`` tiles of K/V live.
+output without ever holding more than two slots of ``pages_per_step``
+``[page_size, head_block·head_dim]`` tiles of K and of V live.
 
 Why heads and head_dim are ONE minor dim: a TPU buffer is tiled (8, 128)
 over its two minor dims, so a 64-wide ``head_dim`` minor either pads every
@@ -31,16 +32,27 @@ over its own head's lanes alone, and ``P · V`` yields every head's
 probabilities against every head's values, of which the finish keeps the
 diagonal blocks.
 
-Grid: ``(batch, head-block, page-group)`` with the page walk innermost so
-the accumulators (index-map invariant over the page dim) stay
-VMEM-resident across the whole walk and are flushed once. Each grid step
-folds ``pages_per_step`` pages: the pool is handed to the call that many
-times, each operand's index map reading its own column of the block
-table, so a step's DMAs amortise the fixed cost of a grid step. Null
-pages (``NULL_PAGE``), pages past a request's allocation (lazy lifecycle:
-block-table tails), and key positions beyond the query's ``lens`` are
-all masked in-kernel — callers hand the raw block tables over and the
-wrapper rewrites invalid entries to ``-1`` (the kernel's skip sentinel).
+Grid: ``(batch, head-block)``, ONE grid step a row, and inside it a loop
+whose length is read from the row's own ``lens``: row *b* folds
+``page_groups_walked(lens[b])`` groups of ``pages_per_step`` pages — the
+groups up to the one that holds its query position — and stops; an
+inactive row (``lens < 0``) folds none. Nothing past a row's query
+position is fetched, indexed or stepped over, so the kernel's time follows
+the batch's live context and not the width of the block table (a
+rectangular ``(batch, head-block, page-group)`` grid pays ~0.8 µs for
+every step of every row, whether it fetches or not: PERF.md, PR 30). A
+fold starts the next group's copies (two VMEM slots a pool, one DMA
+semaphore a pool and slot) before it waits for its own, and a row's last
+fold starts the first group of the next row that has a context: the grid
+runs in order (``"arbitrary"``) and a two-word SMEM note carries what is
+in flight from one grid step to the next. Null pages (``NULL_PAGE``),
+pages past a request's allocation (lazy lifecycle: block-table tails) and
+pages another shard owns — holes that can lie BELOW ``lens`` — are
+masked in-kernel, as are key positions beyond ``lens``: callers hand the
+raw block tables over, the wrapper rewrites invalid entries to ``-1``
+(the kernel's skip sentinel), and a page that does not count is never
+read: its copy fetches local page 0 instead, so stale or non-finite
+pages past the query cannot reach the accumulator.
 
 Contract mirrors ``ops/flash_attention.py`` exactly:
 
@@ -66,6 +78,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -79,8 +92,8 @@ _NEG_INF = -1e30
 #: (pinned by a test; importing it here would cycle ops ← serving ← ops).
 NULL_PAGE = 0
 
-#: per-grid-step live VMEM budget for the kernel's K/V page tiles
-#: (double-buffered) plus the block-diagonal query and the f32
+#: live VMEM budget of a grid step: the kernel's K/V page tiles (two
+#: slots a pool) plus the block-diagonal query and the f32
 #: accumulator/m/l scratch. It bounds ``pages_per_step`` for wide models
 #: and rejects only pathological page_size × head_dim configs.
 _PAGED_VMEM_BUDGET_BYTES = 4 * 1024 * 1024
@@ -90,10 +103,14 @@ _PAGED_VMEM_BUDGET_BYTES = 4 * 1024 * 1024
 #: stays narrow; decode attention is DMA-bound and the MXU otherwise idle
 _MAX_HEAD_BLOCK = 16
 
-#: most pages folded in one grid step: a step costs ~0.35 µs whatever it
-#: moves, a 16 × 1024 bf16 page tile 0.04 µs of HBM time. 24 layer calls
-#: at the 345M serving geometry, 1 / 2 / 4 / 8 / 16 pages a step: 35.1 /
-#: 23.1 / 16.7 / 14.2 / 13.2 ms on the v5e (PERF.md, PR 28)
+#: most pages in one fold. A fold's fixed cost (a loop step, 2 · pages
+#: copies started and waited for) is spread over its pages; the pages of a
+#: row's last group that lie past its query are fetched for nothing. 24
+#: layer calls at the 345M serving geometry on the v5e, 64 rows of 128–767
+#: tokens, 1 / 2 / 4 / 8 / 16 pages a fold: 22.5 / 13.2 / 8.7 / 7.1 / 7.2
+#: ms (6.2 / 5.9 at 8 / 16 once a row's last fold starts the next row's
+#: first copies; the rectangular grid of PR 28 took 13.5; PERF.md, PR 30).
+#: 8 and not 16: the folds then run in the order they always have
 _MAX_PAGES_PER_STEP = 8
 
 
@@ -117,10 +134,10 @@ def pick_head_block(num_heads: int, head_dim: int,
 
 def _step_vmem_bytes(pages: int, page_size: int, hb: int, head_dim: int,
                      dtype: Any) -> int:
-    """Live VMEM of one grid step folding ``pages`` pages."""
+    """Live VMEM of one grid step folding ``pages`` pages a fold."""
     esize = jnp.dtype(dtype).itemsize
     width = hb * head_dim
-    tiles = 2 * 2 * pages * page_size * width * esize   # K+V, two buffers
+    tiles = 2 * 2 * pages * page_size * width * esize   # K+V, two slots
     scratch = hb * width * (esize + 4) + 2 * hb * 128 * 4  # (hb, 1) pads
     return tiles + scratch
 
@@ -128,9 +145,9 @@ def _step_vmem_bytes(pages: int, page_size: int, hb: int, head_dim: int,
 def pick_pages_per_step(*, num_heads: int, head_dim: int, page_size: int,
                         pages_per_req: int,
                         dtype: Any = jnp.float32) -> int:
-    """Pages one grid step folds: the most (a power of two ≤
-    `_MAX_PAGES_PER_STEP`, no more than a request has) whose tiles fit
-    the VMEM budget; 0 when not even one page does."""
+    """Pages one fold takes: the most (a power of two ≤
+    `_MAX_PAGES_PER_STEP`, no more than a request has) whose two slots a
+    pool fit the VMEM budget; 0 when not even one page does."""
     hb = pick_head_block(num_heads, head_dim, dtype)
     if hb == 0:
         return 0
@@ -139,6 +156,16 @@ def pick_pages_per_step(*, num_heads: int, head_dim: int, page_size: int,
             g, page_size, hb, head_dim, dtype) > _PAGED_VMEM_BUDGET_BYTES):
         g //= 2
     return g
+
+
+def page_walk_shape(*, num_heads: int, head_dim: int, page_size: int,
+                    pages_per_req: int, dtype: Any = jnp.float32) -> tuple:
+    """``(tokens one fold covers, folds a whole table row takes)`` for a
+    geometry the kernel admits: what `page_groups_walked` counts in."""
+    g = pick_pages_per_step(num_heads=num_heads, head_dim=head_dim,
+                            page_size=page_size, pages_per_req=pages_per_req,
+                            dtype=dtype)
+    return g * page_size, -(-pages_per_req // g)
 
 
 def paged_attention_supported(*, num_heads: int, head_dim: int,
@@ -181,30 +208,59 @@ def paged_sharded_supported(mesh: Any, *, num_heads: int,
         num_heads % shape.get("tensor", 1) == 0
 
 
-def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, *refs,
-                   pages: int, page_size: int, head_dim: int, scale: float):
-    """One (request, head-block, page-group) step of the online-softmax
-    walk over ``pages`` pages.
+def page_groups_walked(lens: Any, group_tokens: int, table_groups: int):
+    """Page groups the kernel folds for query positions ``lens``: the
+    groups up to and including the one that holds the query, none for an
+    inactive row (``lens < 0``), never more than the table has. The
+    kernel's trip count (a traced scalar) and the engine's
+    ``serving_page_walk_share`` gauge (its host copy of the lengths, a
+    NumPy array) both come from here."""
+    xp = np if isinstance(lens, np.ndarray) else jnp
+    return xp.where(lens < 0, 0,
+                    xp.minimum(lens // group_tokens + 1, table_groups))
+
+
+def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                   acc_out_ref, m_out_ref, l_out_ref,
+                   k_buf, v_buf, sems, ahead_ref, qd_ref, acc_ref, m_ref,
+                   l_ref, *, pages: int, page_size: int, head_dim: int,
+                   scale: float):
+    """One (request, head-block) grid step: the online-softmax walk over
+    the page groups this request's context reaches, ``pages`` pages a
+    fold, and no further.
 
     ``tables_ref``/``lens_ref``/``layer_ref`` are the scalar-prefetch
-    operands (SMEM); the layer is consumed by the K/V index maps alone, so
-    the ``pages`` K refs and ``pages`` V refs in ``refs`` already hold
-    that layer's pages ``[1, page_size, hb·hd]``. A table entry < 0 marks
-    an invalid page — null, beyond the request's lazy allocation, or
-    owned by another shard: its positions are masked, and a step whose
-    pages are all invalid or past the query is skipped entirely (the
-    DMAs still land, on local page 0, but are never folded in). After
-    them come the outputs (the f32 numerator ``[1, 1, hb·hd]``, m and l
-    ``[1, hb, 1]``) and the scratch (the block-diagonal query, the
-    ``[hb, hb·hd]`` accumulator, m, l).
+    operands (SMEM). ``k_hbm``/``v_hbm`` are the whole pools, left where
+    they are (``pl.ANY``): the kernel copies page ``(layer, table[b, c])``
+    into one of two VMEM slots a pool (``k_buf``/``v_buf`` ``[2,
+    pages·page_size, hb·hd]``, one DMA semaphore a pool and slot in
+    ``sems``) and starts the next group's copies before it waits for this
+    group's; a row's last fold starts the first group of the next row
+    that has a context, and says so in ``ahead_ref`` (SMEM: whether the
+    step's first group is in flight, and in which slot), which is why the
+    grid runs in order. The walk is ``page_groups_walked(lens[b])`` folds
+    long. A table entry < 0 marks an invalid page — null, beyond the
+    request's lazy allocation, or owned by another shard: it and every
+    page that starts past the query are not counted, their positions are
+    masked and their copies read local page 0 in their place; a group
+    with no counted page is not folded. Outputs: the f32 numerator ``[1,
+    1, hb·hd]``, m and l ``[1, hb, 1]``; the remaining scratch is the
+    block-diagonal query, the ``[hb, hb·hd]`` accumulator, m and l.
     """
-    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
-    (acc_out_ref, m_out_ref, l_out_ref,
-     qd_ref, acc_ref, m_ref, l_ref) = refs[2 * pages:]
     b = pl.program_id(0)
-    p = pl.program_id(2)
-    np_ = pl.num_programs(2)
+    h = pl.program_id(1)
+    rows = pl.num_programs(0)
+    head_blocks = pl.num_programs(1)
     hb, width = acc_ref.shape
+    span = pages * page_size
+    q_pos = lens_ref[b]
+    layer = layer_ref[0]
+
+    def folds(row):
+        return page_groups_walked(lens_ref[row], span,
+                                  tables_ref.shape[1] // pages)
+
+    n_groups = folds(b)
 
     def own_lanes():
         # row h of a [hb, hb·hd] block owns lanes [h·hd, (h+1)·hd)
@@ -212,65 +268,123 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, *refs,
         row = jax.lax.broadcasted_iota(jnp.int32, (hb, width), 0)
         return (lane >= row * head_dim) & (lane < (row + 1) * head_dim)
 
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def group_pages(row, grp):
+        # per page of a group: does it count — valid, and starting at or
+        # before the query, so one counted page ⇒ one unmasked score —
+        # and the pool page its copies read (local page 0 if it does not).
+        # (lax by name: a traced operator costs several times a bind, and
+        # this runs for every page at every place that starts a group)
+        first = grp * pages
+        reach = (lens_ref[row] - grp * span) // page_size  # last page ≤ query
+        ids = [tables_ref[row, first + j] for j in range(pages)]
+        ok = [jax.lax.bitwise_and(jax.lax.ge(ids[j], 0), jax.lax.ge(reach, j))
+              for j in range(pages)]
+        zero = jax.lax.full_like(reach, 0)
+        return ok, [jax.lax.select(ok[j], ids[j], zero) for j in range(pages)]
+
+    def page_copies(slot, head_block, page_ids):
+        # the 2 · pages copies that fill ``slot``: pool page
+        # ``page_ids[j]`` lands in rows [j·ps, (j+1)·ps)
+        lanes = pl.ds(pl.multiple_of(head_block * width, width), width)
+        return [pltpu.make_async_copy(
+            pool.at[layer, page_ids[j], :, lanes],
+            buf.at[slot, pl.ds(j * page_size, page_size)], sems.at[i, slot])
+            for j in range(pages)
+            for i, (pool, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf)))]
+
+    def start(row, head_block, grp, slot):
+        for c in page_copies(slot, head_block, group_pages(row, grp)[1]):
+            c.start()
+
+    @pl.when((b == 0) & (h == 0))
+    def _nothing_ahead():
+        ahead_ref[0] = 0
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(n_groups > 0)
+    def _walk():
+        in_flight = ahead_ref[0] == 1
+        first_slot = jnp.where(in_flight, ahead_ref[1], 0)
+
+        @pl.when(jnp.logical_not(in_flight))
+        def _first_group():
+            start(b, h, 0, 0)
+
+        # the grid step after this one that has a context — this row's
+        # next head block, else the next active row — gets its first
+        # group started by this step's last fold
+        same = h + 1 < head_blocks
+        next_row = jnp.where(same, b, jax.lax.while_loop(
+            lambda r: (r < rows) & (folds(jnp.minimum(r, rows - 1)) == 0),
+            lambda r: r + 1, b + 1))
+        next_head = jnp.where(same, h + 1, 0)
+        ahead_ref[0] = (next_row < rows).astype(jnp.int32)
+        ahead_ref[1] = (first_slot + n_groups) % 2
+
         # (select in f32: Mosaic has no relayout for a 2-byte select
         # against the broadcast row)
         q = jnp.broadcast_to(q_ref[0].astype(jnp.float32), (hb, width))
         qd_ref[...] = jnp.where(own_lanes(), q, 0.0).astype(qd_ref.dtype)
 
-    q_pos = lens_ref[b]
-    base = p * pages * page_size
-    # page j of this step counts when it is valid and starts at or before
-    # the query; at least one counted page ⇒ at least one unmasked score
-    counted = [(tables_ref[b, p * pages + j] >= 0)
-               & (base + j * page_size <= q_pos) for j in range(pages)]
-    run = functools.reduce(jnp.logical_or, counted) & (q_pos >= 0)
+        def fold(grp, _):
+            slot = (first_slot + grp) % 2
+            last = grp + 1 == n_groups
 
-    @pl.when(run)
-    def _compute():
-        # Heads share the lanes of a K/V row, so the MXU separates them:
-        # row h of the block-diagonal query is zero outside head h's
-        # lanes, and contracting it against a key row's lanes is head h's
-        # dot product alone. f32 pools take the multi-pass product.
-        exact = jax.lax.Precision.HIGHEST
-        k = jnp.concatenate([r[0] for r in k_refs], axis=0)  # [g·ps, hb·hd]
-        s = jax.lax.dot_general(
-            qd_ref[...], k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=exact if k.dtype == jnp.float32 else None) * scale
-        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        page_ok = counted[0].astype(jnp.int32)     # per key: its page counts
-        for j in range(1, pages):
-            page_ok = jnp.where(col >= j * page_size,
-                                counted[j].astype(jnp.int32), page_ok)
-        s = jnp.where((page_ok > 0) & (base + col <= q_pos), s,
-                      _NEG_INF)                            # [hb, g·ps]
-        m_prev = m_ref[...]                                # [hb, 1]
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        pexp = jnp.exp(s - m_new)
-        l_ref[...] = l_ref[...] * alpha + pexp.sum(axis=1, keepdims=True)
-        m_ref[...] = m_new
-        v = jnp.concatenate([r[0] for r in v_refs],
-                            axis=0).astype(jnp.float32)    # [g·ps, hb·hd]
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            pexp, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=exact)
+            @pl.when(jnp.logical_not(last) | (next_row < rows))
+            def _next_group():
+                start(jnp.where(last, next_row, b),
+                      jnp.where(last, next_head, h),
+                      jnp.where(last, 0, grp + 1), 1 - slot)
 
-    @pl.when(p == np_ - 1)
-    def _finish():
-        # keep each head's own block of its accumulator row; m/l laid out
-        # [B, nh, 1]: a (hb, 1) store satisfies Mosaic's last-two-dims
-        # tiling where a 2D (1, hb) block does not — the flash kernel's
-        # lse idiom.
-        acc_out_ref[0] = jnp.where(own_lanes(), acc_ref[...], 0.0).sum(
-            axis=0, keepdims=True)
-        m_out_ref[0] = m_ref[...]
-        l_out_ref[0] = l_ref[...]
+            for c in page_copies(slot, 0, [0] * pages):  # a wait reads sizes
+                c.wait()
+            ok = group_pages(b, grp)[0]
+
+            @pl.when(functools.reduce(jnp.logical_or, ok))
+            def _compute():
+                # Heads share the lanes of a K/V row, so the MXU separates
+                # them: row h of the block-diagonal query is zero outside
+                # head h's lanes, and contracting it against a key row's
+                # lanes is head h's dot product alone. f32 pools take the
+                # multi-pass product.
+                exact = jax.lax.Precision.HIGHEST
+                k = k_buf[slot]                            # [g·ps, hb·hd]
+                s = jax.lax.dot_general(
+                    qd_ref[...], k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                    precision=exact if k.dtype == jnp.float32 else None
+                ) * scale
+                col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                page_ok = ok[0].astype(jnp.int32)  # per key: its page counts
+                for j in range(1, pages):
+                    page_ok = jnp.where(col >= j * page_size,
+                                        ok[j].astype(jnp.int32), page_ok)
+                s = jnp.where((page_ok > 0) & (grp * span + col <= q_pos),
+                              s, _NEG_INF)                 # [hb, g·ps]
+                m_prev = m_ref[...]                        # [hb, 1]
+                m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                pexp = jnp.exp(s - m_new)
+                l_ref[...] = l_ref[...] * alpha + pexp.sum(axis=1,
+                                                           keepdims=True)
+                m_ref[...] = m_new
+                v = v_buf[slot].astype(jnp.float32)        # [g·ps, hb·hd]
+                acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+                    pexp, v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32, precision=exact)
+
+        jax.lax.fori_loop(0, n_groups, fold, None)
+
+    # keep each head's own block of its accumulator row; m/l laid out
+    # [B, nh, 1]: a (hb, 1) store satisfies Mosaic's last-two-dims tiling
+    # where a 2D (1, hb) block does not — the flash kernel's lse idiom.
+    acc_out_ref[0] = jnp.where(own_lanes(), acc_ref[...], 0.0).sum(
+        axis=0, keepdims=True)
+    m_out_ref[0] = m_ref[...]
+    l_out_ref[0] = l_ref[...]
 
 
 def _paged_call(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
@@ -289,38 +403,37 @@ def _paged_call(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     B, nh, hd = q.shape
     ps = pool_k.shape[2]
     hb = pick_head_block(nh, hd, pool_k.dtype)
-    g = pick_pages_per_step(num_heads=nh, head_dim=hd, page_size=ps,
-                            pages_per_req=tables.shape[1],
-                            dtype=pool_k.dtype)
+    span, groups = page_walk_shape(
+        num_heads=nh, head_dim=hd, page_size=ps,
+        pages_per_req=tables.shape[1], dtype=pool_k.dtype)
+    g = span // ps
     # whole page groups: the padding columns are invalid pages
-    tables = jnp.pad(tables, ((0, 0), (0, -tables.shape[1] % g)),
+    tables = jnp.pad(tables, ((0, 0), (0, groups * g - tables.shape[1])),
                      constant_values=-1)
     width = hb * hd
 
-    def q_map(b, h, p, t, l, lay):
+    def q_map(b, h, t, l, lay):
         return b, 0, h
 
-    def ml_map(b, h, p, t, l, lay):
+    def ml_map(b, h, t, l, lay):
         return b, h, 0
 
-    def kv_spec(j):
-        # the layer dim is squeezed: the body sees [1, ps, hb·hd]
-        return pl.BlockSpec(
-            (None, 1, ps, width),
-            lambda b, h, p, t, l, lay: (
-                lay[0], jnp.maximum(t[b, p * g + j], 0), 0, h))
-
-    kv_specs = [kv_spec(j) for j in range(g)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, nh // hb, tables.shape[1] // g),
-        in_specs=[pl.BlockSpec((1, 1, width), q_map)] + kv_specs + kv_specs,
+        grid=(B, nh // hb),
+        in_specs=[pl.BlockSpec((1, 1, width), q_map),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=[
             pl.BlockSpec((1, 1, width), q_map),
             pl.BlockSpec((1, hb, 1), ml_map),
             pl.BlockSpec((1, hb, 1), ml_map),
         ],
         scratch_shapes=[
+            _VMEM((2, g * ps, width), pool_k.dtype),
+            _VMEM((2, g * ps, width), pool_v.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((2,), jnp.int32),
             _VMEM((hb, width), pool_k.dtype),
             _VMEM((hb, width), jnp.float32),
             _VMEM((hb, 1), jnp.float32),
@@ -336,11 +449,13 @@ def _paged_call(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
             jax.ShapeDtypeStruct((B, nh, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, nh, 1), jnp.float32),
         ],
+        # in order: a row's last fold starts the next row's first copies
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=ops.interpret(),
         name="paged_decode",
     )(tables, lens, jnp.reshape(layer, (1,)).astype(jnp.int32),
-      q.astype(pool_k.dtype).reshape(B, 1, nh * hd),
-      *([pool_k] * g), *([pool_v] * g))
+      q.astype(pool_k.dtype).reshape(B, 1, nh * hd), pool_k, pool_v)
     return acc.reshape(B, nh, hd), m[..., 0], l[..., 0]
 
 

@@ -62,6 +62,7 @@ from fleetx_tpu.observability.flight import EventRing
 from fleetx_tpu.observability.metrics import get_registry
 from fleetx_tpu.observability.slo import SLORegistry
 from fleetx_tpu.observability.trace import span
+from fleetx_tpu.ops import paged_attention as PA
 from fleetx_tpu.serving.decode import (SamplingParams, make_step_fns,
                                        paged_kernel_enabled)
 from fleetx_tpu.serving.paged_cache import (NULL_PAGE, PageAllocator,
@@ -397,6 +398,19 @@ class ServingEngine:
             prefill_chunk=sc.prefill_chunk, sampling=self.sampling,
             quantize=bool(sc.quantize_decode), pool_sharding=sharding,
             paged_kernel=self.paged_kernel_active)
+
+        # what one fold of the decode kernel covers and how many a whole
+        # table row would take: the serving_page_walk_share gauge counts a
+        # tick's folds with the helper the kernel's trip count uses
+        self._walk_shape = None
+        if self.paged_kernel_active:
+            self._walk_shape = PA.page_walk_shape(
+                num_heads=model_cfg.num_attention_heads // (
+                    mesh.shape["tensor"] if mesh is not None else 1),
+                head_dim=model_cfg.head_dim, page_size=sc.page_size,
+                pages_per_req=self.pages_per_req, dtype=model_cfg.dtype)
+        # the query positions the last decode call was given (kernel path)
+        self._decoded_lens: Optional[np.ndarray] = None
 
         self._compiled: set = set()  # programs whose compile was logged
 
@@ -847,6 +861,8 @@ class ServingEngine:
         if not running:
             return False
         with _Phase(self, "decode"):
+            if self._walk_shape is not None:
+                self._decoded_lens = self._lens.copy()
             self.pool_k, self.pool_v, toks, _ = self._call(
                 "decode", self.params, self.pool_k, self.pool_v,
                 self._last_tokens, self._block_tables, self._lens,
@@ -1032,6 +1048,14 @@ class ServingEngine:
             self.allocator.occupancy())
         self.metrics.gauge("serving_kv_fragmentation").set(
             self.allocator.internal_fragmentation(self._used_slots()))
+        if self._decoded_lens is not None:
+            # of the page groups in the block table, the share this tick's
+            # decode call folded (1.0: every row at the end of its table)
+            span, groups = self._walk_shape
+            self.metrics.gauge("serving_page_walk_share").set(
+                int(PA.page_groups_walked(self._decoded_lens, span,
+                                          groups).sum())
+                / (self._decoded_lens.size * groups))
 
     def serving_snapshot(self) -> dict:
         """One JSON-ready record in the ``SERVING_RECORD_SCHEMA`` shape."""

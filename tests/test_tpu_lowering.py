@@ -275,3 +275,30 @@ def test_latent_attention_and_grouped_kernels_compile_for_the_v5e(
         arr((), jnp.int32)).compile().as_text()
     for name in ("moe_gmm", "moe_gmm_t", "moe_tgmm"):
         assert name in text, name
+
+
+def test_paged_decode_compiles_at_the_serve_cells_geometry(topo, monkeypatch):
+    """``_paged_call`` at the geometry of both serve cells (64 rows, 16
+    heads of 64, both pools ``[24, 3585, 16, 1024]`` bfloat16, 64 table
+    columns) through the chip's own Mosaic compiler: the two slots a pool
+    fit VMEM, the page copies and the lane slice are tiled as the chip
+    wants them, and the program holds ONE kernel call, under the name the
+    trace finds it by, with each pool handed to it once."""
+    from jax.sharding import SingleDeviceSharding
+
+    from fleetx_tpu.ops import paged_attention as PA
+
+    monkeypatch.setattr(ops, "interpret", lambda: False)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    pool = arr((24, 3585, 16, 1024), jnp.bfloat16)
+    text = jax.jit(PA._paged_call).lower(
+        arr((64, 16, 64), jnp.bfloat16), pool, pool, arr((64, 64)),
+        arr((64,)), arr(())).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "paged_decode" in line]
+    assert len(calls) == 1, calls
+    assert calls[0].count("bf16[24,3585,16,1024]") == 2, calls[0]

@@ -611,6 +611,167 @@ def test_paged_kernel_skips_pages_it_does_not_own(monkeypatch,
                                atol=2e-6, rtol=2e-6)
 
 
+#: the walk's edges at page 4, 8 pages a fold (32 tokens), 19 columns:
+#: name -> (query position, table columns that are holes); ``None`` as
+#: the holes means no page at all
+_WALK_ROWS = {
+    "first_token": (0, ()),
+    "fold_less_one": (31, ()),
+    "fold": (32, ()),
+    "fold_and_one": (33, ()),
+    "full_table": (75, ()),
+    "inactive": (-1, ()),
+    "no_pages": (40, None),
+    "holes_below": (70, (3, 9, 16)),
+}
+_WALK_CASES = {**{k: (k,) for k in _WALK_ROWS}, "mixed": tuple(_WALK_ROWS),
+               "mixed_two_head_blocks": tuple(_WALK_ROWS)}
+
+
+def _walk_case(names, dtype, page_size=4, per_req=19, heads=4, hd=16,
+               pages=200):
+    """One row a name of ``_WALK_ROWS``. Returns the kernel's inputs and,
+    for the gather path (which knows no holes), each row's table with the
+    holes squeezed out and its query position moved down by the tokens
+    they held (-1: nothing left to attend to)."""
+    rng = np.random.default_rng(7)
+    shape = (2, pages, page_size, heads * hd)
+    pool_k = jnp.asarray(rng.normal(size=shape), dtype)
+    pool_v = jnp.asarray(rng.normal(size=shape), dtype)
+    q = jnp.asarray(rng.normal(size=(len(names), heads, hd)), dtype)
+    ids = rng.permutation(np.arange(1, pages))
+    tables = np.full((len(names), per_req), -1, np.int32)
+    dense = np.zeros_like(tables)
+    lens, dense_lens = [], []
+    for r, name in enumerate(names):
+        n, holes = _WALK_ROWS[name]
+        used = 0 if n < 0 or holes is None else n // page_size + 1
+        tables[r, :used] = ids[r * per_req:r * per_req + used]
+        tables[r, list(holes or ())] = -1
+        kept = tables[r][tables[r] >= 0]
+        dense[r, :len(kept)] = kept
+        seen = sum(page_size if c < n // page_size else n % page_size + 1
+                   for c in range(used) if tables[r, c] >= 0)
+        lens.append(n)
+        dense_lens.append(seen - 1)
+    return (q, pool_k, pool_v, jnp.asarray(tables),
+            jnp.asarray(lens, jnp.int32), jnp.asarray(dense),
+            jnp.asarray(dense_lens, jnp.int32))
+
+
+@pytest.mark.parametrize("case", list(_WALK_CASES))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_paged_walk_is_as_long_as_the_row(dtype, case):
+    """The kernel's page walk, bounded by each row's own query position,
+    gives the gather path's answer at every edge of a fold: the first
+    token, one short of a fold, a fold, one past it, the whole table; an
+    inactive row and a row with no page come out as exact zeros; holes
+    below the query (pages another shard owns) are left out; and a batch
+    that mixes them all is right row by row, with one head block a row
+    and with two."""
+    from fleetx_tpu.ops import paged_attention as PA
+
+    wide = dict(heads=32, hd=8) if "two_head_blocks" in case else {}
+    q, pool_k, pool_v, tables, lens, dense, dense_lens = _walk_case(
+        _WALK_CASES[case], dtype, **wide)
+    assert q.shape[1] // PA.pick_head_block(*q.shape[1:], dtype) == \
+        (2 if wide else 1)
+    layer = jnp.int32(1)
+    acc, _, l = PA._paged_call(q, pool_k, pool_v, tables, lens, layer)
+    got = np.asarray(PA._normalize(acc, l, jnp.float32))
+    want = np.asarray(_dense_attention(q, pool_k, pool_v, dense, dense_lens,
+                                       layer))
+    live = np.asarray(dense_lens) >= 0
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got[live], want[live], atol=tol, rtol=tol)
+    assert not got[~live].any()                        # exact zeros
+
+
+@pytest.mark.parametrize("pages_per_step", [1, 8])
+def test_paged_walk_reads_nothing_past_the_query(monkeypatch,
+                                                 pages_per_step):
+    """Every table column past a row's query position names a page full
+    of NaN, in the query's own fold and in the folds after it: the answer
+    is the gather path's and finite only if no such page is ever folded
+    (0 x NaN is NaN: masking its scores would not do)."""
+    from fleetx_tpu.ops import paged_attention as PA
+
+    monkeypatch.setattr(PA, "_MAX_PAGES_PER_STEP", pages_per_step)
+    q, pool_k, pool_v, tables, lens = _paged_case(jnp.float32)
+    tables, n = np.array(tables), np.asarray(lens)
+    poison = next(p for p in range(1, pool_k.shape[1])
+                  if not (tables == p).any())    # a page no row holds
+    for r in range(len(n)):
+        tables[r, max(n[r], -1) // pool_k.shape[2] + 1:] = poison
+    pool_k = pool_k.at[:, poison].set(jnp.nan)
+    pool_v = pool_v.at[:, poison].set(jnp.nan)
+    tables = jnp.asarray(tables)
+    out = np.asarray(PA.paged_attention(q, pool_k, pool_v, tables, lens,
+                                        jnp.int32(1)))
+    assert np.isfinite(out).all()
+    want = _dense_attention(q, jnp.nan_to_num(pool_k), jnp.nan_to_num(pool_v),
+                            tables, lens, 1)
+    live = n >= 0
+    np.testing.assert_allclose(out[live], np.asarray(want)[live],
+                               atol=2e-5, rtol=2e-5)
+    assert not out[~live].any()
+
+
+@pytest.mark.parametrize("position, folds", [
+    (-1, 0), (0, 1), (127, 1), (128, 2), (129, 2), (1023, 8), (4000, 8)])
+def test_page_groups_walked_at_the_edges(position, folds):
+    """The trip-count helper, 128 tokens a fold and 8 folds a table row:
+    none for an inactive row, the query's own fold included, never past
+    the table — the same from the host's NumPy lengths and from a traced
+    scalar."""
+    from fleetx_tpu.ops import paged_attention as PA
+
+    host = PA.page_groups_walked(np.asarray([position], np.int32), 128, 8)
+    assert isinstance(host, np.ndarray) and host.tolist() == [folds]
+    traced = jax.jit(lambda n: PA.page_groups_walked(n, 128, 8))(
+        jnp.int32(position))
+    assert int(traced) == folds
+
+
+def test_page_walk_share_gauge_counts_what_the_kernel_folds(small_model,
+                                                            monkeypatch):
+    """``serving_page_walk_share`` after a tick is the helper's count over
+    the lengths that tick's decode call was given, over the folds in the
+    block table — on an engine whose requests grow past a fold."""
+    from fleetx_tpu.ops import paged_attention as PA
+
+    cfg, _, params = small_model
+    eng = ServingEngine(
+        cfg, params,
+        ServingConfig(max_batch=4, page_size=2, num_pages=129,
+                      max_seq_len=64, prefill_chunk=8),
+        eos_token_id=EOS)
+    assert eng.paged_kernel_active
+    span, folds = eng._walk_shape
+    assert (span, folds) == (16, 4)          # 8 pages of 2 tokens, 32 pages
+    gauge = eng.metrics.gauge("serving_page_walk_share")
+    eng.submit(list(range(1, 14)), 30, request_id="long")
+    eng.submit([5, 9, 23], 6, request_id="short")
+    given, real, seen = [], eng._call, set()
+
+    def spy(name, *args):
+        if name == "decode":
+            given.append(np.array(args[5]))  # the call's query positions
+        return real(name, *args)
+
+    monkeypatch.setattr(eng, "_call", spy)
+    while eng.has_work():
+        calls = len(given)
+        eng.step()
+        if len(given) > calls:
+            want = PA.page_groups_walked(given[-1], span, folds).sum()
+            assert gauge.value == want / (4 * folds)
+            seen.add(int(want))
+    assert {1, 2, 3} <= seen                 # grew fold by fold
+    assert "serving_page_walk_share" in SERVING_METRIC_NAMES
+
+
 # ---------------------------------------------------------------------------
 # lazy page lifecycle: admission, growth, preempt-and-swap (PR 18)
 # ---------------------------------------------------------------------------
