@@ -252,8 +252,9 @@ class EagerEngine(BasicEngine):
                 "continuing without overlap")
             self.overlap_update = False
         if self.sharding_offload:
-            # offload is a fit-enabler that costs ~2.8x step time on-chip
-            # (BENCHMARKS.md); flag configs that would fit without it
+            # offload is a fit-enabler: the f32 moments stream over PCIe
+            # every step (its cost: not measured on the chip, ROADMAP
+            # S10); flag configs that would fit without it
             from fleetx_tpu.parallel.auto_layout import (advice_inputs,
                                                          offload_is_needed)
 
@@ -307,13 +308,7 @@ class EagerEngine(BasicEngine):
         self.obs = Observability(self.cfg.get("Observability"),
                                  default_output_dir=self.output_dir)
         self._engine_kind = type(self).__name__
-        # performance introspection (docs/performance.md): every closed
-        # profiler window is decomposed into the MFU-gap report and landed
-        # in the perf stream + flight ring automatically
-        self.profiler.on_stop = self._on_profiler_stop
         self.mem = None  # HBM monitor — built in prepare (mesh known)
-        self._perf_flops_per_step = None
-        self._perf_report = None
 
         self.optimizer = optimizer
         self.lr_schedule = lr_schedule
@@ -438,7 +433,7 @@ class EagerEngine(BasicEngine):
                 # rolling per-rank skew estimate from here on
                 self.obs.install_arrival_hook()
         if self.obs.enabled and self.mem is None:
-            # HBM attribution (docs/performance.md): sample memory_stats
+            # HBM attribution (docs/observability.md): sample memory_stats
             # at phase boundaries and score the measured peak against the
             # auto_layout prediction for THIS config (hbm_model_error) —
             # closing the loop on the model that plans offload/stages
@@ -685,13 +680,12 @@ class EagerEngine(BasicEngine):
         (docs/zero_sharding.md): global norm + clip + optimizer + apply,
         jitted with the exact closure ``train_step`` uses (``_update_fn``),
         on params-shaped synthetic grads. Each run is recorded as an
-        ``optimizer_update`` span/histogram so ``bench.py`` can emit the
-        phase mean next to the step time; returns the mean seconds.
+        ``optimizer_update`` span/histogram; returns the mean seconds.
 
-        The trace decomposition (BENCHMARKS.md) bounds this phase inside
-        the 38.8 ms/step outside-the-scans tail — this measures the
-        optimizer slice of it directly, including the stage-2
-        reduce-scatter/allgather when ZeRO-2 is on.
+        The phase lies inside `outside_scan_ms` (38.9 ms of the 211 ms
+        GPT-345M step; ledger, PR 30) — this measures the optimizer slice
+        of it directly, including the stage-2 reduce-scatter/allgather
+        when ZeRO-2 is on.
         """
         assert self.state is not None and self.optimizer is not None, \
             "call prepare() first"
@@ -888,17 +882,6 @@ class EagerEngine(BasicEngine):
         # consumed_samples counts GLOBAL samples (the sampler's unit): the
         # per-host leading dim times the number of hosts
         global_batch = _leading_dim(first) * jax.process_count()
-        # model FLOPs per optimizer step for the trace decomposition's
-        # roofline: PER-HOST (leading dim, not global_batch) because the
-        # profiler trace only carries this host's devices and mfu_gap
-        # divides by that count. None for non-LM modules — the report
-        # then ranks raw category costs without an ideal-time floor.
-        fpt = (self.module.flops_per_token()
-               if hasattr(self.module, "flops_per_token") else None)
-        tps = getattr(self.module, "tokens_per_sample", None)
-        self._perf_flops_per_step = (
-            float(fpt) * int(tps) * _leading_dim(first)
-            if fpt and tps else None)
         start_step = int(jax.device_get(self.state.step))
         # sample position at fit entry: rollback rewinds relative to this
         # when the loader has no consumed_samples sampler
@@ -1392,8 +1375,7 @@ class EagerEngine(BasicEngine):
                         gang_wd.check(step)
                 if run_sentinel:
                     # the sentinel's own cost lands in the sdc_sentinel
-                    # span (bench.py reports it next to the step time);
-                    # the replay is a full step and the gang census can
+                    # span; the replay is a full step and the gang census can
                     # block on a wedged peer, so the stall detector is
                     # suspended like every other long host phase
                     with self.obs.timed_span("sdc_sentinel"), wd_quiet():
@@ -1549,46 +1531,6 @@ class EagerEngine(BasicEngine):
                            type(e).__name__, e)
             return None
 
-    def _on_profiler_stop(self, trace_dir: str) -> None:
-        """Decompose the just-closed profiler window (docs/performance.md).
-
-        Installed as ``ProfilerWindow.on_stop``: parses the Chrome trace
-        the window dumped, scores it against the calibrated roofline and
-        lands the report in the perf stream (``perf.jsonl``), the gauge
-        surface and the flight ring — so every profiled fit window yields
-        the BENCHMARKS.md-style decomposition mechanically. Best-effort:
-        a parse failure logs and training continues.
-        """
-        obs = self.obs
-        if not obs.perf_enabled:
-            return
-        try:
-            from fleetx_tpu.observability import perf
-            from fleetx_tpu.utils.hardware import roofline
-
-            rl = roofline(getattr(jax.devices()[0], "device_kind", ""))
-            axis_sizes = {str(a): int(s)
-                          for a, s in dict(self.mesh.shape).items()
-                          if int(s) > 1}
-            report = perf.analyze(
-                trace_dir, flops_per_step=self._perf_flops_per_step,
-                roofline=rl, axis_sizes=axis_sizes or None,
-                top_k=obs.perf_top_k)
-            if self.mem is not None:
-                self.mem.sample("profile_stop")
-                report["hbm"] = self.mem.snapshot()
-            self._perf_report = report
-            obs.emit_perf(report)
-            gap = report.get("mfu_gap") or {}
-            top = ", ".join(
-                f"{c['name']} {c['ms_per_step']:.1f}ms"
-                for c in (gap.get("contributors") or [])[:3])
-            logger.info("trace decomposition: step %.1f ms, mfu %s — top "
-                        "gap: %s", report["step_ms"], gap.get("mfu"), top)
-        except Exception as e:  # noqa: BLE001 — telemetry never kills a run
-            logger.warning("trace decomposition failed for %s: %s: %s",
-                           trace_dir, type(e).__name__, e)
-
     def _emit_train_record(self, log_dict: dict, metrics: dict) -> None:
         """One machine-readable record per logging window → the sinks.
 
@@ -1622,7 +1564,7 @@ class EagerEngine(BasicEngine):
         record.update(derived)
         if self.mem is not None:
             # steady-state HBM sample once per window: peak/live gauges +
-            # the model error riding every record (docs/performance.md)
+            # the model error riding every record (docs/observability.md)
             self.mem.sample("steady_state")
             record.update(self.mem.record_keys())
         if "grad_norm" in metrics:

@@ -292,8 +292,8 @@ def lora_optimizer(inner: Any) -> Any:
 
 
 def trainable_params_frac(params: Any) -> float:
-    """Trainable (adapter) parameter count over the total — the gauge
-    ``bench.py`` emits and ``tools/perf_gate.py`` gates."""
+    """Trainable (adapter) parameter count over the total — the
+    ``trainable_params_frac`` gauge of ``finetune/recipe.py``."""
     mask_leaves = jax.tree.leaves(adapter_mask(meta.unbox(params)))
     leaves = jax.tree.leaves(meta.unbox(params))
     total = sum(int(np.prod(l.shape)) for l in leaves)

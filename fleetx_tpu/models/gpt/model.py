@@ -96,8 +96,10 @@ class GPTConfig:
     use_flash_attention: bool = True
     # single-pass fused flash backward (ops/flash_attention.py): one Pallas
     # kernel sweeps the (q-block, k-block) tiles once and emits dq/dk/dv
-    # together — 1 backward kernel pass where the split dq + dkv pair paid
-    # 3 in the committed trace (flash_recompute, BENCHMARKS.md). Applies
+    # together — one backward kernel per layer where the split path runs a
+    # dq and a dkv kernel that each recompute P. `flash_bwd_roofline` 21.7 %
+    # (GPT-345M) / 43.6 % (GPT-1.3B) of the compute floor (ledger, PR 30);
+    # fused against split: not measured on the chip (ROADMAP S10). Applies
     # only where fused_backward_supported admits the shape; other shapes
     # (wide heads, non-tiling seqs) keep the split kernels regardless.
     flash_fused_bwd: bool = True
@@ -147,9 +149,9 @@ def _flash_residuals_saveable(prim, *_, **__) -> bool:
     the ``shard_map`` of the sharded path is transparent too). The stock
     dots policy rejects them (a Mosaic custom call is not a dot), which
     made the "dots" granularity rerun the whole forward flash kernel
-    inside the backward — a 4th kernel pass worth ~21 ms/step at
-    GPT-345M bs8 (trace decomposition, BENCHMARKS.md round 5). Saving
-    them costs ~17 MB/layer at that shape. Count asserted by
+    inside the backward — one more kernel pass per layer; with and
+    without it: not measured on the chip (ROADMAP S10). Saving them
+    costs ~17 MB/layer at GPT-345M 8 x 1024. Count asserted by
     ``tests/test_flash_attention.py::test_dots_policy_saves_flash_residuals``."""
     return getattr(prim, "name", "") == "pallas_call"
 
@@ -165,10 +167,10 @@ RESIDUAL_NAMES = ("res_qkv", "res_attn_out", "res_mlp_wi", "res_mlp_wo")
 #: one — [b, 3, s, n, d] → [3, b, s, n, d] makes the backward's q/k/v
 #: split three contiguous leading slices (XLA folds the replayed inverse
 #: transpose + slice into a plain slice) where the stock layout forces a
-#: strided mid-axis gather per layer — the dus_traffic copy the trace
-#: decomposition names. The other three residuals are already produced in
-#: the layout their consuming matmuls read ([b, s, features], contracted
-#: over the trailing dim), so their transform is identity.
+#: strided mid-axis gather per layer. The other three residuals are
+#: already produced in the layout their consuming matmuls read
+#: ([b, s, features], contracted over the trailing dim), so their
+#: transform is identity.
 RESIDUAL_CONSUMED_PERMS: dict[str, tuple[int, ...]] = {
     "res_qkv": (1, 0, 2, 3, 4),
 }
@@ -712,10 +714,10 @@ class GPTModel(nn.Module):
                 out_axes=0,
                 length=cfg.num_layers,
                 metadata_params={nn.PARTITION_NAME: "layers"},
-                # >1 lets XLA overlap the scan's stacked-residual
-                # dynamic-update-slice traffic across adjacent layers (the
-                # ~1.8 ms/layer backward DUS cost in the trace
-                # decomposition, BENCHMARKS.md) at compile-time cost
+                # >1 unrolls that many layers into one scan body, so XLA
+                # may schedule adjacent layers' writes into the stacked
+                # residuals together, at compile-time cost; not measured
+                # on the chip (ROADMAP S10)
                 unroll=max(int(cfg.scan_unroll), 1),
             )(cfg, name="layers")
             x, new_caches = stack(x, layer_caches, deterministic, attention_mask)
@@ -798,9 +800,8 @@ def chunked_cross_entropy_per_token(x: jax.Array, wte: jax.Array,
     another, the static chunk loop is unrolled and XLA may overlap chunk
     ``k+1``'s head matmul (MXU) with chunk ``k``'s reductions (VPU)
     instead of serialising them the way a ``lax.scan`` accumulator chain
-    must (measured ~neutral on-chip at bs16 — the real chunking cost is
-    the remat'd 4th head matmul pass, see BENCHMARKS.md — but the unroll
-    removes the serialisation constraint for free). Each chunk is
+    must (chunked against whole logits: not measured on the chip, ROADMAP
+    S10; each chunk's remat adds one head matmul pass). Each chunk is
     rematerialised, so
     peak memory stays one-ish ``[b, s, vocab_chunk]`` f32 block in
     forward AND backward — at GPT-345M bs8×seq1024 that replaces the

@@ -20,7 +20,6 @@ from __future__ import annotations
 
 import contextlib
 import os
-import time
 from typing import Any, Optional
 
 from fleetx_tpu.observability import flight as flight_mod
@@ -89,14 +88,6 @@ class Observability:
         self._gang_sink: Optional[Sink] = None
         self._pending_snaps: list[dict] = []
         self._stash_window = 0
-        # performance introspection (docs/performance.md): decomposition
-        # of closed profiler windows into the perf stream; on by default
-        # whenever telemetry is — it costs nothing until a window closes
-        perf_cfg = dict(cfg.get("perf") or {})
-        self.perf_enabled = self.enabled and bool(perf_cfg.get("enable",
-                                                               True))
-        self.perf_top_k = int(perf_cfg.get("top_k") or 5)
-        self._perf_sink: Optional[Sink] = None
         # crash flight recorder: on whenever telemetry is (an in-memory
         # ring that only touches disk when the run dies); a disabled
         # facade clears any previously-installed recorder, mirroring the
@@ -212,43 +203,6 @@ class Observability:
                 logger.warning("sink %s emit failed: %s",
                                type(sink).__name__, e)
 
-    # -- perf introspection (docs/performance.md) ----------------------------
-    def emit_perf(self, report: dict) -> None:
-        """Land one trace-decomposition report in the perf metrics stream.
-
-        The full report appends to ``perf.jsonl`` next to
-        ``metrics.jsonl`` (its own file: decomposition records have a
-        different shape than step records and would fail the step-record
-        schema gate ``tools/metrics_report.py`` applies); a slim summary
-        goes to the flight ring and the gauge surface
-        (``perf_bwd_scan_ms_per_layer`` & friends) so a crash dump or a
-        Prometheus scrape shows the last window's decomposition. Never
-        raises.
-        """
-        if not self.perf_enabled:
-            return
-        from fleetx_tpu.observability import perf as perf_mod
-
-        slim = perf_mod.summary(report)
-        for key in ("fwd_scan_ms_per_layer", "bwd_scan_ms_per_layer",
-                    "gap_ms", "step_ms"):
-            if slim.get(key) is not None:
-                self.registry.gauge(f"perf_{key}").set(slim[key])
-        if self.flight is not None:
-            self.flight.record("perf", "decomposition", **slim)
-        if self._perf_sink is None:
-            # rank-suffixed like the tracer path: every rank may close a
-            # profiler window, and N processes appending to one shared
-            # file would interleave/tear lines
-            fname = (f"perf.rank{self.rank}.jsonl" if self.rank
-                     else "perf.jsonl")
-            self._perf_sink = JsonlSink(
-                os.path.join(self.output_dir, fname))
-        try:
-            self._perf_sink.emit({"ts": time.time(), **report})
-        except OSError as e:  # a full disk must not kill training
-            logger.warning("perf sink emit failed: %s", e)
-
     # -- gang aggregation (docs/observability.md "Multi-host") ---------------
     def gang_stash(self, record: dict) -> None:
         """Queue one window's record for the next loop-control vote.
@@ -325,8 +279,6 @@ class Observability:
             sink.flush()
         if self._gang_sink is not None:
             self._gang_sink.flush()
-        if self._perf_sink is not None:
-            self._perf_sink.flush()
         if self.tracer is not None and self._trace_path and \
                 self.tracer.events:
             self.tracer.save(self._trace_path)
@@ -342,9 +294,6 @@ class Observability:
         if self._gang_sink is not None:
             self._gang_sink.close()
             self._gang_sink = None
-        if self._perf_sink is not None:
-            self._perf_sink.close()
-            self._perf_sink = None
         if get_tracer() is self.tracer:
             set_tracer(None)
         if flight_mod.get_recorder() is self.flight:

@@ -167,7 +167,7 @@ class MemoryMonitor:
 
     def snapshot(self) -> dict:
         """Full JSON-ready view: availability, per-phase samples, peak,
-        prediction and error — the perf stream / bench JSON surface."""
+        prediction and error."""
         return {
             "available": bool(self.available),
             "peak_bytes": self.peak_bytes,

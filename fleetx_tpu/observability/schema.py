@@ -1,8 +1,8 @@
 """Schema validation for metrics JSONL records — stdlib only, no deps.
 
 One shared definition of "a valid step record", used by the unit tests and
-by ``tools/metrics_report.py`` (which exits non-zero on any violation so it
-can gate bench runs). Deliberately small: required keys with type sets,
+by ``tools/metrics_report.py`` (which exits non-zero on any violation).
+Deliberately small: required keys with type sets,
 optional keys type-checked when present, unknown keys allowed (records are
 forward-extensible).
 """
@@ -54,7 +54,7 @@ STEP_RECORD_SCHEMA: dict[str, tuple[tuple, bool]] = {
     "barrier_wait_ms_mean": (_NUM, False),
     "barrier_wait_ms_max": (_NUM, False),
     "barrier_wait_ms_max_rank": ((int,), False),
-    # HBM attribution (docs/performance.md): measured peak next to the
+    # HBM attribution (docs/observability.md): measured peak next to the
     # auto_layout prediction's relative error; ``hbm_stats`` is the
     # explicit availability marker — backends without ``memory_stats()``
     # say "unavailable" instead of faking a zero peak

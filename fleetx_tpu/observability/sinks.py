@@ -6,7 +6,7 @@ append a line, rewrite a textfile — so a crashed run's output is still
 parseable up to the last flushed record.
 
 - ``JsonlSink``  — one JSON object per line; the canonical machine format
-  (``tools/metrics_report.py`` and the BENCH_* comparisons read it).
+  (``tools/metrics_report.py`` reads it).
 - ``CsvSink``    — spreadsheet-friendly; columns fixed by the first record.
 - ``PrometheusTextfileSink`` — node-exporter textfile-collector format,
   atomically rewritten per flush so a scraper never reads a torn file.
@@ -42,9 +42,8 @@ class Sink:
 
 
 def _coerce(v):
-    """One JSON-safe value: numpy/jax scalars unboxed, containers recursed
-    (perf decomposition records nest phase/contributor dicts), everything
-    else stringified."""
+    """One JSON-safe value: numpy/jax scalars unboxed, containers recursed,
+    everything else stringified."""
     if v is None or isinstance(v, (bool, int, float, str)):
         return v
     if hasattr(v, "item"):

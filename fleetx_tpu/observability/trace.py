@@ -253,10 +253,6 @@ class ProfilerWindow:
         # reference Profiler's "detailed" flag: also emit a standalone
         # perfetto trace file next to the xplane dump
         self.detailed = bool(prof.get("detailed"))
-        # post-window hook (docs/performance.md): the engine installs the
-        # trace-decomposition callback here so every closed window is
-        # analyzed automatically; called with the dump directory
-        self.on_stop = None
         self._active = False
         self._done = False
 
@@ -301,9 +297,3 @@ class ProfilerWindow:
         self._active = False
         self._done = True
         logger.info("profiler trace written to %s", self.output_dir)
-        if self.on_stop is not None:
-            try:
-                self.on_stop(self.output_dir)
-            except Exception as e:  # noqa: BLE001 — analysis is best-effort
-                logger.warning("profiler on_stop hook failed: %s: %s",
-                               type(e).__name__, e)

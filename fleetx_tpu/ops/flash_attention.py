@@ -11,10 +11,11 @@ never materialises the [S, S] score matrix in HBM:
   sweeps the (k-block, q-block) tile grid once, recomputes P once per tile,
   and emits dq, dk and dv together — dq accumulates in its full-sequence
   f32 output window (VMEM-resident per head, one HBM writeback), dk/dv in
-  per-block scratch over the minor (q) dimension. The committed trace paid
-  3 backward kernel passes per layer (dq + dkv each re-reading q/k/v/do and
-  recomputing P); the fused sweep pays 1 (``flash_recompute`` + a share of
-  the HBM re-reads in the BENCHMARKS.md decomposition).
+  per-block scratch over the minor (q) dimension. The split pair re-reads
+  q/k/v/do and recomputes P in each of its two kernels; the fused sweep
+  does both once. ``flash_bwd_roofline`` 21.7 % (GPT-345M, 64-wide heads) /
+  43.6 % (GPT-1.3B, 128-wide) of the compute floor (ledger, PR 30); fused
+  against split: not measured on the chip (ROADMAP S10).
 - backward, split (fallback): FlashAttention-2 style — a dq kernel and a
   dk/dv kernel that recompute P from the saved logsumexp, so residual memory
   is O(S) not O(S^2). Selected when the fused predicate rejects the shape

@@ -56,11 +56,10 @@ def estimate_params(model: dict) -> int:
 
 
 # Activation bytes per (token · hidden · layer), by recompute granularity.
-# Calibrated against the four round-5 on-chip anchor points on the 15.75GB
-# v5-lite chip (GPT-345M seq1024, "dots" remat — BENCHMARKS.md):
-#   bs8 full-logits head ran (measured 12.5GB predicted), bs16 full-logits
-#   OOMed, bs16+vocab_chunk ran, bs32+vocab_chunk OOMed needing 17.62GB
-#   (predicted 22GB — first-order errs on the safe side).
+# A first-order model that errs on the safe side. On the 15.75 GB v5-lite
+# chip GPT-345M seq 1024 with "dots" remat at 8 sequences holds
+# `hbm_peak_gb` 14.17 (ledger, PR 30: peak in use + peak reserved, an upper
+# bound); other batch sizes: not measured on the chip (ROADMAP S11).
 # "none" follows the Megatron selective-recompute accounting (~34 bytes
 # per token·hidden per layer plus the s² attention scores); "full" keeps
 # only layer-boundary activations plus one layer's working set.
@@ -186,8 +185,8 @@ def offload_is_needed(model: dict, degrees: dict, micro_batch: int = 1,
                       hbm_gb: float = 16.0) -> bool:
     """Should Adam-state offload be on for this config? True only when the
     per-device step estimate exceeds HBM — offload is a fit-enabler, not an
-    optimisation: streaming the f32 moments over PCIe measured 2.8× step
-    time on-chip (147 → 407 ms, GPT-345M bs4 — BENCHMARKS.md round 4), so
+    optimisation: the f32 moments stream over PCIe every step (its cost:
+    not measured on the chip, ROADMAP S10), so
     a config that fits without it should keep it off. The engine warns on
     that mismatch (``eager_engine.py``). Applies the planner's workspace
     slack (``_HBM_BUDGET_FRACTION``) so the advice and the plan agree on

@@ -16,9 +16,7 @@ heavy traffic from millions of users" north star needs (docs/serving.md):
 - ``server``       — one engine replica behind a JSON-lines TCP front with
   graceful drain on the PR 4/6 preemption latch;
 - ``router``       — round-robin + least-outstanding request router over N
-  supervised replicas, re-dispatching on replica loss;
-- ``bench``        — Poisson-load serving bench whose tokens/s +
-  tail-latency JSON joins ``tools/perf_gate.py``.
+  supervised replicas, re-dispatching on replica loss.
 """
 
 __all__ = ["ServingConfig", "ServingEngine", "PageAllocator", "init_pool",

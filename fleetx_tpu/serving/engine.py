@@ -234,9 +234,8 @@ class RequestTimeline:
         """Per-phase latency decomposition from the milestone timestamps.
 
         ``queue_s`` (queued→admitted) + ``prefill_s`` (admitted→first
-        token) = ``ttft_s``, then ``decode_s`` (first token→finished) —
-        the request-path analogue of ``perf.py``'s step-time
-        decomposition: TTFT regressions name their phase. ``prefill_s``
+        token) = ``ttft_s``, then ``decode_s`` (first token→finished):
+        TTFT regressions name their phase. ``prefill_s``
         splits at the dispatch of the request's own first chunk into
         ``prefill_wait_s`` (behind other prompts' chunks) and
         ``prefill_run_s`` (its own chunks). Spans whose endpoints haven't

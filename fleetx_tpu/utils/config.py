@@ -262,14 +262,6 @@ def process_observability_config(config: AttrDict) -> AttrDict:
     if capacity is not None and int(capacity) <= 0:
         raise ValueError(
             f"Observability.flight.capacity must be > 0, got {capacity!r}")
-    # perf introspection knobs (docs/performance.md): a zero/negative
-    # top_k would silently truncate every MFU-gap report to nothing —
-    # discovered only when someone reads an empty contributor list
-    perf = obs.get("perf") or {}
-    top_k = perf.get("top_k")
-    if top_k is not None and int(top_k) <= 0:
-        raise ValueError(
-            f"Observability.perf.top_k must be > 0, got {top_k!r}")
     return config
 
 

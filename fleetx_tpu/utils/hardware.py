@@ -1,8 +1,9 @@
 """Chip peak-FLOPs table for MFU reporting.
 
 The reference logs only tokens/s (``language_module.py:58-67``); MFU
-(model FLOPs / step time / chip peak) is the TPU-native utilization metric
-(BASELINE.md tracks it). bf16 dense peak per chip, public figures.
+(model FLOPs / step time / chip peak) is the TPU-native utilization metric.
+bf16 dense peak per chip, public figures; ``benchmarks/peaks.json`` holds
+the same figures with their sources and a test keeps the two equal.
 """
 
 from __future__ import annotations
@@ -16,28 +17,6 @@ PEAK_FLOPS = (
     ("v3", 123e12),
     ("v2", 46e12),
 )
-
-# substring of device_kind (lowercased) → HBM bandwidth, bytes/s (public
-# figures; the roofline's bandwidth axis next to PEAK_FLOPS' compute axis)
-HBM_BANDWIDTH = (
-    ("v6", 1638e9),
-    ("v5p", 2765e9),
-    ("v5", 819e9),    # v5e / "v5 lite"
-    ("v4", 1229e9),
-    ("v3", 900e9),
-    ("v2", 700e9),
-)
-
-# On-chip microbenchmark calibration (BENCHMARKS.md "Chip calibration"):
-# what THIS environment's chip actually sustains, measured in round 3 —
-# 8192³ bf16 matmul 160.5 TFLOP/s (81% of the 197 nominal peak) and
-# elementwise streaming ~1.6 TB/s (reads+writes counted, so it exceeds the
-# one-direction nominal figure). The trace decomposition's roofline
-# (observability/perf.py) scores against these when available: an "ideal"
-# computed from a peak the chip never reaches would overstate every gap.
-CALIBRATED_ROOFLINE = {
-    "v5": {"matmul_flops": 160.5e12, "hbm_bytes_per_s": 1.6e12},
-}
 
 
 def clean_cpu_env(repo_root: str, n_devices: int | None = None) -> dict:
@@ -72,33 +51,6 @@ def peak_flops(device) -> float | None:
         raise ValueError(f"no peak FLOP/s on record for TPU device_kind "
                          f"{device.device_kind!r}: add it to PEAK_FLOPS")
     return None
-
-
-def roofline(device_kind: str) -> dict | None:
-    """Roofline parameters for a device-kind STRING (offline-friendly:
-    trace decomposition runs on committed artifacts with no live backend).
-
-    Returns ``{"peak_flops", "matmul_flops", "hbm_bytes_per_s"}`` —
-    ``peak_flops`` is the nominal bf16 peak (the MFU denominator, so
-    reported MFU stays comparable across repos), while ``matmul_flops`` /
-    ``hbm_bytes_per_s`` are the CALIBRATED achievable rates when this
-    environment has measured them (``CALIBRATED_ROOFLINE``), else the
-    nominal figures. None when the kind matches no table entry (e.g. cpu).
-    """
-    kind = (device_kind or "").lower()
-    nominal_peak = next((p for k, p in PEAK_FLOPS if k in kind), None)
-    if nominal_peak is None:
-        return None
-    nominal_bw = next((b for k, b in HBM_BANDWIDTH if k in kind), None)
-    out = {"peak_flops": nominal_peak, "matmul_flops": nominal_peak,
-           "hbm_bytes_per_s": nominal_bw}
-    for key, cal in CALIBRATED_ROOFLINE.items():
-        # longest-match wins so "v5p" never takes the "v5" calibration
-        if key in kind and not any(k2 in kind and len(k2) > len(key)
-                                   for k2, _ in PEAK_FLOPS):
-            out.update(cal)
-            break
-    return out
 
 
 def gpt_flops_per_token(num_layers: int, hidden_size: int, seq_len: int,

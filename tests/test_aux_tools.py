@@ -231,11 +231,12 @@ def test_startup_checks(monkeypatch):
 
 
 def test_step_hbm_estimate_matches_onchip_anchors():
-    """The planner's memory model vs MEASURED HBM outcomes on the 15.75GB
-    v5-lite chip (VERDICT r4 weak #6 — a fits() nothing validates; the
-    four anchor runs are in BENCHMARKS.md / bench_artifacts):
-    GPT-345M seq1024 dots-remat — bs8 full-logits ran, bs16 full-logits
-    OOMed, bs16 chunked head ran, bs32 chunked OOMed (17.62GB needed)."""
+    """The planner's memory model against the 15.75GB v5-lite chip
+    (VERDICT r4 weak #6 — a fits() nothing validates). GPT-345M seq1024
+    dots-remat at bs8 with the full-logits head runs (`hbm_peak_gb` 14.17:
+    ledger, PR 30); the other three boundaries — bs16 full-logits does
+    not fit, bs16 with a chunked head does, bs32 chunked does not — are
+    the model's own statements, not measured on the chip (ROADMAP S11)."""
     from fleetx_tpu.parallel.auto_layout import estimate_step_hbm_bytes
 
     chip = 15.75 * (1 << 30)

@@ -1,12 +1,11 @@
 """Backward-bandwidth levers (docs/bandwidth_levers.md): bf16 remat
 residuals, scan-unroll wiring, and device-side input double buffering.
 
-The levers target the round-5 trace decomposition (BENCHMARKS.md): the
-backward layer scan pays ~1.8 ms/layer of dynamic-update-slice HBM traffic
-moving scan-stacked remat residuals. These tests pin the *semantics* on the
-CPU mesh — loss parity within tolerance, residual dtypes, config plumbing,
-and prefetch ordering/sharding/shutdown — so the on-chip A/B runs
-(ROADMAP S2) only have to measure.
+The levers aim at the writes of scan-stacked remat residuals in the
+backward layer scan; none has an A/B line in the ledger (ROADMAP S10).
+These tests pin the *semantics* on the CPU mesh — loss parity within
+tolerance, residual dtypes, config plumbing, and prefetch
+ordering/sharding/shutdown — so an on-chip pair only has to measure.
 """
 
 import threading

@@ -54,6 +54,28 @@ def _losses(text):
     return [float(m) for m in re.findall(r"loss: ([0-9.]+)", text)]
 
 
+#: every script under tools/ that parses arguments (tools/auto.py wraps
+#: train.py and tools/bench_losscurve.py takes none)
+TOOLS = ("eval", "export", "finetune", "inference", "lint", "make_corpus",
+         "metrics_report", "postmortem", "preprocess_data", "serve",
+         "shardcheck", "slo_report", "supervise", "train", "verify_ckpt")
+
+
+@pytest.mark.parametrize("tool", TOOLS)
+def test_tool_answers_help(tool):
+    """An entry point whose imports no longer resolve fails here and not on
+    somebody's restart: ``--help`` runs the module's top-level imports and
+    builds its parser in a fresh CPU process."""
+    from fleetx_tpu.utils.hardware import clean_cpu_env
+
+    proc = subprocess.run(
+        [sys.executable, os.path.join("tools", f"{tool}.py"), "--help"],
+        cwd=REPO, env=clean_cpu_env(REPO), capture_output=True, text=True,
+        timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert "usage:" in proc.stdout
+
+
 def test_train_cli_gpt_synthetic():
     proc = _run(["tools/train.py", "-c",
                  "fleetx_tpu/configs/nlp/gpt/pretrain_gpt_345M_synthetic.yaml"]

@@ -1,16 +1,13 @@
 """Fused single-pass flash backward + consumed-layout scan residuals
-(docs/bandwidth_levers.md): the two levers ROADMAP item 3 names against
-the committed trace's backward MFU gap — ``flash_recompute`` (3 backward
-kernel passes where one fused sweep suffices) and ``dus_traffic`` (the
-scan-stacked residuals re-copied into their consumed layout).
+(docs/bandwidth_levers.md): one fused backward sweep where the split path
+runs a dq and a dkv kernel, and the scan-stacked residuals saved in the
+layout the backward reads.
 
 Everything here runs in Pallas interpret mode on the CPU mesh: kernel
 grad parity fused vs split vs naive, fallback-predicate units, the
 save-point transform pipeline's layout/byte evidence via
-``saved_residuals``, fit-loop loss parity with both levers on, config
-round-trips, and the mechanized pass-count evidence through
-``observability/perf.py`` (a synthetic trace decomposes to 1 backward
-flash pass per layer fused vs 3 split).
+``saved_residuals``, fit-loop loss parity with both levers on, and config
+round-trips.
 
 zz-sorted per the tier-1 convention so the timeout-bound gate keeps its
 seed dots.
@@ -25,7 +22,6 @@ from fleetx_tpu.models.gpt.model import (GPTConfig, GPTForPretraining,
                                          RESIDUAL_CONSUMED_PERMS,
                                          RESIDUAL_NAMES, config_from_dict,
                                          cross_entropy_loss)
-from fleetx_tpu.observability import perf
 from fleetx_tpu.ops import flash_attention as FA
 
 pytestmark = pytest.mark.flashbwd
@@ -378,93 +374,3 @@ def test_config_zoo_base_carries_the_knobs():
     cfg = get_config(base, num_devices=1)
     assert cfg["Model"]["flash_fused_bwd"] is True
     assert cfg["Model"]["remat_consumed_layout"] is True
-
-
-# ------------------------------------- mechanized pass-count evidence
-
-
-def _synthetic_trace(bwd_flash_passes: int, layers: int = 4) -> dict:
-    """One-step device trace in the shape observability/perf.py parses:
-    a fwd scan region with 1 flash pass/layer and a bwd region with
-    ``bwd_flash_passes``/layer — the fixture form of the committed
-    trace_gpt_2step fixture, parameterized on the fused/split backward."""
-    pid = 1
-    ev = [
-        {"ph": "M", "pid": pid, "name": "process_name",
-         "args": {"name": "/device:TPU:0"}},
-        {"ph": "M", "pid": pid, "tid": 1, "name": "thread_name",
-         "args": {"name": "Steps"}},
-        {"ph": "M", "pid": pid, "tid": 2, "name": "thread_name",
-         "args": {"name": "XLA Ops"}},
-    ]
-
-    def op(name, ts, dur, cat):
-        return {"ph": "X", "pid": pid, "tid": 2, "name": name, "ts": ts,
-                "dur": dur, "args": {"hlo_category": cat}}
-
-    t = 1000.0
-    step_start = t
-    fwd_start = t
-    for _ in range(layers):
-        ev.append(op("fusion.fwd", t, 40.0, "convolution fusion"))
-        t += 40.0
-        ev.append(op("attn._core_attn.fwd", t, 60.0, "custom-call"))
-        t += 60.0
-    ev.append({"ph": "X", "pid": pid, "tid": 2, "name": "while.fwd",
-               "ts": fwd_start, "dur": t - fwd_start,
-               "args": {"hlo_category": "while"}})
-    bwd_start = t
-    for _ in range(layers):
-        ev.append(op("fusion.bwd", t, 80.0, "convolution fusion"))
-        t += 80.0
-        for p in range(bwd_flash_passes):
-            ev.append(op(f"attn._core_attn.bwd.{p}", t, 60.0, "custom-call"))
-            t += 60.0
-    ev.append({"ph": "X", "pid": pid, "tid": 2, "name": "while.bwd",
-               "ts": bwd_start, "dur": t - bwd_start,
-               "args": {"hlo_category": "while"}})
-    ev.append({"ph": "X", "pid": pid, "tid": 1, "name": "train_step",
-               "ts": step_start, "dur": t - step_start})
-    return {"traceEvents": ev}
-
-
-def test_decomposition_reports_one_fused_backward_pass():
-    """Acceptance: through observability/perf.py, the fused path reports
-    flash_passes_per_layer backward = 1 (vs 3 split), the summary carries
-    it as bwd_flash_passes_per_layer (bench.py's flash_bwd_passes row),
-    and the flash_recompute contributor exists only on the split side."""
-    fused = perf.decompose(_synthetic_trace(1))
-    split = perf.decompose(_synthetic_trace(3))
-    assert fused["phases"]["bwd_scan"]["flash_passes_per_layer"] == 1.0
-    assert split["phases"]["bwd_scan"]["flash_passes_per_layer"] == 3.0
-    assert fused["phases"]["bwd_scan"]["layers"] == 4
-
-    fused["mfu_gap"] = perf.mfu_gap(fused)
-    split["mfu_gap"] = perf.mfu_gap(split)
-    split_names = [c["name"] for c in split["mfu_gap"]["contributors"]]
-    fused_names = [c["name"] for c in fused["mfu_gap"]["contributors"]]
-    assert "flash_recompute" in split_names
-    assert "flash_recompute" not in fused_names
-
-    assert perf.summary(fused)["bwd_flash_passes_per_layer"] == 1.0
-    assert perf.summary(split)["bwd_flash_passes_per_layer"] == 3.0
-
-
-def test_perf_gate_exact_matches_pass_count(tmp_path):
-    """The flash_bwd_passes row regresses on ANY change; skips when the
-    baseline predates it."""
-    from tools.perf_gate import compare
-
-    base = {"value": 100.0, "flash_bwd_passes": 1,
-            "perf_bwd_ms_per_layer": 5.0}
-    rows = {r["metric"]: r for r in compare(dict(base), base)}
-    assert rows["flash_bwd_passes"]["verdict"] == "pass"
-    drift = dict(base, flash_bwd_passes=3)
-    rows = {r["metric"]: r for r in compare(drift, base)}
-    assert rows["flash_bwd_passes"]["verdict"] == "FAIL"
-    slow = dict(base, perf_bwd_ms_per_layer=6.0)
-    rows = {r["metric"]: r for r in compare(slow, base)}
-    assert rows["perf_bwd_ms_per_layer"]["verdict"] == "FAIL"
-    rows = {r["metric"]: r
-            for r in compare({"value": 100.0}, {"value": 100.0})}
-    assert rows["flash_bwd_passes"]["verdict"] == "skip"

@@ -106,8 +106,7 @@ def test_event_ring_bounded_with_drop_accounting():
 
 def test_request_timeline_events_and_attribution(small_model):
     """A completed request's timeline walks the taxonomy in order and its
-    attribution decomposes TTFT into queue + prefill — the request-path
-    analogue of perf.py's step-time decomposition."""
+    attribution decomposes TTFT into queue + prefill."""
     eng = _engine(small_model)
     req = eng.submit([5, 9, 23, 41, 7, 3], 4, request_id="tl")
     eng.run_until_drained()
@@ -583,56 +582,6 @@ def test_metrics_report_dispatches_serving_and_fleet_scopes(tmp_path,
     mixed = tmp_path / "metrics.rank1.jsonl"
     mixed.write_text((tmp_path / "serving.jsonl").read_text())
     assert metrics_report.main([str(tmp_path / "metrics.rank*.jsonl")]) == 2
-
-
-def test_perf_gate_fleet_economics_bands(tmp_path):
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    import perf_gate
-
-    base = {"metric": "serving_poisson_tokens_per_s", "value": 500.0,
-            "serving": {"tokens_per_s": 500.0, "requests_per_chip": 4.0,
-                        "page_occupancy": 0.6, "slo_attainment": 0.99}}
-    # identical capture passes, pre-fleet baseline skips the new rows
-    rows = perf_gate.compare(json.loads(json.dumps(base)), base)
-    assert not [r for r in rows if r["verdict"] == "FAIL"]
-    rows = perf_gate.compare(base, {"value": 500.0})
-    skipped = {r["metric"] for r in rows if r["verdict"] == "skip"}
-    assert {"serving.requests_per_chip", "serving.page_occupancy",
-            "serving.slo_attainment"} <= skipped
-    # a 30% per-chip throughput drop and a 9-point attainment drop FAIL
-    bad = json.loads(json.dumps(base))
-    bad["serving"]["requests_per_chip"] = 2.8
-    bad["serving"]["slo_attainment"] = 0.90
-    failed = {r["metric"] for r in perf_gate.compare(bad, base)
-              if r["verdict"] == "FAIL"}
-    assert "serving.requests_per_chip" in failed
-    assert "serving.slo_attainment" in failed
-    # a 1-point attainment wobble stays inside the 2-point absolute band
-    ok = json.loads(json.dumps(base))
-    ok["serving"]["slo_attainment"] = 0.98
-    assert not [r for r in perf_gate.compare(ok, base)
-                if r["verdict"] == "FAIL"]
-    # the self-check seeds these rows even on pre-fleet baselines
-    assert perf_gate.self_check({"value": 500.0}) == []
-
-
-def test_bench_emits_fleet_economics_keys(small_model):
-    from fleetx_tpu.serving import bench as B
-
-    cfg, params = small_model
-    eng = ServingEngine(
-        cfg, params,
-        ServingConfig(max_batch=4, page_size=4, num_pages=33,
-                      max_seq_len=32, prefill_chunk=4,
-                      slo={"ttft_p99_s": 60.0}),
-        eos_token_id=EOS)
-    result = B.run_serving_bench(eng, n_requests=4, rate_rps=50.0,
-                                 max_prompt=6, max_new=4, seed=0)
-    s = result["serving"]
-    assert s["requests_per_chip"] == pytest.approx(s["completed"])
-    assert 0.0 < s["page_occupancy"] <= 1.0
-    assert s["page_occupancy"] == s["page_occupancy_peak"]
-    assert s["slo_attainment"] == 1.0  # 60 s TTFT budget on 4 requests
 
 
 def test_serving_config_validation_in_config_pipeline(tmp_path):

@@ -6,13 +6,11 @@ Usage::
     python tools/metrics_report.py output/telemetry/           # per-rank dir
     python tools/metrics_report.py 'out/telemetry/metrics.rank*.jsonl'
     python tools/metrics_report.py run.jsonl --json summary.json
-    python tools/metrics_report.py run.jsonl --compare BENCH_SELF.json:gpt
 
 Every record is validated against the shared step-record schema
 (``fleetx_tpu/observability/schema.py``); ANY malformed record exits
-non-zero, so this tool gates bench runs — a pipeline that silently logged
-NaN losses or dropped its MFU field fails loudly here, not three rounds
-later in a BENCHMARKS.md table.
+non-zero, so a pipeline that silently logged NaN losses or dropped its
+MFU field fails loudly here.
 
 Multi-host runs (``Observability.gang``, docs/observability.md
 "Multi-host") write per-rank files: pass the telemetry DIRECTORY or a
@@ -22,10 +20,8 @@ glob and the report shows a per-rank view next to the merged gang view
 versions are REFUSED — silently mixing a pre-gang run's records with
 per-rank records would produce a summary describing neither run.
 
-``--json`` writes the summary as machine-readable JSON in the same spirit
-as the ``BENCH_*.json`` result entries (tokens/s value + step time + MFU),
-and ``--compare FILE:KEY`` diffs the run's throughput against a committed
-``BENCH_*.json`` entry.
+``--json`` writes the summary as machine-readable JSON (tokens/s, step
+time, MFU of the run's own records).
 
 Serving streams (docs/serving.md "Observability") report here too: the
 tool sniffs each file's ``scope`` field and dispatches — replica snapshot
@@ -78,7 +74,7 @@ def summarize(records: list[dict]) -> dict:
         "mfu": _stats([r.get("mfu") for r in records]),
         "data_stall_frac": _stats([r.get("data_stall_frac")
                                    for r in records]),
-        # HBM attribution keys (docs/performance.md) — PR-10 records only;
+        # HBM attribution keys (docs/observability.md) — PR-10 records only;
         # .get() tolerates their absence in older runs (stats stay None
         # and the table shows em-dashes instead of KeyError-ing)
         "hbm_peak_bytes": _stats([r.get("hbm_peak_bytes")
@@ -242,40 +238,6 @@ def print_serving_table(summary: dict) -> None:
               f"drain_refusals={summary['drain_refusals_total']}")
 
 
-def compare(summary: dict, spec: str) -> int:
-    """``FILE:KEY`` → diff mean tokens/s against the bench entry's value."""
-    path, _, key = spec.partition(":")
-    with open(path) as f:
-        bench = json.load(f)
-    entry = bench.get("results", bench).get(key) if key else None
-    if not isinstance(entry, dict) or "value" not in entry:
-        print(f"error: no result entry {key!r} with a 'value' in {path}",
-              file=sys.stderr)
-        return 2
-    tps = summary.get("tokens_per_sec")
-    if not tps:
-        print("error: run has no tokens_per_sec to compare", file=sys.stderr)
-        return 2
-    ref = float(entry["value"])
-    ratio = tps["mean"] / ref if ref else float("inf")
-    print(f"\nvs {path}:{key} ({entry.get('metric', '?')}): "
-          f"{tps['mean']:,.0f} / {ref:,.0f} {entry.get('unit', '')} "
-          f"= {ratio:.3f}x")
-    # the PR-10 keys diff too when BOTH sides carry them; absence on
-    # either side (pre-PR-10 bench entries, CPU runs with stats
-    # unavailable) is silently tolerated — never a KeyError, never a
-    # fake-zero comparison
-    for skey, ekey, label in (("mfu", "mfu", "MFU"),
-                              ("hbm_peak_bytes", "hbm_peak_bytes",
-                               "HBM peak")):
-        st, ref_v = summary.get(skey), entry.get(ekey)
-        if not st or not isinstance(ref_v, (int, float)) or not ref_v:
-            continue
-        print(f"   {label}: {st['mean']:.4g} / {ref_v:.4g} "
-              f"= {st['mean'] / ref_v:.3f}x")
-    return 0
-
-
 def resolve_inputs(spec: str) -> tuple[list[str], str | None]:
     """``spec`` (file | directory | glob) → (rank/run files, gang file).
 
@@ -365,8 +327,6 @@ def main(argv=None) -> int:
                                   "directory, or glob of rank files")
     ap.add_argument("--json", metavar="OUT",
                     help="also write the summary as JSON (- for stdout)")
-    ap.add_argument("--compare", metavar="FILE:KEY",
-                    help="diff tokens/s against a BENCH_*.json result entry")
     args = ap.parse_args(argv)
 
     files, gang_file = resolve_inputs(args.jsonl)
@@ -387,7 +347,7 @@ def main(argv=None) -> int:
     if scope in _SCOPE_STREAMS:
         # serving/fleet streams: validate each file against its schema,
         # concatenate (multiple replica files are one time series) and
-        # render the serving table — no gang merge, no --compare
+        # render the serving table — no gang merge
         records: list = []
         for path in files + ([gang_file] if gang_file else []):
             recs, rc = _load_validated(path, scope=scope)
@@ -406,10 +366,6 @@ def main(argv=None) -> int:
             else:
                 with open(args.json, "w") as f:
                     f.write(payload + "\n")
-        if args.compare:
-            print("error: --compare applies to training step records only",
-                  file=sys.stderr)
-            return 2
         return 0
 
     by_file: dict = {}
@@ -452,10 +408,6 @@ def main(argv=None) -> int:
         else:
             with open(args.json, "w") as f:
                 f.write(payload + "\n")
-    if args.compare:
-        rc = compare(summary, args.compare)
-        if rc:
-            return rc
     return 0
 
 

@@ -1,4 +1,4 @@
-"""Serving entry point: replica, router, or Poisson bench (docs/serving.md).
+"""Serving entry point: replica or router (docs/serving.md).
 
 One process = one role:
 
@@ -18,9 +18,6 @@ One process = one role:
 
       python tools/serve.py --router --port 8999 \
           --backends 127.0.0.1:9000,127.0.0.1:9001
-
-- **bench** (``--bench``): the in-process Poisson serving bench; prints
-  one JSON line for ``tools/perf_gate.py``.
 
 Under a supervisor gang (``FLEETX_PROCESS_ID`` set) the replica offsets
 its port by the member id so one command line can launch N replicas on
@@ -152,28 +149,10 @@ def _run_replica(args, cfg: dict) -> int:
     return args.preemption_code
 
 
-def _run_bench(args, cfg: dict) -> int:
-    """Bench role: in-process Poisson load, one JSON line on stdout."""
-    from fleetx_tpu.serving import bench as B
-
-    engine = _build_engine(cfg)
-    bcfg = dict(cfg.get("ServingBench") or {})
-    result = B.run_serving_bench(
-        engine,
-        n_requests=args.requests or int(bcfg.get("requests", 32)),
-        rate_rps=args.rate or float(bcfg.get("rate_rps", 8.0)),
-        max_prompt=int(bcfg.get("max_prompt", 24)),
-        max_new=int(bcfg.get("max_new", 16)),
-        seed=args.seed,
-        metric=str(bcfg.get("metric", "serving_poisson_tokens_per_s")))
-    B.emit(result, out=args.json_out)
-    return 0
-
-
 def main(argv=None) -> int:
-    """CLI dispatch across the three roles."""
+    """CLI dispatch across the two roles."""
     ap = argparse.ArgumentParser(description="fleetx serving runtime")
-    ap.add_argument("-c", "--config", help="YAML config (replica/bench)")
+    ap.add_argument("-c", "--config", help="YAML config (replica)")
     ap.add_argument("-o", "--override", action="append", default=[],
                     help="dotted config overrides")
     ap.add_argument("--host", default="127.0.0.1")
@@ -196,15 +175,6 @@ def main(argv=None) -> int:
                          "(FLEET_RECORD_SCHEMA JSONL) here")
     ap.add_argument("--poll-interval", type=float, default=1.0,
                     help="router mode: seconds between backend stats polls")
-    ap.add_argument("--bench", action="store_true",
-                    help="run the Poisson serving bench and exit")
-    ap.add_argument("--requests", type=int, default=0,
-                    help="bench: request count (0 = config/default)")
-    ap.add_argument("--rate", type=float, default=0.0,
-                    help="bench: Poisson arrival rate, req/s")
-    ap.add_argument("--seed", type=int, default=0, help="bench: stream seed")
-    ap.add_argument("--json-out", default=None,
-                    help="bench: also write the JSON line to this path")
     args = ap.parse_args(argv)
 
     if args.router:
@@ -232,7 +202,7 @@ def main(argv=None) -> int:
         return router_main(router_argv)
 
     if not args.config:
-        ap.error("replica/bench mode requires -c config.yaml")
+        ap.error("replica mode requires -c config.yaml")
     from fleetx_tpu.utils import config as config_mod
 
     # parse + override only: the training post-processing (batch-size
@@ -247,8 +217,6 @@ def main(argv=None) -> int:
 
     env_mod.init_compile_cache()
     check_config(cfg)
-    if args.bench:
-        return _run_bench(args, cfg)
     return _run_replica(args, cfg)
 
 

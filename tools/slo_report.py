@@ -16,8 +16,7 @@ burn rate and a met/BREACH verdict.
 
 Exit codes follow ``tools/lint.py``: **0** every target's attainment
 meets its objective, **1** any target breached (so CI can gate a serving
-run on its SLOs exactly like ``perf_gate.py`` gates throughput),
-**2** usage error (no records, no SLO block, invalid stream).
+run on its SLOs), **2** usage error (no records, no SLO block, invalid stream).
 """
 
 import argparse
