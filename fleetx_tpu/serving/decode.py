@@ -36,6 +36,28 @@ HBM traffic a call to change 0.2 MB (PERF.md, PR 28).
 ``tests/test_tpu_lowering.py`` pins the aliasing and the absence of any
 pool- or layer-shaped temporary in the compiled programs.
 
+The PARAMETERS are cast once, not once a call. The model's tree arrives
+in ``param_dtype`` (float32 in every recipe, from ``model.init``, a
+restored checkpoint or a LoRA merge); ``serving_params`` — called by
+``ServingEngine.__init__``, and by anyone else who calls ``make_step_fns``
+— makes the tree both programs take as their first argument: kernels,
+their biases and both embedding tables in ``cfg.dtype``, the layer norms'
+``scale`` and ``bias`` as they came (``_layer_norm`` multiplies them in
+float32; casting them would be another result). The forward converts no
+parameter and refuses, while tracing, a tree that would need it. Cast in
+the programs instead, XLA hoists the casts out of the layer scan and runs
+them over the whole ``[24, …]`` stacks on EVERY call: 2.1 GB of HBM
+traffic, ~3.1 ms, to remake the same 0.7 GB (GPT-345M; PERF.md, PR 33).
+Every leaf keeps its shape: compiled for the v5e with bfloat16 arguments,
+neither program holds a ``convert``, ``copy`` or ``transpose`` of a weight
+stack — the runtime stores ``qkv_kernel`` ``[24, 1024, 3, 16, 64]``
+hidden-minor (``{1,4,3,2,0}``, tiled over ``64 × 1024``: unpadded, and the
+layout the product reads), so the ``[…, 16, 64]`` tail that cost the pool
+a transpose costs the weights nothing. One line remains in ``decode``: the
+compiler prefetches the vocabulary matrix into on-chip memory
+(``copy-done`` to ``S(1)``), in place of the product's read from HBM.
+``tests/test_tpu_lowering.py`` pins all of it at GPT-345M's widths.
+
 Decode attention has two compiled forms, chosen ONCE at
 ``make_step_fns`` time (so the jit caches still hold one entry each):
 the ``ops/paged_attention.py`` Pallas kernel that walks block tables
@@ -121,6 +143,44 @@ def _layer_norm(p: dict, x: jax.Array, cfg: Any) -> jax.Array:
     return (y * p["scale"] + p["bias"]).astype(cfg.dtype)
 
 
+#: the layer norms' groups: ``_layer_norm`` multiplies ``scale`` and
+#: ``bias`` in float32, so their leaves stay in the dtype they come in
+_F32_GROUPS = frozenset({"ln1", "ln2", "ln_f"})
+
+
+def _unserved(params: Any, cfg: Any) -> list:
+    """Flattening-order indices of the leaves the forward could not take
+    as they are: every leaf outside the layer norms that is not in
+    ``cfg.dtype``."""
+    dtype = jnp.dtype(cfg.dtype)
+    flat, _ = jax.tree_util.tree_flatten_with_path(params)
+    return [i for i, (path, leaf) in enumerate(flat)
+            if leaf.dtype != dtype
+            and not _F32_GROUPS & {getattr(k, "key", None) for k in path}]
+
+
+def serving_params(params: Any, cfg: Any) -> Any:
+    """The model's (unboxed) parameter tree as the two programs take it:
+    kernels, their biases and both embedding tables in ``cfg.dtype``, the
+    layer norms' leaves untouched. The ONE place a weight changes dtype.
+
+    One jitted call over the leaves that need it, so no float32 temporary
+    outlives it; nothing is donated (the caller's tree stays whole) and a
+    leaf already in ``cfg.dtype`` comes back as the object it was. The
+    cast is elementwise, so sharding propagation hands every leaf back on
+    the sharding it came with (``tests/test_zz_serving.py`` holds that on
+    the partition rules' shardings)."""
+    todo = _unserved(params, cfg)
+    if not todo:
+        return params
+    leaves, treedef = jax.tree.flatten(params)
+    cast = jax.jit(lambda xs: [x.astype(cfg.dtype) for x in xs])(
+        [leaves[i] for i in todo])
+    for i, leaf in zip(todo, cast):
+        leaves[i] = leaf
+    return treedef.unflatten(leaves)
+
+
 def _paged_attention(q: jax.Array, kd: jax.Array, vd: jax.Array,
                      q_pos: jax.Array) -> jax.Array:
     """Decode attention over the gathered page view (mirrors
@@ -160,15 +220,21 @@ def _forward(params: Any, cfg: Any, tokens: jax.Array, positions: jax.Array,
     ``positions`` are absolute token positions (invalid slots must
     already be redirected to the null page via ``block_tables``-aware
     ``positions``/page math by the caller-built scatter indices below).
+    ``params`` is the tree ``serving_params`` makes: no leaf is converted
+    here, and a tree that would need it is refused while tracing.
     """
+    unserved = _unserved(params, cfg)
+    if unserved:
+        raise TypeError(
+            "the serving programs take the tree serving_params() makes: "
+            f"{len(unserved)} leaves are not in {jnp.dtype(cfg.dtype).name}")
     B, S = tokens.shape
     ps = pool_k.shape[2]
     num_layers = pool_k.shape[0]
     gpt = params["gpt"]
     emb = gpt["embeddings"]
 
-    wte = emb["word_embeddings"].astype(cfg.dtype)
-    wpe = emb["position_embeddings"].astype(cfg.dtype)
+    wte, wpe = emb["word_embeddings"], emb["position_embeddings"]
     safe_pos = jnp.clip(positions, 0, cfg.max_position_embeddings - 1)
     x = wte[tokens] + wpe[safe_pos]
 
@@ -190,10 +256,9 @@ def _forward(params: Any, cfg: Any, tokens: jax.Array, positions: jax.Array,
         y = _layer_norm(lp["ln1"], x, cfg)
 
         y_in = _quant(y, act_bits, quantize)
-        qkv_k = _quant(lp["attn"]["qkv_kernel"].astype(cfg.dtype), w_bits,
-                       quantize, axis=0)
+        qkv_k = _quant(lp["attn"]["qkv_kernel"], w_bits, quantize, axis=0)
         qkv = jnp.einsum("bsh,hcnd->bcsnd", y_in, qkv_k)
-        qkv = qkv + lp["attn"]["qkv_bias"].astype(cfg.dtype)[:, None]
+        qkv = qkv + lp["attn"]["qkv_bias"][:, None]
         q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]          # [B, S, nh, hd]
 
         # in place on the carried pool: a [B, S] scatter of nh·hd-wide rows
@@ -216,29 +281,24 @@ def _forward(params: Any, cfg: Any, tokens: jax.Array, positions: jax.Array,
             attn = _paged_attention(q, kd, vd, q_pos)
 
         attn = _quant(attn, act_bits, quantize)
-        out_k = _quant(lp["attn"]["out_kernel"].astype(cfg.dtype), w_bits,
-                       quantize, axis=(0, 1))
+        out_k = _quant(lp["attn"]["out_kernel"], w_bits, quantize,
+                       axis=(0, 1))
         y = jnp.einsum("bsnd,ndh->bsh", attn, out_k)
-        y = y + lp["attn"]["out_bias"].astype(cfg.dtype)
+        y = y + lp["attn"]["out_bias"]
         x = residual + y
 
         residual = x
         y = _layer_norm(lp["ln2"], x, cfg)
         y_in = _quant(y, act_bits, quantize)
-        wi = _quant(lp["mlp"]["wi_kernel"].astype(cfg.dtype), w_bits,
-                    quantize, axis=0)
-        y = jnp.einsum("bsh,hm->bsm", y_in, wi) + \
-            lp["mlp"]["wi_bias"].astype(cfg.dtype)
+        wi = _quant(lp["mlp"]["wi_kernel"], w_bits, quantize, axis=0)
+        y = jnp.einsum("bsh,hm->bsm", y_in, wi) + lp["mlp"]["wi_bias"]
         y = jax.nn.gelu(y, approximate=True)
         y = _quant(y, act_bits, quantize)
-        wo = _quant(lp["mlp"]["wo_kernel"].astype(cfg.dtype), w_bits,
-                    quantize, axis=0)
-        y = jnp.einsum("bsm,mh->bsh", y, wo) + \
-            lp["mlp"]["wo_bias"].astype(cfg.dtype)
+        wo = _quant(lp["mlp"]["wo_kernel"], w_bits, quantize, axis=0)
+        y = jnp.einsum("bsm,mh->bsh", y, wo) + lp["mlp"]["wo_bias"]
         x = residual + y
         return (x, pool_k, pool_v), None
 
-    x = x.astype(cfg.dtype)
     (x, pool_k, pool_v), _ = jax.lax.scan(
         layer, (x, pool_k, pool_v),
         (gpt["layers"], jnp.arange(num_layers, dtype=jnp.int32)))
@@ -248,7 +308,7 @@ def _forward(params: Any, cfg: Any, tokens: jax.Array, positions: jax.Array,
 
 def _logits(params: Any, cfg: Any, x_last: jax.Array) -> jax.Array:
     """Tied-embedding LM head on the selected positions → f32 ``[B, V]``."""
-    wte = params["gpt"]["embeddings"]["word_embeddings"].astype(cfg.dtype)
+    wte = params["gpt"]["embeddings"]["word_embeddings"]
     return jnp.einsum("bh,vh->bv", x_last, wte).astype(jnp.float32)
 
 

@@ -64,7 +64,7 @@ from fleetx_tpu.observability.slo import SLORegistry
 from fleetx_tpu.observability.trace import span
 from fleetx_tpu.ops import paged_attention as PA
 from fleetx_tpu.serving.decode import (SamplingParams, make_step_fns,
-                                       paged_kernel_enabled)
+                                       paged_kernel_enabled, serving_params)
 from fleetx_tpu.serving.paged_cache import (NULL_PAGE, PageAllocator,
                                             init_pool, pool_shardings)
 from fleetx_tpu.utils.env import log_compile
@@ -374,7 +374,16 @@ class ServingEngine:
             "Serving.max_seq_len exceeds the model's position table"
         self.pages_per_req = -(-self.max_seq_len // sc.page_size)
 
-        self.params = meta.unbox(params)
+        # cast ONCE, here: the programs convert no parameter (decode.py)
+        given = meta.unbox(params)
+        self.params = serving_params(given, model_cfg)
+        cast = [a for a, b in zip(jax.tree.leaves(given),
+                                  jax.tree.leaves(self.params)) if a is not b]
+        weights = "%d leaves cast%s, serving tree %d bytes" % (
+            len(cast),
+            " %s -> %s" % ("/".join(sorted({a.dtype.name for a in cast})),
+                           np.dtype(model_cfg.dtype).name) if cast else "",
+            sum(a.nbytes for a in jax.tree.leaves(self.params)))
         self.allocator = PageAllocator(sc.num_pages, sc.page_size)
         self.pool_k, self.pool_v = init_pool(model_cfg, sc.num_pages,
                                              sc.page_size)
@@ -454,12 +463,12 @@ class ServingEngine:
         logger.info(
             "serving engine: max_batch=%d pages=%d x %d tokens "
             "(capacity %d token slots/layer), prefill_chunk=%d, "
-            "quantize_decode=%s, decode=%s, alloc=%s",
+            "quantize_decode=%s, decode=%s, alloc=%s, weights: %s",
             sc.max_batch, self.allocator.usable_pages,
             sc.page_size, self.allocator.usable_pages * sc.page_size,
             sc.prefill_chunk, bool(sc.quantize_decode),
             "paged_kernel" if self.paged_kernel_active else "gather",
-            "lazy" if sc.lazy_alloc else "reserve")
+            "lazy" if sc.lazy_alloc else "reserve", weights)
 
     # ------------------------------------------------------------ submission
     def submit(self, prompt: list, max_new_tokens: int,

@@ -148,31 +148,27 @@ def _pool_holders(hlo: str, shape: tuple) -> list:
             if (m := want.search(line))]
 
 
-@pytest.mark.parametrize("dist", [None, {"fsdp_degree": 2, "mp_degree": 2}],
-                         ids=["one_device", "fsdp2_mp2"])
-@pytest.mark.parametrize("kernel", [True, False], ids=["kernel", "gather"])
-def test_serving_programs_keep_the_pool_one_buffer(topo, monkeypatch, dist,
-                                                   kernel):
-    """Compiled for the v5e, ``decode`` and ``prefill`` alias both pools
-    from input to output and hold no other buffer of the pool's shape or
-    of one layer's: no ``copy``, no ``dynamic-slice`` of a layer, no
-    stacked output. A pool that is a scanned input fails every clause."""
+def _compile_serving_programs(topo, monkeypatch, model, dist, kernel, *,
+                              pages, page, batch, per_req, chunk):
+    """``decode`` and ``prefill`` of a GPT of widths ``model`` at the
+    recipes' dtypes (bfloat16 compute, float32 parameters), compiled for
+    the described v5e — one device, or the mesh ``dist`` with the pool on
+    its shardings — from abstract arguments, the parameters as the engine
+    passes them (``serving_params``). Returns ``(params, what one device
+    holds of a pool, {program: compiled text})``."""
     from flax.core import meta
     from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
     from fleetx_tpu.models.gpt.model import (GPTForPretraining,
                                              config_from_dict)
     from fleetx_tpu.serving.decode import (SamplingParams, make_step_fns,
-                                           paged_kernel_enabled)
+                                           paged_kernel_enabled,
+                                           serving_params)
     from fleetx_tpu.serving.paged_cache import init_pool, pool_shardings
 
     monkeypatch.setattr(ops, "interpret", lambda: False)
-    # a pool too large for the compiler to stage in on-chip memory, as the
-    # real one is (abstract shapes: nothing is allocated)
-    layers, pages, page, batch, per_req, chunk = 3, 4098, 16, 4, 4, 16
-    cfg = config_from_dict(dict(
-        vocab_size=VOCAB, hidden_size=256, num_layers=layers,
-        num_attention_heads=4, max_position_embeddings=64))
+    cfg = config_from_dict(model)
+    assert (cfg.dtype, cfg.param_dtype) == (jnp.bfloat16, jnp.float32)
     if dist:
         mesh = build_mesh(dist, devices=topo.devices)
         pool_sh, rep = pool_shardings(mesh), NamedSharding(
@@ -183,14 +179,15 @@ def test_serving_programs_keep_the_pool_one_buffer(topo, monkeypatch, dist,
     def arr(shape, dtype, sharding=rep):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
+    model_tree = meta.unbox(jax.eval_shape(lambda: GPTForPretraining(
+        cfg).init({"params": jax.random.PRNGKey(0)},
+                  jnp.zeros((1, 8), jnp.int32), None,
+                  deterministic=True)["params"]))
     params = jax.tree.map(
         lambda a: arr(a.shape, a.dtype),
-        meta.unbox(jax.eval_shape(lambda: GPTForPretraining(cfg).init(
-            {"params": jax.random.PRNGKey(0)}, jnp.zeros((1, 8), jnp.int32),
-            None, deterministic=True)["params"])))
+        jax.eval_shape(lambda tree: serving_params(tree, cfg), model_tree))
     pool_k, _ = jax.eval_shape(lambda: init_pool(cfg, pages, page))
     pool = arr(pool_k.shape, pool_k.dtype, pool_sh or rep)
-    local = pool.sharding.shard_shape(pool.shape)   # what one device holds
     if kernel:
         assert paged_kernel_enabled(cfg, page_size=page, num_pages=pages,
                                     pages_per_req=per_req,
@@ -205,11 +202,36 @@ def test_serving_programs_keep_the_pool_one_buffer(topo, monkeypatch, dist,
         "decode": (params, pool, pool, arr((batch,), i32),
                    arr((batch, per_req), i32), arr((batch,), i32), rng),
     }
+    texts = {name: fns[name].lower(*args).compile().as_text()
+             for name, args in programs.items()}
+    assert ("paged_decode" in texts["decode"]) == kernel
+    return params, pool.sharding.shard_shape(pool.shape), texts
+
+
+SERVING_CASES = pytest.mark.parametrize(
+    "dist", [None, {"fsdp_degree": 2, "mp_degree": 2}],
+    ids=["one_device", "fsdp2_mp2"])
+SERVING_FORMS = pytest.mark.parametrize("kernel", [True, False],
+                                        ids=["kernel", "gather"])
+
+
+@SERVING_CASES
+@SERVING_FORMS
+def test_serving_programs_keep_the_pool_one_buffer(topo, monkeypatch, dist,
+                                                   kernel):
+    """Compiled for the v5e, ``decode`` and ``prefill`` alias both pools
+    from input to output and hold no other buffer of the pool's shape or
+    of one layer's: no ``copy``, no ``dynamic-slice`` of a layer, no
+    stacked output. A pool that is a scanned input fails every clause."""
+    # a pool too large for the compiler to stage in on-chip memory, as the
+    # real one is (abstract shapes: nothing is allocated)
+    params, local, texts = _compile_serving_programs(
+        topo, monkeypatch, dict(
+            vocab_size=VOCAB, hidden_size=256, num_layers=3,
+            num_attention_heads=4, max_position_embeddings=64),
+        dist, kernel, pages=4098, page=16, batch=4, per_req=4, chunk=16)
     n_params = len(jax.tree.leaves(params))
-    for name, args in programs.items():
-        hlo = fns[name].lower(*args).compile().as_text()
-        if name == "decode":
-            assert ("paged_decode" in hlo) == kernel, name
+    for name, hlo in texts.items():
         alias = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo)
         assert alias, f"{name}: no input-output aliasing at all"
         for out, arg in ((0, n_params), (1, n_params + 1)):
@@ -229,6 +251,71 @@ def test_serving_programs_keep_the_pool_one_buffer(topo, monkeypatch, dist,
         layer_sized = ",".join(map(str, local[1:]))
         assert not re.search(
             r"= \w+\[(?:1,)?%s\]" % layer_sized, hlo), name
+
+
+# --------------------------------------------- the weights are cast ONCE
+
+def _materialised(hlo: str) -> list:
+    """``(shape, layout, opcode, line)`` of every array-valued instruction
+    of a compiled program that is a buffer of its own: the instructions of
+    the entry computation and of the loop bodies, fusions by their result —
+    not what a fusion computes on the way (its body's lines)."""
+    fused = set(re.findall(r" fusion\(.*?calls=(%[\w.-]+)", hlo))
+    out, inside = [], None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.-]+) \(.*\{$", line)
+        if head:
+            inside = head.group(1)
+        elif inside not in fused and (m := re.search(
+                r"= \w+\[([\d,]*)\](\{\S*)? ([\w-]+)\(", line)):
+            out.append((m.group(1), m.group(2) or "", m.group(3),
+                        line.strip()))
+    return out
+
+
+@SERVING_CASES
+@SERVING_FORMS
+def test_serving_programs_convert_no_weight(topo, monkeypatch, dist, kernel):
+    """At GPT-345M's widths and the serve cells' geometry, the tree the
+    engine passes holds no float32 leaf but the layer norms', and neither
+    compiled program makes a second buffer of a weight stack or of the
+    vocabulary matrix: no ``convert`` (the per-call cast this replaces),
+    no ``copy`` or ``transpose`` (a leaf stored in a layout the products
+    do not read), no fusion with such a result. The one thing allowed is
+    the compiler's own prefetch of an argument into on-chip memory
+    (``copy-start`` / ``copy-done`` to ``S(1)``, the layout unchanged),
+    which takes the place of the product's read from HBM."""
+    from fleetx_tpu.parallel.rules import tree_leaf_names
+
+    params, _, texts = _compile_serving_programs(
+        topo, monkeypatch, dict(
+            vocab_size=50304, hidden_size=1024, num_layers=24,
+            num_attention_heads=16, max_position_embeddings=1024),
+        dist, kernel, pages=3586, page=16, batch=64, per_req=64, chunk=128)
+    named = tree_leaf_names(params)
+    f32 = {name for name, a in named if a.dtype == jnp.float32}
+    assert f32 == {f"gpt/{g}/{leaf}" for g in ("layers/ln1", "layers/ln2",
+                                               "ln_f")
+                   for leaf in ("scale", "bias")}, f32
+    assert all(a.dtype == jnp.bfloat16 for name, a in named
+               if name not in f32)
+    # the four stacked kernels and the vocabulary matrix, by shape (the
+    # position table is as small as one layer's kernel; biases are vectors)
+    stacks = {",".join(map(str, a.shape)): name for name, a in named
+              if name.endswith("_kernel") or name.endswith("word_embeddings")}
+    assert len(stacks) == 5, stacks
+    for name, hlo in texts.items():
+        held = [m for m in _materialised(hlo) if m[0] in stacks]
+        assert {m[0] for m in held} == set(stacks), (name, held)
+        for shape, layout, op, line in held:
+            if op == "copy-done":
+                assert "S(1)" in layout, (name, line)
+                source = [m for m in held if m[0] == shape
+                          and m[2] == "parameter"]
+                assert source and source[0][1] == layout.replace(
+                    "S(1)", ""), (name, line, source)
+            else:
+                assert op in ("parameter", "get-tuple-element"), (name, line)
 
 
 def test_latent_attention_and_grouped_kernels_compile_for_the_v5e(

@@ -423,12 +423,18 @@ def test_engine_resolves_gpt_lora_through_registry(devices8, tmp_path):
                    if n.startswith("opt_state"))
 
 
-def test_serve_builder_merges_adapter_artifact(pipeline):
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_serve_builder_merges_adapter_artifact(pipeline, dtype):
+    """The replica's tree is the merged one, in the dtype the programs
+    consume: the adapter is folded in float32 BEFORE the engine's one
+    cast (``serving_params`` — the identity at the float32 recipe)."""
+    from fleetx_tpu.serving.decode import serving_params
+
     spec = importlib.util.spec_from_file_location(
         "serve_cli_ft", os.path.join(REPO, "tools", "serve.py"))
     serve = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(serve)
-    cfg = {"Model": dict(TINY),
+    cfg = {"Model": dict(TINY, dtype=dtype),
            "Serving": {"max_batch": 2, "page_size": 4, "num_pages": 33,
                        "max_seq_len": 32, "prefill_chunk": 4,
                        "ckpt_dir": pipeline["base_dir"],
@@ -440,9 +446,14 @@ def test_serve_builder_merges_adapter_artifact(pipeline):
     base_params = ckpt_lib.load_params(pipeline["base_dir"])
     merged = ft_ckpt.apply_adapter_checkpoint(base_params,
                                               pipeline["ad_dir"])
+    assert all(a.dtype == jnp.float32 for a in jax.tree.leaves(merged))
+    want = serving_params(meta.unbox(merged), config_from_dict(cfg["Model"]))
     for (n, a), b in zip(R.tree_leaf_names(eng.params),
-                         jax.tree.leaves(merged)):
+                         jax.tree.leaves(want)):
+        assert a.dtype == b.dtype, n
         assert np.array_equal(np.asarray(a), np.asarray(b)), n
+    assert eng.params["gpt"]["layers"]["mlp"]["wi_kernel"].dtype == \
+        jnp.dtype(dtype)
 
 
 def test_finetune_zoo_config_parses_and_audits_clean():

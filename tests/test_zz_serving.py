@@ -390,6 +390,149 @@ def test_registry_sharded_weights_compose_with_sharded_pool(devices8,
 
 
 # ---------------------------------------------------------------------------
+# the weights are cast ONCE, when the engine is built (PR 33)
+# ---------------------------------------------------------------------------
+
+#: the recipes' dtypes: bfloat16 products over float32 parameters
+RECIPE_DTYPES = dict(dtype="bfloat16", param_dtype="float32")
+
+
+def _is_norm(name):
+    return name.split("/")[-2] in ("ln1", "ln2", "ln_f")
+
+
+def _bits(a):
+    return np.asarray(a).view(np.uint16 if a.dtype == jnp.bfloat16
+                              else np.uint32)
+
+
+def test_serving_params_casts_each_leaf_once_and_keeps_shardings(devices8):
+    """bfloat16 / float32, the leaves on the partition rules' shardings:
+    every kernel, bias and embedding table of the serving tree is the
+    model tree's leaf ``.astype(bfloat16)`` bit for bit on the sharding it
+    came with, the layer norms' leaves ARE the float32 originals, the
+    caller's tree is left whole, and a tree that needs nothing comes back
+    as it went in."""
+    from fleetx_tpu.parallel import rules as R
+    from fleetx_tpu.parallel.mesh import build_mesh
+    from fleetx_tpu.serving.decode import serving_params
+
+    cfg, _, params = _build_model(vocab_size=128, **RECIPE_DTYPES)
+    mesh = build_mesh({"fsdp_degree": 2, "mp_degree": 2})
+    params = jax.device_put(params, R.named_shardings(params, mesh, "gpt"))
+    served = serving_params(params, cfg)
+    cast = 0
+    for (name, a), (_, b) in zip(R.tree_leaf_names(params),
+                                 R.tree_leaf_names(served)):
+        assert a.dtype == jnp.float32 and not a.is_deleted(), name
+        if _is_norm(name):
+            assert b is a, name
+            continue
+        cast += 1
+        assert b.dtype == jnp.bfloat16, name
+        assert np.array_equal(_bits(b), _bits(a.astype(jnp.bfloat16))), name
+        assert b.sharding.is_equivalent_to(a.sharding, a.ndim), \
+            (name, a.sharding, b.sharding)
+    assert cast == 10
+    assert "tensor" in str(
+        served["gpt"]["layers"]["attn"]["qkv_kernel"].sharding.spec)
+    again = serving_params(served, cfg)
+    assert again is served
+
+
+def test_serving_params_is_the_identity_at_the_models_dtype(small_model):
+    """float32 / float32 (every CPU recipe): the function returns the
+    leaves it was given, and the engine keeps them."""
+    from fleetx_tpu.serving.decode import serving_params
+
+    cfg, _, params = small_model
+    served = serving_params(params, cfg)
+    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(served)):
+        assert b is a
+    eng = ServingEngine(cfg, params, ServingConfig(
+        max_batch=2, page_size=4, num_pages=9, max_seq_len=16,
+        prefill_chunk=4), eos_token_id=EOS)
+    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(eng.params)):
+        assert b is a
+
+
+def test_step_fns_refuse_a_tree_they_would_have_to_cast():
+    """A float32 tree handed straight to the programs at a bfloat16
+    ``dtype`` cannot silently pay the casts again: tracing refuses it."""
+    from fleetx_tpu.serving.decode import make_step_fns, serving_params
+    from fleetx_tpu.serving.paged_cache import init_pool
+
+    cfg, _, params = _build_model(**RECIPE_DTYPES)
+    fns = make_step_fns(cfg, max_batch=2, pages_per_req=4, prefill_chunk=4,
+                        sampling=SamplingParams())
+    args = (np.zeros((2,), np.int32), np.zeros((2, 4), np.int32),
+            np.full((2,), -1, np.int32), jax.random.PRNGKey(0))
+    with pytest.raises(TypeError, match="10 leaves are not in bfloat16"):
+        fns["decode"](params, *init_pool(cfg, 9, 4), *args)
+    fns["decode"](serving_params(params, cfg), *init_pool(cfg, 9, 4), *args)
+
+
+@pytest.mark.parametrize("quantize", [False, True], ids=["fp", "int8"])
+def test_engine_casts_once_what_a_hand_cast_tree_serves(quantize, caplog):
+    """An engine built from the float32 tree and one built from the same
+    tree cast by hand serve the same greedy tokens and the same first-step
+    logits, bit for bit, with and without ``quantize_decode``; the first
+    says it cast ten leaves, the second none; the jit caches hold one
+    entry each."""
+    from fleetx_tpu.parallel.rules import tree_leaf_names
+    from fleetx_tpu.utils.log import logger as fx_logger
+
+    cfg, _, params = _build_model(qat_act_bits=8, **RECIPE_DTYPES)
+    names = [name for name, _ in tree_leaf_names(params)]
+    by_hand = jax.tree.unflatten(jax.tree.structure(params), [
+        a if _is_norm(name) else a.astype(jnp.bfloat16)
+        for name, a in zip(names, jax.tree.leaves(params))])
+    prompts = [[5, 9, 23, 41], [7, 3, 11, 2, 8, 4, 19, 33, 7]]
+
+    def run(tree):
+        caplog.clear()
+        fx_logger.addHandler(caplog.handler)   # the logger does not propagate
+        try:
+            eng = ServingEngine(
+                cfg, tree,
+                ServingConfig(max_batch=2, page_size=4, num_pages=17,
+                              max_seq_len=32, prefill_chunk=4,
+                              quantize_decode=quantize),
+                eos_token_id=EOS)
+        finally:
+            fx_logger.removeHandler(caplog.handler)
+        said = [r.getMessage() for r in caplog.records
+                if r.getMessage().startswith("serving engine:")]
+        assert len(said) == 1, caplog.text
+        reqs = [eng.submit(p, 6, request_id=f"c{i}")
+                for i, p in enumerate(prompts)]
+        eng.run_until_drained()
+        assert eng._fns["decode"]._cache_size() == 1
+        assert eng._fns["prefill"]._cache_size() == 1
+        table = np.zeros((1, eng.pages_per_req), np.int32)
+        table[0, 0] = 1
+        tokens = np.asarray([prompts[0]], np.int32)
+        _, _, _, logits = eng._fns["prefill"](
+            eng.params, eng.pool_k, eng.pool_v, tokens, table, np.int32(0),
+            np.int32(4), jax.random.PRNGKey(0))
+        assert eng._fns["prefill"]._cache_size() == 1
+        return eng, said[0], [r.tokens for r in reqs], np.asarray(logits)
+
+    eng, said, tokens, logits = run(params)
+    assert "weights: 10 leaves cast float32 -> bfloat16, serving tree " \
+        f"{sum(a.nbytes for a in jax.tree.leaves(by_hand))} bytes" in said
+    for (name, a), b in zip(tree_leaf_names(eng.params),
+                            jax.tree.leaves(by_hand)):
+        assert a.dtype == b.dtype and np.array_equal(_bits(a), _bits(b)), name
+    eng_h, said_h, tokens_h, logits_h = run(by_hand)
+    assert "weights: 0 leaves cast, serving tree " in said_h
+    for a, b in zip(jax.tree.leaves(eng_h.params), jax.tree.leaves(by_hand)):
+        assert a is b
+    assert all(len(t) == 6 for t in tokens) and tokens == tokens_h
+    assert np.array_equal(logits, logits_h)
+
+
+# ---------------------------------------------------------------------------
 # in-kernel paged attention: path pins, predicate, fallback (PR 18)
 # ---------------------------------------------------------------------------
 
