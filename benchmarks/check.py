@@ -135,9 +135,33 @@ def row_width(samples: list, pad_to: int, positions: int) -> int:
     return min(-(-longest // WIDTH_STEP) * WIDTH_STEP, positions)
 
 
-def served_logit_gaps(ref, sizes: dict, weights: dict, samples: list,
+def _forward(ref, sizes: dict, source):
+    """``(tokens [1, width], precision) -> float32 logits [1, width,
+    vocab]`` of the reference. A reference that defines ``logits_streamed
+    (leaf, sizes, tokens, precision)`` walks its layers and asks
+    ``leaf(name)`` or ``leaf(name, layer)`` for each weight as it goes
+    (``weights.Source.leaf``: drawn alone, so one layer stands on the
+    device at a time); any other is handed the whole float32 tree, as
+    ``logits(w, sizes, tokens, precision)`` takes it."""
+    if hasattr(ref, "logits_streamed"):
+        return lambda tokens, precision: ref.logits_streamed(
+            source.leaf, sizes, tokens, precision)
+    fwd = jax.jit(lambda w, t, p: ref.logits(w, sizes, t, p),
+                  static_argnums=2)
+    return lambda tokens, precision: fwd(source.tree(), tokens, precision)
+
+
+@jax.jit
+def _gaps_below_best(lg, judged):
+    return (lg.max(-1) - jnp.take_along_axis(
+        lg, judged[..., None], axis=-1)[..., 0])[0]
+
+
+def served_logit_gaps(ref, sizes: dict, source, samples: list,
                       pad_to: int, chooser: str | None = None) -> dict:
-    """Run the reference once over each prompt with its served tokens.
+    """Run the reference once over each prompt with its served tokens, a
+    sample at a time: what stands on the device is one row's logits
+    beside the reference's weights (``source``: ``weights.Source``).
 
     ``widest_gap``: the widest gap by which a served token's logit lies
     below the reference's best at its position (valid for greedy tokens).
@@ -149,27 +173,22 @@ def served_logit_gaps(ref, sizes: dict, weights: dict, samples: list,
     position table cannot hold.
     """
     width = row_width(samples, pad_to, int(sizes["max_position_embeddings"]))
-    rows = np.zeros((len(samples), width), np.int32)
-    spans = []
-    for i, (prompt, served) in enumerate(samples):
-        seq = list(prompt) + list(served)
-        rows[i, :len(seq)] = seq
-        spans.append((len(prompt), len(served)))
-    tokens = jnp.asarray(rows)
-    fwd = jax.jit(lambda w, t, p: ref.logits(w, sizes, t, p),
-                  static_argnums=2)
-    lg = fwd(weights, tokens, "float32")
-    best = lg.max(-1)
-    if chooser is None:
-        judged = jnp.roll(tokens, -1, axis=1)   # position p predicts p + 1
-    else:
-        judged = jnp.argmax(fwd(weights, tokens, chooser), axis=-1)
-    gap = np.asarray(best - jnp.take_along_axis(
-        lg, judged[..., None], axis=-1)[..., 0])
+    forward = _forward(ref, sizes, source)
     widest, n_tokens = 0.0, 0
-    for i, (plen, nserved) in enumerate(spans):
-        first = plen - 1 if chooser is None else 0
-        g = gap[i, first:plen - 1 + nserved]
-        widest, n_tokens = max(widest, float(g.max())), n_tokens + nserved
+    for prompt, served in samples:
+        row = np.zeros((1, width), np.int32)
+        row[0, :len(prompt) + len(served)] = list(prompt) + list(served)
+        tokens = jnp.asarray(row)
+        lg = forward(tokens, "float32")
+        if chooser is None:
+            judged = jnp.roll(tokens, -1, axis=1)   # position p predicts p + 1
+        else:
+            judged = jnp.argmax(forward(tokens, chooser), axis=-1)
+        gap = np.asarray(_gaps_below_best(lg, judged))
+        del lg      # or the next sample's logits stand beside this one's
+        first = len(prompt) - 1 if chooser is None else 0
+        widest = max(widest, float(
+            gap[first:len(prompt) - 1 + len(served)].max()))
+        n_tokens += len(served)
     return {"widest_gap": widest, "tokens_compared": n_tokens,
             "width": width}

@@ -2,8 +2,10 @@
 
 The harness is driven by data: a cell is ``<config> + <traffic> + chips``,
 and whatever belongs to one configuration, one traffic mix, one per-layer
-metric or one kernel sits in a file of its own that is found by the name
-in the manifest. A later PR adds files and entries and edits nothing.
+metric, one kernel or one served model family sits in a file of its own
+that is found by the name in the manifest (a family: by the recipe's
+``Model.module``). A later PR adds files and entries and edits nothing,
+for a serve cell as for a train cell.
 """
 
 from __future__ import annotations
@@ -173,6 +175,10 @@ class Manifest:
 
     def reference_path(self, name: str) -> str:
         return self._find("reference", name, (".py",))
+
+    def family(self, module: str) -> Any:
+        """The serving family file of a recipe's ``Model.module``."""
+        return load_module(self._find("families", module, (".py",)))
 
     def kernel_trace_names(self) -> tuple:
         """Every kernel's names as a device trace shows them."""

@@ -12,9 +12,6 @@ from __future__ import annotations
 import os
 import time
 
-import jax
-import numpy as np
-
 from benchmarks import check, stats, traffic, weights
 from benchmarks.manifest import ROOT
 
@@ -23,35 +20,30 @@ STALL_S = 1.0      # a tick this long has its stacks dumped to the log
 
 
 def build_engine(ctx, spec):
-    """Recipe + overrides -> ``ServingEngine`` (``tools/serve.py``'s own
-    calls), with the seeded weights in the program's parameter tree (its
-    shapes come from an abstract ``model.init``; no initialiser runs) and
+    """Recipe + overrides -> ``ServingEngine``, through the family file
+    that the recipe's ``Model.module`` names (``benchmarks/families/``):
+    its template says which shape and dtype the engine holds each leaf in,
+    the seeded weights are made in those (no float32 copy of a tree that is
+    served in bfloat16 ever stands on the device, and the engine finds no
+    leaf to cast), and its second call builds the engine around them, with
     an eos id no token can equal."""
-    import jax.numpy as jnp
-    from flax.core import meta
-    from fleetx_tpu.models.gpt.model import GPTForPretraining, config_from_dict
-    from fleetx_tpu.serving.decode import SamplingParams
-    from fleetx_tpu.serving.engine import ServingConfig, ServingEngine
     from fleetx_tpu.utils import config as config_mod
 
     part = ctx.config["serve"]
     cfg = config_mod.get_config(os.path.join(ROOT, part["recipe"]),
                                 list(part["overrides"]),
                                 num_devices=ctx.chips)
-    model_cfg = config_from_dict(dict(cfg.get("Model") or {}))
-    model = GPTForPretraining(model_cfg)
-    template = meta.unbox(jax.eval_shape(lambda: model.init(
-        {"params": jax.random.PRNGKey(0)}, jnp.zeros((1, 8), jnp.int32),
-        None, deterministic=True)["params"]))
-    params = weights.to_program_tree(weights.make(spec, ctx.seed),
-                                     ctx.config["param_paths"], template)
+    family = ctx.manifest.family((cfg.get("Model") or {})["module"])
+    model_cfg, template = family.served_template(cfg)
+    paths = ctx.config["param_paths"]
+    made = weights.make(spec, ctx.seed, dtypes={
+        name: leaf.dtype
+        for name, leaf in weights.program_paths(paths, template).items()})
+    params = weights.to_program_tree(made, paths, template)
     ctx.mark("weights_made")
-    return ServingEngine(
-        model_cfg, params,
-        ServingConfig.from_dict(dict(cfg.get("Serving") or {})),
-        SamplingParams(do_sample=False),
-        eos_token_id=int(part["eos_token_id"]), mesh=None,
-        seed=ctx.seed % (2 ** 31))
+    return family.serving_engine(cfg, model_cfg, params,
+                                 eos_token_id=int(part["eos_token_id"]),
+                                 seed=ctx.seed % (2 ** 31))
 
 
 class Loop:
@@ -126,9 +118,9 @@ def _check_served(ctx, ref, spec, samples: list, pad_to: int) -> tuple:
               "compare, NOT CORRECT", file=ctx.err)
         return {}, False
     sizes = ctx.config
-    w = weights.make(spec, ctx.seed)
+    source = weights.Source(spec, ctx.seed)
     try:
-        got = check.served_logit_gaps(ref, sizes, w, samples, pad_to)
+        got = check.served_logit_gaps(ref, sizes, source, samples, pad_to)
     except check.TooLong as e:
         print(f"check: {e}  NOT CORRECT", file=ctx.err)
         return {}, False
@@ -139,7 +131,7 @@ def _check_served(ctx, ref, spec, samples: list, pad_to: int) -> tuple:
           f"{len(samples)} requests compared (longest request {longest} "
           f"tokens, rows {got['width']} wide)", file=ctx.err)
     if ctx.control:
-        ctl = check.served_logit_gaps(ref, sizes, w, samples, pad_to,
+        ctl = check.served_logit_gaps(ref, sizes, source, samples, pad_to,
                                       chooser=ctx.control)
         ctx.control_numbers = {"served_logit_widest_gap": ctl["widest_gap"]}
     return numbers, correct

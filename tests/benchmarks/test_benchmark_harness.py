@@ -73,6 +73,15 @@ def test_cell_files_found_by_name(real, cell):
     assert os.path.exists(real.reference_path(cfg["reference"]))
     part = cfg["train" if mix["kind"] == "train_steps" else "serve"]
     assert os.path.exists(os.path.join(ROOT, part["recipe"]))
+    if mix["kind"] != "train_steps":
+        from fleetx_tpu.utils import config as config_mod
+
+        recipe = config_mod.get_config(os.path.join(ROOT, part["recipe"]),
+                                       list(part["overrides"]),
+                                       num_devices=w["chips"])
+        family = real.family(recipe["Model"]["module"])
+        assert hasattr(family, "served_template") and \
+            hasattr(family, "serving_engine")
     for m in real.metrics_of(cell, "per_layer"):
         assert hasattr(manifest_mod.load_module(real.reader_path(m["name"])),
                        "read")
@@ -605,9 +614,9 @@ _TOY_SERVED: dict = {}
 
 
 def _toy_served(seed=5):
-    """``(ref, cfg, weights, samples)``: three requests of 64, 100 and 120
+    """``(ref, cfg, source, samples)``: three requests of 64, 100 and 120
     tokens whose served tokens are the reference's own greedy ones (they
-    stand for a sound program)."""
+    stand for a sound program); ``source`` makes the reference's weights."""
     if seed in _TOY_SERVED:
         return _TOY_SERVED[seed]
     import jax
@@ -617,7 +626,8 @@ def _toy_served(seed=5):
     m = Manifest(ROOT)
     cfg = toy.toy_config()
     ref = manifest_mod.load_module(m.reference_path("gpt_ref"))
-    w = weights.make(ref.weight_spec(cfg), seed)
+    source = weights.Source(ref.weight_spec(cfg), seed)
+    w = source.tree()
     rng = np.random.default_rng(seed)
     samples = []
     fwd = jax.jit(lambda t: ref.logits(w, cfg, t, "float32"))
@@ -627,7 +637,7 @@ def _toy_served(seed=5):
             padded = jnp.asarray([seq + [0] * (128 - len(seq))])
             seq.append(int(jnp.argmax(fwd(padded)[0, len(seq) - 1])))
         samples.append((seq[:plen], seq[plen:]))
-    _TOY_SERVED[seed] = (ref, cfg, w, samples)
+    _TOY_SERVED[seed] = (ref, cfg, source, samples)
     return _TOY_SERVED[seed]
 
 
@@ -637,14 +647,14 @@ def _toy_serve_gap(chooser, seed=5, pad_to=128, widths=None):
     import types
     from benchmarks import check
 
-    ref, cfg, w, samples = _toy_served(seed)
+    ref, cfg, source, samples = _toy_served(seed)
 
     def logits(w, sizes, tokens, precision):
         if widths is not None:
             widths.append(tuple(tokens.shape))
         return ref.logits(w, sizes, tokens, precision)
     got = check.served_logit_gaps(types.SimpleNamespace(logits=logits), cfg,
-                                  w, samples, pad_to, chooser=chooser)
+                                  source, samples, pad_to, chooser=chooser)
     return got, cfg["check"]["serve"]
 
 
@@ -662,12 +672,12 @@ def test_serve_control_one_precision_lower_is_not_correct():
 @pytest.mark.parametrize("pad_to,width", [(128, 128), (120, 120),
                                           (100, 128), (64, 128)])
 def test_serve_check_rows_follow_the_samples(pad_to, width):
-    """Samples that all fit ``pad_to`` are compared at exactly that width
-    (the same reference program as before ISSUE 27); a longer one widens
-    the rows, and every served token is still compared."""
+    """Samples that all fit ``pad_to`` are compared at exactly that width;
+    a longer one widens the rows, and every served token is still compared.
+    The reference sees one sample at a time (one program for all three)."""
     widths = []
     got, _ = _toy_serve_gap(None, pad_to=pad_to, widths=widths)
-    assert widths == [(3, width)] and got["width"] == width
+    assert widths == [(1, width)] and got["width"] == width
     assert got["tokens_compared"] == 164
     assert np.isfinite(got["widest_gap"]) and got["widest_gap"] <= 1e-4
 
@@ -675,7 +685,7 @@ def test_serve_check_rows_follow_the_samples(pad_to, width):
 def test_serve_control_goes_through_the_same_width():
     widths = []
     got, limits = _toy_serve_gap("float8", pad_to=100, widths=widths)
-    assert widths == [(3, 128), (3, 128)]       # float32, then the chooser
+    assert widths == [(1, 128), (1, 128)]       # float32, then the chooser
     assert got["widest_gap"] > limits["served_logit_widest_gap"]
 
 
@@ -695,10 +705,10 @@ def test_row_width(samples, pad_to, positions, width):
 def test_a_sample_past_the_position_table_is_too_long():
     from benchmarks import check
 
-    ref, cfg, w, samples = _toy_served()
+    ref, cfg, source, samples = _toy_served()
     prompt, served = samples[-1]
     with pytest.raises(check.TooLong) as e:
-        check.served_logit_gaps(ref, cfg, w, samples[:2] + [
+        check.served_logit_gaps(ref, cfg, source, samples[:2] + [
             (prompt, served + [1] * 9)], 128)
     assert str(e.value) == ("request of 129 tokens exceeds the reference's "
                             "128 positions")
@@ -811,11 +821,7 @@ def test_a_later_pr_adds_files_only(tmp_path):
     and the new cell runs."""
     root = toy.make_root(str(tmp_path))
     bench = os.path.join(root, "benchmarks")
-    before = {}
-    for d, _, files in os.walk(bench):
-        for fn in files:
-            with open(os.path.join(d, fn), "rb") as f:
-                before[os.path.join(d, fn)] = f.read()
+    before = toy.files_under(bench)
     cfg = toy.toy_config()
     cfg.update(name="toy-wide", num_attention_heads=1, head_dim=128)
     cfg["serve"]["overrides"] = [
@@ -865,6 +871,4 @@ def test_a_later_pr_adds_files_only(tmp_path):
     assert line["correct"] is True
     assert line["metrics"]["ticks_per_token"]["value"] > 0
     assert "toy_gather" in Manifest(root).kernel_trace_names()
-    for p, content in before.items():
-        with open(p, "rb") as f:
-            assert f.read() == content, f"{p} was edited"
+    toy.assert_none_edited(before)

@@ -201,6 +201,8 @@ def test_first_token_wait_reads_the_newest_samples():
 
 
 def test_manifest_holds_the_seven_new_metrics():
+    """By name and by layer: where in ``per_layer`` they stand, and how
+    many layers later PRs have named, is not this test's business."""
     m = Manifest(ROOT)
     for name, moves in NEW.items():
         entry = m.per_layer[name]
@@ -209,13 +211,12 @@ def test_manifest_holds_the_seven_new_metrics():
         assert (entry["unit"], entry["better"], entry["moves"]) == \
             ("ms", "lower", moves)
         assert hasattr(manifest_mod.load_module(m.reader_path(name)), "read")
-    layers = {e["layer"] for e in m.per_layer.values()}
+    older = {e["layer"] for n, e in m.per_layer.items() if n not in NEW}
+    assert {m.per_layer[n]["layer"] for n in NEW} <= older   # no new layer
     assert m.per_layer["tick_host_ms"]["layer"] == \
         m.per_layer["decode_occupancy"]["layer"]
     assert m.per_layer["fit_host_ms"]["layer"] == \
         m.per_layer["train_step_ms"]["layer"]
-    assert len(layers) == 7       # no new layer name
-    assert [e["name"] for e in m.data["per_layer"]][-7:] == list(NEW)
 
 
 # ------------------------------------------------- toy cells, rehearsed

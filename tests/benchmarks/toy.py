@@ -97,3 +97,20 @@ def make_root(tmp: str, chips: int = 1) -> str:
     with open(os.path.join(tmp, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
     return tmp
+
+
+def files_under(directory: str) -> dict:
+    """Path -> content of every file under ``directory``: what a later PR
+    may add to and not edit."""
+    found = {}
+    for d, _, files in os.walk(directory):
+        for fn in files:
+            with open(os.path.join(d, fn), "rb") as f:
+                found[os.path.join(d, fn)] = f.read()
+    return found
+
+
+def assert_none_edited(before: dict) -> None:
+    for path, content in before.items():
+        with open(path, "rb") as f:
+            assert f.read() == content, f"{path} was edited"
