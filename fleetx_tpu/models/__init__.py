@@ -45,6 +45,11 @@ def get_registry():
         modules["MLAMoEModule"] = MLAMoEModule
     except ImportError:
         pass
+    try:
+        from fleetx_tpu.models.swa_moe.module import SWAMoEModule
+        modules["SWAMoEModule"] = SWAMoEModule
+    except ImportError:
+        pass
     return modules
 
 

@@ -133,7 +133,7 @@ def plan_rows(ids: jax.Array, first: int, held: int, tile: int,
 
 
 # ---------------------------------------------------------- grouped products
-def _pass_inputs(c, x, w_flat, plan, k, chunk, tile):
+def pass_inputs(c, x, w_flat, plan, k, chunk, tile):
     """What pass ``c`` works on: its rows' tokens, weights (zero on padding
     rows), each tile's expert, the tiles that hold rows, the gathered rows."""
     at, tiles = c * chunk, chunk // tile
@@ -169,7 +169,7 @@ def grouped_experts(x, w_flat, gate_up, down, plan, k, chunk, tile):
 def _grouped_fwd(x, w_flat, gate_up, down, plan, k, chunk, tile):
     def body(state):
         c, y = state
-        tok, wt, _, experts, n_tiles, xs = _pass_inputs(
+        tok, wt, _, experts, n_tiles, xs = pass_inputs(
             c, x, w_flat, plan, k, chunk, tile)
         o = _gated(xs, gate_up, down, experts, n_tiles, tile)[2]
         return c + 1, y.at[tok].add(o * wt[:, None])
@@ -187,7 +187,7 @@ def _grouped_bwd(k, chunk, tile, residuals, dy):
 
     def body(state):
         c, dx, d_row_w, d_gate_up, d_down = state
-        tok, wt, valid, experts, n_tiles, xs = _pass_inputs(
+        tok, wt, valid, experts, n_tiles, xs = pass_inputs(
             c, x, w_flat, plan, k, chunk, tile)
         gu, a, o = _gated(xs, gate_up, down, experts, n_tiles, tile)
         dyc = dy[tok]

@@ -183,6 +183,15 @@ SERVING_METRIC_NAMES = (
     # of the page groups in the block table, the share the last decode
     # call's kernel folded (ops/paged_attention.py:page_groups_walked)
     "serving_page_walk_share",
+    # tokens the running rows hold in a layer that keeps every token and in
+    # a window layer's rings, and the bytes of all cache buffers
+    "serving_kv_full_tokens", "serving_kv_window_tokens",
+    "serving_kv_cache_bytes",
+    # a family with sparse experts (serving/registry.py): held experts a
+    # decode step hit (mean over its expert layers), (token, expert) pairs
+    # on held experts and all pairs, of the rows that decoded
+    "serving_moe_experts_hit", "serving_moe_pairs_held_total",
+    "serving_moe_pairs_total",
     "serving_requests_total", "serving_requests_completed",
     "serving_requests_refused", "serving_tokens_total",
     # deadline-admission plane (docs/serving.md "Fault tolerance"):

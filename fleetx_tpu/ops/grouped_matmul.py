@@ -56,11 +56,14 @@ def _gmm_kernel(tile_expert, n_tiles, lhs_ref, rhs_ref, out_ref, *,
 
 def moe_gmm(lhs: jax.Array, rhs: jax.Array, tile_expert: jax.Array,
             n_tiles: jax.Array, *, tile: int, transpose_rhs: bool = False,
-            out_dtype=None, block_n: int = 2048) -> jax.Array:
+            out_dtype=None, block_n: int = 2048,
+            name: str | None = None) -> jax.Array:
     """``lhs`` [rows, k] times each tile's expert matrix: ``rhs`` is
     ``[experts, k, n]``, or ``[experts, n, k]`` with ``transpose_rhs``.
     ``tile_expert`` [rows / tile] int32, ``n_tiles`` the tiles that hold
-    rows. Returns ``[rows, n]``, zeros in the tiles past ``n_tiles``."""
+    rows. Returns ``[rows, n]``, zeros in the tiles past ``n_tiles``.
+    ``name``: what a device trace calls the kernel (a program that wants
+    its calls told apart from another's; ``moe_gmm`` / ``moe_gmm_t``)."""
     rows, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     tn = _block(n, block_n)
@@ -93,7 +96,7 @@ def moe_gmm(lhs: jax.Array, rhs: jax.Array, tile_expert: jax.Array,
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=ops.interpret(),
-        name="moe_gmm_t" if transpose_rhs else "moe_gmm",
+        name=name or ("moe_gmm_t" if transpose_rhs else "moe_gmm"),
     )(tile_expert.astype(jnp.int32), n_tiles, lhs, rhs)
 
 

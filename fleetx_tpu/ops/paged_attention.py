@@ -54,6 +54,24 @@ raw block tables over, the wrapper rewrites invalid entries to ``-1``
 read: its copy fetches local page 0 instead, so stale or non-finite
 pages past the query cannot reach the accumulator.
 
+Fewer key-value heads than query heads, and a window. The pool's minor
+dimension is ``kv_heads·head_dim``; with ``group = heads / kv_heads > 1``
+query heads to a key-value head, the block-diagonal query has a row for
+every QUERY head of the block (row *r* holds its query in the lanes of
+key-value head ``r // group``), so a key-value tile is copied once and
+read by all the query heads that share it. Which lanes a row owns is a
+0/1 mask the wrapper makes once and the kernel keeps in VMEM (its block
+index never changes), and the finish folds each row's own ``head_dim``
+lanes out of its ``[rows, kv_heads·head_dim]`` accumulator. With a
+``window`` a row's walk does not start at its first page but at the group
+that holds position ``lens − window + 1``: keys older than the window are
+neither fetched nor scored, whatever the context, which is also how a ring
+of ``window`` + one prefill chunk of tokens a slot is read (the ring's
+static table maps logical page *j* onto ring page ``j mod ring_pages``:
+``serving/swa_moe.py``). With ``heads == kv_heads`` and no window the
+kernel is, line for line, the one it was (``tests/test_zz_serving.py``
+holds the outputs to the bit).
+
 Contract mirrors ``ops/flash_attention.py`` exactly:
 
 - ``paged_attention_supported(...)`` gates the path; rejected shapes keep
@@ -133,44 +151,53 @@ def pick_head_block(num_heads: int, head_dim: int,
 
 
 def _step_vmem_bytes(pages: int, page_size: int, hb: int, head_dim: int,
-                     dtype: Any) -> int:
-    """Live VMEM of one grid step folding ``pages`` pages a fold."""
+                     dtype: Any, group: int = 1) -> int:
+    """Live VMEM of one grid step folding ``pages`` pages a fold; ``hb``
+    key-value heads a block, ``group`` query heads to each."""
     esize = jnp.dtype(dtype).itemsize
-    width = hb * head_dim
+    width, rows = hb * head_dim, hb * group
     tiles = 2 * 2 * pages * page_size * width * esize   # K+V, two slots
-    scratch = hb * width * (esize + 4) + 2 * hb * 128 * 4  # (hb, 1) pads
+    scratch = rows * width * (esize + 4) + 2 * rows * 128 * 4  # (rows, 1) pads
+    if group > 1:
+        scratch += rows * width * 4                     # the lane mask
     return tiles + scratch
 
 
 def pick_pages_per_step(*, num_heads: int, head_dim: int, page_size: int,
-                        pages_per_req: int,
-                        dtype: Any = jnp.float32) -> int:
+                        pages_per_req: int, dtype: Any = jnp.float32,
+                        num_kv_heads: Optional[int] = None) -> int:
     """Pages one fold takes: the most (a power of two ≤
     `_MAX_PAGES_PER_STEP`, no more than a request has) whose two slots a
-    pool fit the VMEM budget; 0 when not even one page does."""
-    hb = pick_head_block(num_heads, head_dim, dtype)
-    if hb == 0:
+    pool fit the VMEM budget; 0 when not even one page does. The head
+    block is picked over the KEY-VALUE heads (``num_kv_heads``; all the
+    heads when None), each with ``num_heads / num_kv_heads`` query rows."""
+    kv = num_kv_heads or num_heads
+    hb = pick_head_block(kv, head_dim, dtype)
+    if hb == 0 or num_heads % kv:
         return 0
     g = _MAX_PAGES_PER_STEP
     while g and (g > pages_per_req or _step_vmem_bytes(
-            g, page_size, hb, head_dim, dtype) > _PAGED_VMEM_BUDGET_BYTES):
+            g, page_size, hb, head_dim, dtype, num_heads // kv)
+            > _PAGED_VMEM_BUDGET_BYTES):
         g //= 2
     return g
 
 
 def page_walk_shape(*, num_heads: int, head_dim: int, page_size: int,
-                    pages_per_req: int, dtype: Any = jnp.float32) -> tuple:
+                    pages_per_req: int, dtype: Any = jnp.float32,
+                    num_kv_heads: Optional[int] = None) -> tuple:
     """``(tokens one fold covers, folds a whole table row takes)`` for a
     geometry the kernel admits: what `page_groups_walked` counts in."""
     g = pick_pages_per_step(num_heads=num_heads, head_dim=head_dim,
                             page_size=page_size, pages_per_req=pages_per_req,
-                            dtype=dtype)
+                            dtype=dtype, num_kv_heads=num_kv_heads)
     return g * page_size, -(-pages_per_req // g)
 
 
 def paged_attention_supported(*, num_heads: int, head_dim: int,
                               page_size: int, pages_per_req: int,
-                              dtype: Any = jnp.float32) -> bool:
+                              dtype: Any = jnp.float32,
+                              num_kv_heads: Optional[int] = None) -> bool:
     """True when the in-kernel page walk applies to this engine geometry.
 
     Consulted ONCE per engine (``serving/decode.py:make_step_fns``) —
@@ -180,9 +207,23 @@ def paged_attention_supported(*, num_heads: int, head_dim: int,
     flat ``heads·head_dim`` minor dim and the dtype's tile can address)
     and the VMEM tile budget. The shipped geometry — 16 heads × 64, page
     16, bf16 — compiles and decodes right on the v5e (PERF.md).
+    ``num_kv_heads`` (fewer key-value heads than query heads): the query
+    rows of a head block are then ``group`` to a key-value head, so they
+    have to fill whole sublane tiles or be all the heads, and a head's
+    lanes have to be whole lane tiles (``head_dim`` a multiple of 128).
     """
     if num_heads < 1 or pages_per_req < 1 or page_size < 1:
         return False
+    kv = num_kv_heads or num_heads
+    if kv < 1 or num_heads % kv:
+        return False
+    if kv != num_heads:
+        hb = pick_head_block(kv, head_dim, dtype)
+        rows = hb * (num_heads // kv)
+        sublanes = 8 * 4 // jnp.dtype(dtype).itemsize
+        if head_dim % 128 or hb == 0 or \
+                (hb != kv and rows % sublanes):
+            return False
     if head_dim < 8 or head_dim % 8 or head_dim > 256:
         return False
     if jnp.dtype(dtype) not in (jnp.dtype(jnp.float32),
@@ -190,7 +231,7 @@ def paged_attention_supported(*, num_heads: int, head_dim: int,
         return False
     return pick_pages_per_step(
         num_heads=num_heads, head_dim=head_dim, page_size=page_size,
-        pages_per_req=pages_per_req, dtype=dtype) > 0
+        pages_per_req=pages_per_req, dtype=dtype, num_kv_heads=kv) > 0
 
 
 def paged_sharded_supported(mesh: Any, *, num_heads: int,
@@ -208,26 +249,37 @@ def paged_sharded_supported(mesh: Any, *, num_heads: int,
         num_heads % shape.get("tensor", 1) == 0
 
 
-def page_groups_walked(lens: Any, group_tokens: int, table_groups: int):
+def first_group_walked(lens: Any, group_tokens: int, window: int):
+    """The page group a windowed row's walk starts at: the one that holds
+    position ``lens − window + 1`` (the oldest key the query sees)."""
+    xp = np if isinstance(lens, np.ndarray) else jnp
+    return xp.maximum(lens - (window - 1), 0) // group_tokens
+
+
+def page_groups_walked(lens: Any, group_tokens: int, table_groups: int,
+                       window: Optional[int] = None):
     """Page groups the kernel folds for query positions ``lens``: the
-    groups up to and including the one that holds the query, none for an
+    groups up to and including the one that holds the query (from the
+    group `first_group_walked` names, with a ``window``), none for an
     inactive row (``lens < 0``), never more than the table has. The
     kernel's trip count (a traced scalar) and the engine's
     ``serving_page_walk_share`` gauge (its host copy of the lengths, a
     NumPy array) both come from here."""
     xp = np if isinstance(lens, np.ndarray) else jnp
-    return xp.where(lens < 0, 0,
-                    xp.minimum(lens // group_tokens + 1, table_groups))
+    upto = xp.minimum(lens // group_tokens + 1, table_groups)
+    if window is not None:
+        upto = upto - xp.minimum(
+            first_group_walked(lens, group_tokens, window), upto)
+    return xp.where(lens < 0, 0, upto)
 
 
-def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm,
-                   acc_out_ref, m_out_ref, l_out_ref,
-                   k_buf, v_buf, sems, ahead_ref, qd_ref, acc_ref, m_ref,
-                   l_ref, *, pages: int, page_size: int, head_dim: int,
-                   scale: float):
+def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, *refs,
+                   pages: int, page_size: int, head_dim: int, scale: float,
+                   group: int = 1, window: Optional[int] = None):
     """One (request, head-block) grid step: the online-softmax walk over
     the page groups this request's context reaches, ``pages`` pages a
-    fold, and no further.
+    fold, and no further (nor, with a ``window``, further back than the
+    group that holds position ``lens − window + 1``).
 
     ``tables_ref``/``lens_ref``/``layer_ref`` are the scalar-prefetch
     operands (SMEM). ``k_hbm``/``v_hbm`` are the whole pools, left where
@@ -241,26 +293,41 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm,
     grid runs in order. The walk is ``page_groups_walked(lens[b])`` folds
     long. A table entry < 0 marks an invalid page — null, beyond the
     request's lazy allocation, or owned by another shard: it and every
-    page that starts past the query are not counted, their positions are
-    masked and their copies read local page 0 in their place; a group
-    with no counted page is not folded. Outputs: the f32 numerator ``[1,
-    1, hb·hd]``, m and l ``[1, hb, 1]``; the remaining scratch is the
-    block-diagonal query, the ``[hb, hb·hd]`` accumulator, m and l.
+    page that starts past the query (or ends before the window) are not
+    counted, their positions are masked and their copies read local page
+    0 in their place; a group with no counted page is not folded.
+
+    ``group == 1``: ``q_ref`` is the head block's queries side by side
+    ``[1, 1, hb·hd]``; outputs the f32 numerator ``[1, 1, hb·hd]``, m and
+    l ``[1, hb, 1]``. ``group > 1``: ``hb`` counts KEY-VALUE heads,
+    ``q_ref`` is ``[1, hb·group, hd]`` (a row a query head), a further
+    input is the 0/1 mask of the lanes each row owns ``[hb·group,
+    hb·hd]``, and the numerator comes out ``[1, hb·group, hd]``. The
+    remaining scratch is the block-diagonal query, the ``[rows, hb·hd]``
+    accumulator, m and l.
     """
+    if group > 1:
+        own_ref, refs = refs[0], refs[1:]
+    (k_hbm, v_hbm, acc_out_ref, m_out_ref, l_out_ref,
+     k_buf, v_buf, sems, ahead_ref, qd_ref, acc_ref, m_ref, l_ref) = refs
     b = pl.program_id(0)
     h = pl.program_id(1)
     rows = pl.num_programs(0)
     head_blocks = pl.num_programs(1)
-    hb, width = acc_ref.shape
+    hb, width = acc_ref.shape       # group > 1: hb is the block's QUERY rows
     span = pages * page_size
     q_pos = lens_ref[b]
     layer = layer_ref[0]
+    table_groups = tables_ref.shape[1] // pages
+
+    def first_group(row):
+        return first_group_walked(lens_ref[row], span, window)
 
     def folds(row):
-        return page_groups_walked(lens_ref[row], span,
-                                  tables_ref.shape[1] // pages)
+        return page_groups_walked(lens_ref[row], span, table_groups, window)
 
     n_groups = folds(b)
+    g0 = first_group(b) if window is not None else 0
 
     def own_lanes():
         # row h of a [hb, hb·hd] block owns lanes [h·hd, (h+1)·hd)
@@ -279,6 +346,12 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm,
         ids = [tables_ref[row, first + j] for j in range(pages)]
         ok = [jax.lax.bitwise_and(jax.lax.ge(ids[j], 0), jax.lax.ge(reach, j))
               for j in range(pages)]
+        if window is not None:
+            # ... and ending inside the window: its last key is seen
+            oldest = lens_ref[row] - (window - 1) - grp * span
+            ok = [jax.lax.bitwise_and(
+                ok[j], jax.lax.ge((j + 1) * page_size - 1, oldest))
+                for j in range(pages)]
         zero = jax.lax.full_like(reach, 0)
         return ok, [jax.lax.select(ok[j], ids[j], zero) for j in range(pages)]
 
@@ -311,7 +384,7 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm,
 
         @pl.when(jnp.logical_not(in_flight))
         def _first_group():
-            start(b, h, 0, 0)
+            start(b, h, g0, 0)
 
         # the grid step after this one that has a context — this row's
         # next head block, else the next active row — gets its first
@@ -323,21 +396,31 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm,
         next_head = jnp.where(same, h + 1, 0)
         ahead_ref[0] = (next_row < rows).astype(jnp.int32)
         ahead_ref[1] = (first_slot + n_groups) % 2
+        next_first = 0 if window is None else \
+            first_group(jnp.minimum(next_row, rows - 1))
 
-        # (select in f32: Mosaic has no relayout for a 2-byte select
-        # against the broadcast row)
-        q = jnp.broadcast_to(q_ref[0].astype(jnp.float32), (hb, width))
-        qd_ref[...] = jnp.where(own_lanes(), q, 0.0).astype(qd_ref.dtype)
+        if group == 1:
+            # (select in f32: Mosaic has no relayout for a 2-byte select
+            # against the broadcast row)
+            q = jnp.broadcast_to(q_ref[0].astype(jnp.float32), (hb, width))
+            qd_ref[...] = jnp.where(own_lanes(), q, 0.0).astype(qd_ref.dtype)
+        else:
+            # a row's query repeated under every key-value head's lanes,
+            # kept where the row owns them
+            q = q_ref[0].astype(jnp.float32)               # [rows, hd]
+            qd_ref[...] = (jnp.concatenate([q] * (width // head_dim), axis=1)
+                           * own_ref[...]).astype(qd_ref.dtype)
 
-        def fold(grp, _):
-            slot = (first_slot + grp) % 2
-            last = grp + 1 == n_groups
+        def fold(i, _):
+            grp = g0 + i
+            slot = (first_slot + i) % 2
+            last = i + 1 == n_groups
 
             @pl.when(jnp.logical_not(last) | (next_row < rows))
             def _next_group():
                 start(jnp.where(last, next_row, b),
                       jnp.where(last, next_head, h),
-                      jnp.where(last, 0, grp + 1), 1 - slot)
+                      jnp.where(last, next_first, grp + 1), 1 - slot)
 
             for c in page_copies(slot, 0, [0] * pages):  # a wait reads sizes
                 c.wait()
@@ -362,8 +445,10 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm,
                 for j in range(1, pages):
                     page_ok = jnp.where(col >= j * page_size,
                                         ok[j].astype(jnp.int32), page_ok)
-                s = jnp.where((page_ok > 0) & (grp * span + col <= q_pos),
-                              s, _NEG_INF)                 # [hb, g·ps]
+                seen = (page_ok > 0) & (grp * span + col <= q_pos)
+                if window is not None:
+                    seen = seen & (grp * span + col > q_pos - window)
+                s = jnp.where(seen, s, _NEG_INF)           # [hb, g·ps]
                 m_prev = m_ref[...]                        # [hb, 1]
                 m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
                 alpha = jnp.exp(m_prev - m_new)
@@ -371,46 +456,65 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, k_hbm, v_hbm,
                 l_ref[...] = l_ref[...] * alpha + pexp.sum(axis=1,
                                                            keepdims=True)
                 m_ref[...] = m_new
-                v = v_buf[slot].astype(jnp.float32)        # [g·ps, hb·hd]
-                acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-                    pexp, v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32, precision=exact)
+                if group > 1 and k.dtype != jnp.float32:
+                    # the probabilities in the values' dtype: one pass
+                    # of the MXU where the f32 product takes six
+                    pv = jax.lax.dot_general(
+                        pexp.astype(k.dtype), v_buf[slot],
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                else:
+                    v = v_buf[slot].astype(jnp.float32)    # [g·ps, hb·hd]
+                    pv = jax.lax.dot_general(
+                        pexp, v, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32, precision=exact)
+                acc_ref[...] = acc_ref[...] * alpha + pv
 
         jax.lax.fori_loop(0, n_groups, fold, None)
 
     # keep each head's own block of its accumulator row; m/l laid out
     # [B, nh, 1]: a (hb, 1) store satisfies Mosaic's last-two-dims tiling
     # where a 2D (1, hb) block does not — the flash kernel's lse idiom.
-    acc_out_ref[0] = jnp.where(own_lanes(), acc_ref[...], 0.0).sum(
-        axis=0, keepdims=True)
+    if group == 1:
+        acc_out_ref[0] = jnp.where(own_lanes(), acc_ref[...], 0.0).sum(
+            axis=0, keepdims=True)
+    else:
+        kept = acc_ref[...] * own_ref[...]
+        acc_out_ref[0] = functools.reduce(jnp.add, [
+            kept[:, j * head_dim:(j + 1) * head_dim]
+            for j in range(width // head_dim)])
     m_out_ref[0] = m_ref[...]
     l_out_ref[0] = l_ref[...]
 
 
 def _paged_call(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
-                tables: jax.Array, lens: jax.Array, layer: jax.Array):
+                tables: jax.Array, lens: jax.Array, layer: jax.Array,
+                window: Optional[int] = None):
     """Raw kernel invocation on one device's shard.
 
-    ``q`` ``[B, nh, hd]``, pools ``[layers, pages, page_size, nh·hd]``
-    (the whole pool), ``tables`` ``[B, pages_per_req]`` int32 with ``-1``
-    marking invalid entries, ``lens`` ``[B]`` int32 absolute query
-    positions (< 0 = inactive row), ``layer`` an int32 scalar: which
-    layer of the pool the K/V tiles are fetched from. Returns the
-    UNnormalized ``(acc [B,nh,hd] f32, m [B,nh], l [B,nh])`` triple so
-    sharded callers can run the cross-shard softmax combine before
-    dividing.
+    ``q`` ``[B, nh, hd]``, pools ``[layers, pages, page_size, kv·hd]``
+    (the whole pool; ``kv`` key-value heads, ``nh`` a multiple of it),
+    ``tables`` ``[B, pages_per_req]`` int32 with ``-1`` marking invalid
+    entries, ``lens`` ``[B]`` int32 absolute query positions (< 0 =
+    inactive row), ``layer`` an int32 scalar: which layer of the pool the
+    K/V tiles are fetched from; ``window``: a query sees the keys at the
+    last ``window`` positions only. Returns the UNnormalized ``(acc
+    [B,nh,hd] f32, m [B,nh], l [B,nh])`` triple so sharded callers can
+    run the cross-shard softmax combine before dividing.
     """
     B, nh, hd = q.shape
     ps = pool_k.shape[2]
-    hb = pick_head_block(nh, hd, pool_k.dtype)
+    kv = pool_k.shape[3] // hd
+    group = nh // kv
+    hb = pick_head_block(kv, hd, pool_k.dtype)
     span, groups = page_walk_shape(
         num_heads=nh, head_dim=hd, page_size=ps,
-        pages_per_req=tables.shape[1], dtype=pool_k.dtype)
+        pages_per_req=tables.shape[1], dtype=pool_k.dtype, num_kv_heads=kv)
     g = span // ps
     # whole page groups: the padding columns are invalid pages
     tables = jnp.pad(tables, ((0, 0), (0, groups * g - tables.shape[1])),
                      constant_values=-1)
-    width = hb * hd
+    width, rows = hb * hd, hb * group
 
     def q_map(b, h, t, l, lay):
         return b, 0, h
@@ -418,34 +522,50 @@ def _paged_call(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     def ml_map(b, h, t, l, lay):
         return b, h, 0
 
+    if group == 1:
+        q_in = q.astype(pool_k.dtype).reshape(B, 1, nh * hd)
+        q_specs = [pl.BlockSpec((1, 1, width), q_map)]
+        acc_spec = pl.BlockSpec((1, 1, width), q_map)
+        acc_shape = (B, 1, nh * hd)
+        inputs = (q_in,)
+    else:
+        # the lanes row r of a block owns: those of key-value head r // group
+        own = (jnp.arange(rows)[:, None] // group
+               == jnp.arange(width)[None, :] // hd).astype(jnp.float32)
+        q_specs = [pl.BlockSpec((1, rows, hd), ml_map),
+                   pl.BlockSpec((rows, width), lambda b, h, t, l, lay: (0, 0))]
+        acc_spec = pl.BlockSpec((1, rows, hd), ml_map)
+        acc_shape = (B, nh, hd)
+        inputs = (q.astype(pool_k.dtype), own)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, nh // hb),
-        in_specs=[pl.BlockSpec((1, 1, width), q_map),
-                  pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
+        grid=(B, kv // hb),
+        in_specs=q_specs + [pl.BlockSpec(memory_space=pl.ANY),
+                            pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=[
-            pl.BlockSpec((1, 1, width), q_map),
-            pl.BlockSpec((1, hb, 1), ml_map),
-            pl.BlockSpec((1, hb, 1), ml_map),
+            acc_spec,
+            pl.BlockSpec((1, rows, 1), ml_map),
+            pl.BlockSpec((1, rows, 1), ml_map),
         ],
         scratch_shapes=[
             _VMEM((2, g * ps, width), pool_k.dtype),
             _VMEM((2, g * ps, width), pool_v.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((2,), jnp.int32),
-            _VMEM((hb, width), pool_k.dtype),
-            _VMEM((hb, width), jnp.float32),
-            _VMEM((hb, 1), jnp.float32),
-            _VMEM((hb, 1), jnp.float32),
+            _VMEM((rows, width), pool_k.dtype),
+            _VMEM((rows, width), jnp.float32),
+            _VMEM((rows, 1), jnp.float32),
+            _VMEM((rows, 1), jnp.float32),
         ],
     )
     acc, m, l = pl.pallas_call(
         functools.partial(_decode_kernel, pages=g, page_size=ps,
-                          head_dim=hd, scale=1.0 / math.sqrt(hd)),
+                          head_dim=hd, scale=1.0 / math.sqrt(hd),
+                          group=group, window=window),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, 1, nh * hd), jnp.float32),
+            jax.ShapeDtypeStruct(acc_shape, jnp.float32),
             jax.ShapeDtypeStruct((B, nh, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, nh, 1), jnp.float32),
         ],
@@ -453,9 +573,10 @@ def _paged_call(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=ops.interpret(),
-        name="paged_decode",
+        # a device trace tells the windowed walk from the whole one by name
+        name="paged_decode" if window is None else "paged_decode_window",
     )(tables, lens, jnp.reshape(layer, (1,)).astype(jnp.int32),
-      q.astype(pool_k.dtype).reshape(B, 1, nh * hd), pool_k, pool_v)
+      *inputs, pool_k, pool_v)
     return acc.reshape(B, nh, hd), m[..., 0], l[..., 0]
 
 
@@ -476,7 +597,8 @@ def _normalize(acc: jax.Array, l: jax.Array, dtype) -> jax.Array:
 
 def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                     block_tables: jax.Array, lens: jax.Array,
-                    layer: jax.Array) -> jax.Array:
+                    layer: jax.Array,
+                    window: Optional[int] = None) -> jax.Array:
     """Single-shard paged decode attention over layer ``layer`` of the
     pool.
 
@@ -485,16 +607,20 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     scaling, f32 accumulation, output cast back to ``q.dtype``. Inactive
     rows (``lens < 0``) return exact zeros (the gather path returns
     finite null-page garbage there; both are discarded by the host).
+    The pool's minor dimension says how many key-value heads there are
+    (``q``'s heads a multiple of them); with ``window`` the softmax is
+    over the last ``window`` positions ``lens − window + 1 … lens``.
     """
     tables = _localize_tables(block_tables, 0, pool_k.shape[1])
-    acc, _, l = _paged_call(q, pool_k, pool_v, tables, lens, layer)
+    acc, _, l = _paged_call(q, pool_k, pool_v, tables, lens, layer, window)
     return _normalize(acc, l, q.dtype)
 
 
 def paged_attention_sharded(q: jax.Array, pool_k: jax.Array,
                             pool_v: jax.Array, block_tables: jax.Array,
                             lens: jax.Array, layer: jax.Array, *,
-                            mesh: Optional[Any] = None) -> jax.Array:
+                            mesh: Optional[Any] = None,
+                            window: Optional[int] = None) -> jax.Array:
     """Mesh-aware paged attention: the pool's pages stay sharded over
     ``fsdp`` and its heads over ``tensor`` (the ``serving_kv`` placement
     from ``parallel/rules.py``, taken as it is; ``layer`` is replicated)
@@ -509,7 +635,8 @@ def paged_attention_sharded(q: jax.Array, pool_k: jax.Array,
     from fleetx_tpu.parallel.rules import kv_pool_spec
 
     if mesh is None or mesh.size == 1:
-        return paged_attention(q, pool_k, pool_v, block_tables, lens, layer)
+        return paged_attention(q, pool_k, pool_v, block_tables, lens, layer,
+                               window)
 
     # the registry's serving_kv spec as it is — rules.py stays the one
     # source of placement (PartitionSpec drops trailing Nones, hence the
@@ -522,7 +649,7 @@ def paged_attention_sharded(q: jax.Array, pool_k: jax.Array,
     def body(q, pk, pv, tabs, lens, layer):
         lo = jax.lax.axis_index(pages_ax) * local_pages
         tabs = _localize_tables(tabs, lo, local_pages)
-        acc, m, l = _paged_call(q, pk, pv, tabs, lens, layer)
+        acc, m, l = _paged_call(q, pk, pv, tabs, lens, layer, window)
         # flash-decoding combine across the page shards: rescale every
         # shard's numerator/denominator to the global running max, sum
         m_g = jax.lax.pmax(m, pages_ax)

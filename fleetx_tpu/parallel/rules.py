@@ -11,7 +11,7 @@ stage shards which term, and the serving KV pool hand-wired its own
 hardware. Here the whole mapping is *data*:
 
 - ``PARTITION_RULES``: per model family (``gpt``, ``gpt_moe``,
-  ``gpt_lora``, ``vision``, ``ernie``, ``imagen``, ``mla_moe``, plus the serving KV
+  ``gpt_lora``, ``vision``, ``ernie``, ``imagen``, ``mla_moe``, ``swa_moe``, plus the serving KV
   pool as ``serving_kv``), an
   ORDERED tuple of ``(regex, logical-axes template)`` rules matched against
   slash-joined parameter-tree paths, first match wins — the
@@ -320,6 +320,23 @@ PARTITION_RULES: dict[str, tuple] = {
         (r"mtp/proj$", (None, "embed")),
         (r"(^|/)\w*norm/scale$", ("norm",)),
     ),
+    # the windowed-attention sparse-expert decoder (models/swa_moe;
+    # served): queries, keys and the output projection a [head_dim, hidden]
+    # matrix a head, heads over the Megatron axis; values and the per-head
+    # gates with the heads in their minor dimension; the held experts over
+    # ``expert``, the router (ALL experts) replicated
+    "swa_moe": (
+        (r"attn/(q|k|out)$", ("heads", "kv", "embed")),
+        (r"attn/(v|gate)$", ("embed", "heads")),
+        (r"(mlp/|moe/shared_)(gate|up)$", ("embed", "mlp")),
+        (r"(mlp/|moe/shared_)down$", ("mlp", "embed")),
+        (r"moe/router$", ("embed", None)),
+        (r"moe/experts_(gate|up)$", ("expert", "embed", None)),
+        (r"moe/experts_down$", ("expert", None, "embed")),
+        (r"embed/tokens$", ("vocab", "embed")),
+        (r"head/kernel$", ("embed", "vocab")),
+        (r"(^|/)\w*norm/scale$", ("norm",)),
+    ),
     # the serving KV page pool (serving/paged_cache.py): pages over the
     # ZeRO axis (capacity scales with fsdp), heads over the Megatron axis
     # (heads and head_dim share the pool's minor dim, heads major)
@@ -338,6 +355,7 @@ STACK_MARKERS: dict[str, str] = {
     "vision": r"(^|/)blocks/",
     "ernie": r"(^|/)layers/",
     "mla_moe": r"(^|/)(dense_layers|moe_layers|mtp/layers)/",
+    "swa_moe": r"(^|/)(full|window)_(dense|moe)/",
 }
 
 #: families whose fully-replicated leaves are accepted at ANY size by the
@@ -554,11 +572,28 @@ def stage_shards(term: str, stage: int) -> bool:
 
 # ------------------------------------------------------- derived one-liners
 
-def kv_pool_spec(layout: Optional[SpecLayout] = None):
+def kv_pool_spec(layout: Optional[SpecLayout] = None, *,
+                 num_kv_heads: Optional[int] = None,
+                 tensor_degree: Optional[int] = None):
     """The serving KV pool's placement, resolved through the registry
-    (family ``serving_kv``): pages over ``fsdp``, heads over ``tensor``."""
+    (family ``serving_kv``): pages over ``fsdp``, heads over ``tensor``.
+
+    The heads the pool's minor dimension holds are the KEY-VALUE heads;
+    told how many there are and how wide the ``tensor`` axis is, it
+    refuses a split that would cut a head in two: a head's ``head_dim``
+    values are one contiguous run of a token's row, the decode kernel
+    reads whole heads, and ``tensor`` devices beyond the key-value heads
+    would each need a head another device holds."""
     from jax.sharding import PartitionSpec as P
 
+    if num_kv_heads is not None and tensor_degree is not None and \
+            num_kv_heads % tensor_degree:
+        raise ValueError(
+            f"the KV pool's heads axis holds {num_kv_heads} key-value "
+            f"heads; a tensor axis of {tensor_degree} does not divide "
+            f"them (a head is not split, and a device cannot hold a "
+            f"fraction of one): keep tensor_degree a divisor of the "
+            f"key-value heads or replicate the pool's heads")
     return P(*spec_for("serving_kv", "kv_pool/k", (1, 2, 2, 2),
                        layout or SpecLayout()))
 

@@ -103,7 +103,7 @@ def _sample_batch(module: Any, family: str) -> dict:
         s = int(module.model_cfg.max_position_embeddings)
         tok = np.zeros((1, s), np.int32)
         return {"tokens": tok, "position_ids": tok.copy()}
-    if family == "mla_moe":
+    if family in ("mla_moe", "swa_moe"):
         tok = np.zeros((1, int(module.tokens_per_sample)), np.int32)
         return {"tokens": tok, "position_ids": tok.copy()}
     if family == "ernie":
@@ -165,8 +165,12 @@ def _kv_pool_leaves(cfg: dict, module: Any) -> Optional[list]:
 
     num_pages = int(serving.get("num_pages") or 256)
     page_size = int(serving.get("page_size") or 16)
-    k, v = jax.eval_shape(
-        lambda: init_pool(module.model_cfg, num_pages, page_size))
+    if hasattr(module, "kv_pool_shape"):    # not every layer keeps every token
+        k = v = jax.ShapeDtypeStruct(
+            module.kv_pool_shape(num_pages, page_size), module.model_cfg.dtype)
+    else:
+        k, v = jax.eval_shape(
+            lambda: init_pool(module.model_cfg, num_pages, page_size))
     return [("kv_pool/k", k), ("kv_pool/v", v)]
 
 

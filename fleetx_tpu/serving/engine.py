@@ -1,8 +1,13 @@
 """Continuous-batching serving engine over the paged KV cache.
 
-One ``ServingEngine`` owns the device state (params + page pools + the two
-jitted step programs from ``serving/decode.py``) and the host state (slot
-table, block tables, page allocator, request queues). The scheduler runs
+One ``ServingEngine`` owns the device state (params + cache buffers + the
+two jitted step programs of its model family: ``serving/registry.py``
+finds the family, ``serving/decode.py`` is GPT's, ``serving/swa_moe.py`` the
+windowed-attention sparse-expert one's) and the host state (slot table,
+block tables, page allocator, request queues). Whatever the family, the
+pages the allocator hands out and the block table a request holds are those
+of the layers that keep every token; a family's other caches (a ring a
+slot for window layers) cost the host nothing. The scheduler runs
 the vLLM-style loop, one ``step()`` per iteration:
 
 1. **admit** — waiting requests take a free decode slot + a **lazy** page
@@ -63,10 +68,9 @@ from fleetx_tpu.observability.metrics import get_registry
 from fleetx_tpu.observability.slo import SLORegistry
 from fleetx_tpu.observability.trace import span
 from fleetx_tpu.ops import paged_attention as PA
-from fleetx_tpu.serving.decode import (SamplingParams, make_step_fns,
-                                       paged_kernel_enabled, serving_params)
-from fleetx_tpu.serving.paged_cache import (NULL_PAGE, PageAllocator,
-                                            init_pool, pool_shardings)
+from fleetx_tpu.serving import registry
+from fleetx_tpu.serving.decode import SamplingParams
+from fleetx_tpu.serving.paged_cache import NULL_PAGE, PageAllocator
 from fleetx_tpu.utils.env import log_compile
 from fleetx_tpu.utils.log import logger
 
@@ -375,8 +379,9 @@ class ServingEngine:
         self.pages_per_req = -(-self.max_seq_len // sc.page_size)
 
         # cast ONCE, here: the programs convert no parameter (decode.py)
+        self.family = registry.family_of(model_cfg)
         given = meta.unbox(params)
-        self.params = serving_params(given, model_cfg)
+        self.params = self.family.serving_params(given, model_cfg)
         cast = [a for a, b in zip(jax.tree.leaves(given),
                                   jax.tree.leaves(self.params)) if a is not b]
         weights = "%d leaves cast%s, serving tree %d bytes" % (
@@ -385,38 +390,20 @@ class ServingEngine:
                            np.dtype(model_cfg.dtype).name) if cast else "",
             sum(a.nbytes for a in jax.tree.leaves(self.params)))
         self.allocator = PageAllocator(sc.num_pages, sc.page_size)
-        self.pool_k, self.pool_v = init_pool(model_cfg, sc.num_pages,
-                                             sc.page_size)
-        sharding = None
-        if mesh is not None:
-            sharding = pool_shardings(mesh)
-            self.pool_k = jax.device_put(self.pool_k, sharding)
-            self.pool_v = jax.device_put(self.pool_v, sharding)
-        # kernel-vs-gather is decided HERE, once: the support predicates
-        # are static functions of the config/pool/mesh, so the decode
-        # program compiles exactly one attention path and the jit cache
-        # stays pinned at one entry (test_serving pins this)
-        self.paged_kernel_active = bool(sc.paged_kernel) and \
-            paged_kernel_enabled(
-                model_cfg, page_size=sc.page_size, num_pages=sc.num_pages,
-                pages_per_req=self.pages_per_req, pool_sharding=sharding)
-        self._fns = make_step_fns(
-            model_cfg, max_batch=sc.max_batch,
-            pages_per_req=self.pages_per_req,
-            prefill_chunk=sc.prefill_chunk, sampling=self.sampling,
-            quantize=bool(sc.quantize_decode), pool_sharding=sharding,
-            paged_kernel=self.paged_kernel_active)
-
+        # the family's cache buffers and its two programs; kernel-vs-gather
+        # is decided there, once, so each jit cache stays at one entry. The
+        # buffers are donated to every call and rebound from its result
+        self._programs = self.family.programs(
+            model_cfg, sc, self.sampling, mesh, self.pages_per_req)
+        self.cache: list = list(self._programs.cache)
+        self._programs.cache = []       # the engine holds the only handles
+        self._fns = self._programs.fns
+        self.paged_kernel_active = self._programs.paged_kernel_active
+        self.cache_bytes = sum(int(a.nbytes) for a in self.cache)
         # what one fold of the decode kernel covers and how many a whole
         # table row would take: the serving_page_walk_share gauge counts a
         # tick's folds with the helper the kernel's trip count uses
-        self._walk_shape = None
-        if self.paged_kernel_active:
-            self._walk_shape = PA.page_walk_shape(
-                num_heads=model_cfg.num_attention_heads // (
-                    mesh.shape["tensor"] if mesh is not None else 1),
-                head_dim=model_cfg.head_dim, page_size=sc.page_size,
-                pages_per_req=self.pages_per_req, dtype=model_cfg.dtype)
+        self._walk_shape = self._programs.walk_shape
         # the query positions the last decode call was given (kernel path)
         self._decoded_lens: Optional[np.ndarray] = None
 
@@ -463,12 +450,25 @@ class ServingEngine:
         logger.info(
             "serving engine: max_batch=%d pages=%d x %d tokens "
             "(capacity %d token slots/layer), prefill_chunk=%d, "
-            "quantize_decode=%s, decode=%s, alloc=%s, weights: %s",
+            "quantize_decode=%s, decode=%s, alloc=%s, cache %d bytes%s, "
+            "weights: %s",
             sc.max_batch, self.allocator.usable_pages,
             sc.page_size, self.allocator.usable_pages * sc.page_size,
             sc.prefill_chunk, bool(sc.quantize_decode),
             "paged_kernel" if self.paged_kernel_active else "gather",
-            "lazy" if sc.lazy_alloc else "reserve", weights)
+            "lazy" if sc.lazy_alloc else "reserve", self.cache_bytes,
+            " (%s)" % self._programs.describe
+            if self._programs.describe else "", weights)
+
+    # the first two cache buffers are the paged pool's K and V in every
+    # family (GPT has no others)
+    @property
+    def pool_k(self):
+        return self.cache[0]
+
+    @property
+    def pool_v(self):
+        return self.cache[1]
 
     # ------------------------------------------------------------ submission
     def submit(self, prompt: list, max_new_tokens: int,
@@ -645,6 +645,14 @@ class ServingEngine:
             log_compile(f"serving {name}", fn, *args)
         return fn(*args)
 
+    def _rebind(self, out: tuple) -> tuple:
+        """A program's result starts with the cache buffers it was given
+        (donated): keep those, return what follows — ``(token(s), logits[,
+        counters])``."""
+        n = len(self.cache)
+        self.cache = list(out[:n])
+        return out[n:]
+
     def _next_rng(self) -> jax.Array:
         self._rng, sub = jax.random.split(self._rng)
         return sub
@@ -666,9 +674,10 @@ class ServingEngine:
             if req.prefill_started_at is None:
                 req.prefill_started_at = time.monotonic()
                 self.timelines.note(req.id, "prefill_started")
-            self.pool_k, self.pool_v, tok, _ = self._call(
-                "prefill", self.params, self.pool_k, self.pool_v, tokens,
-                table, np.int32(pos), np.int32(n_valid), self._next_rng())
+            tok = self._rebind(self._call(
+                "prefill", self.params, *self.cache, tokens, table,
+                np.int32(pos), np.int32(n_valid), self._next_rng(),
+                *self._programs.prefill_extra(req.slot)))[0]
             req.prefill_pos = pos + n_valid
             self.timelines.note(req.id, "prefill_chunk", chunk=index,
                                 tokens=n_valid)
@@ -871,15 +880,17 @@ class ServingEngine:
         with _Phase(self, "decode"):
             if self._walk_shape is not None:
                 self._decoded_lens = self._lens.copy()
-            self.pool_k, self.pool_v, toks, _ = self._call(
-                "decode", self.params, self.pool_k, self.pool_v,
-                self._last_tokens, self._block_tables, self._lens,
-                self._next_rng())
+            toks, _, *counters = self._rebind(self._call(
+                "decode", self.params, *self.cache, self._last_tokens,
+                self._block_tables, self._lens, self._next_rng()))
         with _Phase(self, "decode.wait"):
-            toks = jax.device_get(toks)
+            # a family's step counters ride with the tokens: one device_get
+            toks, counters = jax.device_get((toks, counters))
         self._drained_at = self._phase_end
         with _Phase(self, "emit"):
             now = time.monotonic()
+            if counters and self._programs.record_stats is not None:
+                self._programs.record_stats(self.metrics, counters[0])
             for req in running:
                 tok = int(toks[req.slot])
                 self._lens[req.slot] += 1  # the step wrote position `lens`
@@ -1030,11 +1041,14 @@ class ServingEngine:
                      "serving_requests_refused", "serving_requests_preempted",
                      "serving_tokens_total", "serving_deadline_sheds",
                      "serving_refusals_overloaded",
-                     "serving_refusals_unmeetable"):
+                     "serving_refusals_unmeetable",
+                     "serving_moe_pairs_held_total",
+                     "serving_moe_pairs_total"):
             self.metrics.counter(name).reset()
         for name in ("serving_ttft", "serving_inter_token", "serving_tick",
                      "serving_chunk_tick", "serving_queue_wait",
-                     "serving_prefill_wait", "serving_prefill_run"):
+                     "serving_prefill_wait", "serving_prefill_run",
+                     "serving_moe_experts_hit"):
             h = self.metrics.histogram(name)
             h.reset()
             h.total_count = 0
@@ -1056,6 +1070,12 @@ class ServingEngine:
             self.allocator.occupancy())
         self.metrics.gauge("serving_kv_fragmentation").set(
             self.allocator.internal_fragmentation(self._used_slots()))
+        # tokens the running rows hold, a layer of each kind of cache, and
+        # the bytes of all cache buffers (fixed when the engine is built)
+        full, window = self._programs.kv_tokens(self._lens)
+        self.metrics.gauge("serving_kv_full_tokens").set(full)
+        self.metrics.gauge("serving_kv_window_tokens").set(window)
+        self.metrics.gauge("serving_kv_cache_bytes").set(self.cache_bytes)
         if self._decoded_lens is not None:
             # of the page groups in the block table, the share this tick's
             # decode call folded (1.0: every row at the end of its table)

@@ -64,7 +64,19 @@ def init_pool(cfg: Any, num_pages: int, page_size: int,
     """Allocate the (K, V) page pools for a GPT config.
 
     ``num_pages`` INCLUDES the reserved null page, so usable capacity is
-    ``(num_pages - 1) * page_size`` token slots per layer.
+    ``(num_pages - 1) * page_size`` token slots per layer, and the pool's
+    bytes are ``2 (K, V) * layers * num_pages * page_size * heads *
+    head_dim * itemsize``.
+
+    A family whose layers do not all keep every token sizes two caches
+    (``serving/swa_moe.py:init_cache``; docs/serving.md "Sizing the
+    pool"): this pool over its FULL-attention layers and KEY-VALUE heads
+    only — ``2 * full_layers * num_pages * page_size * kv_heads * head_dim
+    * itemsize`` — which is what ``num_pages``, admission, growth and
+    preemption count; and for its window layers a ring a decode slot,
+    ``2 * window_layers * (1 + max_batch * ceil((window + prefill_chunk) /
+    page_size)) * page_size * kv_heads * head_dim * itemsize``, which does
+    not depend on ``max_seq_len`` or ``num_pages`` at all.
     """
     dtype = dtype or cfg.dtype
     shape = (cfg.num_layers, int(num_pages), int(page_size),

@@ -1,0 +1,396 @@
+"""The serving programs of the windowed-attention sparse-expert family
+(``models/swa_moe``): what ``serving/decode.py`` is to the GPT block.
+
+Two jitted programs with static shapes, ``prefill`` (one chunk of one
+request) and ``decode`` (one token for every slot), built once an engine
+and called by the same scheduler as GPT's (``serving/registry.py``).
+
+**Two caches under one engine.** Full-attention layers keep every token: a
+paged pool ``[full layers, pages, page_size, kv_heads · head_dim]``, K and
+V, addressed through the request's block table, grown and freed by the
+engine's ``PageAllocator`` exactly as GPT's pool is. Window layers never
+need more than the window: a RING per decode slot, ``[window layers, 1 +
+slots · ring_pages, page_size, kv_heads · head_dim]`` with ``ring_pages =
+ceil((window + prefill_chunk) / page_size)``; the token at position *p*
+lives in the slot's ring at ``p mod ring_tokens``, so a slot's ring bytes
+do not depend on ``max_seq_len``, nothing is allocated or released as a
+request grows, and the host does no work for it. Page 0 of either buffer is
+the null page (masked rows and the tail of a ragged chunk write there). A
+chunk's keys are written before its queries read, and the ``prefill_chunk``
+tokens they overwrite are older than the window of every query in the
+chunk: that is what the extra chunk of ring is for. A preempted request is
+prefilled again from its first token, which rebuilds its ring.
+
+All four buffers ride the carry of every layer loop and are donated: each
+stays one buffer from a program's input to its output (``decode.py`` has
+the reasons; ``tests/test_tpu_lowering.py`` pins it for these programs).
+
+**Layers in the published order.** The layers of one shape are stacked;
+the order (``SWAMoEConfig.runs``) is walked run by run, each run a loop
+over its slice of its stack, the stack indexed inside the loop as a layer
+scan indexes its operand. The experts' stacks are never indexed by layer at
+all: ``moe_gmm`` reads tile *t*'s matrix at ``layer · held + expert(t)`` of
+the stack seen flat.
+
+**Attention.** Decode: ``ops/paged_attention.py`` with 8 key-value heads
+under 48 or 72 query heads — the full layers over the request's pages, the
+window layers over the slot's ring through a static table (logical page
+*j* → ring page ``j mod ring_pages``) with ``window=`` set, so a row's walk
+starts at the page that holds ``len − window + 1``. Where the kernel does
+not admit the geometry (toy widths), the gathered view. Prefill: the gather
+path — a full layer folds the request's pages a block of keys at a time up
+to the chunk's end (a loop as long as the context, online softmax), a
+window layer reads its slot's ring whole.
+
+**Parameters**: bfloat16, but the norms' scales and the router in
+float32; ``serving_params`` makes that tree once and the programs refuse
+any other.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+
+from fleetx_tpu.models.swa_moe import model as M
+from fleetx_tpu.models.swa_moe.config import FULL, WINDOW, SWAMoEConfig
+from fleetx_tpu.ops import paged_attention as PA
+from fleetx_tpu.serving.decode import SamplingParams, _sample
+
+_NEG = -1e30
+
+
+# -------------------------------------------------------------------- caches
+def ring_pages(cfg: SWAMoEConfig, page_size: int, prefill_chunk: int) -> int:
+    """Pages of one slot's ring: the window plus one prefill chunk."""
+    return -(-(cfg.sliding_window + int(prefill_chunk)) // int(page_size))
+
+
+def cache_shapes(cfg: SWAMoEConfig, *, num_pages: int, page_size: int,
+                 max_batch: int, prefill_chunk: int) -> tuple:
+    """``(full pool shape, ring shape)``; each exists twice, K and V."""
+    width = cfg.num_key_value_heads * cfg.head_dim
+    rp = ring_pages(cfg, page_size, prefill_chunk)
+    return ((cfg.layers_of("full"), int(num_pages), int(page_size), width),
+            (cfg.layers_of("window"), 1 + int(max_batch) * rp,
+             int(page_size), width))
+
+
+def init_cache(cfg: SWAMoEConfig, *, num_pages: int, page_size: int,
+               max_batch: int, prefill_chunk: int) -> tuple:
+    """``(full_k, full_v, ring_k, ring_v)``, zeros in ``cfg.dtype``.
+
+    Full layers: ``num_pages`` INCLUDES the null page, so the usable
+    capacity is ``(num_pages - 1) * page_size`` token slots a full layer —
+    what admission, growth and preemption count. Window layers: ``slots ·
+    ring_pages · page_size`` token slots a window layer whatever
+    ``max_seq_len`` is (+ the null page)."""
+    full, ring = cache_shapes(cfg, num_pages=num_pages, page_size=page_size,
+                              max_batch=max_batch,
+                              prefill_chunk=prefill_chunk)
+    z = lambda shape: jnp.zeros(shape, cfg.dtype)  # noqa: E731
+    return z(full), z(full), z(ring), z(ring)
+
+
+def paged_kernel_enabled(cfg: SWAMoEConfig, *, page_size: int,
+                         pages_per_req: int) -> bool:
+    """Whether ``ops/paged_attention.py`` admits every layer's geometry."""
+    return all(PA.paged_attention_supported(
+        num_heads=h, head_dim=cfg.head_dim, page_size=page_size,
+        pages_per_req=pages_per_req, dtype=cfg.dtype,
+        num_kv_heads=cfg.num_key_value_heads)
+        for h in set(cfg.num_attention_heads_per_layer))
+
+
+# ---------------------------------------------------------------- parameters
+def _unserved(params: Any, cfg: SWAMoEConfig) -> list:
+    flat, _ = jax.tree_util.tree_flatten_with_path(params)
+    return [i for i, (path, leaf) in enumerate(flat)
+            if leaf.dtype != M.served_dtype(path, cfg)]
+
+
+def serving_params(params: Any, cfg: SWAMoEConfig) -> Any:
+    """The tree both programs take: every leaf in ``cfg.dtype`` but the
+    norms' scales and the router (float32). One jitted cast of the leaves
+    that need it; a leaf already served comes back as the object it was."""
+    todo = _unserved(params, cfg)
+    if not todo:
+        return params
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    want = [M.served_dtype(flat[i][0], cfg) for i in todo]
+    cast = jax.jit(lambda xs: [x.astype(d) for x, d in zip(xs, want)])(
+        [flat[i][1] for i in todo])
+    leaves = [leaf for _, leaf in flat]
+    for i, leaf in zip(todo, cast):
+        leaves[i] = leaf
+    return treedef.unflatten(leaves)
+
+
+# ----------------------------------------------------------------- attention
+def _gathered_attention(q, k, v, key_pos, q_pos, window, dtype):
+    """``q`` [B, S, H, hd] against gathered keys ``k``/``v`` [B, K, kv, hd]
+    that hold the tokens at absolute positions ``key_pos`` [B, K] (< 0: no
+    token): softmax over the keys at ``q_pos − window < p ≤ q_pos``."""
+    B, S, H, hd = q.shape
+    kv = k.shape[2]
+    qg = q.reshape(B, S, kv, H // kv, hd)
+    s = jnp.einsum("bskgd,btkd->bkgst", qg, k,
+                   preferred_element_type=jnp.float32) / math.sqrt(hd)
+    kp, qp = key_pos[:, None, :], q_pos[:, :, None]
+    seen = (kp >= 0) & (kp <= qp)
+    if window is not None:
+        seen = seen & (kp > qp - window)
+    s = jnp.where(seen[:, None, None], s, _NEG)
+    p = jax.nn.softmax(s, axis=-1).astype(dtype)
+    o = jnp.einsum("bkgst,btkd->bskgd", p, v,
+                   preferred_element_type=jnp.float32)
+    return o.reshape(B, S, H, hd).astype(dtype)
+
+
+def _prefill_full_attention(q, pool_k, pool_v, layer, table, q_pos, n_keys,
+                            key_block: int, dtype):
+    """One chunk's queries ``q`` [1, C, H, hd] against the request's pages
+    of layer ``layer``, ``key_block`` keys at a time, up to key ``n_keys``
+    (a loop as long as the context; online softmax in float32)."""
+    _, C, H, hd = q.shape
+    ps, width = pool_k.shape[2], pool_k.shape[3]
+    kv = width // hd
+    per = key_block // ps
+    cols = -(-table.shape[1] // per) * per
+    row = jnp.pad(table[0], (0, cols - table.shape[1]))    # null pages
+    qg = q[0].reshape(C, kv, H // kv, hd)
+    qp = q_pos[0][None, None, :, None]
+
+    def body(j, state):
+        m, l, acc = state
+        pages = jax.lax.dynamic_slice(row, (j * per,), (per,))
+        k = pool_k[layer, pages].reshape(key_block, kv, hd)
+        v = pool_v[layer, pages].reshape(key_block, kv, hd)
+        s = jnp.einsum("ckgd,tkd->kgct", qg, k,
+                       preferred_element_type=jnp.float32) / math.sqrt(hd)
+        kp = j * key_block + jnp.arange(key_block, dtype=jnp.int32)
+        s = jnp.where(kp[None, None, None, :] <= qp, s, _NEG)
+        m_new = jnp.maximum(m, s.max(-1))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new[..., None])
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "kgct,tkd->kgcd", p.astype(dtype), v,
+            preferred_element_type=jnp.float32)
+        return m_new, l * alpha + p.sum(-1), acc
+
+    shape = (kv, H // kv, C)
+    m, l, acc = jax.lax.fori_loop(
+        0, (n_keys + key_block - 1) // key_block, body,
+        (jnp.full(shape, _NEG, jnp.float32), jnp.zeros(shape, jnp.float32),
+         jnp.zeros(shape + (hd,), jnp.float32)))
+    o = acc / jnp.where(l == 0.0, 1.0, l)[..., None]
+    return jnp.transpose(o, (2, 0, 1, 3)).reshape(1, C, H, hd).astype(dtype)
+
+
+# ------------------------------------------------------------------- forward
+def _forward(params: Any, cfg: SWAMoEConfig, tokens, positions, cache,
+             block_tables, slots, last, *, rp: int, view: int, decode: bool,
+             paged_kernel: bool, moe_kernel: str):
+    """``tokens`` [B, S] at absolute ``positions`` [B, S] (< 0: no token)
+    through every layer in the published order. ``cache`` is ``(full_k,
+    full_v, ring_k, ring_v)``; ``block_tables`` [B, pages_per_req] the
+    rows' pages in the full pool; ``slots`` [B] whose ring each row is;
+    ``last`` [B] the last position each row holds after this call; ``rp``
+    the pages of one slot's ring and ``view`` how many of them, ending at
+    the page of ``last``, a gathered window layer reads (window + chunk).
+    Returns
+    ``(hidden [B, S, h], cache, stats)``; ``stats``: held experts hit,
+    summed over the expert layers, and (token, expert) pairs on held
+    experts."""
+    unserved = _unserved(params, cfg)
+    if unserved:
+        raise TypeError(
+            "the serving programs take the tree serving_params() makes: "
+            f"{len(unserved)} leaves are not in their served dtype")
+    B, S = tokens.shape
+    dt = cfg.dtype
+    hd, kv = cfg.head_dim, cfg.num_key_value_heads
+    full_k = cache[0]
+    ps, P = full_k.shape[2], block_tables.shape[1]
+    window = cfg.sliding_window
+    moe_pass_rows = M.pass_rows(cfg, B * S)
+    # keys a block of the prefill's full-layer attention scores at once:
+    # as many as the chunk has queries, in whole pages
+    key_block = -(-S // ps) * ps
+
+    x = params["embed"]["tokens"][jnp.maximum(tokens, 0)]
+    valid = positions >= 0
+    q_pos = jnp.maximum(positions, 0)
+    offs = jnp.clip(positions % ps, 0, ps - 1)
+    # where each (row, slot) is written: the request's page, the slot's ring
+    page_slot = jnp.clip(positions // ps, 0, P - 1)
+    full_pages = jnp.where(
+        valid, jnp.take_along_axis(block_tables, page_slot, axis=1), 0)
+    ring_first = 1 + slots * rp                               # [B]
+    ring_at = jnp.where(
+        valid, ring_first[:, None] + (q_pos // ps) % rp, 0)
+    # the ring as a block table: logical page j -> ring page j mod rp
+    ring_table = ring_first[:, None] + \
+        jnp.arange(P, dtype=jnp.int32)[None, :] % rp
+    # the gathered view of a ring: the ``view`` logical pages that end at
+    # the page of ``last``, in order, and the position of each key in them
+    view_first = (jnp.maximum(last, 0) // ps - (view - 1))[:, None] \
+        + jnp.arange(view, dtype=jnp.int32)[None, :]          # [B, view]
+    view_pages = ring_first[:, None] + view_first % rp
+    view_pos = (view_first[:, :, None] * ps + jnp.arange(
+        ps, dtype=jnp.int32)[None, None, :]).reshape(B, view * ps)
+    tables = {t: M.rotary_tables(cfg, t, q_pos) for t in (FULL, WINDOW)}
+    valid_tok = valid.reshape(B * S)
+
+    def attention(kind_type, u, lp, cache, at):
+        q = jnp.einsum("bsh,ndh->bsnd", u, lp["q"])
+        k = jnp.einsum("bsh,ndh->bsnd", u, lp["k"])
+        v = jnp.einsum("bsh,hn->bsn", u, lp["v"])
+        cos, sin = tables[kind_type]
+        q, k = M.apply_rotary(q, cos, sin), M.apply_rotary(k, cos, sin)
+        full_k, full_v, ring_k, ring_v = cache
+        k_rows = k.reshape(B, S, kv * hd)
+        if kind_type == FULL:
+            full_k = full_k.at[at, full_pages, offs].set(k_rows)
+            full_v = full_v.at[at, full_pages, offs].set(v)
+            if decode and paged_kernel:
+                o = PA.paged_attention(q[:, 0], full_k, full_v, block_tables,
+                                       positions[:, 0], at)[:, None]
+            elif decode:
+                kd = full_k[at, block_tables].reshape(B, -1, kv, hd)
+                vd = full_v[at, block_tables].reshape(B, -1, kv, hd)
+                kp = jnp.broadcast_to(jnp.arange(P * ps, dtype=jnp.int32),
+                                      (B, P * ps))
+                o = _gathered_attention(q, kd, vd, kp, q_pos, None, dt)
+            else:
+                o = _prefill_full_attention(
+                    q, full_k, full_v, at, block_tables, q_pos, last[0] + 1,
+                    key_block, dt)
+        else:
+            ring_k = ring_k.at[at, ring_at, offs].set(k_rows)
+            ring_v = ring_v.at[at, ring_at, offs].set(v)
+            if decode and paged_kernel:
+                o = PA.paged_attention(q[:, 0], ring_k, ring_v, ring_table,
+                                       positions[:, 0], at,
+                                       window=window)[:, None]
+            else:
+                kd = ring_k[at, view_pages].reshape(B, -1, kv, hd)
+                vd = ring_v[at, view_pages].reshape(B, -1, kv, hd)
+                o = _gathered_attention(q, kd, vd, view_pos, q_pos, window,
+                                        dt)
+        gate = jax.nn.sigmoid(jnp.einsum(
+            "bsh,hn->bsn", u, lp["gate"],
+            preferred_element_type=jnp.float32))
+        o = (o.astype(jnp.float32) * gate[..., None]).astype(dt)
+        y = jnp.einsum("bsnd,ndh->bsh", o, lp["out"])
+        return y, (full_k, full_v, ring_k, ring_v)
+
+    def run(kind, lo, n, cache_lo, carry):
+        stack = params[kind]
+        kind_type = WINDOW if kind.startswith("window") else FULL
+        dense = kind.endswith("dense")
+        per_layer = {k: v for k, v in stack.items() if k != "moe"}
+        if not dense:
+            per_layer["moe"] = {k: v for k, v in stack["moe"].items()
+                                if not k.startswith("experts_")}
+
+        def layer(i, carry):
+            x, cache, hit, pairs = carry
+            lp = jax.tree.map(lambda w: w[i], per_layer)
+            u = M.rms_norm(x, lp["attn_norm"]["scale"], cfg.rms_norm_eps, dt)
+            y, cache = attention(kind_type, u, lp["attn"], cache,
+                                 cache_lo + (i - lo))
+            x = x + y
+            u = M.rms_norm(x, lp["mlp_norm"]["scale"], cfg.rms_norm_eps, dt)
+            if dense:
+                y = M.gated_mlp(u, lp["mlp"]["gate"], lp["mlp"]["up"],
+                                lp["mlp"]["down"]).astype(dt)
+            else:
+                u2d = u.reshape(B * S, -1)
+                ids, weights = M.route(u2d, lp["moe"]["router"], cfg)
+                ids = jnp.where(valid_tok[:, None], ids, -1)
+                routed, rows = M.held_experts(
+                    u2d, ids, weights, stack["moe"], i, cfg, moe_pass_rows,
+                    moe_kernel)
+                shared = M.gated_mlp(u2d, lp["moe"]["shared_gate"],
+                                     lp["moe"]["shared_up"],
+                                     lp["moe"]["shared_down"])
+                y = (routed + shared).astype(dt).reshape(B, S, -1)
+                hit = hit + (rows > 0).sum().astype(jnp.float32)
+                pairs = pairs + rows.sum().astype(jnp.int32)
+            return x + y, cache, hit, pairs
+
+        if n == 1:      # a static index: the layer is a view of its stack
+            return layer(lo, carry)
+        return jax.lax.fori_loop(lo, lo + n, layer, carry)
+
+    carry = (x, tuple(cache), jnp.float32(0.0), jnp.int32(0))
+    for kind, lo, n, cache_lo in cfg.runs():
+        carry = run(kind, lo, n, cache_lo, carry)
+    x, cache, hit, pairs = carry
+    x = M.rms_norm(x, params["final_norm"]["scale"], cfg.rms_norm_eps, dt)
+    return x, cache, {"hit": hit, "pairs_held": pairs}
+
+
+def _logits(params: Any, x_last: jax.Array) -> jax.Array:
+    """The (untied) head on the selected positions -> float32 ``[B, V]``."""
+    return jnp.einsum("bh,hv->bv", x_last, params["head"]["kernel"],
+                      preferred_element_type=jnp.float32)
+
+
+def make_step_fns(cfg: SWAMoEConfig, *, prefill_chunk: int, page_size: int,
+                  sampling: SamplingParams,
+                  paged_kernel: bool = False) -> dict:
+    """The two jitted programs of one engine, ``{"prefill", "decode"}``.
+
+    Both take ``(params, full_k, full_v, ring_k, ring_v, ...)``, donate the
+    four cache buffers and return them first. ``prefill`` then takes what
+    GPT's takes and the slot whose ring the request owns; ``decode`` what
+    GPT's takes. After the caches come the sampled token(s), the float32
+    logits and, from ``decode``, the step's expert counters (held experts
+    hit, summed over the expert layers; pairs on held experts): they ride
+    to the host with the tokens. Shapes are static (``max_batch`` /
+    ``pages_per_req`` / ``prefill_chunk`` arrive with the arrays), so each
+    jit cache holds one entry for the engine's lifetime."""
+    rp = ring_pages(cfg, page_size, prefill_chunk)
+
+    def prefill(params, full_k, full_v, ring_k, ring_v, tokens, block_table,
+                start, n_valid, rng, slot):
+        """One prompt chunk of the request in slot ``slot``: ``tokens``
+        ``[1, C]`` with ``n_valid`` real entries from position ``start``."""
+        idx = jnp.arange(prefill_chunk, dtype=jnp.int32)[None, :]
+        positions = jnp.where(idx < n_valid, start + idx, -1)
+        last = jnp.reshape(start + n_valid - 1, (1,)).astype(jnp.int32)
+        x, cache, _ = _forward(
+            params, cfg, tokens, positions, (full_k, full_v, ring_k, ring_v),
+            block_table, jnp.reshape(slot, (1,)).astype(jnp.int32), last,
+            rp=rp, view=rp, decode=False, paged_kernel=False,
+            moe_kernel="moe_gmm_prefill")
+        at = jnp.clip(n_valid - 1, 0, prefill_chunk - 1)
+        x_last = jax.lax.dynamic_index_in_dim(x[0], at, axis=0,
+                                              keepdims=False)[None]
+        logits = _logits(params, x_last)
+        return (*cache, _sample(logits, rng, sampling), logits)
+
+    def decode(params, full_k, full_v, ring_k, ring_v, tokens, block_tables,
+               lens, rng):
+        """One token for every slot: ``tokens`` / ``lens`` ``[max_batch]``
+        (an empty slot carries ``lens < 0`` and a null-page table)."""
+        positions = jnp.where(lens >= 0, lens, -1)[:, None]
+        slots = jnp.arange(tokens.shape[0], dtype=jnp.int32)
+        x, cache, stats = _forward(
+            params, cfg, tokens[:, None], positions,
+            (full_k, full_v, ring_k, ring_v), block_tables, slots,
+            jnp.maximum(lens, -1).astype(jnp.int32), rp=rp, view=rp,
+            decode=True, paged_kernel=paged_kernel,
+            moe_kernel="moe_gmm_decode")
+        logits = _logits(params, x[:, 0])
+        stats["rows"] = (lens >= 0).sum().astype(jnp.int32)
+        return (*cache, _sample(logits, rng, sampling), logits, stats)
+
+    donate = (1, 2, 3, 4)
+    return {"prefill": jax.jit(prefill, donate_argnums=donate),
+            "decode": jax.jit(decode, donate_argnums=donate)}

@@ -1,0 +1,527 @@
+"""The windowed-attention sparse-expert family (``models/swa_moe``,
+``serving/swa_moe.py``) against its plain reference
+(``benchmarks/reference/laguna_ref.py``), at toy widths on the CPU.
+
+Weights are seeded float32, so program and reference differ by the order of
+float32 sums alone: logits are held to 2e-5 (the products accumulate a few
+hundred terms of size ~1; float32's grain there is ~1e-7 a term).
+"""
+
+import json
+import math
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.manifest import load_module
+from fleetx_tpu.models.swa_moe import model as M
+from fleetx_tpu.models.swa_moe.config import config_from_dict
+from fleetx_tpu.serving import registry, swa_moe as S
+from fleetx_tpu.serving.decode import SamplingParams
+from fleetx_tpu.serving.engine import ServingConfig, ServingEngine
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ref = load_module(os.path.join(ROOT, "benchmarks/reference/laguna_ref.py"))
+with open(os.path.join(ROOT, "benchmarks/configs/laguna-s-2.1.json")) as _f:
+    SHIPPED = json.load(_f)
+
+WINDOW, CHUNK, PAGE = 8, 8, 4
+TOY = dict(
+    vocab_size=64, hidden_size=32, intermediate_size=48, num_hidden_layers=9,
+    num_attention_heads_per_layer=[4, 6, 6, 6] * 3,
+    layer_types=["full_attention"] + ["sliding_attention"] * 3
+    + ["full_attention"] + ["sliding_attention"] * 3 + ["full_attention"],
+    mlp_only_layers=[0], num_key_value_heads=2, head_dim=16,
+    sliding_window=WINDOW, num_experts=16, experts_held=4,
+    first_expert_held=4, num_experts_per_tok=3, moe_intermediate_size=24,
+    shared_expert_intermediate_size=24,
+    dtype="float32", param_dtype="float32", max_position_embeddings=4096)
+
+
+def _sizes(toy: dict, cfg) -> dict:
+    """What the reference reads, for a toy model config."""
+    sizes = dict(toy)
+    sizes.update(num_experts=cfg.experts_held, router_experts=cfg.num_experts,
+                 rope_parameters=cfg.rope_parameters, rms_norm_eps=1e-6,
+                 norm_topk_prob=True, moe_routed_scaling_factor=2.5)
+    return sizes
+
+
+def _seeded(cfg, seed=0):
+    """Parameters with every leaf random (norm scales 1 + N(0, 0.1)) and
+    matrices large enough that every path matters."""
+    params = M.init_params(cfg, jax.random.PRNGKey(seed))
+
+    def spread(path, x):
+        key = jax.random.PRNGKey(abs(hash(str(path))) % (2 ** 31))
+        if {getattr(k, "key", None) for k in path} & M.F32_GROUPS:
+            return x + 0.1 * jax.random.normal(key, x.shape)
+        return x * 5.0
+    return S.serving_params(
+        jax.tree_util.tree_map_with_path(spread, params), cfg)
+
+
+def _reference_weights(params, sizes) -> dict:
+    """The program's tree under the reference's names, through the shipped
+    configuration's ``param_paths``."""
+    out = {}
+    for name, (shape, _) in ref.weight_spec(sizes).items():
+        node = params
+        for key in SHIPPED["param_paths"][name].split("/"):
+            node = node[key]
+        assert tuple(node.shape) == tuple(shape), (name, node.shape, shape)
+        out[name] = jnp.asarray(node, jnp.float32)
+    return out
+
+
+@pytest.fixture(scope="module")
+def toy():
+    cfg = config_from_dict(TOY)
+    params = _seeded(cfg)
+    sizes = _sizes(TOY, cfg)
+    return cfg, params, sizes, _reference_weights(params, sizes)
+
+
+def _serve(cfg, params, prompt, new, *, max_seq=64, slot=1, max_batch=3,
+           paged_kernel=False, page=PAGE, chunk=CHUNK, cache=None):
+    """Prefill ``prompt`` in chunks, decode ``new`` tokens greedily, in slot
+    ``slot`` of an otherwise empty batch: ``(tokens, logits a step)``."""
+    P = max_seq // page
+    fns = S.make_step_fns(cfg, page_size=page, prefill_chunk=chunk,
+                          sampling=SamplingParams(),
+                          paged_kernel=paged_kernel)
+    cache = cache or S.init_cache(cfg, num_pages=1 + max_batch * P,
+                                  page_size=page, max_batch=max_batch,
+                                  prefill_chunk=chunk)
+    table = np.zeros((max_batch, P), np.int32)
+    table[slot] = 1 + slot * P + np.arange(P)
+    key = jax.random.PRNGKey(0)
+    toks, logits, pos = list(prompt), [], 0
+    while pos < len(prompt):
+        part = prompt[pos:pos + chunk]
+        row = np.zeros((1, chunk), np.int32)
+        row[0, :len(part)] = part
+        *cache, tok, lg = fns["prefill"](
+            params, *cache, row, table[slot:slot + 1], np.int32(pos),
+            np.int32(len(part)), key, np.int32(slot))
+        pos += len(part)
+    logits.append(np.asarray(lg[0]))
+    toks.append(int(tok[0]))
+    lens = np.full((max_batch,), -1, np.int32)
+    last = np.zeros((max_batch,), np.int32)
+    for _ in range(new):
+        lens[slot], last[slot] = len(toks) - 1, toks[-1]
+        *cache, tk, lg, stats = fns["decode"](params, *cache, last, table,
+                                              lens, key)
+        logits.append(np.asarray(lg[slot]))
+        toks.append(int(tk[slot]))
+    return toks, logits, jax.device_get(stats), fns
+
+
+@pytest.mark.parametrize("prompt_len,new", [
+    (3, 2),                 # all below the window
+    (WINDOW, 3),            # the prompt is the window
+    (WINDOW + 5, 12),       # decode leaves the window and a second chunk
+    (3 * CHUNK + 1, 20),    # several chunks, a ragged last one, long decode
+])
+def test_prefill_then_decode_through_both_caches_is_the_reference(
+        toy, prompt_len, new):
+    cfg, params, sizes, w = toy
+    prompt = np.random.default_rng(prompt_len).integers(
+        0, cfg.vocab_size, size=prompt_len).tolist()
+    toks, logits, stats, fns = _serve(cfg, params, prompt, new)
+    width = -(-len(toks) // 16) * 16
+    row = np.zeros((1, width), np.int32)
+    row[0, :len(toks)] = toks
+    want = np.asarray(ref.logits(w, sizes, jnp.asarray(row)))[0]
+    for i, got in enumerate(logits):
+        at = prompt_len - 1 + i
+        np.testing.assert_allclose(got, want[at], atol=2e-5, rtol=0)
+    # one active row: at most k held experts a layer hit, every pair counted
+    layers = sum(n for kind, n in cfg.kinds().items() if kind.endswith("moe"))
+    assert int(stats["rows"]) == 1
+    assert 0 <= float(stats["hit"]) <= layers * cfg.num_experts_per_tok
+    assert float(stats["hit"]) == int(stats["pairs_held"])
+    assert fns["decode"]._cache_size() == 1
+    assert fns["prefill"]._cache_size() == 1
+
+
+def test_ring_is_an_all_paged_cache_to_the_bit(toy, monkeypatch):
+    """Window layers in a ring of window + chunk tokens a slot, against the
+    same layers keeping EVERY token (a slot's cache as long as a request:
+    nothing wraps), at ``max_seq_len`` four times the window: the logits of
+    prefill and of every decode step are the same bits. And the ring's
+    bytes do not depend on ``max_seq_len``."""
+    cfg, params, _, _ = toy
+    max_seq = 4 * WINDOW
+    prompt = np.random.default_rng(7).integers(0, 64, size=13).tolist()
+    ring = _serve(cfg, params, prompt, max_seq - 14, max_seq=max_seq)
+    # the all-paged twin: the same forward told that a slot's window cache
+    # is as many pages as a whole request, over buffers that large
+    all_pages, forward = max_seq // PAGE, S._forward
+    monkeypatch.setattr(S, "_forward", lambda *a, rp, **kw: forward(
+        *a, rp=all_pages, **kw))
+    full, ring_shape = S.cache_shapes(cfg, num_pages=1 + 3 * all_pages,
+                                      page_size=PAGE, max_batch=3,
+                                      prefill_chunk=CHUNK)
+    twin = (ring_shape[0], 1 + 3 * all_pages) + ring_shape[2:]
+    paged = _serve(cfg, params, prompt, max_seq - 14, max_seq=max_seq,
+                   cache=[jnp.zeros(full), jnp.zeros(full),
+                          jnp.zeros(twin), jnp.zeros(twin)])
+    monkeypatch.undo()
+    assert ring[0] == paged[0]
+    for a, b in zip(ring[1], paged[1]):
+        np.testing.assert_array_equal(a.view(np.uint32), b.view(np.uint32))
+    shapes = [S.cache_shapes(cfg, num_pages=n, page_size=PAGE, max_batch=3,
+                             prefill_chunk=CHUNK)[1]
+              for n in (1 + 3 * 8, 1 + 3 * 512)]
+    assert shapes[0] == shapes[1] == (
+        6, 1 + 3 * (WINDOW + CHUNK) // PAGE, PAGE,
+        cfg.num_key_value_heads * cfg.head_dim)
+
+
+def test_the_shares_add_up_to_the_uncut_layer(toy):
+    """The routed parts the 4 shares of 4 experts give, plus the shared
+    expert once, are the uncut reference's expert layer."""
+    cfg, params, sizes, w = toy
+    rng = np.random.default_rng(3)
+    u = jnp.asarray(rng.normal(size=(11, cfg.hidden_size)), jnp.float32)
+    full = {name: jnp.asarray(0.3 * rng.normal(size=shape), jnp.float32)
+            for name, shape in {
+                "router": (cfg.hidden_size, 16),
+                "e_gate": (16, cfg.hidden_size, 24),
+                "e_up": (16, cfg.hidden_size, 24),
+                "e_down": (16, 24, cfg.hidden_size),
+                "s_gate": (cfg.hidden_size, 24), "s_up": (cfg.hidden_size, 24),
+                "s_down": (24, cfg.hidden_size)}.items()}
+    uncut = dict(sizes, num_experts=16, first_expert_held=0)
+    want = np.asarray(ref._experts(u, full, uncut, "float32"))
+    shared = np.asarray(ref._gated_mlp(u, full["s_gate"], full["s_up"],
+                                       full["s_down"], "float32"))
+    total = np.zeros_like(want)
+    for share in range(4):
+        held = config_from_dict(dict(TOY, first_expert_held=4 * share))
+        ids, weights = M.route(u, full["router"], held)
+        lo = 4 * share
+        moe = {"experts_gate": full["e_gate"][None, lo:lo + 4],
+               "experts_up": full["e_up"][None, lo:lo + 4],
+               "experts_down": full["e_down"][None, lo:lo + 4]}
+        routed, rows = M.held_experts(u, ids, weights, moe, jnp.int32(0),
+                                      held, 16, "moe_gmm")
+        assert int(rows.sum()) == int(((ids >= lo) & (ids < lo + 4)).sum())
+        total += np.asarray(routed)
+    np.testing.assert_allclose(total + shared, want, atol=2e-5, rtol=0)
+
+
+def test_the_expert_layers_sizes_are_derived_and_a_skewed_router_drops_none(
+        toy):
+    """Tile rows, pass rows and the prefill's key block are no keys of the
+    config: the tile is the served dtype's sublane tile, a pass what a
+    uniform router sends the held experts in whole tiles (at the recipe 32
+    x 16 rows a decode step, 32 x 32 a chunk: the values swept on the
+    chip). A router that sends every token to ONE held expert fills more
+    than a pass; the loop takes the further turns and drops no row."""
+    import dataclasses
+
+    from fleetx_tpu.utils import config as config_mod
+
+    fields = {f.name for f in dataclasses.fields(type(toy[0]))}
+    assert not fields & {"moe_tile_rows", "moe_decode_pass_rows",
+                         "moe_prefill_pass_rows", "prefill_key_block"}
+    recipe = registry.model_config(config_mod.get_config(
+        os.path.join(ROOT, SHIPPED["serve"]["recipe"]), [], num_devices=1))
+    assert (M.tile_rows(recipe), M.pass_rows(recipe, 64),
+            M.pass_rows(recipe, 512)) == (16, 512, 1024)
+    cfg = toy[0]
+    assert M.tile_rows(cfg) == 8            # float32
+    rng = np.random.default_rng(5)
+    n, h, f = 40, cfg.hidden_size, cfg.moe_intermediate_size
+    u = jnp.asarray(rng.normal(size=(n, h)), jnp.float32)
+    moe = {name: jnp.asarray(0.3 * rng.normal(size=shape), jnp.float32)
+           for name, shape in {"experts_gate": (1, 4, h, f),
+                               "experts_up": (1, 4, h, f),
+                               "experts_down": (1, 4, f, h)}.items()}
+    lo = cfg.first_expert_held
+    ids = jnp.asarray(np.tile([lo + 2, 0, 1], (n, 1)), jnp.int32)
+    weights = jnp.asarray(rng.uniform(0.5, 1.0, size=(n, 3)), jnp.float32)
+    rows = M.pass_rows(cfg, n)
+    assert rows < n                          # 40 rows on one expert: 2 passes
+    got, held = M.held_experts(u, ids, weights, moe, jnp.int32(0), cfg, rows,
+                               "moe_gmm")
+    assert held.tolist() == [0, 0, n, 0]
+    want = weights[:, :1] * ref._gated_mlp(
+        u, moe["experts_gate"][0, 2], moe["experts_up"][0, 2],
+        moe["experts_down"][0, 2], "float32")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5,
+                               rtol=0)
+
+
+def test_the_built_tree_is_3199_m_parameters_served_in_bfloat16():
+    from fleetx_tpu.utils import config as config_mod
+
+    cfg = config_mod.get_config(
+        os.path.join(ROOT, SHIPPED["serve"]["recipe"]),
+        list(SHIPPED["serve"]["overrides"]), num_devices=1)
+    model_cfg, template = registry.served_template(cfg)
+    leaves = jax.tree_util.tree_flatten_with_path(template)[0]
+    assert sum(math.prod(l.shape) for _, l in leaves) == 3_199_460_352 \
+        == SHIPPED["bytes"]["parameters"] == M.count_params(model_cfg)
+    assert sum(math.prod(l.shape) * l.dtype.itemsize for _, l in leaves) \
+        == SHIPPED["bytes"]["served_bytes"]
+    for path, leaf in leaves:
+        keys = {getattr(k, "key", None) for k in path}
+        f32 = bool(keys & (M.F32_GROUPS | M.F32_LEAVES))
+        assert leaf.dtype == (jnp.float32 if f32 else jnp.bfloat16), path
+    # the reference's spec is the same tree under its own names
+    spec = ref.weight_spec(SHIPPED)
+    assert set(spec) == set(SHIPPED["param_paths"])
+    assert sum(math.prod(s) for s, _ in spec.values()) == 3_199_460_352
+    # the pattern is data: two periods after layer 0
+    assert model_cfg.runs() == [
+        ("full_dense", 0, 1, 0), ("window_moe", 0, 3, 0),
+        ("full_moe", 0, 1, 1), ("window_moe", 3, 3, 3),
+        ("full_moe", 1, 1, 2)]
+    sc = cfg["Serving"]
+    full, ring = S.cache_shapes(
+        model_cfg, num_pages=sc["num_pages"], page_size=sc["page_size"],
+        max_batch=sc["max_batch"], prefill_chunk=sc["prefill_chunk"])
+    assert full == (3, 18001, 16, 1024) and ring == (6, 4097, 16, 1024)
+
+
+# ------------------------------------------------------------ the engine
+def _engine(cfg, params, **serving):
+    sc = ServingConfig(max_batch=3, page_size=PAGE, num_pages=40,
+                       max_seq_len=64, prefill_chunk=CHUNK, max_queue=0,
+                       **serving)
+    return ServingEngine(cfg, params, sc, SamplingParams(), eos_token_id=-1)
+
+
+def _widest_gap(w, sizes, prompt, served) -> float:
+    toks = list(prompt) + list(served)
+    row = np.zeros((1, -(-len(toks) // 16) * 16), np.int32)
+    row[0, :len(toks)] = toks
+    lg = np.asarray(ref.logits(w, sizes, jnp.asarray(row)))[0]
+    at = np.arange(len(prompt) - 1, len(toks) - 1)
+    return float((lg[at].max(-1) - lg[at, np.asarray(served)]).max())
+
+
+def test_the_engine_serves_the_family_and_never_retraces(toy):
+    """Requests join and leave one engine (the same class, scheduler and
+    allocator as GPT's); every served token is the reference's best within
+    float32's grain; each program compiled once; the expert counters and
+    the cache gauges are the program's own."""
+    import logging
+
+    from fleetx_tpu.utils.log import logger as program_logger
+
+    cfg, params, sizes, w = toy
+    said = []
+    handler = logging.Handler()
+    handler.emit = lambda record: said.append(record.getMessage())
+    program_logger.addHandler(handler)
+    try:
+        eng = _engine(cfg, params)
+    finally:
+        program_logger.removeHandler(handler)
+    assert eng.family is registry.family("SWAMoEModule")
+    assert any(" 0 leaves cast, serving tree " in line for line in said)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, 64, size=n).tolist() for n in (5, 19, 9, 26)]
+    reqs = [eng.submit(p, 12) for p in prompts[:2]]
+    for _ in range(6):
+        eng.step()
+    reqs += [eng.submit(p, 12) for p in prompts[2:]]
+    eng.run_until_drained()
+    for req, prompt in zip(reqs, prompts):
+        assert req.state == "finished" and len(req.tokens) == 12
+        assert _widest_gap(w, sizes, prompt, req.tokens) < 1e-4
+    assert eng._fns["decode"]._cache_size() == 1
+    assert eng._fns["prefill"]._cache_size() == 1
+    assert eng.allocator.allocated_pages == 0
+    m = eng.metrics
+    assert m.histogram("serving_moe_experts_hit").summary()["count"] > 0
+    assert 0 < m.counter("serving_moe_pairs_held_total").value \
+        <= m.counter("serving_moe_pairs_total").value
+    assert m.gauge("serving_kv_cache_bytes").value == eng.cache_bytes \
+        == sum(int(a.nbytes) for a in eng.cache)
+
+
+def test_a_preempted_request_resumes_to_the_same_tokens(toy):
+    """A pool too small for three growing requests preempts the youngest;
+    it is prefilled again (which rebuilds its ring) and serves the tokens
+    an unpressed engine serves."""
+    cfg, params, _, _ = toy
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, 64, size=n).tolist() for n in (9, 10, 11)]
+
+    def run(num_pages):
+        eng = _engine(cfg, params)
+        eng.allocator = type(eng.allocator)(num_pages, PAGE)
+        reqs = [eng.submit(p, 24) for p in prompts]
+        eng.run_until_drained()
+        return reqs
+
+    calm, pressed = run(40), run(18)
+    assert sum(r.preemptions for r in calm) == 0
+    assert sum(r.preemptions for r in pressed) > 0
+    for a, b in zip(calm, pressed):
+        assert a.tokens == b.tokens and len(b.tokens) == 24
+
+
+def test_window_cache_bytes_do_not_follow_max_seq_len(toy):
+    cfg, params, _, _ = toy
+    short = _engine(cfg, params)
+    long = ServingEngine(cfg, params, ServingConfig(
+        max_batch=3, page_size=PAGE, num_pages=40, max_seq_len=256,
+        prefill_chunk=CHUNK, max_queue=0), SamplingParams(), eos_token_id=-1)
+    assert [a.shape for a in short.cache] == [a.shape for a in long.cache]
+    assert short.cache[2].shape[1] == 1 + 3 * (WINDOW + CHUNK) // PAGE
+
+
+def test_the_kernel_path_serves_what_the_gather_serves():
+    """At a geometry ``ops/paged_attention.py`` admits (128-wide heads, 2
+    key-value heads under 4 or 6 query heads, a window) the decode program
+    walks both caches in-kernel (interpret mode here) and serves the tokens
+    and, within float32's grain, the logits of the gathered view."""
+    toy = dict(TOY, head_dim=128, num_hidden_layers=5, sliding_window=16,
+               rope_parameters={"full_attention": dict(
+                   SHIPPED["rope_parameters"]["full_attention"])})
+    cfg = config_from_dict(toy)
+    assert S.paged_kernel_enabled(cfg, page_size=8, pages_per_req=8)
+    params = _seeded(cfg, 1)
+    prompt = np.random.default_rng(2).integers(0, 64, size=21).tolist()
+    kw = dict(max_seq=64, page=8, chunk=8)
+    gather = _serve(cfg, params, prompt, 30, **kw)
+    kernel = _serve(cfg, params, prompt, 30, paged_kernel=True, **kw)
+    assert gather[0] == kernel[0]
+    for a, b in zip(gather[1], kernel[1]):
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=0)
+
+
+def test_tools_serve_builds_the_recipe_through_the_registry():
+    """``tools/serve.py:_build_engine`` on the shipped recipe at toy
+    widths: the same function that builds GPT's engine."""
+    import sys
+
+    from fleetx_tpu.utils import config as config_mod
+
+    sys.path.insert(0, os.path.join(ROOT, "tools"))
+    import serve as serve_tool
+
+    over = [f"Model.{k}={json.dumps(v)}" for k, v in TOY.items()
+            if k not in ("dtype", "param_dtype")]
+    over += ["Model.dtype=float32", "Serving.max_batch=2",
+             "Serving.num_pages=33", "Serving.page_size=4",
+             "Serving.max_seq_len=64", "Serving.prefill_chunk=8"]
+    cfg = config_mod.get_config(
+        os.path.join(ROOT, SHIPPED["serve"]["recipe"]), over, num_devices=1)
+    eng = serve_tool._build_engine(cfg)
+    assert isinstance(eng, ServingEngine)
+    assert type(eng.family).__name__ == "SWAMoEFamily"
+    req = eng.submit([1, 2, 3, 4, 5], 4)
+    eng.run_until_drained()
+    assert req.state == "finished" and len(req.tokens) == 4
+
+
+# ------------------------------------------------------------- the kernel
+def _kernel_case(dtype, heads, kv, hd, *, B=6, ps=16, per_req=12, layers=2,
+                 seed=35):
+    from fleetx_tpu.ops import paged_attention as PA  # noqa: F401
+
+    rng = np.random.default_rng(seed)
+    shape = (layers, 1 + B * per_req, ps, kv * hd)
+    pk = jnp.asarray(rng.normal(size=shape), dtype)
+    pv = jnp.asarray(rng.normal(size=shape), dtype)
+    q = jnp.asarray(rng.normal(size=(B, heads, hd)), dtype)
+    tables = (1 + rng.permutation(B * per_req).reshape(B, per_req)
+              ).astype(np.int32)
+    lens = np.array([-1, 0, 37, per_req * ps - 1, 100, 129], np.int32)
+    for b in range(B):      # lazy allocation: null pages past the query
+        tables[b, max(int(lens[b]), -1) // ps + 1:] = 0
+    return q, pk, pv, jnp.asarray(tables), jnp.asarray(lens)
+
+
+def _gathered(q, pk, pv, tables, lens, layer, window):
+    """The gather path's attention over the same pages, float64."""
+    B, H, hd = q.shape
+    kv = pk.shape[-1] // hd
+    kd = np.asarray(pk[layer][tables], np.float64).reshape(B, -1, kv, hd)
+    vd = np.asarray(pv[layer][tables], np.float64).reshape(B, -1, kv, hd)
+    out = np.zeros((B, H, hd))
+    for b in range(B):
+        last = int(lens[b])
+        if last < 0:
+            continue
+        lo = 0 if window is None else max(last - window + 1, 0)
+        for j in range(H):
+            k, v = kd[b, lo:last + 1, j // (H // kv)], \
+                vd[b, lo:last + 1, j // (H // kv)]
+            s = k @ np.asarray(q[b, j], np.float64) / math.sqrt(hd)
+            p = np.exp(s - s.max())
+            out[b, j] = (p / p.sum()) @ v
+    return out
+
+
+@pytest.mark.parametrize("heads,kv,hd,window", [
+    (16, 16, 64, None), (6 * 8, 8, 128, None), (9 * 8, 8, 128, 40),
+    (9 * 8, 8, 128, 512)])
+def test_paged_attention_with_fewer_kv_heads_and_a_window(heads, kv, hd,
+                                                          window):
+    """The extended kernel (interpret mode) against the gather for the
+    serve cells' head geometries: as many key-value heads as query heads,
+    6 and 9 query heads to each of 8, a window shorter than the context
+    and one longer than any."""
+    from fleetx_tpu.ops import paged_attention as PA
+
+    assert PA.paged_attention_supported(
+        num_heads=heads, head_dim=hd, page_size=16, pages_per_req=12,
+        num_kv_heads=kv)
+    args = _kernel_case(jnp.float32, heads, kv, hd)
+    for layer in range(2):
+        got = np.asarray(PA.paged_attention(*args, jnp.int32(layer), window))
+        np.testing.assert_allclose(got, _gathered(*args, layer, window),
+                                   atol=3e-6, rtol=0)
+        assert not got[0].any()         # an inactive row: exact zeros
+
+
+@pytest.mark.parametrize("dtype,digest", [
+    (jnp.float32, "5c9720caa1fb241f65b752f9b1b7e1d9430e8548"),
+    (jnp.bfloat16, "56c33a0b89a801e173fa8f9799997c2dabbd861e")])
+def test_with_as_many_kv_heads_and_no_window_it_is_the_kernel_it_was(
+        dtype, digest):
+    """16 heads of 64 over 16 key-value heads, no window: the bits of PR
+    34's kernel (``git show c2ae43d:fleetx_tpu/ops/paged_attention.py`` on
+    this case, in this container's interpret mode, gave these digests; the
+    new kernel was compared with it array for array when it was written)."""
+    import hashlib
+
+    from fleetx_tpu.ops import paged_attention as PA
+
+    out = PA.paged_attention(*_kernel_case(dtype, 16, 16, 64), jnp.int32(1))
+    got = hashlib.sha1(np.asarray(out.astype(jnp.float32)).tobytes())
+    assert got.hexdigest() == digest
+
+
+def test_the_window_walk_starts_where_the_window_does():
+    from fleetx_tpu.ops import paged_attention as PA
+
+    lens = np.array([-1, 0, 127, 128, 511, 512, 639, 640, 9000], np.int32)
+    first = PA.first_group_walked(lens, 128, 512)
+    np.testing.assert_array_equal(first[1:], [0, 0, 0, 0, 0, 1, 1, 66])
+    np.testing.assert_array_equal(
+        PA.page_groups_walked(lens, 128, 76, 512),
+        [0, 1, 1, 2, 4, 5, 4, 5, 5])
+    np.testing.assert_array_equal(
+        PA.page_groups_walked(lens, 128, 76), [0, 1, 1, 2, 4, 5, 5, 6, 71])
+
+
+def test_kv_pool_spec_refuses_a_tensor_axis_wider_than_the_kv_heads():
+    from fleetx_tpu.parallel.rules import kv_pool_spec
+
+    assert kv_pool_spec(num_kv_heads=8, tensor_degree=4) == kv_pool_spec()
+    with pytest.raises(ValueError, match="8 key-value heads.*tensor axis "
+                                         "of 16"):
+        kv_pool_spec(num_kv_heads=8, tensor_degree=16)

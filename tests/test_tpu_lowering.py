@@ -389,3 +389,81 @@ def test_paged_decode_compiles_at_the_serve_cells_geometry(topo, monkeypatch):
              if "custom-call(" in line and "paged_decode" in line]
     assert len(calls) == 1, calls
     assert calls[0].count("bf16[24,3585,16,1024]") == 2, calls[0]
+
+
+# ------------------------- the second serving family: two caches, one buffer
+# each (serving/swa_moe.py)
+
+def test_swa_moe_programs_keep_both_caches_one_buffer(topo, monkeypatch):
+    """``decode`` and ``prefill`` of the windowed-attention sparse-expert
+    family, compiled for the v5e at widths its kernels admit: all four
+    cache buffers (the paged pool's K and V, the rings' K and V) aliased
+    from input to output, none held by anything but what enters, the loops'
+    carries and the in-place row scatters, no layer of a cache cut out; the
+    Mosaic kernels are in the programs under the names the trace finds
+    them by."""
+    from jax.sharding import SingleDeviceSharding
+
+    from fleetx_tpu.models.swa_moe import model as M
+    from fleetx_tpu.models.swa_moe.config import config_from_dict
+    from fleetx_tpu.serving import swa_moe as S
+    from fleetx_tpu.serving.decode import SamplingParams
+
+    monkeypatch.setattr(ops, "interpret", lambda: False)
+    cfg = config_from_dict(dict(
+        vocab_size=VOCAB, hidden_size=256, intermediate_size=512,
+        num_hidden_layers=9, num_key_value_heads=2, head_dim=128,
+        num_attention_heads_per_layer=[4, 6, 6, 6, 4, 6, 6, 6, 4],
+        layer_types=(["full_attention"] + ["sliding_attention"] * 3) * 2
+        + ["full_attention"], sliding_window=1024, num_experts=16,
+        experts_held=4, num_experts_per_tok=2, moe_intermediate_size=128,
+        shared_expert_intermediate_size=128))
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one)
+
+    # caches too large for the compiler to stage in on-chip memory, as the
+    # real ones are (abstract shapes: nothing is allocated)
+    batch, page, per_req, chunk, pages = 64, 16, 128, 64, 8194
+    assert S.paged_kernel_enabled(cfg, page_size=page, pages_per_req=per_req)
+    params = jax.tree.map(lambda a: arr(a.shape, a.dtype),
+                          M.served_template(cfg))
+    full, ring = S.cache_shapes(cfg, num_pages=pages, page_size=page,
+                                max_batch=batch, prefill_chunk=chunk)
+    cache = [arr(full, jnp.bfloat16)] * 2 + [arr(ring, jnp.bfloat16)] * 2
+    fns = S.make_step_fns(cfg, prefill_chunk=chunk, page_size=page,
+                          sampling=SamplingParams(), paged_kernel=True)
+    rng = arr((2,), jnp.uint32)
+    programs = {
+        "prefill": (params, *cache, arr((1, chunk)), arr((1, per_req)),
+                    arr(()), arr(()), rng, arr(())),
+        "decode": (params, *cache, arr((batch,)), arr((batch, per_req)),
+                   arr((batch,)), rng),
+    }
+    kernels = {"prefill": {"moe_gmm_prefill"},
+               "decode": {"moe_gmm_decode", "paged_decode",
+                          "paged_decode_window"}}
+    n_params = len(jax.tree.leaves(params))
+    for name, args in programs.items():
+        lowered = fns[name].lower(*args)
+        assert set(mosaic_kernels(lowered.as_text())) == kernels[name], name
+        hlo = lowered.compile().as_text()
+        alias = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo)
+        assert alias, f"{name}: no input-output aliasing at all"
+        for out in range(4):
+            assert f"{{{out}}}: ({n_params + out}, {{}}," in alias.group(1), \
+                (name, out, alias.group(1))
+        for shape in (full, ring):
+            holders = _pool_holders(hlo, shape)
+            assert holders, f"{name}: the cache {shape} is not in the program"
+            stray = [h for h in holders if h[0] not in _POOL_CARRIERS]
+            assert not stray, (name, stray)
+            for line in (ln for op, ln in holders if op == "fusion"):
+                root = re.search(r"calls=(%[\w.-]+)", line).group(1)
+                body = hlo.split(f"{root} ", 1)[1].split("\n}", 1)[0]
+                assert re.search(r"ROOT \S+ = \S+ scatter\(", body), \
+                    (name, line)
+            layer_sized = ",".join(map(str, shape[1:]))
+            assert not re.search(
+                r"= \w+\[(?:1,)?%s\]" % layer_sized, hlo), (name, shape)

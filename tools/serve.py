@@ -35,35 +35,20 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 def _build_engine(cfg: dict):
     """Config sections → a ready ``ServingEngine`` (params from the
-    ``Serving.ckpt_dir`` checkpoint when given, else seeded init)."""
+    ``Serving.ckpt_dir`` checkpoint when given, else seeded init). The
+    model family — config, seeded tree, caches and programs — is the
+    registry's (``fleetx_tpu/serving/registry.py``, keyed on
+    ``Model.module``); this function adds what a replica process brings:
+    its mesh, a checkpoint, an adapter."""
     import jax
-    import jax.numpy as jnp
 
     from fleetx_tpu.core.engine.inference_engine import serving_mesh
-    from fleetx_tpu.models.gpt.model import GPTForPretraining, config_from_dict
-    from fleetx_tpu.serving.decode import SamplingParams
-    from fleetx_tpu.serving.engine import ServingConfig, ServingEngine
+    from fleetx_tpu.serving import registry
 
-    model_dict = dict(cfg.get("Model") or {})
-    quant = dict(cfg.get("Quantization") or {})
-    if quant.get("weight_bits"):
-        model_dict["qat_bits"] = int(quant["weight_bits"])
-    if quant.get("activation_bits"):
-        model_dict["qat_act_bits"] = int(quant["activation_bits"])
-    model_cfg = config_from_dict(model_dict)
-    serving = ServingConfig.from_dict(dict(cfg.get("Serving") or {}))
-    gen = dict(cfg.get("Generation") or {})
-    strategy = gen.get("decode_strategy") or "greedy_search"
-    sampling = SamplingParams(
-        do_sample=strategy == "sampling",
-        temperature=float(gen.get("temperature", 1.0)),
-        top_k=int(gen.get("top_k", 0)),
-        top_p=float(gen.get("top_p", 0.0)))
-    eos = int(gen.get("eos_token_id", 50256))
-
-    model = GPTForPretraining(model_cfg)
+    model_cfg = registry.model_config(cfg)
     mesh = serving_mesh(cfg.get("Distributed"))
-    ckpt_dir = serving.ckpt_dir
+    serving = dict(cfg.get("Serving") or {})
+    ckpt_dir = serving.get("ckpt_dir")
     if ckpt_dir:
         from fleetx_tpu.core.checkpoint import load_params
 
@@ -88,11 +73,8 @@ def _build_engine(cfg: dict):
             layout=SpecLayout.from_dist_config(
                 dict(cfg.get("Distributed") or {})))
     else:
-        seed = int((cfg.get("Global") or {}).get("seed", 0))
-        params = model.init(
-            {"params": jax.random.PRNGKey(seed)},
-            jnp.zeros((1, 8), jnp.int32), None, deterministic=True)["params"]
-    if serving.adapter_dir:
+        params = registry.init_params(cfg, model_cfg)
+    if serving.get("adapter_dir"):
         # fine-tuned serving (docs/finetune.md): merge the LoRA adapter
         # artifact into the base weights — verified against the stamped
         # base digests + registry fingerprint, refused loudly on drift
@@ -100,10 +82,9 @@ def _build_engine(cfg: dict):
                          "(the adapter's frozen base)"
         from fleetx_tpu.finetune.checkpoint import apply_adapter_checkpoint
 
-        params = apply_adapter_checkpoint(params, str(serving.adapter_dir))
-    return ServingEngine(model_cfg, params, serving, sampling,
-                         eos_token_id=eos, mesh=mesh,
-                         seed=int((cfg.get("Global") or {}).get("seed", 0)))
+        params = apply_adapter_checkpoint(params,
+                                          str(serving["adapter_dir"]))
+    return registry.build_engine(cfg, model_cfg, params, mesh=mesh)
 
 
 def _run_replica(args, cfg: dict) -> int:
