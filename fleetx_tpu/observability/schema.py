@@ -89,6 +89,12 @@ SERVING_RECORD_SCHEMA: dict[str, tuple[tuple, bool]] = {
     # requests shed at a decode tick because their deadline expired
     "deadline_sheds": ((int,), False),
     "decode_path": ((str,), False),
+    # the tick's order (docs/serving.md "The tick"): decode steps
+    # dispatched, those dispatched while the step before was unfetched, and
+    # row-steps computed past an eos and dropped
+    "decode_steps": ((int,), False),
+    "decode_overlapped": ((int,), False),
+    "overrun_rows": ((int,), False),
     "queue_depth": (_NULLABLE_INT, True),
     "active_requests": (_NULLABLE_INT, True),
     "page_occupancy": (_NULLABLE_NUM, True),
@@ -172,10 +178,10 @@ FLEET_RECORD_SCHEMA: dict[str, tuple[tuple, bool]] = {
 #: request-latency histograms + scheduler gauges, all in the PR 1 registry
 SERVING_METRIC_NAMES = (
     "serving_ttft", "serving_inter_token",
-    # wall time of a tick that ran device work, top of step() to the device
-    # drained, and the same of the ticks that carried a prefill chunk (what
-    # deadline admission prices a chunk at); a first token's wait in three
-    # parts that sum to serving_ttft
+    # the period between two consecutive drains of a working engine (what a
+    # token costs), and the same of the periods in which the device ran a
+    # prefill chunk (what deadline admission prices a chunk at); a first
+    # token's wait in three parts that sum to serving_ttft
     "serving_tick", "serving_chunk_tick", "serving_queue_wait",
     "serving_prefill_wait", "serving_prefill_run",
     "serving_queue_depth", "serving_active_requests",
@@ -194,6 +200,10 @@ SERVING_METRIC_NAMES = (
     "serving_moe_pairs_total",
     "serving_requests_total", "serving_requests_completed",
     "serving_requests_refused", "serving_tokens_total",
+    # decode steps dispatched, those dispatched while the step before was
+    # still unfetched, row-steps computed past an eos and dropped
+    "serving_decode_steps", "serving_decode_overlapped",
+    "serving_overrun_rows",
     # deadline-admission plane (docs/serving.md "Fault tolerance"):
     # classified refusals + in-flight sheds at decode-tick boundaries
     "serving_deadline_sheds", "serving_refusals_overloaded",

@@ -125,26 +125,34 @@ def get_tracer() -> Optional[Tracer]:
 #: outside this table (tests hold them to it) and the benchmark's readers
 #: (``benchmarks/program_spans.py``) import it. Spans nest strictly, on the
 #: loop's own thread: ``serve.tick`` holds every other ``serve.*`` span and
-#: ``serve.prefill`` holds ``serve.prefill.wait``; the ``fit`` spans follow
-#: each other.
+#: ``serve.emit`` holds ``serve.prefill.wait``; the ``fit`` spans follow
+#: each other. A tick dispatches before it fetches (docs/serving.md "The
+#: tick"): the two waits are for work dispatched EARLIER, while the device
+#: already holds this tick's.
 HOT_LOOP_SPANS: dict = {
     "serve.tick": ("working", "one ServingEngine.step(): every serve.* "
                               "span below lies inside it"),
     "serve.admit": ("working", "waiting requests take a slot and pages"),
     "serve.prefill": ("working", "build one prompt chunk and dispatch the "
-                                 "prefill program (rid=, chunk=); on a last "
-                                 "chunk also the first token's book-keeping"),
-    "serve.prefill.wait": ("waiting", "device_get of a last chunk's token: "
-                                      "the device runs this chunk and what "
-                                      "was queued before it"),
+                                 "prefill program (rid=, chunk=); a last "
+                                 "chunk joins the decode batch here"),
+    "serve.prefill.wait": ("waiting", "device_get of the token of a last "
+                                      "chunk dispatched in this tick, after "
+                                      "the decode step behind it was: the "
+                                      "device runs what was queued before "
+                                      "the chunk, and the chunk"),
     "serve.schedule": ("working", "shed expired requests, grow block tables "
-                                  "or preempt, list the running rows"),
-    "serve.decode": ("working", "dispatch the decode program"),
-    "serve.decode.wait": ("waiting", "device_get of the decoded tokens: the "
-                                     "device runs this tick's prefill chunk "
-                                     "and decode step"),
-    "serve.emit": ("working", "per running row: length, inter-token "
-                              "sample, timeline note, finish or next token"),
+                                  "or preempt, list the rows that decode"),
+    "serve.decode": ("working", "dispatch the decode program on the tokens "
+                                "the device holds; lengths advance here"),
+    "serve.decode.wait": ("waiting", "device_get of the tokens of the step "
+                                     "dispatched in the tick BEFORE: the "
+                                     "device finishes that step while this "
+                                     "tick's work is queued behind it"),
+    "serve.emit": ("working", "per row of the fetched step that still is "
+                              "what it ran: inter-token sample, timeline "
+                              "note, the token, finish; then a last "
+                              "chunk's first token (prefill.wait inside)"),
     "serve.gauges": ("working", "queue, slot, page and fragmentation gauges"),
     "data_fetch": ("working", "next batch from the loader or the device "
                               "prefetcher"),

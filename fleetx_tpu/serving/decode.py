@@ -13,6 +13,16 @@ engine, that the continuous-batching scheduler calls every step —
   shape. Continuous batching therefore **never retraces**
   (``tests/test_zz_serving.py`` pins the jit cache size at 1).
 
+**The last tokens never leave the device.** ``decode`` takes the previous
+call's sampled tokens as they are, and beside them the newest ``prefill``
+call's sampled token with the slot it belongs in (``merge_fresh``: the
+request that left prefill in this tick joins inside the program). The host
+therefore needs no token's VALUE to dispatch the next step, and
+``ServingEngine.step`` dispatches step N+1 before it fetches step N
+(``serving/engine.py``; docs/serving.md "The tick"). A sampling program
+folds the host's draw count into the one base key itself
+(``jax.random.fold_in``): no key is split by a dispatch of its own.
+
 The forward re-implements the ``models/gpt/model.py`` decode math over the
 RAW parameter pytree (scanned-layer layout) instead of ``model.apply``:
 the dense ``DecodeCache`` threads a single scalar write index through the
@@ -123,6 +133,16 @@ class SamplingParams:
     temperature: float = 1.0
     top_k: int = 0
     top_p: float = 0.0
+
+
+def token_sharding(mesh: Any) -> Any:
+    """Where a mesh holds the sampled tokens (``[max_batch]``, ``[1]``):
+    whole on every device."""
+    from jax.sharding import NamedSharding
+
+    from fleetx_tpu.parallel.rules import activation_spec
+
+    return NamedSharding(mesh, activation_spec())
 
 
 def _quant(x: jax.Array, bits: int, enabled: bool, axis=None) -> jax.Array:
@@ -312,16 +332,32 @@ def _logits(params: Any, cfg: Any, x_last: jax.Array) -> jax.Array:
     return jnp.einsum("bh,vh->bv", x_last, wte).astype(jnp.float32)
 
 
-def _sample(logits: jax.Array, rng: jax.Array,
+def _sample(logits: jax.Array, rng: jax.Array, draw: jax.Array,
             sp: SamplingParams) -> jax.Array:
     """Greedy argmax or the sampling-transform chain shared with
-    ``generation.generate`` (temperature → top-k → top-p → categorical)."""
+    ``generation.generate`` (temperature → top-k → top-p → categorical).
+
+    ``rng`` is the engine's one base key and ``draw`` the host's count of
+    the programs it has dispatched: the call's key is folded HERE, inside
+    the program, so no key is ever split by a dispatch of its own. Greedy
+    reads neither (and ``jit`` then drops both from the executable)."""
     if not sp.do_sample:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
     l = G.apply_temperature(logits, sp.temperature)
     l = G.apply_top_k(l, sp.top_k)
     l = G.apply_top_p(l, sp.top_p)
-    return jax.random.categorical(rng, l, axis=-1).astype(jnp.int32)
+    return jax.random.categorical(jax.random.fold_in(rng, draw), l,
+                                  axis=-1).astype(jnp.int32)
+
+
+def merge_fresh(tokens: jax.Array, fresh_slot: jax.Array,
+                fresh_tok: jax.Array) -> jax.Array:
+    """The decode batch's input tokens: the previous step's output, which
+    never left the device, with the first token of the request that left
+    prefill in this tick (``fresh_tok`` ``[1]``, the last chunk's sampled
+    token, unfetched) put in its slot; ``fresh_slot < 0``: none did."""
+    rows = jnp.arange(tokens.shape[0], dtype=jnp.int32)
+    return jnp.where(rows == fresh_slot, fresh_tok[0], tokens)
 
 
 def make_step_fns(cfg: Any, *, max_batch: int, pages_per_req: int,
@@ -347,8 +383,15 @@ def make_step_fns(cfg: Any, *, max_batch: int, pages_per_req: int,
             return pool
         return jax.lax.with_sharding_constraint(pool, pool_sharding)
 
+    def everywhere(toks):
+        # the sampled tokens feed the next decode call as they are: on a
+        # mesh they come out where ``token_sharding`` put the first ones
+        if mesh is None:
+            return toks
+        return jax.lax.with_sharding_constraint(toks, token_sharding(mesh))
+
     def prefill(params, pool_k, pool_v, tokens, block_table, start, n_valid,
-                rng):
+                rng, draw):
         """One prompt chunk for one request: ``tokens`` ``[1, C]`` with
         ``n_valid`` real entries starting at absolute position ``start``;
         returns the pools plus the last valid position's sampled token and
@@ -362,19 +405,24 @@ def make_step_fns(cfg: Any, *, max_batch: int, pages_per_req: int,
                                               keepdims=False)[None]
         logits = _logits(params, cfg, x_last)
         return (constrain(pool_k), constrain(pool_v),
-                _sample(logits, rng, sampling), logits)
+                everywhere(_sample(logits, rng, draw, sampling)), logits)
 
-    def decode(params, pool_k, pool_v, tokens, block_tables, lens, rng):
+    def decode(params, pool_k, pool_v, tokens, fresh_slot, fresh_tok,
+               block_tables, lens, rng, draw):
         """One decode step for the full static batch: ``tokens``/``lens``
         ``[max_batch]`` (inactive slots carry ``lens < 0`` and null-page
-        block tables); returns pools + sampled tokens + f32 logits."""
+        block tables; what their ``tokens`` hold is never read past the
+        masked row), ``tokens`` the previous call's sampled tokens with
+        ``merge_fresh``'s one new row; returns pools + sampled tokens + f32
+        logits."""
+        tokens = merge_fresh(tokens, fresh_slot, fresh_tok)
         positions = jnp.where(lens >= 0, lens, -1)[:, None]
         x, pool_k, pool_v = _forward(params, cfg, tokens[:, None], positions,
                                      pool_k, pool_v, block_tables, quantize,
                                      paged_kernel=paged_kernel, mesh=mesh)
         logits = _logits(params, cfg, x[:, 0])
         return (constrain(pool_k), constrain(pool_v),
-                _sample(logits, rng, sampling), logits)
+                everywhere(_sample(logits, rng, draw, sampling)), logits)
 
     del max_batch, pages_per_req  # shapes arrive via the arrays themselves
     return {

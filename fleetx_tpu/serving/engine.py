@@ -8,7 +8,16 @@ block tables, page allocator, request queues). Whatever the family, the
 pages the allocator hands out and the block table a request holds are those
 of the layers that keep every token; a family's other caches (a ring a
 slot for window layers) cost the host nothing. The scheduler runs
-the vLLM-style loop, one ``step()`` per iteration:
+the vLLM-style loop, one ``step()`` per iteration, and keeps the device
+fed: the device work of tick N+1 is dispatched BEFORE the tokens of tick N
+are fetched (order of a tick: admit → dispatch a chunk → schedule → dispatch
+decode N+1 → fetch and emit decode N → fetch and emit a last chunk's first
+token → gauges; docs/serving.md "The tick"). Exactly one decode step is in
+flight: what the host learns late — an eos, and that a cancel, shed or
+preemption came too late for the step already running — it learns one step
+late, never more. The last tokens stay on the device; the host keeps its
+books (lengths, pages, counts) at dispatch and stamps every latency at
+emit, once the value is on the host:
 
 1. **admit** — waiting requests take a free decode slot + a **lazy** page
    grant: the prompt's pages plus ``alloc_watermark`` headroom pages
@@ -19,7 +28,8 @@ the vLLM-style loop, one ``step()`` per iteration:
 2. **prefill** — ONE chunk (``prefill_chunk`` tokens) of the oldest
    prefilling request is forwarded; long prompts therefore spread over
    several steps instead of stalling the decode batch, and the final
-   chunk's logits yield the request's first token (TTFT);
+   chunk's logits yield the request's first token (TTFT), which joins
+   the decode batch on the device in the same tick;
 3. **decode** — one token for every RUNNING slot in a single static-shape
    step; each running request's block table grows one page at a time as
    its length crosses page boundaries, and when the pool runs dry the
@@ -28,8 +38,11 @@ the vLLM-style loop, one ``step()`` per iteration:
    the re-run regenerates the same greedy tokens, the loss-free-recovery
    property the router's re-dispatch already relies on). New requests
    join at the next step boundary, finished ones (eos /
-   ``max_new_tokens``) free their pages and leave — no retrace in any
-   direction.
+   ``max_new_tokens``) free their pages and leave when their last token
+   is emitted — no retrace in any direction. A finish by length is known
+   from a count and the row is simply not dispatched again; an eos is a
+   value, so the one row-step computed past it is dropped
+   (``serving_overrun_rows``).
 
 Telemetry rides the PR 1 metrics registry (``serving_ttft`` /
 ``serving_inter_token`` histograms; queue-depth / active-request /
@@ -41,8 +54,8 @@ and each of its phases is a profiler annotation
 ``time.monotonic`` reading kept for the last tick (``last_tick``); a first
 token's wait is recorded in three parts (``serving_queue_wait`` +
 ``serving_prefill_wait`` + ``serving_prefill_run`` = ``serving_ttft``).
-The engine knows a tick's wall time (``serving_tick``), never a program's
-device time: that is the trace's to say.
+The engine knows the period between two drains (``serving_tick``), never a
+program's device time: that is the trace's to say.
 """
 
 from __future__ import annotations
@@ -155,6 +168,11 @@ class ServingRequest:
     pages: list = dataclasses.field(default_factory=list)
     prefill_pos: int = 0
     tokens: list = dataclasses.field(default_factory=list)
+    # tokens whose computation has been DISPATCHED (the first by the last
+    # prefill chunk, one a decode step): ahead of ``len(tokens)`` by what is
+    # in flight; a row stops being dispatched when this reaches
+    # ``max_new_tokens``, one tick before its last token is emitted
+    dispatched: int = 0
     error: Optional[str] = None
     submitted_at: float = 0.0
     # the two marks between submission and first token (monotonic, stamped
@@ -357,6 +375,20 @@ class _Phase:
         return self._span.__exit__(*exc)
 
 
+@dataclasses.dataclass
+class _InFlight:
+    """The ONE decode step the device runs while the host goes on: what it
+    will have sampled (still on the device, its copy to the host started),
+    the family's step counters, and the rows it ran, each by identity —
+    ``(request, admit_seq)``. A row whose request was preempted, cancelled,
+    shed or finished between this dispatch and its emit is void."""
+
+    toks: Any
+    counters: list
+    rows: list
+    lens: np.ndarray        # the lengths the call was given (a snapshot)
+
+
 class ServingEngine:
     """Request-level decode runtime (see module docstring for the loop)."""
 
@@ -414,10 +446,21 @@ class ServingEngine:
         self._block_tables = np.full((sc.max_batch, self.pages_per_req),
                                      NULL_PAGE, np.int32)
         self._lens = np.full((sc.max_batch,), -1, np.int32)
-        self._last_tokens = np.zeros((sc.max_batch,), np.int32)
+        # the last tokens never leave the device: ``decode`` takes the
+        # previous call's sampled tokens as they are and ``prefill``'s
+        # newest sampled token beside them (merged inside the program)
+        self._tokens = self._programs.tokens
+        self._fresh_tok: Any = None
+        # what the device is ahead of the host by: one decode step, and
+        # (inside a tick only) the first token of a prompt's last chunk
+        self._inflight: Optional[_InFlight] = None
+        self._first: Optional[tuple] = None     # (request, admit_seq, tok)
         self._waiting: deque = deque()
         self._prefilling: deque = deque()
-        self._rng = jax.random.PRNGKey(int(seed))
+        # one base key, on the host, and the count of programs dispatched:
+        # a sampling program folds the count into the key itself
+        self._rng = np.asarray(jax.random.PRNGKey(int(seed)))
+        self._draws = 0
         self.draining = False
         self.steps = 0
         self._started_at = time.monotonic()
@@ -428,6 +471,11 @@ class ServingEngine:
         self.last_tick: dict = {}
         self._phase_end = 0.0
         self._drained_at: Optional[float] = None
+        # where the last working tick's period ended (None: the engine was
+        # idle since), and whether a dispatched chunk's device time is not
+        # inside a recorded period yet
+        self._mark: Optional[float] = None
+        self._chunk_unpriced = False
         # monotonic id mint: never reset (reset_stats() zeroing the
         # request counter used to recycle ids across bench windows,
         # silently merging two requests' timelines and router bookkeeping)
@@ -552,10 +600,12 @@ class ServingEngine:
         ``service_s`` is the request's own cost — prefill chunks at the
         measured mean ``serving_chunk_tick`` (a chunk costs its request one
         tick, whatever else rides in that tick: one chunk is forwarded a
-        tick; and a tick that carries a chunk is longer than one that only
-        decodes, so the mean is taken over the chunk-carrying ticks alone)
+        tick; and the period between two drains in which the device ran a
+        chunk is longer than one in which it only decoded, so the mean is
+        taken over the chunk-carrying periods alone)
         plus ``max_new`` tokens at the measured mean inter-token latency.
-        The engine knows wall time per tick, not device time per program;
+        The engine knows the wall time between drains, not device time per
+        program;
         the old per-program timers stopped at a ``device_get`` that a
         chunk other than a prompt's last never reaches, and priced a chunk
         three to six times too low (PERF.md, PR 23). ``eta_s`` adds the queue
@@ -653,12 +703,23 @@ class ServingEngine:
         self.cache = list(out[:n])
         return out[n:]
 
-    def _next_rng(self) -> jax.Array:
-        self._rng, sub = jax.random.split(self._rng)
-        return sub
+    def _draw(self) -> tuple:
+        """``(base key, draw count)`` for the program about to be dispatched:
+        two host values, nothing runs on the device for them."""
+        draw = np.uint32(self._draws & 0xFFFFFFFF)
+        self._draws += 1
+        return self._rng, draw
+
+    def _holds(self, req: ServingRequest, admit_seq: int) -> bool:
+        """Whether ``req`` still is the admission a dispatch ran it as: not
+        preempted, cancelled, shed or finished since."""
+        return req.state == RUNNING and req.admit_seq == admit_seq
 
     def _prefill_step(self) -> bool:
-        """Forward one chunk of the oldest prefilling request."""
+        """Dispatch one chunk of the oldest prefilling request. A prompt's
+        last chunk joins the decode batch at once — its sampled token stays
+        on the device for this tick's decode call, and is fetched for the
+        caller only after that call is dispatched (``_first_token``)."""
         if not self._prefilling:
             return False
         req = self._prefilling[0]
@@ -670,33 +731,44 @@ class ServingEngine:
             n_valid = len(chunk)
             tokens = np.zeros((1, sc.prefill_chunk), np.int32)
             tokens[0, :n_valid] = chunk
-            table = self._block_tables[req.slot:req.slot + 1]
+            # a copy: the program may start after the host's next change
+            table = self._block_tables[req.slot:req.slot + 1].copy()
             if req.prefill_started_at is None:
                 req.prefill_started_at = time.monotonic()
                 self.timelines.note(req.id, "prefill_started")
-            tok = self._rebind(self._call(
+            tok = self._fresh_tok = self._rebind(self._call(
                 "prefill", self.params, *self.cache, tokens, table,
-                np.int32(pos), np.int32(n_valid), self._next_rng(),
+                np.int32(pos), np.int32(n_valid), *self._draw(),
                 *self._programs.prefill_extra(req.slot)))[0]
             req.prefill_pos = pos + n_valid
             self.timelines.note(req.id, "prefill_chunk", chunk=index,
                                 tokens=n_valid)
             if req.prefill_pos >= len(req.prompt):
-                with _Phase(self, "prefill.wait"):
-                    first = int(jax.device_get(tok)[0])
-                self._drained_at = self._phase_end
+                tok.copy_to_host_async()
                 self._prefilling.popleft()
-                now = time.monotonic()
-                req.first_token_at = req.last_token_at = now
-                self._record_first_token(req)
-                self.timelines.note(req.id, "first_token", token=first)
-                self._emit(req, first)
-                if req.state != FINISHED:
-                    req.state = RUNNING
-                    self._lens[req.slot] = len(req.prompt)
-                    self._last_tokens[req.slot] = first
-                flight.note("serving", "first_token", id=req.id)
+                req.state, req.dispatched = RUNNING, 1
+                self._lens[req.slot] = len(req.prompt)
+                self._first = (req, req.admit_seq, tok)
         return True
+
+    def _first_token(self) -> None:
+        """Inside ``serve.emit``: fetch and emit the first token of the last
+        chunk dispatched in this tick, unless its request was shed or
+        preempted since. The wait is for the chunk alone: the decode step
+        dispatched behind it keeps the device busy meanwhile."""
+        req, admit_seq, tok = self._first
+        self._first = None
+        if not self._holds(req, admit_seq):
+            return
+        with _Phase(self, "prefill.wait"):
+            first = int(jax.device_get(tok)[0])
+        if self._drained_at is None:
+            self._drained_at = self._phase_end
+        req.first_token_at = req.last_token_at = time.monotonic()
+        self._record_first_token(req)
+        self.timelines.note(req.id, "first_token", token=first)
+        self._emit(req, first)
+        flight.note("serving", "first_token", id=req.id)
 
     def _record_first_token(self, req: ServingRequest) -> None:
         """``serving_ttft`` and its three parts, which sum to it: the
@@ -713,9 +785,10 @@ class ServingEngine:
             req.first_token_at - req.prefill_started_at)
 
     def _grow_or_preempt(self) -> None:
-        """Extend each RUNNING request's block table to cover the token
-        the next decode step will write; when the pool is dry, preempt
-        the YOUNGEST live request and retry.
+        """Extend the block table of each request the next decode step will
+        run to cover the token that step writes (``_lens`` is advanced when a
+        step is dispatched, so it already names that position); when the
+        pool is dry, preempt the YOUNGEST live request and retry.
 
         Preempting youngest (highest ``admit_seq``) keeps the oldest
         request making forward progress, which bounds the scheme: each
@@ -724,7 +797,7 @@ class ServingEngine:
         livelock. A request can preempt ITSELF (it was the youngest);
         it simply sits out this decode step and re-enters the queue."""
         for req in list(self._slots):
-            if req is None or req.state != RUNNING:
+            if req is None or not self._decodes(req):
                 continue  # freed or preempted earlier in this pass
             need = self.allocator.pages_needed(int(self._lens[req.slot]) + 1)
             while len(req.pages) < need:
@@ -743,6 +816,13 @@ class ServingEngine:
                 if victim is req:
                     break
 
+    @staticmethod
+    def _decodes(req: ServingRequest) -> bool:
+        """Whether the next decode step runs ``req``: it left prefill and
+        not all its tokens are dispatched yet. (A row whose last token is in
+        flight keeps its slot and pages until that token is emitted.)"""
+        return req.state == RUNNING and req.dispatched < req.max_new_tokens
+
     def _youngest_live(self) -> Optional[ServingRequest]:
         """The most recently admitted request still holding pages."""
         live = [r for r in self._slots if r is not None]
@@ -753,7 +833,9 @@ class ServingEngine:
         of the admission queue with all generation state reset — decode
         is deterministic (greedy or seeded), so the re-run regenerates
         the same tokens and the caller never observes the eviction beyond
-        latency."""
+        latency. A step already dispatched still computes its row: that
+        token is void (``_InFlight``), and its write lands before whatever
+        is dispatched to the freed pages next."""
         tsan.note_access(self, "preempt")
         pages_freed = len(req.pages)
         self.allocator.free(req.pages)
@@ -761,12 +843,11 @@ class ServingEngine:
         self._slots[slot] = None
         self._block_tables[slot] = NULL_PAGE
         self._lens[slot] = -1
-        self._last_tokens[slot] = 0
         if req in self._prefilling:
             self._prefilling.remove(req)
         req.state, req.slot, req.pages = WAITING, -1, []
         req.prefill_pos = 0
-        req.tokens = []
+        req.tokens, req.dispatched = [], 0
         req.first_token_at = None
         req.last_token_at = None
         req.preemptions += 1
@@ -809,7 +890,6 @@ class ServingEngine:
             self._slots[slot] = None
             self._block_tables[slot] = NULL_PAGE
             self._lens[slot] = -1
-            self._last_tokens[slot] = 0
             if req in self._prefilling:
                 self._prefilling.remove(req)
         req.slot, req.pages = -1, []
@@ -867,42 +947,83 @@ class ServingEngine:
             req.callback(req)
         return True
 
-    def _decode_step(self) -> bool:
-        """One token for every RUNNING slot (static batch; masked rows)."""
+    def _decode_step(self) -> Optional[_InFlight]:
+        """Schedule, then dispatch one token for every slot that decodes
+        (static batch; masked rows). The host's books move HERE: a row's
+        length advances and its token is counted as dispatched; the values
+        come back a tick later (``_drain``)."""
         with _Phase(self, "schedule"):
             self._shed_expired()
             if self.serving.lazy_alloc:
                 self._grow_or_preempt()
-            running = [r for r in self._slots
-                       if r is not None and r.state == RUNNING]
-        if not running:
-            return False
+            rows = [r for r in self._slots
+                    if r is not None and self._decodes(r)]
+        if not rows:
+            return None
         with _Phase(self, "decode"):
+            # snapshots: ``_lens`` and ``_block_tables`` change while the
+            # program that reads these may not have started (and the CPU
+            # backend may alias a NumPy buffer instead of copying it)
+            slots = [r.slot for r in rows]
+            lens = np.full_like(self._lens, -1)
+            lens[slots] = self._lens[slots]
             if self._walk_shape is not None:
-                self._decoded_lens = self._lens.copy()
+                self._decoded_lens = lens
+            fresh = -1
+            if self._first is not None and self._holds(*self._first[:2]):
+                fresh = self._first[0].slot
+            self.metrics.counter("serving_decode_steps").inc()
+            if self._inflight is not None:
+                self.metrics.counter("serving_decode_overlapped").inc()
             toks, _, *counters = self._rebind(self._call(
-                "decode", self.params, *self.cache, self._last_tokens,
-                self._block_tables, self._lens, self._next_rng()))
-        with _Phase(self, "decode.wait"):
-            # a family's step counters ride with the tokens: one device_get
-            toks, counters = jax.device_get((toks, counters))
-        self._drained_at = self._phase_end
+                "decode", self.params, *self.cache, self._tokens,
+                np.int32(fresh), self._fresh_tok, self._block_tables.copy(),
+                lens, *self._draw()))
+            self._tokens = toks
+            for leaf in jax.tree.leaves((toks, counters)):
+                leaf.copy_to_host_async()   # the fetch will be a wait
+            self._lens[slots] += 1          # the step writes position `lens`
+            for req in rows:
+                req.dispatched += 1
+        return _InFlight(toks, counters, [(r, r.admit_seq) for r in rows],
+                         lens)
+
+    def _drain(self, step: Optional[_InFlight]) -> None:
+        """Fetch the tokens of the step dispatched a tick ago (if one was)
+        and emit them, then the first token of a last chunk dispatched in
+        this tick (if one was): every latency is stamped here, once the
+        value is on the host."""
+        if step is not None:
+            with _Phase(self, "decode.wait"):
+                # a family's step counters ride with the tokens: one
+                # device_get
+                toks, counters = jax.device_get((step.toks, step.counters))
+            self._drained_at = self._phase_end
         with _Phase(self, "emit"):
-            now = time.monotonic()
-            if counters and self._programs.record_stats is not None:
-                self._programs.record_stats(self.metrics, counters[0])
-            for req in running:
-                tok = int(toks[req.slot])
-                self._lens[req.slot] += 1  # the step wrote position `lens`
-                self.metrics.histogram("serving_inter_token").record(
-                    now - req.last_token_at)
-                req.last_token_at = now
-                self.timelines.note(req.id, "decode_tick",
-                                    pos=int(self._lens[req.slot]))
-                self._emit(req, tok)
-                if req.state != FINISHED:
-                    self._last_tokens[req.slot] = tok
-        return True
+            if step is not None:
+                self._emit_step(step, toks, counters)
+            if self._first is not None:
+                self._first_token()
+
+    def _emit_step(self, step: _InFlight, toks: np.ndarray,
+                   counters: list) -> None:
+        """One token for every row of ``step`` that still is what it ran."""
+        now = time.monotonic()
+        if counters and self._programs.record_stats is not None:
+            self._programs.record_stats(self.metrics, counters[0])
+        for req, admit_seq in step.rows:
+            if not self._holds(req, admit_seq):
+                # preempted, cancelled or shed since: no token. Finished
+                # since: it emitted its eos while this step ran
+                if req.state == FINISHED and req.admit_seq == admit_seq:
+                    self.metrics.counter("serving_overrun_rows").inc()
+                continue
+            self.metrics.histogram("serving_inter_token").record(
+                now - req.last_token_at)
+            req.last_token_at = now
+            self.timelines.note(req.id, "decode_tick",
+                                pos=int(step.lens[req.slot]) + 1)
+            self._emit(req, int(toks[req.slot]))
 
     def _emit(self, req: ServingRequest, token: int) -> None:
         """Record one generated token and finish on eos / length."""
@@ -920,7 +1041,6 @@ class ServingEngine:
         self._slots[slot] = None
         self._block_tables[slot] = NULL_PAGE
         self._lens[slot] = -1
-        self._last_tokens[slot] = 0
         self.metrics.counter("serving_requests_completed").inc()
         tl = self.timelines.get(req.id)
         if tl is not None:
@@ -935,7 +1055,11 @@ class ServingEngine:
 
     # ------------------------------------------------------------------ loop
     def step(self) -> bool:
-        """One scheduler iteration; True when any device work ran."""
+        """One scheduler iteration; True when any device work ran or was
+        fetched. The device work of this tick is dispatched BEFORE the
+        tokens of the last one are fetched, so the host's book-keeping and
+        the fetch's return pass while the device runs; exactly one decode
+        step is ever in flight."""
         tsan.note_access(self, "step")
         self.last_tick = {}
         self._drained_at = None
@@ -944,31 +1068,40 @@ class ServingEngine:
             with _Phase(self, "admit"):
                 self._admit()
             chunk = self._prefill_step()
-            worked = self._decode_step() or chunk
-            # the device is drained at the tick's last device_get; a tick
-            # that only dispatched (a prompt's earlier chunk, nothing
-            # decoding) ends where its dispatch did
+            drained, self._inflight = self._inflight, self._decode_step()
+            if drained is not None or self._first is not None:
+                self._drain(drained)
+            # the tick's period ends where its step was drained; with none
+            # in flight, at a first token's fetch; a tick that only
+            # dispatched ends where its dispatch did
             work_end = self._phase_end if self._drained_at is None \
                 else self._drained_at
+            worked = chunk or drained is not None \
+                or self._inflight is not None
             if worked:
                 self.steps += 1
             with _Phase(self, "gauges"):
                 self._update_gauges()
-        self._close_tick(t_top, work_end, worked, chunk)
+        self._close_tick(t_top, work_end, worked)
         return worked
 
-    def _close_tick(self, t_top: float, work_end: float, worked: bool,
-                    chunk: bool) -> None:
-        """Finish the last tick's phase seconds; for a tick that ran device
-        work record ``serving_tick`` (top of ``step`` to the device drained;
-        under ``serving_chunk_tick`` too when it carried a prefill chunk:
-        what ``projected_completion_s`` prices a chunk at) and, first, say
-        so if it took many times the recent ones."""
+    def _close_tick(self, t_top: float, work_end: float,
+                    worked: bool) -> None:
+        """Finish the last tick's phase seconds; for a tick that worked
+        record ``serving_tick``: the period from where the last working
+        tick's ended (the top of ``step`` after an idle one) to where this
+        one's did — between two drains, what a token costs. The period is
+        recorded under ``serving_chunk_tick`` too when the device ran a
+        prefill chunk in it (what ``projected_completion_s`` prices a chunk
+        at): a chunk runs behind the step that was in flight when it was
+        dispatched, so it is inside the period that ends at the NEXT drain.
+        First, say so if the tick took many times the recent periods."""
         phases = self.last_tick
         if "prefill.wait" in phases:      # nested: keep the two disjoint
-            phases["prefill"] -= phases["prefill.wait"]
+            phases["emit"] -= phases["prefill.wait"]
         phases["tick"] = total = self._phase_end - t_top
         if not worked:
+            self._mark = None
             return
         hist = self.metrics.histogram("serving_tick")
         recent = hist.last(SLOW_TICK_WINDOW)
@@ -977,14 +1110,24 @@ class ServingEngine:
             logger.warning("slow tick %.2f s: %s", total, ", ".join(
                 f"{k} {v:.2f}" for k, v in sorted(
                     phases.items(), key=lambda kv: -kv[1]) if k != "tick"))
-        hist.record(work_end - t_top)
-        if chunk:
-            self.metrics.histogram("serving_chunk_tick").record(
-                work_end - t_top)
+        period = work_end - (t_top if self._mark is None else self._mark)
+        self._mark = work_end
+        # what the tick did is in the phases it went through
+        chunk, carried = "prefill" in phases, self._chunk_unpriced
+        if "decode.wait" in phases:     # the device is done with all but
+            priced, self._chunk_unpriced = carried, chunk   # this tick's
+        elif "prefill.wait" in phases:  # ... with this tick's chunk too
+            priced, self._chunk_unpriced = carried or chunk, False
+        else:               # only dispatched: the chunk's time is to come
+            priced, self._chunk_unpriced = chunk, carried or chunk
+        hist.record(period)
+        if priced:
+            self.metrics.histogram("serving_chunk_tick").record(period)
 
     def has_work(self) -> bool:
-        """Anything queued, prefilling or decoding?"""
+        """Anything queued, prefilling, decoding or in flight?"""
         return bool(self._waiting or self._prefilling
+                    or self._inflight is not None
                     or any(r is not None for r in self._slots))
 
     def run_until_drained(self, max_steps: int = 100_000) -> None:
@@ -1040,7 +1183,8 @@ class ServingEngine:
         for name in ("serving_requests_total", "serving_requests_completed",
                      "serving_requests_refused", "serving_requests_preempted",
                      "serving_tokens_total", "serving_deadline_sheds",
-                     "serving_refusals_overloaded",
+                     "serving_decode_steps", "serving_decode_overlapped",
+                     "serving_overrun_rows", "serving_refusals_overloaded",
                      "serving_refusals_unmeetable",
                      "serving_moe_pairs_held_total",
                      "serving_moe_pairs_total"):
@@ -1130,6 +1274,13 @@ class ServingEngine:
                 m.counter("serving_deadline_sheds").value),
             "decode_path": ("paged_kernel" if self.paged_kernel_active
                             else "gather"),
+            # decode steps dispatched, how many of them while the step
+            # before was still unfetched (all but the first of a busy
+            # period), and row-steps computed past an eos and dropped
+            "decode_steps": int(m.counter("serving_decode_steps").value),
+            "decode_overlapped": int(
+                m.counter("serving_decode_overlapped").value),
+            "overrun_rows": int(m.counter("serving_overrun_rows").value),
             **gauges,
             "tokens_total": int(tokens),
             "tokens_per_sec": tokens / wall,

@@ -31,10 +31,13 @@ class Programs:
 
     cache: list                     # device buffers, donated every call
     fns: dict                       # {"prefill", "decode"}: jitted
+    # [max_batch] int32 on the device: what the first ``decode`` call takes
+    # as the last tokens; every later one takes the call before's output
+    tokens: object
     paged_kernel_active: bool
     # (tokens one fold of the decode kernel covers, folds of a table row)
     walk_shape: Optional[tuple] = None
-    # slot -> further arguments of ``prefill`` after the rng
+    # slot -> further arguments of ``prefill`` after the rng and the draw
     prefill_extra: Callable = lambda slot: ()
     # (metrics registry, what ``decode`` returned after its logits)
     record_stats: Optional[Callable] = None
@@ -115,18 +118,23 @@ class GPTFamily(Family):
         """See ``Family.programs``."""
         import jax
 
+        import jax.numpy as jnp
+
         from fleetx_tpu.ops import paged_attention as PA
         from fleetx_tpu.serving.decode import (make_step_fns,
-                                               paged_kernel_enabled)
+                                               paged_kernel_enabled,
+                                               token_sharding)
         from fleetx_tpu.serving.paged_cache import init_pool, pool_shardings
 
         sc = serving
         pool_k, pool_v = init_pool(model_cfg, sc.num_pages, sc.page_size)
+        tokens = jnp.zeros((sc.max_batch,), jnp.int32)
         sharding = None
         if mesh is not None:
             sharding = pool_shardings(mesh)
             pool_k = jax.device_put(pool_k, sharding)
             pool_v = jax.device_put(pool_v, sharding)
+            tokens = jax.device_put(tokens, token_sharding(mesh))
         # kernel-vs-gather is decided HERE, once: the support predicates
         # are static functions of the config/pool/mesh, so the decode
         # program compiles exactly one attention path and the jit cache
@@ -146,7 +154,7 @@ class GPTFamily(Family):
                     mesh.shape["tensor"] if mesh is not None else 1),
                 head_dim=model_cfg.head_dim, page_size=sc.page_size,
                 pages_per_req=pages_per_req, dtype=model_cfg.dtype)
-        return Programs(cache=[pool_k, pool_v], fns=fns,
+        return Programs(cache=[pool_k, pool_v], fns=fns, tokens=tokens,
                         paged_kernel_active=active, walk_shape=walk)
 
 
@@ -191,6 +199,7 @@ class SWAMoEFamily(Family):
     def programs(self, model_cfg, serving, sampling, mesh,
                  pages_per_req: int) -> Programs:
         """See ``Family.programs``."""
+        import jax.numpy as jnp
         import numpy as np
 
         from fleetx_tpu.ops import paged_attention as PA
@@ -243,8 +252,9 @@ class SWAMoEFamily(Family):
             return int(live.sum()), int(np.minimum(live, window).sum())
 
         return Programs(
-            cache=cache, fns=fns, paged_kernel_active=active,
-            walk_shape=walk,
+            cache=cache, fns=fns,
+            tokens=jnp.zeros((sc.max_batch,), jnp.int32),
+            paged_kernel_active=active, walk_shape=walk,
             prefill_extra=lambda slot: (np.int32(slot),),
             record_stats=record, kv_tokens=kv_tokens,
             describe="%d full layers paged, %d window layers a ring of %d "

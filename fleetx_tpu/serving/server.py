@@ -285,6 +285,8 @@ class ReplicaServer:
                 self.engine.begin_drain()
             self._drain_submissions()
             self._serve_control()
+            # True also for the tick that only fetches and emits the one
+            # step still in flight: a drain leaves the loop after it
             worked = self.engine.step()
             if worked:
                 work_steps += 1

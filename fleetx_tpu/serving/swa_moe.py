@@ -58,7 +58,8 @@ import jax.numpy as jnp
 from fleetx_tpu.models.swa_moe import model as M
 from fleetx_tpu.models.swa_moe.config import FULL, WINDOW, SWAMoEConfig
 from fleetx_tpu.ops import paged_attention as PA
-from fleetx_tpu.serving.decode import SamplingParams, _sample
+from fleetx_tpu.serving.decode import (SamplingParams, _sample,
+                                       merge_fresh)
 
 _NEG = -1e30
 
@@ -349,7 +350,9 @@ def make_step_fns(cfg: SWAMoEConfig, *, prefill_chunk: int, page_size: int,
     Both take ``(params, full_k, full_v, ring_k, ring_v, ...)``, donate the
     four cache buffers and return them first. ``prefill`` then takes what
     GPT's takes and the slot whose ring the request owns; ``decode`` what
-    GPT's takes. After the caches come the sampled token(s), the float32
+    GPT's takes (the last tokens as the device holds them, the one fresh
+    row, tables, lengths, the base key and the draw count). After the
+    caches come the sampled token(s), the float32
     logits and, from ``decode``, the step's expert counters (held experts
     hit, summed over the expert layers; pairs on held experts): they ride
     to the host with the tokens. Shapes are static (``max_batch`` /
@@ -358,7 +361,7 @@ def make_step_fns(cfg: SWAMoEConfig, *, prefill_chunk: int, page_size: int,
     rp = ring_pages(cfg, page_size, prefill_chunk)
 
     def prefill(params, full_k, full_v, ring_k, ring_v, tokens, block_table,
-                start, n_valid, rng, slot):
+                start, n_valid, rng, draw, slot):
         """One prompt chunk of the request in slot ``slot``: ``tokens``
         ``[1, C]`` with ``n_valid`` real entries from position ``start``."""
         idx = jnp.arange(prefill_chunk, dtype=jnp.int32)[None, :]
@@ -373,12 +376,15 @@ def make_step_fns(cfg: SWAMoEConfig, *, prefill_chunk: int, page_size: int,
         x_last = jax.lax.dynamic_index_in_dim(x[0], at, axis=0,
                                               keepdims=False)[None]
         logits = _logits(params, x_last)
-        return (*cache, _sample(logits, rng, sampling), logits)
+        return (*cache, _sample(logits, rng, draw, sampling), logits)
 
-    def decode(params, full_k, full_v, ring_k, ring_v, tokens, block_tables,
-               lens, rng):
+    def decode(params, full_k, full_v, ring_k, ring_v, tokens, fresh_slot,
+               fresh_tok, block_tables, lens, rng, draw):
         """One token for every slot: ``tokens`` / ``lens`` ``[max_batch]``
-        (an empty slot carries ``lens < 0`` and a null-page table)."""
+        (an empty slot carries ``lens < 0`` and a null-page table);
+        ``tokens`` is the previous call's sampled tokens, ``merge_fresh``
+        puts the one request that left prefill this tick in its slot."""
+        tokens = merge_fresh(tokens, fresh_slot, fresh_tok)
         positions = jnp.where(lens >= 0, lens, -1)[:, None]
         slots = jnp.arange(tokens.shape[0], dtype=jnp.int32)
         x, cache, stats = _forward(
@@ -389,7 +395,8 @@ def make_step_fns(cfg: SWAMoEConfig, *, prefill_chunk: int, page_size: int,
             moe_kernel="moe_gmm_decode")
         logits = _logits(params, x[:, 0])
         stats["rows"] = (lens >= 0).sum().astype(jnp.int32)
-        return (*cache, _sample(logits, rng, sampling), logits, stats)
+        return (*cache, _sample(logits, rng, draw, sampling), logits,
+                stats)
 
     donate = (1, 2, 3, 4)
     return {"prefill": jax.jit(prefill, donate_argnums=donate),

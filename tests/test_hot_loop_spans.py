@@ -117,21 +117,34 @@ def test_serving_tick_spans_reach_the_profiler_nested(tmp_path, small_model,
         holders = [t for t in ticks if t["ts"] <= e["ts"] and
                    e["ts"] + e["dur"] <= t["ts"] + t["dur"]]
         assert len(holders) == 1, e
+    # a tick dispatches (prefill, decode) before it fetches (decode.wait,
+    # then, inside emit, prefill.wait): its phases in that order, each once
+    order = ["serve.admit", "serve.prefill", "serve.schedule", "serve.decode",
+             "serve.decode.wait", "serve.emit", "serve.prefill.wait",
+             "serve.gauges"]
     for t in ticks:
         held = [e for e in inner if t["ts"] <= e["ts"] < t["ts"] + t["dur"]]
         assert 1 + len(held) <= 10
         names = [e["name"] for e in sorted(held, key=lambda e: e["ts"])]
-        assert len(names) == len(set(names)), "each phase once a tick"
+        assert names == [n for n in order if n in names], names
         assert names[0] == "serve.admit" and names[-1] == "serve.gauges"
+    both = [t for t in ticks if {"serve.decode", "serve.decode.wait"} <= {
+        e["name"] for e in inner if t["ts"] <= e["ts"] < t["ts"] + t["dur"]}]
+    assert len(both) >= 3, "steps were dispatched with one still unfetched"
     chunks = [e for e in mine if e["name"] == "serve.prefill"]
     assert {e["args"]["rid"] for e in chunks} == {"r3", "r6", "r5"}
     assert sorted(e["args"]["chunk"] for e in chunks
                   if e["args"]["rid"] == "r6") == [0, 1]
     waits = [e for e in mine if e["name"] == "serve.prefill.wait"]
     assert len(waits) == 3      # one a prompt: its last chunk's device_get
-    for w in waits:
+    emits = [e for e in mine if e["name"] == "serve.emit"]
+    for w in waits:     # after this tick's chunk, inside this tick's emit
         assert any(c["ts"] <= w["ts"] and
-                   w["ts"] + w["dur"] <= c["ts"] + c["dur"] for c in chunks)
+                   w["ts"] + w["dur"] <= c["ts"] + c["dur"] for c in emits)
+        tick = next(t for t in ticks
+                    if t["ts"] <= w["ts"] < t["ts"] + t["dur"])
+        assert any(tick["ts"] <= c["ts"] and c["ts"] + c["dur"] <= w["ts"]
+                   for c in chunks)
     # nothing else of the program's carries a hot-loop prefix
     assert not [e["name"] for e in events
                 if e["name"].startswith(("serve.", "fit."))
@@ -394,8 +407,9 @@ def test_slow_tick_names_its_phase(small_model, caplog):
         assert "slow tick" not in caplog.text
         tick = eng.metrics.histogram("serving_tick")
         assert tick.total_count == 12
-        # top of step() to the device drained: the reads up to decode.wait
+        # from one drain to the next: every reading of a tick, once
         assert all(0.005 < s < 0.02 for s in tick.last(12))
+        assert tick.last(10) == pytest.approx([0.013] * 10, abs=1e-9)
         assert eng.metrics.histogram("serving_chunk_tick").total_count == 1
         assert eng.last_tick["tick"] == pytest.approx(
             sum(v for k, v in eng.last_tick.items() if k != "tick"),
@@ -418,10 +432,12 @@ def test_slow_tick_names_its_phase(small_model, caplog):
         assert lines[0].startswith("slow tick 2.5")
         assert lines[0].split(": ")[1].startswith("gauges 2.50, ")
         assert eng.last_tick["gauges"] == pytest.approx(2.501, abs=2e-3)
-        # the stall came after the device was drained: serving_tick does
-        # not hold it, and the next tick is not measured against it
+        # the stall came after the drain: it delays the NEXT token, so the
+        # next period (drain to drain) holds it, this one does not; the line
+        # is said once, by the tick whose phase stalled
         assert tick.last(1)[0] < 0.02
         assert eng.step()
+        assert tick.last(1)[0] == pytest.approx(2.513, abs=2e-3)
         assert len([r for r in caplog.records
                     if "slow tick" in r.getMessage()]) == 1
     finally:
@@ -437,7 +453,9 @@ def test_projection_prices_a_chunk_at_the_mean_chunk_tick(small_model):
     tick = m.histogram("serving_tick")
     chunk = m.histogram("serving_chunk_tick")
     itl = m.histogram("serving_inter_token")
-    # five prompt tokens in chunks of four: two of the ticks carried a chunk
+    # five prompt tokens in chunks of four: two of the periods held a chunk
+    # (nothing was in flight: the first ends where its dispatch did, the
+    # second at the first token's fetch)
     assert tick.total_count == eng.steps > chunk.total_count == 2
     assert chunk.last(2) == tick.last(tick.total_count)[:2]
     mean_chunk = chunk.total_sum / chunk.total_count
@@ -453,6 +471,44 @@ def test_projection_prices_a_chunk_at_the_mean_chunk_tick(small_model):
     for gone in ("serving_prefill_step", "serving_decode_step"):
         assert gone not in m.snapshot() or \
             m.histogram(gone).total_count == 0
+
+
+def test_a_chunk_is_priced_in_the_period_that_ends_at_the_next_drain(
+        small_model):
+    """With a step in flight, a chunk runs on the device BEHIND that step:
+    the period in which the device ran it ends at the drain of the tick
+    after its dispatch, and that period is the one ``serving_chunk_tick``
+    (so ``projected_completion_s``) takes."""
+    eng = _engine(small_model)
+    eng._clock = _Clock()
+    eng.submit([5, 9, 23], 24, request_id="long")
+    for _ in range(4):
+        eng.step()
+    m = eng.metrics
+    tick = m.histogram("serving_tick")
+    chunk = m.histogram("serving_chunk_tick")
+    assert chunk.total_count == 1
+    eng.submit([7, 3, 11, 2, 8, 4, 19, 33, 6], 2, request_id="late")
+    dispatched, priced = [], []
+    for _ in range(6):
+        before = chunk.total_count
+        assert eng.step()
+        dispatched.append("prefill" in eng.last_tick)
+        priced.append(chunk.total_count - before)
+        if priced[-1]:
+            assert chunk.last(1) == tick.last(1)
+    assert dispatched == [True, True, True, False, False, False]
+    assert priced == [0, 1, 1, 1, 0, 0]
+    # a period that held a chunk is longer than one that only decoded (on
+    # this clock by a phase's two readings: the chunk's dispatch or, for the
+    # last, its first token's fetch, which comes after that tick's drain)
+    assert chunk.last(3) == pytest.approx([0.015] * 3, abs=1e-9)
+    assert tick.last(2) == pytest.approx([0.013] * 2, abs=1e-9)
+    service, _ = eng.projected_completion_s(8, 1)
+    itl = m.histogram("serving_inter_token")
+    assert service == pytest.approx(
+        2 * chunk.total_sum / chunk.total_count
+        + itl.total_sum / itl.total_count)
 
 
 def test_router_trace_keeps_the_attribution_with_a_first_token():

@@ -106,7 +106,7 @@ def _serve(cfg, params, prompt, new, *, max_seq=64, slot=1, max_batch=3,
         row[0, :len(part)] = part
         *cache, tok, lg = fns["prefill"](
             params, *cache, row, table[slot:slot + 1], np.int32(pos),
-            np.int32(len(part)), key, np.int32(slot))
+            np.int32(len(part)), key, np.uint32(0), np.int32(slot))
         pos += len(part)
     logits.append(np.asarray(lg[0]))
     toks.append(int(tok[0]))
@@ -114,8 +114,9 @@ def _serve(cfg, params, prompt, new, *, max_seq=64, slot=1, max_batch=3,
     last = np.zeros((max_batch,), np.int32)
     for _ in range(new):
         lens[slot], last[slot] = len(toks) - 1, toks[-1]
-        *cache, tk, lg, stats = fns["decode"](params, *cache, last, table,
-                                              lens, key)
+        *cache, tk, lg, stats = fns["decode"](
+            params, *cache, last, np.int32(-1), np.zeros((1,), np.int32),
+            table, lens, key, np.uint32(0))
         logits.append(np.asarray(lg[slot]))
         toks.append(int(tk[slot]))
     return toks, logits, jax.device_get(stats), fns
@@ -369,6 +370,53 @@ def test_a_preempted_request_resumes_to_the_same_tokens(toy):
     assert sum(r.preemptions for r in pressed) > 0
     for a, b in zip(calm, pressed):
         assert a.tokens == b.tokens and len(b.tokens) == 24
+
+
+@pytest.mark.parametrize("case", ["eos", "preempt_in_flight"])
+def test_the_family_runs_the_one_tick_order(toy, case):
+    """This family under the engine's one loop (a step is dispatched before
+    the one before it is fetched; its programs take the slot's ring and
+    return expert counters): a row that ends on an eos is acted on a step
+    late, its overrun dropped and counted while the others run on; a row
+    preempted while it is in flight gets nothing from that step and serves
+    the same tokens again; the counters ride with every fetched step."""
+    cfg, params, _, _ = toy
+    rng = np.random.default_rng(21)
+    prompts = [rng.integers(0, 64, size=n).tolist() for n in (7, 19, 11)]
+
+    def serve(eos=-1, preempt=None):
+        eng = ServingEngine(cfg, params, ServingConfig(
+            max_batch=3, page_size=PAGE, num_pages=40, max_seq_len=64,
+            prefill_chunk=CHUNK, max_queue=0), SamplingParams(),
+            eos_token_id=eos)
+        eng.reset_stats()
+        reqs = [eng.submit(p, 14) for p in prompts]
+        while eng.has_work():
+            eng.step()
+            r = reqs[preempt] if preempt is not None else None
+            if r is not None and len(r.tokens) == 4 and not r.preemptions:
+                assert any(q is r for q, _ in eng._inflight.rows)
+                eng._preempt(r)
+        assert eng.allocator.allocated_pages == 0 and eng._inflight is None
+        m = eng.metrics
+        assert m.histogram("serving_moe_experts_hit").total_count == \
+            m.counter("serving_decode_steps").value
+        return reqs, m.counter("serving_overrun_rows").value
+
+    calm, overrun = serve()
+    assert overrun == 0 and all(len(r.tokens) == 14 for r in calm)
+    if case == "eos":
+        eos = next(t for t in calm[1].tokens[2:-1]
+                   if t not in calm[1].tokens[:2])
+        want = [r.tokens[:r.tokens.index(eos) + 1] if eos in r.tokens
+                else r.tokens for r in calm]
+        got, overrun = serve(eos=eos)
+        assert [r.tokens for r in got] == want
+        assert overrun == sum(len(w) < 14 for w in want) >= 1
+    else:
+        got, overrun = serve(preempt=2)
+        assert got[2].preemptions == 1 and overrun == 0
+        assert [r.tokens for r in got] == [r.tokens for r in calm]
 
 
 def test_window_cache_bytes_do_not_follow_max_seq_len(toy):

@@ -109,8 +109,9 @@ def test_serving_decode_lowers_for_tpu(devices8, monkeypatch, dist):
     assert engine.paged_kernel_active
     found = _lower_for_tpu(
         monkeypatch, engine._fns["decode"], engine.params, engine.pool_k,
-        engine.pool_v, engine._last_tokens, engine._block_tables,
-        engine._lens, engine._next_rng())
+        engine.pool_v, engine._tokens, np.int32(-1),
+        np.zeros((1,), np.int32), engine._block_tables, engine._lens,
+        *engine._draw())
     assert set(found) == {"paged_decode"}, found
 
 
@@ -198,9 +199,11 @@ def _compile_serving_programs(topo, monkeypatch, model, dist, kernel, *,
     i32, rng = jnp.int32, arr((2,), jnp.uint32)
     programs = {
         "prefill": (params, pool, pool, arr((1, chunk), i32),
-                    arr((1, per_req), i32), arr((), i32), arr((), i32), rng),
-        "decode": (params, pool, pool, arr((batch,), i32),
-                   arr((batch, per_req), i32), arr((batch,), i32), rng),
+                    arr((1, per_req), i32), arr((), i32), arr((), i32), rng,
+                    arr((), jnp.uint32)),
+        "decode": (params, pool, pool, arr((batch,), i32), arr((), i32),
+                   arr((1,), i32), arr((batch, per_req), i32),
+                   arr((batch,), i32), rng, arr((), jnp.uint32)),
     }
     texts = {name: fns[name].lower(*args).compile().as_text()
              for name, args in programs.items()}
@@ -437,9 +440,10 @@ def test_swa_moe_programs_keep_both_caches_one_buffer(topo, monkeypatch):
     rng = arr((2,), jnp.uint32)
     programs = {
         "prefill": (params, *cache, arr((1, chunk)), arr((1, per_req)),
-                    arr(()), arr(()), rng, arr(())),
-        "decode": (params, *cache, arr((batch,)), arr((batch, per_req)),
-                   arr((batch,)), rng),
+                    arr(()), arr(()), rng, arr((), jnp.uint32), arr(())),
+        "decode": (params, *cache, arr((batch,)), arr(()), arr((1,)),
+                   arr((batch, per_req)), arr((batch,)), rng,
+                   arr((), jnp.uint32)),
     }
     kernels = {"prefill": {"moe_gmm_prefill"},
                "decode": {"moe_gmm_decode", "paged_decode",

@@ -314,7 +314,7 @@ def test_merged_int8_decode_within_drift_bound(pipeline):
         tokens[0, :4] = prompt
         _, _, _, logits = eng._fns["prefill"](
             eng.params, eng.pool_k, eng.pool_v, tokens, table,
-            np.int32(0), np.int32(4), jax.random.PRNGKey(0))
+            np.int32(0), np.int32(4), jax.random.PRNGKey(0), np.uint32(0))
         return req.tokens, np.asarray(logits)[0]
 
     fp_tokens, fp_logits = run(False)

@@ -294,7 +294,7 @@ def test_quantized_decode_parity_bounded(request, which, paged_kernel):
         tokens[0, :4] = prompts[0]
         _, _, _, logits = eng._fns["prefill"](
             eng.params, pool_k, pool_v, tokens, table, np.int32(0),
-            np.int32(4), jax.random.PRNGKey(0))
+            np.int32(4), jax.random.PRNGKey(0), np.uint32(0))
         return [r.tokens for r in reqs], np.asarray(logits)[0]
 
     fp_tokens, fp_logits = run(False)
@@ -465,8 +465,9 @@ def test_step_fns_refuse_a_tree_they_would_have_to_cast():
     cfg, _, params = _build_model(**RECIPE_DTYPES)
     fns = make_step_fns(cfg, max_batch=2, pages_per_req=4, prefill_chunk=4,
                         sampling=SamplingParams())
-    args = (np.zeros((2,), np.int32), np.zeros((2, 4), np.int32),
-            np.full((2,), -1, np.int32), jax.random.PRNGKey(0))
+    args = (np.zeros((2,), np.int32), np.int32(-1), np.zeros((1,), np.int32),
+            np.zeros((2, 4), np.int32), np.full((2,), -1, np.int32),
+            jax.random.PRNGKey(0), np.uint32(0))
     with pytest.raises(TypeError, match="10 leaves are not in bfloat16"):
         fns["decode"](params, *init_pool(cfg, 9, 4), *args)
     fns["decode"](serving_params(params, cfg), *init_pool(cfg, 9, 4), *args)
@@ -514,7 +515,7 @@ def test_engine_casts_once_what_a_hand_cast_tree_serves(quantize, caplog):
         tokens = np.asarray([prompts[0]], np.int32)
         _, _, _, logits = eng._fns["prefill"](
             eng.params, eng.pool_k, eng.pool_v, tokens, table, np.int32(0),
-            np.int32(4), jax.random.PRNGKey(0))
+            np.int32(4), *eng._draw())
         assert eng._fns["prefill"]._cache_size() == 1
         return eng, said[0], [r.tokens for r in reqs], np.asarray(logits)
 
@@ -539,8 +540,9 @@ def test_engine_casts_once_what_a_hand_cast_tree_serves(quantize, caplog):
 def _decode_jaxpr(eng):
     """The traced decode program (pins which attention path compiled)."""
     return str(jax.make_jaxpr(eng._fns["decode"])(
-        eng.params, eng.pool_k, eng.pool_v, eng._last_tokens,
-        eng._block_tables, eng._lens, jax.random.PRNGKey(0)))
+        eng.params, eng.pool_k, eng.pool_v, eng._tokens, np.int32(-1),
+        np.zeros((1,), np.int32), eng._block_tables, eng._lens,
+        jax.random.PRNGKey(0), np.uint32(0)))
 
 
 def test_null_page_constant_pinned_across_modules():
@@ -900,7 +902,7 @@ def test_page_walk_share_gauge_counts_what_the_kernel_folds(small_model,
 
     def spy(name, *args):
         if name == "decode":
-            given.append(np.array(args[5]))  # the call's query positions
+            given.append(np.array(args[7]))  # the call's query positions
         return real(name, *args)
 
     monkeypatch.setattr(eng, "_call", spy)
@@ -1030,6 +1032,296 @@ def test_allocator_conserves_pages_under_grow_free_preempt():
     for grant in held:
         a.free(grant)
     assert a.free_pages == a.usable_pages
+
+
+# ---------------------------------------------------------------------------
+# the tick's order: step N+1 is dispatched before step N is fetched (PR 36)
+# ---------------------------------------------------------------------------
+
+def _tick_engine(small_model, eos=EOS, **over):
+    cfg, _, params = small_model
+    kw = dict(max_batch=4, page_size=4, num_pages=33, max_seq_len=32,
+              prefill_chunk=4)
+    kw.update(over)
+    eng = ServingEngine(cfg, params, ServingConfig(**kw), eos_token_id=eos)
+    eng.reset_stats()
+    return eng
+
+
+def _in_flight(eng, req) -> bool:
+    """Whether the step the device runs right now has a row of ``req``."""
+    step = eng._inflight
+    return step is not None and any(r is req for r, _ in step.rows)
+
+
+TICK_PROMPTS = [[5, 9, 23, 41], [7, 3], [11, 2, 8, 4, 19, 33, 7, 6, 1, 2, 3]]
+
+
+@pytest.mark.parametrize("where", ["mid_stream", "first_token"])
+def test_an_eos_is_acted_on_one_step_late_and_the_overrun_is_dropped(
+        small_model, where):
+    """Some rows end on an eos while others run on: every row is the
+    one-shot row up to and with its eos, the one row-step the device
+    computed past each eos is dropped and counted, and no page leaks."""
+    cfg, model, params = small_model
+    new = 10
+    rows = [[int(t) for t in row]
+            for row in one_shot(model, params, TICK_PROMPTS, new)]
+    if where == "first_token":
+        eos = rows[0][0]
+    else:   # a token some row reaches after its first and before its last
+        eos = next(t for row in rows for t in row[1:-1]
+                   if t not in (row[0], rows[0][0]))
+    want = [row[:row.index(eos) + 1] if eos in row else row for row in rows]
+    assert any(len(w) < new for w in want) and \
+        any(len(w) == new for w in want), (eos, rows)
+    eng = _tick_engine(small_model, eos=eos)
+    reqs = [eng.submit(p, new, request_id=f"e{i}")
+            for i, p in enumerate(TICK_PROMPTS)]
+    eng.run_until_drained()
+    assert [r.tokens for r in reqs] == want
+    assert all(r.state == "finished" and r.error is None for r in reqs)
+    # an eos before a row's last token had one more row-step in flight
+    overrun = sum(len(w) < new for w in want)
+    assert eng.metrics.counter("serving_overrun_rows").value == overrun
+    assert eng.serving_snapshot()["overrun_rows"] == overrun
+    assert eng.metrics.counter("serving_tokens_total").value == \
+        sum(len(w) for w in want)
+    assert eng.allocator.allocated_pages == 0
+    assert eng.allocator.free_pages == eng.allocator.usable_pages
+    assert not eng.has_work() and eng._inflight is None
+
+
+@pytest.mark.parametrize("decision", ["preempt", "cancel", "shed"])
+def test_a_decision_voids_the_row_already_in_flight(small_model, decision):
+    """Preempted, cancelled or shed while its row runs on the device: the
+    request gets no token and no callback from that step; the other row of
+    the step does; run again, the request serves the one-shot tokens."""
+    cfg, model, params = small_model
+    want = one_shot(model, params, TICK_PROMPTS[:2], 10)
+    eng = _tick_engine(small_model)
+    calls = []
+    a = eng.submit(TICK_PROMPTS[0], 10, request_id="a",
+                   callback=lambda r: calls.append((r.state, len(r.tokens))),
+                   deadline_s=600.0 if decision == "shed" else None)
+    b = eng.submit(TICK_PROMPTS[1], 10, request_id="b")
+    while len(a.tokens) < 3 or len(b.tokens) < 2:
+        assert eng.step()
+    assert _in_flight(eng, a) and _in_flight(eng, b)
+    held, b_held = list(a.tokens), len(b.tokens)
+    if decision == "preempt":
+        eng._preempt(a)             # as _grow_or_preempt does, mid-schedule
+        assert a.state == "waiting" and a.tokens == [] and not calls
+    elif decision == "cancel":
+        assert eng.cancel("a")
+        assert a.state == "refused" and calls == [("refused", len(held))]
+    else:
+        a.deadline_s = 1e-6         # expired: the next schedule sheds it
+    assert eng.step()               # fetches the step that ran a's row
+    assert len(b.tokens) == b_held + 1, "the other row of that step counts"
+    if decision == "preempt":
+        # admitted again at the top of that tick: at most the first token of
+        # its new pass, never one from the step its old pass was in
+        assert a.tokens in ([], held[:1]) and a.preemptions == 1
+        assert not calls
+    else:
+        assert a.tokens == held and a.state == "refused"
+        assert calls == [("refused", len(held))]
+        assert ("deadline_shed" in a.error) == (decision == "shed")
+    eng.run_until_drained()
+    check_parity(b, want[1])
+    if decision != "preempt":
+        assert a.tokens == held and len(calls) == 1
+        a = eng.submit(TICK_PROMPTS[0], 10, request_id="a2")
+        eng.run_until_drained()
+    assert a.state == "finished"
+    check_parity(a, want[0])
+    assert eng.metrics.counter("serving_overrun_rows").value == 0
+    assert eng.allocator.allocated_pages == 0 and not eng.has_work()
+
+
+def test_a_request_grows_a_token_a_step_as_the_harness_reads_it(small_model):
+    """What ``benchmarks/serve_cell.py:Loop.tick`` stands on: per ``step()``
+    a request grows by at most one token (two only in the step in which its
+    first lands), stamped ``[first_token_at, last_token_at]``, over a run
+    with joins, finishes and a preemption; ``serving_tokens_total`` counts a
+    token when it is emitted."""
+    cfg, model, params = small_model
+    eng = _tick_engine(small_model, num_pages=10,    # 9 usable: it preempts
+                       alloc_watermark=0)
+    prompts = [[5 + i, 9, 23, 41, 7, 3][:3 + i % 4] for i in range(7)]
+    want = one_shot(model, params, prompts, 9)
+    live, seen, stamps, done = {}, {}, {}, []
+    todo = list(enumerate(prompts))
+    for _ in range(500):
+        while todo and len(live) < 5:
+            i, prompt = todo.pop(0)
+            live[i] = eng.submit(prompt, 9, request_id=f"h{i}")
+            seen[i], stamps[i] = 0, []
+        if not live:
+            break
+        eng.step()
+        for i, h in list(live.items()):
+            if len(h.tokens) < seen[i]:         # preempted: it starts over
+                seen[i], stamps[i] = 0, []
+            grown = len(h.tokens) - seen[i]
+            assert grown <= (2 if seen[i] == 0 else 1), (h.id, grown)
+            if grown:
+                times = [h.last_token_at]
+                if seen[i] == 0:
+                    times = [h.first_token_at] + (
+                        [h.last_token_at] if grown > 1 else [])
+                assert len(times) == grown
+                stamps[i] += times
+                seen[i] += grown
+            if h.state == "finished":
+                done.append(live.pop(i))
+        assert eng.metrics.counter("serving_tokens_total").value >= \
+            sum(seen.values())
+    assert len(done) == len(prompts) and not eng.has_work()
+    assert sum(h.preemptions for h in done) > 0, "the drill needs one"
+    for i, row in enumerate(want):
+        h = next(h for h in done if h.id == f"h{i}")
+        check_parity(h, row)
+        assert stamps[i] == sorted(stamps[i]) and len(stamps[i]) == 9
+        assert h.first_token_at == stamps[i][0]
+        assert h.finished_at >= h.last_token_at == stamps[i][-1]
+
+
+@pytest.mark.parametrize("how", ["run_until_drained", "begin_drain"])
+def test_a_drain_delivers_the_token_in_flight(small_model, how):
+    """The last in-flight step is flushed: a tick that only fetches and
+    emits returns True, and ``has_work()`` is false only after it."""
+    cfg, model, params = small_model
+    want = one_shot(model, params, TICK_PROMPTS, 6)
+    eng = _tick_engine(small_model)
+    reqs = [eng.submit(p, 6, request_id=f"d{i}")
+            for i, p in enumerate(TICK_PROMPTS)]
+    if how == "run_until_drained":
+        eng.run_until_drained()
+    else:
+        for _ in range(4):
+            eng.step()
+        assert eng._inflight is not None
+        eng.begin_drain()
+        assert eng.submit([1, 2], 2).error == "draining"
+        fetch_only = 0
+        while eng.step():       # the server loop's condition
+            dispatched = "decode" in eng.last_tick or \
+                "prefill" in eng.last_tick
+            fetch_only += not dispatched
+            assert dispatched or "decode.wait" in eng.last_tick
+        assert fetch_only == 1, "the last tick only fetched and emitted"
+    assert not eng.has_work() and eng._inflight is None
+    assert not eng.step()
+    for req, row in zip(reqs, want):
+        assert req.state == "finished" and len(req.tokens) == 6
+        check_parity(req, row)
+    assert eng.allocator.allocated_pages == 0
+
+
+def test_a_dispatch_takes_snapshots_of_the_hosts_arrays(small_model,
+                                                        monkeypatch):
+    """``_block_tables`` and ``_lens`` change while a program that read them
+    may not have started (and the CPU backend may alias a NumPy buffer):
+    every call is given arrays of its own, and scribbling over the host's
+    right after a tick changes nothing that tick dispatched."""
+    cfg, model, params = small_model
+    want = one_shot(model, params, TICK_PROMPTS, 8)
+    eng = _tick_engine(small_model)
+    real, calls = eng._call, []
+
+    def spy(name, *args):
+        host = [a for a in args if isinstance(a, np.ndarray) and a.ndim]
+        assert len(host) >= 3       # tokens or lengths, a table, the key
+        for a in host:
+            assert not np.shares_memory(a, eng._block_tables), name
+            assert not np.shares_memory(a, eng._lens), name
+        calls.append(name)
+        return real(name, *args)
+
+    monkeypatch.setattr(eng, "_call", spy)
+    reqs = [eng.submit(p, 8, request_id=f"s{i}")
+            for i, p in enumerate(TICK_PROMPTS)]
+    while eng.has_work():
+        eng.step()
+        tables, lens = eng._block_tables.copy(), eng._lens.copy()
+        eng._block_tables[:] = NULL_PAGE
+        eng._lens[:] = 0
+        if eng._inflight is not None:
+            jax.block_until_ready(eng._inflight.toks)
+        eng._block_tables[:] = tables
+        eng._lens[:] = lens
+    assert {"prefill", "decode"} == set(calls)
+    for req, row in zip(reqs, want):
+        check_parity(req, row)
+
+
+def test_decode_overlapped_counts_all_but_the_first_of_a_busy_period(
+        small_model, monkeypatch):
+    """``serving_decode_overlapped`` is the decode steps dispatched while
+    the step before was still unfetched: all of a busy period but its
+    first."""
+    eng = _tick_engine(small_model)
+    real, decodes = eng._call, []
+
+    def spy(name, *args):
+        if name == "decode":
+            decodes.append(eng._inflight is not None)
+        return real(name, *args)
+
+    monkeypatch.setattr(eng, "_call", spy)
+    for period in range(2):
+        for i, p in enumerate(TICK_PROMPTS):
+            eng.submit(p, 5 + i, request_id=f"o{period}{i}")
+        eng.run_until_drained()
+        assert not eng.step()           # idle between the two
+    m = eng.metrics
+    steps = m.counter("serving_decode_steps").value
+    assert steps == len(decodes) > 8
+    assert m.counter("serving_decode_overlapped").value == steps - 2 \
+        == sum(decodes)
+    snap = eng.serving_snapshot()
+    assert (snap["decode_steps"], snap["decode_overlapped"]) == \
+        (steps, steps - 2)
+    assert validate_serving_record(snap) == []
+    for name in ("serving_decode_steps", "serving_decode_overlapped",
+                 "serving_overrun_rows"):
+        assert name in SERVING_METRIC_NAMES
+
+
+def test_sampling_folds_a_host_count_into_one_key_inside_the_program(
+        small_model):
+    """No key is split on the device in a tick: a sampling engine hands its
+    programs the one base key and the count of programs dispatched, and the
+    same seed serves the same stream; greedy programs read neither."""
+    cfg, _, params = small_model
+    sc = ServingConfig(max_batch=2, page_size=4, num_pages=17,
+                       max_seq_len=32, prefill_chunk=4)
+
+    def run(seed):
+        eng = ServingEngine(cfg, params, sc, SamplingParams(
+            do_sample=True, temperature=1.0, top_k=20), eos_token_id=EOS,
+            seed=seed)
+        eng.reset_stats()
+        key = eng._rng.copy()
+        reqs = [eng.submit(p, 8) for p in TICK_PROMPTS[:2]]
+        eng.run_until_drained()
+        assert isinstance(eng._rng, np.ndarray) and \
+            np.array_equal(eng._rng, key), "the base key never moves"
+        assert eng._draws == \
+            eng.metrics.counter("serving_decode_steps").value + 2
+        return [r.tokens for r in reqs]
+
+    first, again, other = run(3), run(3), run(4)
+    assert first == again and first != other
+    assert all(len(t) == 8 for t in first)
+    greedy = _tick_engine(small_model)
+    text = str(jax.make_jaxpr(greedy._fns["decode"])(
+        greedy.params, greedy.pool_k, greedy.pool_v, greedy._tokens,
+        np.int32(-1), np.zeros((1,), np.int32), greedy._block_tables,
+        greedy._lens, *greedy._draw()))
+    assert "random_bits" not in text and "threefry" not in text
 
 
 # ---------------------------------------------------------------------------
