@@ -1,15 +1,21 @@
 """Configuration of the windowed-attention sparse-expert decoder family.
 
-The keys are those of the published ``config.json`` of the Laguna line of
-models (``layer_types``, ``num_attention_heads_per_layer``,
-``mlp_only_layers``, ``rope_parameters``, ``num_experts`` ...), so a recipe
-reads like the model card. The layer pattern is DATA: which layers attend
-over a window, how many query heads each layer has and which layers have a
-dense MLP are lists the model walks, nothing in the code names a period.
-Three keys describe what the published file cannot: the chip's share of
-the expert layer (``experts_held`` and ``first_expert_held``: the router
-still scores all ``num_experts``) and of the vocabulary (``vocab_size`` is
-the number of ids held here; traffic, logits and sampling are over them).
+Two published models are members (``docs/swa_moe.md``): Laguna-S-2.1 and
+SmallThinker-21BA3B-Instruct. The keys are those of the first's
+``config.json`` (``layer_types``, ``num_attention_heads_per_layer``,
+``mlp_only_layers``, ``rope_parameters``, ``num_experts`` ...); a recipe of
+the second derives them from its own published keys and says which beside
+them. The layer pattern is DATA: which layers attend over a window, how
+many query heads each layer has, which layers have a dense MLP, whether a
+layer type rotates at all. So is everything else the members differ in:
+the gate, where the router reads and how it scores, the experts'
+activation, the shared expert, the routed scaling. A recipe states each of
+those keys (``MEMBER_KEYS``): ``config_from_dict`` lends no model's number
+to another. Three keys describe what no published file can: the chip's
+share of the expert layer (``experts_held`` and ``first_expert_held``: the
+router still scores all ``num_experts``) and of the vocabulary
+(``vocab_size`` is the number of ids held here; traffic, logits and
+sampling are over them).
 """
 
 from __future__ import annotations
@@ -21,7 +27,20 @@ import jax.numpy as jnp
 
 FULL, WINDOW = "full_attention", "sliding_attention"
 
-#: the published ``rope_parameters`` of Laguna-S-2.1, the defaults here
+GATINGS = ("per-head", "none")
+ROUTER_INPUTS = ("post_attention", "pre_attention")
+ROUTER_SCORINGS = ("softmax_topk", "topk_softmax")
+ACTIVATIONS = ("silu", "relu")
+
+#: the keys on which the family's members differ: a recipe states every one
+#: (``config_from_dict``); the dataclass's own defaults are for toy tests
+MEMBER_KEYS = ("moe_routed_scaling_factor", "shared_expert_intermediate_size",
+               "mlp_only_layers", "sliding_window", "num_key_value_heads",
+               "gating", "router_input", "router_scoring", "hidden_act",
+               "rope_parameters")
+
+#: the dataclass's default ``rope_parameters`` (Laguna-S-2.1's published
+#: groups): what a toy test that names none builds on, never a recipe
 _ROPE = {
     FULL: {"rope_theta": 500000, "rope_type": "yarn", "factor": 128,
            "original_max_position_embeddings": 8192, "beta_slow": 1,
@@ -50,7 +69,10 @@ class SWAMoEConfig:
     num_key_value_heads: int = 8
     head_dim: int = 128
     sliding_window: int = 512            # keys a window query sees, itself in
-    rope_parameters: Any = None          # layer type -> the published group
+    # layer type -> the published group; a layer type mapped to None (or
+    # "none") carries no position signal at all: its queries and keys are
+    # not rotated
+    rope_parameters: Any = None
     rms_norm_eps: float = 1e-6
     num_experts: int = 256               # the router's width
     experts_held: int | None = None      # None: all of them
@@ -60,6 +82,15 @@ class SWAMoEConfig:
     shared_expert_intermediate_size: int = 1024
     norm_topk_prob: bool = True
     moe_routed_scaling_factor: float = 2.5
+    # a sigmoid gate a query head on the attention output, or none
+    gating: str = "per-head"
+    # the router reads the normed state after attention, or the layer's
+    # normed INPUT (computed before attention, applied after it)
+    router_input: str = "post_attention"
+    # a softmax over all experts then the k largest, or the k largest
+    # logits then a softmax over those k alone
+    router_scoring: str = "softmax_topk"
+    hidden_act: str = "silu"             # of every gated MLP
     max_position_embeddings: int = 1048576
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
@@ -86,10 +117,19 @@ class SWAMoEConfig:
         assert all(h % self.num_key_value_heads == 0
                    for h in self.num_attention_heads_per_layer), \
             "query heads are a multiple of the key-value heads"
+        for value, allowed, key in (
+                (self.gating, GATINGS, "gating"),
+                (self.router_input, ROUTER_INPUTS, "router_input"),
+                (self.router_scoring, ROUTER_SCORINGS, "router_scoring"),
+                (self.hidden_act, ACTIVATIONS, "hidden_act")):
+            assert value in allowed, \
+                f"{key}: {value!r} is not one of {allowed}"
         given = dict(self.rope_parameters or {})
+        groups = {FULL: given.get("full_attention", _ROPE[FULL]),
+                  WINDOW: given.get("sliding_attention", _ROPE[WINDOW])}
         self.rope_parameters = {
-            FULL: dict(given.get("full_attention") or _ROPE[FULL]),
-            WINDOW: dict(given.get("sliding_attention") or _ROPE[WINDOW])}
+            t: None if g in (None, "none") else dict(g)
+            for t, g in groups.items()}
 
     # ----------------------------------------------------- the layer pattern
     def kind_of(self, layer: int) -> str:
@@ -143,7 +183,21 @@ _DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32,
 
 def config_from_dict(d: dict) -> SWAMoEConfig:
     """Build the config from a YAML ``Model:`` section (unknown keys, such
-    as ``name``, are dropped)."""
+    as ``name``, are dropped). The keys on which the family's members
+    differ (``MEMBER_KEYS``) take no default on this way: a recipe that
+    omits one is refused by name."""
+    missing = [k for k in MEMBER_KEYS if d.get(k) is None]
+    if missing:
+        raise ValueError(
+            "a recipe of Model.module SWAMoEModule states every key the "
+            f"family's members differ in; missing: {', '.join(missing)}")
+    rope = d["rope_parameters"]
+    absent = [t for t in (FULL, WINDOW) if t not in rope]
+    if absent:
+        raise ValueError(
+            "rope_parameters names both layer types (a group, or 'none' for "
+            "a layer type that does not rotate); missing: "
+            + ", ".join(absent))
     known = {f.name for f in dataclasses.fields(SWAMoEConfig)}
     kwargs = {k: v for k, v in d.items() if k in known and v is not None}
     for key in ("dtype", "param_dtype"):

@@ -1,26 +1,38 @@
-"""The windowed-attention sparse-expert decoder: parameters and the parts
-of a layer that need no cache.
+"""The windowed-attention sparse-expert decoder family: parameters and
+the parts of a layer that need no cache.
 
-Pre-norm layers, RMS norm, no biases, untied head. Layer ℓ:
-``h = x + Attn(norm(x))``, ``y = h + FF(norm(h))``.
+Pre-norm layers, RMS norm, no biases, untied head. Layer ℓ, input ``x``:
+``u = norm(x)``, ``h = x + Attn(u)``, ``v = norm(h)``, ``y = h + FF(v)``.
+What the published members differ in is data of the config
+(``SWAMoEConfig``; ``docs/swa_moe.md`` has each member's equations):
 
-- *Attention*: ``H`` query heads (a count a layer) over 8 key-value heads
-  of 128; rotary on queries and keys (full layers: YaRN on the first half
-  of a head's dimensions, cos and sin times ``attention_factor``; window
-  layers: plain rotary on all of them); scores ``q·k / sqrt(head_dim)``,
-  causal, and in a window layer query *i* sees keys ``i − window + 1 … i``;
-  a per-head gate ``g = sigmoid(u W_g)`` multiplies each head's output
-  before the output projection.
-- *Feed-forward*: a gated MLP (``down(silu(gate u) * up u)``) in the
-  layers ``mlp_only_layers`` names; elsewhere router logits in float32, a
-  softmax over all ``num_experts``, the ``num_experts_per_tok`` largest
-  chosen, their scores over their sum, times the routed scaling, applied
-  to the outputs of the experts HELD here (``models/mla_moe/moe.py``'s
-  sorted rows and ``ops/grouped_matmul.py:moe_gmm``), plus one shared
-  expert added unweighted. What the absent experts would add is left out.
+- *Attention*: ``H`` query heads (a count a layer) over
+  ``num_key_value_heads`` key-value heads of ``head_dim``; rotary on
+  queries and keys by the layer type's group of ``rope_parameters`` — YaRN
+  on part of a head's dimensions (cos and sin times ``attention_factor``),
+  plain rotary, or NONE: a layer type without a group carries no position
+  signal and is not rotated. Scores ``q·k / sqrt(head_dim)``, causal, and
+  in a window layer query *i* sees keys ``i − window + 1 … i``. With
+  ``gating: per-head`` a gate ``g = sigmoid(u W_g)`` multiplies each head's
+  output before the output projection; with ``none`` there is no such leaf.
+- *Feed-forward*: a gated MLP (``down(act(gate v) * up v)``, ``hidden_act``
+  SiLU or ReLU) in the layers ``mlp_only_layers`` names (there may be
+  none); elsewhere router logits in float32 — of ``v``, or with
+  ``router_input: pre_attention`` of the layer's normed input ``u``,
+  computed before attention and applied after it — scored
+  (``router_scoring``) by a softmax over all ``num_experts`` of which the
+  ``num_experts_per_tok`` largest are chosen, or by choosing the largest
+  logits and taking the softmax over the chosen alone; with
+  ``norm_topk_prob`` the chosen scores over their sum; times the routed
+  scaling; applied to the outputs of the experts HELD here (all of them,
+  or a share: ``models/mla_moe/moe.py``'s sorted rows and
+  ``ops/grouped_matmul.py:moe_gmm``), plus, where
+  ``shared_expert_intermediate_size`` is not 0, one shared expert added
+  unweighted. What absent experts would add is left out.
 
 Layers of one shape are stacked (``SWAMoEConfig.kind_of``): the tree is
-``{"embed", "head", "final_norm", "<kind>": {...leaves [layers, ...]}}``.
+``{"embed", "head", "final_norm", "<kind>": {...leaves [layers, ...]}}``
+with no leaf for what a member lacks (gate, shared expert, dense stack).
 What walks the layers with their caches is ``serving/swa_moe.py``.
 """
 
@@ -62,10 +74,11 @@ def param_shapes(cfg: SWAMoEConfig) -> dict:
             # on every call of one program or the other: 340 MB, ~4.6 ms
             # by the compiler's estimate, for six layers' queries)
             "attn": {"q": (n, heads, hd, h), "k": (n, kv, hd, h),
-                     "v": (n, h, kv * hd), "gate": (n, h, heads),
-                     "out": (n, heads, hd, h)},
+                     "v": (n, h, kv * hd), "out": (n, heads, hd, h)},
             "mlp_norm": {"scale": (n, h)},
         }
+        if cfg.gating == "per-head":
+            layer["attn"]["gate"] = (n, h, heads)
         if kind.endswith("dense"):
             i = cfg.intermediate_size
             layer["mlp"] = {"gate": (n, h, i), "up": (n, h, i),
@@ -74,9 +87,11 @@ def param_shapes(cfg: SWAMoEConfig) -> dict:
             layer["moe"] = {
                 "router": (n, h, cfg.num_experts),
                 "experts_gate": (n, held, h, f), "experts_up": (n, held, h, f),
-                "experts_down": (n, held, f, h),
-                "shared_gate": (n, h, fs), "shared_up": (n, h, fs),
-                "shared_down": (n, fs, h)}
+                "experts_down": (n, held, f, h)}
+            if fs:
+                layer["moe"].update(shared_gate=(n, h, fs),
+                                    shared_up=(n, h, fs),
+                                    shared_down=(n, fs, h))
         tree[kind] = layer
     return tree
 
@@ -141,7 +156,8 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float, dtype) -> jax.Array:
 
 def rotary_frequencies(cfg: SWAMoEConfig, layer_type: str) -> tuple:
     """``(inverse frequencies [rot / 2] float64 on the host, factor on cos
-    and sin)`` of a layer type: plain rotary, or YaRN — the low frequencies
+    and sin)`` of a layer type that rotates: plain rotary, or YaRN — the
+    low frequencies
     (whose wavelength passes the original context) divided by ``factor``,
     the high ones kept, a linear ramp between ``beta_fast`` and
     ``beta_slow`` turns within the original context."""
@@ -173,8 +189,11 @@ def rotary_frequencies(cfg: SWAMoEConfig, layer_type: str) -> tuple:
 
 
 def rotary_tables(cfg: SWAMoEConfig, layer_type: str,
-                  positions: jax.Array) -> tuple:
-    """``(cos, sin)`` float32 ``[..., rot / 2]`` at ``positions``."""
+                  positions: jax.Array):
+    """``(cos, sin)`` float32 ``[..., rot / 2]`` at ``positions``; None for
+    a layer type that carries no position signal."""
+    if cfg.rope_parameters[layer_type] is None:
+        return None
     inv, scale = rotary_frequencies(cfg, layer_type)
     angle = positions.astype(jnp.float32)[..., None] \
         * jnp.asarray(inv, jnp.float32)
@@ -194,14 +213,21 @@ def apply_rotary(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 
 
 def route(u2d: jax.Array, router: jax.Array, cfg: SWAMoEConfig) -> tuple:
-    """``u2d`` [N, h] -> (expert ids [N, k], weights [N, k] float32): a
-    softmax over all ``num_experts`` in float32, the k largest chosen,
-    their scores over their sum, times the routed scaling."""
+    """``u2d`` [N, h] -> (expert ids [N, k], weights [N, k] float32), the
+    logits in float32. ``softmax_topk``: a softmax over all ``num_experts``,
+    the k largest chosen; ``topk_softmax``: the k largest LOGITS chosen, a
+    softmax over those k alone. Then their scores over their sum
+    (``norm_topk_prob``; it changes nothing after a softmax over the
+    chosen), times the routed scaling."""
     logits = jnp.einsum("nh,he->ne", u2d.astype(jnp.float32),
                         router.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
-    scores = jax.nn.softmax(logits, axis=-1)
-    picked, ids = jax.lax.top_k(scores, cfg.num_experts_per_tok)
+    if cfg.router_scoring == "topk_softmax":
+        top, ids = jax.lax.top_k(logits, cfg.num_experts_per_tok)
+        picked = jax.nn.softmax(top, axis=-1)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+        picked, ids = jax.lax.top_k(scores, cfg.num_experts_per_tok)
     if cfg.norm_topk_prob:
         picked = picked / picked.sum(-1, keepdims=True)
     return ids, picked * cfg.moe_routed_scaling_factor
@@ -217,24 +243,30 @@ def tile_rows(cfg: SWAMoEConfig) -> int:
 
 def pass_rows(cfg: SWAMoEConfig, tokens: int) -> int:
     """Rows one pass of the grouped products takes when ``tokens`` tokens
-    are routed: what a uniform router sends each held expert, in whole
-    tiles, for every held expert — 32 x 16 rows for a decode step of 64
-    rows (2.5 rows an expert), 32 x 32 for a 512-token chunk (20). Rows
-    past the last held one cost their gather and scatter-add and nothing
-    else, so the pass is no larger than that; a pass is a turn of a loop
-    that ends after the last held row, so a router that sends every token
-    here takes more turns and drops none. (Tile, pass and the prefill's
-    key block were swept on the chip: PERF.md section 6, PR 35.)"""
+    are routed: what a uniform router sends each held expert plus the half
+    tile its run is expected to be padded by, in whole tiles, for every
+    held expert — with 32 of 256 experts held and 10 a token, 32 x 16 rows
+    for a decode step of 64 rows (2.5 rows an expert) and 32 x 32 for a
+    512-token chunk (20); with all 64 held and 6 a token, 64 x 16 for 48
+    rows (4.5) and 64 x 64 for a chunk (48 rows an expert are three whole
+    tiles: without the half tile every chunk's padding spills into a
+    second pass in every layer, 3.2 ms of a 25 ms chunk on the chip:
+    PERF.md section 6, PR 38). Rows past the last held one cost their
+    gather and scatter-add and nothing else, so the pass is no larger than
+    that; a pass is a turn of a loop that ends after the last held row, so
+    a router that sends every token here takes more turns and drops none.
+    (Tile, pass and the prefill's key block were swept on the chip:
+    PERF.md section 6, PR 35.)"""
     tile = tile_rows(cfg)
     per_expert = -(-tokens * cfg.num_experts_per_tok // cfg.num_experts)
-    return cfg.experts_held * -(-per_expert // tile) * tile
+    return cfg.experts_held * -(-(per_expert + tile // 2) // tile) * tile
 
 
 def held_experts(u2d: jax.Array, ids: jax.Array, weights: jax.Array,
                  moe: dict, layer: jax.Array, cfg: SWAMoEConfig,
                  pass_rows: int, kernel_name: str) -> tuple:
-    """The held experts' weighted sum for every token, [N, h] float32, and
-    the rows each held expert got [held].
+    """The held experts' weighted sum for every token, [N, h] float32, the
+    rows each held expert got [held], and the passes the loop took.
 
     ``moe`` holds the STACKED experts of the layer's kind (``[layers, held,
     ...]``) and ``layer`` says which: a tile's matrix is read at index
@@ -253,12 +285,14 @@ def held_experts(u2d: jax.Array, ids: jax.Array, weights: jax.Array,
         lhs, stacks[name], experts, n_tiles, tile=tile,
         out_dtype=jnp.float32, name=kernel_name)
 
+    act = activation(cfg)
+
     def body(state):
         c, y = state
         tok, wt, _, experts, n_tiles, xs = held_share.pass_inputs(
             c, u2d, w_flat, plan, k, pass_rows, tile)
         experts = experts + layer * held
-        a = (jax.nn.silu(gmm(xs, "experts_gate", experts, n_tiles))
+        a = (act(gmm(xs, "experts_gate", experts, n_tiles))
              * gmm(xs, "experts_up", experts, n_tiles)).astype(u2d.dtype)
         o = gmm(a, "experts_down", experts, n_tiles)
         return c + 1, y.at[tok].add(o * wt[:, None])
@@ -266,16 +300,21 @@ def held_experts(u2d: jax.Array, ids: jax.Array, weights: jax.Array,
     _, y = jax.lax.while_loop(
         lambda s: s[0] < plan["n_passes"], body,
         (jnp.int32(0), jnp.zeros(u2d.shape, jnp.float32)))
-    return y, plan["rows_held"]
+    return y, plan["rows_held"], plan["n_passes"]
+
+
+def activation(cfg: SWAMoEConfig):
+    """``hidden_act`` of every gated MLP: SiLU, or ReLU (ReGLU experts)."""
+    return {"silu": jax.nn.silu, "relu": jax.nn.relu}[cfg.hidden_act]
 
 
 def gated_mlp(u: jax.Array, gate: jax.Array, up: jax.Array,
-              down: jax.Array) -> jax.Array:
-    """``down(silu(gate u) * up u)``, float32 accumulation, ``u``'s dtype
+              down: jax.Array, act=jax.nn.silu) -> jax.Array:
+    """``down(act(gate u) * up u)``, float32 accumulation, ``u``'s dtype
     between the products."""
     g = jnp.einsum("...h,hf->...f", u, gate,
                    preferred_element_type=jnp.float32)
     v = jnp.einsum("...h,hf->...f", u, up,
                    preferred_element_type=jnp.float32)
-    return jnp.einsum("...f,fh->...h", (jax.nn.silu(g) * v).astype(u.dtype),
+    return jnp.einsum("...f,fh->...h", (act(g) * v).astype(u.dtype),
                       down, preferred_element_type=jnp.float32)

@@ -95,6 +95,12 @@ SERVING_RECORD_SCHEMA: dict[str, tuple[tuple, bool]] = {
     "decode_steps": ((int,), False),
     "decode_overlapped": ((int,), False),
     "overrun_rows": ((int,), False),
+    # a family with sparse experts only (serving/registry.py): over the
+    # decode steps, the mean of (rows of the fullest held expert / the
+    # mean, worst layer of a step), null before the first step; and the
+    # turns the held experts' loops took, all layers
+    "serving_moe_load_max_over_mean": (_NULLABLE_NUM, False),
+    "serving_moe_passes_total": ((int,), False),
     "queue_depth": (_NULLABLE_INT, True),
     "active_requests": (_NULLABLE_INT, True),
     "page_occupancy": (_NULLABLE_NUM, True),
@@ -198,6 +204,9 @@ SERVING_METRIC_NAMES = (
     # on held experts and all pairs, of the rows that decoded
     "serving_moe_experts_hit", "serving_moe_pairs_held_total",
     "serving_moe_pairs_total",
+    # rows of the fullest held expert over the mean, worst layer of a
+    # decode step; turns of the held experts' loops, all layers
+    "serving_moe_load_max_over_mean", "serving_moe_passes_total",
     "serving_requests_total", "serving_requests_completed",
     "serving_requests_refused", "serving_tokens_total",
     # decode steps dispatched, those dispatched while the step before was

@@ -194,14 +194,16 @@ def page_walk_shape(*, num_heads: int, head_dim: int, page_size: int,
     return g * page_size, -(-pages_per_req // g)
 
 
-def paged_attention_supported(*, num_heads: int, head_dim: int,
-                              page_size: int, pages_per_req: int,
-                              dtype: Any = jnp.float32,
-                              num_kv_heads: Optional[int] = None) -> bool:
-    """True when the in-kernel page walk applies to this engine geometry.
+def paged_attention_refusal(*, num_heads: int, head_dim: int,
+                            page_size: int, pages_per_req: int,
+                            dtype: Any = jnp.float32,
+                            num_kv_heads: Optional[int] = None) -> str:
+    """The bound that keeps the in-kernel page walk from this engine
+    geometry, in words, or "" when the kernel applies.
 
-    Consulted ONCE per engine (``serving/decode.py:make_step_fns``) —
-    shapes it rejects take the dense gather path, never silence.
+    Consulted ONCE per engine (``serving/decode.py:make_step_fns``, the
+    families of ``serving/registry.py``) — shapes it rejects take the
+    dense gather path, never silence: the caller logs the reason.
     ``num_heads`` is what ONE device holds (the kernel runs per shard).
     Bounds are alignment (sublane-friendly ``head_dim``, a head block the
     flat ``heads·head_dim`` minor dim and the dtype's tile can address)
@@ -209,29 +211,45 @@ def paged_attention_supported(*, num_heads: int, head_dim: int,
     16, bf16 — compiles and decodes right on the v5e (PERF.md).
     ``num_kv_heads`` (fewer key-value heads than query heads): the query
     rows of a head block are then ``group`` to a key-value head, so they
-    have to fill whole sublane tiles or be all the heads, and a head's
+    have to fill whole sublane tiles or be all the heads (7 query heads to
+    each of 4 key-value heads: one block of all 28 rows), and a head's
     lanes have to be whole lane tiles (``head_dim`` a multiple of 128).
     """
     if num_heads < 1 or pages_per_req < 1 or page_size < 1:
-        return False
+        return "no heads, pages or page rows"
     kv = num_kv_heads or num_heads
     if kv < 1 or num_heads % kv:
-        return False
+        return f"{num_heads} query heads are no multiple of {kv} " \
+               f"key-value heads"
     if kv != num_heads:
         hb = pick_head_block(kv, head_dim, dtype)
         rows = hb * (num_heads // kv)
         sublanes = 8 * 4 // jnp.dtype(dtype).itemsize
-        if head_dim % 128 or hb == 0 or \
-                (hb != kv and rows % sublanes):
-            return False
+        if head_dim % 128:
+            return f"head_dim {head_dim} is not whole 128-lane tiles " \
+                   f"(grouped queries)"
+        if hb == 0 or (hb != kv and rows % sublanes):
+            return f"no block of the {kv} key-value heads gives query " \
+                   f"rows in whole {sublanes}-row sublane tiles"
     if head_dim < 8 or head_dim % 8 or head_dim > 256:
-        return False
+        return f"head_dim {head_dim} is not a multiple of 8 in 8..256"
     if jnp.dtype(dtype) not in (jnp.dtype(jnp.float32),
                                 jnp.dtype(jnp.bfloat16)):
-        return False
-    return pick_pages_per_step(
-        num_heads=num_heads, head_dim=head_dim, page_size=page_size,
-        pages_per_req=pages_per_req, dtype=dtype, num_kv_heads=kv) > 0
+        return f"dtype {jnp.dtype(dtype).name} is neither float32 nor " \
+               f"bfloat16"
+    if pick_pages_per_step(
+            num_heads=num_heads, head_dim=head_dim, page_size=page_size,
+            pages_per_req=pages_per_req, dtype=dtype, num_kv_heads=kv) <= 0:
+        return f"no page fits the {_PAGED_VMEM_BUDGET_BYTES >> 20} MiB " \
+               f"VMEM budget of a fold (or no head block addresses " \
+               f"{kv} x {head_dim} lanes)"
+    return ""
+
+
+def paged_attention_supported(**geometry) -> bool:
+    """True when the in-kernel page walk applies to this engine geometry
+    (`paged_attention_refusal` names the bound when it does not)."""
+    return not paged_attention_refusal(**geometry)
 
 
 def paged_sharded_supported(mesh: Any, *, num_heads: int,

@@ -1187,12 +1187,13 @@ class ServingEngine:
                      "serving_overrun_rows", "serving_refusals_overloaded",
                      "serving_refusals_unmeetable",
                      "serving_moe_pairs_held_total",
-                     "serving_moe_pairs_total"):
+                     "serving_moe_pairs_total", "serving_moe_passes_total"):
             self.metrics.counter(name).reset()
         for name in ("serving_ttft", "serving_inter_token", "serving_tick",
                      "serving_chunk_tick", "serving_queue_wait",
                      "serving_prefill_wait", "serving_prefill_run",
-                     "serving_moe_experts_hit"):
+                     "serving_moe_experts_hit",
+                     "serving_moe_load_max_over_mean"):
             h = self.metrics.histogram(name)
             h.reset()
             h.total_count = 0
@@ -1300,6 +1301,13 @@ class ServingEngine:
             "chips": int(self.n_chips),
             "requests_per_chip": completed / max(self.n_chips, 1),
         }
+        if self._programs.record_stats is not None:
+            # a family with sparse experts: what rode the decode program's
+            # outputs to the host with the tokens
+            snap["serving_moe_load_max_over_mean"] = m.histogram(
+                "serving_moe_load_max_over_mean").summary().get("mean")
+            snap["serving_moe_passes_total"] = int(
+                m.counter("serving_moe_passes_total").value)
         if self.slo is not None:
             snap["slo_attainment"] = self.slo.observe(snap)["attainment"]
         return snap

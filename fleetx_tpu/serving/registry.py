@@ -159,8 +159,9 @@ class GPTFamily(Family):
 
 
 class SWAMoEFamily(Family):
-    """Windowed and full grouped-query attention over sparse experts held
-    as a share (``models/swa_moe``, ``serving/swa_moe.py``)."""
+    """Windowed and full grouped-query attention over sparse experts, all
+    of them held or a share (``models/swa_moe``, ``serving/swa_moe.py``;
+    ``docs/swa_moe.md`` has the family's members)."""
 
     modules = ("SWAMoEModule",)
 
@@ -220,8 +221,17 @@ class SWAMoEFamily(Family):
                         max_batch=sc.max_batch,
                         prefill_chunk=sc.prefill_chunk)
         cache = list(S.init_cache(cfg, **geometry))
-        active = bool(sc.paged_kernel) and S.paged_kernel_enabled(
-            cfg, page_size=sc.page_size, pages_per_req=pages_per_req)
+        refused = S.gather_fallbacks(cfg, page_size=sc.page_size,
+                                     pages_per_req=pages_per_req)
+        active = bool(sc.paged_kernel) and not refused
+        if sc.paged_kernel and refused:
+            from fleetx_tpu.utils.log import logger
+
+            # said once, when the engine is built: decode attention reads
+            # a gathered view of BOTH caches, not the kernel's page walk
+            logger.warning(
+                "decode attention falls back to the gathered view: %s",
+                "; ".join(f"{kind} layers: {why}" for kind, why in refused))
         fns = S.make_step_fns(
             cfg, prefill_chunk=sc.prefill_chunk, page_size=sc.page_size,
             sampling=sampling, paged_kernel=active)
@@ -246,6 +256,10 @@ class SWAMoEFamily(Family):
                 int(stats["pairs_held"]))
             metrics.counter("serving_moe_pairs_total").inc(
                 int(stats["rows"]) * cfg.num_experts_per_tok * expert_layers)
+            metrics.histogram("serving_moe_load_max_over_mean").record(
+                float(stats["load_max_over_mean"]))
+            metrics.counter("serving_moe_passes_total").inc(
+                int(stats["passes"]))
 
         def kv_tokens(lens):
             live = lens[lens >= 0]

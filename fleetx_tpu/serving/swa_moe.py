@@ -32,15 +32,28 @@ scan indexes its operand. The experts' stacks are never indexed by layer at
 all: ``moe_gmm`` reads tile *t*'s matrix at ``layer · held + expert(t)`` of
 the stack seen flat.
 
-**Attention.** Decode: ``ops/paged_attention.py`` with 8 key-value heads
-under 48 or 72 query heads — the full layers over the request's pages, the
-window layers over the slot's ring through a static table (logical page
-*j* → ring page ``j mod ring_pages``) with ``window=`` set, so a row's walk
-starts at the page that holds ``len − window + 1``. Where the kernel does
-not admit the geometry (toy widths), the gathered view. Prefill: the gather
-path — a full layer folds the request's pages a block of keys at a time up
-to the chunk's end (a loop as long as the context, online softmax), a
-window layer reads its slot's ring whole.
+**Attention.** Decode: ``ops/paged_attention.py`` with fewer key-value
+heads than query heads (a whole number of query heads to each: 6 or 9 over
+8 in one member, 7 over 4 in the other) — the full layers over the
+request's pages, the window layers over the slot's ring through a static
+table (logical page *j* → ring page ``j mod ring_pages``) with ``window=``
+set, so a row's walk starts at the page that holds ``len − window + 1``.
+Where the kernel does not admit the geometry (toy widths), the gathered
+view, and the engine's build says so once (``gather_fallbacks``). Prefill:
+the gather path — a full layer folds the request's pages a block of keys at
+a time up to the chunk's end (a loop as long as the context, online
+softmax); a window layer reads its slot's ring whole while the ring is at
+most ``_WHOLE_RING_BLOCKS`` key blocks (a 512-token window under a
+512-token chunk: one score of ``[heads, chunk, 1,024]``), and folds it a
+key block at a time, from the block that holds the first query's oldest
+key, when it is longer (4,096 + 512 tokens: the one score would be 264 MB
+of float32 a layer).
+
+**The router's input.** With ``router_input: pre_attention`` the experts'
+ids and weights are computed from the attention norm's output and carried
+over the attention call to the experts, which act on the normed state
+after attention; otherwise both read that state. A layer type whose
+``rope_parameters`` group is None is not rotated.
 
 **Parameters**: bfloat16, but the norms' scales and the router in
 float32; ``serving_params`` makes that tree once and the programs refuse
@@ -62,6 +75,14 @@ from fleetx_tpu.serving.decode import (SamplingParams, _sample,
                                        merge_fresh)
 
 _NEG = -1e30
+
+#: a window layer's prefill scores its slot's ring whole while the ring is
+#: no more than this many key blocks (a block: as many keys as the chunk
+#: has queries); a longer ring is folded a block at a time. The value keeps
+#: the first member's program what it was, and for no other reason: on the
+#: chip the fold is the faster at a 1,024-token ring too (PERF.md section 6,
+#: PR 38), so ROADMAP S16 deletes this constant and the whole-ring branch
+_WHOLE_RING_BLOCKS = 2
 
 
 # -------------------------------------------------------------------- caches
@@ -96,14 +117,30 @@ def init_cache(cfg: SWAMoEConfig, *, num_pages: int, page_size: int,
     return z(full), z(full), z(ring), z(ring)
 
 
+def gather_fallbacks(cfg: SWAMoEConfig, *, page_size: int,
+                     pages_per_req: int) -> list:
+    """The layer kinds whose decode attention ``ops/paged_attention.py``
+    does not admit at this geometry, each with the bound that refused it:
+    ``[(kind, reason), ...]``, empty when the kernel serves every layer.
+    One refused kind puts the whole decode program on the gathered view
+    (one attention path a program), so the engine logs these when it is
+    built."""
+    out = []
+    for kind in cfg.kinds():
+        why = PA.paged_attention_refusal(
+            num_heads=cfg.heads_of(kind), head_dim=cfg.head_dim,
+            page_size=page_size, pages_per_req=pages_per_req,
+            dtype=cfg.dtype, num_kv_heads=cfg.num_key_value_heads)
+        if why:
+            out.append((kind, why))
+    return out
+
+
 def paged_kernel_enabled(cfg: SWAMoEConfig, *, page_size: int,
                          pages_per_req: int) -> bool:
     """Whether ``ops/paged_attention.py`` admits every layer's geometry."""
-    return all(PA.paged_attention_supported(
-        num_heads=h, head_dim=cfg.head_dim, page_size=page_size,
-        pages_per_req=pages_per_req, dtype=cfg.dtype,
-        num_kv_heads=cfg.num_key_value_heads)
-        for h in set(cfg.num_attention_heads_per_layer))
+    return not gather_fallbacks(cfg, page_size=page_size,
+                                pages_per_req=pages_per_req)
 
 
 # ---------------------------------------------------------------- parameters
@@ -151,11 +188,18 @@ def _gathered_attention(q, k, v, key_pos, q_pos, window, dtype):
     return o.reshape(B, S, H, hd).astype(dtype)
 
 
-def _prefill_full_attention(q, pool_k, pool_v, layer, table, q_pos, n_keys,
-                            key_block: int, dtype):
-    """One chunk's queries ``q`` [1, C, H, hd] against the request's pages
-    of layer ``layer``, ``key_block`` keys at a time, up to key ``n_keys``
-    (a loop as long as the context; online softmax in float32)."""
+def _prefill_blocked_attention(q, pool_k, pool_v, layer, table, q_pos,
+                               n_keys, key_block: int, dtype, window=None):
+    """One chunk's queries ``q`` [1, C, H, hd] against the pages ``table``
+    [1, P] names in layer ``layer`` of a cache (a request's pages in the
+    full pool, or a slot's ring as a table: logical page *j* → ring page
+    ``j mod ring_pages``), ``key_block`` keys at a time, up to key
+    ``n_keys`` (online softmax in float32). Without a ``window`` the loop
+    is as long as the context; with one it starts at the block that holds
+    the first query's oldest key, ``q_pos[0, 0] − window + 1``, so it is as
+    long as window + chunk whatever the context, and the keys of that
+    block the ring has since overwritten lie before every query's window
+    and are masked."""
     _, C, H, hd = q.shape
     ps, width = pool_k.shape[2], pool_k.shape[3]
     kv = width // hd
@@ -172,8 +216,12 @@ def _prefill_full_attention(q, pool_k, pool_v, layer, table, q_pos, n_keys,
         v = pool_v[layer, pages].reshape(key_block, kv, hd)
         s = jnp.einsum("ckgd,tkd->kgct", qg, k,
                        preferred_element_type=jnp.float32) / math.sqrt(hd)
-        kp = j * key_block + jnp.arange(key_block, dtype=jnp.int32)
-        s = jnp.where(kp[None, None, None, :] <= qp, s, _NEG)
+        kp = (j * key_block + jnp.arange(key_block, dtype=jnp.int32)
+              )[None, None, None, :]
+        seen = kp <= qp
+        if window is not None:
+            seen = seen & (kp > qp - window)
+        s = jnp.where(seen, s, _NEG)
         m_new = jnp.maximum(m, s.max(-1))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new[..., None])
@@ -183,8 +231,10 @@ def _prefill_full_attention(q, pool_k, pool_v, layer, table, q_pos, n_keys,
         return m_new, l * alpha + p.sum(-1), acc
 
     shape = (kv, H // kv, C)
+    first = 0 if window is None else \
+        jnp.maximum(q_pos[0, 0] - (window - 1), 0) // key_block
     m, l, acc = jax.lax.fori_loop(
-        0, (n_keys + key_block - 1) // key_block, body,
+        first, (n_keys + key_block - 1) // key_block, body,
         (jnp.full(shape, _NEG, jnp.float32), jnp.zeros(shape, jnp.float32),
          jnp.zeros(shape + (hd,), jnp.float32)))
     o = acc / jnp.where(l == 0.0, 1.0, l)[..., None]
@@ -204,8 +254,9 @@ def _forward(params: Any, cfg: SWAMoEConfig, tokens, positions, cache,
     the page of ``last``, a gathered window layer reads (window + chunk).
     Returns
     ``(hidden [B, S, h], cache, stats)``; ``stats``: held experts hit,
-    summed over the expert layers, and (token, expert) pairs on held
-    experts."""
+    summed over the expert layers, (token, expert) pairs on held experts,
+    the rows of the fullest held expert over the mean (worst layer) and the
+    passes the held experts' loops took (all layers)."""
     unserved = _unserved(params, cfg)
     if unserved:
         raise TypeError(
@@ -245,13 +296,19 @@ def _forward(params: Any, cfg: SWAMoEConfig, tokens, positions, cache,
         ps, dtype=jnp.int32)[None, None, :]).reshape(B, view * ps)
     tables = {t: M.rotary_tables(cfg, t, q_pos) for t in (FULL, WINDOW)}
     valid_tok = valid.reshape(B * S)
+    act = M.activation(cfg)
+    router_first = cfg.router_input == "pre_attention"
+    # a window layer's prefill folds its ring a key block at a time once
+    # the ring is longer than a few blocks
+    fold_ring = not decode and view * ps > _WHOLE_RING_BLOCKS * key_block
 
     def attention(kind_type, u, lp, cache, at):
         q = jnp.einsum("bsh,ndh->bsnd", u, lp["q"])
         k = jnp.einsum("bsh,ndh->bsnd", u, lp["k"])
         v = jnp.einsum("bsh,hn->bsn", u, lp["v"])
-        cos, sin = tables[kind_type]
-        q, k = M.apply_rotary(q, cos, sin), M.apply_rotary(k, cos, sin)
+        if tables[kind_type] is not None:       # else: no position signal
+            cos, sin = tables[kind_type]
+            q, k = M.apply_rotary(q, cos, sin), M.apply_rotary(k, cos, sin)
         full_k, full_v, ring_k, ring_v = cache
         k_rows = k.reshape(B, S, kv * hd)
         if kind_type == FULL:
@@ -267,7 +324,7 @@ def _forward(params: Any, cfg: SWAMoEConfig, tokens, positions, cache,
                                       (B, P * ps))
                 o = _gathered_attention(q, kd, vd, kp, q_pos, None, dt)
             else:
-                o = _prefill_full_attention(
+                o = _prefill_blocked_attention(
                     q, full_k, full_v, at, block_tables, q_pos, last[0] + 1,
                     key_block, dt)
         else:
@@ -277,15 +334,20 @@ def _forward(params: Any, cfg: SWAMoEConfig, tokens, positions, cache,
                 o = PA.paged_attention(q[:, 0], ring_k, ring_v, ring_table,
                                        positions[:, 0], at,
                                        window=window)[:, None]
+            elif fold_ring:
+                o = _prefill_blocked_attention(
+                    q, ring_k, ring_v, at, ring_table, q_pos, last[0] + 1,
+                    key_block, dt, window=window)
             else:
                 kd = ring_k[at, view_pages].reshape(B, -1, kv, hd)
                 vd = ring_v[at, view_pages].reshape(B, -1, kv, hd)
                 o = _gathered_attention(q, kd, vd, view_pos, q_pos, window,
                                         dt)
-        gate = jax.nn.sigmoid(jnp.einsum(
-            "bsh,hn->bsn", u, lp["gate"],
-            preferred_element_type=jnp.float32))
-        o = (o.astype(jnp.float32) * gate[..., None]).astype(dt)
+        if cfg.gating == "per-head":
+            gate = jax.nn.sigmoid(jnp.einsum(
+                "bsh,hn->bsn", u, lp["gate"],
+                preferred_element_type=jnp.float32))
+            o = (o.astype(jnp.float32) * gate[..., None]).astype(dt)
         y = jnp.einsum("bsnd,ndh->bsh", o, lp["out"])
         return y, (full_k, full_v, ring_k, ring_v)
 
@@ -299,41 +361,54 @@ def _forward(params: Any, cfg: SWAMoEConfig, tokens, positions, cache,
                                 if not k.startswith("experts_")}
 
         def layer(i, carry):
-            x, cache, hit, pairs = carry
+            x, cache, hit, pairs, load, passes = carry
             lp = jax.tree.map(lambda w: w[i], per_layer)
             u = M.rms_norm(x, lp["attn_norm"]["scale"], cfg.rms_norm_eps, dt)
+            if router_first and not dense:
+                # chosen from the layer's normed input, applied after
+                # attention to the normed state the experts act on
+                routing = M.route(u.reshape(B * S, -1),
+                                  lp["moe"]["router"], cfg)
             y, cache = attention(kind_type, u, lp["attn"], cache,
                                  cache_lo + (i - lo))
             x = x + y
             u = M.rms_norm(x, lp["mlp_norm"]["scale"], cfg.rms_norm_eps, dt)
             if dense:
                 y = M.gated_mlp(u, lp["mlp"]["gate"], lp["mlp"]["up"],
-                                lp["mlp"]["down"]).astype(dt)
+                                lp["mlp"]["down"], act).astype(dt)
             else:
                 u2d = u.reshape(B * S, -1)
-                ids, weights = M.route(u2d, lp["moe"]["router"], cfg)
+                ids, weights = routing if router_first else \
+                    M.route(u2d, lp["moe"]["router"], cfg)
                 ids = jnp.where(valid_tok[:, None], ids, -1)
-                routed, rows = M.held_experts(
+                y, rows, turns = M.held_experts(
                     u2d, ids, weights, stack["moe"], i, cfg, moe_pass_rows,
                     moe_kernel)
-                shared = M.gated_mlp(u2d, lp["moe"]["shared_gate"],
-                                     lp["moe"]["shared_up"],
-                                     lp["moe"]["shared_down"])
-                y = (routed + shared).astype(dt).reshape(B, S, -1)
+                if cfg.shared_expert_intermediate_size:
+                    y = y + M.gated_mlp(u2d, lp["moe"]["shared_gate"],
+                                        lp["moe"]["shared_up"],
+                                        lp["moe"]["shared_down"], act)
+                y = y.astype(dt).reshape(B, S, -1)
                 hit = hit + (rows > 0).sum().astype(jnp.float32)
                 pairs = pairs + rows.sum().astype(jnp.int32)
-            return x + y, cache, hit, pairs
+                held = rows.astype(jnp.float32)
+                load = jnp.maximum(
+                    load, held.max() / jnp.maximum(held.mean(), 1e-9))
+                passes = passes + turns.astype(jnp.int32)
+            return x + y, cache, hit, pairs, load, passes
 
         if n == 1:      # a static index: the layer is a view of its stack
             return layer(lo, carry)
         return jax.lax.fori_loop(lo, lo + n, layer, carry)
 
-    carry = (x, tuple(cache), jnp.float32(0.0), jnp.int32(0))
+    carry = (x, tuple(cache), jnp.float32(0.0), jnp.int32(0),
+             jnp.float32(0.0), jnp.int32(0))
     for kind, lo, n, cache_lo in cfg.runs():
         carry = run(kind, lo, n, cache_lo, carry)
-    x, cache, hit, pairs = carry
+    x, cache, hit, pairs, load, passes = carry
     x = M.rms_norm(x, params["final_norm"]["scale"], cfg.rms_norm_eps, dt)
-    return x, cache, {"hit": hit, "pairs_held": pairs}
+    return x, cache, {"hit": hit, "pairs_held": pairs,
+                      "load_max_over_mean": load, "passes": passes}
 
 
 def _logits(params: Any, x_last: jax.Array) -> jax.Array:
@@ -354,8 +429,10 @@ def make_step_fns(cfg: SWAMoEConfig, *, prefill_chunk: int, page_size: int,
     row, tables, lengths, the base key and the draw count). After the
     caches come the sampled token(s), the float32
     logits and, from ``decode``, the step's expert counters (held experts
-    hit, summed over the expert layers; pairs on held experts): they ride
-    to the host with the tokens. Shapes are static (``max_batch`` /
+    hit, summed over the expert layers; pairs on held experts; the fullest
+    held expert's rows over the mean, worst layer; the passes the held
+    experts' loops took, all layers): they ride to the host with the
+    tokens. Shapes are static (``max_batch`` /
     ``pages_per_req`` / ``prefill_chunk`` arrive with the arrays), so each
     jit cache holds one entry for the engine's lifetime."""
     rp = ring_pages(cfg, page_size, prefill_chunk)

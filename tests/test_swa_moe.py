@@ -38,6 +38,10 @@ TOY = dict(
     sliding_window=WINDOW, num_experts=16, experts_held=4,
     first_expert_held=4, num_experts_per_tok=3, moe_intermediate_size=24,
     shared_expert_intermediate_size=24,
+    # what the family's members differ in, as the first member states it
+    moe_routed_scaling_factor=2.5, gating="per-head",
+    router_input="post_attention", router_scoring="softmax_topk",
+    hidden_act="silu", rope_parameters=SHIPPED["rope_parameters"],
     dtype="float32", param_dtype="float32", max_position_embeddings=4096)
 
 
@@ -64,13 +68,14 @@ def _seeded(cfg, seed=0):
         jax.tree_util.tree_map_with_path(spread, params), cfg)
 
 
-def _reference_weights(params, sizes) -> dict:
-    """The program's tree under the reference's names, through the shipped
-    configuration's ``param_paths``."""
+def _reference_weights(params, sizes, module=None, shipped=None) -> dict:
+    """The program's tree under a reference's names (``laguna_ref`` unless
+    told), through its shipped configuration's ``param_paths``."""
+    module, shipped = module or ref, shipped or SHIPPED
     out = {}
-    for name, (shape, _) in ref.weight_spec(sizes).items():
+    for name, (shape, _) in module.weight_spec(sizes).items():
         node = params
-        for key in SHIPPED["param_paths"][name].split("/"):
+        for key in shipped["param_paths"][name].split("/"):
             node = node[key]
         assert tuple(node.shape) == tuple(shape), (name, node.shape, shape)
         out[name] = jnp.asarray(node, jnp.float32)
@@ -210,8 +215,8 @@ def test_the_shares_add_up_to_the_uncut_layer(toy):
         moe = {"experts_gate": full["e_gate"][None, lo:lo + 4],
                "experts_up": full["e_up"][None, lo:lo + 4],
                "experts_down": full["e_down"][None, lo:lo + 4]}
-        routed, rows = M.held_experts(u, ids, weights, moe, jnp.int32(0),
-                                      held, 16, "moe_gmm")
+        routed, rows, _ = M.held_experts(u, ids, weights, moe, jnp.int32(0),
+                                         held, 16, "moe_gmm")
         assert int(rows.sum()) == int(((ids >= lo) & (ids < lo + 4)).sum())
         total += np.asarray(routed)
     np.testing.assert_allclose(total + shared, want, atol=2e-5, rtol=0)
@@ -239,7 +244,7 @@ def test_the_expert_layers_sizes_are_derived_and_a_skewed_router_drops_none(
     cfg = toy[0]
     assert M.tile_rows(cfg) == 8            # float32
     rng = np.random.default_rng(5)
-    n, h, f = 40, cfg.hidden_size, cfg.moe_intermediate_size
+    n, h, f = 100, cfg.hidden_size, cfg.moe_intermediate_size
     u = jnp.asarray(rng.normal(size=(n, h)), jnp.float32)
     moe = {name: jnp.asarray(0.3 * rng.normal(size=shape), jnp.float32)
            for name, shape in {"experts_gate": (1, 4, h, f),
@@ -249,10 +254,10 @@ def test_the_expert_layers_sizes_are_derived_and_a_skewed_router_drops_none(
     ids = jnp.asarray(np.tile([lo + 2, 0, 1], (n, 1)), jnp.int32)
     weights = jnp.asarray(rng.uniform(0.5, 1.0, size=(n, 3)), jnp.float32)
     rows = M.pass_rows(cfg, n)
-    assert rows < n                          # 40 rows on one expert: 2 passes
-    got, held = M.held_experts(u, ids, weights, moe, jnp.int32(0), cfg, rows,
-                               "moe_gmm")
-    assert held.tolist() == [0, 0, n, 0]
+    assert rows < n                         # 100 rows on one expert: 2 passes
+    got, held, turns = M.held_experts(u, ids, weights, moe, jnp.int32(0),
+                                      cfg, rows, "moe_gmm")
+    assert held.tolist() == [0, 0, n, 0] and int(turns) == 2
     want = weights[:, :1] * ref._gated_mlp(
         u, moe["experts_gate"][0, 2], moe["experts_up"][0, 2],
         moe["experts_down"][0, 2], "float32")
@@ -435,8 +440,7 @@ def test_the_kernel_path_serves_what_the_gather_serves():
     walks both caches in-kernel (interpret mode here) and serves the tokens
     and, within float32's grain, the logits of the gathered view."""
     toy = dict(TOY, head_dim=128, num_hidden_layers=5, sliding_window=16,
-               rope_parameters={"full_attention": dict(
-                   SHIPPED["rope_parameters"]["full_attention"])})
+               rope_parameters=SHIPPED["rope_parameters"])
     cfg = config_from_dict(toy)
     assert S.paged_kernel_enabled(cfg, page_size=8, pages_per_req=8)
     params = _seeded(cfg, 1)
@@ -573,3 +577,428 @@ def test_kv_pool_spec_refuses_a_tensor_axis_wider_than_the_kv_heads():
     with pytest.raises(ValueError, match="8 key-value heads.*tensor axis "
                                          "of 16"):
         kv_pool_spec(num_kv_heads=8, tensor_degree=16)
+
+
+# ---------------------------------------------- the family's second member
+# SmallThinker (ISSUE 38): the router reads the layer's normed INPUT, the k
+# largest logits are chosen and the softmax is over those alone, ReGLU
+# experts all held, no shared expert, no dense layer, no gate; window layers
+# rotate, full layers carry no position signal; 7 query heads to a
+# key-value head. Against ``benchmarks/reference/smallthinker_ref.py``,
+# which reads the PUBLISHED keys.
+ref2 = load_module(os.path.join(ROOT,
+                                "benchmarks/reference/smallthinker_ref.py"))
+with open(os.path.join(ROOT,
+                       "benchmarks/configs/smallthinker-21b-a3b.json")) as _f:
+    SHIPPED2 = json.load(_f)
+
+PUBLISHED2 = dict(
+    vocab_size=64, hidden_size=32, num_hidden_layers=8, head_dim=16,
+    num_attention_heads=14, num_key_value_heads=2,
+    sliding_window_layout=[0, 1, 1, 1] * 2, rope_layout=[0, 1, 1, 1] * 2,
+    sliding_window_size=WINDOW, rope_theta=1500000, rms_norm_eps=1e-6,
+    moe_num_primary_experts=16, moe_num_active_primary_experts=3,
+    moe_ffn_hidden_size=24, moe_primary_router_apply_softmax=True,
+    norm_topk_prob=True)
+
+
+def _toy2(published: dict = PUBLISHED2, **over) -> dict:
+    """The family's keys as the shipped recipe derives them from the
+    published ones (``SHIPPED2['derived']`` says how)."""
+    p = published
+    rotary = {"rope_type": "default", "rope_theta": p["rope_theta"],
+              "partial_rotary_factor": 1}
+    by_type = {}
+    for win, rot in zip(p["sliding_window_layout"], p["rope_layout"]):
+        by_type["sliding_attention" if win else "full_attention"] = \
+            rotary if rot else "none"
+    toy = dict(
+        vocab_size=p["vocab_size"], hidden_size=p["hidden_size"],
+        intermediate_size=0, num_hidden_layers=p["num_hidden_layers"],
+        num_attention_heads_per_layer=[p["num_attention_heads"]]
+        * p["num_hidden_layers"],
+        layer_types=["sliding_attention" if w else "full_attention"
+                     for w in p["sliding_window_layout"]],
+        mlp_only_layers=[], num_key_value_heads=p["num_key_value_heads"],
+        head_dim=p["head_dim"], sliding_window=p["sliding_window_size"],
+        rope_parameters=by_type, num_experts=p["moe_num_primary_experts"],
+        experts_held=p["moe_num_primary_experts"], first_expert_held=0,
+        num_experts_per_tok=p["moe_num_active_primary_experts"],
+        moe_intermediate_size=p["moe_ffn_hidden_size"],
+        shared_expert_intermediate_size=0, moe_routed_scaling_factor=1.0,
+        norm_topk_prob=True, gating="none", router_input="pre_attention",
+        router_scoring="topk_softmax", hidden_act="relu", dtype="float32",
+        param_dtype="float32", max_position_embeddings=4096)
+    toy.update(over)
+    return toy
+
+
+@pytest.fixture(scope="module")
+def second():
+    cfg = config_from_dict(_toy2())
+    params = _seeded(cfg, 2)
+    return cfg, params, dict(PUBLISHED2), \
+        _reference_weights(params, PUBLISHED2, ref2, SHIPPED2)
+
+
+def _reference_rows(module, w, sizes, toks):
+    row = np.zeros((1, -(-len(toks) // 16) * 16), np.int32)
+    row[0, :len(toks)] = toks
+    return np.asarray(module.logits(w, sizes, jnp.asarray(row)))[0]
+
+
+def test_the_second_members_tree_has_no_leaf_for_what_it_lacks(second):
+    cfg, params, _, _ = second
+    assert set(params) == {"embed", "head", "final_norm", "full_moe",
+                           "window_moe"}
+    assert cfg.kinds() == {"full_moe": 2, "window_moe": 6}
+    for kind in ("full_moe", "window_moe"):
+        assert set(params[kind]["attn"]) == {"q", "k", "v", "out"}
+        assert set(params[kind]["moe"]) == {
+            "router", "experts_gate", "experts_up", "experts_down"}
+    assert cfg.rope_parameters["full_attention"] is None
+    assert M.rotary_tables(cfg, "full_attention", jnp.arange(4)) is None
+    assert M.count_params(cfg) == sum(
+        math.prod(x.shape) for x in jax.tree.leaves(params))
+
+
+@pytest.mark.parametrize("prompt_len,new,chunk", [
+    (3, 3, 4),                  # all below the window
+    (6, 8, 4),                  # crosses the window mid-decode
+    (WINDOW + 5, 30, 4),        # wraps a ring of 12 tokens: no multiple of
+                                # the window; prefill folds the ring
+    (3 * CHUNK + 1, 20, CHUNK),  # a ring of two key blocks: scored whole
+])
+def test_second_member_prefill_then_decode_is_the_reference_on_logits(
+        second, prompt_len, new, chunk):
+    """Prefill in chunks, then decode, through the paged pool (full
+    layers, not rotated) and the ring (window layers, rotated): every
+    step's LOGITS are the reference's full forward at that position."""
+    cfg, params, sizes, w = second
+    ring = S.ring_pages(cfg, PAGE, chunk) * PAGE
+    assert (ring % WINDOW != 0) == (chunk == 4)
+    prompt = np.random.default_rng(prompt_len).integers(
+        0, cfg.vocab_size, size=prompt_len).tolist()
+    toks, logits, stats, fns = _serve(cfg, params, prompt, new, chunk=chunk)
+    want = _reference_rows(ref2, w, sizes, toks)
+    for i, got in enumerate(logits):
+        np.testing.assert_allclose(got, want[prompt_len - 1 + i], atol=2e-5,
+                                   rtol=0)
+    # one active row, every expert held: k experts a layer hit, one pass a
+    # layer, and the fullest expert's one row over a mean of k / 16
+    assert int(stats["rows"]) == 1
+    assert float(stats["hit"]) == int(stats["pairs_held"]) == 8 * 3
+    assert int(stats["passes"]) == 8
+    np.testing.assert_allclose(float(stats["load_max_over_mean"]), 16 / 3,
+                               rtol=1e-6)
+    assert fns["decode"]._cache_size() == fns["prefill"]._cache_size() == 1
+
+
+def _router_reads_v(x, lw, sizes_key, windowed, rotated, precision):
+    """``smallthinker_ref._layer`` with the router's input SWAPPED: it
+    reads the normed state after attention."""
+    sizes = ref2._SIZES[sizes_key]
+    eps = float(sizes["rms_norm_eps"])
+    u = ref2._rms_norm(x, lw["norm_in"], eps)
+    h = x + ref2._attention(u, lw, sizes, windowed, rotated, precision)
+    v = ref2._rms_norm(h, lw["norm_post"], eps)
+    ids, weights = ref2._route(v, lw["router"], sizes)
+    return h + ref2._experts(v, ids, weights, lw, precision)
+
+
+def _silu_glu(v, gate, up, down, precision):
+    a = jax.nn.silu(ref2._product("sh,hf->sf", v, gate, precision)) \
+        * ref2._product("sh,hf->sf", v, up, precision)
+    return ref2._product("sf,fh->sh", a, down, precision)
+
+
+@pytest.mark.parametrize("departure", [
+    "router_reads_the_state_after_attention", "silu_experts",
+    "full_layers_rotated", "window_layers_not_rotated"])
+def test_a_departure_from_the_second_members_equations_is_seen(
+        second, monkeypatch, departure):
+    """The comparison above is tight enough to tell the member's equations
+    from their neighbours: the program matches the reference as written and
+    NOT a reference whose router reads ``v`` instead of ``u``, whose experts
+    are SiLU-gated, whose full layers rotate or whose window layers do not
+    (each would pass unseen if program and reference shared the mistake:
+    the reference shares no code with the program)."""
+    cfg, params, sizes, w = second
+    prompt = np.random.default_rng(4).integers(0, 64, size=13).tolist()
+    toks, logits, _, _ = _serve(cfg, params, prompt, 6, chunk=4)
+    got = np.stack(logits)
+    at = np.arange(len(prompt) - 1, len(toks) - 1)
+    right = _reference_rows(ref2, w, sizes, toks)[at]
+    assert np.abs(got - right).max() < 2e-5
+    wrong_sizes = dict(sizes)
+    if departure == "router_reads_the_state_after_attention":
+        monkeypatch.setattr(ref2, "_layer", _router_reads_v)
+    elif departure == "silu_experts":
+        monkeypatch.setattr(ref2, "_reglu", _silu_glu)
+    elif departure == "full_layers_rotated":
+        wrong_sizes["rope_layout"] = [1] * 8
+    else:
+        wrong_sizes["rope_layout"] = [0] * 8
+    ref2._jitted_layer.cache_clear()
+    try:
+        wrong = _reference_rows(ref2, w, wrong_sizes, toks)[at]
+    finally:
+        monkeypatch.undo()
+        ref2._jitted_layer.cache_clear()
+    assert np.abs(got - wrong).max() > 1e-2, departure
+
+
+def test_topk_then_softmax_in_float32(second):
+    """``topk_softmax``: the k largest LOGITS, a softmax over those alone,
+    no scaling. (With ``norm_topk_prob`` the first member's scoring — a
+    softmax over all, the chosen over their sum — is the same function up
+    to rounding; the member states its own and a test holds it.)"""
+    cfg = second[0]
+    rng = np.random.default_rng(9)
+    u = jnp.asarray(rng.normal(size=(7, 32)), jnp.float32)
+    router = jnp.asarray(rng.normal(size=(32, 16)), jnp.float32)
+    ids, weights = M.route(u, router, cfg)
+    z = np.asarray(u, np.float64) @ np.asarray(router, np.float64)
+    want_ids = np.argsort(-z, axis=-1)[:, :3]
+    np.testing.assert_array_equal(np.asarray(ids), want_ids)
+    top = np.take_along_axis(z, want_ids, axis=-1)
+    want = np.exp(top - top.max(-1, keepdims=True))
+    want /= want.sum(-1, keepdims=True)
+    np.testing.assert_allclose(np.asarray(weights), want, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(weights).sum(-1), 1.0, atol=1e-6)
+    other = config_from_dict(_toy2(router_scoring="softmax_topk"))
+    ids2, weights2 = M.route(u, router, other)
+    np.testing.assert_array_equal(np.asarray(ids2), want_ids)
+    np.testing.assert_allclose(np.asarray(weights2), want, atol=1e-6)
+
+
+def test_four_shares_of_16_add_up_to_the_uncut_reference_layer():
+    """All 64 experts held as four shares of 16 (the cut this member's
+    cell does NOT make; the layer is the one a share of a wider deployment
+    would run): the shares' routed parts add up to the uncut reference's
+    expert layer, the choice made once from ``u`` for all of them."""
+    published = dict(PUBLISHED2, moe_num_primary_experts=64,
+                     moe_num_active_primary_experts=6)
+    rng = np.random.default_rng(13)
+    h, f = 32, 24
+    u = jnp.asarray(rng.normal(size=(11, h)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(11, h)), jnp.float32)
+    full = {name: jnp.asarray(0.3 * rng.normal(size=shape), jnp.float32)
+            for name, shape in {"router": (h, 64), "e_gate": (64, h, f),
+                                "e_up": (64, h, f),
+                                "e_down": (64, f, h)}.items()}
+    ids, weights = ref2._route(u, full["router"], published)
+    want = np.asarray(ref2._experts(v, ids, weights, full, "float32"))
+    total = np.zeros_like(want)
+    for share in range(4):
+        lo = 16 * share
+        held = config_from_dict(_toy2(published, experts_held=16,
+                                      first_expert_held=lo))
+        got_ids, got_w = M.route(u, full["router"], held)
+        np.testing.assert_array_equal(np.asarray(got_ids), np.asarray(ids))
+        moe = {"experts_gate": full["e_gate"][None, lo:lo + 16],
+               "experts_up": full["e_up"][None, lo:lo + 16],
+               "experts_down": full["e_down"][None, lo:lo + 16]}
+        routed, rows, _ = M.held_experts(
+            v, got_ids, got_w, moe, jnp.int32(0), held,
+            M.pass_rows(held, 11), "moe_gmm")
+        assert int(rows.sum()) == int(((ids >= lo) & (ids < lo + 16)).sum())
+        total += np.asarray(routed)
+    np.testing.assert_allclose(total, want, atol=2e-5, rtol=0)
+
+
+def test_the_second_members_built_tree_is_3967_m_parameters():
+    from fleetx_tpu.utils import config as config_mod
+
+    cfg = config_mod.get_config(
+        os.path.join(ROOT, SHIPPED2["serve"]["recipe"]),
+        list(SHIPPED2["serve"]["overrides"]), num_devices=1)
+    model_cfg, template = registry.served_template(cfg)
+    leaves = jax.tree_util.tree_flatten_with_path(template)[0]
+    assert sum(math.prod(l.shape) for _, l in leaves) == 3_966_937_600 \
+        == SHIPPED2["bytes"]["parameters"] == M.count_params(model_cfg)
+    assert sum(math.prod(l.shape) * l.dtype.itemsize for _, l in leaves) \
+        == SHIPPED2["bytes"]["served_bytes"]
+    # a layer: attention, router, 64 experts, two norms
+    assert 3_966_937_600 == 8 * (20_971_520 + 163_840 + 64 * 5_898_240
+                                 + 5_120) + 2 * 151_936 * 2_560 + 2_560
+    for path, leaf in leaves:
+        keys = {getattr(k, "key", None) for k in path}
+        f32 = bool(keys & (M.F32_GROUPS | M.F32_LEAVES))
+        assert leaf.dtype == (jnp.float32 if f32 else jnp.bfloat16), path
+    # the reference's spec, from the PUBLISHED keys, is the same tree
+    spec = ref2.weight_spec(SHIPPED2)
+    assert set(spec) == set(SHIPPED2["param_paths"])
+    assert sum(math.prod(s) for s, _ in spec.values()) == 3_966_937_600
+    by_name = {"/".join(str(k.key) for k in path): leaf
+               for path, leaf in leaves}
+    for name, (shape, _) in spec.items():
+        assert by_name[SHIPPED2["param_paths"][name]].shape == tuple(shape)
+    assert model_cfg.runs() == [
+        ("full_moe", 0, 1, 0), ("window_moe", 0, 3, 0),
+        ("full_moe", 1, 1, 1), ("window_moe", 3, 3, 3)]
+    sc = cfg["Serving"]
+    assert S.ring_pages(model_cfg, sc["page_size"],
+                        sc["prefill_chunk"]) == 288
+    full, ring = S.cache_shapes(
+        model_cfg, num_pages=sc["num_pages"], page_size=sc["page_size"],
+        max_batch=sc["max_batch"], prefill_chunk=sc["prefill_chunk"])
+    assert full == (2, 28001, 16, 512) and ring == (6, 13825, 16, 512)
+    # one chip holds the whole expert set: nothing masked, 288 pairs a
+    # decode step over 64 experts in one pass of 64 tiles
+    assert (model_cfg.experts_held, model_cfg.first_expert_held) == (64, 0)
+    assert (M.tile_rows(model_cfg), M.pass_rows(model_cfg, 48),
+            M.pass_rows(model_cfg, 512)) == (16, 1024, 4096)
+    # group 7: the kernel takes all 4 key-value heads, 28 query rows, in
+    # one block, and walks a row's 4,096-token window in 33 folds at most
+    from fleetx_tpu.ops import paged_attention as PA
+
+    per_req = -(-sc["max_seq_len"] // sc["page_size"])
+    assert S.gather_fallbacks(model_cfg, page_size=16,
+                              pages_per_req=per_req) == []
+    assert PA.pick_head_block(4, 128, jnp.bfloat16) == 4
+    span, _ = PA.page_walk_shape(num_heads=28, head_dim=128, page_size=16,
+                                 pages_per_req=per_req, dtype=jnp.bfloat16,
+                                 num_kv_heads=4)
+    assert span == 128
+    lens = np.arange(4096, 13056, 37, dtype=np.int32)
+    assert PA.page_groups_walked(lens, span, -(-per_req // 8),
+                                 4096).max() == 33
+
+
+@pytest.mark.parametrize("routing", ["uniform", "skewed", "one_tile_each"])
+def test_a_chunks_sorted_rows_fit_one_pass_at_the_second_members_sizes(
+        routing):
+    """What ``pass_rows``' half tile buys, by count: the recipe's chunk
+    sends 512 x 6 pairs to 64 experts in 16-row tiles, whose padded runs
+    are at most 3,072 + 64 x 15 = 4,032 rows WHATEVER the routing, so the
+    pass of 64 x 64 rows takes one turn of ``held_experts``' loop; the
+    uniform share alone (64 x 48, no room for the padding) takes a second
+    turn unless every expert's count is a whole number of tiles."""
+    from fleetx_tpu.models.mla_moe import moe as held_share
+    from fleetx_tpu.utils import config as config_mod
+
+    cfg = registry.model_config(config_mod.get_config(
+        os.path.join(ROOT, SHIPPED2["serve"]["recipe"]),
+        list(SHIPPED2["serve"]["overrides"]), num_devices=1))
+    n, k, held, tile = 512, cfg.num_experts_per_tok, cfg.experts_held, 16
+    rng = np.random.default_rng(38)
+    if routing == "uniform":
+        ids = np.stack([rng.permutation(held)[:k] for _ in range(n)])
+    elif routing == "skewed":       # a few experts draw most of the rows
+        p = np.exp(-np.arange(held) / 6.0)
+        ids = np.stack([rng.choice(held, k, replace=False, p=p / p.sum())
+                        for _ in range(n)])
+    else:                           # 48 rows each: three whole tiles
+        ids = (np.arange(n * k) % held).reshape(n, k)
+    shipped = M.pass_rows(cfg, n)
+    bare = held * -(-(n * k // held) // tile) * tile
+    assert (shipped, bare) == (4096, 3072)
+    assert n * k + held * (tile - 1) <= shipped
+    turns = {rows: int(held_share.plan_rows(
+        jnp.asarray(ids, jnp.int32), 0, held, tile, rows)["n_passes"])
+        for rows in (shipped, bare)}
+    assert turns[shipped] == 1
+    assert turns[bare] == (1 if routing == "one_tile_each" else 2)
+
+
+@pytest.mark.parametrize("missing", [
+    "moe_routed_scaling_factor", "shared_expert_intermediate_size",
+    "mlp_only_layers", "sliding_window", "num_key_value_heads", "gating",
+    "router_input", "router_scoring", "hidden_act", "rope_parameters"])
+def test_a_recipe_that_omits_a_member_key_is_refused_by_name(missing):
+    """On the way from a recipe no key on which the members differ takes a
+    default: one model's number is never lent to another."""
+    from fleetx_tpu.models.swa_moe.config import MEMBER_KEYS
+
+    assert missing in MEMBER_KEYS and len(MEMBER_KEYS) == 10
+    for toy in (TOY, _toy2()):
+        assert config_from_dict(toy)
+        short = {k: v for k, v in toy.items() if k != missing}
+        with pytest.raises(ValueError, match=missing):
+            config_from_dict(short)
+    with pytest.raises(ValueError, match="sliding_attention"):
+        config_from_dict(dict(_toy2(), rope_parameters={
+            "full_attention": "none"}))
+
+
+def test_the_shipped_recipes_state_every_member_key():
+    import yaml
+
+    from fleetx_tpu.models.swa_moe.config import MEMBER_KEYS
+
+    for recipe in (SHIPPED["serve"]["recipe"], SHIPPED2["serve"]["recipe"]):
+        with open(os.path.join(ROOT, recipe)) as f:
+            model = yaml.safe_load(f)["Model"]
+        assert not [k for k in MEMBER_KEYS if model.get(k) is None], recipe
+
+
+def test_a_gather_fallback_is_said_once_when_the_engine_is_built(second):
+    """At toy widths ``ops/paged_attention.py`` does not admit the
+    geometry: the engine's build names each layer kind and the bound that
+    refused it, once; at a geometry the kernel admits it says nothing."""
+    import logging
+
+    from fleetx_tpu.utils.log import logger as program_logger
+
+    cfg, params, _, _ = second
+    said = []
+    handler = logging.Handler()
+    handler.emit = lambda record: said.append(record.getMessage())
+    program_logger.addHandler(handler)
+    try:
+        eng = _engine(cfg, params)
+        wide = config_from_dict(_toy2(dict(PUBLISHED2, head_dim=128)))
+        ServingEngine(wide, _seeded(wide, 3), ServingConfig(
+            max_batch=2, page_size=8, num_pages=20, max_seq_len=64,
+            prefill_chunk=8, max_queue=0), SamplingParams(), eos_token_id=-1)
+    finally:
+        program_logger.removeHandler(handler)
+    lines = [s for s in said if "falls back to the gathered view" in s]
+    assert len(lines) == 1 and not eng.paged_kernel_active
+    assert "full_moe layers: head_dim 16 is not whole 128-lane tiles" \
+        in lines[0] and "window_moe layers: " in lines[0]
+    for _ in range(3):
+        eng.submit([1, 2, 3], 2)
+    eng.run_until_drained()
+    assert len([s for s in said if "falls back" in s]) == 1
+
+
+def test_the_new_counters_ride_with_the_tokens_and_add_no_span(second):
+    """``serving_moe_load_max_over_mean`` and ``serving_moe_passes_total``
+    come out of the decode program with the tokens (no span, no extra
+    fetch: the tick's span table is the one it was) and are in
+    ``serving_snapshot()``."""
+    from fleetx_tpu.observability import schema
+    from fleetx_tpu.observability.trace import HOT_LOOP_SPANS
+
+    assert sorted(HOT_LOOP_SPANS) == [
+        "data_fetch", "fit.fetch_metrics", "fit.log", "sdc_sentinel",
+        "serve.admit", "serve.decode", "serve.decode.wait", "serve.emit",
+        "serve.gauges", "serve.prefill", "serve.prefill.wait",
+        "serve.schedule", "serve.tick", "shard_batch", "train_step"]
+    cfg, params, _, _ = second
+    eng = _engine(cfg, params)
+    eng.reset_stats()
+    snap = eng.serving_snapshot()
+    assert snap["serving_moe_load_max_over_mean"] is None
+    assert snap["serving_moe_passes_total"] == 0
+    reqs = [eng.submit(p, 9) for p in ([1, 2, 3, 4, 5], [7] * 11)]
+    eng.run_until_drained()
+    assert all(r.state == "finished" for r in reqs)
+    m, snap = eng.metrics, eng.serving_snapshot()
+    steps = m.counter("serving_decode_steps").value
+    assert m.histogram("serving_moe_load_max_over_mean").total_count == steps
+    assert snap["serving_moe_passes_total"] == \
+        m.counter("serving_moe_passes_total").value == 8 * steps
+    # one or two rows of 3 experts each over 16 experts: 16/3 or 16/6 .. 16/3
+    assert 16 / 6 - 1e-6 <= snap["serving_moe_load_max_over_mean"] \
+        <= 16 / 3 + 1e-6
+    schema.validate_serving_record(snap)
+    for name in ("serving_moe_load_max_over_mean",
+                 "serving_moe_passes_total"):
+        assert name in schema.SERVING_METRIC_NAMES
+    with open(os.path.join(ROOT, "docs/observability.md")) as f:
+        text = f.read()
+    assert "serving_moe_load_max_over_mean" in text and \
+        "serving_moe_passes_total" in text

@@ -397,14 +397,55 @@ def test_paged_decode_compiles_at_the_serve_cells_geometry(topo, monkeypatch):
 # ------------------------- the second serving family: two caches, one buffer
 # each (serving/swa_moe.py)
 
-def test_swa_moe_programs_keep_both_caches_one_buffer(topo, monkeypatch):
+_SWA_MEMBERS = {
+    # the first member's shape: gated heads, 2 or 3 query heads to a
+    # key-value head, a dense layer, a shared expert, a share of the experts
+    "gated_share": dict(
+        intermediate_size=512, num_hidden_layers=9, num_key_value_heads=2,
+        num_attention_heads_per_layer=[4, 6, 6, 6, 4, 6, 6, 6, 4],
+        layer_types=(["full_attention"] + ["sliding_attention"] * 3) * 2
+        + ["full_attention"], mlp_only_layers=[0], num_experts=16,
+        experts_held=4, num_experts_per_tok=2,
+        shared_expert_intermediate_size=128, moe_routed_scaling_factor=2.5,
+        gating="per-head", router_input="post_attention",
+        router_scoring="softmax_topk", hidden_act="silu",
+        rope_parameters={
+            "full_attention": {"rope_theta": 500000, "rope_type": "yarn",
+                               "factor": 128, "beta_slow": 1, "beta_fast": 32,
+                               "original_max_position_embeddings": 8192,
+                               "partial_rotary_factor": 0.5},
+            "sliding_attention": {"rope_type": "default",
+                                  "rope_theta": 10000}}),
+    # the second's: 7 query heads to each of 4 key-value heads (28 query
+    # rows fill no sublane tile evenly: one block of all the heads), every
+    # expert held, the router before attention, full layers not rotated
+    "whole_group7": dict(
+        intermediate_size=0, num_hidden_layers=8, num_key_value_heads=4,
+        num_attention_heads_per_layer=[28] * 8,
+        layer_types=(["full_attention"] + ["sliding_attention"] * 3) * 2,
+        mlp_only_layers=[], num_experts=16, experts_held=16,
+        num_experts_per_tok=2, shared_expert_intermediate_size=0,
+        moe_routed_scaling_factor=1.0, gating="none",
+        router_input="pre_attention", router_scoring="topk_softmax",
+        hidden_act="relu", rope_parameters={
+            "full_attention": "none",
+            "sliding_attention": {"rope_type": "default",
+                                  "rope_theta": 1500000}}),
+}
+
+
+@pytest.mark.parametrize("member", sorted(_SWA_MEMBERS))
+def test_swa_moe_programs_keep_both_caches_one_buffer(topo, monkeypatch,
+                                                      member):
     """``decode`` and ``prefill`` of the windowed-attention sparse-expert
-    family, compiled for the v5e at widths its kernels admit: all four
-    cache buffers (the paged pool's K and V, the rings' K and V) aliased
-    from input to output, none held by anything but what enters, the loops'
-    carries and the in-place row scatters, no layer of a cache cut out; the
-    Mosaic kernels are in the programs under the names the trace finds
-    them by."""
+    family, compiled for the v5e at widths its kernels admit, in the shape
+    of each of its two members: all four cache buffers (the paged pool's K
+    and V, the rings' K and V) aliased from input to output, none held by
+    anything but what enters, the loops' carries and the in-place row
+    scatters, no layer of a cache cut out; the Mosaic kernels are in the
+    programs under the names the trace finds them by (a gather fallback is
+    not support: both decode kernels ENGAGE at 7 query heads to a
+    key-value head)."""
     from jax.sharding import SingleDeviceSharding
 
     from fleetx_tpu.models.swa_moe import model as M
@@ -414,13 +455,8 @@ def test_swa_moe_programs_keep_both_caches_one_buffer(topo, monkeypatch):
 
     monkeypatch.setattr(ops, "interpret", lambda: False)
     cfg = config_from_dict(dict(
-        vocab_size=VOCAB, hidden_size=256, intermediate_size=512,
-        num_hidden_layers=9, num_key_value_heads=2, head_dim=128,
-        num_attention_heads_per_layer=[4, 6, 6, 6, 4, 6, 6, 6, 4],
-        layer_types=(["full_attention"] + ["sliding_attention"] * 3) * 2
-        + ["full_attention"], sliding_window=1024, num_experts=16,
-        experts_held=4, num_experts_per_tok=2, moe_intermediate_size=128,
-        shared_expert_intermediate_size=128))
+        vocab_size=VOCAB, hidden_size=256, head_dim=128, sliding_window=1024,
+        moe_intermediate_size=128, **_SWA_MEMBERS[member]))
     one = SingleDeviceSharding(topo.devices[0])
 
     def arr(shape, dtype=jnp.int32):
