@@ -95,6 +95,9 @@ SERVING_RECORD_SCHEMA: dict[str, tuple[tuple, bool]] = {
     "decode_steps": ((int,), False),
     "decode_overlapped": ((int,), False),
     "overrun_rows": ((int,), False),
+    # cache kind ("full", "window") -> [pages a fold of the decode kernel
+    # takes, copies a cache buffer that fetch them]; empty on the gather
+    "kv_folds": ((dict,), False),
     # a family with sparse experts only (serving/registry.py): over the
     # decode steps, the mean of (rows of the fullest held expert / the
     # mean, worst layer of a step), null before the first step; and the
@@ -199,6 +202,13 @@ SERVING_METRIC_NAMES = (
     # a window layer's rings, and the bytes of all cache buffers
     "serving_kv_full_tokens", "serving_kv_window_tokens",
     "serving_kv_cache_bytes",
+    # how the decode kernel fetches a fold, a kind of cache (paged full
+    # layers, window layers' rings): pages a fold, and the copies a cache
+    # buffer that fetch them (a page each through a block table, one for a
+    # ring's run of pages); set once, when the engine is built; 0: no such
+    # cache, or the gathered view
+    "serving_kv_fold_pages_full", "serving_kv_fold_pages_window",
+    "serving_kv_fold_copies_full", "serving_kv_fold_copies_window",
     # a family with sparse experts (serving/registry.py): held experts a
     # decode step hit (mean over its expert layers), (token, expert) pairs
     # on held experts and all pairs, of the rows that decoded

@@ -13,7 +13,8 @@ pages_per_req·page_size, heads, head_dim]`` materialises either. An
 online-softmax accumulator in f32 VMEM scratch (the
 ``ops/flash_attention.py`` m/l/acc discipline) folds the pages into the
 output without ever holding more than two slots of ``pages_per_step``
-``[page_size, head_block·head_dim]`` tiles of K and of V live.
+``[page_size, head_block·head_dim]`` tiles of K and of V live
+(`pick_pages_per_step`: the pages that move 256 KB a pool; below).
 
 Why heads and head_dim are ONE minor dim: a TPU buffer is tiled (8, 128)
 over its two minor dims, so a 64-wide ``head_dim`` minor either pads every
@@ -65,12 +66,33 @@ index never changes), and the finish folds each row's own ``head_dim``
 lanes out of its ``[rows, kv_heads·head_dim]`` accumulator. With a
 ``window`` a row's walk does not start at its first page but at the group
 that holds position ``lens − window + 1``: keys older than the window are
-neither fetched nor scored, whatever the context, which is also how a ring
-of ``window`` + one prefill chunk of tokens a slot is read (the ring's
-static table maps logical page *j* onto ring page ``j mod ring_pages``:
-``serving/swa_moe.py``). With ``heads == kv_heads`` and no window the
-kernel is, line for line, the one it was (``tests/test_zz_serving.py``
-holds the outputs to the bit).
+neither fetched nor scored, whatever the context. With ``heads ==
+kv_heads`` and no window the kernel is, line for line, the one it was
+(``tests/test_zz_serving.py`` holds the outputs to the bit).
+
+How a fold's tile is fetched follows the layout. **Pages a fold follow the
+bytes a fold moves** (`pick_pages_per_step`): a fold's cost is the core's
+own — a loop step, the copies started and waited for, the scalar work of
+its pages — and is not hidden behind the copies, so a pool half as wide
+takes twice the pages for the same bytes (8 pages of 1,024 bfloat16 lanes,
+16 of 512). **A ring is fetched in one copy a pool a fold**: a caller that
+keeps a row's last tokens in a ring it laid out itself (``window`` + one
+prefill chunk of tokens a slot, ``ring_pages`` consecutive pages of the
+buffer, logical page *j* at ``j mod ring_pages``: ``serving/swa_moe.py``)
+says so (``ring_pages=``, each row's first ring page in the block table's
+place). The fold then divides the ring, so an aligned group of a ring's
+pages is one contiguous run of the buffer that never straddles the wrap —
+one ``make_async_copy`` of ``[pages, page_size, lanes]`` a pool where the
+table path starts a copy a page — and with nothing that grows with its
+pages a ring's fold is as large as the VMEM budget allows (32 pages of 512
+lanes, 16 of 1,024). No table entry is read and no page flagged: every
+page of a ring is valid, so the positions' mask is the whole mask, and what
+the ring holds at positions the query does not see is READ and multiplied
+by a zero probability — it has to be finite (a ring is zeros until a
+program writes it). Through a block table the guarantee above stands: a
+page that does not count is never read. Same keys, same masks, same order
+of sums: at the same pages a fold the ring fetch gives the table path's
+bits (``tests/test_swa_moe.py``).
 
 Contract mirrors ``ops/flash_attention.py`` exactly:
 
@@ -121,15 +143,37 @@ _PAGED_VMEM_BUDGET_BYTES = 4 * 1024 * 1024
 #: stays narrow; decode attention is DMA-bound and the MXU otherwise idle
 _MAX_HEAD_BLOCK = 16
 
-#: most pages in one fold. A fold's fixed cost (a loop step, 2 · pages
-#: copies started and waited for) is spread over its pages; the pages of a
-#: row's last group that lie past its query are fetched for nothing. 24
-#: layer calls at the 345M serving geometry on the v5e, 64 rows of 128–767
-#: tokens, 1 / 2 / 4 / 8 / 16 pages a fold: 22.5 / 13.2 / 8.7 / 7.1 / 7.2
-#: ms (6.2 / 5.9 at 8 / 16 once a row's last fold starts the next row's
-#: first copies; the rectangular grid of PR 28 took 13.5; PERF.md, PR 30).
-#: 8 and not 16: the folds then run in the order they always have
-_MAX_PAGES_PER_STEP = 8
+#: what one fold moves, a pool: the K (or V) tile of the 345M serving
+#: geometry's fold, 8 pages of ``[16, 1024]`` bfloat16. A fold's fixed cost
+#: (a loop step, the copies started and waited for, the scalar work of its
+#: pages run twice) is the core's own and is not hidden behind the copies:
+#: 24 layer calls at that geometry on the v5e, 64 rows of 128–767 tokens,
+#: 1 / 2 / 4 / 8 / 16 pages a fold: 22.5 / 13.2 / 8.7 / 7.1 / 7.2 ms (6.2 /
+#: 5.9 at 8 / 16 once a row's last fold starts the next row's first
+#: copies; PERF.md, PR 30) — and on a pool half as wide (4 key-value heads
+#: of 128: a page 16 KB) the same 8 pages move half the bytes for the same
+#: cost, where 16 read 7.7 % off the whole decode program (PERF.md, PR 38).
+#: So the pages of a fold follow the bytes: 8 on a 1,024-lane bfloat16
+#: pool (the folds then run in the order they always have), 16 on a
+#: 512-lane one. The pages of a row's last group that lie past its query
+#: are fetched for nothing, which is what keeps the target from growing
+_FOLD_BYTES = 256 * 1024
+
+#: ... and never fewer than this many pages: the fixed cost is spread over
+#: pages whatever they weigh, so a heavier page (a float32 pool) keeps the
+#: count the sweep above chose and leaves the halving to the VMEM budget
+_MIN_FOLD_PAGES = 8
+
+#: what one fold of a RING moves, a pool. A ring's pages are one run of the
+#: buffer, fetched in one copy a pool whatever their number, so nothing of a
+#: fold's cost grows with its pages and the fold is as large as the VMEM
+#: budget and the coarser start of the window's walk allow (a 4,096-key
+#: window on 512-key groups: 9 folds fetch 4,608 keys): 32 pages of ``[16,
+#: 512]``, 16 of ``[16, 1024]``. The whole decode program at the 512-lane
+#: recipe's sizes on the v5e: 27.75 ms with both caches through tables 8
+#: pages a fold, 22.59 with a ring fold of 16 pages, 22.01 with 32; the
+#: window kernel 8.07 -> 3.57 ms a step, 83 % of its HBM floor (PERF.md, PR 39)
+_RING_FOLD_BYTES = 512 * 1024
 
 
 def pick_head_block(num_heads: int, head_dim: int,
@@ -165,20 +209,32 @@ def _step_vmem_bytes(pages: int, page_size: int, hb: int, head_dim: int,
 
 def pick_pages_per_step(*, num_heads: int, head_dim: int, page_size: int,
                         pages_per_req: int, dtype: Any = jnp.float32,
-                        num_kv_heads: Optional[int] = None) -> int:
-    """Pages one fold takes: the most (a power of two ≤
-    `_MAX_PAGES_PER_STEP`, no more than a request has) whose two slots a
-    pool fit the VMEM budget; 0 when not even one page does. The head
-    block is picked over the KEY-VALUE heads (``num_kv_heads``; all the
-    heads when None), each with ``num_heads / num_kv_heads`` query rows."""
+                        num_kv_heads: Optional[int] = None,
+                        ring_pages: Optional[int] = None) -> int:
+    """Pages one fold takes, from the bytes of a page of the pool it is
+    given (``page_size · head block · head_dim · itemsize``, a pool): the
+    power of two that moves `_FOLD_BYTES` a pool (no fewer than
+    `_MIN_FOLD_PAGES`), no more than a request has, halved until its two
+    slots a pool fit the VMEM budget; 0 when not even one page does. The
+    head block is picked over the KEY-VALUE heads (``num_kv_heads``; all
+    the heads when None), each with ``num_heads / num_kv_heads`` query
+    rows. With ``ring_pages`` — the caller's pages are a ring of that many
+    consecutive pages of the buffer — the target is `_RING_FOLD_BYTES` and
+    the count also divides the ring, so that an aligned group of a ring's
+    pages is one run of the buffer and never straddles the wrap."""
     kv = num_kv_heads or num_heads
     hb = pick_head_block(kv, head_dim, dtype)
     if hb == 0 or num_heads % kv:
         return 0
-    g = _MAX_PAGES_PER_STEP
+    page_bytes = page_size * hb * head_dim * jnp.dtype(dtype).itemsize
+    if ring_pages is None:
+        most = max(_FOLD_BYTES // page_bytes, _MIN_FOLD_PAGES)
+    else:
+        most = max(_RING_FOLD_BYTES // page_bytes, 1)
+    g = 1 << (most.bit_length() - 1)
     while g and (g > pages_per_req or _step_vmem_bytes(
             g, page_size, hb, head_dim, dtype, num_heads // kv)
-            > _PAGED_VMEM_BUDGET_BYTES):
+            > _PAGED_VMEM_BUDGET_BYTES or (ring_pages or g) % g):
         g //= 2
     return g
 
@@ -192,6 +248,14 @@ def page_walk_shape(*, num_heads: int, head_dim: int, page_size: int,
                             page_size=page_size, pages_per_req=pages_per_req,
                             dtype=dtype, num_kv_heads=num_kv_heads)
     return g * page_size, -(-pages_per_req // g)
+
+
+def fold_shape(*, ring_pages: Optional[int] = None, **geometry) -> tuple:
+    """``(pages a fold takes, copies a pool that fetch them)`` for a
+    geometry the kernel admits (`pick_pages_per_step`'s arguments): a copy
+    a page through a block table, one for a ring's run of pages."""
+    g = pick_pages_per_step(ring_pages=ring_pages, **geometry)
+    return g, g if ring_pages is None else 1
 
 
 def paged_attention_refusal(*, num_heads: int, head_dim: int,
@@ -274,17 +338,20 @@ def first_group_walked(lens: Any, group_tokens: int, window: int):
     return xp.maximum(lens - (window - 1), 0) // group_tokens
 
 
-def page_groups_walked(lens: Any, group_tokens: int, table_groups: int,
+def page_groups_walked(lens: Any, group_tokens: int,
+                       table_groups: Optional[int],
                        window: Optional[int] = None):
     """Page groups the kernel folds for query positions ``lens``: the
     groups up to and including the one that holds the query (from the
     group `first_group_walked` names, with a ``window``), none for an
-    inactive row (``lens < 0``), never more than the table has. The
-    kernel's trip count (a traced scalar) and the engine's
-    ``serving_page_walk_share`` gauge (its host copy of the lengths, a
-    NumPy array) both come from here."""
+    inactive row (``lens < 0``), never more than the table has (a ring has
+    no end: ``table_groups`` None). The kernel's trip count (a traced
+    scalar) and the engine's ``serving_page_walk_share`` gauge (its host
+    copy of the lengths, a NumPy array) both come from here."""
     xp = np if isinstance(lens, np.ndarray) else jnp
-    upto = xp.minimum(lens // group_tokens + 1, table_groups)
+    upto = lens // group_tokens + 1
+    if table_groups is not None:
+        upto = xp.minimum(upto, table_groups)
     if window is not None:
         upto = upto - xp.minimum(
             first_group_walked(lens, group_tokens, window), upto)
@@ -293,7 +360,8 @@ def page_groups_walked(lens: Any, group_tokens: int, table_groups: int,
 
 def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, *refs,
                    pages: int, page_size: int, head_dim: int, scale: float,
-                   group: int = 1, window: Optional[int] = None):
+                   group: int = 1, window: Optional[int] = None,
+                   ring_pages: Optional[int] = None):
     """One (request, head-block) grid step: the online-softmax walk over
     the page groups this request's context reaches, ``pages`` pages a
     fold, and no further (nor, with a ``window``, further back than the
@@ -314,6 +382,17 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, *refs,
     page that starts past the query (or ends before the window) are not
     counted, their positions are masked and their copies read local page
     0 in their place; a group with no counted page is not folded.
+
+    ``ring_pages`` (with a ``window``): a row's pages are a RING the
+    caller laid out itself, ``ring_pages`` consecutive pages of the buffer
+    from page ``tables_ref[b]`` (``[B]``: no table), logical page *j* at
+    ``j mod ring_pages``. ``pages`` divides the ring, so group ``grp`` is
+    the run of ``pages`` pages from ``(grp · pages) mod ring_pages`` and
+    is fetched in ONE copy a pool (slots ``[2, pages, page_size, hb·hd]``,
+    read as the same ``[pages·page_size, hb·hd]`` tile). No table entry is
+    read and no page flagged: every page of a ring is a page of the ring,
+    so the positions' mask (``q_pos − window < p ≤ q_pos``) is the whole
+    mask. What a masked key holds is READ here, and has to be finite.
 
     ``group == 1``: ``q_ref`` is the head block's queries side by side
     ``[1, 1, hb·hd]``; outputs the f32 numerator ``[1, 1, hb·hd]``, m and
@@ -336,7 +415,8 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, *refs,
     span = pages * page_size
     q_pos = lens_ref[b]
     layer = layer_ref[0]
-    table_groups = tables_ref.shape[1] // pages
+    ring = ring_pages is not None
+    table_groups = None if ring else tables_ref.shape[1] // pages
 
     def first_group(row):
         return first_group_walked(lens_ref[row], span, window)
@@ -375,16 +455,25 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, *refs,
 
     def page_copies(slot, head_block, page_ids):
         # the 2 · pages copies that fill ``slot``: pool page
-        # ``page_ids[j]`` lands in rows [j·ps, (j+1)·ps)
+        # ``page_ids[j]`` lands in rows [j·ps, (j+1)·ps) — or, of a ring,
+        # the two that do: the run of ``pages`` pages from ``page_ids[0]``
         lanes = pl.ds(pl.multiple_of(head_block * width, width), width)
+        pools = tuple(enumerate(((k_hbm, k_buf), (v_hbm, v_buf))))
+        if ring:
+            return [pltpu.make_async_copy(
+                pool.at[layer, pl.ds(page_ids[0], pages), :, lanes],
+                buf.at[slot], sems.at[i, slot]) for i, (pool, buf) in pools]
         return [pltpu.make_async_copy(
             pool.at[layer, page_ids[j], :, lanes],
             buf.at[slot, pl.ds(j * page_size, page_size)], sems.at[i, slot])
-            for j in range(pages)
-            for i, (pool, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf)))]
+            for j in range(pages) for i, (pool, buf) in pools]
 
     def start(row, head_block, grp, slot):
-        for c in page_copies(slot, head_block, group_pages(row, grp)[1]):
+        if ring:
+            ids = [tables_ref[row] + jax.lax.rem(grp * pages, ring_pages)]
+        else:
+            ids = group_pages(row, grp)[1]
+        for c in page_copies(slot, head_block, ids):
             c.start()
 
     @pl.when((b == 0) & (h == 0))
@@ -434,6 +523,11 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, *refs,
             slot = (first_slot + i) % 2
             last = i + 1 == n_groups
 
+            def tile(buf):
+                # a slot as the MXU reads it; a ring's copy landed as the
+                # pages it read, [g, ps, hb·hd]
+                return buf[slot].reshape(span, width) if ring else buf[slot]
+
             @pl.when(jnp.logical_not(last) | (next_row < rows))
             def _next_group():
                 start(jnp.where(last, next_row, b),
@@ -442,7 +536,9 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, *refs,
 
             for c in page_copies(slot, 0, [0] * pages):  # a wait reads sizes
                 c.wait()
-            ok = group_pages(b, grp)[0]
+            # (a ring's walk runs from the window's first group to the
+            # query's: each holds a key the query sees)
+            ok = [True] if ring else group_pages(b, grp)[0]
 
             @pl.when(functools.reduce(jnp.logical_or, ok))
             def _compute():
@@ -452,18 +548,21 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, *refs,
                 # lanes is head h's dot product alone. f32 pools take the
                 # multi-pass product.
                 exact = jax.lax.Precision.HIGHEST
-                k = k_buf[slot]                            # [g·ps, hb·hd]
+                k = tile(k_buf)                            # [g·ps, hb·hd]
                 s = jax.lax.dot_general(
                     qd_ref[...], k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
                     precision=exact if k.dtype == jnp.float32 else None
                 ) * scale
                 col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-                page_ok = ok[0].astype(jnp.int32)  # per key: its page counts
-                for j in range(1, pages):
-                    page_ok = jnp.where(col >= j * page_size,
-                                        ok[j].astype(jnp.int32), page_ok)
-                seen = (page_ok > 0) & (grp * span + col <= q_pos)
+                if ring:
+                    seen = grp * span + col <= q_pos
+                else:
+                    page_ok = ok[0].astype(jnp.int32)  # per key: its page counts
+                    for j in range(1, pages):
+                        page_ok = jnp.where(col >= j * page_size,
+                                            ok[j].astype(jnp.int32), page_ok)
+                    seen = (page_ok > 0) & (grp * span + col <= q_pos)
                 if window is not None:
                     seen = seen & (grp * span + col > q_pos - window)
                 s = jnp.where(seen, s, _NEG_INF)           # [hb, g·ps]
@@ -478,11 +577,11 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, *refs,
                     # the probabilities in the values' dtype: one pass
                     # of the MXU where the f32 product takes six
                     pv = jax.lax.dot_general(
-                        pexp.astype(k.dtype), v_buf[slot],
+                        pexp.astype(k.dtype), tile(v_buf),
                         (((1,), (0,)), ((), ())),
                         preferred_element_type=jnp.float32)
                 else:
-                    v = v_buf[slot].astype(jnp.float32)    # [g·ps, hb·hd]
+                    v = tile(v_buf).astype(jnp.float32)    # [g·ps, hb·hd]
                     pv = jax.lax.dot_general(
                         pexp, v, (((1,), (0,)), ((), ())),
                         preferred_element_type=jnp.float32, precision=exact)
@@ -507,7 +606,8 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, *refs,
 
 def _paged_call(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                 tables: jax.Array, lens: jax.Array, layer: jax.Array,
-                window: Optional[int] = None):
+                window: Optional[int] = None,
+                ring_pages: Optional[int] = None):
     """Raw kernel invocation on one device's shard.
 
     ``q`` ``[B, nh, hd]``, pools ``[layers, pages, page_size, kv·hd]``
@@ -516,23 +616,33 @@ def _paged_call(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     entries, ``lens`` ``[B]`` int32 absolute query positions (< 0 =
     inactive row), ``layer`` an int32 scalar: which layer of the pool the
     K/V tiles are fetched from; ``window``: a query sees the keys at the
-    last ``window`` positions only. Returns the UNnormalized ``(acc
-    [B,nh,hd] f32, m [B,nh], l [B,nh])`` triple so sharded callers can
-    run the cross-shard softmax combine before dividing.
+    last ``window`` positions only; ``ring_pages``: ``tables`` is ``[B]``,
+    the first page of each row's ring of that many pages. Returns the
+    UNnormalized ``(acc [B,nh,hd] f32, m [B,nh], l [B,nh])`` triple so
+    sharded callers can run the cross-shard softmax combine before
+    dividing.
     """
     B, nh, hd = q.shape
     ps = pool_k.shape[2]
     kv = pool_k.shape[3] // hd
     group = nh // kv
     hb = pick_head_block(kv, hd, pool_k.dtype)
-    span, groups = page_walk_shape(
-        num_heads=nh, head_dim=hd, page_size=ps,
-        pages_per_req=tables.shape[1], dtype=pool_k.dtype, num_kv_heads=kv)
-    g = span // ps
-    # whole page groups: the padding columns are invalid pages
-    tables = jnp.pad(tables, ((0, 0), (0, groups * g - tables.shape[1])),
-                     constant_values=-1)
     width, rows = hb * hd, hb * group
+    if ring_pages is None:
+        span, groups = page_walk_shape(
+            num_heads=nh, head_dim=hd, page_size=ps,
+            pages_per_req=tables.shape[1], dtype=pool_k.dtype,
+            num_kv_heads=kv)
+        g = span // ps
+        # whole page groups: the padding columns are invalid pages
+        tables = jnp.pad(tables, ((0, 0), (0, groups * g - tables.shape[1])),
+                         constant_values=-1)
+        slots = (2, g * ps, width)
+    else:
+        g = pick_pages_per_step(
+            num_heads=nh, head_dim=hd, page_size=ps, pages_per_req=ring_pages,
+            dtype=pool_k.dtype, num_kv_heads=kv, ring_pages=ring_pages)
+        slots = (2, g, ps, width)       # a copy lands as the pages it read
 
     def q_map(b, h, t, l, lay):
         return b, 0, h
@@ -567,8 +677,8 @@ def _paged_call(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
             pl.BlockSpec((1, rows, 1), ml_map),
         ],
         scratch_shapes=[
-            _VMEM((2, g * ps, width), pool_k.dtype),
-            _VMEM((2, g * ps, width), pool_v.dtype),
+            _VMEM(slots, pool_k.dtype),
+            _VMEM(slots, pool_v.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((2,), jnp.int32),
             _VMEM((rows, width), pool_k.dtype),
@@ -580,7 +690,8 @@ def _paged_call(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     acc, m, l = pl.pallas_call(
         functools.partial(_decode_kernel, pages=g, page_size=ps,
                           head_dim=hd, scale=1.0 / math.sqrt(hd),
-                          group=group, window=window),
+                          group=group, window=window,
+                          ring_pages=ring_pages),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct(acc_shape, jnp.float32),
@@ -615,8 +726,8 @@ def _normalize(acc: jax.Array, l: jax.Array, dtype) -> jax.Array:
 
 def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                     block_tables: jax.Array, lens: jax.Array,
-                    layer: jax.Array,
-                    window: Optional[int] = None) -> jax.Array:
+                    layer: jax.Array, window: Optional[int] = None,
+                    ring_pages: Optional[int] = None) -> jax.Array:
     """Single-shard paged decode attention over layer ``layer`` of the
     pool.
 
@@ -628,9 +739,26 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     The pool's minor dimension says how many key-value heads there are
     (``q``'s heads a multiple of them); with ``window`` the softmax is
     over the last ``window`` positions ``lens − window + 1 … lens``.
+
+    ``ring_pages`` (with a ``window`` no longer than the ring): the caller
+    keeps each row's last ``ring_pages · page_size`` tokens in a ring it
+    laid out itself — ``ring_pages`` consecutive pages of the buffer, the
+    token at position *p* in page ``(p // page_size) mod ring_pages`` of
+    them — and ``block_tables`` is ``[B]``, the first page of each row's
+    ring. Same answer as the table ``ring_first[:, None] + j mod
+    ring_pages`` gives; a fold is then fetched in one copy a pool, and
+    what the ring holds at positions the query does not see is read and
+    masked, so it has to be finite (a ring is zeros until a program
+    writes it). Through a block table a page that does not count is never
+    read.
     """
-    tables = _localize_tables(block_tables, 0, pool_k.shape[1])
-    acc, _, l = _paged_call(q, pool_k, pool_v, tables, lens, layer, window)
+    if ring_pages is None:
+        block_tables = _localize_tables(block_tables, 0, pool_k.shape[1])
+    else:
+        assert window is not None and block_tables.ndim == 1 and \
+            window <= ring_pages * pool_k.shape[2], "a ring holds its window"
+    acc, _, l = _paged_call(q, pool_k, pool_v, block_tables.astype(jnp.int32),
+                            lens, layer, window, ring_pages)
     return _normalize(acc, l, q.dtype)
 
 

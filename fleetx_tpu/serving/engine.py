@@ -465,6 +465,14 @@ class ServingEngine:
         self.steps = 0
         self._started_at = time.monotonic()
         self.metrics = get_registry()
+        # how the decode kernel fetches a fold of each kind of cache: fixed
+        # when the programs were built, so the gauges are set once, here
+        # (0: no such cache, or the gathered view)
+        folds = self._programs.kv_folds
+        for kind in ("full", "window"):
+            pages, copies = folds.get(kind, (0, 0))
+            self.metrics.gauge(f"serving_kv_fold_pages_{kind}").set(pages)
+            self.metrics.gauge(f"serving_kv_fold_copies_{kind}").set(copies)
         # the tick's own clock (a test may replace it) and what it last
         # read: seconds by phase of the LAST tick only, ``tick`` the whole
         self._clock = time.monotonic
@@ -498,12 +506,15 @@ class ServingEngine:
         logger.info(
             "serving engine: max_batch=%d pages=%d x %d tokens "
             "(capacity %d token slots/layer), prefill_chunk=%d, "
-            "quantize_decode=%s, decode=%s, alloc=%s, cache %d bytes%s, "
+            "quantize_decode=%s, decode=%s%s, alloc=%s, cache %d bytes%s, "
             "weights: %s",
             sc.max_batch, self.allocator.usable_pages,
             sc.page_size, self.allocator.usable_pages * sc.page_size,
             sc.prefill_chunk, bool(sc.quantize_decode),
             "paged_kernel" if self.paged_kernel_active else "gather",
+            "".join(", %s cache %d pages a fold in %d cop%s a buffer"
+                    % (kind, pages, copies, "y" if copies == 1 else "ies")
+                    for kind, (pages, copies) in sorted(folds.items())),
             "lazy" if sc.lazy_alloc else "reserve", self.cache_bytes,
             " (%s)" % self._programs.describe
             if self._programs.describe else "", weights)
@@ -1282,6 +1293,10 @@ class ServingEngine:
             "decode_overlapped": int(
                 m.counter("serving_decode_overlapped").value),
             "overrun_rows": int(m.counter("serving_overrun_rows").value),
+            # cache kind -> [pages a fold of the decode kernel takes,
+            # copies a cache buffer that fetch them] (kernel path only)
+            "kv_folds": {kind: list(shape) for kind, shape
+                         in self._programs.kv_folds.items()},
             **gauges,
             "tokens_total": int(tokens),
             "tokens_per_sec": tokens / wall,

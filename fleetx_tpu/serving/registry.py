@@ -37,6 +37,10 @@ class Programs:
     paged_kernel_active: bool
     # (tokens one fold of the decode kernel covers, folds of a table row)
     walk_shape: Optional[tuple] = None
+    # cache kind ("full", "window") -> (pages one fold of the decode kernel
+    # takes, copies a cache buffer that fetch them); a kind the engine does
+    # not have, or every kind on the gathered view, is absent
+    kv_folds: dict = dataclasses.field(default_factory=dict)
     # slot -> further arguments of ``prefill`` after the rng and the draw
     prefill_extra: Callable = lambda slot: ()
     # (metrics registry, what ``decode`` returned after its logits)
@@ -147,15 +151,18 @@ class GPTFamily(Family):
             prefill_chunk=sc.prefill_chunk, sampling=sampling,
             quantize=bool(sc.quantize_decode), pool_sharding=sharding,
             paged_kernel=active)
-        walk = None
+        walk, folds = None, {}
         if active:
-            walk = PA.page_walk_shape(
+            geometry = dict(
                 num_heads=model_cfg.num_attention_heads // (
                     mesh.shape["tensor"] if mesh is not None else 1),
                 head_dim=model_cfg.head_dim, page_size=sc.page_size,
                 pages_per_req=pages_per_req, dtype=model_cfg.dtype)
+            walk = PA.page_walk_shape(**geometry)
+            folds = {"full": PA.fold_shape(**geometry)}
         return Programs(cache=[pool_k, pool_v], fns=fns, tokens=tokens,
-                        paged_kernel_active=active, walk_shape=walk)
+                        paged_kernel_active=active, walk_shape=walk,
+                        kv_folds=folds)
 
 
 class SWAMoEFamily(Family):
@@ -235,17 +242,21 @@ class SWAMoEFamily(Family):
         fns = S.make_step_fns(
             cfg, prefill_chunk=sc.prefill_chunk, page_size=sc.page_size,
             sampling=sampling, paged_kernel=active)
-        walk = None
-        if active:
-            walk = PA.page_walk_shape(
-                num_heads=max(cfg.num_attention_heads_per_layer),
-                head_dim=cfg.head_dim, page_size=sc.page_size,
-                pages_per_req=pages_per_req, dtype=cfg.dtype,
-                num_kv_heads=cfg.num_key_value_heads)
-        expert_layers = sum(n for kind, n in cfg.kinds().items()
-                            if kind.endswith("moe"))
         window = cfg.sliding_window
         ring = S.ring_pages(cfg, sc.page_size, sc.prefill_chunk)
+        walk, folds = None, {}
+        if active:
+            geometry = dict(
+                num_heads=max(cfg.num_attention_heads_per_layer),
+                head_dim=cfg.head_dim, page_size=sc.page_size,
+                dtype=cfg.dtype, num_kv_heads=cfg.num_key_value_heads)
+            walk = PA.page_walk_shape(pages_per_req=pages_per_req, **geometry)
+            folds = {"full": PA.fold_shape(pages_per_req=pages_per_req,
+                                           **geometry),
+                     "window": PA.fold_shape(pages_per_req=ring,
+                                             ring_pages=ring, **geometry)}
+        expert_layers = sum(n for kind, n in cfg.kinds().items()
+                            if kind.endswith("moe"))
 
         def record(metrics, stats):
             if not expert_layers:
@@ -268,7 +279,7 @@ class SWAMoEFamily(Family):
         return Programs(
             cache=cache, fns=fns,
             tokens=jnp.zeros((sc.max_batch,), jnp.int32),
-            paged_kernel_active=active, walk_shape=walk,
+            paged_kernel_active=active, walk_shape=walk, kv_folds=folds,
             prefill_extra=lambda slot: (np.int32(slot),),
             record_stats=record, kv_tokens=kv_tokens,
             describe="%d full layers paged, %d window layers a ring of %d "

@@ -35,9 +35,12 @@ the stack seen flat.
 **Attention.** Decode: ``ops/paged_attention.py`` with fewer key-value
 heads than query heads (a whole number of query heads to each: 6 or 9 over
 8 in one member, 7 over 4 in the other) — the full layers over the
-request's pages, the window layers over the slot's ring through a static
-table (logical page *j* → ring page ``j mod ring_pages``) with ``window=``
-set, so a row's walk starts at the page that holds ``len − window + 1``.
+request's pages, the window layers over the slot's ring with ``window=``
+set, so a row's walk starts at the group of pages that holds ``len − window
++ 1``. The kernel is told what the ring is (``ring_pages=``, the first page
+of each row's ring in the block table's place): logical page *j* is ring
+page ``j mod ring_pages``, an aligned group of pages is one run of the
+buffer, and a fold fetches it in one copy a cache buffer.
 Where the kernel does not admit the geometry (toy widths), the gathered
 view, and the engine's build says so once (``gather_fallbacks``). Prefill:
 the gather path — a full layer folds the request's pages a block of keys at
@@ -284,9 +287,6 @@ def _forward(params: Any, cfg: SWAMoEConfig, tokens, positions, cache,
     ring_first = 1 + slots * rp                               # [B]
     ring_at = jnp.where(
         valid, ring_first[:, None] + (q_pos // ps) % rp, 0)
-    # the ring as a block table: logical page j -> ring page j mod rp
-    ring_table = ring_first[:, None] + \
-        jnp.arange(P, dtype=jnp.int32)[None, :] % rp
     # the gathered view of a ring: the ``view`` logical pages that end at
     # the page of ``last``, in order, and the position of each key in them
     view_first = (jnp.maximum(last, 0) // ps - (view - 1))[:, None] \
@@ -301,6 +301,10 @@ def _forward(params: Any, cfg: SWAMoEConfig, tokens, positions, cache,
     # a window layer's prefill folds its ring a key block at a time once
     # the ring is longer than a few blocks
     fold_ring = not decode and view * ps > _WHOLE_RING_BLOCKS * key_block
+    # ... through the ring as a block table: logical page j -> ring page
+    # j mod rp
+    ring_table = ring_first[:, None] + \
+        jnp.arange(P, dtype=jnp.int32)[None, :] % rp if fold_ring else None
 
     def attention(kind_type, u, lp, cache, at):
         q = jnp.einsum("bsh,ndh->bsnd", u, lp["q"])
@@ -331,9 +335,11 @@ def _forward(params: Any, cfg: SWAMoEConfig, tokens, positions, cache,
             ring_k = ring_k.at[at, ring_at, offs].set(k_rows)
             ring_v = ring_v.at[at, ring_at, offs].set(v)
             if decode and paged_kernel:
-                o = PA.paged_attention(q[:, 0], ring_k, ring_v, ring_table,
-                                       positions[:, 0], at,
-                                       window=window)[:, None]
+                # the kernel is told what this is: a ring of ``rp`` pages
+                # from ``ring_first``, so a fold is one run of the buffer
+                o = PA.paged_attention(q[:, 0], ring_k, ring_v, ring_first,
+                                       positions[:, 0], at, window=window,
+                                       ring_pages=rp)[:, None]
             elif fold_ring:
                 o = _prefill_blocked_attention(
                     q, ring_k, ring_v, at, ring_table, q_pos, last[0] + 1,

@@ -570,6 +570,131 @@ def test_the_window_walk_starts_where_the_window_does():
         PA.page_groups_walked(lens, 128, 76), [0, 1, 1, 2, 4, 5, 5, 6, 71])
 
 
+def _ring_reference(q, pk, pv, first, lens, layer, window, rp):
+    """Attention over the last ``window`` positions of each row's ring
+    (position *p* in ring page ``(p // page) mod rp``), float64."""
+    B, H, hd = q.shape
+    ps, kv = pk.shape[2], pk.shape[3] // hd
+    out = np.zeros((B, H, hd))
+    pk, pv = (np.asarray(a[layer], np.float32) for a in (pk, pv))
+    for b in range(B):
+        last = int(lens[b])
+        if last < 0:
+            continue
+        pos = np.arange(max(last - window + 1, 0), last + 1)
+        at = int(first[b]) + (pos // ps) % rp, pos % ps
+        k = pk[at].astype(np.float64).reshape(len(pos), kv, hd)
+        v = pv[at].astype(np.float64).reshape(len(pos), kv, hd)
+        for j in range(H):
+            s = k[:, j // (H // kv)] @ np.asarray(q[b, j], np.float64) \
+                / math.sqrt(hd)
+            p = np.exp(s - s.max())
+            out[b, j] = (p / p.sum()) @ v[:, j // (H // kv)]
+    return out
+
+
+#: name -> (query heads, key-value heads, ring pages, window, pages a fold
+#: asked for, pages a fold taken, dtype): the two members' rings — 7 query
+#: heads over each of 4 key-value heads of 128, a ring of 288 pages under a
+#: 4,096-token window; 6 and 9 over 8, 64 pages under 512 — at 8, 16 and 32
+#: pages a fold (32 pages of 1,024 lanes do not fit the fold's VMEM budget:
+#: 16), and a ring that 32 and 16 pages do not divide (72 = 8 · 9)
+RING_CASES = {
+    "group7-8": (28, 4, 288, 4096, 8, 8, jnp.bfloat16),
+    "group7-16": (28, 4, 288, 4096, 16, 16, jnp.bfloat16),
+    "group7-32": (28, 4, 288, 4096, 32, 32, jnp.bfloat16),
+    "group7-16-f32": (28, 4, 288, 4096, 16, 16, jnp.float32),
+    "group6-8": (48, 8, 64, 512, 8, 8, jnp.bfloat16),
+    "group6-16": (48, 8, 64, 512, 16, 16, jnp.bfloat16),
+    "group9-8": (72, 8, 64, 512, 8, 8, jnp.bfloat16),
+    "group9-16": (72, 8, 64, 512, 16, 16, jnp.bfloat16),
+    "group9-32-does-not-fit": (72, 8, 64, 512, 32, 16, jnp.bfloat16),
+    "group9-8-f32": (72, 8, 64, 512, 8, 8, jnp.float32),
+    "group7-ring-of-72-takes-8": (28, 4, 72, 1024, 32, 8, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RING_CASES))
+def test_a_ring_fetched_in_one_copy_is_the_table_path_to_the_bit(
+        monkeypatch, case):
+    """The window layers' decode call tells the kernel what its pages are
+    (``ring_pages=``, each row's first ring page in the table's place): a
+    fold is then one copy a buffer and no page is flagged. At the same
+    pages a fold the answer is the table path's (logical page *j* -> ring
+    page ``j mod ring_pages``) to the BIT — the same keys, the same masks,
+    the same order of sums — and the gathered reference's within the
+    dtype's grain, for rows under the window, exactly at it, one past it,
+    at the ring's wrap, at multiples of the ring, and inactive (exact
+    zeros). The ring holds finite values everywhere: what a masked key
+    holds is read."""
+    from fleetx_tpu.ops import paged_attention as PA
+
+    heads, kv, rp, window, asked, taken, dtype = RING_CASES[case]
+    hd, ps = 128, 16
+    page_bytes = ps * kv * hd * jnp.dtype(dtype).itemsize
+    monkeypatch.setattr(PA, "_RING_FOLD_BYTES", asked * page_bytes)
+    monkeypatch.setattr(PA, "_FOLD_BYTES", taken * page_bytes)
+    geometry = dict(num_heads=heads, head_dim=hd, page_size=ps, dtype=dtype,
+                    num_kv_heads=kv)
+    assert PA.fold_shape(pages_per_req=rp, ring_pages=rp, **geometry) \
+        == (taken, 1)
+    ring = rp * ps
+    lens = np.array([-1, 0, window // 2 + 3, window - 1, window, ring - 1,
+                     ring, ring + 5, 2 * ring - 1, 2 * ring, 3 * ring + 77],
+                    np.int32)
+    B, per_req = len(lens), -(-(int(lens.max()) + 1) // ps)
+    assert PA.fold_shape(pages_per_req=per_req, **geometry) == (taken, taken)
+    rng = np.random.default_rng(39)
+    shape = (2, 1 + B * rp, ps, kv * hd)
+    pk = jnp.asarray(rng.standard_normal(shape, np.float32), dtype)
+    pv = jnp.asarray(rng.standard_normal(shape, np.float32), dtype)
+    q = jnp.asarray(rng.normal(size=(B, heads, hd)), dtype)
+    first = (1 + rp * rng.permutation(B)).astype(np.int32)
+    table = first[:, None] + np.arange(per_req, dtype=np.int32)[None, :] % rp
+    layer = jnp.int32(1)
+    got = PA.paged_attention(q, pk, pv, jnp.asarray(first), jnp.asarray(lens),
+                             layer, window, ring_pages=rp)
+    by_table = PA.paged_attention(q, pk, pv, jnp.asarray(table),
+                                  jnp.asarray(lens), layer, window)
+    assert got.dtype == q.dtype
+    got, by_table = (np.asarray(a, np.float32) for a in (got, by_table))
+    np.testing.assert_array_equal(got, by_table)
+    tol = 3e-6 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(
+        got, _ring_reference(q, pk, pv, first, lens, 1, window, rp),
+        atol=tol, rtol=tol)
+    assert not got[0].any()             # an inactive row: exact zeros
+
+
+def test_pages_a_fold_follow_the_bytes_a_fold_moves():
+    """The one rule, at the geometries that are served: 8 pages a fold on
+    the 1,024-lane bfloat16 pools (both GPT cells: 16 heads of 64; Laguna's
+    full layers: 8 key-value heads of 128), 16 on the second member's
+    512-lane full pool; a ring is one copy a buffer whatever its pages, and
+    takes 32 pages of 512 lanes or 16 of 1,024; a float32 pool keeps 8."""
+    from fleetx_tpu.ops import paged_attention as PA
+
+    page = dict(head_dim=128, page_size=16, dtype=jnp.bfloat16)
+    assert PA.fold_shape(num_heads=16, head_dim=64, page_size=16,
+                         pages_per_req=64, dtype=jnp.bfloat16) == (8, 8)
+    assert PA.fold_shape(num_heads=16, head_dim=64, page_size=16,
+                         pages_per_req=64, dtype=jnp.float32) == (8, 8)
+    for heads in (48, 72):
+        assert PA.fold_shape(num_heads=heads, num_kv_heads=8,
+                             pages_per_req=608, **page) == (8, 8)
+    assert PA.fold_shape(num_heads=72, num_kv_heads=8, pages_per_req=64,
+                         ring_pages=64, **page) == (16, 1)
+    assert PA.fold_shape(num_heads=28, num_kv_heads=4, pages_per_req=816,
+                         **page) == (16, 16)
+    assert PA.fold_shape(num_heads=28, num_kv_heads=4, pages_per_req=288,
+                         ring_pages=288, **page) == (32, 1)
+    # no more than a request has; a ring no fold divides is walked a page
+    assert PA.fold_shape(num_heads=28, num_kv_heads=4, pages_per_req=5,
+                         **page) == (4, 4)
+    assert PA.fold_shape(num_heads=28, num_kv_heads=4, pages_per_req=33,
+                         ring_pages=33, **page) == (1, 1)
+
+
 def test_kv_pool_spec_refuses_a_tensor_axis_wider_than_the_kv_heads():
     from fleetx_tpu.parallel.rules import kv_pool_spec
 
@@ -850,19 +975,26 @@ def test_the_second_members_built_tree_is_3967_m_parameters():
     assert (M.tile_rows(model_cfg), M.pass_rows(model_cfg, 48),
             M.pass_rows(model_cfg, 512)) == (16, 1024, 4096)
     # group 7: the kernel takes all 4 key-value heads, 28 query rows, in
-    # one block, and walks a row's 4,096-token window in 33 folds at most
+    # one block. A page of the 512-lane pool is 16 KB, so a fold of the
+    # full layers takes 16 pages (256 keys) and one of a ring, fetched in
+    # one copy a buffer, 32 (512 keys): a row's 4,096-token window is
+    # walked in 9 folds at most (33 of 8 pages through a block table)
     from fleetx_tpu.ops import paged_attention as PA
 
     per_req = -(-sc["max_seq_len"] // sc["page_size"])
     assert S.gather_fallbacks(model_cfg, page_size=16,
                               pages_per_req=per_req) == []
     assert PA.pick_head_block(4, 128, jnp.bfloat16) == 4
-    span, _ = PA.page_walk_shape(num_heads=28, head_dim=128, page_size=16,
-                                 pages_per_req=per_req, dtype=jnp.bfloat16,
-                                 num_kv_heads=4)
-    assert span == 128
+    geometry = dict(num_heads=28, head_dim=128, page_size=16,
+                    dtype=jnp.bfloat16, num_kv_heads=4)
+    span, folds = PA.page_walk_shape(pages_per_req=per_req, **geometry)
+    assert (span, folds) == (256, 51)
+    ring_span = 16 * PA.pick_pages_per_step(
+        pages_per_req=288, ring_pages=288, **geometry)
+    assert ring_span == 512
     lens = np.arange(4096, 13056, 37, dtype=np.int32)
-    assert PA.page_groups_walked(lens, span, -(-per_req // 8),
+    assert PA.page_groups_walked(lens, ring_span, None, 4096).max() == 9
+    assert PA.page_groups_walked(lens, 128, -(-per_req // 8),
                                  4096).max() == 33
 
 
@@ -962,6 +1094,55 @@ def test_a_gather_fallback_is_said_once_when_the_engine_is_built(second):
         eng.submit([1, 2, 3], 2)
     eng.run_until_drained()
     assert len([s for s in said if "falls back" in s]) == 1
+
+
+def test_the_fold_gauges_say_how_each_cache_is_fetched(second):
+    """Set once, when the engine is built, a cache kind each: the pages a
+    fold of the decode kernel takes and the copies a buffer that fetch
+    them — a copy a page through the request's block table, ONE for a
+    ring's run of pages; on the build's log line and in
+    ``serving_snapshot()``; 0 and no entry on the gathered view."""
+    import logging
+
+    from fleetx_tpu.observability import schema
+    from fleetx_tpu.utils.log import logger as program_logger
+
+    said = []
+    handler = logging.Handler()
+    handler.emit = lambda record: said.append(record.getMessage())
+    program_logger.addHandler(handler)
+    try:
+        wide = config_from_dict(_toy2(dict(PUBLISHED2, head_dim=128)))
+        eng = ServingEngine(wide, _seeded(wide, 3), ServingConfig(
+            max_batch=2, page_size=8, num_pages=20, max_seq_len=64,
+            prefill_chunk=8, max_queue=0), SamplingParams(), eos_token_id=-1)
+    finally:
+        program_logger.removeHandler(handler)
+    assert eng.paged_kernel_active
+    # 8 pages a request; a ring of (window 8 + chunk 8) / 8 = 2 pages
+    want = {"full": [8, 8], "window": [2, 1]}
+    assert eng.serving_snapshot()["kv_folds"] == want
+    assert not schema.validate_serving_record(eng.serving_snapshot())
+    for kind, (pages, copies) in want.items():
+        assert eng.metrics.gauge(f"serving_kv_fold_pages_{kind}").value \
+            == pages
+        assert eng.metrics.gauge(f"serving_kv_fold_copies_{kind}").value \
+            == copies
+    line = next(s for s in said if s.startswith("serving engine:"))
+    assert "full cache 8 pages a fold in 8 copies a buffer" in line and \
+        "window cache 2 pages a fold in 1 copy a buffer" in line
+    req = eng.submit(list(range(1, 30)), 20)
+    eng.run_until_drained()
+    assert req.state == "finished" and len(req.tokens) == 20
+    cfg, params, _, _ = second          # toy widths: the gathered view
+    gathered = _engine(cfg, params)
+    assert gathered.serving_snapshot()["kv_folds"] == {}
+    for name in ("pages_full", "copies_full", "pages_window",
+                 "copies_window"):
+        assert gathered.metrics.gauge(f"serving_kv_fold_{name}").value == 0
+        assert f"serving_kv_fold_{name}" in schema.SERVING_METRIC_NAMES
+    with open(os.path.join(ROOT, "docs/observability.md")) as f:
+        assert "serving_kv_fold_copies_window" in f.read()
 
 
 def test_the_new_counters_ride_with_the_tokens_and_add_no_span(second):

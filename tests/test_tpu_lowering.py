@@ -394,6 +394,63 @@ def test_paged_decode_compiles_at_the_serve_cells_geometry(topo, monkeypatch):
     assert calls[0].count("bf16[24,3585,16,1024]") == 2, calls[0]
 
 
+#: member -> (slots, query heads of a window layer, key-value heads, full
+#: pool pages, table columns, ring pages, window, pages a fold of the pool,
+#: pages a fold of a ring): both caches of each shipped recipe of the
+#: second family, bfloat16, pages of 16 tokens
+_SWA_CACHES = {
+    "laguna_s": (64, 72, 8, 18001, 608, 64, 512, 8, 16),
+    "smallthinker": (48, 28, 4, 28001, 816, 288, 4096, 16, 32),
+}
+
+
+@pytest.mark.parametrize("member", sorted(_SWA_CACHES))
+def test_ring_fetch_compiles_at_the_second_familys_geometries(
+        topo, monkeypatch, member):
+    """Both decode calls of each member of the second family at its
+    recipe's cache shapes, through the chip's own Mosaic compiler: the
+    full layers' walk through the block table at the pages a fold its
+    pool's page bytes give, and the window layers' with the ring fetch
+    (each row's first ring page in the table's place, a run of pages one
+    copy a buffer into ``[2, pages, 16, lanes]`` slots, read as one
+    ``[pages · 16, lanes]`` tile): each fits VMEM, is ONE kernel call under
+    the name the trace finds it by, and is handed each buffer once."""
+    from jax.sharding import SingleDeviceSharding
+
+    from fleetx_tpu.ops import paged_attention as PA
+
+    monkeypatch.setattr(ops, "interpret", lambda: False)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    slots, heads, kv, pages, cols, rp, window, fold, ring_fold = \
+        _SWA_CACHES[member]
+    geometry = dict(num_heads=heads, head_dim=128, page_size=16,
+                    dtype=jnp.bfloat16, num_kv_heads=kv)
+    assert PA.fold_shape(pages_per_req=cols, **geometry) == (fold, fold)
+    assert PA.fold_shape(pages_per_req=rp, ring_pages=rp, **geometry) \
+        == (ring_fold, 1)
+    q = arr((slots, heads, 128), jnp.bfloat16)
+    pool = arr((3, pages, 16, kv * 128), jnp.bfloat16)
+    ring = arr((6, 1 + slots * rp, 16, kv * 128), jnp.bfloat16)
+    calls = {
+        "paged_decode": (pool, jax.jit(PA._paged_call).lower(
+            q, pool, pool, arr((slots, cols)), arr((slots,)), arr(()))),
+        "paged_decode_window": (ring, jax.jit(
+            lambda *a: PA._paged_call(*a, window, rp)).lower(
+            q, ring, ring, arr((slots,)), arr((slots,)), arr(()))),
+    }
+    for name, (buffer, lowered) in calls.items():
+        assert set(mosaic_kernels(lowered.as_text())) == {name}
+        found = [line for line in lowered.compile().as_text().splitlines()
+                 if "custom-call(" in line and name in line]
+        assert len(found) == 1, (name, found)
+        shape = "bf16[%s]" % ",".join(map(str, buffer.shape))
+        assert found[0].count(shape) == 2, found[0]
+
+
 # ------------------------- the second serving family: two caches, one buffer
 # each (serving/swa_moe.py)
 

@@ -678,6 +678,15 @@ def _paged_case(dtype, layers=3, pages=60, page_size=4, heads=4, hd=16,
     return q, pool_k, pool_v, jnp.asarray(tables), jnp.asarray(lens)
 
 
+def _fold_pages(monkeypatch, pages: int) -> None:
+    """Hold the kernel to ``pages`` pages a fold whatever a page weighs (the
+    rule follows the bytes a fold moves: pages as light as these tests'
+    would otherwise all go in one fold, and no edge of a fold be walked)."""
+    from fleetx_tpu.ops import paged_attention as PA
+
+    monkeypatch.setattr(PA, "pick_pages_per_step", lambda **geometry: pages)
+
+
 def _dense_attention(q, pool_k, pool_v, tables, lens, layer):
     """The gather path itself (``serving/decode.py``) on layer ``layer``,
     in f32."""
@@ -704,7 +713,7 @@ def test_paged_call_reads_the_layer_it_is_given(monkeypatch, dtype,
     arithmetic; inactive rows come out as exact zeros."""
     from fleetx_tpu.ops import paged_attention as PA
 
-    monkeypatch.setattr(PA, "_MAX_PAGES_PER_STEP", pages_per_step)
+    _fold_pages(monkeypatch, pages_per_step)
     q, pool_k, pool_v, tables, lens = _paged_case(dtype)
     local = PA._localize_tables(tables, 0, pool_k.shape[1])
     outs = []
@@ -739,7 +748,7 @@ def test_paged_kernel_skips_pages_it_does_not_own(monkeypatch,
     combine to the whole — the cross-shard contract."""
     from fleetx_tpu.ops import paged_attention as PA
 
-    monkeypatch.setattr(PA, "_MAX_PAGES_PER_STEP", pages_per_step)
+    _fold_pages(monkeypatch, pages_per_step)
     q, pool_k, pool_v, tables, lens = _paged_case(jnp.float32)
     local = PA._localize_tables(tables, 0, pool_k.shape[1])
     col = jnp.arange(local.shape[1])[None, :]
@@ -807,7 +816,7 @@ def _walk_case(names, dtype, page_size=4, per_req=19, heads=4, hd=16,
 @pytest.mark.parametrize("case", list(_WALK_CASES))
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-def test_paged_walk_is_as_long_as_the_row(dtype, case):
+def test_paged_walk_is_as_long_as_the_row(monkeypatch, dtype, case):
     """The kernel's page walk, bounded by each row's own query position,
     gives the gather path's answer at every edge of a fold: the first
     token, one short of a fold, a fold, one past it, the whole table; an
@@ -817,6 +826,7 @@ def test_paged_walk_is_as_long_as_the_row(dtype, case):
     and with two."""
     from fleetx_tpu.ops import paged_attention as PA
 
+    _fold_pages(monkeypatch, 8)
     wide = dict(heads=32, hd=8) if "two_head_blocks" in case else {}
     q, pool_k, pool_v, tables, lens, dense, dense_lens = _walk_case(
         _WALK_CASES[case], dtype, **wide)
@@ -842,7 +852,7 @@ def test_paged_walk_reads_nothing_past_the_query(monkeypatch,
     (0 x NaN is NaN: masking its scores would not do)."""
     from fleetx_tpu.ops import paged_attention as PA
 
-    monkeypatch.setattr(PA, "_MAX_PAGES_PER_STEP", pages_per_step)
+    _fold_pages(monkeypatch, pages_per_step)
     q, pool_k, pool_v, tables, lens = _paged_case(jnp.float32)
     tables, n = np.array(tables), np.asarray(lens)
     poison = next(p for p in range(1, pool_k.shape[1])
@@ -887,6 +897,7 @@ def test_page_walk_share_gauge_counts_what_the_kernel_folds(small_model,
     from fleetx_tpu.ops import paged_attention as PA
 
     cfg, _, params = small_model
+    _fold_pages(monkeypatch, 8)
     eng = ServingEngine(
         cfg, params,
         ServingConfig(max_batch=4, page_size=2, num_pages=129,
@@ -915,6 +926,44 @@ def test_page_walk_share_gauge_counts_what_the_kernel_folds(small_model,
             seen.add(int(want))
     assert {1, 2, 3} <= seen                 # grew fold by fold
     assert "serving_page_walk_share" in SERVING_METRIC_NAMES
+
+
+def test_fold_gauges_of_a_gpt_engine_are_set_at_its_build(small_model):
+    """How the decode kernel fetches a fold is fixed when the engine is
+    built: at the 345M serving geometry (16 heads of 64, pages of 16,
+    bfloat16 — one layer and a small vocabulary here) 8 pages a fold, a
+    copy a page, and no window cache; on the gathered view all four gauges
+    read 0 and the snapshot names no fold."""
+    cfg, _, params = _build_model(
+        hidden_size=1024, num_attention_heads=16, num_layers=1,
+        ffn_hidden_size=64, dtype="bfloat16", max_position_embeddings=1024)
+    sc = dict(max_batch=2, page_size=16, num_pages=9, max_seq_len=64,
+              prefill_chunk=16)
+    eng = ServingEngine(cfg, params, ServingConfig(**sc), eos_token_id=EOS)
+    assert eng.paged_kernel_active
+    read = lambda: {  # noqa: E731
+        name: eng.metrics.gauge(f"serving_kv_fold_{name}").value
+        for name in ("pages_full", "copies_full", "pages_window",
+                     "copies_window")}
+    assert read() == {"pages_full": 4, "copies_full": 4, "pages_window": 0,
+                      "copies_window": 0}      # a request has 4 pages
+    eng = ServingEngine(cfg, params, ServingConfig(
+        **dict(sc, num_pages=65, max_seq_len=1024)), eos_token_id=EOS)
+    assert read() == {"pages_full": 8, "copies_full": 8, "pages_window": 0,
+                      "copies_window": 0}
+    snap = eng.serving_snapshot()
+    assert snap["kv_folds"] == {"full": [8, 8]}
+    assert not validate_serving_record(snap)
+    gathered = ServingEngine(
+        small_model[0], small_model[2],
+        ServingConfig(max_batch=2, page_size=4, num_pages=9, max_seq_len=16,
+                      prefill_chunk=4, paged_kernel=False),
+        eos_token_id=EOS)
+    assert not gathered.paged_kernel_active
+    assert set(read().values()) == {0}
+    assert gathered.serving_snapshot()["kv_folds"] == {}
+    for name in read():
+        assert f"serving_kv_fold_{name}" in SERVING_METRIC_NAMES
 
 
 # ---------------------------------------------------------------------------
