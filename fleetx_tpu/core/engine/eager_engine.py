@@ -38,7 +38,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from fleetx_tpu.core import checkpoint as ckpt_lib
 from fleetx_tpu.observability import MemoryMonitor, Observability, flight
-from fleetx_tpu.observability.trace import ProfilerWindow
+from fleetx_tpu.observability.trace import ProfilerWindow, device_scope
 from fleetx_tpu.optims.optimizer import global_norm
 from fleetx_tpu.parallel import rules as rules_lib
 from fleetx_tpu.parallel.mesh import build_mesh
@@ -492,9 +492,10 @@ class EagerEngine(BasicEngine):
             if grad_spec_leaves is None:
                 return grads
             leaves, treedef = jax.tree.flatten(grads)
-            return jax.tree.unflatten(treedef, [
-                jax.lax.with_sharding_constraint(g, s)
-                for g, s in zip(leaves, grad_spec_leaves)])
+            with device_scope("optimizer"):
+                return jax.tree.unflatten(treedef, [
+                    jax.lax.with_sharding_constraint(g, s)
+                    for g, s in zip(leaves, grad_spec_leaves)])
 
         def gather_params(params):
             """Allgather the fsdp-sharded resident params back to their full
@@ -505,9 +506,10 @@ class EagerEngine(BasicEngine):
             if gather_spec_leaves is None:
                 return params
             leaves, treedef = jax.tree.flatten(params)
-            return jax.tree.unflatten(treedef, [
-                jax.lax.with_sharding_constraint(p, s)
-                for p, s in zip(leaves, gather_spec_leaves)])
+            with device_scope("optimizer"):
+                return jax.tree.unflatten(treedef, [
+                    jax.lax.with_sharding_constraint(p, s)
+                    for p, s in zip(leaves, gather_spec_leaves)])
 
         def grads_and_metrics(params, scaler, batch, step):
             def loss_fn(p):
@@ -518,8 +520,10 @@ class EagerEngine(BasicEngine):
                 return loss, metrics
             (_, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
             if use_scaler:
-                inv = 1.0 / scaler.loss_scale
-                grads = jax.tree.map(lambda g: g * inv.astype(g.dtype), grads)
+                with device_scope("optimizer"):
+                    inv = 1.0 / scaler.loss_scale
+                    grads = jax.tree.map(lambda g: g * inv.astype(g.dtype),
+                                         grads)
             return constrain_grads(grads), metrics
 
         def update_fn(params, opt_state, grads):
@@ -527,9 +531,9 @@ class EagerEngine(BasicEngine):
             reduction shared by the ``grad_norm`` metric and the clip —
             either owned by a ``fused_clip`` optimizer or threaded in as an
             optax extra arg — then update + apply under stage-2 sharded
-            grads. Shared verbatim by ``train_step`` and the isolated
-            ``measure_update_phase`` timing."""
-            with jax.named_scope("optimizer_update"):
+            grads. Its device time is the step program's ``optimizer``
+            scope (the benchmark's ``scope_optimizer_ms``)."""
+            with device_scope("optimizer"):
                 if opt_dev_shardings is not None:  # offload: host -> device
                     opt_state = jax.device_put(opt_state, opt_dev_shardings)
                 if getattr(optimizer, "fused_clip", False):
@@ -543,10 +547,6 @@ class EagerEngine(BasicEngine):
                     new_opt = jax.device_put(new_opt, opt_host_shardings)
                 new_params = optax.apply_updates(params, updates)
             return new_params, new_opt, grad_norm
-
-        self._update_fn = update_fn
-        self._constrain_grads = constrain_grads
-        self._gather_params = gather_params
 
         def train_step(state: TrainState, batch: dict):
             if accum > 1:
@@ -574,9 +574,10 @@ class EagerEngine(BasicEngine):
                 def body(carry, mb):
                     g_acc, m_acc = carry
                     g, m = grads_and_metrics(state.params, state.scaler, mb, state.step)
-                    g_acc = constrain_grads(jax.tree.map(
-                        lambda a, gi: a + gi.astype(a.dtype), g_acc, g))
-                    m_acc = jax.tree.map(jnp.add, m_acc, m)
+                    with device_scope("optimizer"):  # the accumulator
+                        g_acc = constrain_grads(jax.tree.map(
+                            lambda a, gi: a + gi.astype(a.dtype), g_acc, g))
+                        m_acc = jax.tree.map(jnp.add, m_acc, m)
                     return (g_acc, m_acc), None
 
                 first = jax.tree.map(lambda x: x[0], micro)
@@ -586,55 +587,59 @@ class EagerEngine(BasicEngine):
                 # back to the params' dtype for the update (a fp32/bf16
                 # carry over fp16-scaled grads must not leak its dtype into
                 # the optimizer chain)
-                grads = jax.tree.map(lambda g, p: (g / accum).astype(p.dtype),
-                                     grads, state.params)
-                metrics = jax.tree.map(lambda m: m / accum, metrics)
+                with device_scope("optimizer"):
+                    grads = jax.tree.map(
+                        lambda g, p: (g / accum).astype(p.dtype), grads,
+                        state.params)
+                    metrics = jax.tree.map(lambda m: m / accum, metrics)
             else:
                 grads, metrics = grads_and_metrics(state.params, state.scaler,
                                                    batch, state.step)
 
             metrics = dict(metrics)
             if lr_schedule is not None:
-                metrics["lr"] = lr_schedule(state.step)
+                with device_scope("optimizer"):
+                    metrics["lr"] = lr_schedule(state.step)
 
             new_params, new_opt, grad_norm = update_fn(
                 state.params, state.opt_state, grads)
             metrics["grad_norm"] = grad_norm
 
             new_scaler = state.scaler
-            new_step = state.step + 1
-            if check_finite:
-                finite = jnp.isfinite(grad_norm) & jnp.isfinite(
-                    metrics["loss"])
-                # skip the update on a non-finite step (fp16 overflow, NaN
-                # loss): revert params/opt to the pre-step values
-                # (reference GradScaler semantics, eager_engine.py:157-164,
-                # extended to every dtype by the resilience guard)
-                new_params = jax.tree.map(
-                    lambda new, old: jnp.where(finite, new, old),
-                    new_params, state.params)
-                new_opt = jax.tree.map(
-                    lambda new, old: jnp.where(finite, new, old) if
-                    getattr(new, "shape", None) == getattr(old, "shape", None)
-                    else new, new_opt, state.opt_state)
-                # a skipped step must not advance the LR schedule /
-                # dropout fold-in
-                new_step = state.step + jnp.where(finite, 1, 0).astype(
-                    state.step.dtype)
-                # host-side guard policy reads this at logging windows
-                metrics["finite"] = finite
-            if use_scaler:
-                # grow/backoff the dynamic loss scale
-                tracker = jnp.where(finite, state.scaler.growth_tracker + 1, 0)
-                grow = tracker >= 1000
-                scale = jnp.where(
-                    finite,
-                    jnp.where(grow, state.scaler.loss_scale * 2.0,
-                              state.scaler.loss_scale),
-                    state.scaler.loss_scale * 0.5)
-                new_scaler = ScalerState(loss_scale=scale,
-                                         growth_tracker=jnp.where(grow, 0, tracker))
-                metrics["loss_scale"] = scale
+            with device_scope("optimizer"):   # step count, finite-step guard
+                new_step = state.step + 1
+                if check_finite:
+                    finite = jnp.isfinite(grad_norm) & jnp.isfinite(
+                        metrics["loss"])
+                    # skip the update on a non-finite step (fp16 overflow, NaN
+                    # loss): revert params/opt to the pre-step values
+                    # (reference GradScaler semantics, eager_engine.py:157-164,
+                    # extended to every dtype by the resilience guard)
+                    new_params = jax.tree.map(
+                        lambda new, old: jnp.where(finite, new, old),
+                        new_params, state.params)
+                    new_opt = jax.tree.map(
+                        lambda new, old: jnp.where(finite, new, old) if
+                        getattr(new, "shape", None) == getattr(old, "shape", None)
+                        else new, new_opt, state.opt_state)
+                    # a skipped step must not advance the LR schedule /
+                    # dropout fold-in
+                    new_step = state.step + jnp.where(finite, 1, 0).astype(
+                        state.step.dtype)
+                    # host-side guard policy reads this at logging windows
+                    metrics["finite"] = finite
+                if use_scaler:
+                    # grow/backoff the dynamic loss scale
+                    tracker = jnp.where(finite, state.scaler.growth_tracker + 1, 0)
+                    grow = tracker >= 1000
+                    scale = jnp.where(
+                        finite,
+                        jnp.where(grow, state.scaler.loss_scale * 2.0,
+                                  state.scaler.loss_scale),
+                        state.scaler.loss_scale * 0.5)
+                    new_scaler = ScalerState(loss_scale=scale,
+                                             growth_tracker=jnp.where(grow, 0, tracker))
+                    metrics["loss_scale"] = scale
 
             # let the host resync its step mirror at logging points (the
             # fp16 scaler and the resilience guard skip step increments on
@@ -673,41 +678,6 @@ class EagerEngine(BasicEngine):
         """Place a host batch onto the mesh, sharded over the data axes."""
         bs = batch_sharding(self.mesh)
         return jax.tree.map(lambda x: jax.device_put(np.asarray(x), bs), batch)
-
-    # ------------------------------------------------- update-phase timing
-    def measure_update_phase(self, iters: int = 3) -> float:
-        """Time the outside-the-scans update path in isolation
-        (docs/zero_sharding.md): global norm + clip + optimizer + apply,
-        jitted with the exact closure ``train_step`` uses (``_update_fn``),
-        on params-shaped synthetic grads. Each run is recorded as an
-        ``optimizer_update`` span/histogram; returns the mean seconds.
-
-        The phase lies inside `outside_scan_ms` (38.9 ms of the 211 ms
-        GPT-345M step; ledger, PR 30) — this measures the optimizer slice
-        of it directly, including the stage-2 reduce-scatter/allgather
-        when ZeRO-2 is on.
-        """
-        assert self.state is not None and self.optimizer is not None, \
-            "call prepare() first"
-        update_fn, constrain_grads = self._update_fn, self._constrain_grads
-
-        def update_only(state: TrainState):
-            grads = constrain_grads(jax.tree.map(jnp.ones_like, state.params))
-            return update_fn(state.params, state.opt_state, grads)
-
-        with self._ctx():
-            fn = jax.jit(update_only,
-                         in_shardings=(self.state_shardings,),
-                         out_shardings=(self.state_shardings.params,
-                                        self.state_shardings.opt_state, None))
-            jax.block_until_ready(fn(self.state))  # compile + warm
-            total = 0.0
-            for _ in range(max(iters, 1)):
-                with self.obs.timed_span("optimizer_update"):
-                    t0 = time.perf_counter()
-                    jax.block_until_ready(fn(self.state))
-                    total += time.perf_counter() - t0
-        return total / max(iters, 1)
 
     # -------------------------------------------------------- SDC sentinel
     def _ensure_sentinel_fns(self):

@@ -36,6 +36,7 @@ from flax import linen as nn
 from flax import struct
 from jax.ad_checkpoint import checkpoint_name
 
+from fleetx_tpu.observability.trace import device_scope
 from fleetx_tpu.utils.log import logger
 
 _NEG_INF_F32 = -1e30  # finite stand-in for -inf (keeps exp/grad NaN-free)
@@ -330,37 +331,42 @@ class MultiHeadAttention(nn.Module):
             "out_bias", param_with_axes(nn.initializers.zeros, ("embed",)),
             (h,), cfg.param_dtype)
 
-        x = x.astype(cfg.dtype)
-        qkv_k = qkv_kernel.astype(cfg.dtype)
-        if cfg.use_qat:
-            # QAT (reference language_module.py:142-144): fake-quant the
-            # matmul operands; per-channel scales over the input dim
-            from fleetx_tpu.ops.quantization import fake_quant
+        with device_scope("attn.proj"):
+            x = x.astype(cfg.dtype)
+            qkv_k = qkv_kernel.astype(cfg.dtype)
+            if cfg.use_qat:
+                # QAT (reference language_module.py:142-144): fake-quant the
+                # matmul operands; per-channel scales over the input dim
+                from fleetx_tpu.ops.quantization import fake_quant
 
-            x = fake_quant(x, cfg.qat_act_bits)
-            qkv_k = fake_quant(qkv_k, cfg.qat_bits, axis=0)
-        qkv = jnp.einsum("bsh,hcnd->bcsnd", x, qkv_k)
-        qkv = qkv + qkv_bias.astype(cfg.dtype)[:, None, :, :]
-        if layer_cache is None:  # decode has no backward — skip the cast
-            qkv = _save_residual(qkv, "res_qkv", cfg)
-        q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]  # [b, s, n, d]
-        q = with_logical(q, ("batch", "act_seq", "act_heads", "act_kv"))
+                x = fake_quant(x, cfg.qat_act_bits)
+                qkv_k = fake_quant(qkv_k, cfg.qat_bits, axis=0)
+            qkv = jnp.einsum("bsh,hcnd->bcsnd", x, qkv_k)
+            qkv = qkv + qkv_bias.astype(cfg.dtype)[:, None, :, :]
+            if layer_cache is None:  # decode has no backward — skip the cast
+                qkv = _save_residual(qkv, "res_qkv", cfg)
+            q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]  # [b, s, n, d]
+            q = with_logical(q, ("batch", "act_seq", "act_heads", "act_kv"))
 
         new_cache = None
         if layer_cache is not None:
             # decode: append this step's k/v at position cache['index'];
             # the key-validity mask keeps left-pad positions masked forever
             idx = layer_cache["index"]
-            step_mask = (attention_mask.astype(bool) if attention_mask is not None
-                         else jnp.ones(x.shape[:2], bool))
-            ck = jax.lax.dynamic_update_slice_in_dim(layer_cache["key"], k, idx, axis=1)
-            cv = jax.lax.dynamic_update_slice_in_dim(layer_cache["value"], v, idx, axis=1)
-            cm = jax.lax.dynamic_update_slice_in_dim(layer_cache["mask"], step_mask,
-                                                     idx, axis=1)
-            # keep the rolling cache TP-sharded over heads through the decode
-            # loop (SURVEY hard-part 5: kv-cache sharding under TP)
-            ck = with_logical(ck, ("batch", None, "act_heads", "act_kv"))
-            cv = with_logical(cv, ("batch", None, "act_heads", "act_kv"))
+            with device_scope("attn.cache"):
+                step_mask = (attention_mask.astype(bool)
+                             if attention_mask is not None
+                             else jnp.ones(x.shape[:2], bool))
+                ck = jax.lax.dynamic_update_slice_in_dim(
+                    layer_cache["key"], k, idx, axis=1)
+                cv = jax.lax.dynamic_update_slice_in_dim(
+                    layer_cache["value"], v, idx, axis=1)
+                cm = jax.lax.dynamic_update_slice_in_dim(
+                    layer_cache["mask"], step_mask, idx, axis=1)
+                # keep the rolling cache TP-sharded over heads through the
+                # decode loop (SURVEY hard-part 5: kv-cache sharding under TP)
+                ck = with_logical(ck, ("batch", None, "act_heads", "act_kv"))
+                cv = with_logical(cv, ("batch", None, "act_heads", "act_kv"))
             new_cache = {"key": ck, "value": cv, "index": idx + x.shape[1],
                          "mask": cm}
             k, v = ck, cv
@@ -370,18 +376,20 @@ class MultiHeadAttention(nn.Module):
         else:
             attn_out = self._core_attn(q, k, v, deterministic)
 
-        out_k = out_kernel.astype(cfg.dtype)
-        if cfg.use_qat:
-            from fleetx_tpu.ops.quantization import fake_quant
+        with device_scope("attn.proj"):
+            out_k = out_kernel.astype(cfg.dtype)
+            if cfg.use_qat:
+                from fleetx_tpu.ops.quantization import fake_quant
 
-            attn_out = fake_quant(attn_out, cfg.qat_act_bits)
-            out_k = fake_quant(out_k, cfg.qat_bits, axis=(0, 1))
-        out = jnp.einsum("bsnd,ndh->bsh", attn_out, out_k)
-        out = out + out_bias.astype(cfg.dtype)
-        if layer_cache is None:
-            out = _save_residual(out, "res_attn_out", cfg)
+                attn_out = fake_quant(attn_out, cfg.qat_act_bits)
+                out_k = fake_quant(out_k, cfg.qat_bits, axis=(0, 1))
+            out = jnp.einsum("bsnd,ndh->bsh", attn_out, out_k)
+            out = out + out_bias.astype(cfg.dtype)
+            if layer_cache is None:
+                out = _save_residual(out, "res_attn_out", cfg)
         return out, new_cache
 
+    @device_scope("attn.core")
     def _core_attn(self, q, k, v, deterministic: bool) -> jax.Array:
         """Causal attention core (reference ``core_attn`` + fused upper-tri
         softmax, ``hybrid_model.py:268-298``)."""
@@ -435,6 +443,7 @@ class MultiHeadAttention(nn.Module):
             fn = jax.checkpoint(fn)
         return fn(q, k, v)
 
+    @device_scope("attn.core")
     def _masked_attn(self, q, k, v, attention_mask, deterministic) -> jax.Array:
         """Causal attention with an explicit key-padding mask (left-padded
         prompts; reference mask handling ``language_module.py:221-243``)."""
@@ -451,6 +460,7 @@ class MultiHeadAttention(nn.Module):
         return jnp.einsum("bnqk,bknd->bqnd", probs, v)
 
     @staticmethod
+    @device_scope("attn.core")
     def _decode_attention(q, k, v, cache_index, key_mask=None) -> jax.Array:
         """Single/few-token decode against the full cache with length masking."""
         scores = jnp.einsum("bqnd,bknd->bnqk", q, k) / jnp.sqrt(q.shape[-1]).astype(q.dtype)
@@ -562,7 +572,8 @@ class TransformerDecoderLayer(nn.Module):
         cfg = self.cfg
         layer_input = x
         residual = x
-        y = LayerNorm(cfg, name="ln1")(x)
+        with device_scope("norm"):
+            y = LayerNorm(cfg, name="ln1")(x)
 
         attn = MultiHeadAttention(cfg, name="attn")
         if cfg.use_recompute and cfg.recompute_granularity == "full_attn" and layer_cache is None:
@@ -583,7 +594,8 @@ class TransformerDecoderLayer(nn.Module):
         # ln2 folds the post-attention residual add: `x = residual + y`
         # rides inside the fused kernel (or the unfused fallback) and comes
         # back as the updated stream alongside the normed MLP input.
-        y, x = LayerNorm(cfg, name="ln2")(y, residual=residual)
+        with device_scope("norm"):
+            y, x = LayerNorm(cfg, name="ln2")(y, residual=residual)
 
         residual = x
         if cfg.moe_num_experts > 0:
@@ -603,11 +615,15 @@ class TransformerDecoderLayer(nn.Module):
         else:
             # decode (layer_cache set) has no backward — skip the residual
             # casts there, mirroring the attention-side gating above
-            y = GPTMlp(cfg, name="mlp")(y, save_residuals=layer_cache is None)
-        if cfg.hidden_dropout_prob > 0.0 and not deterministic:
-            y = nn.Dropout(cfg.hidden_dropout_prob)(y, deterministic=False)
-        x = residual + y
-        x = with_logical(x, ("batch", "act_seq", "act_embed"))
+            with device_scope("mlp"):
+                y = GPTMlp(cfg, name="mlp")(
+                    y, save_residuals=layer_cache is None)
+        with device_scope("mlp"):   # the block's closing residual add
+            if cfg.hidden_dropout_prob > 0.0 and not deterministic:
+                y = nn.Dropout(cfg.hidden_dropout_prob)(y,
+                                                        deterministic=False)
+            x = residual + y
+            x = with_logical(x, ("batch", "act_seq", "act_embed"))
         return x, new_cache
 
 
@@ -626,11 +642,14 @@ class GPTEmbeddings(nn.Module):
         wpe = self.param("position_embeddings",
                          param_with_axes(_dense_init(cfg), (None, "embed")),
                          (cfg.max_position_embeddings, cfg.hidden_size), cfg.param_dtype)
-        x = wte.astype(cfg.dtype)[tokens] + wpe.astype(cfg.dtype)[position_ids]
-        if cfg.hidden_dropout_prob > 0.0 and not deterministic:
-            x = nn.Dropout(cfg.hidden_dropout_prob)(x, deterministic=False)
-        # SP scatter point (reference hybrid_model.py:613-619)
-        return with_logical(x, ("batch", "act_seq", "act_embed"))
+        with device_scope("embed"):
+            x = wte.astype(cfg.dtype)[tokens] \
+                + wpe.astype(cfg.dtype)[position_ids]
+            if cfg.hidden_dropout_prob > 0.0 and not deterministic:
+                x = nn.Dropout(cfg.hidden_dropout_prob)(x,
+                                                        deterministic=False)
+            # SP scatter point (reference hybrid_model.py:613-619)
+            return with_logical(x, ("batch", "act_seq", "act_embed"))
 
 
 class GPTModel(nn.Module):
@@ -693,9 +712,10 @@ class GPTModel(nn.Module):
                 cfg.num_layers // chunks, num_repeats=V,
                 deterministic=deterministic, remat_policy=policy,
                 remat=use_remat)(cfg, name="layers")
-            x = pipeline_apply(stages, x, cfg.pp_degree,
-                               cfg.pp_microbatches or cfg.pp_degree,
-                               deterministic=deterministic, num_repeats=V)
+            with device_scope("stack"):
+                x = pipeline_apply(stages, x, cfg.pp_degree,
+                                   cfg.pp_microbatches or cfg.pp_degree,
+                                   deterministic=deterministic, num_repeats=V)
             new_cache = None
         elif cfg.scan_layers:
             layer_caches = None
@@ -720,7 +740,9 @@ class GPTModel(nn.Module):
                 # on the chip (ROADMAP S10)
                 unroll=max(int(cfg.scan_unroll), 1),
             )(cfg, name="layers")
-            x, new_caches = stack(x, layer_caches, deterministic, attention_mask)
+            with device_scope("stack"):
+                x, new_caches = stack(x, layer_caches, deterministic,
+                                      attention_mask)
             new_cache = None
             if cache is not None:
                 new_cache = DecodeCache(key=new_caches["key"], value=new_caches["value"],
@@ -747,7 +769,8 @@ class GPTModel(nn.Module):
                                         index=cache.index + tokens.shape[1],
                                         mask=new_mask)
 
-        x = LayerNorm(cfg, name="ln_f")(x)
+        with device_scope("head"):
+            x = LayerNorm(cfg, name="ln_f")(x)
         return x, new_cache
 
 
@@ -774,15 +797,19 @@ class GPTForPretraining(nn.Module):
         wte = self.variables["params"]["gpt"]["embeddings"]["word_embeddings"]
         wte = getattr(wte, "unbox", lambda: wte)()
         if self.cfg.vocab_chunk and labels is not None and cache is None:
+            with device_scope("head"):
+                wte = wte.astype(self.cfg.dtype)
             losses = chunked_cross_entropy_per_token(
-                x, wte.astype(self.cfg.dtype), labels,
-                int(self.cfg.vocab_chunk))
-            mask = (jnp.ones_like(losses) if loss_mask is None else loss_mask)
-            return masked_mean(losses, mask)
+                x, wte, labels, int(self.cfg.vocab_chunk))
+            with device_scope("loss"):
+                mask = (jnp.ones_like(losses) if loss_mask is None
+                        else loss_mask)
+                return masked_mean(losses, mask)
         # SP gather point (reference hybrid_model.py:738-740) is implicit in the
         # act_seq→vocab logical re-layout below.
-        logits = jnp.einsum("bsh,vh->bsv", x, wte.astype(self.cfg.dtype))
-        logits = with_logical(logits, ("batch", "act_seq", "act_vocab"))
+        with device_scope("head"):
+            logits = jnp.einsum("bsh,vh->bsv", x, wte.astype(self.cfg.dtype))
+            logits = with_logical(logits, ("batch", "act_seq", "act_vocab"))
         if cache is not None:
             return logits, new_cache
         return logits
@@ -822,29 +849,36 @@ def chunked_cross_entropy_per_token(x: jax.Array, wte: jax.Array,
     chunk = min(-(-base // 128) * 128, cap)
     n_chunks = -(-V // chunk)
     pad = n_chunks * chunk - V
-    wte_p = jnp.pad(wte, ((0, pad), (0, 0))) if pad else wte
-    wte_ch = wte_p.reshape(n_chunks, chunk, wte.shape[1])
+    with device_scope("head"):
+        wte_p = jnp.pad(wte, ((0, pad), (0, 0))) if pad else wte
+        wte_ch = wte_p.reshape(n_chunks, chunk, wte.shape[1])
 
     @jax.checkpoint
     def one_chunk(ci, w):
-        logits = jnp.einsum("bsh,vh->bsv", x, w).astype(jnp.float32)
-        if pad:
-            ids = ci * chunk + jnp.arange(chunk)
-            logits = jnp.where(ids < V, logits, _NEG_INF_F32)
-        m = logits.max(axis=-1)
-        l = jnp.exp(logits - m[..., None]).sum(axis=-1)
-        local = jnp.clip(labels - ci * chunk, 0, chunk - 1)
-        ll = jnp.take_along_axis(logits, local[..., None], axis=-1)[..., 0]
-        in_ch = (labels >= ci * chunk) & (labels < (ci + 1) * chunk)
-        return m, l, jnp.where(in_ch, ll, 0.0)
+        with device_scope("head"):
+            logits = jnp.einsum("bsh,vh->bsv", x, w).astype(jnp.float32)
+        with device_scope("loss"):
+            if pad:
+                ids = ci * chunk + jnp.arange(chunk)
+                logits = jnp.where(ids < V, logits, _NEG_INF_F32)
+            m = logits.max(axis=-1)
+            l = jnp.exp(logits - m[..., None]).sum(axis=-1)
+            local = jnp.clip(labels - ci * chunk, 0, chunk - 1)
+            ll = jnp.take_along_axis(logits, local[..., None],
+                                     axis=-1)[..., 0]
+            in_ch = (labels >= ci * chunk) & (labels < (ci + 1) * chunk)
+            return m, l, jnp.where(in_ch, ll, 0.0)
 
     if n_chunks <= 32:
-        stats = [one_chunk(jnp.int32(ci), wte_ch[ci])
+        with device_scope("head"):
+            chunks = [wte_ch[ci] for ci in range(n_chunks)]
+        stats = [one_chunk(jnp.int32(ci), chunks[ci])
                  for ci in range(n_chunks)]
-        m = functools.reduce(jnp.maximum, [s_[0] for s_ in stats])
-        l = sum(s_[1] * jnp.exp(s_[0] - m) for s_ in stats)
-        lab = sum(s_[2] for s_ in stats)  # label lands in exactly one chunk
-        return m + jnp.log(l) - lab
+        with device_scope("loss"):
+            m = functools.reduce(jnp.maximum, [s_[0] for s_ in stats])
+            l = sum(s_[1] * jnp.exp(s_[0] - m) for s_ in stats)
+            lab = sum(s_[2] for s_ in stats)  # label lands in exactly one chunk
+            return m + jnp.log(l) - lab
 
     def fold(acc, xs):
         m, l, lab = acc
@@ -855,12 +889,13 @@ def chunked_cross_entropy_per_token(x: jax.Array, wte: jax.Array,
         return (m_new, l, lab + clab), None
 
     b, s = labels.shape
-    m0 = jnp.full((b, s), _NEG_INF_F32, jnp.float32)
-    l0 = jnp.zeros((b, s), jnp.float32)
-    lab0 = jnp.zeros((b, s), jnp.float32)
-    (m, l, lab), _ = jax.lax.scan(
-        fold, (m0, l0, lab0), (jnp.arange(n_chunks), wte_ch))
-    return m + jnp.log(l) - lab
+    with device_scope("loss"):
+        m0 = jnp.full((b, s), _NEG_INF_F32, jnp.float32)
+        l0 = jnp.zeros((b, s), jnp.float32)
+        lab0 = jnp.zeros((b, s), jnp.float32)
+        (m, l, lab), _ = jax.lax.scan(
+            fold, (m0, l0, lab0), (jnp.arange(n_chunks), wte_ch))
+        return m + jnp.log(l) - lab
 
 
 def cross_entropy_per_token(logits: jax.Array, labels: jax.Array) -> jax.Array:
@@ -878,6 +913,7 @@ def masked_mean(losses: jax.Array, loss_mask: jax.Array) -> jax.Array:
     return (losses * loss_mask).sum() / jnp.maximum(loss_mask.sum(), 1.0)
 
 
+@device_scope("loss")
 def cross_entropy_loss(logits: jax.Array, labels: jax.Array,
                        loss_mask: jax.Array) -> jax.Array:
     """Masked LM loss (reference ``GPTPretrainingCriterion``,

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from fleetx_tpu.models.mla_moe import moe
 from fleetx_tpu.models.mla_moe.config import MLAMoEConfig
+from fleetx_tpu.observability.trace import device_scope
 from fleetx_tpu.ops import mla_attention
 
 # not in the published config.json; the family's convention
@@ -135,8 +136,10 @@ def _pairs_to_halves(w):
     return jnp.concatenate([w[..., 0::2], w[..., 1::2]], axis=-1)
 
 
+@device_scope("attn.proj")
 def attention(x, p, cfg: MLAMoEConfig, positions):
-    """Latent attention on normed ``x`` [B, S, h]."""
+    """Latent attention on normed ``x`` [B, S, h]: the products, latent
+    norms and rotary are ``attn.proj``, the kernel ``attn.core``."""
     dt, heads = x.dtype, cfg.num_attention_heads
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     b, s, _ = x.shape
@@ -162,26 +165,35 @@ def attention(x, p, cfg: MLAMoEConfig, positions):
     kn = jnp.einsum("bsr,rnd->bnsd", ckv, kv_b[..., :dn])
     v = jnp.einsum("bsr,rnd->bnsd", ckv, kv_b[..., dn:])
     scale = float(cfg.qk_head_dim) ** -0.5
-    if (cfg.use_flash_attention and pack == 2 and dn == dv and
-            mla_attention.supported(qn, qr2, v)):
-        o = mla_attention.mla_flash_attention(qn, qr2, kn, kr, v, scale=scale)
-    else:
-        o = mla_attention.reference_attention(qn, qr2, kn, kr, v, scale=scale)
+    with device_scope("attn.core"):
+        if (cfg.use_flash_attention and pack == 2 and dn == dv and
+                mla_attention.supported(qn, qr2, v)):
+            o = mla_attention.mla_flash_attention(qn, qr2, kn, kr, v,
+                                                  scale=scale)
+        else:
+            o = mla_attention.reference_attention(qn, qr2, kn, kr, v,
+                                                  scale=scale)
     return jnp.einsum("bnsd,ndh->bsh", o, p["out"].astype(dt))
 
 
 def _layer(x, p, cfg: MLAMoEConfig, positions, dense: bool):
     eps = cfg.rms_norm_eps
-    x = x + attention(rms_norm(x, p["attn_norm"]["scale"], eps), p["attn"],
-                      cfg, positions)
-    y = rms_norm(x, p["mlp_norm"]["scale"], eps)
+    with device_scope("norm"):
+        u = rms_norm(x, p["attn_norm"]["scale"], eps)
+    y = attention(u, p["attn"], cfg, positions)
+    with device_scope("norm"):
+        x = x + y
+        y = rms_norm(x, p["mlp_norm"]["scale"], eps)
     if dense:
         dt = x.dtype
         m = p["mlp"]
-        return x + moe.gated_mlp(y, m["gate"].astype(dt), m["up"].astype(dt),
-                                 m["down"].astype(dt)), {}
+        with device_scope("mlp"):
+            return x + moe.gated_mlp(y, m["gate"].astype(dt),
+                                     m["up"].astype(dt),
+                                     m["down"].astype(dt)), {}
     out, stats = moe.moe_layer(y, p["moe"], cfg)
-    return x + out, stats
+    with device_scope("mlp"):
+        return x + out, stats
 
 
 def _stack(x, layers, cfg: MLAMoEConfig, positions, dense: bool):
@@ -189,7 +201,9 @@ def _stack(x, layers, cfg: MLAMoEConfig, positions, dense: bool):
     def body(x, p):
         return _layer(x, p, cfg, positions, dense)
 
-    return jax.lax.scan(jax.checkpoint(body, prevent_cse=False), x, layers)
+    with device_scope("stack"):
+        return jax.lax.scan(jax.checkpoint(body, prevent_cse=False), x,
+                            layers)
 
 
 def lm_loss_sum(x, norm_scale, head, targets, mask, cfg: MLAMoEConfig):
@@ -204,19 +218,23 @@ def lm_loss_sum(x, norm_scale, head, targets, mask, cfg: MLAMoEConfig):
     blocks = (x.reshape(n // rows, rows, h),
               targets.reshape(n // rows, rows),
               mask.astype(jnp.float32).reshape(n // rows, rows))
-    w = head.astype(x.dtype)
+    with device_scope("head"):
+        w = head.astype(x.dtype)
 
     @jax.checkpoint
     def block(total, blk):
         xb, tb, mb = blk
-        y = rms_norm(xb, norm_scale, cfg.rms_norm_eps)
-        logits = jnp.einsum("nh,vh->nv", y, w,
-                            preferred_element_type=jnp.float32)
-        logz = jax.scipy.special.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(logits, tb[:, None], axis=-1)[:, 0]
-        return total + ((logz - picked) * mb).sum(), None
+        with device_scope("head"):
+            y = rms_norm(xb, norm_scale, cfg.rms_norm_eps)
+            logits = jnp.einsum("nh,vh->nv", y, w,
+                                preferred_element_type=jnp.float32)
+        with device_scope("loss"):
+            logz = jax.scipy.special.logsumexp(logits, axis=-1)
+            picked = jnp.take_along_axis(logits, tb[:, None], axis=-1)[:, 0]
+            return total + ((logz - picked) * mb).sum(), None
 
-    total, _ = jax.lax.scan(block, jnp.float32(0.0), blocks)
+    with device_scope("loss"):
+        total, _ = jax.lax.scan(block, jnp.float32(0.0), blocks)
     return total
 
 
@@ -229,7 +247,8 @@ def _positions(tokens, positions=None):
 def hidden_states(params, cfg: MLAMoEConfig, tokens, positions):
     """The main model up to (not through) its final norm, and the expert
     layers' stats."""
-    x = params["embed"]["tokens"].astype(cfg.dtype)[tokens]
+    with device_scope("embed"):
+        x = params["embed"]["tokens"].astype(cfg.dtype)[tokens]
     stats = {}
     if cfg.first_k_dense_replace:
         x, _ = _stack(x, params["dense_layers"], cfg, positions, dense=True)
@@ -242,9 +261,10 @@ def hidden_states(params, cfg: MLAMoEConfig, tokens, positions):
 def logits(params, cfg: MLAMoEConfig, tokens, positions=None):
     """Float32 logits of the main head, [B, S, vocab]."""
     x, _ = hidden_states(params, cfg, tokens, _positions(tokens, positions))
-    y = rms_norm(x, params["final_norm"]["scale"], cfg.rms_norm_eps)
-    return jnp.einsum("bsh,vh->bsv", y, params["head"]["kernel"].astype(
-        cfg.dtype), preferred_element_type=jnp.float32)
+    with device_scope("head"):
+        y = rms_norm(x, params["final_norm"]["scale"], cfg.rms_norm_eps)
+        return jnp.einsum("bsh,vh->bsv", y, params["head"]["kernel"].astype(
+            cfg.dtype), preferred_element_type=jnp.float32)
 
 
 def _mtp_hidden(params, cfg: MLAMoEConfig, x, next_tokens, positions):
@@ -252,12 +272,14 @@ def _mtp_hidden(params, cfg: MLAMoEConfig, x, next_tokens, positions):
     (first) with the normed hidden state, project back to the hidden size
     and run one expert block. Returns its state and the block's stats."""
     m, eps = params["mtp"], cfg.rms_norm_eps
-    emb = params["embed"]["tokens"].astype(cfg.dtype)[next_tokens]
-    joined = jnp.concatenate([
-        rms_norm(emb, m["embed_norm"]["scale"], eps),
-        rms_norm(x, m["hidden_norm"]["scale"], eps)], axis=-1)
-    x = jnp.einsum("bsk,kh->bsh", joined, m["proj"].astype(cfg.dtype))
-    return _stack(x, m["layers"], cfg, positions, dense=False)
+    with device_scope("mtp"):
+        with device_scope("embed"):
+            emb = params["embed"]["tokens"].astype(cfg.dtype)[next_tokens]
+        joined = jnp.concatenate([
+            rms_norm(emb, m["embed_norm"]["scale"], eps),
+            rms_norm(x, m["hidden_norm"]["scale"], eps)], axis=-1)
+        x = jnp.einsum("bsk,kh->bsh", joined, m["proj"].astype(cfg.dtype))
+        return _stack(x, m["layers"], cfg, positions, dense=False)
 
 
 def training_loss(params, cfg: MLAMoEConfig, batch: dict):
@@ -269,28 +291,34 @@ def training_loss(params, cfg: MLAMoEConfig, batch: dict):
     positions = _positions(tokens, batch.get("position_ids"))
     x, stats = hidden_states(params, cfg, tokens, positions)
     head = params["head"]["kernel"]
-    main = lm_loss_sum(x, params["final_norm"]["scale"], head, labels, mask,
-                       cfg) / jnp.maximum(mask.sum(), 1.0)
+    with device_scope("loss"):
+        main = lm_loss_sum(x, params["final_norm"]["scale"], head, labels,
+                           mask, cfg) / jnp.maximum(mask.sum(), 1.0)
     loss, metrics = main, {"loss_main": main}
     stats_all = [stats] if stats else []
     if cfg.num_nextn_predict_layers:
         xm, mtp_stats = _mtp_hidden(params, cfg, x, labels, positions)
         stats_all.append(mtp_stats)
         # position i holds token i + 1 (its label) and predicts token i + 2
-        target = jnp.roll(labels, -1, axis=1)
-        tmask = jnp.roll(mask, -1, axis=1).at[:, -1].set(0.0)
-        mtp = lm_loss_sum(xm, params["mtp"]["final_norm"]["scale"], head,
-                          target, tmask, cfg) / jnp.maximum(tmask.sum(), 1.0)
-        loss = loss + cfg.mtp_loss_weight * mtp
+        with device_scope("loss"):
+            target = jnp.roll(labels, -1, axis=1)
+            tmask = jnp.roll(mask, -1, axis=1).at[:, -1].set(0.0)
+            mtp = lm_loss_sum(xm, params["mtp"]["final_norm"]["scale"], head,
+                              target, tmask, cfg) \
+                / jnp.maximum(tmask.sum(), 1.0)
+            loss = loss + cfg.mtp_loss_weight * mtp
         metrics["loss_mtp"] = mtp
     if stats_all:
-        stats = jax.tree.map(lambda *a: jnp.concatenate(a), *stats_all)
-        # value zero; its cotangent moves the selection biases by the load
-        loss = loss + stats["bias_step"].sum()
-        metrics["moe_load_max_over_mean"] = stats["rows_max_over_mean"].max()
-        metrics["moe_load_max_over_mean_by_layer"] = \
-            stats["rows_max_over_mean"]
-        metrics["moe_held_share"] = stats["held_share"].mean()
-        metrics["moe_bias_abs_max"] = stats["bias_abs_max"].max()
+        with device_scope("moe.route"):     # the load-bias step, counters
+            stats = jax.tree.map(lambda *a: jnp.concatenate(a), *stats_all)
+            # value zero; its cotangent moves the selection biases by the
+            # load
+            loss = loss + stats["bias_step"].sum()
+            metrics["moe_load_max_over_mean"] = \
+                stats["rows_max_over_mean"].max()
+            metrics["moe_load_max_over_mean_by_layer"] = \
+                stats["rows_max_over_mean"]
+            metrics["moe_held_share"] = stats["held_share"].mean()
+            metrics["moe_bias_abs_max"] = stats["bias_abs_max"].max()
     metrics["loss"] = loss
     return loss, metrics

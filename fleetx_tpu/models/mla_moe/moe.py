@@ -38,10 +38,12 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from fleetx_tpu.observability.trace import device_scope
 from fleetx_tpu.ops import grouped_matmul
 
 
 # ------------------------------------------------------------------ routing
+@device_scope("moe.route")
 def route(x2d: jax.Array, router: jax.Array, bias: jax.Array, top_k: int,
           scaling: float, normalise: bool):
     """``x2d`` [N, h] -> (expert ids [N, k], weights [N, k] float32, load
@@ -89,6 +91,7 @@ def buffer_rows(n_pairs_max: int, held: int, tile: int, chunk: int) -> int:
     return -(-rows // chunk) * chunk
 
 
+@device_scope("moe.route")
 def plan_rows(ids: jax.Array, first: int, held: int, tile: int,
               chunk: int) -> dict:
     """Where each held (token, expert) pair goes in the sorted buffer.
@@ -133,6 +136,7 @@ def plan_rows(ids: jax.Array, first: int, held: int, tile: int,
 
 
 # ---------------------------------------------------------- grouped products
+@device_scope("moe.route")
 def pass_inputs(c, x, w_flat, plan, k, chunk, tile):
     """What pass ``c`` works on: its rows' tokens, weights (zero on padding
     rows), each tile's expert, the tiles that hold rows, the gathered rows."""
@@ -146,6 +150,7 @@ def pass_inputs(c, x, w_flat, plan, k, chunk, tile):
     return tok, w_flat[pair] * valid, valid, experts, n_tiles, x[tok]
 
 
+@device_scope("moe.experts")
 def _gated(xs, gate_up, down, experts, n_tiles, tile):
     """The pass's gated MLP: ``(gu, a, o)`` with ``gu`` the gate and up
     products side by side, ``a = silu(gate) * up``, ``o = a @ down``."""
@@ -172,11 +177,13 @@ def _grouped_fwd(x, w_flat, gate_up, down, plan, k, chunk, tile):
         tok, wt, _, experts, n_tiles, xs = pass_inputs(
             c, x, w_flat, plan, k, chunk, tile)
         o = _gated(xs, gate_up, down, experts, n_tiles, tile)[2]
-        return c + 1, y.at[tok].add(o * wt[:, None])
+        with device_scope("moe.route"):
+            return c + 1, y.at[tok].add(o * wt[:, None])
 
-    _, y = jax.lax.while_loop(
-        lambda s: s[0] < plan["n_passes"], body,
-        (jnp.int32(0), jnp.zeros(x.shape, jnp.float32)))
+    with device_scope("moe.experts"):
+        _, y = jax.lax.while_loop(
+            lambda s: s[0] < plan["n_passes"], body,
+            (jnp.int32(0), jnp.zeros(x.shape, jnp.float32)))
     return y, (x, w_flat, gate_up, down, plan)
 
 
@@ -190,35 +197,44 @@ def _grouped_bwd(k, chunk, tile, residuals, dy):
         tok, wt, valid, experts, n_tiles, xs = pass_inputs(
             c, x, w_flat, plan, k, chunk, tile)
         gu, a, o = _gated(xs, gate_up, down, experts, n_tiles, tile)
-        dyc = dy[tok]
-        d_wt = (o * dyc).sum(-1) * valid
-        do = (dyc * wt[:, None]).astype(dt)
-        da = grouped_matmul.moe_gmm(do, down, experts, n_tiles, tile=tile,
-                                    transpose_rhs=True,
-                                    out_dtype=jnp.float32)
-        g, u = jnp.split(gu, 2, axis=-1)
-        sig = jax.nn.sigmoid(g)
-        dgu = jnp.concatenate(
-            [da * u * sig * (1.0 + g * (1.0 - sig)), da * g * sig],
-            axis=-1).astype(dt)
-        dxs = grouped_matmul.moe_gmm(dgu, gate_up, experts, n_tiles,
-                                     tile=tile, transpose_rhs=True,
-                                     out_dtype=jnp.float32)
-        return (c + 1, dx.at[tok].add(dxs),
-                jax.lax.dynamic_update_slice(d_row_w, d_wt, (c * chunk,)),
-                grouped_matmul.moe_tgmm(xs, dgu, d_gate_up, experts, n_tiles,
-                                        tile=tile),
-                grouped_matmul.moe_tgmm(a, do, d_down, experts, n_tiles,
-                                        tile=tile))
+        with device_scope("moe.route"):
+            dyc = dy[tok]
+            d_wt = (o * dyc).sum(-1) * valid
+            do = (dyc * wt[:, None]).astype(dt)
+        with device_scope("moe.experts"):
+            da = grouped_matmul.moe_gmm(do, down, experts, n_tiles,
+                                        tile=tile, transpose_rhs=True,
+                                        out_dtype=jnp.float32)
+            g, u = jnp.split(gu, 2, axis=-1)
+            sig = jax.nn.sigmoid(g)
+            dgu = jnp.concatenate(
+                [da * u * sig * (1.0 + g * (1.0 - sig)), da * g * sig],
+                axis=-1).astype(dt)
+            dxs = grouped_matmul.moe_gmm(dgu, gate_up, experts, n_tiles,
+                                         tile=tile, transpose_rhs=True,
+                                         out_dtype=jnp.float32)
+            d_gate_up = grouped_matmul.moe_tgmm(xs, dgu, d_gate_up, experts,
+                                                n_tiles, tile=tile)
+            d_down = grouped_matmul.moe_tgmm(a, do, d_down, experts, n_tiles,
+                                             tile=tile)
+        with device_scope("moe.route"):
+            return (c + 1, dx.at[tok].add(dxs),
+                    jax.lax.dynamic_update_slice(d_row_w, d_wt,
+                                                 (c * chunk,)),
+                    d_gate_up, d_down)
 
     zeros32 = functools.partial(jnp.zeros_like, dtype=jnp.float32)
-    _, dx, d_row_w, d_gate_up, d_down = jax.lax.while_loop(
-        lambda s: s[0] < plan["n_passes"], body,
-        (jnp.int32(0), zeros32(x), jnp.zeros((rows,), jnp.float32),
-         zeros32(gate_up), zeros32(down)))
-    d_w = jnp.where(plan["pair_held"], d_row_w[plan["pair_row"]], 0.0)
-    return (dx.astype(dt), d_w.astype(w_flat.dtype),
-            d_gate_up.astype(gate_up.dtype), d_down.astype(down.dtype), None)
+    with device_scope("moe.experts"):
+        _, dx, d_row_w, d_gate_up, d_down = jax.lax.while_loop(
+            lambda s: s[0] < plan["n_passes"], body,
+            (jnp.int32(0), zeros32(x), jnp.zeros((rows,), jnp.float32),
+             zeros32(gate_up), zeros32(down)))
+        d_gate_up = d_gate_up.astype(gate_up.dtype)
+        d_down = d_down.astype(down.dtype)
+    with device_scope("moe.route"):
+        d_w = jnp.where(plan["pair_held"], d_row_w[plan["pair_row"]], 0.0)
+        return (dx.astype(dt), d_w.astype(w_flat.dtype), d_gate_up, d_down,
+                None)
 
 
 grouped_experts.defvjp(_grouped_fwd, _grouped_bwd)
@@ -242,26 +258,30 @@ def moe_layer(x: jax.Array, p: dict, cfg) -> tuple:
     x2d = x.reshape(b * s, h)
     k, held = cfg.num_experts_per_tok, cfg.experts_held
     first = cfg.first_expert_held
-    ids, weights, load = route(x2d, p["router"], p["selection_bias"], k,
-                               cfg.routed_scaling_factor, cfg.norm_topk_prob)
     chunk = min(cfg.moe_chunk_rows,
                 -(-(b * s * min(k, held)) // cfg.moe_tile_rows)
                 * cfg.moe_tile_rows)
+    ids, weights, load = route(x2d, p["router"], p["selection_bias"], k,
+                               cfg.routed_scaling_factor, cfg.norm_topk_prob)
     plan = plan_rows(ids, first, held, cfg.moe_tile_rows, chunk)
-    gate_up = jnp.concatenate([p["experts_gate"], p["experts_up"]],
-                              axis=-1).astype(dt)
-    routed = grouped_experts(
-        x2d, weights.reshape(-1).astype(jnp.float32), gate_up,
-        p["experts_down"].astype(dt), plan, k, chunk, cfg.moe_tile_rows)
-    shared = gated_mlp(x2d, p["shared_gate"].astype(dt),
-                       p["shared_up"].astype(dt), p["shared_down"].astype(dt))
-    y = (routed + shared.astype(jnp.float32)).astype(dt).reshape(b, s, h)
-    rows = plan["rows_held"].astype(jnp.float32)
-    stats = {
-        "bias_step": load_as_cotangent(p["selection_bias"],
-                                       load - load.mean()),
-        "rows_max_over_mean": rows.max() / jnp.maximum(rows.mean(), 1.0),
-        "held_share": rows.sum() / (b * s * k),
-        "bias_abs_max": jnp.abs(p["selection_bias"]).max(),
-    }
+    with device_scope("moe.experts"):
+        gate_up = jnp.concatenate([p["experts_gate"], p["experts_up"]],
+                                  axis=-1).astype(dt)
+        routed = grouped_experts(
+            x2d, weights.reshape(-1).astype(jnp.float32), gate_up,
+            p["experts_down"].astype(dt), plan, k, chunk, cfg.moe_tile_rows)
+    with device_scope("mlp"):
+        shared = gated_mlp(x2d, p["shared_gate"].astype(dt),
+                           p["shared_up"].astype(dt),
+                           p["shared_down"].astype(dt))
+        y = (routed + shared.astype(jnp.float32)).astype(dt).reshape(b, s, h)
+    with device_scope("moe.route"):
+        rows = plan["rows_held"].astype(jnp.float32)
+        stats = {
+            "bias_step": load_as_cotangent(p["selection_bias"],
+                                           load - load.mean()),
+            "rows_max_over_mean": rows.max() / jnp.maximum(rows.mean(), 1.0),
+            "held_share": rows.sum() / (b * s * k),
+            "bias_abs_max": jnp.abs(p["selection_bias"]).max(),
+        }
     return y, stats

@@ -46,6 +46,7 @@ import numpy as np
 
 from fleetx_tpu.models.mla_moe import moe as held_share
 from fleetx_tpu.models.swa_moe.config import SWAMoEConfig
+from fleetx_tpu.observability.trace import device_scope
 from fleetx_tpu.ops import grouped_matmul
 
 #: leaves kept in float32 whatever ``cfg.dtype`` is: the norms' scales
@@ -212,6 +213,7 @@ def apply_rotary(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
                            axis=-1).astype(x.dtype)
 
 
+@device_scope("moe.route")
 def route(u2d: jax.Array, router: jax.Array, cfg: SWAMoEConfig) -> tuple:
     """``u2d`` [N, h] -> (expert ids [N, k], weights [N, k] float32), the
     logits in float32. ``softmax_topk``: a softmax over all ``num_experts``,
@@ -295,11 +297,13 @@ def held_experts(u2d: jax.Array, ids: jax.Array, weights: jax.Array,
         a = (act(gmm(xs, "experts_gate", experts, n_tiles))
              * gmm(xs, "experts_up", experts, n_tiles)).astype(u2d.dtype)
         o = gmm(a, "experts_down", experts, n_tiles)
-        return c + 1, y.at[tok].add(o * wt[:, None])
+        with device_scope("moe.route"):
+            return c + 1, y.at[tok].add(o * wt[:, None])
 
-    _, y = jax.lax.while_loop(
-        lambda s: s[0] < plan["n_passes"], body,
-        (jnp.int32(0), jnp.zeros(u2d.shape, jnp.float32)))
+    with device_scope("moe.experts"):
+        _, y = jax.lax.while_loop(
+            lambda s: s[0] < plan["n_passes"], body,
+            (jnp.int32(0), jnp.zeros(u2d.shape, jnp.float32)))
     return y, plan["rows_held"], plan["n_passes"]
 
 

@@ -24,8 +24,10 @@ multi-host traces merge cleanly.
 from __future__ import annotations
 
 import functools
+import glob
 import json
 import os
+import re
 import threading
 import time
 from typing import Any, Optional
@@ -171,6 +173,231 @@ HOT_LOOP_SPANS: dict = {
 }
 
 
+#: Every scope the device programs open — the train step and the two serving
+#: programs of every family — with what it covers. ``device_scope(name)``
+#: opens ``jax.named_scope("fx.<name>")``; a scope is opened where the work
+#: is written and the innermost one an instruction lies in is its scope, so
+#: a kernel wrapper in ``ops/`` opens none (its caller's holds it). The
+#: engines and models open no ``fx.`` scope outside this table (tests hold
+#: them to it) and the benchmark's readers (``benchmarks/program_scopes.py``)
+#: put a trace's device time under these names through
+#: ``compiled_programs()``.
+DEVICE_SCOPES: dict = {
+    "embed": "token (and position) embedding look-up; its backward is the "
+             "embedding gradient's scatter-add",
+    "norm": "a layer's pre-norms (layer norm or RMS norm, fused or not) "
+            "and the residual add a fused norm holds",
+    "attn.proj": "query / key / value / latent / output products, their "
+                 "biases, the latents' norms, rotary, gates",
+    "attn.core": "the flash, latent-flash or paged kernel, or the XLA "
+                 "scores where no kernel runs (a prefill's folded key "
+                 "blocks, a ring scored whole, the gathered page view)",
+    "attn.cache": "the pool's row scatter, a ring's write, a dense decode "
+                  "cache's update",
+    "mlp": "dense MLP, shared expert, and the block's closing residual add",
+    "moe.route": "router product and scoring, top-k, plan_rows' sort and "
+                 "search, the gathers into expert order, the combine's "
+                 "scatter-add, the load-bias step",
+    "moe.experts": "the grouped products over the held experts and the "
+                   "activation between them",
+    "stack": "what a layer loop does around its layers: slices of the "
+             "stacked weights, writes and reads of the activations saved "
+             "for the backward, the stacked gradients, the loop's counters",
+    "head": "final norm and the logits product",
+    "loss": "log-sum-exp, the label's logit, the masked mean",
+    "mtp": "the multi-token-prediction module's own work: the join of "
+           "the next token's embedding with the hidden state, and its "
+           "projection",
+    "sample": "the fresh row's merge, argmax or the sampling chain",
+    "optimizer": "global norm, clip, AdamW, apply; the ZeRO gather of the "
+                 "parameters and scatter of the gradients; loss-scale and "
+                 "finite-step bookkeeping",
+}
+
+SCOPE_PREFIX = "fx."
+_FX = re.compile(re.escape(SCOPE_PREFIX) + r"([a-z]+(?:\.[a-z]+)*)")
+DIRECTIONS = ("fwd", "bwd", "remat")
+UNSCOPED = ("", "")
+
+
+def device_scope(name: str):
+    """``with device_scope("attn.core"): ...`` — the one place the package
+    opens a ``jax.named_scope``: ``fx.<name>`` on every instruction traced
+    inside, for ``device_scope_table`` to find. Metadata only: the compiled
+    program is the one it would be without."""
+    if name not in DEVICE_SCOPES:
+        raise KeyError(f"{name!r} is not in DEVICE_SCOPES")
+    import jax
+
+    return jax.named_scope(SCOPE_PREFIX + name)
+
+
+_COMPUTATION = re.compile(r"^(ENTRY )?%?([^\s(]+) \(.*\{$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?(\S+) = ")
+_OPCODE = re.compile(r" ([a-z][a-z0-9\-]*)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_REFERS_TO = re.compile(r"%([^\s,(){}]+)")
+_CALLED = re.compile(
+    r"(?:body|condition|to_apply|calls|true_computation|false_computation)"
+    r"=%?([^\s,)}]+)|branch_computations=\{([^}]*)\}")
+#: opcodes whose called computations run as instructions of their own on
+#: the device's timeline (a fusion's or a reduce's do not)
+_STEPS_INTO = frozenset({"while", "conditional", "call", "async-start"})
+
+
+def scope_of(op_name: str) -> tuple:
+    """``(scope, direction)`` of one ``op_name``: the innermost ``fx.``
+    component; ``remat`` under ``rematted_computation`` (whatever else
+    surrounds it), ``bwd`` under ``transpose(``, else ``fwd``. Of several
+    names joined by ``;`` (instructions the compiler merged) the first that
+    has a scope. ``("", "")`` without one."""
+    for part in op_name.split(";"):
+        found = _FX.findall(part)
+        if found:
+            return found[-1], ("remat" if "rematted_computation" in part
+                               else "bwd" if "transpose(" in part else "fwd")
+    return UNSCOPED
+
+
+def hlo_instructions(hlo_text: str):
+    """``(name, opcode, op_name, operands)`` of every instruction of an
+    optimised program's text (``compiled.as_text()``) that runs on the
+    device's timeline as an instruction of its own: the entry computation and the
+    ``while`` / ``conditional`` / ``call`` bodies under it — what a device
+    trace's ``XLA Ops`` line shows — not the insides of a fusion or a
+    reducer. Names as the trace spells them, without ``%``; ``op_name`` is
+    the instruction's own (a fusion's is its root's), ``""`` without one;
+    ``operands`` the ``%`` names its text refers to."""
+    computations: dict = {}
+    entry, current = None, None
+    for line in hlo_text.splitlines():
+        if current is None:
+            m = _COMPUTATION.match(line)
+            if m:
+                current = computations.setdefault(m.group(2), [])
+                if m.group(1):
+                    entry = m.group(2)
+        elif line.startswith("}"):
+            current = None
+        elif _INSTRUCTION.match(line):
+            current.append(line)
+    todo, seen = [entry], {entry}
+    while todo:
+        for line in computations.get(todo.pop(), ()):
+            name = _INSTRUCTION.match(line).group(1)
+            rest = line.split(" = ", 1)[1]
+            found = _OPCODE.search(" " + rest)
+            opcode = found.group(1) if found else ""
+            op_name = _OP_NAME.search(rest)
+            yield (name, opcode, op_name.group(1) if op_name else "",
+                   _REFERS_TO.findall(rest))
+            if opcode not in _STEPS_INTO:
+                continue
+            for one, many in _CALLED.findall(rest):
+                for called in [one] if one else re.findall(r"%?([^\s,]+)",
+                                                           many):
+                    if called not in seen:
+                        seen.add(called)
+                        todo.append(called)
+
+
+def device_scope_table(hlo_text: str) -> dict:
+    """``{instruction: (scope, direction)}`` for an optimised program's
+    text: every instruction ``hlo_instructions`` yields, by ``scope_of``
+    its ``op_name``. An instruction WITHOUT a name of the program's (no
+    ``op_name``, or one that is no path: a parameter's, a compiler pass's
+    own) is one the compiler made for another — a prefetch's ``copy-start``
+    / ``copy-done``, a relayout's ``copy``, a hoisted ``convert``, the
+    window sums a ``cumsum`` becomes — and goes where the work it serves
+    goes: to the first scope down its chain of users (through further
+    unnamed ones); else up its operands, to the first that has a scope or,
+    unnamed itself, serves one (a prefetch for the loop's next turn hangs
+    on the value the loop carries) — not through a ``parameter`` or a
+    ``tuple``, which everything a loop carries shares; ``("", "")`` when
+    there is none."""
+    rows = list(hlo_instructions(hlo_text))
+    # the program's own names are paths (``jit(step)/…``); a name without
+    # a ``/`` is a parameter's or one a compiler pass made up
+    own = {name: scope_of(op_name) if "/" in op_name else None
+           for name, _, op_name, _ in rows}
+    shared = {name for name, opcode, _, _ in rows
+              if opcode in ("parameter", "tuple")}
+    operands = {name: [o for o in refs if o in own]
+                for name, _, _, refs in rows}
+    users: dict = {}
+    for name, made_from in operands.items():
+        for o in made_from:
+            users.setdefault(o, []).append(name)
+
+    def serves(name: str, seen: set) -> tuple:
+        """The first scope down the chain of users, depth first."""
+        for user in users.get(name, ()):
+            if user in seen:
+                continue
+            seen.add(user)
+            got = own[user] if own[user] is not None else serves(user, seen)
+            if got != UNSCOPED:
+                return got
+        return UNSCOPED
+
+    def nearest(name: str) -> tuple:
+        got = serves(name, {name})
+        seen, todo = {name}, [name]
+        while got == UNSCOPED and todo:
+            for o in operands[todo.pop(0)]:
+                if o in seen:
+                    continue
+                seen.add(o)
+                if own[o] is not None:
+                    got = own[o]
+                elif o not in shared:
+                    got = serves(o, {o, name})
+                if got != UNSCOPED:
+                    break
+                if own[o] is None:
+                    todo.append(o)
+        return got
+
+    return {name: nearest(name) if got is None else got
+            for name, got in own.items()}
+
+
+#: module name as a device trace prints it (``jit_train_step``) -> the
+#: optimised HLO modules of the newest program of that name ``log_compile``
+#: compiled (host objects: no device buffer, no executable), or, once
+#: ``compiled_programs`` was asked, its table
+_programs: dict = {}
+_programs_lock = threading.Lock()
+
+
+def keep_compiled(hlo_modules: list) -> None:
+    """Remember a compiled program's optimised HLO modules
+    (``compiled.runtime_executable().hlo_modules()``) under their name.
+    Nothing is printed or parsed here: ``compiled_programs`` does that when
+    asked. The newest program of a name replaces the older."""
+    if hlo_modules:
+        with _programs_lock:
+            _programs[hlo_modules[0].name] = list(hlo_modules)
+
+
+def compiled_programs() -> dict:
+    """``{module: {instruction: (scope, direction)}}`` for every program
+    ``utils.env.log_compile`` compiled in this process, newest of each
+    name. The text is printed and parsed on the first request and only the
+    table kept. It is the executable's OWN metadata: a program loaded from
+    the persistent compile cache says what it was compiled with, so table
+    and trace always agree with each other."""
+    with _programs_lock:
+        for name, kept in list(_programs.items()):
+            if isinstance(kept, list):
+                table: dict = {}
+                for module in kept:
+                    table.update(device_scope_table(module.to_string()))
+                _programs[name] = table
+        return dict(_programs)
+
+
+
 class span:
     """``with span("train_step", step=3): ...`` or ``@span("load")``.
 
@@ -305,3 +532,23 @@ class ProfilerWindow:
         self._active = False
         self._done = True
         logger.info("profiler trace written to %s", self.output_dir)
+        self._write_device_scopes()
+
+    def _write_device_scopes(self) -> None:
+        """``device_scopes.json`` beside the ``.xplane.pb`` just closed:
+        ``{module: {instruction: [scope, direction]}}`` from
+        ``compiled_programs()``, to join to the trace's ``XLA Ops`` line
+        (docs/profiler.md). Nothing where nothing was compiled through
+        ``log_compile`` or no trace file is found."""
+        written = glob.glob(os.path.join(self.output_dir, "plugins",
+                                         "profile", "*", "*.xplane.pb"))
+        programs = compiled_programs()
+        if not written or not programs:
+            return
+        path = os.path.join(
+            os.path.dirname(max(written, key=os.path.getmtime)),
+            "device_scopes.json")
+        with open(path, "w") as f:
+            json.dump(programs, f)
+        logger.info("device scopes of %d programs written to %s",
+                    len(programs), path)

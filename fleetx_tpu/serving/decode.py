@@ -99,6 +99,7 @@ import jax
 import jax.numpy as jnp
 
 from fleetx_tpu.models.gpt import generation as G
+from fleetx_tpu.observability.trace import device_scope
 from fleetx_tpu.ops import paged_attention as PA
 
 
@@ -255,16 +256,18 @@ def _forward(params: Any, cfg: Any, tokens: jax.Array, positions: jax.Array,
     emb = gpt["embeddings"]
 
     wte, wpe = emb["word_embeddings"], emb["position_embeddings"]
-    safe_pos = jnp.clip(positions, 0, cfg.max_position_embeddings - 1)
-    x = wte[tokens] + wpe[safe_pos]
+    with device_scope("embed"):
+        safe_pos = jnp.clip(positions, 0, cfg.max_position_embeddings - 1)
+        x = wte[tokens] + wpe[safe_pos]
 
     # scatter targets, shared by every layer: page id + in-page offset per
     # (row, slot). Negative positions mark invalid slots → null page 0.
-    page_slot = jnp.clip(positions // ps, 0, block_tables.shape[1] - 1)
-    pages = jnp.take_along_axis(block_tables, page_slot, axis=1)
-    pages = jnp.where(positions >= 0, pages, 0)
-    offs = jnp.clip(positions % ps, 0, ps - 1)
-    q_pos = jnp.maximum(positions, 0)
+    with device_scope("attn.cache"):
+        page_slot = jnp.clip(positions // ps, 0, block_tables.shape[1] - 1)
+        pages = jnp.take_along_axis(block_tables, page_slot, axis=1)
+        pages = jnp.where(positions >= 0, pages, 0)
+        offs = jnp.clip(positions % ps, 0, ps - 1)
+        q_pos = jnp.maximum(positions, 0)
 
     nh, hd = cfg.num_attention_heads, cfg.head_dim
     act_bits, w_bits = cfg.qat_act_bits, cfg.qat_bits
@@ -273,65 +276,76 @@ def _forward(params: Any, cfg: Any, tokens: jax.Array, positions: jax.Array,
         x, pool_k, pool_v = carry
         lp, l = scanned
         residual = x
-        y = _layer_norm(lp["ln1"], x, cfg)
+        with device_scope("norm"):
+            y = _layer_norm(lp["ln1"], x, cfg)
 
-        y_in = _quant(y, act_bits, quantize)
-        qkv_k = _quant(lp["attn"]["qkv_kernel"], w_bits, quantize, axis=0)
-        qkv = jnp.einsum("bsh,hcnd->bcsnd", y_in, qkv_k)
-        qkv = qkv + lp["attn"]["qkv_bias"][:, None]
-        q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]          # [B, S, nh, hd]
+        with device_scope("attn.proj"):
+            y_in = _quant(y, act_bits, quantize)
+            qkv_k = _quant(lp["attn"]["qkv_kernel"], w_bits, quantize, axis=0)
+            qkv = jnp.einsum("bsh,hcnd->bcsnd", y_in, qkv_k)
+            qkv = qkv + lp["attn"]["qkv_bias"][:, None]
+            q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]      # [B, S, nh, hd]
 
         # in place on the carried pool: a [B, S] scatter of nh·hd-wide rows
-        pool_k = pool_k.at[l, pages, offs].set(k.reshape(B, S, nh * hd))
-        pool_v = pool_v.at[l, pages, offs].set(v.reshape(B, S, nh * hd))
-        if paged_kernel and S == 1:
-            # in-kernel block-table walk (ops/paged_attention.py): layer
-            # l of the pool is read page-by-page via scalar-prefetched
-            # ids — neither the layer nor the dense [B,
-            # pages_per_req·page_size, nh, hd] view is ever materialised.
-            # positions[:, 0] is each row's query position (< 0 =
-            # inactive slot → all pages masked, exact-zero out).
-            attn = PA.paged_attention_sharded(
-                q[:, 0], pool_k, pool_v, block_tables, positions[:, 0], l,
-                mesh=mesh)[:, None]
-        else:
-            # one gather, the layer folded into its indices
-            kd = pool_k[l, block_tables].reshape(B, -1, nh, hd)
-            vd = pool_v[l, block_tables].reshape(B, -1, nh, hd)
-            attn = _paged_attention(q, kd, vd, q_pos)
+        with device_scope("attn.cache"):
+            pool_k = pool_k.at[l, pages, offs].set(k.reshape(B, S, nh * hd))
+            pool_v = pool_v.at[l, pages, offs].set(v.reshape(B, S, nh * hd))
+        with device_scope("attn.core"):
+            if paged_kernel and S == 1:
+                # in-kernel block-table walk (ops/paged_attention.py): layer
+                # l of the pool is read page-by-page via scalar-prefetched
+                # ids — neither the layer nor the dense [B,
+                # pages_per_req·page_size, nh, hd] view is ever
+                # materialised. positions[:, 0] is each row's query position
+                # (< 0 = inactive slot → all pages masked, exact-zero out).
+                attn = PA.paged_attention_sharded(
+                    q[:, 0], pool_k, pool_v, block_tables, positions[:, 0],
+                    l, mesh=mesh)[:, None]
+            else:
+                # one gather, the layer folded into its indices
+                kd = pool_k[l, block_tables].reshape(B, -1, nh, hd)
+                vd = pool_v[l, block_tables].reshape(B, -1, nh, hd)
+                attn = _paged_attention(q, kd, vd, q_pos)
 
-        attn = _quant(attn, act_bits, quantize)
-        out_k = _quant(lp["attn"]["out_kernel"], w_bits, quantize,
-                       axis=(0, 1))
-        y = jnp.einsum("bsnd,ndh->bsh", attn, out_k)
-        y = y + lp["attn"]["out_bias"]
-        x = residual + y
+        with device_scope("attn.proj"):
+            attn = _quant(attn, act_bits, quantize)
+            out_k = _quant(lp["attn"]["out_kernel"], w_bits, quantize,
+                           axis=(0, 1))
+            y = jnp.einsum("bsnd,ndh->bsh", attn, out_k)
+            y = y + lp["attn"]["out_bias"]
 
-        residual = x
-        y = _layer_norm(lp["ln2"], x, cfg)
-        y_in = _quant(y, act_bits, quantize)
-        wi = _quant(lp["mlp"]["wi_kernel"], w_bits, quantize, axis=0)
-        y = jnp.einsum("bsh,hm->bsm", y_in, wi) + lp["mlp"]["wi_bias"]
-        y = jax.nn.gelu(y, approximate=True)
-        y = _quant(y, act_bits, quantize)
-        wo = _quant(lp["mlp"]["wo_kernel"], w_bits, quantize, axis=0)
-        y = jnp.einsum("bsm,mh->bsh", y, wo) + lp["mlp"]["wo_bias"]
-        x = residual + y
+        with device_scope("norm"):
+            x = residual + y
+            residual = x
+            y = _layer_norm(lp["ln2"], x, cfg)
+        with device_scope("mlp"):
+            y_in = _quant(y, act_bits, quantize)
+            wi = _quant(lp["mlp"]["wi_kernel"], w_bits, quantize, axis=0)
+            y = jnp.einsum("bsh,hm->bsm", y_in, wi) + lp["mlp"]["wi_bias"]
+            y = jax.nn.gelu(y, approximate=True)
+            y = _quant(y, act_bits, quantize)
+            wo = _quant(lp["mlp"]["wo_kernel"], w_bits, quantize, axis=0)
+            y = jnp.einsum("bsm,mh->bsh", y, wo) + lp["mlp"]["wo_bias"]
+            x = residual + y
         return (x, pool_k, pool_v), None
 
-    (x, pool_k, pool_v), _ = jax.lax.scan(
-        layer, (x, pool_k, pool_v),
-        (gpt["layers"], jnp.arange(num_layers, dtype=jnp.int32)))
-    x = _layer_norm(gpt["ln_f"], x, cfg)
+    with device_scope("stack"):
+        (x, pool_k, pool_v), _ = jax.lax.scan(
+            layer, (x, pool_k, pool_v),
+            (gpt["layers"], jnp.arange(num_layers, dtype=jnp.int32)))
+    with device_scope("head"):
+        x = _layer_norm(gpt["ln_f"], x, cfg)
     return x, pool_k, pool_v
 
 
+@device_scope("head")
 def _logits(params: Any, cfg: Any, x_last: jax.Array) -> jax.Array:
     """Tied-embedding LM head on the selected positions → f32 ``[B, V]``."""
     wte = params["gpt"]["embeddings"]["word_embeddings"]
     return jnp.einsum("bh,vh->bv", x_last, wte).astype(jnp.float32)
 
 
+@device_scope("sample")
 def _sample(logits: jax.Array, rng: jax.Array, draw: jax.Array,
             sp: SamplingParams) -> jax.Array:
     """Greedy argmax or the sampling-transform chain shared with
@@ -350,6 +364,7 @@ def _sample(logits: jax.Array, rng: jax.Array, draw: jax.Array,
                                   axis=-1).astype(jnp.int32)
 
 
+@device_scope("sample")
 def merge_fresh(tokens: jax.Array, fresh_slot: jax.Array,
                 fresh_tok: jax.Array) -> jax.Array:
     """The decode batch's input tokens: the previous step's output, which
@@ -400,9 +415,10 @@ def make_step_fns(cfg: Any, *, max_batch: int, pages_per_req: int,
         positions = jnp.where(idx < n_valid, start + idx, -1)
         x, pool_k, pool_v = _forward(params, cfg, tokens, positions,
                                      pool_k, pool_v, block_table, quantize)
-        last = jnp.clip(n_valid - 1, 0, prefill_chunk - 1)
-        x_last = jax.lax.dynamic_index_in_dim(x[0], last, axis=0,
-                                              keepdims=False)[None]
+        with device_scope("head"):
+            last = jnp.clip(n_valid - 1, 0, prefill_chunk - 1)
+            x_last = jax.lax.dynamic_index_in_dim(x[0], last, axis=0,
+                                                  keepdims=False)[None]
         logits = _logits(params, cfg, x_last)
         return (constrain(pool_k), constrain(pool_v),
                 everywhere(_sample(logits, rng, draw, sampling)), logits)

@@ -73,6 +73,7 @@ import jax.numpy as jnp
 
 from fleetx_tpu.models.swa_moe import model as M
 from fleetx_tpu.models.swa_moe.config import FULL, WINDOW, SWAMoEConfig
+from fleetx_tpu.observability.trace import device_scope
 from fleetx_tpu.ops import paged_attention as PA
 from fleetx_tpu.serving.decode import (SamplingParams, _sample,
                                        merge_fresh)
@@ -276,26 +277,31 @@ def _forward(params: Any, cfg: SWAMoEConfig, tokens, positions, cache,
     # as many as the chunk has queries, in whole pages
     key_block = -(-S // ps) * ps
 
-    x = params["embed"]["tokens"][jnp.maximum(tokens, 0)]
-    valid = positions >= 0
-    q_pos = jnp.maximum(positions, 0)
-    offs = jnp.clip(positions % ps, 0, ps - 1)
-    # where each (row, slot) is written: the request's page, the slot's ring
-    page_slot = jnp.clip(positions // ps, 0, P - 1)
-    full_pages = jnp.where(
-        valid, jnp.take_along_axis(block_tables, page_slot, axis=1), 0)
-    ring_first = 1 + slots * rp                               # [B]
-    ring_at = jnp.where(
-        valid, ring_first[:, None] + (q_pos // ps) % rp, 0)
-    # the gathered view of a ring: the ``view`` logical pages that end at
-    # the page of ``last``, in order, and the position of each key in them
-    view_first = (jnp.maximum(last, 0) // ps - (view - 1))[:, None] \
-        + jnp.arange(view, dtype=jnp.int32)[None, :]          # [B, view]
-    view_pages = ring_first[:, None] + view_first % rp
-    view_pos = (view_first[:, :, None] * ps + jnp.arange(
-        ps, dtype=jnp.int32)[None, None, :]).reshape(B, view * ps)
-    tables = {t: M.rotary_tables(cfg, t, q_pos) for t in (FULL, WINDOW)}
-    valid_tok = valid.reshape(B * S)
+    with device_scope("embed"):
+        x = params["embed"]["tokens"][jnp.maximum(tokens, 0)]
+    with device_scope("attn.cache"):    # where the rows go, for every layer
+        valid = positions >= 0
+        q_pos = jnp.maximum(positions, 0)
+        offs = jnp.clip(positions % ps, 0, ps - 1)
+        # where each (row, slot) is written: the request's page, the slot's
+        # ring
+        page_slot = jnp.clip(positions // ps, 0, P - 1)
+        full_pages = jnp.where(
+            valid, jnp.take_along_axis(block_tables, page_slot, axis=1), 0)
+        ring_first = 1 + slots * rp                               # [B]
+        ring_at = jnp.where(
+            valid, ring_first[:, None] + (q_pos // ps) % rp, 0)
+        # the gathered view of a ring: the ``view`` logical pages that end
+        # at the page of ``last``, in order, and the position of each key
+        # in them
+        view_first = (jnp.maximum(last, 0) // ps - (view - 1))[:, None] \
+            + jnp.arange(view, dtype=jnp.int32)[None, :]          # [B, view]
+        view_pages = ring_first[:, None] + view_first % rp
+        view_pos = (view_first[:, :, None] * ps + jnp.arange(
+            ps, dtype=jnp.int32)[None, None, :]).reshape(B, view * ps)
+        valid_tok = valid.reshape(B * S)
+    with device_scope("attn.proj"):
+        tables = {t: M.rotary_tables(cfg, t, q_pos) for t in (FULL, WINDOW)}
     act = M.activation(cfg)
     router_first = cfg.router_input == "pre_attention"
     # a window layer's prefill folds its ring a key block at a time once
@@ -303,59 +309,67 @@ def _forward(params: Any, cfg: SWAMoEConfig, tokens, positions, cache,
     fold_ring = not decode and view * ps > _WHOLE_RING_BLOCKS * key_block
     # ... through the ring as a block table: logical page j -> ring page
     # j mod rp
-    ring_table = ring_first[:, None] + \
-        jnp.arange(P, dtype=jnp.int32)[None, :] % rp if fold_ring else None
+    with device_scope("attn.cache"):
+        ring_table = ring_first[:, None] + \
+            jnp.arange(P, dtype=jnp.int32)[None, :] % rp if fold_ring else None
 
     def attention(kind_type, u, lp, cache, at):
-        q = jnp.einsum("bsh,ndh->bsnd", u, lp["q"])
-        k = jnp.einsum("bsh,ndh->bsnd", u, lp["k"])
-        v = jnp.einsum("bsh,hn->bsn", u, lp["v"])
-        if tables[kind_type] is not None:       # else: no position signal
-            cos, sin = tables[kind_type]
-            q, k = M.apply_rotary(q, cos, sin), M.apply_rotary(k, cos, sin)
+        with device_scope("attn.proj"):
+            q = jnp.einsum("bsh,ndh->bsnd", u, lp["q"])
+            k = jnp.einsum("bsh,ndh->bsnd", u, lp["k"])
+            v = jnp.einsum("bsh,hn->bsn", u, lp["v"])
+            if tables[kind_type] is not None:   # else: no position signal
+                cos, sin = tables[kind_type]
+                q, k = M.apply_rotary(q, cos, sin), \
+                    M.apply_rotary(k, cos, sin)
+            k_rows = k.reshape(B, S, kv * hd)
         full_k, full_v, ring_k, ring_v = cache
-        k_rows = k.reshape(B, S, kv * hd)
-        if kind_type == FULL:
-            full_k = full_k.at[at, full_pages, offs].set(k_rows)
-            full_v = full_v.at[at, full_pages, offs].set(v)
-            if decode and paged_kernel:
-                o = PA.paged_attention(q[:, 0], full_k, full_v, block_tables,
-                                       positions[:, 0], at)[:, None]
-            elif decode:
-                kd = full_k[at, block_tables].reshape(B, -1, kv, hd)
-                vd = full_v[at, block_tables].reshape(B, -1, kv, hd)
-                kp = jnp.broadcast_to(jnp.arange(P * ps, dtype=jnp.int32),
-                                      (B, P * ps))
-                o = _gathered_attention(q, kd, vd, kp, q_pos, None, dt)
+        with device_scope("attn.cache"):
+            if kind_type == FULL:
+                full_k = full_k.at[at, full_pages, offs].set(k_rows)
+                full_v = full_v.at[at, full_pages, offs].set(v)
             else:
-                o = _prefill_blocked_attention(
-                    q, full_k, full_v, at, block_tables, q_pos, last[0] + 1,
-                    key_block, dt)
-        else:
-            ring_k = ring_k.at[at, ring_at, offs].set(k_rows)
-            ring_v = ring_v.at[at, ring_at, offs].set(v)
-            if decode and paged_kernel:
-                # the kernel is told what this is: a ring of ``rp`` pages
-                # from ``ring_first``, so a fold is one run of the buffer
-                o = PA.paged_attention(q[:, 0], ring_k, ring_v, ring_first,
-                                       positions[:, 0], at, window=window,
-                                       ring_pages=rp)[:, None]
-            elif fold_ring:
-                o = _prefill_blocked_attention(
-                    q, ring_k, ring_v, at, ring_table, q_pos, last[0] + 1,
-                    key_block, dt, window=window)
-            else:
-                kd = ring_k[at, view_pages].reshape(B, -1, kv, hd)
-                vd = ring_v[at, view_pages].reshape(B, -1, kv, hd)
-                o = _gathered_attention(q, kd, vd, view_pos, q_pos, window,
-                                        dt)
-        if cfg.gating == "per-head":
-            gate = jax.nn.sigmoid(jnp.einsum(
-                "bsh,hn->bsn", u, lp["gate"],
-                preferred_element_type=jnp.float32))
-            o = (o.astype(jnp.float32) * gate[..., None]).astype(dt)
-        y = jnp.einsum("bsnd,ndh->bsh", o, lp["out"])
+                ring_k = ring_k.at[at, ring_at, offs].set(k_rows)
+                ring_v = ring_v.at[at, ring_at, offs].set(v)
+        with device_scope("attn.core"):
+            o = scores(kind_type, q, full_k, full_v, ring_k, ring_v, at)
+        with device_scope("attn.proj"):
+            if cfg.gating == "per-head":
+                gate = jax.nn.sigmoid(jnp.einsum(
+                    "bsh,hn->bsn", u, lp["gate"],
+                    preferred_element_type=jnp.float32))
+                o = (o.astype(jnp.float32) * gate[..., None]).astype(dt)
+            y = jnp.einsum("bsnd,ndh->bsh", o, lp["out"])
         return y, (full_k, full_v, ring_k, ring_v)
+
+    def scores(kind_type, q, full_k, full_v, ring_k, ring_v, at):
+        """The layer's attention over the cache it has just written."""
+        if kind_type == FULL and decode and paged_kernel:
+            return PA.paged_attention(q[:, 0], full_k, full_v, block_tables,
+                                      positions[:, 0], at)[:, None]
+        if kind_type == FULL and decode:
+            kd = full_k[at, block_tables].reshape(B, -1, kv, hd)
+            vd = full_v[at, block_tables].reshape(B, -1, kv, hd)
+            kp = jnp.broadcast_to(jnp.arange(P * ps, dtype=jnp.int32),
+                                  (B, P * ps))
+            return _gathered_attention(q, kd, vd, kp, q_pos, None, dt)
+        if kind_type == FULL:
+            return _prefill_blocked_attention(
+                q, full_k, full_v, at, block_tables, q_pos, last[0] + 1,
+                key_block, dt)
+        if decode and paged_kernel:
+            # the kernel is told what this is: a ring of ``rp`` pages from
+            # ``ring_first``, so a fold is one run of the buffer
+            return PA.paged_attention(q[:, 0], ring_k, ring_v, ring_first,
+                                      positions[:, 0], at, window=window,
+                                      ring_pages=rp)[:, None]
+        if fold_ring:
+            return _prefill_blocked_attention(
+                q, ring_k, ring_v, at, ring_table, q_pos, last[0] + 1,
+                key_block, dt, window=window)
+        kd = ring_k[at, view_pages].reshape(B, -1, kv, hd)
+        vd = ring_v[at, view_pages].reshape(B, -1, kv, hd)
+        return _gathered_attention(q, kd, vd, view_pos, q_pos, window, dt)
 
     def run(kind, lo, n, cache_lo, carry):
         stack = params[kind]
@@ -369,7 +383,9 @@ def _forward(params: Any, cfg: SWAMoEConfig, tokens, positions, cache,
         def layer(i, carry):
             x, cache, hit, pairs, load, passes = carry
             lp = jax.tree.map(lambda w: w[i], per_layer)
-            u = M.rms_norm(x, lp["attn_norm"]["scale"], cfg.rms_norm_eps, dt)
+            with device_scope("norm"):
+                u = M.rms_norm(x, lp["attn_norm"]["scale"], cfg.rms_norm_eps,
+                               dt)
             if router_first and not dense:
                 # chosen from the layer's normed input, applied after
                 # attention to the normed state the experts act on
@@ -377,46 +393,57 @@ def _forward(params: Any, cfg: SWAMoEConfig, tokens, positions, cache,
                                   lp["moe"]["router"], cfg)
             y, cache = attention(kind_type, u, lp["attn"], cache,
                                  cache_lo + (i - lo))
-            x = x + y
-            u = M.rms_norm(x, lp["mlp_norm"]["scale"], cfg.rms_norm_eps, dt)
+            with device_scope("norm"):
+                x = x + y
+                u = M.rms_norm(x, lp["mlp_norm"]["scale"], cfg.rms_norm_eps,
+                               dt)
             if dense:
-                y = M.gated_mlp(u, lp["mlp"]["gate"], lp["mlp"]["up"],
-                                lp["mlp"]["down"], act).astype(dt)
+                with device_scope("mlp"):
+                    y = M.gated_mlp(u, lp["mlp"]["gate"], lp["mlp"]["up"],
+                                    lp["mlp"]["down"], act).astype(dt)
             else:
                 u2d = u.reshape(B * S, -1)
                 ids, weights = routing if router_first else \
                     M.route(u2d, lp["moe"]["router"], cfg)
-                ids = jnp.where(valid_tok[:, None], ids, -1)
+                with device_scope("moe.route"):
+                    ids = jnp.where(valid_tok[:, None], ids, -1)
                 y, rows, turns = M.held_experts(
                     u2d, ids, weights, stack["moe"], i, cfg, moe_pass_rows,
                     moe_kernel)
-                if cfg.shared_expert_intermediate_size:
-                    y = y + M.gated_mlp(u2d, lp["moe"]["shared_gate"],
-                                        lp["moe"]["shared_up"],
-                                        lp["moe"]["shared_down"], act)
-                y = y.astype(dt).reshape(B, S, -1)
-                hit = hit + (rows > 0).sum().astype(jnp.float32)
-                pairs = pairs + rows.sum().astype(jnp.int32)
-                held = rows.astype(jnp.float32)
-                load = jnp.maximum(
-                    load, held.max() / jnp.maximum(held.mean(), 1e-9))
-                passes = passes + turns.astype(jnp.int32)
-            return x + y, cache, hit, pairs, load, passes
+                with device_scope("mlp"):
+                    if cfg.shared_expert_intermediate_size:
+                        y = y + M.gated_mlp(u2d, lp["moe"]["shared_gate"],
+                                            lp["moe"]["shared_up"],
+                                            lp["moe"]["shared_down"], act)
+                    y = y.astype(dt).reshape(B, S, -1)
+                with device_scope("moe.route"):     # the step's counters
+                    hit = hit + (rows > 0).sum().astype(jnp.float32)
+                    pairs = pairs + rows.sum().astype(jnp.int32)
+                    held = rows.astype(jnp.float32)
+                    load = jnp.maximum(
+                        load, held.max() / jnp.maximum(held.mean(), 1e-9))
+                    passes = passes + turns.astype(jnp.int32)
+            with device_scope("mlp"):
+                return x + y, cache, hit, pairs, load, passes
 
-        if n == 1:      # a static index: the layer is a view of its stack
-            return layer(lo, carry)
-        return jax.lax.fori_loop(lo, lo + n, layer, carry)
+        with device_scope("stack"):
+            if n == 1:  # a static index: the layer is a view of its stack
+                return layer(lo, carry)
+            return jax.lax.fori_loop(lo, lo + n, layer, carry)
 
     carry = (x, tuple(cache), jnp.float32(0.0), jnp.int32(0),
              jnp.float32(0.0), jnp.int32(0))
     for kind, lo, n, cache_lo in cfg.runs():
         carry = run(kind, lo, n, cache_lo, carry)
     x, cache, hit, pairs, load, passes = carry
-    x = M.rms_norm(x, params["final_norm"]["scale"], cfg.rms_norm_eps, dt)
+    with device_scope("head"):
+        x = M.rms_norm(x, params["final_norm"]["scale"], cfg.rms_norm_eps,
+                       dt)
     return x, cache, {"hit": hit, "pairs_held": pairs,
                       "load_max_over_mean": load, "passes": passes}
 
 
+@device_scope("head")
 def _logits(params: Any, x_last: jax.Array) -> jax.Array:
     """The (untied) head on the selected positions -> float32 ``[B, V]``."""
     return jnp.einsum("bh,hv->bv", x_last, params["head"]["kernel"],
@@ -455,9 +482,10 @@ def make_step_fns(cfg: SWAMoEConfig, *, prefill_chunk: int, page_size: int,
             block_table, jnp.reshape(slot, (1,)).astype(jnp.int32), last,
             rp=rp, view=rp, decode=False, paged_kernel=False,
             moe_kernel="moe_gmm_prefill")
-        at = jnp.clip(n_valid - 1, 0, prefill_chunk - 1)
-        x_last = jax.lax.dynamic_index_in_dim(x[0], at, axis=0,
-                                              keepdims=False)[None]
+        with device_scope("head"):
+            at = jnp.clip(n_valid - 1, 0, prefill_chunk - 1)
+            x_last = jax.lax.dynamic_index_in_dim(x[0], at, axis=0,
+                                                  keepdims=False)[None]
         logits = _logits(params, x_last)
         return (*cache, _sample(logits, rng, draw, sampling), logits)
 
@@ -477,7 +505,8 @@ def make_step_fns(cfg: SWAMoEConfig, *, prefill_chunk: int, page_size: int,
             decode=True, paged_kernel=paged_kernel,
             moe_kernel="moe_gmm_decode")
         logits = _logits(params, x[:, 0])
-        stats["rows"] = (lens >= 0).sum().astype(jnp.int32)
+        with device_scope("moe.route"):     # rides with the counters
+            stats["rows"] = (lens >= 0).sum().astype(jnp.int32)
         return (*cache, _sample(logits, rng, draw, sampling), logits,
                 stats)
 

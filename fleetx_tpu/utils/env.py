@@ -101,13 +101,20 @@ def log_compile(what: str, jitted, *args) -> None:
     """Compile ``jitted`` for ``args`` ahead of its first call and log the
     seconds it took and the Mosaic kernels in it — one line a reader of
     the log (``chip_smoke.py``) can parse. The call that follows reuses
-    the executable, so nothing compiles twice."""
+    the executable, so nothing compiles twice. The optimised program's
+    HLO modules (host objects) go to ``observability.trace.keep_compiled``,
+    which prints and parses them only if ``compiled_programs()`` is asked
+    for the table from instruction to device scope."""
+    from fleetx_tpu.observability import trace
+
     t0 = time.time()
     lowered = jitted.lower(*args)
     kernels = mosaic_kernels(lowered.as_text())
-    lowered.compile()
+    executable = lowered.compile().runtime_executable()
     logger.info("compiled %s in %.1fs; Mosaic kernels: %s", what,
                 time.time() - t0, json.dumps(kernels))
+    if executable is not None:
+        trace.keep_compiled(executable.hlo_modules())
 
 
 def set_seed(seed: int) -> jax.Array:

@@ -27,13 +27,14 @@ TRACKED_DIRS = ("fleetx_tpu/", "tools/", "tests/", "benchmarks/", "docs/",
 BARE_ENDINGS = (".py", ".md", ".json")
 #: names that are no file of the checkout: what a run writes (a
 #: checkpoint's marker and manifest, an export's description, a
-#: tokenizer's vocabulary), a model card's config, the reference
+#: tokenizer's vocabulary, a profiler window's table of device scopes), a
+#: model card's config, the reference
 #: project's own sources, and the lint baseline, which
 #: `tools/lint.py --write-baseline` writes and which is absent while it
 #: has no entry
 NOT_OF_THE_CHECKOUT = frozenset({
     "fleetx_meta.json", "fleetx_integrity.json", "meta.json", "vocab.json",
-    "config.json", "hybrid_model.py", "language_module.py",
+    "device_scopes.json", "config.json", "hybrid_model.py", "language_module.py",
     "tools/lint_baseline.json",
 })
 

@@ -397,35 +397,3 @@ def test_yaml_roundtrip_for_zero_knobs(tmp_path):
     base_cfg = get_config(base, num_devices=1)
     assert str(base_cfg["Model"]["grad_accum_dtype"]) == "float32"
     assert base_cfg["Optimizer"]["grad_clip"]["fused"] is False
-
-
-# ------------------------------------------- update-phase observability
-
-def test_measure_update_phase_records_span_and_gauge(devices8):
-    cfg = stage_cfg(2)
-    cfg["Observability"] = {"enable": True, "trace": {"enable": False},
-                            "sinks": []}
-    mesh = build_mesh(cfg["Distributed"], devices=devices8)
-    eng = build_engine(cfg, mesh)
-    eng.prepare(make_batches(1)[0])
-    mean_s = eng.measure_update_phase(iters=2)
-    assert mean_s > 0.0
-    summ = eng.obs.registry.histogram("optimizer_update").summary()
-    assert summ["count"] == 2
-    gauge = eng.obs.registry.gauge("grad_bytes_sharded").value
-    assert gauge and gauge > 0
-    # the gauge counts exactly the fsdp-sharded grad leaves
-    from fleetx_tpu.core.engine.eager_engine import _sharded_grad_bytes
-    from flax.core import meta
-
-    expect = _sharded_grad_bytes(
-        jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-                     meta.unbox(eng.state.params)), eng._grad_shardings)
-    assert int(gauge) == expect
-
-
-def test_measure_update_phase_runs_without_observability(devices8):
-    mesh = build_mesh({}, devices=devices8[:1])
-    eng = build_engine(tiny_cfg(), mesh)
-    eng.prepare(make_batches(1)[0])
-    assert eng.measure_update_phase(iters=1) > 0.0

@@ -364,10 +364,8 @@ def test_overlap_jaxpr_pins_gather_at_step_head(devices8):
                if "fsdp" in str(leaf.sharding.spec)) == 0
 
 
-def test_overlap_eval_and_update_phase_run_sharded(devices8):
-    """eval_step gathers the resident shards too, and
-    measure_update_phase times the update on the sharded operands — the
-    `optimizer_update` span that makes the overlap measurable."""
+def test_overlap_eval_runs_sharded(devices8):
+    """eval_step gathers the resident shards too."""
     mesh = build_mesh({"fsdp_degree": 4, "dp_degree": 2}, devices=devices8)
     b = _batches(1)[0]
     eng = _engine(_stage_cfg(2, overlap=True), mesh)
@@ -378,8 +376,6 @@ def test_overlap_eval_and_update_phase_run_sharded(devices8):
     ev_b = base._eval_step(base.state, base.shard_batch(b))
     np.testing.assert_allclose(float(ev_o["loss"]), float(ev_b["loss"]),
                                rtol=2e-4, atol=2e-4)
-    t = eng.measure_update_phase(iters=1)
-    assert np.isfinite(t) and t > 0
 
 
 def test_overlap_demotes_below_stage2(devices8):
