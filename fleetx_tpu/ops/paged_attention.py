@@ -14,7 +14,11 @@ online-softmax accumulator in f32 VMEM scratch (the
 ``ops/flash_attention.py`` m/l/acc discipline) folds the pages into the
 output without ever holding more than two slots of ``pages_per_step``
 ``[page_size, head_block·head_dim]`` tiles of K and of V live
-(`pick_pages_per_step`: the pages that move 256 KB a pool; below).
+(`pick_pages_per_step`: the pages that move 256 KB a pool; below). Both
+products of a fold follow the POOL'S dtype and nothing else: a bfloat16
+pool takes one MXU pass, bfloat16 probabilities into the value tile as it
+landed (as prefill and the gather path do), a float32 pool the exact
+multi-pass product; m, l and the accumulator are f32 either way.
 
 Why heads and head_dim are ONE minor dim: a TPU buffer is tiled (8, 128)
 over its two minor dims, so a 64-wide ``head_dim`` minor either pads every
@@ -67,8 +71,8 @@ lanes out of its ``[rows, kv_heads·head_dim]`` accumulator. With a
 ``window`` a row's walk does not start at its first page but at the group
 that holds position ``lens − window + 1``: keys older than the window are
 neither fetched nor scored, whatever the context. With ``heads ==
-kv_heads`` and no window the kernel is, line for line, the one it was
-(``tests/test_zz_serving.py`` holds the outputs to the bit).
+kv_heads`` and no window a float32 pool's outputs are to the bit what they
+were before either existed (``tests/test_swa_moe.py`` holds a digest).
 
 How a fold's tile is fetched follows the layout. **Pages a fold follow the
 bytes a fold moves** (`pick_pages_per_step`): a fold's cost is the core's
@@ -149,14 +153,14 @@ _MAX_HEAD_BLOCK = 16
 #: pages run twice) is the core's own and is not hidden behind the copies:
 #: 24 layer calls at that geometry on the v5e, 64 rows of 128–767 tokens,
 #: 1 / 2 / 4 / 8 / 16 pages a fold: 22.5 / 13.2 / 8.7 / 7.1 / 7.2 ms (6.2 /
-#: 5.9 at 8 / 16 once a row's last fold starts the next row's first
-#: copies; PERF.md, PR 30) — and on a pool half as wide (4 key-value heads
-#: of 128: a page 16 KB) the same 8 pages move half the bytes for the same
-#: cost, where 16 read 7.7 % off the whole decode program (PERF.md, PR 38).
-#: So the pages of a fold follow the bytes: 8 on a 1,024-lane bfloat16
-#: pool (the folds then run in the order they always have), 16 on a
-#: 512-lane one. The pages of a row's last group that lie past its query
-#: are fetched for nothing, which is what keeps the target from growing
+#: 5.9 at 8 / 16 once a row's last fold starts the next row's first copies,
+#: PR 30; 6.0 / 5.1 on PR 41's one-pass product: PERF.md section 7 has what
+#: 16 would buy) — and on a pool half as wide (4 key-value heads of 128: a
+#: page 16 KB) the same 8 pages move half the bytes for the same cost, where
+#: 16 read 7.7 % off the whole decode program (PR 38). So the pages of a
+#: fold follow the bytes: 8 on a 1,024-lane bfloat16 pool (the folds run in
+#: the order they always have), 16 on a 512-lane one. The pages of a row's
+#: last group past its query are fetched for nothing: the target stays put
 _FOLD_BYTES = 256 * 1024
 
 #: ... and never fewer than this many pages: the fixed cost is spread over
@@ -401,7 +405,8 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, *refs,
     input is the 0/1 mask of the lanes each row owns ``[hb·group,
     hb·hd]``, and the numerator comes out ``[1, hb·group, hd]``. The
     remaining scratch is the block-diagonal query, the ``[rows, hb·hd]``
-    accumulator, m and l.
+    accumulator, m and l (f32). ``Q · Kᵀ`` and ``P · V`` are one MXU pass
+    each in a bf16 pool's dtype, the multi-pass product in an f32 pool's.
     """
     if group > 1:
         own_ref, refs = refs[0], refs[1:]
@@ -545,14 +550,14 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, *refs,
                 # Heads share the lanes of a K/V row, so the MXU separates
                 # them: row h of the block-diagonal query is zero outside
                 # head h's lanes, and contracting it against a key row's
-                # lanes is head h's dot product alone. f32 pools take the
-                # multi-pass product.
-                exact = jax.lax.Precision.HIGHEST
+                # lanes is head h's dot product alone. Both products
+                # follow the pool's dtype: f32 pools take the multi-pass one
+                precision = jax.lax.Precision.HIGHEST \
+                    if k_buf.dtype == jnp.float32 else None
                 k = tile(k_buf)                            # [g·ps, hb·hd]
                 s = jax.lax.dot_general(
                     qd_ref[...], k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                    precision=exact if k.dtype == jnp.float32 else None
+                    preferred_element_type=jnp.float32, precision=precision
                 ) * scale
                 col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
                 if ring:
@@ -573,18 +578,13 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, *refs,
                 l_ref[...] = l_ref[...] * alpha + pexp.sum(axis=1,
                                                            keepdims=True)
                 m_ref[...] = m_new
-                if group > 1 and k.dtype != jnp.float32:
-                    # the probabilities in the values' dtype: one pass
-                    # of the MXU where the f32 product takes six
-                    pv = jax.lax.dot_general(
-                        pexp.astype(k.dtype), tile(v_buf),
-                        (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32)
-                else:
-                    v = tile(v_buf).astype(jnp.float32)    # [g·ps, hb·hd]
-                    pv = jax.lax.dot_general(
-                        pexp, v, (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32, precision=exact)
+                # the probabilities in the values' dtype, the tile as it
+                # landed: one pass of the MXU at any head count (a bf16 V
+                # has no low parts for a second to multiply), f32 exact
+                pv = jax.lax.dot_general(
+                    pexp.astype(k.dtype), tile(v_buf),     # [g·ps, hb·hd]
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32, precision=precision)
                 acc_ref[...] = acc_ref[...] * alpha + pv
 
         jax.lax.fori_loop(0, n_groups, fold, None)

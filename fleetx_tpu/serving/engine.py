@@ -511,7 +511,11 @@ class ServingEngine:
             sc.max_batch, self.allocator.usable_pages,
             sc.page_size, self.allocator.usable_pages * sc.page_size,
             sc.prefill_chunk, bool(sc.quantize_decode),
-            "paged_kernel" if self.paged_kernel_active else "gather",
+            # the kernel's P · V follows the pool's dtype and nothing else
+            ("paged_kernel (P·V %s)" % (
+                "float32, exact" if self.pool_k.dtype == np.float32
+                else "%s, one pass" % self.pool_k.dtype.name))
+            if self.paged_kernel_active else "gather",
             "".join(", %s cache %d pages a fold in %d cop%s a buffer"
                     % (kind, pages, copies, "y" if copies == 1 else "ies")
                     for kind, (pages, copies) in sorted(folds.items())),

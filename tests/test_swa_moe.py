@@ -539,22 +539,104 @@ def test_paged_attention_with_fewer_kv_heads_and_a_window(heads, kv, hd,
         assert not got[0].any()         # an inactive row: exact zeros
 
 
-@pytest.mark.parametrize("dtype,digest", [
-    (jnp.float32, "5c9720caa1fb241f65b752f9b1b7e1d9430e8548"),
-    (jnp.bfloat16, "56c33a0b89a801e173fa8f9799997c2dabbd861e")])
-def test_with_as_many_kv_heads_and_no_window_it_is_the_kernel_it_was(
-        dtype, digest):
-    """16 heads of 64 over 16 key-value heads, no window: the bits of PR
-    34's kernel (``git show c2ae43d:fleetx_tpu/ops/paged_attention.py`` on
-    this case, in this container's interpret mode, gave these digests; the
-    new kernel was compared with it array for array when it was written)."""
+def test_with_as_many_kv_heads_and_no_window_a_float32_pool_keeps_its_bits():
+    """16 heads of 64 over 16 key-value heads, no window, a float32 pool:
+    the bits of PR 34's kernel (``git show
+    c2ae43d:fleetx_tpu/ops/paged_attention.py`` on this case, in this
+    container's interpret mode, gave this digest; the new kernel was
+    compared with it array for array when it was written). A pool that
+    states float32 gets the exact product, whatever came since."""
     import hashlib
 
     from fleetx_tpu.ops import paged_attention as PA
 
-    out = PA.paged_attention(*_kernel_case(dtype, 16, 16, 64), jnp.int32(1))
-    got = hashlib.sha1(np.asarray(out.astype(jnp.float32)).tobytes())
-    assert got.hexdigest() == digest
+    out = PA.paged_attention(*_kernel_case(jnp.float32, 16, 16, 64),
+                             jnp.int32(1))
+    got = hashlib.sha1(np.asarray(out).tobytes())
+    assert got.hexdigest() == "5c9720caa1fb241f65b752f9b1b7e1d9430e8548"
+
+
+def test_with_as_many_kv_heads_a_bfloat16_pool_multiplies_as_the_gather():
+    """The same case on a bfloat16 pool (PR 34's bits until PR 41: they
+    were the float32 product of a value tile converted every fold). The
+    kernel now rounds its probabilities to bfloat16 and multiplies the tile
+    as it landed, as the served program's gather path does
+    (``serving/decode.py:_paged_attention`` on the same pool): the two
+    agree within one bfloat16 ulp of the output's scale, and the kernel
+    holds the float64 reference within the 2e-2 of the other bfloat16
+    tests (it keeps its scores in float32, so it lies nearer than the
+    gather does)."""
+    from fleetx_tpu.ops import paged_attention as PA
+    from fleetx_tpu.serving.decode import _paged_attention
+
+    q, pk, pv, tables, lens = _kernel_case(jnp.bfloat16, 16, 16, 64)
+    out = PA.paged_attention(q, pk, pv, tables, lens, jnp.int32(1))
+    assert out.dtype == jnp.bfloat16
+    out = np.asarray(out.astype(jnp.float32))
+    B, H, hd = q.shape
+    kd, vd = (pool[1][tables].reshape(B, -1, H, hd) for pool in (pk, pv))
+    gathered = np.asarray(_paged_attention(
+        q[:, None], kd, vd, lens[:, None])[:, 0].astype(jnp.float32))
+    ulp = 2.0 ** (math.floor(math.log2(np.abs(out).max())) - 7)
+    assert not out[0].any()             # the inactive row: exact zeros
+    np.testing.assert_allclose(out[1:], gathered[1:], atol=ulp, rtol=0)
+    np.testing.assert_allclose(
+        out, _gathered(q, pk, pv, tables, lens, 1, None), atol=2e-2, rtol=0)
+
+
+def _kernel_body_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold (the
+    Pallas body, its loops and branches)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _kernel_body_eqns(sub)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("heads,kv,hd", [(16, 16, 64), (6 * 8, 8, 128)],
+                         ids=["group1", "group6"])
+def test_the_folds_products_follow_the_pools_dtype(monkeypatch, heads, kv,
+                                                   hd, dtype):
+    """What the kernel multiplies, read from its jaxpr as Mosaic would get
+    it (interpret mode off; nothing is lowered): ``Q · Kᵀ`` and ``P · V``,
+    one each. A bfloat16 pool — at one head count as with grouped queries —
+    has no ``HIGHEST`` product, multiplies bfloat16 into bfloat16 with a
+    float32 result, and makes no float32 copy of a ``[pages · page_size,
+    width]`` tile; a float32 pool has the ``HIGHEST`` product, twice."""
+    from fleetx_tpu import ops
+    from fleetx_tpu.ops import paged_attention as PA
+
+    monkeypatch.setattr(ops, "interpret", lambda: False)
+    ps, per_req = 16, 12
+    pool = jax.ShapeDtypeStruct((2, 40, ps, kv * hd), dtype)
+    jaxpr = jax.make_jaxpr(PA._paged_call)(
+        jax.ShapeDtypeStruct((4, heads, hd), dtype), pool, pool,
+        jax.ShapeDtypeStruct((4, per_req), jnp.int32),
+        jax.ShapeDtypeStruct((4,), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32))
+    pages = PA.pick_pages_per_step(
+        num_heads=heads, head_dim=hd, page_size=ps, pages_per_req=per_req,
+        dtype=dtype, num_kv_heads=kv)
+    tile = (pages * ps, PA.pick_head_block(kv, hd, dtype) * hd)
+    eqns = list(_kernel_body_eqns(jaxpr.jaxpr))
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    assert len(dots) == 2
+    for dot in dots:
+        assert [v.aval.dtype for v in dot.invars] == [dtype, dtype]
+        assert dot.outvars[0].aval.dtype == jnp.float32
+        assert tile in [v.aval.shape for v in dot.invars]
+        exact = dot.params["precision"] is not None and all(
+            p == jax.lax.Precision.HIGHEST for p in dot.params["precision"])
+        assert exact == (dtype == jnp.float32)
+    if dtype == jnp.bfloat16:
+        assert not [e for e in eqns for v in e.outvars
+                    if getattr(v.aval, "shape", None) == tile
+                    and v.aval.dtype == jnp.float32]
 
 
 def test_the_window_walk_starts_where_the_window_does():

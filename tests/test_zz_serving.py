@@ -966,6 +966,36 @@ def test_fold_gauges_of_a_gpt_engine_are_set_at_its_build(small_model):
         assert f"serving_kv_fold_{name}" in SERVING_METRIC_NAMES
 
 
+@pytest.mark.parametrize("dtype,paged,said", [
+    ("bfloat16", True, "decode=paged_kernel (P·V bfloat16, one pass), full"),
+    ("float32", True, "decode=paged_kernel (P·V float32, exact), full"),
+    ("bfloat16", False, "decode=gather, alloc=")])
+def test_the_build_line_says_what_the_kernels_product_is(caplog, dtype,
+                                                         paged, said):
+    """The form of the decode kernel's ``P · V`` is fixed by the pool's
+    dtype when the programs are built (PR 41): one MXU pass in a bfloat16
+    pool's dtype at any head count, the exact multi-pass product in a
+    float32 pool's. Nothing to count a step, so the line in which the
+    engine says which attention path it built says this too."""
+    from fleetx_tpu.utils.log import logger as fx_logger
+
+    cfg, _, params = _build_model(
+        hidden_size=128, num_attention_heads=2, num_layers=1,
+        ffn_hidden_size=64, dtype=dtype, max_position_embeddings=64)
+    fx_logger.addHandler(caplog.handler)       # the logger does not propagate
+    try:
+        eng = ServingEngine(cfg, params, ServingConfig(
+            max_batch=2, page_size=16, num_pages=9, max_seq_len=64,
+            prefill_chunk=16, paged_kernel=paged), eos_token_id=EOS)
+    finally:
+        fx_logger.removeHandler(caplog.handler)
+    assert eng.paged_kernel_active == paged
+    assert eng.pool_k.dtype == jnp.dtype(dtype)
+    line, = [r.getMessage() for r in caplog.records
+             if r.getMessage().startswith("serving engine:")]
+    assert said in line, line
+
+
 # ---------------------------------------------------------------------------
 # lazy page lifecycle: admission, growth, preempt-and-swap (PR 18)
 # ---------------------------------------------------------------------------
