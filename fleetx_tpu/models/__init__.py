@@ -50,6 +50,11 @@ def get_registry():
         modules["SWAMoEModule"] = SWAMoEModule
     except ImportError:
         pass
+    try:
+        from fleetx_tpu.models.gdn_mla.module import GDNMLAModule
+        modules["GDNMLAModule"] = GDNMLAModule
+    except ImportError:
+        pass
     return modules
 
 

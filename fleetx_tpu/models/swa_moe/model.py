@@ -266,9 +266,11 @@ def pass_rows(cfg: SWAMoEConfig, tokens: int) -> int:
 
 def held_experts(u2d: jax.Array, ids: jax.Array, weights: jax.Array,
                  moe: dict, layer: jax.Array, cfg: SWAMoEConfig,
-                 pass_rows: int, kernel_name: str) -> tuple:
+                 pass_rows: int, kernel_name: str, glu=None) -> tuple:
     """The held experts' weighted sum for every token, [N, h] float32, the
     rows each held expert got [held], and the passes the loop took.
+    ``glu`` (gate product, up product) -> their combination, for a family
+    whose gated MLP is not plain ``act(gate) · up``.
 
     ``moe`` holds the STACKED experts of the layer's kind (``[layers, held,
     ...]``) and ``layer`` says which: a tile's matrix is read at index
@@ -288,14 +290,15 @@ def held_experts(u2d: jax.Array, ids: jax.Array, weights: jax.Array,
         out_dtype=jnp.float32, name=kernel_name)
 
     act = activation(cfg)
+    glu = glu or (lambda g, u: act(g) * u)
 
     def body(state):
         c, y = state
         tok, wt, _, experts, n_tiles, xs = held_share.pass_inputs(
             c, u2d, w_flat, plan, k, pass_rows, tile)
         experts = experts + layer * held
-        a = (act(gmm(xs, "experts_gate", experts, n_tiles))
-             * gmm(xs, "experts_up", experts, n_tiles)).astype(u2d.dtype)
+        a = glu(gmm(xs, "experts_gate", experts, n_tiles),
+                gmm(xs, "experts_up", experts, n_tiles)).astype(u2d.dtype)
         o = gmm(a, "experts_down", experts, n_tiles)
         with device_scope("moe.route"):
             return c + 1, y.at[tok].add(o * wt[:, None])

@@ -95,9 +95,15 @@ SERVING_RECORD_SCHEMA: dict[str, tuple[tuple, bool]] = {
     "decode_steps": ((int,), False),
     "decode_overlapped": ((int,), False),
     "overrun_rows": ((int,), False),
-    # cache kind ("full", "window") -> [pages a fold of the decode kernel
-    # takes, copies a cache buffer that fetch them]; empty on the gather
+    # cache kind ("full", "window", "latent") -> [pages a fold of the
+    # decode kernel takes, copies a cache buffer that fetch them]; empty on
+    # the gather
     "kv_folds": ((dict,), False),
+    # bytes of a family's constant-size state a slot (the recurrent state
+    # and the convolution's tail of its linear-attention layers) and of its
+    # paged pool of latents; 0 in a family without
+    "serving_state_cache_bytes": ((int,), False),
+    "serving_latent_cache_bytes": ((int,), False),
     # a family with sparse experts only (serving/registry.py): over the
     # decode steps, the mean of (rows of the fullest held expert / the
     # mean, worst layer of a step), null before the first step; and the
@@ -209,6 +215,12 @@ SERVING_METRIC_NAMES = (
     # cache, or the gathered view
     "serving_kv_fold_pages_full", "serving_kv_fold_pages_window",
     "serving_kv_fold_copies_full", "serving_kv_fold_copies_window",
+    "serving_kv_fold_pages_latent", "serving_kv_fold_copies_latent",
+    # bytes of the caches that are not lists of keys and values: the
+    # constant-size state a slot of linear-attention layers (recurrent
+    # state + convolution tail) and the paged pool of latents; set once,
+    # when the engine is built; 0 in a family without
+    "serving_state_cache_bytes", "serving_latent_cache_bytes",
     # a family with sparse experts (serving/registry.py): held experts a
     # decode step hit (mean over its expert layers), (token, expert) pairs
     # on held experts and all pairs, of the rows that decoded

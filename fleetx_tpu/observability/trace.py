@@ -194,6 +194,15 @@ DEVICE_SCOPES: dict = {
                  "blocks, a ring scored whole, the gathered page view)",
     "attn.cache": "the pool's row scatter, a ring's write, a dense decode "
                   "cache's update",
+    "gdn.proj": "a linear-attention layer's products (queries, keys, "
+                "values, the output gate, decay and write strength), its "
+                "gates, the output's norm and gate, the output product",
+    "gdn.conv": "the causal depth-wise convolution over [q; k; v] with its "
+                "SiLU, and the read and write of the slot's last inputs",
+    "gdn.core": "the gated delta rule: the chunked form of a prefill chunk "
+                "(triangular system, gdn_chunk kernel), the one-token "
+                "step (gdn_decode), the heads' L2 norms, the state's read "
+                "and write",
     "mlp": "dense MLP, shared expert, and the block's closing residual add",
     "moe.route": "router product and scoring, top-k, plan_rows' sort and "
                  "search, the gathers into expert order, the combine's "

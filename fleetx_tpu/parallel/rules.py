@@ -11,7 +11,7 @@ stage shards which term, and the serving KV pool hand-wired its own
 hardware. Here the whole mapping is *data*:
 
 - ``PARTITION_RULES``: per model family (``gpt``, ``gpt_moe``,
-  ``gpt_lora``, ``vision``, ``ernie``, ``imagen``, ``mla_moe``, ``swa_moe``, plus the serving KV
+  ``gpt_lora``, ``vision``, ``ernie``, ``imagen``, ``mla_moe``, ``swa_moe``, ``gdn_mla``, plus the serving KV
   pool as ``serving_kv``), an
   ORDERED tuple of ``(regex, logical-axes template)`` rules matched against
   slash-joined parameter-tree paths, first match wins — the
@@ -337,6 +337,29 @@ PARTITION_RULES: dict[str, tuple] = {
         (r"head/kernel$", ("embed", "vocab")),
         (r"(^|/)\w*norm/scale$", ("norm",)),
     ),
+    # the linear-attention / latent-attention sparse-expert decoder
+    # (models/gdn_mla; served on one chip): the wide products over the
+    # Megatron axis by their output (or, for the output products, input)
+    # channels, the latents' small products and every vector replicated;
+    # the held experts over ``expert``, the router (ALL experts) replicated
+    "gdn_mla": (
+        (r"mixer/(qkv|z|gate)$", ("embed", "heads")),
+        (r"mixer/(ab|q_a|kv_a)$", ("embed", None)),
+        (r"mixer/conv$", (None, "heads")),
+        (r"mixer/(A_log|dt_bias|o_norm|q_norm|kv_norm)$", (None,)),
+        (r"(linear_dense|linear_moe)/mixer/out$", ("heads", "embed")),
+        (r"mixer/(q_bn|q_br|k_b|v_b)$", (None, "heads", "kv")),
+        (r"(latent_dense|latent_moe)/mixer/out$", ("heads", "kv", "embed")),
+        (r"(mlp/|moe/shared_)(gate|up)$", ("embed", "mlp")),
+        (r"(mlp/|moe/shared_)down$", ("mlp", "embed")),
+        (r"moe/router$", ("embed", None)),
+        (r"moe/selection_bias$", (None,)),
+        (r"moe/experts_(gate|up)$", ("expert", "embed", None)),
+        (r"moe/experts_down$", ("expert", None, "embed")),
+        (r"embed/tokens$", ("vocab", "embed")),
+        (r"head/kernel$", ("embed", "vocab")),
+        (r"(^|/)\w*norm/w$", ("norm",)),
+    ),
     # the serving KV page pool (serving/paged_cache.py): pages over the
     # ZeRO axis (capacity scales with fsdp), heads over the Megatron axis
     # (heads and head_dim share the pool's minor dim, heads major)
@@ -356,6 +379,7 @@ STACK_MARKERS: dict[str, str] = {
     "ernie": r"(^|/)layers/",
     "mla_moe": r"(^|/)(dense_layers|moe_layers|mtp/layers)/",
     "swa_moe": r"(^|/)(full|window)_(dense|moe)/",
+    "gdn_mla": r"(^|/)(linear|latent)_(dense|moe)/",
 }
 
 #: families whose fully-replicated leaves are accepted at ANY size by the

@@ -3,11 +3,13 @@
 One ``ServingEngine`` owns the device state (params + cache buffers + the
 two jitted step programs of its model family: ``serving/registry.py``
 finds the family, ``serving/decode.py`` is GPT's, ``serving/swa_moe.py`` the
-windowed-attention sparse-expert one's) and the host state (slot table,
+windowed-attention sparse-expert one's, ``serving/gdn_mla.py`` the
+linear-attention / latent-attention one's) and the host state (slot table,
 block tables, page allocator, request queues). Whatever the family, the
 pages the allocator hands out and the block table a request holds are those
-of the layers that keep every token; a family's other caches (a ring a
-slot for window layers) cost the host nothing. The scheduler runs
+of the layers that keep every token (keys and values, or latents); a
+family's other caches (a ring a slot for window layers, a recurrent state a
+slot for linear-attention layers) cost the host nothing. The scheduler runs
 the vLLM-style loop, one ``step()`` per iteration, and keeps the device
 fed: the device work of tick N+1 is dispatched BEFORE the tokens of tick N
 are fetched (order of a tick: admit → dispatch a chunk → schedule → dispatch
@@ -469,10 +471,15 @@ class ServingEngine:
         # when the programs were built, so the gauges are set once, here
         # (0: no such cache, or the gathered view)
         folds = self._programs.kv_folds
-        for kind in ("full", "window"):
+        for kind in ("full", "window", "latent"):
             pages, copies = folds.get(kind, (0, 0))
             self.metrics.gauge(f"serving_kv_fold_pages_{kind}").set(pages)
             self.metrics.gauge(f"serving_kv_fold_copies_{kind}").set(copies)
+        # bytes of the caches that are not lists of keys and values (a pool
+        # of latents, a constant-size state a slot): 0 in a family without
+        for kind in ("state", "latent"):
+            self.metrics.gauge(f"serving_{kind}_cache_bytes").set(
+                self._programs.cache_bytes.get(kind, 0))
         # the tick's own clock (a test may replace it) and what it last
         # read: seconds by phase of the LAST tick only, ``tick`` the whole
         self._clock = time.monotonic
@@ -1301,6 +1308,10 @@ class ServingEngine:
             # copies a cache buffer that fetch them] (kernel path only)
             "kv_folds": {kind: list(shape) for kind, shape
                          in self._programs.kv_folds.items()},
+            "serving_state_cache_bytes":
+                int(self._programs.cache_bytes.get("state", 0)),
+            "serving_latent_cache_bytes":
+                int(self._programs.cache_bytes.get("latent", 0)),
             **gauges,
             "tokens_total": int(tokens),
             "tokens_per_sec": tokens / wall,

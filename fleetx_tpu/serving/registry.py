@@ -37,10 +37,14 @@ class Programs:
     paged_kernel_active: bool
     # (tokens one fold of the decode kernel covers, folds of a table row)
     walk_shape: Optional[tuple] = None
-    # cache kind ("full", "window") -> (pages one fold of the decode kernel
-    # takes, copies a cache buffer that fetch them); a kind the engine does
-    # not have, or every kind on the gathered view, is absent
+    # cache kind ("full", "window", "latent") -> (pages one fold of the
+    # decode kernel takes, copies a cache buffer that fetch them); a kind
+    # the engine does not have, or every kind on the gathered view, is absent
     kv_folds: dict = dataclasses.field(default_factory=dict)
+    # bytes of the caches that are not lists of keys and values: "latent"
+    # (a paged pool of latents) and "state" (constant-size state a slot);
+    # absent in a family without them
+    cache_bytes: dict = dataclasses.field(default_factory=dict)
     # slot -> further arguments of ``prefill`` after the rng and the draw
     prefill_extra: Callable = lambda slot: ()
     # (metrics registry, what ``decode`` returned after its logits)
@@ -165,6 +169,27 @@ class GPTFamily(Family):
                         kv_folds=folds)
 
 
+def _expert_counters(expert_layers: int, per_tok: int) -> Callable:
+    """``Programs.record_stats`` of a family with sparse experts: what its
+    decode program returns after its logits -> the ``serving_moe_*``
+    metrics."""
+    def record(metrics, stats):
+        if not expert_layers:
+            return
+        metrics.histogram("serving_moe_experts_hit").record(
+            float(stats["hit"]) / expert_layers)
+        metrics.counter("serving_moe_pairs_held_total").inc(
+            int(stats["pairs_held"]))
+        metrics.counter("serving_moe_pairs_total").inc(
+            int(stats["rows"]) * per_tok * expert_layers)
+        metrics.histogram("serving_moe_load_max_over_mean").record(
+            float(stats["load_max_over_mean"]))
+        metrics.counter("serving_moe_passes_total").inc(
+            int(stats["passes"]))
+
+    return record
+
+
 class SWAMoEFamily(Family):
     """Windowed and full grouped-query attention over sparse experts, all
     of them held or a share (``models/swa_moe``, ``serving/swa_moe.py``;
@@ -258,20 +283,6 @@ class SWAMoEFamily(Family):
         expert_layers = sum(n for kind, n in cfg.kinds().items()
                             if kind.endswith("moe"))
 
-        def record(metrics, stats):
-            if not expert_layers:
-                return
-            metrics.histogram("serving_moe_experts_hit").record(
-                float(stats["hit"]) / expert_layers)
-            metrics.counter("serving_moe_pairs_held_total").inc(
-                int(stats["pairs_held"]))
-            metrics.counter("serving_moe_pairs_total").inc(
-                int(stats["rows"]) * cfg.num_experts_per_tok * expert_layers)
-            metrics.histogram("serving_moe_load_max_over_mean").record(
-                float(stats["load_max_over_mean"]))
-            metrics.counter("serving_moe_passes_total").inc(
-                int(stats["passes"]))
-
         def kv_tokens(lens):
             live = lens[lens >= 0]
             return int(live.sum()), int(np.minimum(live, window).sum())
@@ -281,13 +292,107 @@ class SWAMoEFamily(Family):
             tokens=jnp.zeros((sc.max_batch,), jnp.int32),
             paged_kernel_active=active, walk_shape=walk, kv_folds=folds,
             prefill_extra=lambda slot: (np.int32(slot),),
-            record_stats=record, kv_tokens=kv_tokens,
+            record_stats=_expert_counters(expert_layers,
+                                          cfg.num_experts_per_tok), kv_tokens=kv_tokens,
             describe="%d full layers paged, %d window layers a ring of %d "
                      "pages a slot" % (cfg.layers_of("full"),
                                        cfg.layers_of("window"), ring))
 
 
-_FAMILIES = (GPTFamily(), SWAMoEFamily())
+class GDNMLAFamily(Family):
+    """Gated-delta-rule layers beside latent attention over sparse experts
+    held as a share (``models/gdn_mla``, ``serving/gdn_mla.py``;
+    ``docs/gdn_mla.md``): a paged pool of latents, and a recurrent state
+    and a convolution tail a slot."""
+
+    modules = ("GDNMLAModule",)
+
+    def model_config(self, model: dict, quantization: dict):
+        """See ``Family.model_config``."""
+        from fleetx_tpu.models.gdn_mla.config import config_from_dict
+
+        assert not quantization.get("weight_bits") and \
+            not quantization.get("activation_bits"), \
+            "quantized decode is not written for this family"
+        return config_from_dict(dict(model))
+
+    def init_params(self, model_cfg, seed: int):
+        """See ``Family.init_params``."""
+        import jax
+
+        from fleetx_tpu.models.gdn_mla.model import init_params
+
+        # made as it is served: the recipe's tree is 9.5 GB in bfloat16
+        return jax.jit(lambda key: init_params(model_cfg, key, served=True))(
+            jax.random.PRNGKey(seed))
+
+    def serving_params(self, params, model_cfg):
+        """See ``Family.serving_params``."""
+        from fleetx_tpu.serving.gdn_mla import serving_params
+
+        return serving_params(params, model_cfg)
+
+    def served_template(self, model_cfg):
+        """See ``Family.served_template``."""
+        from fleetx_tpu.models.gdn_mla.model import served_template
+
+        return served_template(model_cfg)
+
+    def programs(self, model_cfg, serving, sampling, mesh,
+                 pages_per_req: int) -> Programs:
+        """See ``Family.programs``."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        from fleetx_tpu.models.gdn_mla.config import LATENT, LINEAR
+        from fleetx_tpu.ops import mla_paged_attention as LA
+        from fleetx_tpu.serving import gdn_mla as S
+
+        sc, cfg = serving, model_cfg
+        assert mesh is None or mesh.size == 1, \
+            "this family serves on one chip: its programs place none of " \
+            "its caches on a mesh yet"
+        assert not sc.quantize_decode, \
+            "quantized decode is not written for this family"
+        cache = list(S.init_cache(cfg, num_pages=sc.num_pages,
+                                  page_size=sc.page_size,
+                                  max_batch=sc.max_batch))
+        refused = S.latent_kernel_refusal(cfg, page_size=sc.page_size)
+        active = bool(sc.paged_kernel) and not refused
+        if sc.paged_kernel and refused:
+            from fleetx_tpu.utils.log import logger
+
+            logger.warning("latent decode attention falls back to the "
+                           "gathered view: %s", refused)
+        fns = S.make_step_fns(cfg, prefill_chunk=sc.prefill_chunk,
+                              sampling=sampling,
+                              kernels=bool(sc.paged_kernel),
+                              latent_kernel=active)
+        folds, walk = {}, None
+        if active:
+            g = LA.fold_pages(sc.page_size, cache[0].shape[3], pages_per_req,
+                              cfg.dtype)
+            folds = {"latent": (g, g)}      # a copy a page, one buffer
+            walk = (g * sc.page_size, -(-pages_per_req // g))
+        expert_layers = sum(n for kind, n in cfg.kinds().items()
+                            if kind.endswith("moe"))
+
+        return Programs(
+            cache=cache, fns=fns,
+            tokens=jnp.zeros((sc.max_batch,), jnp.int32),
+            paged_kernel_active=active, walk_shape=walk, kv_folds=folds,
+            prefill_extra=lambda slot: (np.int32(slot),),
+            record_stats=_expert_counters(expert_layers,
+                                          cfg.num_experts_per_tok),
+            cache_bytes={"latent": int(cache[0].nbytes),
+                         "state": int(cache[1].nbytes + cache[2].nbytes)},
+            describe="%d latent layers paged (%d lanes a token), %d linear "
+                     "layers a state and a convolution tail a slot" % (
+                         cfg.layers_of(LATENT), cache[0].shape[3],
+                         cfg.layers_of(LINEAR)))
+
+
+_FAMILIES = (GPTFamily(), SWAMoEFamily(), GDNMLAFamily())
 
 
 def families() -> dict:

@@ -148,21 +148,23 @@ def paged_kernel_enabled(cfg: SWAMoEConfig, *, page_size: int,
 
 
 # ---------------------------------------------------------------- parameters
-def _unserved(params: Any, cfg: SWAMoEConfig) -> list:
+def _unserved(params: Any, cfg: Any, served_dtype=M.served_dtype) -> list:
     flat, _ = jax.tree_util.tree_flatten_with_path(params)
     return [i for i, (path, leaf) in enumerate(flat)
-            if leaf.dtype != M.served_dtype(path, cfg)]
+            if leaf.dtype != served_dtype(path, cfg)]
 
 
-def serving_params(params: Any, cfg: SWAMoEConfig) -> Any:
+def serving_params(params: Any, cfg: Any, served_dtype=M.served_dtype) -> Any:
     """The tree both programs take: every leaf in ``cfg.dtype`` but the
-    norms' scales and the router (float32). One jitted cast of the leaves
-    that need it; a leaf already served comes back as the object it was."""
-    todo = _unserved(params, cfg)
+    norms' scales and the router (float32) — ``served_dtype(path, cfg)``
+    says which (another family passes its own). One jitted cast of the
+    leaves that need it; a leaf already served comes back as the object it
+    was."""
+    todo = _unserved(params, cfg, served_dtype)
     if not todo:
         return params
     flat, treedef = jax.tree_util.tree_flatten_with_path(params)
-    want = [M.served_dtype(flat[i][0], cfg) for i in todo]
+    want = [served_dtype(flat[i][0], cfg) for i in todo]
     cast = jax.jit(lambda xs: [x.astype(d) for x, d in zip(xs, want)])(
         [flat[i][1] for i in todo])
     leaves = [leaf for _, leaf in flat]

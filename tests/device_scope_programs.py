@@ -2,8 +2,9 @@
 args)`` for ``utils.env.log_compile`` or for ``jitted.lower(*args)``: the
 three train steps (GPT on one device, GPT under ZeRO stage 2 with the
 overlapped update, the latent-attention expert family) and ``prefill`` +
-``decode`` of the three served ones (GPT, the two members of the
-windowed-attention expert family). Tests only; arguments are abstract
+``decode`` of the four served ones (GPT, the two members of the
+windowed-attention expert family, the linear-attention / latent-attention
+family). Tests only; arguments are abstract
 wherever nothing has to be initialised."""
 
 from __future__ import annotations
@@ -204,8 +205,35 @@ def smallthinker_serve() -> list:
     return swa_moe_serve(_smallthinker_toy())
 
 
+def gigachat_serve(batch=3, page=4, chunk=8, max_seq=64) -> list:
+    """The linear-attention / latent-attention family at toy widths."""
+    import gdn_mla_toy
+
+    from fleetx_tpu.models.gdn_mla import model as M
+    from fleetx_tpu.models.gdn_mla.config import config_from_dict
+    from fleetx_tpu.serving import gdn_mla as S
+    from fleetx_tpu.serving.decode import SamplingParams
+
+    cfg = config_from_dict(gdn_mla_toy.model_section())
+    per_req = max_seq // page
+    params = M.served_template(cfg)
+    cache = _abstract(jax.eval_shape(lambda: S.init_cache(
+        cfg, num_pages=1 + batch * per_req, page_size=page,
+        max_batch=batch)))
+    fns = S.make_step_fns(cfg, prefill_chunk=chunk,
+                          sampling=SamplingParams())
+    rng = _arr((2,), U32)
+    return [
+        ("serving prefill", fns["prefill"],
+         (params, *cache, _arr((1, chunk)), _arr((1, per_req)), _arr(()),
+          _arr(()), rng, _arr((), U32), _arr(()))),
+        ("serving decode", fns["decode"],
+         (params, *cache, _arr((batch,)), _arr(()), _arr((1,)),
+          _arr((batch, per_req)), _arr((batch,)), rng, _arr((), U32)))]
+
+
 #: family -> the programs' builder; a train builder takes the devices
 TRAIN = {"gpt": gpt_train, "gpt_zero2": gpt_train_zero2,
          "joyai": joyai_train}
 SERVE = {"gpt": gpt_serve, "laguna": laguna_serve,
-         "smallthinker": smallthinker_serve}
+         "smallthinker": smallthinker_serve, "gigachat": gigachat_serve}

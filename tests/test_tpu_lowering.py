@@ -564,3 +564,82 @@ def test_swa_moe_programs_keep_both_caches_one_buffer(topo, monkeypatch,
             layer_sized = ",".join(map(str, shape[1:]))
             assert not re.search(
                 r"= \w+\[(?:1,)?%s\]" % layer_sized, hlo), (name, shape)
+
+
+def test_gdn_mla_programs_keep_every_cache_one_buffer(topo, monkeypatch):
+    """``decode`` and ``prefill`` of the linear-attention / latent-attention
+    family, compiled for the v5e at widths its kernels admit (the published
+    head sizes, a narrow hidden state): the latent pool, the recurrent state
+    and the convolution's tail each aliased from input to output; the pool
+    and the state held by nothing but what enters, the loops' carries, the
+    in-place row writes and — the state — the ``gdn_decode`` kernel that
+    updates it in place (no copy, no layer of either cut out); the three
+    new Mosaic kernels in the programs under the names the trace finds them
+    by."""
+    from jax.sharding import SingleDeviceSharding
+
+    from fleetx_tpu.models.gdn_mla import model as M
+    from fleetx_tpu.models.gdn_mla.config import GDNMLAConfig
+    from fleetx_tpu.serving import gdn_mla as S
+    from fleetx_tpu.serving.decode import SamplingParams
+
+    monkeypatch.setattr(ops, "interpret", lambda: False)
+    cfg = GDNMLAConfig(
+        vocab_size=VOCAB, hidden_size=256, intermediate_size=512,
+        moe_intermediate_size=128, num_hidden_layers=5,
+        first_k_dense_replace=1, full_attention_layers=(1,),
+        num_attention_heads=8, q_lora_rank=128, kv_lora_rank=128,
+        linear_num_key_heads=2, linear_num_value_heads=4,
+        n_routed_experts=16, experts_held=8, num_experts_per_tok=3,
+        rope_scaling={"type": "yarn", "factor": 8, "beta_fast": 32,
+                      "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                      "original_max_position_embeddings": 32768})
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one)
+
+    # caches too large for the compiler to stage in on-chip memory, as the
+    # real ones are (abstract shapes: nothing is allocated)
+    batch, page, per_req, chunk, pages = 64, 16, 256, 128, 8194
+    assert not S.latent_kernel_refusal(cfg, page_size=page)
+    params = jax.tree.map(lambda a: arr(a.shape, a.dtype),
+                          M.served_template(cfg))
+    pool, state, tail = S.cache_shapes(cfg, num_pages=pages, page_size=page,
+                                       max_batch=batch)
+    assert pool[-1] == 256 and state == (4, batch, 4, 128, 128)
+    cache = [arr(pool, jnp.bfloat16), arr(state, jnp.float32),
+             arr(tail, jnp.bfloat16)]
+    fns = S.make_step_fns(cfg, prefill_chunk=chunk,
+                          sampling=SamplingParams(), kernels=True,
+                          latent_kernel=True)
+    rng = arr((2,), jnp.uint32)
+    programs = {
+        "prefill": (params, *cache, arr((1, chunk)), arr((1, per_req)),
+                    arr(()), arr(()), rng, arr((), jnp.uint32), arr(())),
+        "decode": (params, *cache, arr((batch,)), arr(()), arr((1,)),
+                   arr((batch, per_req)), arr((batch,)), rng,
+                   arr((), jnp.uint32)),
+    }
+    kernels = {"prefill": {"moe_gmm_prefill", "gdn_chunk"},
+               "decode": {"moe_gmm_decode", "gdn_decode",
+                          "mla_paged_decode"}}
+    # (a bitcast moves nothing: the one latent layer's pool seen without
+    # its leading 1)
+    in_place = _POOL_CARRIERS | {"custom-call", "dynamic-update-slice",
+                                 "bitcast"}
+    n_params = len(jax.tree.leaves(params))
+    for name, args in programs.items():
+        lowered = fns[name].lower(*args)
+        assert set(mosaic_kernels(lowered.as_text())) == kernels[name], name
+        hlo = lowered.compile().as_text()
+        alias = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo)
+        assert alias, f"{name}: no input-output aliasing at all"
+        for out in range(3):
+            assert f"{{{out}}}: ({n_params + out}, {{}}," in alias.group(1), \
+                (name, out, alias.group(1))
+        for shape in (pool, state):
+            holders = _pool_holders(hlo, shape)
+            assert holders, f"{name}: the cache {shape} is not in the program"
+            stray = [h for h in holders if h[0] not in in_place]
+            assert not stray, (name, stray)
