@@ -14,7 +14,7 @@ online-softmax accumulator in f32 VMEM scratch (the
 ``ops/flash_attention.py`` m/l/acc discipline) folds the pages into the
 output without ever holding more than two slots of ``pages_per_step``
 ``[page_size, head_block·head_dim]`` tiles of K and of V live
-(`pick_pages_per_step`: the pages that move 256 KB a pool; below). Both
+(`pick_pages_per_step`: the pages that move 512 KB a pool; below). Both
 products of a fold follow the POOL'S dtype and nothing else: a bfloat16
 pool takes one MXU pass, bfloat16 probabilities into the value tile as it
 landed (as prefill and the gather path do), a float32 pool the exact
@@ -78,8 +78,8 @@ How a fold's tile is fetched follows the layout. **Pages a fold follow the
 bytes a fold moves** (`pick_pages_per_step`): a fold's cost is the core's
 own — a loop step, the copies started and waited for, the scalar work of
 its pages — and is not hidden behind the copies, so a pool half as wide
-takes twice the pages for the same bytes (8 pages of 1,024 bfloat16 lanes,
-16 of 512). **A ring is fetched in one copy a pool a fold**: a caller that
+takes twice the pages for the same bytes (16 pages of 1,024 bfloat16 lanes,
+32 of 512). **A ring is fetched in one copy a pool a fold**: a caller that
 keeps a row's last tokens in a ring it laid out itself (``window`` + one
 prefill chunk of tokens a slot, ``ring_pages`` consecutive pages of the
 buffer, logical page *j* at ``j mod ring_pages``: ``serving/swa_moe.py``)
@@ -147,26 +147,33 @@ _PAGED_VMEM_BUDGET_BYTES = 4 * 1024 * 1024
 #: stays narrow; decode attention is DMA-bound and the MXU otherwise idle
 _MAX_HEAD_BLOCK = 16
 
-#: what one fold moves, a pool: the K (or V) tile of the 345M serving
-#: geometry's fold, 8 pages of ``[16, 1024]`` bfloat16. A fold's fixed cost
-#: (a loop step, the copies started and waited for, the scalar work of its
-#: pages run twice) is the core's own and is not hidden behind the copies:
-#: 24 layer calls at that geometry on the v5e, 64 rows of 128–767 tokens,
-#: 1 / 2 / 4 / 8 / 16 pages a fold: 22.5 / 13.2 / 8.7 / 7.1 / 7.2 ms (6.2 /
-#: 5.9 at 8 / 16 once a row's last fold starts the next row's first copies,
-#: PR 30; 6.0 / 5.1 on PR 41's one-pass product: PERF.md section 7 has what
-#: 16 would buy) — and on a pool half as wide (4 key-value heads of 128: a
-#: page 16 KB) the same 8 pages move half the bytes for the same cost, where
-#: 16 read 7.7 % off the whole decode program (PR 38). So the pages of a
-#: fold follow the bytes: 8 on a 1,024-lane bfloat16 pool (the folds run in
-#: the order they always have), 16 on a 512-lane one. The pages of a row's
-#: last group past its query are fetched for nothing: the target stays put
-_FOLD_BYTES = 256 * 1024
-
-#: ... and never fewer than this many pages: the fixed cost is spread over
-#: pages whatever they weigh, so a heavier page (a float32 pool) keeps the
-#: count the sweep above chose and leaves the halving to the VMEM budget
-_MIN_FOLD_PAGES = 8
+#: what one fold moves, a pool: 16 pages of ``[16, 1024]`` bfloat16 (the 345M
+#: serving geometry and Laguna's full layers), 32 of ``[16, 512]``
+#: (SmallThinker's). A fold's cost is the core's own and is not hidden behind
+#: the copies: ~0.39 µs whatever its pages (a loop step, two products, the
+#: accumulator's rescale) plus ~0.064 µs a page (its table entry and
+#: predicate read at the start and at the fold, two copies started and two
+#: waited for, its rows of the mask) — the SAME 0.058 µs a page on a pool
+#: half as wide, so the pages of a fold follow its bytes and the larger
+#: fold wins until VMEM ends it. The kernel alone on the v5e, 8 /
+#: 16 pages a fold (PR 43's sweep, on PR 41's one-pass product; docs/serving.md
+#: has PR 30's and PR 38's): 24 layer calls at the 345M geometry, 64 rows of
+#: 128–767 tokens 5.80 / 4.87 ms (HBM floor 3.50), 64 rows of 1,023 11.12 /
+#: 8.70 (floor 7.87), 8 live rows of 64 0.97 / 0.91; Laguna's 3 full layers,
+#: 48 query rows over 8 × 128, contexts to 9k, 5.09 / 4.10 (floor 3.38);
+#: SmallThinker's 2, 28 rows over 4 × 128, 512 lanes, contexts 4k–12k, at 16 /
+#: 32 pages 3.46 / 3.03 (floor 1.71). Two slots a pool of 512 KB are 2 MB of
+#: the 4 MB budget; 1 MB a pool does not fit. The pages of a row's last group
+#: past its query (17 % of what the decode cell's folds copy, 3 % at long
+#: contexts) are copied from page 0 for nothing, and for free: with their
+#: copies skipped — not started, not waited for — the same sweep read 5.10 ms
+#: for 4.87 and 8.98 for 8.70 (a branch a page costs more than two copies
+#: nobody waits long for), and with a group's upper half neither fetched nor
+#: multiplied where the query does not reach it, 4.80 and 8.73: a page's
+#: cost is what the unrolled fold does for it whether it counts or not. A
+#: heavier page (float32, a wider block) takes fewer pages for the same
+#: bytes, down to the one the VMEM budget allows
+_FOLD_BYTES = 512 * 1024
 
 #: what one fold of a RING moves, a pool. A ring's pages are one run of the
 #: buffer, fetched in one copy a pool whatever their number, so nothing of a
@@ -217,12 +224,12 @@ def pick_pages_per_step(*, num_heads: int, head_dim: int, page_size: int,
                         ring_pages: Optional[int] = None) -> int:
     """Pages one fold takes, from the bytes of a page of the pool it is
     given (``page_size · head block · head_dim · itemsize``, a pool): the
-    power of two that moves `_FOLD_BYTES` a pool (no fewer than
-    `_MIN_FOLD_PAGES`), no more than a request has, halved until its two
-    slots a pool fit the VMEM budget; 0 when not even one page does. The
-    head block is picked over the KEY-VALUE heads (``num_kv_heads``; all
-    the heads when None), each with ``num_heads / num_kv_heads`` query
-    rows. With ``ring_pages`` — the caller's pages are a ring of that many
+    power of two that moves `_FOLD_BYTES` a pool (at least one page), no
+    more than a request has, halved until its two slots a pool fit the
+    VMEM budget; 0 when not even one page does. The head block is picked
+    over the KEY-VALUE heads (``num_kv_heads``; all the heads when None),
+    each with ``num_heads / num_kv_heads`` query rows. With
+    ``ring_pages`` — the caller's pages are a ring of that many
     consecutive pages of the buffer — the target is `_RING_FOLD_BYTES` and
     the count also divides the ring, so that an aligned group of a ring's
     pages is one run of the buffer and never straddles the wrap."""
@@ -231,10 +238,8 @@ def pick_pages_per_step(*, num_heads: int, head_dim: int, page_size: int,
     if hb == 0 or num_heads % kv:
         return 0
     page_bytes = page_size * hb * head_dim * jnp.dtype(dtype).itemsize
-    if ring_pages is None:
-        most = max(_FOLD_BYTES // page_bytes, _MIN_FOLD_PAGES)
-    else:
-        most = max(_RING_FOLD_BYTES // page_bytes, 1)
+    target = _FOLD_BYTES if ring_pages is None else _RING_FOLD_BYTES
+    most = max(target // page_bytes, 1)
     g = 1 << (most.bit_length() - 1)
     while g and (g > pages_per_req or _step_vmem_bytes(
             g, page_size, hb, head_dim, dtype, num_heads // kv)

@@ -479,38 +479,43 @@ def test_tools_serve_builds_the_recipe_through_the_registry():
 
 
 # ------------------------------------------------------------- the kernel
-def _kernel_case(dtype, heads, kv, hd, *, B=6, ps=16, per_req=12, layers=2,
-                 seed=35):
+def _kernel_case(dtype, heads, kv, hd, *, ps=16, per_req=12, layers=2,
+                 seed=35, lens=None):
     from fleetx_tpu.ops import paged_attention as PA  # noqa: F401
 
     rng = np.random.default_rng(seed)
+    if lens is None:
+        lens = [-1, 0, 37, per_req * ps - 1, 100, 129]
+    B = len(lens)
     shape = (layers, 1 + B * per_req, ps, kv * hd)
     pk = jnp.asarray(rng.normal(size=shape), dtype)
     pv = jnp.asarray(rng.normal(size=shape), dtype)
     q = jnp.asarray(rng.normal(size=(B, heads, hd)), dtype)
     tables = (1 + rng.permutation(B * per_req).reshape(B, per_req)
               ).astype(np.int32)
-    lens = np.array([-1, 0, 37, per_req * ps - 1, 100, 129], np.int32)
+    lens = np.array(lens, np.int32)
     for b in range(B):      # lazy allocation: null pages past the query
         tables[b, max(int(lens[b]), -1) // ps + 1:] = 0
     return q, pk, pv, jnp.asarray(tables), jnp.asarray(lens)
 
 
 def _gathered(q, pk, pv, tables, lens, layer, window):
-    """The gather path's attention over the same pages, float64."""
+    """The gather path's attention over the same pages, float64; the keys
+    of a null page below the query (a hole in the table) are not seen."""
     B, H, hd = q.shape
     kv = pk.shape[-1] // hd
     kd = np.asarray(pk[layer][tables], np.float64).reshape(B, -1, kv, hd)
     vd = np.asarray(pv[layer][tables], np.float64).reshape(B, -1, kv, hd)
+    named = np.repeat(np.asarray(tables) != 0, pk.shape[2], axis=1)
     out = np.zeros((B, H, hd))
     for b in range(B):
         last = int(lens[b])
         if last < 0:
             continue
         lo = 0 if window is None else max(last - window + 1, 0)
+        at = lo + np.flatnonzero(named[b, lo:last + 1])
         for j in range(H):
-            k, v = kd[b, lo:last + 1, j // (H // kv)], \
-                vd[b, lo:last + 1, j // (H // kv)]
+            k, v = kd[b, at, j // (H // kv)], vd[b, at, j // (H // kv)]
             s = k @ np.asarray(q[b, j], np.float64) / math.sqrt(hd)
             p = np.exp(s - s.max())
             out[b, j] = (p / p.sum()) @ v
@@ -537,6 +542,73 @@ def test_paged_attention_with_fewer_kv_heads_and_a_window(heads, kv, hd,
         np.testing.assert_allclose(got, _gathered(*args, layer, window),
                                    atol=3e-6, rtol=0)
         assert not got[0].any()         # an inactive row: exact zeros
+
+
+#: query positions of the long-table case's rows: either side of a 16-page
+#: fold's edge (256 tokens at pages of 16) and of the next, an inactive row
+#: between live ones, a row inside its first fold and one at its table's end
+_FOLD_EDGE_LENS = (255, 256, -1, 257, 511, 512, 37, 639)
+
+
+@pytest.mark.parametrize("stale", ["finite", "nan"])
+@pytest.mark.parametrize("heads,kv,hd", [(16, 16, 64), (6 * 8, 8, 128)],
+                         ids=["group1", "group6"])
+def test_a_fold_of_16_pages_on_a_long_table_is_the_gather(heads, kv, hd,
+                                                          stale):
+    """A bfloat16 pool 1,024 lanes wide and 40 pages a request: the table
+    walk folds 16 pages at a time (`_kernel_case`'s 12 pages a request never
+    reach such a fold). Against the gathered reference for query positions
+    either side of a fold's edge, an inactive row between live ones and a
+    null entry INSIDE a counted group (a hole below the query: its keys are
+    masked). ``nan``: every page no table names but the null page holds NaN
+    — a page that does not count is copied from local page 0 in its place,
+    so nothing of them reaches the output (the null page is READ and
+    multiplied by a zero probability: it has to be finite)."""
+    from fleetx_tpu.ops import paged_attention as PA
+
+    ps, per_req = 16, 40
+    assert PA.fold_shape(num_heads=heads, num_kv_heads=kv, head_dim=hd,
+                         page_size=ps, pages_per_req=per_req,
+                         dtype=jnp.bfloat16) == (16, 16)
+    q, pk, pv, tables, lens = _kernel_case(
+        jnp.bfloat16, heads, kv, hd, per_req=per_req, layers=1,
+        lens=_FOLD_EDGE_LENS)
+    tables = np.array(tables)
+    tables[4, 5] = tables[7, 20] = 0    # holes below the query
+    if stale == "nan":
+        unnamed = np.setdiff1d(np.arange(1, pk.shape[1]), tables[tables > 0])
+        pk, pv = (pool.at[:, unnamed].set(jnp.nan) for pool in (pk, pv))
+    got = np.asarray(PA.paged_attention(
+        q, pk, pv, jnp.asarray(tables), lens, jnp.int32(0)
+    ).astype(jnp.float32))
+    assert np.isfinite(got).all()
+    assert not got[2].any()             # the inactive row: exact zeros
+    np.testing.assert_allclose(
+        got, _gathered(q, pk, pv, tables, lens, 0, None), atol=2e-2, rtol=0)
+
+
+#: cell geometry -> (query heads, key-value heads, head_dim, table columns,
+#: pages a fold): 512 KB a pool a fold, PR 43's sweep on the one-pass product
+_CELL_FOLDS = {
+    "gpt345m": (16, 16, 64, 64, 16),
+    "laguna-full-layers": (48, 8, 128, 608, 16),
+    "smallthinker-full-layers": (28, 4, 128, 816, 32),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_FOLDS))
+def test_pages_a_fold_at_the_cells_geometries(cell):
+    from fleetx_tpu.ops import paged_attention as PA
+
+    heads, kv, hd, cols, fold = _CELL_FOLDS[cell]
+    geometry = dict(num_heads=heads, num_kv_heads=kv, head_dim=hd,
+                    page_size=16, pages_per_req=cols, dtype=jnp.bfloat16)
+    assert PA.pick_pages_per_step(**geometry) == fold
+    assert PA.page_walk_shape(**geometry) == (fold * 16, -(-cols // fold))
+    # two slots a pool of it (2 MB) and the scratch fit the fold's budget
+    assert 2 << 20 < PA._step_vmem_bytes(
+        fold, 16, PA.pick_head_block(kv, hd, jnp.bfloat16), hd,
+        jnp.bfloat16, heads // kv) <= PA._PAGED_VMEM_BUDGET_BYTES
 
 
 def test_with_as_many_kv_heads_and_no_window_a_float32_pool_keeps_its_bits():
@@ -749,25 +821,26 @@ def test_a_ring_fetched_in_one_copy_is_the_table_path_to_the_bit(
 
 
 def test_pages_a_fold_follow_the_bytes_a_fold_moves():
-    """The one rule, at the geometries that are served: 8 pages a fold on
+    """The one rule, at the geometries that are served: 16 pages a fold on
     the 1,024-lane bfloat16 pools (both GPT cells: 16 heads of 64; Laguna's
-    full layers: 8 key-value heads of 128), 16 on the second member's
-    512-lane full pool; a ring is one copy a buffer whatever its pages, and
-    takes 32 pages of 512 lanes or 16 of 1,024; a float32 pool keeps 8."""
+    full layers: 8 key-value heads of 128), 32 on the second member's
+    512-lane full pool — 512 KB a pool, as a ring's fold; a ring is one
+    copy a buffer whatever its pages, and takes 32 pages of 512 lanes or 16
+    of 1,024; a float32 pool keeps 8 (its page is twice the bytes)."""
     from fleetx_tpu.ops import paged_attention as PA
 
     page = dict(head_dim=128, page_size=16, dtype=jnp.bfloat16)
     assert PA.fold_shape(num_heads=16, head_dim=64, page_size=16,
-                         pages_per_req=64, dtype=jnp.bfloat16) == (8, 8)
+                         pages_per_req=64, dtype=jnp.bfloat16) == (16, 16)
     assert PA.fold_shape(num_heads=16, head_dim=64, page_size=16,
                          pages_per_req=64, dtype=jnp.float32) == (8, 8)
     for heads in (48, 72):
         assert PA.fold_shape(num_heads=heads, num_kv_heads=8,
-                             pages_per_req=608, **page) == (8, 8)
+                             pages_per_req=608, **page) == (16, 16)
     assert PA.fold_shape(num_heads=72, num_kv_heads=8, pages_per_req=64,
                          ring_pages=64, **page) == (16, 1)
     assert PA.fold_shape(num_heads=28, num_kv_heads=4, pages_per_req=816,
-                         **page) == (16, 16)
+                         **page) == (32, 32)
     assert PA.fold_shape(num_heads=28, num_kv_heads=4, pages_per_req=288,
                          ring_pages=288, **page) == (32, 1)
     # no more than a request has; a ring no fold divides is walked a page
@@ -1058,9 +1131,10 @@ def test_the_second_members_built_tree_is_3967_m_parameters():
             M.pass_rows(model_cfg, 512)) == (16, 1024, 4096)
     # group 7: the kernel takes all 4 key-value heads, 28 query rows, in
     # one block. A page of the 512-lane pool is 16 KB, so a fold of the
-    # full layers takes 16 pages (256 keys) and one of a ring, fetched in
-    # one copy a buffer, 32 (512 keys): a row's 4,096-token window is
-    # walked in 9 folds at most (33 of 8 pages through a block table)
+    # full layers takes 32 pages (512 keys: 512 KB a pool, PR 43) and one
+    # of a ring, fetched in one copy a buffer, as many: a row's
+    # 4,096-token window is walked in 9 folds at most (33 of 8 pages
+    # through a block table)
     from fleetx_tpu.ops import paged_attention as PA
 
     per_req = -(-sc["max_seq_len"] // sc["page_size"])
@@ -1070,7 +1144,7 @@ def test_the_second_members_built_tree_is_3967_m_parameters():
     geometry = dict(num_heads=28, head_dim=128, page_size=16,
                     dtype=jnp.bfloat16, num_kv_heads=4)
     span, folds = PA.page_walk_shape(pages_per_req=per_req, **geometry)
-    assert (span, folds) == (256, 51)
+    assert (span, folds) == (512, 26)
     ring_span = 16 * PA.pick_pages_per_step(
         pages_per_req=288, ring_pages=288, **geometry)
     assert ring_span == 512

@@ -399,8 +399,8 @@ def test_paged_decode_compiles_at_the_serve_cells_geometry(topo, monkeypatch):
 #: pages a fold of a ring): both caches of each shipped recipe of the
 #: second family, bfloat16, pages of 16 tokens
 _SWA_CACHES = {
-    "laguna_s": (64, 72, 8, 18001, 608, 64, 512, 8, 16),
-    "smallthinker": (48, 28, 4, 28001, 816, 288, 4096, 16, 32),
+    "laguna_s": (64, 72, 8, 18001, 608, 64, 512, 16, 16),
+    "smallthinker": (48, 28, 4, 28001, 816, 288, 4096, 32, 32),
 }
 
 

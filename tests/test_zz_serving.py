@@ -931,7 +931,7 @@ def test_page_walk_share_gauge_counts_what_the_kernel_folds(small_model,
 def test_fold_gauges_of_a_gpt_engine_are_set_at_its_build(small_model):
     """How the decode kernel fetches a fold is fixed when the engine is
     built: at the 345M serving geometry (16 heads of 64, pages of 16,
-    bfloat16 — one layer and a small vocabulary here) 8 pages a fold, a
+    bfloat16 — one layer and a small vocabulary here) 16 pages a fold, a
     copy a page, and no window cache; on the gathered view all four gauges
     read 0 and the snapshot names no fold."""
     cfg, _, params = _build_model(
@@ -949,10 +949,10 @@ def test_fold_gauges_of_a_gpt_engine_are_set_at_its_build(small_model):
                       "copies_window": 0}      # a request has 4 pages
     eng = ServingEngine(cfg, params, ServingConfig(
         **dict(sc, num_pages=65, max_seq_len=1024)), eos_token_id=EOS)
-    assert read() == {"pages_full": 8, "copies_full": 8, "pages_window": 0,
+    assert read() == {"pages_full": 16, "copies_full": 16, "pages_window": 0,
                       "copies_window": 0}
     snap = eng.serving_snapshot()
-    assert snap["kv_folds"] == {"full": [8, 8]}
+    assert snap["kv_folds"] == {"full": [16, 16]}
     assert not validate_serving_record(snap)
     gathered = ServingEngine(
         small_model[0], small_model[2],
