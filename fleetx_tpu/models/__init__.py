@@ -55,6 +55,11 @@ def get_registry():
         modules["GDNMLAModule"] = GDNMLAModule
     except ImportError:
         pass
+    try:
+        from fleetx_tpu.models.conv_moe.module import ConvMoEModule
+        modules["ConvMoEModule"] = ConvMoEModule
+    except ImportError:
+        pass
     return modules
 
 

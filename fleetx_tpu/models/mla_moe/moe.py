@@ -45,11 +45,12 @@ from fleetx_tpu.ops import grouped_matmul
 # ------------------------------------------------------------------ routing
 @device_scope("moe.route")
 def route(x2d: jax.Array, router: jax.Array, bias: jax.Array, top_k: int,
-          scaling: float, normalise: bool):
+          scaling: float, normalise: bool, eps: float = 1e-20):
     """``x2d`` [N, h] -> (expert ids [N, k], weights [N, k] float32, load
     [E] float32). Scores are sigmoids in float32; the k largest of
     ``score + bias`` are chosen; weights are the chosen scores themselves
-    (without the bias), over their sum, times ``scaling``."""
+    (without the bias), over their sum plus ``eps`` (a family's own:
+    ``models/conv_moe`` divides by the sum + 1e-6), times ``scaling``."""
     logits = jnp.einsum("nh,he->ne", x2d.astype(jnp.float32),
                         router.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
@@ -57,7 +58,7 @@ def route(x2d: jax.Array, router: jax.Array, bias: jax.Array, top_k: int,
     _, ids = jax.lax.top_k(scores + jax.lax.stop_gradient(bias)[None], top_k)
     picked = jnp.take_along_axis(scores, ids, axis=-1)
     if normalise:
-        picked = picked / (picked.sum(-1, keepdims=True) + 1e-20)
+        picked = picked / (picked.sum(-1, keepdims=True) + eps)
     n_experts = router.shape[-1]
     load = (ids[..., None] == jnp.arange(n_experts)).sum(
         axis=(0, 1)).astype(jnp.float32)

@@ -203,6 +203,11 @@ DEVICE_SCOPES: dict = {
                 "(triangular system, gdn_chunk kernel), the one-token "
                 "step (gdn_decode), the heads' L2 norms, the state's read "
                 "and write",
+    "conv.proj": "a short-convolution layer's two products: into the two "
+                 "gates and the convolution's input, and out",
+    "conv.mix": "the gates' products with the input and the output, the "
+                "depth-wise taps, and the read and write of the slot's "
+                "last inputs (the tail)",
     "mlp": "dense MLP, shared expert, and the block's closing residual add",
     "moe.route": "router product and scoring, top-k, plan_rows' sort and "
                  "search, the gathers into expert order, the combine's "

@@ -67,8 +67,15 @@ key-value head ``r // group``), so a key-value tile is copied once and
 read by all the query heads that share it. Which lanes a row owns is a
 0/1 mask the wrapper makes once and the kernel keeps in VMEM (its block
 index never changes), and the finish folds each row's own ``head_dim``
-lanes out of its ``[rows, kv_heads·head_dim]`` accumulator. With a
-``window`` a row's walk does not start at its first page but at the group
+lanes out of its ``[rows, kv_heads·head_dim]`` accumulator. A grouped head
+may be whole lane tiles (``head_dim`` a multiple of 128: the row's query is
+concatenated under every key-value head, the finish adds the lane slices) or
+HALF of one (``head_dim`` 64, 32 query heads over 8 key-value heads: Mosaic
+has no concatenation at half-tile offsets, so a 0/1 matrix ``[head_dim,
+lanes]`` repeats the row on the MXU — each value passes as it is — and its
+transpose folds the accumulator's own lanes out at ``HIGHEST`` precision,
+exactly: one term of every sum is not zero); any other width is refused.
+With a ``window`` a row's walk does not start at its first page but at the group
 that holds position ``lens − window + 1``: keys older than the window are
 neither fetched nor scored, whatever the context. With ``heads ==
 kv_heads`` and no window a float32 pool's outputs are to the bit what they
@@ -215,6 +222,8 @@ def _step_vmem_bytes(pages: int, page_size: int, hb: int, head_dim: int,
     scratch = rows * width * (esize + 4) + 2 * rows * 128 * 4  # (rows, 1) pads
     if group > 1:
         scratch += rows * width * 4                     # the lane mask
+        if head_dim % 128:
+            scratch += head_dim * width * 4             # the lane spread
     return tiles + scratch
 
 
@@ -286,7 +295,10 @@ def paged_attention_refusal(*, num_heads: int, head_dim: int,
     rows of a head block are then ``group`` to a key-value head, so they
     have to fill whole sublane tiles or be all the heads (7 query heads to
     each of 4 key-value heads: one block of all 28 rows), and a head's
-    lanes have to be whole lane tiles (``head_dim`` a multiple of 128).
+    lanes have to be whole lane tiles (``head_dim`` a multiple of 128) or
+    HALF of one (``head_dim`` 64: 4 query heads to each of 8 key-value
+    heads is one block of 32 rows over 512 lanes); a grouped head of any
+    other width is refused — nothing here has compiled one.
     """
     if num_heads < 1 or pages_per_req < 1 or page_size < 1:
         return "no heads, pages or page rows"
@@ -298,9 +310,9 @@ def paged_attention_refusal(*, num_heads: int, head_dim: int,
         hb = pick_head_block(kv, head_dim, dtype)
         rows = hb * (num_heads // kv)
         sublanes = 8 * 4 // jnp.dtype(dtype).itemsize
-        if head_dim % 128:
-            return f"head_dim {head_dim} is not whole 128-lane tiles " \
-                   f"(grouped queries)"
+        if head_dim % 128 and head_dim != 64:
+            return f"head_dim {head_dim} is neither whole 128-lane tiles " \
+                   f"nor half of one (grouped queries)"
         if hb == 0 or (hb != kv and rows % sublanes):
             return f"no block of the {kv} key-value heads gives query " \
                    f"rows in whole {sublanes}-row sublane tiles"
@@ -413,8 +425,11 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, *refs,
     accumulator, m and l (f32). ``Q · Kᵀ`` and ``P · V`` are one MXU pass
     each in a bf16 pool's dtype, the multi-pass product in an f32 pool's.
     """
+    spread_ref = None
     if group > 1:
         own_ref, refs = refs[0], refs[1:]
+        if head_dim % 128:
+            spread_ref, refs = refs[0], refs[1:]
     (k_hbm, v_hbm, acc_out_ref, m_out_ref, l_out_ref,
      k_buf, v_buf, sems, ahead_ref, qd_ref, acc_ref, m_ref, l_ref) = refs
     b = pl.program_id(0)
@@ -524,9 +539,20 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, *refs,
         else:
             # a row's query repeated under every key-value head's lanes,
             # kept where the row owns them
-            q = q_ref[0].astype(jnp.float32)               # [rows, hd]
-            qd_ref[...] = (jnp.concatenate([q] * (width // head_dim), axis=1)
-                           * own_ref[...]).astype(qd_ref.dtype)
+            if spread_ref is None:
+                q = q_ref[0].astype(jnp.float32)           # [rows, hd]
+                q = jnp.concatenate([q] * (width // head_dim), axis=1)
+            else:
+                # a head narrower than a lane tile: no concatenation at
+                # half-tile offsets, the MXU repeats the row instead (a
+                # product with 0 / 1: each value comes through as it is)
+                q = jax.lax.dot_general(
+                    q_ref[0], spread_ref[...].astype(q_ref.dtype),
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST
+                    if q_ref.dtype == jnp.float32 else None)
+            qd_ref[...] = (q * own_ref[...]).astype(qd_ref.dtype)
 
         def fold(i, _):
             grp = g0 + i
@@ -600,11 +626,18 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, *refs,
     if group == 1:
         acc_out_ref[0] = jnp.where(own_lanes(), acc_ref[...], 0.0).sum(
             axis=0, keepdims=True)
-    else:
+    elif spread_ref is None:
         kept = acc_ref[...] * own_ref[...]
         acc_out_ref[0] = functools.reduce(jnp.add, [
             kept[:, j * head_dim:(j + 1) * head_dim]
             for j in range(width // head_dim)])
+    else:
+        # ... and folds each row's own lanes out again (exact: one term of
+        # every sum is not zero, and HIGHEST keeps all of a float32)
+        acc_out_ref[0] = jax.lax.dot_general(
+            acc_ref[...] * own_ref[...], spread_ref[...],
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST)
     m_out_ref[0] = m_ref[...]
     l_out_ref[0] = l_ref[...]
 
@@ -670,6 +703,13 @@ def _paged_call(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
         acc_spec = pl.BlockSpec((1, rows, hd), ml_map)
         acc_shape = (B, nh, hd)
         inputs = (q.astype(pool_k.dtype), own)
+        if hd % 128:
+            # lane l of a block repeats value l mod hd of a row's query
+            spread = (jnp.arange(hd)[:, None]
+                      == jnp.arange(width)[None, :] % hd).astype(jnp.float32)
+            q_specs.append(pl.BlockSpec((hd, width),
+                                        lambda b, h, t, l, lay: (0, 0)))
+            inputs += (spread,)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,

@@ -11,7 +11,7 @@ stage shards which term, and the serving KV pool hand-wired its own
 hardware. Here the whole mapping is *data*:
 
 - ``PARTITION_RULES``: per model family (``gpt``, ``gpt_moe``,
-  ``gpt_lora``, ``vision``, ``ernie``, ``imagen``, ``mla_moe``, ``swa_moe``, ``gdn_mla``, plus the serving KV
+  ``gpt_lora``, ``vision``, ``ernie``, ``imagen``, ``mla_moe``, ``swa_moe``, ``gdn_mla``, ``conv_moe``, plus the serving KV
   pool as ``serving_kv``), an
   ORDERED tuple of ``(regex, logical-axes template)`` rules matched against
   slash-joined parameter-tree paths, first match wins — the
@@ -360,6 +360,28 @@ PARTITION_RULES: dict[str, tuple] = {
         (r"head/kernel$", ("embed", "vocab")),
         (r"(^|/)\w*norm/w$", ("norm",)),
     ),
+    # the short-convolution / grouped-query sparse-expert decoder
+    # (models/conv_moe; served on one chip): the convolution's two products
+    # and the attention products over the Megatron axis, the taps with
+    # their channels, the head-wide norms replicated; the experts over
+    # ``expert``, the router and its selection bias replicated; no head
+    # leaf (tied to the embedding)
+    "conv_moe": (
+        (r"conv/in$", ("embed", "heads")),
+        (r"conv/taps$", (None, "heads")),
+        (r"conv/out$", ("heads", "embed")),
+        (r"attn/(q|k|out)$", ("heads", "kv", "embed")),
+        (r"attn/v$", ("embed", "heads")),
+        (r"attn/(q_norm|k_norm)$", (None,)),
+        (r"mlp/(gate|up)$", ("embed", "mlp")),
+        (r"mlp/down$", ("mlp", "embed")),
+        (r"moe/router$", ("embed", None)),
+        (r"moe/expert_bias$", (None,)),
+        (r"moe/experts_(gate|up)$", ("expert", "embed", None)),
+        (r"moe/experts_down$", ("expert", None, "embed")),
+        (r"embed/tokens$", ("vocab", "embed")),
+        (r"(^|/)\w*norm/scale$", ("norm",)),
+    ),
     # the serving KV page pool (serving/paged_cache.py): pages over the
     # ZeRO axis (capacity scales with fsdp), heads over the Megatron axis
     # (heads and head_dim share the pool's minor dim, heads major)
@@ -380,6 +402,7 @@ STACK_MARKERS: dict[str, str] = {
     "mla_moe": r"(^|/)(dense_layers|moe_layers|mtp/layers)/",
     "swa_moe": r"(^|/)(full|window)_(dense|moe)/",
     "gdn_mla": r"(^|/)(linear|latent)_(dense|moe)/",
+    "conv_moe": r"(^|/)(conv|full)_(dense|moe)/",
 }
 
 #: families whose fully-replicated leaves are accepted at ANY size by the

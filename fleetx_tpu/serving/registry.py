@@ -42,8 +42,9 @@ class Programs:
     # the engine does not have, or every kind on the gathered view, is absent
     kv_folds: dict = dataclasses.field(default_factory=dict)
     # bytes of the caches that are not lists of keys and values: "latent"
-    # (a paged pool of latents) and "state" (constant-size state a slot);
-    # absent in a family without them
+    # (a paged pool of latents) and "state" (constant-size state a slot: a
+    # recurrent state, a convolution's tail); absent in a family without
+    # them
     cache_bytes: dict = dataclasses.field(default_factory=dict)
     # slot -> further arguments of ``prefill`` after the rng and the draw
     prefill_extra: Callable = lambda slot: ()
@@ -169,10 +170,15 @@ class GPTFamily(Family):
                         kv_folds=folds)
 
 
-def _expert_counters(expert_layers: int, per_tok: int) -> Callable:
-    """``Programs.record_stats`` of a family with sparse experts: what its
-    decode program returns after its logits -> the ``serving_moe_*``
-    metrics."""
+def _expert_counters(cfg) -> Callable:
+    """``Programs.record_stats`` of a family with sparse experts (``cfg``:
+    its model config, whose ``kinds()`` name the stacks with experts
+    ``*moe``): what its decode program returns after its logits -> the
+    ``serving_moe_*`` metrics."""
+    expert_layers = sum(n for kind, n in cfg.kinds().items()
+                        if kind.endswith("moe"))
+    per_tok = cfg.num_experts_per_tok
+
     def record(metrics, stats):
         if not expert_layers:
             return
@@ -280,8 +286,6 @@ class SWAMoEFamily(Family):
                                            **geometry),
                      "window": PA.fold_shape(pages_per_req=ring,
                                              ring_pages=ring, **geometry)}
-        expert_layers = sum(n for kind, n in cfg.kinds().items()
-                            if kind.endswith("moe"))
 
         def kv_tokens(lens):
             live = lens[lens >= 0]
@@ -292,8 +296,7 @@ class SWAMoEFamily(Family):
             tokens=jnp.zeros((sc.max_batch,), jnp.int32),
             paged_kernel_active=active, walk_shape=walk, kv_folds=folds,
             prefill_extra=lambda slot: (np.int32(slot),),
-            record_stats=_expert_counters(expert_layers,
-                                          cfg.num_experts_per_tok), kv_tokens=kv_tokens,
+            record_stats=_expert_counters(cfg), kv_tokens=kv_tokens,
             describe="%d full layers paged, %d window layers a ring of %d "
                      "pages a slot" % (cfg.layers_of("full"),
                                        cfg.layers_of("window"), ring))
@@ -374,16 +377,13 @@ class GDNMLAFamily(Family):
                               cfg.dtype)
             folds = {"latent": (g, g)}      # a copy a page, one buffer
             walk = (g * sc.page_size, -(-pages_per_req // g))
-        expert_layers = sum(n for kind, n in cfg.kinds().items()
-                            if kind.endswith("moe"))
 
         return Programs(
             cache=cache, fns=fns,
             tokens=jnp.zeros((sc.max_batch,), jnp.int32),
             paged_kernel_active=active, walk_shape=walk, kv_folds=folds,
             prefill_extra=lambda slot: (np.int32(slot),),
-            record_stats=_expert_counters(expert_layers,
-                                          cfg.num_experts_per_tok),
+            record_stats=_expert_counters(cfg),
             cache_bytes={"latent": int(cache[0].nbytes),
                          "state": int(cache[1].nbytes + cache[2].nbytes)},
             describe="%d latent layers paged (%d lanes a token), %d linear "
@@ -392,7 +392,94 @@ class GDNMLAFamily(Family):
                          cfg.layers_of(LINEAR)))
 
 
-_FAMILIES = (GPTFamily(), SWAMoEFamily(), GDNMLAFamily())
+class ConvMoEFamily(Family):
+    """Gated short-convolution layers beside grouped-query attention over
+    sparse experts held whole (``models/conv_moe``, ``serving/conv_moe.py``;
+    ``docs/conv_moe.md``): a paged key-value pool for the attention layers
+    only, and a convolution tail a slot."""
+
+    modules = ("ConvMoEModule",)
+
+    def model_config(self, model: dict, quantization: dict):
+        """See ``Family.model_config``."""
+        from fleetx_tpu.models.conv_moe.config import config_from_dict
+
+        assert not quantization.get("weight_bits") and \
+            not quantization.get("activation_bits"), \
+            "quantized decode is not written for this family"
+        return config_from_dict(dict(model))
+
+    def init_params(self, model_cfg, seed: int):
+        """See ``Family.init_params``."""
+        import jax
+
+        from fleetx_tpu.models.conv_moe.model import init_params
+
+        # made as it is served: the recipe's tree is 10.3 GB in bfloat16
+        return jax.jit(lambda key: init_params(model_cfg, key, served=True))(
+            jax.random.PRNGKey(seed))
+
+    def serving_params(self, params, model_cfg):
+        """See ``Family.serving_params``."""
+        from fleetx_tpu.serving.conv_moe import serving_params
+
+        return serving_params(params, model_cfg)
+
+    def served_template(self, model_cfg):
+        """See ``Family.served_template``."""
+        from fleetx_tpu.models.conv_moe.model import served_template
+
+        return served_template(model_cfg)
+
+    def programs(self, model_cfg, serving, sampling, mesh,
+                 pages_per_req: int) -> Programs:
+        """See ``Family.programs``."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        from fleetx_tpu.models.conv_moe.config import CONV, FULL
+        from fleetx_tpu.ops import paged_attention as PA
+        from fleetx_tpu.serving import conv_moe as S
+
+        sc, cfg = serving, model_cfg
+        assert mesh is None or mesh.size == 1, \
+            "this family serves on one chip: its programs place neither " \
+            "the key-value pool nor the convolution tails on a mesh yet"
+        assert not sc.quantize_decode, \
+            "quantized decode is not written for this family"
+        cache = list(S.init_cache(cfg, num_pages=sc.num_pages,
+                                  page_size=sc.page_size,
+                                  max_batch=sc.max_batch))
+        geometry = S.kernel_geometry(cfg, page_size=sc.page_size,
+                                     pages_per_req=pages_per_req)
+        refused = PA.paged_attention_refusal(**geometry)
+        active = bool(sc.paged_kernel) and not refused
+        if sc.paged_kernel and refused:
+            from fleetx_tpu.utils.log import logger
+
+            logger.warning("decode attention falls back to the gathered "
+                           "view: %s", refused)
+        fns = S.make_step_fns(cfg, prefill_chunk=sc.prefill_chunk,
+                              sampling=sampling, paged_kernel=active)
+        walk, folds = None, {}
+        if active:
+            walk = PA.page_walk_shape(**geometry)
+            folds = {"full": PA.fold_shape(**geometry)}
+
+        return Programs(
+            cache=cache, fns=fns,
+            tokens=jnp.zeros((sc.max_batch,), jnp.int32),
+            paged_kernel_active=active, walk_shape=walk, kv_folds=folds,
+            prefill_extra=lambda slot: (np.int32(slot),),
+            record_stats=_expert_counters(cfg),
+            cache_bytes={"state": int(cache[2].nbytes)},
+            describe="%d attention layers paged (%d lanes a token), %d "
+                     "convolution layers a tail of %d rows a slot" % (
+                         cfg.layers_of(FULL), cache[0].shape[3],
+                         cfg.layers_of(CONV), cache[2].shape[1]))
+
+
+_FAMILIES = (GPTFamily(), SWAMoEFamily(), GDNMLAFamily(), ConvMoEFamily())
 
 
 def families() -> dict:
@@ -405,8 +492,11 @@ def family(module: str) -> Family:
     ``module``; an unknown one is an error that names the served ones."""
     table = families()
     if module not in table:
-        raise ValueError(f"no serving family for Model.module {module!r}; "
-                         f"served: {sorted(table)}")
+        raise ValueError(
+            f"no serving family for Model.module {module!r}; the "
+            f"{len(_FAMILIES)} served families: " + "; ".join(
+                f"{type(f).__name__} ({', '.join(f.modules)})"
+                for f in _FAMILIES))
     return table[module]
 
 

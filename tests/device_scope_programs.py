@@ -2,9 +2,9 @@
 args)`` for ``utils.env.log_compile`` or for ``jitted.lower(*args)``: the
 three train steps (GPT on one device, GPT under ZeRO stage 2 with the
 overlapped update, the latent-attention expert family) and ``prefill`` +
-``decode`` of the four served ones (GPT, the two members of the
+``decode`` of the five served ones (GPT, the two members of the
 windowed-attention expert family, the linear-attention / latent-attention
-family). Tests only; arguments are abstract
+family, the short-convolution family). Tests only; arguments are abstract
 wherever nothing has to be initialised."""
 
 from __future__ import annotations
@@ -232,8 +232,36 @@ def gigachat_serve(batch=3, page=4, chunk=8, max_seq=64) -> list:
           _arr((batch, per_req)), _arr((batch,)), rng, _arr((), U32)))]
 
 
+def lfm2_serve(batch=3, page=4, chunk=8, max_seq=64) -> list:
+    """The short-convolution / grouped-query family at toy widths."""
+    import conv_moe_toy
+
+    from fleetx_tpu.models.conv_moe import model as M
+    from fleetx_tpu.models.conv_moe.config import config_from_dict
+    from fleetx_tpu.serving import conv_moe as S
+    from fleetx_tpu.serving.decode import SamplingParams
+
+    cfg = config_from_dict(conv_moe_toy.model_section())
+    per_req = max_seq // page
+    params = M.served_template(cfg)
+    cache = _abstract(jax.eval_shape(lambda: S.init_cache(
+        cfg, num_pages=1 + batch * per_req, page_size=page,
+        max_batch=batch)))
+    fns = S.make_step_fns(cfg, prefill_chunk=chunk,
+                          sampling=SamplingParams())
+    rng = _arr((2,), U32)
+    return [
+        ("serving prefill", fns["prefill"],
+         (params, *cache, _arr((1, chunk)), _arr((1, per_req)), _arr(()),
+          _arr(()), rng, _arr((), U32), _arr(()))),
+        ("serving decode", fns["decode"],
+         (params, *cache, _arr((batch,)), _arr(()), _arr((1,)),
+          _arr((batch, per_req)), _arr((batch,)), rng, _arr((), U32)))]
+
+
 #: family -> the programs' builder; a train builder takes the devices
 TRAIN = {"gpt": gpt_train, "gpt_zero2": gpt_train_zero2,
          "joyai": joyai_train}
 SERVE = {"gpt": gpt_serve, "laguna": laguna_serve,
-         "smallthinker": smallthinker_serve, "gigachat": gigachat_serve}
+         "smallthinker": smallthinker_serve, "gigachat": gigachat_serve,
+         "lfm2": lfm2_serve}

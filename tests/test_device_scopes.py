@@ -64,7 +64,7 @@ def test_the_vocabulary_holds_both_ways():
     assert None not in opened, "a device_scope whose name is not a literal"
     assert opened == set(DEVICE_SCOPES), (
         opened - set(DEVICE_SCOPES), set(DEVICE_SCOPES) - opened)
-    assert len(DEVICE_SCOPES) <= 17
+    assert len(DEVICE_SCOPES) <= 19
     assert all(isinstance(what, str) and what
                for what in DEVICE_SCOPES.values())
     with pytest.raises(KeyError, match="optimizer_update"):

@@ -1244,8 +1244,8 @@ def test_a_gather_fallback_is_said_once_when_the_engine_is_built(second):
         program_logger.removeHandler(handler)
     lines = [s for s in said if "falls back to the gathered view" in s]
     assert len(lines) == 1 and not eng.paged_kernel_active
-    assert "full_moe layers: head_dim 16 is not whole 128-lane tiles" \
-        in lines[0] and "window_moe layers: " in lines[0]
+    assert "full_moe layers: head_dim 16 is neither whole 128-lane tiles " \
+        "nor half of one" in lines[0] and "window_moe layers: " in lines[0]
     for _ in range(3):
         eng.submit([1, 2, 3], 2)
     eng.run_until_drained()

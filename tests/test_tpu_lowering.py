@@ -643,3 +643,73 @@ def test_gdn_mla_programs_keep_every_cache_one_buffer(topo, monkeypatch):
             assert holders, f"{name}: the cache {shape} is not in the program"
             stray = [h for h in holders if h[0] not in in_place]
             assert not stray, (name, stray)
+
+
+def test_conv_moe_programs_keep_both_caches_one_buffer(topo, monkeypatch):
+    """``decode`` and ``prefill`` of the short-convolution family, compiled
+    for the v5e at the recipe's head geometry — 4 query heads to each of 8
+    key-value heads of 64: half a lane tile, a 512-lane pool — and a narrow
+    expert width: both pools and the convolution's tail aliased from input
+    to output; the pool held by nothing but what enters, the loops'
+    carries, the in-place row writes and the kernel that reads it (no copy,
+    no layer of it cut out); ``paged_decode`` in the decode program under
+    the name the trace finds it by, the grouped products in both."""
+    from jax.sharding import SingleDeviceSharding
+
+    from fleetx_tpu.models.conv_moe import model as M
+    from fleetx_tpu.models.conv_moe.config import CONV, FULL, ConvMoEConfig
+    from fleetx_tpu.ops import paged_attention as PA
+    from fleetx_tpu.serving import conv_moe as S
+    from fleetx_tpu.serving.decode import SamplingParams
+
+    monkeypatch.setattr(ops, "interpret", lambda: False)
+    cfg = ConvMoEConfig(
+        vocab_size=VOCAB, hidden_size=2048, intermediate_size=512,
+        moe_intermediate_size=128, num_hidden_layers=5,
+        layer_types=(CONV, FULL, CONV, CONV, CONV), num_dense_layers=1,
+        num_experts=8, num_experts_per_tok=2,
+        rope_parameters={"rope_theta": 1000000, "rope_type": "default"})
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one)
+
+    # a pool too large for the compiler to stage in on-chip memory, as the
+    # real one is (abstract shapes: nothing is allocated)
+    batch, page, per_req, chunk, pages = 64, 16, 224, 128, 8193
+    assert not PA.paged_attention_refusal(**S.kernel_geometry(
+        cfg, page_size=page, pages_per_req=per_req))
+    params = jax.tree.map(lambda a: arr(a.shape, a.dtype),
+                          M.served_template(cfg))
+    pool, tail = S.cache_shapes(cfg, num_pages=pages, page_size=page,
+                                max_batch=batch)
+    assert pool == (1, pages, page, 512) and tail == (4, 2, batch, 2048)
+    cache = [arr(pool, jnp.bfloat16), arr(pool, jnp.bfloat16),
+             arr(tail, jnp.bfloat16)]
+    fns = S.make_step_fns(cfg, prefill_chunk=chunk,
+                          sampling=SamplingParams(), paged_kernel=True)
+    rng = arr((2,), jnp.uint32)
+    programs = {
+        "prefill": (params, *cache, arr((1, chunk)), arr((1, per_req)),
+                    arr(()), arr(()), rng, arr((), jnp.uint32), arr(())),
+        "decode": (params, *cache, arr((batch,)), arr(()), arr((1,)),
+                   arr((batch, per_req)), arr((batch,)), rng,
+                   arr((), jnp.uint32)),
+    }
+    kernels = {"prefill": {"moe_gmm_prefill"},
+               "decode": {"moe_gmm_decode", "paged_decode"}}
+    in_place = _POOL_CARRIERS | {"custom-call", "bitcast"}
+    n_params = len(jax.tree.leaves(params))
+    for name, args in programs.items():
+        lowered = fns[name].lower(*args)
+        assert set(mosaic_kernels(lowered.as_text())) == kernels[name], name
+        hlo = lowered.compile().as_text()
+        alias = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo)
+        assert alias, f"{name}: no input-output aliasing at all"
+        for out in range(3):
+            assert f"{{{out}}}: ({n_params + out}, {{}}," in alias.group(1), \
+                (name, out, alias.group(1))
+        holders = _pool_holders(hlo, pool)
+        assert holders, f"{name}: the pool {pool} is not in the program"
+        stray = [h for h in holders if h[0] not in in_place]
+        assert not stray, (name, stray)
