@@ -191,7 +191,8 @@ DEVICE_SCOPES: dict = {
                  "biases, the latents' norms, rotary, gates",
     "attn.core": "the flash, latent-flash or paged kernel, or the XLA "
                  "scores where no kernel runs (a prefill's folded key "
-                 "blocks, a ring scored whole, the gathered page view)",
+                 "blocks of a request's pages or a slot's ring, a decode "
+                 "fallback's gathered page view)",
     "attn.cache": "the pool's row scatter, a ring's write, a dense decode "
                   "cache's update",
     "gdn.proj": "a linear-attention layer's products (queries, keys, "

@@ -43,14 +43,12 @@ page ``j mod ring_pages``, an aligned group of pages is one run of the
 buffer, and a fold fetches it in one copy a cache buffer.
 Where the kernel does not admit the geometry (toy widths), the gathered
 view, and the engine's build says so once (``gather_fallbacks``). Prefill:
-the gather path — a full layer folds the request's pages a block of keys at
-a time up to the chunk's end (a loop as long as the context, online
-softmax); a window layer reads its slot's ring whole while the ring is at
-most ``_WHOLE_RING_BLOCKS`` key blocks (a 512-token window under a
-512-token chunk: one score of ``[heads, chunk, 1,024]``), and folds it a
-key block at a time, from the block that holds the first query's oldest
-key, when it is longer (4,096 + 512 tokens: the one score would be 264 MB
-of float32 a layer).
+one fold for both caches (``_prefill_blocked_attention``: a block of keys as
+long as the chunk at a time, online softmax) — a full layer over the
+request's pages up to the chunk's end (a loop as long as the context); a
+window layer over its slot's ring as a block table, from the block that
+holds the first query's oldest key (a loop as long as window + chunk: two
+blocks at a 512-token window under 512-token chunks, nine or ten at 4,096).
 
 **The router's input.** With ``router_input: pre_attention`` the experts'
 ids and weights are computed from the attention norm's output and carried
@@ -79,14 +77,6 @@ from fleetx_tpu.serving.decode import (SamplingParams, _sample,
                                        merge_fresh)
 
 _NEG = -1e30
-
-#: a window layer's prefill scores its slot's ring whole while the ring is
-#: no more than this many key blocks (a block: as many keys as the chunk
-#: has queries); a longer ring is folded a block at a time. The value keeps
-#: the first member's program what it was, and for no other reason: on the
-#: chip the fold is the faster at a 1,024-token ring too (PERF.md section 6,
-#: PR 38), so ROADMAP S16 deletes this constant and the whole-ring branch
-_WHOLE_RING_BLOCKS = 2
 
 
 # -------------------------------------------------------------------- caches
@@ -249,16 +239,14 @@ def _prefill_blocked_attention(q, pool_k, pool_v, layer, table, q_pos,
 
 # ------------------------------------------------------------------- forward
 def _forward(params: Any, cfg: SWAMoEConfig, tokens, positions, cache,
-             block_tables, slots, last, *, rp: int, view: int, decode: bool,
+             block_tables, slots, last, *, rp: int, decode: bool,
              paged_kernel: bool, moe_kernel: str):
     """``tokens`` [B, S] at absolute ``positions`` [B, S] (< 0: no token)
     through every layer in the published order. ``cache`` is ``(full_k,
     full_v, ring_k, ring_v)``; ``block_tables`` [B, pages_per_req] the
     rows' pages in the full pool; ``slots`` [B] whose ring each row is;
     ``last`` [B] the last position each row holds after this call; ``rp``
-    the pages of one slot's ring and ``view`` how many of them, ending at
-    the page of ``last``, a gathered window layer reads (window + chunk).
-    Returns
+    the pages of one slot's ring (window + chunk). Returns
     ``(hidden [B, S, h], cache, stats)``; ``stats``: held experts hit,
     summed over the expert layers, (token, expert) pairs on held experts,
     the rows of the fullest held expert over the mean (worst layer) and the
@@ -275,8 +263,8 @@ def _forward(params: Any, cfg: SWAMoEConfig, tokens, positions, cache,
     ps, P = full_k.shape[2], block_tables.shape[1]
     window = cfg.sliding_window
     moe_pass_rows = M.pass_rows(cfg, B * S)
-    # keys a block of the prefill's full-layer attention scores at once:
-    # as many as the chunk has queries, in whole pages
+    # keys a block of the prefill's attention scores at once: as many as
+    # the chunk has queries, in whole pages
     key_block = -(-S // ps) * ps
 
     with device_scope("embed"):
@@ -293,27 +281,25 @@ def _forward(params: Any, cfg: SWAMoEConfig, tokens, positions, cache,
         ring_first = 1 + slots * rp                               # [B]
         ring_at = jnp.where(
             valid, ring_first[:, None] + (q_pos // ps) % rp, 0)
-        # the gathered view of a ring: the ``view`` logical pages that end
-        # at the page of ``last``, in order, and the position of each key
-        # in them
-        view_first = (jnp.maximum(last, 0) // ps - (view - 1))[:, None] \
-            + jnp.arange(view, dtype=jnp.int32)[None, :]          # [B, view]
-        view_pages = ring_first[:, None] + view_first % rp
-        view_pos = (view_first[:, :, None] * ps + jnp.arange(
-            ps, dtype=jnp.int32)[None, None, :]).reshape(B, view * ps)
         valid_tok = valid.reshape(B * S)
     with device_scope("attn.proj"):
         tables = {t: M.rotary_tables(cfg, t, q_pos) for t in (FULL, WINDOW)}
     act = M.activation(cfg)
     router_first = cfg.router_input == "pre_attention"
-    # a window layer's prefill folds its ring a key block at a time once
-    # the ring is longer than a few blocks
-    fold_ring = not decode and view * ps > _WHOLE_RING_BLOCKS * key_block
-    # ... through the ring as a block table: logical page j -> ring page
-    # j mod rp
-    with device_scope("attn.cache"):
-        ring_table = ring_first[:, None] + \
-            jnp.arange(P, dtype=jnp.int32)[None, :] % rp if fold_ring else None
+    with device_scope("attn.cache"):    # how a window layer reads its ring
+        if not decode:
+            # folded as a block table: logical page j -> ring page j mod rp
+            ring_table = ring_first[:, None] + \
+                jnp.arange(P, dtype=jnp.int32)[None, :] % rp
+        elif not paged_kernel:
+            # the gathered view: the ring's ``rp`` logical pages that end at
+            # the page of ``last``, in order, and the position of each key
+            # in them
+            view_first = (jnp.maximum(last, 0) // ps - (rp - 1))[:, None] \
+                + jnp.arange(rp, dtype=jnp.int32)[None, :]        # [B, rp]
+            view_pages = ring_first[:, None] + view_first % rp
+            view_pos = (view_first[:, :, None] * ps + jnp.arange(
+                ps, dtype=jnp.int32)[None, None, :]).reshape(B, rp * ps)
 
     def attention(kind_type, u, lp, cache, at):
         with device_scope("attn.proj"):
@@ -365,7 +351,7 @@ def _forward(params: Any, cfg: SWAMoEConfig, tokens, positions, cache,
             return PA.paged_attention(q[:, 0], ring_k, ring_v, ring_first,
                                       positions[:, 0], at, window=window,
                                       ring_pages=rp)[:, None]
-        if fold_ring:
+        if not decode:
             return _prefill_blocked_attention(
                 q, ring_k, ring_v, at, ring_table, q_pos, last[0] + 1,
                 key_block, dt, window=window)
@@ -482,7 +468,7 @@ def make_step_fns(cfg: SWAMoEConfig, *, prefill_chunk: int, page_size: int,
         x, cache, _ = _forward(
             params, cfg, tokens, positions, (full_k, full_v, ring_k, ring_v),
             block_table, jnp.reshape(slot, (1,)).astype(jnp.int32), last,
-            rp=rp, view=rp, decode=False, paged_kernel=False,
+            rp=rp, decode=False, paged_kernel=False,
             moe_kernel="moe_gmm_prefill")
         with device_scope("head"):
             at = jnp.clip(n_valid - 1, 0, prefill_chunk - 1)
@@ -503,9 +489,8 @@ def make_step_fns(cfg: SWAMoEConfig, *, prefill_chunk: int, page_size: int,
         x, cache, stats = _forward(
             params, cfg, tokens[:, None], positions,
             (full_k, full_v, ring_k, ring_v), block_tables, slots,
-            jnp.maximum(lens, -1).astype(jnp.int32), rp=rp, view=rp,
-            decode=True, paged_kernel=paged_kernel,
-            moe_kernel="moe_gmm_decode")
+            jnp.maximum(lens, -1).astype(jnp.int32), rp=rp, decode=True,
+            paged_kernel=paged_kernel, moe_kernel="moe_gmm_decode")
         logits = _logits(params, x[:, 0])
         with device_scope("moe.route"):     # rides with the counters
             stats["rows"] = (lens >= 0).sum().astype(jnp.int32)

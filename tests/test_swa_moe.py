@@ -90,14 +90,22 @@ def toy():
     return cfg, params, sizes, _reference_weights(params, sizes)
 
 
-def _serve(cfg, params, prompt, new, *, max_seq=64, slot=1, max_batch=3,
-           paged_kernel=False, page=PAGE, chunk=CHUNK, cache=None):
+def _serve(*args, **kw):
+    """``_serve_cache`` without the caches it leaves."""
+    return _serve_cache(*args, **kw)[:4]
+
+
+def _serve_cache(cfg, params, prompt, new, *, max_seq=64, slot=1,
+                 max_batch=3, paged_kernel=False, page=PAGE, chunk=CHUNK,
+                 cache=None, fns=None):
     """Prefill ``prompt`` in chunks, decode ``new`` tokens greedily, in slot
-    ``slot`` of an otherwise empty batch: ``(tokens, logits a step)``."""
+    ``slot`` of an otherwise empty batch: ``(tokens, logits a step, the last
+    step's counters, the programs, the four caches as the request leaves
+    them)``; ``cache`` and ``fns`` of an earlier call carry on from it."""
     P = max_seq // page
-    fns = S.make_step_fns(cfg, page_size=page, prefill_chunk=chunk,
-                          sampling=SamplingParams(),
-                          paged_kernel=paged_kernel)
+    fns = fns or S.make_step_fns(cfg, page_size=page, prefill_chunk=chunk,
+                                 sampling=SamplingParams(),
+                                 paged_kernel=paged_kernel)
     cache = cache or S.init_cache(cfg, num_pages=1 + max_batch * P,
                                   page_size=page, max_batch=max_batch,
                                   prefill_chunk=chunk)
@@ -124,7 +132,7 @@ def _serve(cfg, params, prompt, new, *, max_seq=64, slot=1, max_batch=3,
             table, lens, key, np.uint32(0))
         logits.append(np.asarray(lg[slot]))
         toks.append(int(tk[slot]))
-    return toks, logits, jax.device_get(stats), fns
+    return toks, logits, jax.device_get(stats), fns, cache
 
 
 @pytest.mark.parametrize("prompt_len,new", [
@@ -947,7 +955,8 @@ def test_the_second_members_tree_has_no_leaf_for_what_it_lacks(second):
     (6, 8, 4),                  # crosses the window mid-decode
     (WINDOW + 5, 30, 4),        # wraps a ring of 12 tokens: no multiple of
                                 # the window; prefill folds the ring
-    (3 * CHUNK + 1, 20, CHUNK),  # a ring of two key blocks: scored whole
+    (3 * CHUNK + 1, 20, CHUNK),  # a ring of two key blocks: the fold visits
+                                 # both, three on the ragged last chunk
 ])
 def test_second_member_prefill_then_decode_is_the_reference_on_logits(
         second, prompt_len, new, chunk):
@@ -972,6 +981,95 @@ def test_second_member_prefill_then_decode_is_the_reference_on_logits(
     np.testing.assert_allclose(float(stats["load_max_over_mean"]), 16 / 3,
                                rtol=1e-6)
     assert fns["decode"]._cache_size() == fns["prefill"]._cache_size() == 1
+
+
+@pytest.mark.parametrize("member", ["toy", "second"])
+def test_a_reused_slots_stale_ring_keys_are_masked_by_position(request,
+                                                               member):
+    """A ring of two key blocks (window = chunk): a long request wraps its
+    slot's ring three times and leaves; a shorter one takes the slot, ring
+    and pages as they were left. Its prefill folds blocks that still hold
+    the first one's keys, at logical positions past its own last token, and
+    its decode reads them in the gathered view: every step's logits are the
+    reference's, for both members."""
+    cfg, params, sizes, w = request.getfixturevalue(member)
+    module = ref if member == "toy" else ref2
+    rp = S.ring_pages(cfg, PAGE, CHUNK)
+    assert rp * PAGE == 2 * CHUNK
+    rng = np.random.default_rng(46)
+    long = rng.integers(0, cfg.vocab_size, size=3 * CHUNK + 1).tolist()
+    short = rng.integers(0, cfg.vocab_size, size=CHUNK + 3).tolist()
+    *_, fns, cache = _serve_cache(cfg, params, long, 20)
+    for ring in cache[2:]:      # slot 1's ring is full of the first one's
+        assert bool(jnp.all(jnp.any(ring[:, 1 + rp:1 + 2 * rp] != 0, -1)))
+    toks, logits, _, _ = _serve(cfg, params, short, 9, cache=cache, fns=fns)
+    assert fns["decode"]._cache_size() == fns["prefill"]._cache_size() == 1
+    want = _reference_rows(module, w, sizes, toks)
+    for i, got in enumerate(logits):
+        np.testing.assert_allclose(got, want[len(short) - 1 + i], atol=2e-5,
+                                   rtol=0)
+
+
+def _avals(fn, *args):
+    """Every value the traced ``fn`` computes, dead code included, in the
+    jaxprs its equations hold too: ``[(shape, dtype's name), ...]``."""
+    return [(tuple(v.aval.shape), str(v.aval.dtype))
+            for eqn in _kernel_body_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+            for v in eqn.outvars if hasattr(v.aval, "shape")]
+
+
+def _program_args(cfg, *, page, chunk, max_batch=3, max_seq=64):
+    """Arguments to trace both programs with, the parameters abstract:
+    ``{"prefill", "decode"}``."""
+    P, params = max_seq // page, M.served_template(cfg)
+    cache = S.init_cache(cfg, num_pages=1 + max_batch * P, page_size=page,
+                         max_batch=max_batch, prefill_chunk=chunk)
+    i32, key = np.int32(0), jax.random.PRNGKey(0)
+    return {
+        "prefill": (params, *cache, np.zeros((1, chunk), np.int32),
+                    np.zeros((1, P), np.int32), i32, i32, key, np.uint32(0),
+                    i32),
+        "decode": (params, *cache, np.zeros((max_batch,), np.int32), i32,
+                   np.zeros((1,), np.int32),
+                   np.zeros((max_batch, P), np.int32),
+                   np.zeros((max_batch,), np.int32), key, np.uint32(0))}
+
+
+def test_no_prefill_scores_a_ring_whole_and_kernel_decode_builds_no_view():
+    """The traced programs, so the whole-ring score cannot come back
+    unseen. Prefill at a ring of exactly two key blocks (the geometry that
+    was scored whole; sizes that no other array's last two dimensions
+    share): no float32 value of ``[..., chunk, ring tokens]``, what a score
+    of the ring at once would be; the fold's ``[..., chunk, key block]``
+    is there. Decode on the paged kernel: no int32 positions of a gathered
+    ring ``[slots, ring tokens]`` and no ring gathered ``[slots, ring
+    tokens, kv, hd]``; the gathered fallback has both."""
+    chunk = window = 20
+    cfg = config_from_dict(dict(TOY, sliding_window=window))
+    ring = S.ring_pages(cfg, PAGE, chunk) * PAGE
+    assert ring == 2 * chunk
+    fns = S.make_step_fns(cfg, page_size=PAGE, prefill_chunk=chunk,
+                          sampling=SamplingParams())
+    args = _program_args(cfg, page=PAGE, chunk=chunk)
+    f32 = [shape for shape, dtype in _avals(fns["prefill"], *args["prefill"])
+           if dtype == "float32"]
+    assert not [s for s in f32 if s[-2:] == (chunk, ring)]
+    assert [s for s in f32 if s[-2:] == (chunk, chunk)]
+
+    # the geometry of test_the_kernel_path_serves_what_the_gather_serves
+    cfg = config_from_dict(dict(TOY, head_dim=128, num_hidden_layers=5,
+                                sliding_window=16))
+    page = chunk = 8
+    ring = S.ring_pages(cfg, page, chunk) * page
+    args = _program_args(cfg, page=page, chunk=chunk)
+    view = {((3, ring), "int32"),
+            ((3, ring, cfg.num_key_value_heads, cfg.head_dim), "float32")}
+    for paged_kernel in (True, False):
+        fns = S.make_step_fns(cfg, page_size=page, prefill_chunk=chunk,
+                              sampling=SamplingParams(),
+                              paged_kernel=paged_kernel)
+        seen = set(_avals(fns["decode"], *args["decode"]))
+        assert (view & seen) == (set() if paged_kernel else view)
 
 
 def _router_reads_v(x, lw, sizes_key, windowed, rotated, precision):
