@@ -38,14 +38,15 @@ stays one buffer from a program's input to its output.
 
 **Attention.** A chunk writes K and V to the slot's pages and folds the
 request's pages a key block at a time up to the chunk's end
-(``serving/swa_moe.py:_prefill_blocked_attention``); decode calls
+(``serving/programs.py:prefill_blocked_attention``); decode calls
 ``ops/paged_attention.py`` with 4 query heads to each key-value head of 64 —
 half a lane tile — or, where the kernel does not admit the geometry, reads
 the gathered view (the engine's build says so once).
 
 **Parameters**: bfloat16, but every norm's weight, the router and its
-selection bias in float32; ``serving_params`` makes that tree once and the
-programs refuse any other.
+selection bias in float32; ``programs.serving_params`` makes that tree once
+(``Family.serving_params``, ``serving/registry.py``) and the programs refuse
+any other.
 """
 
 from __future__ import annotations
@@ -60,9 +61,8 @@ from fleetx_tpu.models.conv_moe.config import CONV, FULL, ConvMoEConfig
 from fleetx_tpu.models.swa_moe import model as shared
 from fleetx_tpu.observability.trace import device_scope
 from fleetx_tpu.ops import paged_attention as PA
-from fleetx_tpu.serving import swa_moe as windowed
-from fleetx_tpu.serving.decode import (SamplingParams, _sample,
-                                       merge_fresh)
+from fleetx_tpu.serving import programs
+from fleetx_tpu.serving.programs import SamplingParams
 
 
 # -------------------------------------------------------------------- caches
@@ -87,6 +87,14 @@ def init_cache(cfg: ConvMoEConfig, **geometry) -> tuple:
             jnp.zeros(tail, cfg.dtype))
 
 
+def describe(cfg: ConvMoEConfig, serving: Any, cache: list) -> str:
+    """The caches of one engine, in words (its start-up line)."""
+    return "%d attention layers paged (%d lanes a token), %d convolution " \
+        "layers a tail of %d rows a slot" % (
+            cfg.layers_of(FULL), cache[0].shape[3], cfg.layers_of(CONV),
+            cache[2].shape[1])
+
+
 def kernel_geometry(cfg: ConvMoEConfig, *, page_size: int,
                     pages_per_req: int) -> dict:
     """What ``ops/paged_attention.py`` is asked about the attention layers."""
@@ -95,16 +103,10 @@ def kernel_geometry(cfg: ConvMoEConfig, *, page_size: int,
                 dtype=cfg.dtype, num_kv_heads=cfg.num_key_value_heads)
 
 
-# ---------------------------------------------------------------- parameters
-def _unserved(params: Any, cfg: ConvMoEConfig) -> list:
-    return windowed._unserved(params, cfg, M.served_dtype)
-
-
-def serving_params(params: Any, cfg: ConvMoEConfig) -> Any:
-    """The tree both programs take: every leaf in ``cfg.dtype`` but those
-    ``models/conv_moe/model.py`` keeps in float32 (one jitted cast of the
-    leaves that need it: ``serving/swa_moe.py:serving_params``)."""
-    return windowed.serving_params(params, cfg, M.served_dtype)
+def kernel_refusal(cfg: ConvMoEConfig, **geometry) -> str:
+    """Why ``ops/paged_attention.py`` does not admit the attention layers'
+    decode at this geometry (``page_size``, ``pages_per_req``), or ""."""
+    return PA.paged_attention_refusal(**kernel_geometry(cfg, **geometry))
 
 
 # ------------------------------------------------------------------- forward
@@ -117,12 +119,8 @@ def _forward(params: Any, cfg: ConvMoEConfig, tokens, positions, cache,
     ``slot``, ``n_valid`` of them real, from position ``start``. ``cache``
     is ``(pool_k, pool_v, tail)``; ``block_tables`` [B, pages_per_req] the
     rows' pages in the pool. Returns ``(hidden [rows, h], cache, stats)`` —
-    the stats are ``serving/swa_moe.py``'s."""
-    unserved = _unserved(params, cfg)
-    if unserved:
-        raise TypeError(
-            "the serving programs take the tree serving_params() makes: "
-            f"{len(unserved)} leaves are not in their served dtype")
+    the stats are ``programs.walk_runs``'s."""
+    programs.refuse_unserved(params, cfg, M.served_dtype)
     (rows,) = tokens.shape
     dt = cfg.dtype
     hd, kv = cfg.head_dim, cfg.num_key_value_heads
@@ -133,15 +131,8 @@ def _forward(params: Any, cfg: ConvMoEConfig, tokens, positions, cache,
 
     with device_scope("embed"):
         x = params["embed"]["tokens"][jnp.maximum(tokens, 0)]
-    with device_scope("attn.cache"):    # where the rows go, for every layer
-        valid = positions >= 0
-        q_pos = jnp.maximum(positions, 0)
-        offs = jnp.clip(positions % ps, 0, ps - 1)
-        page_slot = jnp.clip(positions // ps, 0, P - 1)
-        tables = block_tables if decode else \
-            jnp.broadcast_to(block_tables, (rows, P))
-        pages = jnp.where(valid, jnp.take_along_axis(
-            tables, page_slot[:, None], axis=1)[:, 0], 0)
+    valid, q_pos, offs, pages = programs.row_targets(positions, block_tables,
+                                                     ps)
     with device_scope("attn.proj"):
         cos, sin = M.rotary_tables(cfg, q_pos)
     first = None if decode else start == 0      # the request's first chunk
@@ -187,27 +178,24 @@ def _forward(params: Any, cfg: ConvMoEConfig, tokens, positions, cache,
                 vd = pool_v[at, block_tables].reshape(rows, -1, kv, hd)
                 kp = jnp.broadcast_to(jnp.arange(P * ps, dtype=jnp.int32),
                                       (rows, P * ps))
-                o = windowed._gathered_attention(
+                o = programs.gathered_attention(
                     q[:, None], kd, vd, kp, q_pos[:, None], None, dt)[:, 0]
             else:
-                o = windowed._prefill_blocked_attention(
+                o = programs.prefill_blocked_attention(
                     q[None], pool_k, pool_v, at, block_tables, q_pos[None],
                     start + n_valid, key_block, dt)[0]
         with device_scope("attn.proj"):
             return jnp.einsum("snd,ndh->sh", o, lp["out"]), \
                 (pool_k, pool_v, tail)
 
-    def run(kind, lo, n, cache_lo, carry):
+    def layer_of(kind, lo, cache_lo):
         stack = params[kind]
         op, mlp = kind.split("_")
         dense = mlp == "dense"
-        per_layer = {k: v for k, v in stack.items() if k != "moe"}
-        if not dense:
-            per_layer["moe"] = {k: v for k, v in stack["moe"].items()
-                                if not k.startswith("experts_")}
+        per_layer = programs.per_layer_leaves(stack)
 
         def layer(i, carry):
-            x, cache, hit, pairs, load, passes = carry
+            x, cache, counters = carry
             lp = jax.tree.map(lambda w: w[i], per_layer)
             with device_scope("norm"):
                 u = M.rms_norm(x, lp["operator_norm"]["scale"], cfg.norm_eps,
@@ -232,30 +220,16 @@ def _forward(params: Any, cfg: ConvMoEConfig, tokens, positions, cache,
                 y, held_rows, turns = shared.held_experts(
                     f, ids, weights, stack["moe"], i, cfg, moe_pass_rows,
                     moe_kernel)
-                with device_scope("moe.route"):     # the step's counters
-                    hit = hit + (held_rows > 0).sum().astype(jnp.float32)
-                    pairs = pairs + held_rows.sum().astype(jnp.int32)
-                    held = held_rows.astype(jnp.float32)
-                    load = jnp.maximum(
-                        load, held.max() / jnp.maximum(held.mean(), 1e-9))
-                    passes = passes + turns.astype(jnp.int32)
+                counters = programs.count_held(counters, held_rows, turns)
             with device_scope("mlp"):
-                return x + y.astype(dt), cache, hit, pairs, load, passes
+                return x + y.astype(dt), cache, counters
 
-        with device_scope("stack"):
-            if n == 1:  # a static index: the layer is a view of its stack
-                return layer(lo, carry)
-            return jax.lax.fori_loop(lo, lo + n, layer, carry)
+        return layer
 
-    carry = (x, tuple(cache), jnp.float32(0.0), jnp.int32(0),
-             jnp.float32(0.0), jnp.int32(0))
-    for kind, lo, n, cache_lo in cfg.runs():
-        carry = run(kind, lo, n, cache_lo, carry)
-    x, cache, hit, pairs, load, passes = carry
+    x, cache, stats = programs.walk_runs(cfg, x, cache, layer_of)
     with device_scope("head"):
         x = M.rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps, dt)
-    return x, cache, {"hit": hit, "pairs_held": pairs,
-                      "load_max_over_mean": load, "passes": passes}
+    return x, cache, stats
 
 
 _logits = device_scope("head")(M.logits)
@@ -264,49 +238,24 @@ _logits = device_scope("head")(M.logits)
 def make_step_fns(cfg: ConvMoEConfig, *, prefill_chunk: int,
                   sampling: SamplingParams,
                   paged_kernel: bool = False) -> dict:
-    """The two jitted programs of one engine, ``{"prefill", "decode"}``.
+    """The two jitted programs of one engine, ``{"prefill", "decode"}``:
+    ``serving/programs.py:step_fns`` around ``_forward`` over ``(pool_k,
+    pool_v, tail)``. ``prefill`` takes the slot whose tail the request owns
+    after the draw count; ``decode`` returns the step's expert counters
+    after its logits. ``paged_kernel``: the decode kernel (else the gathered
+    view)."""
+    def prefill(params, cache, tokens, positions, block_table, start,
+                n_valid, slot):
+        return _forward(
+            params, cfg, tokens[0], positions, cache, block_table, slot,
+            start, n_valid, decode=False, paged_kernel=False,
+            moe_kernel="moe_gmm_prefill")
 
-    Both take ``(params, pool_k, pool_v, tail, ...)``, donate the three
-    cache buffers and return them first; what follows is what
-    ``serving/swa_moe.py``'s programs take and return (``prefill`` with the
-    slot whose tail the request owns; ``decode`` with the step's expert
-    counters after its logits). ``paged_kernel``: the decode kernel (else
-    the gathered view). Shapes are static, so each jit cache holds one
-    entry for the engine's lifetime."""
-    def prefill(params, pool_k, pool_v, tail, tokens, block_table, start,
-                n_valid, rng, draw, slot):
-        """One prompt chunk of the request in slot ``slot``: ``tokens``
-        ``[1, C]`` with ``n_valid`` real entries from position ``start``."""
-        idx = jnp.arange(prefill_chunk, dtype=jnp.int32)
-        positions = jnp.where(idx < n_valid, start + idx, -1)
-        x, cache, _ = _forward(
-            params, cfg, tokens[0], positions, (pool_k, pool_v, tail),
-            block_table, slot, start, n_valid, decode=False,
-            paged_kernel=False, moe_kernel="moe_gmm_prefill")
-        with device_scope("head"):
-            at = jnp.clip(n_valid - 1, 0, prefill_chunk - 1)
-            x_last = jax.lax.dynamic_index_in_dim(x, at, axis=0,
-                                                  keepdims=False)[None]
-        logits = _logits(params, x_last)
-        return (*cache, _sample(logits, rng, draw, sampling), logits)
+    def decode(params, cache, tokens, positions, block_tables, lens):
+        return _forward(
+            params, cfg, tokens, positions, cache, block_tables, None, None,
+            None, decode=True, paged_kernel=paged_kernel,
+            moe_kernel="moe_gmm_decode")
 
-    def decode(params, pool_k, pool_v, tail, tokens, fresh_slot, fresh_tok,
-               block_tables, lens, rng, draw):
-        """One token for every slot: ``tokens`` / ``lens`` ``[max_batch]``
-        (an empty slot, or one still in prefill, carries ``lens < 0`` and
-        keeps its tail)."""
-        tokens = merge_fresh(tokens, fresh_slot, fresh_tok)
-        positions = jnp.where(lens >= 0, lens, -1)
-        x, cache, stats = _forward(
-            params, cfg, tokens, positions, (pool_k, pool_v, tail),
-            block_tables, None, None, None, decode=True,
-            paged_kernel=paged_kernel, moe_kernel="moe_gmm_decode")
-        logits = _logits(params, x)
-        with device_scope("moe.route"):     # rides with the counters
-            stats["rows"] = (lens >= 0).sum().astype(jnp.int32)
-        return (*cache, _sample(logits, rng, draw, sampling), logits,
-                stats)
-
-    donate = (1, 2, 3)
-    return {"prefill": jax.jit(prefill, donate_argnums=donate),
-            "decode": jax.jit(decode, donate_argnums=donate)}
+    return programs.step_fns(prefill, decode, _logits, caches=3,
+                             prefill_chunk=prefill_chunk, sampling=sampling)

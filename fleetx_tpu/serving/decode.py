@@ -91,49 +91,47 @@ mesh by ``tests/test_zz_serving.py``.
 
 from __future__ import annotations
 
-import dataclasses
-from functools import partial
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 
-from fleetx_tpu.models.gpt import generation as G
 from fleetx_tpu.observability.trace import device_scope
 from fleetx_tpu.ops import paged_attention as PA
+# the tail of every family's programs lives in ``serving/programs.py``
+from fleetx_tpu.serving.programs import SamplingParams, merge_fresh, sample
 
 
-def paged_kernel_enabled(cfg: Any, *, page_size: int, num_pages: int,
-                         pages_per_req: int,
-                         pool_sharding: Optional[Any] = None) -> bool:
-    """Static kernel-vs-gather decision for one engine's geometry.
+def kernel_geometry(cfg: Any, *, page_size: int, pages_per_req: int,
+                    pool_sharding: Optional[Any] = None) -> dict:
+    """What ``ops/paged_attention.py`` is asked about ONE device's share of
+    the pool (the kernel runs per shard: ``tensor`` splits the heads)."""
+    tensor = 1 if pool_sharding is None else \
+        pool_sharding.mesh.shape["tensor"]
+    return dict(num_heads=cfg.num_attention_heads // tensor,
+                head_dim=cfg.head_dim, page_size=page_size,
+                pages_per_req=pages_per_req, dtype=cfg.dtype)
 
-    True when the Pallas page-walk kernel serves decode: the shape
-    predicate admits one shard's (heads, head_dim, page) tiling, and —
-    under a multi-device mesh — the per-device ``shard_map`` wrapping
-    applies too. Consulted once per engine; the result is baked
-    into the decode program so the no-retrace pin is untouched.
-    """
-    heads = cfg.num_attention_heads
+
+def kernel_refusal(cfg: Any, *, page_size: int, num_pages: int,
+                   pages_per_req: int,
+                   pool_sharding: Optional[Any] = None) -> str:
+    """Why the Pallas page-walk kernel does not serve decode at this
+    geometry, or "" when it does: the shape predicate refuses one shard's
+    (heads, head_dim, page) tiling, or — under a multi-device mesh — the
+    per-device ``shard_map`` wrapping does not apply. Static: consulted
+    once per engine, and the result is baked into the decode program so the
+    no-retrace pin is untouched."""
     if pool_sharding is not None and pool_sharding.mesh.size > 1:
         mesh = pool_sharding.mesh
-        if not PA.paged_sharded_supported(mesh, num_heads=heads,
-                                          num_pages=num_pages):
-            return False
-        heads //= mesh.shape["tensor"]  # the kernel sees one shard's heads
-    return PA.paged_attention_supported(
-        num_heads=heads, head_dim=cfg.head_dim, page_size=page_size,
-        pages_per_req=pages_per_req, dtype=cfg.dtype)
-
-
-@dataclasses.dataclass(frozen=True)
-class SamplingParams:
-    """Engine-wide sampling knobs (static: baked into the two programs)."""
-
-    do_sample: bool = False
-    temperature: float = 1.0
-    top_k: int = 0
-    top_p: float = 0.0
+        if not PA.paged_sharded_supported(
+                mesh, num_heads=cfg.num_attention_heads, num_pages=num_pages):
+            return "the mesh %s does not split %d pages over fsdp and %d " \
+                   "heads over tensor alone" % (
+                       dict(mesh.shape), num_pages, cfg.num_attention_heads)
+    return PA.paged_attention_refusal(**kernel_geometry(
+        cfg, page_size=page_size, pages_per_req=pages_per_req,
+        pool_sharding=pool_sharding))
 
 
 def token_sharding(mesh: Any) -> Any:
@@ -345,36 +343,6 @@ def _logits(params: Any, cfg: Any, x_last: jax.Array) -> jax.Array:
     return jnp.einsum("bh,vh->bv", x_last, wte).astype(jnp.float32)
 
 
-@device_scope("sample")
-def _sample(logits: jax.Array, rng: jax.Array, draw: jax.Array,
-            sp: SamplingParams) -> jax.Array:
-    """Greedy argmax or the sampling-transform chain shared with
-    ``generation.generate`` (temperature → top-k → top-p → categorical).
-
-    ``rng`` is the engine's one base key and ``draw`` the host's count of
-    the programs it has dispatched: the call's key is folded HERE, inside
-    the program, so no key is ever split by a dispatch of its own. Greedy
-    reads neither (and ``jit`` then drops both from the executable)."""
-    if not sp.do_sample:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    l = G.apply_temperature(logits, sp.temperature)
-    l = G.apply_top_k(l, sp.top_k)
-    l = G.apply_top_p(l, sp.top_p)
-    return jax.random.categorical(jax.random.fold_in(rng, draw), l,
-                                  axis=-1).astype(jnp.int32)
-
-
-@device_scope("sample")
-def merge_fresh(tokens: jax.Array, fresh_slot: jax.Array,
-                fresh_tok: jax.Array) -> jax.Array:
-    """The decode batch's input tokens: the previous step's output, which
-    never left the device, with the first token of the request that left
-    prefill in this tick (``fresh_tok`` ``[1]``, the last chunk's sampled
-    token, unfetched) put in its slot; ``fresh_slot < 0``: none did."""
-    rows = jnp.arange(tokens.shape[0], dtype=jnp.int32)
-    return jnp.where(rows == fresh_slot, fresh_tok[0], tokens)
-
-
 def make_step_fns(cfg: Any, *, max_batch: int, pages_per_req: int,
                   prefill_chunk: int, sampling: SamplingParams,
                   quantize: bool = False,
@@ -421,7 +389,7 @@ def make_step_fns(cfg: Any, *, max_batch: int, pages_per_req: int,
                                                   keepdims=False)[None]
         logits = _logits(params, cfg, x_last)
         return (constrain(pool_k), constrain(pool_v),
-                everywhere(_sample(logits, rng, draw, sampling)), logits)
+                everywhere(sample(logits, rng, draw, sampling)), logits)
 
     def decode(params, pool_k, pool_v, tokens, fresh_slot, fresh_tok,
                block_tables, lens, rng, draw):
@@ -438,7 +406,7 @@ def make_step_fns(cfg: Any, *, max_batch: int, pages_per_req: int,
                                      paged_kernel=paged_kernel, mesh=mesh)
         logits = _logits(params, cfg, x[:, 0])
         return (constrain(pool_k), constrain(pool_v),
-                everywhere(_sample(logits, rng, draw, sampling)), logits)
+                everywhere(sample(logits, rng, draw, sampling)), logits)
 
     del max_batch, pages_per_req  # shapes arrive via the arrays themselves
     return {

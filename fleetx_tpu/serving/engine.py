@@ -84,8 +84,8 @@ from fleetx_tpu.observability.slo import SLORegistry
 from fleetx_tpu.observability.trace import span
 from fleetx_tpu.ops import paged_attention as PA
 from fleetx_tpu.serving import registry
-from fleetx_tpu.serving.decode import SamplingParams
 from fleetx_tpu.serving.paged_cache import NULL_PAGE, PageAllocator
+from fleetx_tpu.serving.programs import SamplingParams
 from fleetx_tpu.utils.env import log_compile
 from fleetx_tpu.utils.log import logger
 
@@ -434,11 +434,9 @@ class ServingEngine:
         self._fns = self._programs.fns
         self.paged_kernel_active = self._programs.paged_kernel_active
         self.cache_bytes = sum(int(a.nbytes) for a in self.cache)
-        # what one fold of the decode kernel covers and how many a whole
-        # table row would take: the serving_page_walk_share gauge counts a
-        # tick's folds with the helper the kernel's trip count uses
-        self._walk_shape = self._programs.walk_shape
-        # the query positions the last decode call was given (kernel path)
+        # the query positions the last decode call was given (kernel path:
+        # the serving_page_walk_share gauge counts a tick's folds from them
+        # with the helper the kernel's trip count uses)
         self._decoded_lens: Optional[np.ndarray] = None
 
         self._compiled: set = set()  # programs whose compile was logged
@@ -477,9 +475,10 @@ class ServingEngine:
             self.metrics.gauge(f"serving_kv_fold_copies_{kind}").set(copies)
         # bytes of the caches that are not lists of keys and values (a pool
         # of latents, a constant-size state a slot): 0 in a family without
-        for kind in ("state", "latent"):
-            self.metrics.gauge(f"serving_{kind}_cache_bytes").set(
-                self._programs.cache_bytes.get(kind, 0))
+        self._other_cache_bytes = self.family.cache_bytes(self.cache)
+        self._record_stats = self.family.stats_recorder(model_cfg)
+        for kind, nbytes in self._other_cache_bytes.items():
+            self.metrics.gauge(f"serving_{kind}_cache_bytes").set(nbytes)
         # the tick's own clock (a test may replace it) and what it last
         # read: seconds by phase of the LAST tick only, ``tick`` the whole
         self._clock = time.monotonic
@@ -510,6 +509,7 @@ class ServingEngine:
         # threads must go through the server's submission queue, never
         # call submit()/step() directly. FLEETX_TSAN=1 enforces that.
         tsan.register_object(self, "serving-engine")
+        caches = self.family.describe(model_cfg, sc, self.cache)
         logger.info(
             "serving engine: max_batch=%d pages=%d x %d tokens "
             "(capacity %d token slots/layer), prefill_chunk=%d, "
@@ -527,8 +527,7 @@ class ServingEngine:
                     % (kind, pages, copies, "y" if copies == 1 else "ies")
                     for kind, (pages, copies) in sorted(folds.items())),
             "lazy" if sc.lazy_alloc else "reserve", self.cache_bytes,
-            " (%s)" % self._programs.describe
-            if self._programs.describe else "", weights)
+            caches, weights)
 
     # the first two cache buffers are the paged pool's K and V in every
     # family (GPT has no others)
@@ -761,7 +760,7 @@ class ServingEngine:
             tok = self._fresh_tok = self._rebind(self._call(
                 "prefill", self.params, *self.cache, tokens, table,
                 np.int32(pos), np.int32(n_valid), *self._draw(),
-                *self._programs.prefill_extra(req.slot)))[0]
+                *self.family.prefill_extra(req.slot)))[0]
             req.prefill_pos = pos + n_valid
             self.timelines.note(req.id, "prefill_chunk", chunk=index,
                                 tokens=n_valid)
@@ -989,7 +988,7 @@ class ServingEngine:
             slots = [r.slot for r in rows]
             lens = np.full_like(self._lens, -1)
             lens[slots] = self._lens[slots]
-            if self._walk_shape is not None:
+            if self.paged_kernel_active:
                 self._decoded_lens = lens
             fresh = -1
             if self._first is not None and self._holds(*self._first[:2]):
@@ -1031,8 +1030,7 @@ class ServingEngine:
                    counters: list) -> None:
         """One token for every row of ``step`` that still is what it ran."""
         now = time.monotonic()
-        if counters and self._programs.record_stats is not None:
-            self._programs.record_stats(self.metrics, counters[0])
+        self._record_stats(self.metrics, counters)
         for req, admit_seq in step.rows:
             if not self._holds(req, admit_seq):
                 # preempted, cancelled or shed since: no token. Finished
@@ -1239,14 +1237,14 @@ class ServingEngine:
             self.allocator.internal_fragmentation(self._used_slots()))
         # tokens the running rows hold, a layer of each kind of cache, and
         # the bytes of all cache buffers (fixed when the engine is built)
-        full, window = self._programs.kv_tokens(self._lens)
+        full, window = self.family.kv_tokens(self.cfg, self._lens)
         self.metrics.gauge("serving_kv_full_tokens").set(full)
         self.metrics.gauge("serving_kv_window_tokens").set(window)
         self.metrics.gauge("serving_kv_cache_bytes").set(self.cache_bytes)
         if self._decoded_lens is not None:
             # of the page groups in the block table, the share this tick's
             # decode call folded (1.0: every row at the end of its table)
-            span, groups = self._walk_shape
+            span, groups = self._programs.kernel.walk_shape
             self.metrics.gauge("serving_page_walk_share").set(
                 int(PA.page_groups_walked(self._decoded_lens, span,
                                           groups).sum())
@@ -1308,10 +1306,8 @@ class ServingEngine:
             # copies a cache buffer that fetch them] (kernel path only)
             "kv_folds": {kind: list(shape) for kind, shape
                          in self._programs.kv_folds.items()},
-            "serving_state_cache_bytes":
-                int(self._programs.cache_bytes.get("state", 0)),
-            "serving_latent_cache_bytes":
-                int(self._programs.cache_bytes.get("latent", 0)),
+            "serving_state_cache_bytes": self._other_cache_bytes["state"],
+            "serving_latent_cache_bytes": self._other_cache_bytes["latent"],
             **gauges,
             "tokens_total": int(tokens),
             "tokens_per_sec": tokens / wall,
@@ -1331,13 +1327,9 @@ class ServingEngine:
             "chips": int(self.n_chips),
             "requests_per_chip": completed / max(self.n_chips, 1),
         }
-        if self._programs.record_stats is not None:
-            # a family with sparse experts: what rode the decode program's
-            # outputs to the host with the tokens
-            snap["serving_moe_load_max_over_mean"] = m.histogram(
-                "serving_moe_load_max_over_mean").summary().get("mean")
-            snap["serving_moe_passes_total"] = int(
-                m.counter("serving_moe_passes_total").value)
+        # a family with sparse experts: what rode the decode program's
+        # outputs to the host with the tokens
+        snap.update(self.family.stats_snapshot(m))
         if self.slo is not None:
             snap["slo_attainment"] = self.slo.observe(snap)["attainment"]
         return snap

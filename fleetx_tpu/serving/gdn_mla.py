@@ -51,8 +51,9 @@ all slots, in place (``gdn_decode``). Tokens past a ragged chunk's end
 carry ``g = 0, β = 0`` and change nothing.
 
 **Parameters**: bfloat16, but every norm's weight, the router with its
-selection bias and the decay's vectors in float32; ``serving_params`` makes
-that tree once and the programs refuse any other.
+selection bias and the decay's vectors in float32;
+``programs.serving_params`` makes that tree once (``Family.serving_params``,
+``serving/registry.py``) and the programs refuse any other.
 """
 
 from __future__ import annotations
@@ -69,9 +70,8 @@ from fleetx_tpu.models.swa_moe import model as shared
 from fleetx_tpu.observability.trace import device_scope
 from fleetx_tpu.ops import gated_delta as GD
 from fleetx_tpu.ops import mla_paged_attention as LA
-from fleetx_tpu.serving import swa_moe as windowed
-from fleetx_tpu.serving.decode import (SamplingParams, _sample,
-                                       merge_fresh)
+from fleetx_tpu.serving import programs
+from fleetx_tpu.serving.programs import SamplingParams
 
 _NEG = -1e30
 
@@ -100,24 +100,19 @@ def init_cache(cfg: GDNMLAConfig, **geometry) -> tuple:
             jnp.zeros(tail, cfg.dtype))
 
 
-def latent_kernel_refusal(cfg: GDNMLAConfig, *, page_size: int) -> str:
+def describe(cfg: GDNMLAConfig, serving: Any, cache: list) -> str:
+    """The caches of one engine, in words (its start-up line)."""
+    return "%d latent layers paged (%d lanes a token), %d linear layers a " \
+        "state and a convolution tail a slot" % (
+            cfg.layers_of(LATENT), cache[0].shape[3], cfg.layers_of(LINEAR))
+
+
+def kernel_refusal(cfg: GDNMLAConfig, *, page_size: int) -> str:
     """Why the latent decode kernel does not admit this geometry, or ""."""
     return LA.refusal(num_heads=cfg.num_attention_heads,
                       lanes=LA.lanes_of(cfg.latent_width),
                       value_width=cfg.kv_lora_rank, page_size=page_size,
                       dtype=cfg.dtype)
-
-
-# ---------------------------------------------------------------- parameters
-def _unserved(params: Any, cfg: GDNMLAConfig) -> list:
-    return windowed._unserved(params, cfg, M.served_dtype)
-
-
-def serving_params(params: Any, cfg: GDNMLAConfig) -> Any:
-    """The tree both programs take: every leaf in ``cfg.dtype`` but those
-    ``models/gdn_mla/model.py`` keeps in float32 (one jitted cast of the
-    leaves that need it: ``serving/swa_moe.py:serving_params``)."""
-    return windowed.serving_params(params, cfg, M.served_dtype)
 
 
 # ----------------------------------------------------------------- attention
@@ -177,16 +172,12 @@ def _forward(params: Any, cfg: GDNMLAConfig, tokens, positions, cache,
     ``slot``, ``n_valid`` of them real, from position ``start``. ``cache``
     is ``(latent pool, state, tail)``; ``block_tables`` [B, pages_per_req]
     the rows' pages in the pool. Returns ``(hidden [rows, h], cache,
-    stats)`` — the stats are ``serving/swa_moe.py``'s."""
-    unserved = _unserved(params, cfg)
-    if unserved:
-        raise TypeError(
-            "the serving programs take the tree serving_params() makes: "
-            f"{len(unserved)} leaves are not in their served dtype")
+    stats)`` — the stats are ``programs.walk_runs``'s."""
+    programs.refuse_unserved(params, cfg, M.served_dtype)
     (rows,) = tokens.shape
     dt = cfg.dtype
     pool = cache[0]
-    ps, P = pool.shape[2], block_tables.shape[1]
+    ps = pool.shape[2]
     lanes, width = pool.shape[3], cfg.latent_width
     taps = cfg.linear_conv_kernel_dim
     moe_pass_rows = shared.pass_rows(cfg, rows)
@@ -195,15 +186,8 @@ def _forward(params: Any, cfg: GDNMLAConfig, tokens, positions, cache,
 
     with device_scope("embed"):
         x = params["embed"]["tokens"][jnp.maximum(tokens, 0)]
-    with device_scope("attn.cache"):    # where the rows go, for every layer
-        valid = positions >= 0
-        q_pos = jnp.maximum(positions, 0)
-        offs = jnp.clip(positions % ps, 0, ps - 1)
-        page_slot = jnp.clip(positions // ps, 0, P - 1)
-        tables = block_tables if decode else \
-            jnp.broadcast_to(block_tables, (rows, P))
-        pages = jnp.where(valid, jnp.take_along_axis(
-            tables, page_slot[:, None], axis=1)[:, 0], 0)
+    valid, q_pos, offs, pages = programs.row_targets(positions, block_tables,
+                                                     ps)
     first = None if decode else start == 0      # the request's first chunk
 
     def linear_mixer(u, lp, cache, at):
@@ -271,17 +255,14 @@ def _forward(params: Any, cfg: GDNMLAConfig, tokens, positions, cache,
         with device_scope("attn.proj"):
             return M.latent_output(o, u, lp, cfg), (pool, state, tail)
 
-    def run(kind, lo, n, cache_lo, carry):
+    def layer_of(kind, lo, cache_lo):
         stack = params[kind]
         mixer, mlp = kind.split("_")
         dense = mlp == "dense"
-        per_layer = {k: v for k, v in stack.items() if k != "moe"}
-        if not dense:
-            per_layer["moe"] = {k: v for k, v in stack["moe"].items()
-                                if not k.startswith("experts_")}
+        per_layer = programs.per_layer_leaves(stack)
 
         def layer(i, carry):
-            x, cache, hit, pairs, load, passes = carry
+            x, cache, counters = carry
             lp = jax.tree.map(lambda w: w[i], per_layer)
             with device_scope("norm"):
                 u = M.norm(x, lp["attn_norm"]["w"], cfg, dt)
@@ -310,88 +291,41 @@ def _forward(params: Any, cfg: GDNMLAConfig, tokens, positions, cache,
                         y = y + M.gated_mlp(u, moe["shared_gate"],
                                             moe["shared_up"],
                                             moe["shared_down"], combine)
-                with device_scope("moe.route"):     # the step's counters
-                    hit = hit + (held_rows > 0).sum().astype(jnp.float32)
-                    pairs = pairs + held_rows.sum().astype(jnp.int32)
-                    held = held_rows.astype(jnp.float32)
-                    load = jnp.maximum(
-                        load, held.max() / jnp.maximum(held.mean(), 1e-9))
-                    passes = passes + turns.astype(jnp.int32)
+                counters = programs.count_held(counters, held_rows, turns)
             with device_scope("norm"):
                 x = x + M.norm(y, lp["mlp_post_norm"]["w"], cfg, dt)
-            return x, cache, hit, pairs, load, passes
+            return x, cache, counters
 
-        with device_scope("stack"):
-            if n == 1:  # a static index: the layer is a view of its stack
-                return layer(lo, carry)
-            return jax.lax.fori_loop(lo, lo + n, layer, carry)
+        return layer
 
-    carry = (x, tuple(cache), jnp.float32(0.0), jnp.int32(0),
-             jnp.float32(0.0), jnp.int32(0))
-    for kind, lo, n, cache_lo in cfg.runs():
-        carry = run(kind, lo, n, cache_lo, carry)
-    x, cache, hit, pairs, load, passes = carry
+    x, cache, stats = programs.walk_runs(cfg, x, cache, layer_of)
     with device_scope("head"):
         x = M.norm(x, params["final_norm"]["w"], cfg, dt)
-    return x, cache, {"hit": hit, "pairs_held": pairs,
-                      "load_max_over_mean": load, "passes": passes}
-
-
-@device_scope("head")
-def _logits(params: Any, x_last: jax.Array) -> jax.Array:
-    """The (untied) head on the selected positions -> float32 ``[B, V]``."""
-    return jnp.einsum("bh,hv->bv", x_last, params["head"]["kernel"],
-                      preferred_element_type=jnp.float32)
+    return x, cache, stats
 
 
 def make_step_fns(cfg: GDNMLAConfig, *, prefill_chunk: int,
                   sampling: SamplingParams, kernels: bool = False,
                   latent_kernel: bool = False) -> dict:
-    """The two jitted programs of one engine, ``{"prefill", "decode"}``.
+    """The two jitted programs of one engine, ``{"prefill", "decode"}``:
+    ``serving/programs.py:step_fns`` around ``_forward`` over ``(latent
+    pool, state, tail)``. ``prefill`` takes the slot whose state the request
+    owns after the draw count; ``decode`` returns the step's expert counters
+    after its logits. ``kernels``: the two Pallas kernels of the rule (else
+    their XLA paths); ``latent_kernel``: the latent decode kernel (else the
+    gathered view)."""
+    def prefill(params, cache, tokens, positions, block_table, start,
+                n_valid, slot):
+        return _forward(
+            params, cfg, tokens[0], positions, cache, block_table, slot,
+            start, n_valid, decode=False, kernels=kernels,
+            latent_kernel=False, moe_kernel="moe_gmm_prefill")
 
-    Both take ``(params, latent pool, state, tail, ...)``, donate the three
-    cache buffers and return them first; what follows is what
-    ``serving/swa_moe.py``'s programs take and return (``prefill`` with the
-    slot whose state the request owns; ``decode`` with the step's expert
-    counters after its logits). ``kernels``: the two Pallas kernels of the
-    rule (else their XLA paths); ``latent_kernel``: the latent decode
-    kernel (else the gathered view). Shapes are static, so each jit cache
-    holds one entry for the engine's lifetime."""
-    def prefill(params, pool, state, tail, tokens, block_table, start,
-                n_valid, rng, draw, slot):
-        """One prompt chunk of the request in slot ``slot``: ``tokens``
-        ``[1, C]`` with ``n_valid`` real entries from position ``start``."""
-        idx = jnp.arange(prefill_chunk, dtype=jnp.int32)
-        positions = jnp.where(idx < n_valid, start + idx, -1)
-        x, cache, _ = _forward(
-            params, cfg, tokens[0], positions, (pool, state, tail),
-            block_table, slot, start, n_valid, decode=False,
-            kernels=kernels, latent_kernel=False,
-            moe_kernel="moe_gmm_prefill")
-        with device_scope("head"):
-            at = jnp.clip(n_valid - 1, 0, prefill_chunk - 1)
-            x_last = jax.lax.dynamic_index_in_dim(x, at, axis=0,
-                                                  keepdims=False)[None]
-        logits = _logits(params, x_last)
-        return (*cache, _sample(logits, rng, draw, sampling), logits)
+    def decode(params, cache, tokens, positions, block_tables, lens):
+        return _forward(
+            params, cfg, tokens, positions, cache, block_tables, None, None,
+            None, decode=True, kernels=kernels, latent_kernel=latent_kernel,
+            moe_kernel="moe_gmm_decode")
 
-    def decode(params, pool, state, tail, tokens, fresh_slot, fresh_tok,
-               block_tables, lens, rng, draw):
-        """One token for every slot: ``tokens`` / ``lens`` ``[max_batch]``
-        (an empty slot, or one still in prefill, carries ``lens < 0`` and
-        keeps its state)."""
-        tokens = merge_fresh(tokens, fresh_slot, fresh_tok)
-        positions = jnp.where(lens >= 0, lens, -1)
-        x, cache, stats = _forward(
-            params, cfg, tokens, positions, (pool, state, tail),
-            block_tables, None, None, None, decode=True, kernels=kernels,
-            latent_kernel=latent_kernel, moe_kernel="moe_gmm_decode")
-        logits = _logits(params, x)
-        with device_scope("moe.route"):     # rides with the counters
-            stats["rows"] = (lens >= 0).sum().astype(jnp.int32)
-        return (*cache, _sample(logits, rng, draw, sampling), logits,
-                stats)
-
-    donate = (1, 2, 3)
-    return {"prefill": jax.jit(prefill, donate_argnums=donate),
-            "decode": jax.jit(decode, donate_argnums=donate)}
+    return programs.step_fns(prefill, decode, programs.untied_logits, caches=3,
+                             prefill_chunk=prefill_chunk, sampling=sampling)

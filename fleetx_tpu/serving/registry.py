@@ -19,10 +19,31 @@ family file) finds its family by the ``module`` its config carries.
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Optional
+import importlib
+from typing import Optional
 
-__all__ = ["Family", "Programs", "families", "family", "family_of",
-           "model_config", "served_template", "init_params", "build_engine"]
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from fleetx_tpu.ops import paged_attention as PA
+from fleetx_tpu.serving import programs
+from fleetx_tpu.utils.log import logger
+
+__all__ = ["Family", "KernelWalk", "Programs", "families", "family",
+           "family_of", "model_config", "served_template", "init_params",
+           "build_engine"]
+
+
+@dataclasses.dataclass(frozen=True)
+class KernelWalk:
+    """How the decode kernel walks an engine's caches."""
+
+    # (tokens one fold of the kernel covers, folds of a table row)
+    walk_shape: tuple
+    # cache kind ("full", "window", "latent") the engine has -> (pages one
+    # fold takes, copies a cache buffer that fetch them)
+    kv_folds: dict
 
 
 @dataclasses.dataclass
@@ -34,60 +55,125 @@ class Programs:
     # [max_batch] int32 on the device: what the first ``decode`` call takes
     # as the last tokens; every later one takes the call before's output
     tokens: object
-    paged_kernel_active: bool
-    # (tokens one fold of the decode kernel covers, folds of a table row)
-    walk_shape: Optional[tuple] = None
-    # cache kind ("full", "window", "latent") -> (pages one fold of the
-    # decode kernel takes, copies a cache buffer that fetch them); a kind
-    # the engine does not have, or every kind on the gathered view, is absent
-    kv_folds: dict = dataclasses.field(default_factory=dict)
-    # bytes of the caches that are not lists of keys and values: "latent"
-    # (a paged pool of latents) and "state" (constant-size state a slot: a
-    # recurrent state, a convolution's tail); absent in a family without
-    # them
-    cache_bytes: dict = dataclasses.field(default_factory=dict)
-    # slot -> further arguments of ``prefill`` after the rng and the draw
-    prefill_extra: Callable = lambda slot: ()
-    # (metrics registry, what ``decode`` returned after its logits)
-    record_stats: Optional[Callable] = None
-    # host lengths [slots] -> (tokens held in full layers' pages, tokens
-    # held in window layers' rings), a layer each
-    kv_tokens: Callable = lambda lens: (int(lens[lens >= 0].sum()), 0)
-    describe: str = ""
+    # None: decode attention reads the gathered view
+    kernel: Optional[KernelWalk]
+
+    @property
+    def paged_kernel_active(self) -> bool:
+        return self.kernel is not None
+
+    @property
+    def kv_folds(self) -> dict:
+        return self.kernel.kv_folds if self.kernel else {}
 
 
 class Family:
-    """One served model family; subclasses fill the functions."""
+    """One served model family: a class of attributes, its ``programs()``
+    and the answers below that differ from the default written here.
+
+    ``model_package`` names a package with ``config.config_from_dict`` and
+    ``model.init_params(cfg, key, served=True)`` / ``served_template`` /
+    ``served_dtype``; ``serving_module`` the family's caches and forward."""
 
     modules: tuple = ()             # the ``Model.module`` names it serves
+    model_package: str = ""         # "fleetx_tpu.models.<family>"
+    serving_module: str = ""        # "fleetx_tpu.serving.<family>"
+    #: what the one warning calls the attention that fell back
+    decode_attention = "decode attention"
+    #: what the programs do not place on a mesh yet
+    unplaced = "its caches"
 
+    def _model(self, part: str):
+        return importlib.import_module(f"{self.model_package}.{part}")
+
+    def _serving(self):
+        return importlib.import_module(self.serving_module)
+
+    # ---------------------------------------------------------- parameters
     def model_config(self, model: dict, quantization: dict):
         """The recipe's ``Model:`` (and ``Quantization:``) -> config."""
-        raise NotImplementedError
+        assert not quantization.get("weight_bits") and \
+            not quantization.get("activation_bits"), \
+            f"quantized decode is not written for {type(self).__name__}"
+        return self._model("config").config_from_dict(dict(model))
 
     def init_params(self, model_cfg, seed: int):
-        """Seeded parameters, in the model's ``param_dtype``."""
-        raise NotImplementedError
+        """Seeded parameters, made as they are served: a recipe's tree is
+        6–10 GB in bfloat16 and would be twice that beside its cast."""
+        init = self._model("model").init_params
+        return jax.jit(lambda key: init(model_cfg, key, served=True))(
+            jax.random.PRNGKey(seed))
 
     def serving_params(self, params, model_cfg):
-        """The tree the programs take (cast once; ``decode.py``)."""
-        raise NotImplementedError
+        """The tree the programs take (cast once; ``programs.py``)."""
+        return programs.serving_params(params, model_cfg,
+                                       self._model("model").served_dtype)
 
     def served_template(self, model_cfg):
         """That tree as ``ShapeDtypeStruct`` leaves, nothing initialised."""
-        import jax
+        return self._model("model").served_template(model_cfg)
 
-        return jax.eval_shape(lambda: self.serving_params(
-            self.init_params(model_cfg, 0), model_cfg))
-
+    # ------------------------------------------------------------ programs
     def programs(self, model_cfg, serving, sampling, mesh,
                  pages_per_req: int) -> Programs:
         """The cache buffers and the two jitted programs of one engine."""
         raise NotImplementedError
 
+    def _one_chip_unquantized(self, serving, mesh) -> None:
+        """What every family but GPT refuses, with a sentence."""
+        assert mesh is None or mesh.size == 1, \
+            f"{type(self).__name__} serves on one chip: its programs " \
+            f"place {self.unplaced} on a mesh yet"
+        assert not serving.quantize_decode, \
+            f"quantized decode is not written for {type(self).__name__}"
+
+    def _kernel_serves(self, serving, refusal: str) -> bool:
+        """Kernel or gather, decided HERE, once, for every family.
+        ``refusal``: why the decode kernel cannot take this geometry ("":
+        it can) — a static function of the config, the pool and the mesh,
+        so the decode program compiles exactly one attention path and the
+        jit cache stays pinned at one entry."""
+        if serving.paged_kernel and refusal:
+            # said once, when the engine is built
+            logger.warning("%s falls back to the gathered view: %s",
+                           self.decode_attention, refusal)
+        return bool(serving.paged_kernel) and not refusal
+
+    # ------------------------------------- what a family says about itself
+    def describe(self, model_cfg, serving, cache: list) -> str:
+        """What the engine's start-up line says after the caches' bytes:
+        ``" (<the caches, in words>)"``."""
+        return " (%s)" % self._serving().describe(model_cfg, serving, cache)
+
+    def cache_bytes(self, cache: list) -> dict:
+        """Bytes of the caches that are not lists of keys and values:
+        "latent" (a paged pool of latents) and "state" (constant-size state
+        a slot: a recurrent state, a convolution's tail)."""
+        return {"latent": 0, "state": 0}
+
+    def prefill_extra(self, slot: int) -> tuple:
+        """Further arguments of ``prefill`` after the rng and the draw: the
+        slot whose ring, state or tail the request owns."""
+        return (np.int32(slot),)
+
+    def kv_tokens(self, model_cfg, lens) -> tuple:
+        """Host lengths [slots] -> (tokens held in full layers' pages,
+        tokens held in window layers' rings), a layer each."""
+        return int(lens[lens >= 0].sum()), 0
+
+    def stats_recorder(self, model_cfg):
+        """``record(metrics, what decode returned after its logits)``: the
+        step's expert counters -> the ``serving_moe_*`` metrics."""
+        return programs.expert_stats_recorder(model_cfg)
+
+    def stats_snapshot(self, metrics) -> dict:
+        """The ``serving_snapshot()`` keys of what the recorder keeps."""
+        return programs.expert_stats_snapshot(metrics)
+
 
 class GPTFamily(Family):
-    """The GPT block (``models/gpt``, ``serving/decode.py``)."""
+    """The GPT block (``models/gpt``, ``serving/decode.py``): flax
+    parameters, QAT bits, pools placed on a mesh; no experts, no slot."""
 
     modules = ("GPTModule", "GPTGenerationModule", "GPTEvalModule",
                "LoRAGPTModule")
@@ -104,9 +190,7 @@ class GPTFamily(Family):
         return config_from_dict(model)
 
     def init_params(self, model_cfg, seed: int):
-        """See ``Family.init_params``."""
-        import jax
-        import jax.numpy as jnp
+        """Seeded parameters, in the model's ``param_dtype``."""
         from flax.core import meta
 
         from fleetx_tpu.models.gpt.model import GPTForPretraining
@@ -117,83 +201,58 @@ class GPTFamily(Family):
             deterministic=True)["params"])
 
     def serving_params(self, params, model_cfg):
-        """See ``Family.serving_params``."""
+        """``decode.py``'s own rule: the layer norms' leaves as they came."""
         from fleetx_tpu.serving.decode import serving_params
 
         return serving_params(params, model_cfg)
 
+    def served_template(self, model_cfg):
+        """See ``Family.served_template``."""
+        return jax.eval_shape(lambda: self.serving_params(
+            self.init_params(model_cfg, 0), model_cfg))
+
     def programs(self, model_cfg, serving, sampling, mesh,
                  pages_per_req: int) -> Programs:
         """See ``Family.programs``."""
-        import jax
-
-        import jax.numpy as jnp
-
-        from fleetx_tpu.ops import paged_attention as PA
-        from fleetx_tpu.serving.decode import (make_step_fns,
-                                               paged_kernel_enabled,
-                                               token_sharding)
+        from fleetx_tpu.serving import decode as S
         from fleetx_tpu.serving.paged_cache import init_pool, pool_shardings
 
         sc = serving
-        pool_k, pool_v = init_pool(model_cfg, sc.num_pages, sc.page_size)
+        cache = list(init_pool(model_cfg, sc.num_pages, sc.page_size))
         tokens = jnp.zeros((sc.max_batch,), jnp.int32)
         sharding = None
         if mesh is not None:
             sharding = pool_shardings(mesh)
-            pool_k = jax.device_put(pool_k, sharding)
-            pool_v = jax.device_put(pool_v, sharding)
-            tokens = jax.device_put(tokens, token_sharding(mesh))
-        # kernel-vs-gather is decided HERE, once: the support predicates
-        # are static functions of the config/pool/mesh, so the decode
-        # program compiles exactly one attention path and the jit cache
-        # stays pinned at one entry (test_serving pins this)
-        active = bool(sc.paged_kernel) and paged_kernel_enabled(
-            model_cfg, page_size=sc.page_size, num_pages=sc.num_pages,
-            pages_per_req=pages_per_req, pool_sharding=sharding)
-        fns = make_step_fns(
+            cache = [jax.device_put(pool, sharding) for pool in cache]
+            tokens = jax.device_put(tokens, S.token_sharding(mesh))
+        geometry = dict(page_size=sc.page_size, pages_per_req=pages_per_req,
+                        pool_sharding=sharding)
+        kernel = self._kernel_serves(sc, S.kernel_refusal(
+            model_cfg, num_pages=sc.num_pages, **geometry))
+        fns = S.make_step_fns(
             model_cfg, max_batch=sc.max_batch, pages_per_req=pages_per_req,
             prefill_chunk=sc.prefill_chunk, sampling=sampling,
             quantize=bool(sc.quantize_decode), pool_sharding=sharding,
-            paged_kernel=active)
-        walk, folds = None, {}
-        if active:
-            geometry = dict(
-                num_heads=model_cfg.num_attention_heads // (
-                    mesh.shape["tensor"] if mesh is not None else 1),
-                head_dim=model_cfg.head_dim, page_size=sc.page_size,
-                pages_per_req=pages_per_req, dtype=model_cfg.dtype)
-            walk = PA.page_walk_shape(**geometry)
-            folds = {"full": PA.fold_shape(**geometry)}
-        return Programs(cache=[pool_k, pool_v], fns=fns, tokens=tokens,
-                        paged_kernel_active=active, walk_shape=walk,
-                        kv_folds=folds)
+            paged_kernel=kernel)
+        walk = None
+        if kernel:
+            one_shard = S.kernel_geometry(model_cfg, **geometry)
+            walk = KernelWalk(PA.page_walk_shape(**one_shard),
+                              {"full": PA.fold_shape(**one_shard)})
+        return Programs(cache=cache, fns=fns, tokens=tokens, kernel=walk)
 
+    def describe(self, model_cfg, serving, cache: list) -> str:
+        return ""
 
-def _expert_counters(cfg) -> Callable:
-    """``Programs.record_stats`` of a family with sparse experts (``cfg``:
-    its model config, whose ``kinds()`` name the stacks with experts
-    ``*moe``): what its decode program returns after its logits -> the
-    ``serving_moe_*`` metrics."""
-    expert_layers = sum(n for kind, n in cfg.kinds().items()
-                        if kind.endswith("moe"))
-    per_tok = cfg.num_experts_per_tok
+    def prefill_extra(self, slot: int) -> tuple:
+        return ()
 
-    def record(metrics, stats):
-        if not expert_layers:
-            return
-        metrics.histogram("serving_moe_experts_hit").record(
-            float(stats["hit"]) / expert_layers)
-        metrics.counter("serving_moe_pairs_held_total").inc(
-            int(stats["pairs_held"]))
-        metrics.counter("serving_moe_pairs_total").inc(
-            int(stats["rows"]) * per_tok * expert_layers)
-        metrics.histogram("serving_moe_load_max_over_mean").record(
-            float(stats["load_max_over_mean"]))
-        metrics.counter("serving_moe_passes_total").inc(
-            int(stats["passes"]))
+    def stats_recorder(self, model_cfg):
+        """No experts: ``decode`` returns nothing after its logits."""
+        return lambda metrics, counters: None
 
-    return record
+    def stats_snapshot(self, metrics) -> dict:
+        return {}
 
 
 class SWAMoEFamily(Family):
@@ -202,104 +261,40 @@ class SWAMoEFamily(Family):
     ``docs/swa_moe.md`` has the family's members)."""
 
     modules = ("SWAMoEModule",)
-
-    def model_config(self, model: dict, quantization: dict):
-        """See ``Family.model_config``."""
-        from fleetx_tpu.models.swa_moe.config import config_from_dict
-
-        assert not quantization.get("weight_bits") and \
-            not quantization.get("activation_bits"), \
-            "quantized decode is not written for this family"
-        return config_from_dict(dict(model))
-
-    def init_params(self, model_cfg, seed: int):
-        """See ``Family.init_params``."""
-        import jax
-
-        from fleetx_tpu.models.swa_moe.model import init_params
-
-        # made as it is served: the recipe's tree is 6.4 GB in bfloat16
-        # and would be 12.8 GB in float32 beside its cast
-        return jax.jit(lambda key: init_params(model_cfg, key, served=True))(
-            jax.random.PRNGKey(seed))
-
-    def serving_params(self, params, model_cfg):
-        """See ``Family.serving_params``."""
-        from fleetx_tpu.serving.swa_moe import serving_params
-
-        return serving_params(params, model_cfg)
-
-    def served_template(self, model_cfg):
-        """See ``Family.served_template``."""
-        from fleetx_tpu.models.swa_moe.model import served_template
-
-        return served_template(model_cfg)
+    model_package = "fleetx_tpu.models.swa_moe"
+    serving_module = "fleetx_tpu.serving.swa_moe"
+    unplaced = "neither cache"
 
     def programs(self, model_cfg, serving, sampling, mesh,
                  pages_per_req: int) -> Programs:
         """See ``Family.programs``."""
-        import jax.numpy as jnp
-        import numpy as np
-
-        from fleetx_tpu.ops import paged_attention as PA
-        from fleetx_tpu.serving import swa_moe as S
-
-        sc, cfg = serving, model_cfg
+        S, sc = self._serving(), serving
         if mesh is not None:
             from fleetx_tpu.parallel.rules import kv_pool_spec
 
-            kv_pool_spec(num_kv_heads=cfg.num_key_value_heads,
+            kv_pool_spec(num_kv_heads=model_cfg.num_key_value_heads,
                          tensor_degree=int(dict(mesh.shape).get("tensor", 1)))
-            assert mesh.size == 1, \
-                "this family serves on one chip: its programs place " \
-                "neither cache on a mesh yet"
-        assert not sc.quantize_decode, \
-            "quantized decode is not written for this family"
-        geometry = dict(num_pages=sc.num_pages, page_size=sc.page_size,
-                        max_batch=sc.max_batch,
-                        prefill_chunk=sc.prefill_chunk)
-        cache = list(S.init_cache(cfg, **geometry))
-        refused = S.gather_fallbacks(cfg, page_size=sc.page_size,
-                                     pages_per_req=pages_per_req)
-        active = bool(sc.paged_kernel) and not refused
-        if sc.paged_kernel and refused:
-            from fleetx_tpu.utils.log import logger
-
-            # said once, when the engine is built: decode attention reads
-            # a gathered view of BOTH caches, not the kernel's page walk
-            logger.warning(
-                "decode attention falls back to the gathered view: %s",
-                "; ".join(f"{kind} layers: {why}" for kind, why in refused))
+        self._one_chip_unquantized(sc, mesh)
+        geometry = dict(page_size=sc.page_size, pages_per_req=pages_per_req)
+        cache = S.init_cache(
+            model_cfg, num_pages=sc.num_pages, page_size=sc.page_size,
+            max_batch=sc.max_batch, prefill_chunk=sc.prefill_chunk)
+        kernel = self._kernel_serves(
+            sc, S.kernel_refusal(model_cfg, **geometry))
         fns = S.make_step_fns(
-            cfg, prefill_chunk=sc.prefill_chunk, page_size=sc.page_size,
-            sampling=sampling, paged_kernel=active)
-        window = cfg.sliding_window
-        ring = S.ring_pages(cfg, sc.page_size, sc.prefill_chunk)
-        walk, folds = None, {}
-        if active:
-            geometry = dict(
-                num_heads=max(cfg.num_attention_heads_per_layer),
-                head_dim=cfg.head_dim, page_size=sc.page_size,
-                dtype=cfg.dtype, num_kv_heads=cfg.num_key_value_heads)
-            walk = PA.page_walk_shape(pages_per_req=pages_per_req, **geometry)
-            folds = {"full": PA.fold_shape(pages_per_req=pages_per_req,
-                                           **geometry),
-                     "window": PA.fold_shape(pages_per_req=ring,
-                                             ring_pages=ring, **geometry)}
+            model_cfg, prefill_chunk=sc.prefill_chunk, page_size=sc.page_size,
+            sampling=sampling, paged_kernel=kernel)
+        walk = KernelWalk(*S.kernel_walk(
+            model_cfg, prefill_chunk=sc.prefill_chunk, **geometry)) \
+            if kernel else None
+        return Programs(cache=list(cache), fns=fns, kernel=walk,
+                        tokens=jnp.zeros((sc.max_batch,), jnp.int32))
 
-        def kv_tokens(lens):
-            live = lens[lens >= 0]
-            return int(live.sum()), int(np.minimum(live, window).sum())
-
-        return Programs(
-            cache=cache, fns=fns,
-            tokens=jnp.zeros((sc.max_batch,), jnp.int32),
-            paged_kernel_active=active, walk_shape=walk, kv_folds=folds,
-            prefill_extra=lambda slot: (np.int32(slot),),
-            record_stats=_expert_counters(cfg), kv_tokens=kv_tokens,
-            describe="%d full layers paged, %d window layers a ring of %d "
-                     "pages a slot" % (cfg.layers_of("full"),
-                                       cfg.layers_of("window"), ring))
+    def kv_tokens(self, model_cfg, lens) -> tuple:
+        """See ``Family.kv_tokens``: a ring holds the window's at most."""
+        live = lens[lens >= 0]
+        return int(live.sum()), int(
+            np.minimum(live, model_cfg.sliding_window).sum())
 
 
 class GDNMLAFamily(Family):
@@ -309,87 +304,38 @@ class GDNMLAFamily(Family):
     and a convolution tail a slot."""
 
     modules = ("GDNMLAModule",)
-
-    def model_config(self, model: dict, quantization: dict):
-        """See ``Family.model_config``."""
-        from fleetx_tpu.models.gdn_mla.config import config_from_dict
-
-        assert not quantization.get("weight_bits") and \
-            not quantization.get("activation_bits"), \
-            "quantized decode is not written for this family"
-        return config_from_dict(dict(model))
-
-    def init_params(self, model_cfg, seed: int):
-        """See ``Family.init_params``."""
-        import jax
-
-        from fleetx_tpu.models.gdn_mla.model import init_params
-
-        # made as it is served: the recipe's tree is 9.5 GB in bfloat16
-        return jax.jit(lambda key: init_params(model_cfg, key, served=True))(
-            jax.random.PRNGKey(seed))
-
-    def serving_params(self, params, model_cfg):
-        """See ``Family.serving_params``."""
-        from fleetx_tpu.serving.gdn_mla import serving_params
-
-        return serving_params(params, model_cfg)
-
-    def served_template(self, model_cfg):
-        """See ``Family.served_template``."""
-        from fleetx_tpu.models.gdn_mla.model import served_template
-
-        return served_template(model_cfg)
+    model_package = "fleetx_tpu.models.gdn_mla"
+    serving_module = "fleetx_tpu.serving.gdn_mla"
+    decode_attention = "latent decode attention"
+    unplaced = "none of its caches"
 
     def programs(self, model_cfg, serving, sampling, mesh,
                  pages_per_req: int) -> Programs:
         """See ``Family.programs``."""
-        import jax.numpy as jnp
-        import numpy as np
-
-        from fleetx_tpu.models.gdn_mla.config import LATENT, LINEAR
         from fleetx_tpu.ops import mla_paged_attention as LA
-        from fleetx_tpu.serving import gdn_mla as S
 
-        sc, cfg = serving, model_cfg
-        assert mesh is None or mesh.size == 1, \
-            "this family serves on one chip: its programs place none of " \
-            "its caches on a mesh yet"
-        assert not sc.quantize_decode, \
-            "quantized decode is not written for this family"
-        cache = list(S.init_cache(cfg, num_pages=sc.num_pages,
-                                  page_size=sc.page_size,
-                                  max_batch=sc.max_batch))
-        refused = S.latent_kernel_refusal(cfg, page_size=sc.page_size)
-        active = bool(sc.paged_kernel) and not refused
-        if sc.paged_kernel and refused:
-            from fleetx_tpu.utils.log import logger
-
-            logger.warning("latent decode attention falls back to the "
-                           "gathered view: %s", refused)
-        fns = S.make_step_fns(cfg, prefill_chunk=sc.prefill_chunk,
-                              sampling=sampling,
-                              kernels=bool(sc.paged_kernel),
-                              latent_kernel=active)
-        folds, walk = {}, None
-        if active:
+        S, sc = self._serving(), serving
+        self._one_chip_unquantized(sc, mesh)
+        cache = S.init_cache(model_cfg, num_pages=sc.num_pages,
+                             page_size=sc.page_size, max_batch=sc.max_batch)
+        kernel = self._kernel_serves(
+            sc, S.kernel_refusal(model_cfg, page_size=sc.page_size))
+        fns = S.make_step_fns(
+            model_cfg, prefill_chunk=sc.prefill_chunk, sampling=sampling,
+            kernels=bool(sc.paged_kernel), latent_kernel=kernel)
+        walk = None
+        if kernel:
             g = LA.fold_pages(sc.page_size, cache[0].shape[3], pages_per_req,
-                              cfg.dtype)
-            folds = {"latent": (g, g)}      # a copy a page, one buffer
-            walk = (g * sc.page_size, -(-pages_per_req // g))
+                              model_cfg.dtype)
+            # a copy a page, one buffer
+            walk = KernelWalk((g * sc.page_size, -(-pages_per_req // g)),
+                              {"latent": (g, g)})
+        return Programs(cache=list(cache), fns=fns, kernel=walk,
+                        tokens=jnp.zeros((sc.max_batch,), jnp.int32))
 
-        return Programs(
-            cache=cache, fns=fns,
-            tokens=jnp.zeros((sc.max_batch,), jnp.int32),
-            paged_kernel_active=active, walk_shape=walk, kv_folds=folds,
-            prefill_extra=lambda slot: (np.int32(slot),),
-            record_stats=_expert_counters(cfg),
-            cache_bytes={"latent": int(cache[0].nbytes),
-                         "state": int(cache[1].nbytes + cache[2].nbytes)},
-            describe="%d latent layers paged (%d lanes a token), %d linear "
-                     "layers a state and a convolution tail a slot" % (
-                         cfg.layers_of(LATENT), cache[0].shape[3],
-                         cfg.layers_of(LINEAR)))
+    def cache_bytes(self, cache: list) -> dict:
+        return {"latent": int(cache[0].nbytes),
+                "state": int(cache[1].nbytes + cache[2].nbytes)}
 
 
 class ConvMoEFamily(Family):
@@ -399,84 +345,32 @@ class ConvMoEFamily(Family):
     only, and a convolution tail a slot."""
 
     modules = ("ConvMoEModule",)
-
-    def model_config(self, model: dict, quantization: dict):
-        """See ``Family.model_config``."""
-        from fleetx_tpu.models.conv_moe.config import config_from_dict
-
-        assert not quantization.get("weight_bits") and \
-            not quantization.get("activation_bits"), \
-            "quantized decode is not written for this family"
-        return config_from_dict(dict(model))
-
-    def init_params(self, model_cfg, seed: int):
-        """See ``Family.init_params``."""
-        import jax
-
-        from fleetx_tpu.models.conv_moe.model import init_params
-
-        # made as it is served: the recipe's tree is 10.3 GB in bfloat16
-        return jax.jit(lambda key: init_params(model_cfg, key, served=True))(
-            jax.random.PRNGKey(seed))
-
-    def serving_params(self, params, model_cfg):
-        """See ``Family.serving_params``."""
-        from fleetx_tpu.serving.conv_moe import serving_params
-
-        return serving_params(params, model_cfg)
-
-    def served_template(self, model_cfg):
-        """See ``Family.served_template``."""
-        from fleetx_tpu.models.conv_moe.model import served_template
-
-        return served_template(model_cfg)
+    model_package = "fleetx_tpu.models.conv_moe"
+    serving_module = "fleetx_tpu.serving.conv_moe"
+    unplaced = "neither the key-value pool nor the convolution tails"
 
     def programs(self, model_cfg, serving, sampling, mesh,
                  pages_per_req: int) -> Programs:
         """See ``Family.programs``."""
-        import jax.numpy as jnp
-        import numpy as np
+        S, sc = self._serving(), serving
+        self._one_chip_unquantized(sc, mesh)
+        cache = S.init_cache(model_cfg, num_pages=sc.num_pages,
+                             page_size=sc.page_size, max_batch=sc.max_batch)
+        geometry = dict(page_size=sc.page_size, pages_per_req=pages_per_req)
+        kernel = self._kernel_serves(
+            sc, S.kernel_refusal(model_cfg, **geometry))
+        fns = S.make_step_fns(model_cfg, prefill_chunk=sc.prefill_chunk,
+                              sampling=sampling, paged_kernel=kernel)
+        walk = None
+        if kernel:
+            asked = S.kernel_geometry(model_cfg, **geometry)
+            walk = KernelWalk(PA.page_walk_shape(**asked),
+                              {"full": PA.fold_shape(**asked)})
+        return Programs(cache=list(cache), fns=fns, kernel=walk,
+                        tokens=jnp.zeros((sc.max_batch,), jnp.int32))
 
-        from fleetx_tpu.models.conv_moe.config import CONV, FULL
-        from fleetx_tpu.ops import paged_attention as PA
-        from fleetx_tpu.serving import conv_moe as S
-
-        sc, cfg = serving, model_cfg
-        assert mesh is None or mesh.size == 1, \
-            "this family serves on one chip: its programs place neither " \
-            "the key-value pool nor the convolution tails on a mesh yet"
-        assert not sc.quantize_decode, \
-            "quantized decode is not written for this family"
-        cache = list(S.init_cache(cfg, num_pages=sc.num_pages,
-                                  page_size=sc.page_size,
-                                  max_batch=sc.max_batch))
-        geometry = S.kernel_geometry(cfg, page_size=sc.page_size,
-                                     pages_per_req=pages_per_req)
-        refused = PA.paged_attention_refusal(**geometry)
-        active = bool(sc.paged_kernel) and not refused
-        if sc.paged_kernel and refused:
-            from fleetx_tpu.utils.log import logger
-
-            logger.warning("decode attention falls back to the gathered "
-                           "view: %s", refused)
-        fns = S.make_step_fns(cfg, prefill_chunk=sc.prefill_chunk,
-                              sampling=sampling, paged_kernel=active)
-        walk, folds = None, {}
-        if active:
-            walk = PA.page_walk_shape(**geometry)
-            folds = {"full": PA.fold_shape(**geometry)}
-
-        return Programs(
-            cache=cache, fns=fns,
-            tokens=jnp.zeros((sc.max_batch,), jnp.int32),
-            paged_kernel_active=active, walk_shape=walk, kv_folds=folds,
-            prefill_extra=lambda slot: (np.int32(slot),),
-            record_stats=_expert_counters(cfg),
-            cache_bytes={"state": int(cache[2].nbytes)},
-            describe="%d attention layers paged (%d lanes a token), %d "
-                     "convolution layers a tail of %d rows a slot" % (
-                         cfg.layers_of(FULL), cache[0].shape[3],
-                         cfg.layers_of(CONV), cache[2].shape[1]))
+    def cache_bytes(self, cache: list) -> dict:
+        return {"latent": 0, "state": int(cache[2].nbytes)}
 
 
 _FAMILIES = (GPTFamily(), SWAMoEFamily(), GDNMLAFamily(), ConvMoEFamily())
@@ -537,13 +431,12 @@ def build_engine(cfg, model_cfg, params, *, mesh=None, sampling=None,
                  seed: Optional[int] = None):
     """Recipe config + a parameter tree -> ``ServingEngine``; ``Serving:``
     and ``Generation:`` are read here, once, for every caller."""
-    from fleetx_tpu.serving.decode import SamplingParams
     from fleetx_tpu.serving.engine import ServingConfig, ServingEngine
 
     gen = dict(cfg.get("Generation") or {})
     if sampling is None:
         strategy = gen.get("decode_strategy") or "greedy_search"
-        sampling = SamplingParams(
+        sampling = programs.SamplingParams(
             do_sample=strategy == "sampling",
             temperature=float(gen.get("temperature", 1.0)),
             top_k=int(gen.get("top_k", 0)),

@@ -42,10 +42,10 @@ of each row's ring in the block table's place): logical page *j* is ring
 page ``j mod ring_pages``, an aligned group of pages is one run of the
 buffer, and a fold fetches it in one copy a cache buffer.
 Where the kernel does not admit the geometry (toy widths), the gathered
-view, and the engine's build says so once (``gather_fallbacks``). Prefill:
-one fold for both caches (``_prefill_blocked_attention``: a block of keys as
-long as the chunk at a time, online softmax) — a full layer over the
-request's pages up to the chunk's end (a loop as long as the context); a
+view, and the engine's build says so once (``kernel_refusal``). Prefill:
+one fold for both caches (``programs.prefill_blocked_attention``: a block
+of keys as long as the chunk at a time, online softmax) — a full layer over
+the request's pages up to the chunk's end (a loop as long as the context); a
 window layer over its slot's ring as a block table, from the block that
 holds the first query's oldest key (a loop as long as window + chunk: two
 blocks at a 512-token window under 512-token chunks, nine or ten at 4,096).
@@ -57,13 +57,13 @@ after attention; otherwise both read that state. A layer type whose
 ``rope_parameters`` group is None is not rotated.
 
 **Parameters**: bfloat16, but the norms' scales and the router in
-float32; ``serving_params`` makes that tree once and the programs refuse
+float32; ``programs.serving_params`` makes that tree once
+(``Family.serving_params``, ``serving/registry.py``) and the programs refuse
 any other.
 """
 
 from __future__ import annotations
 
-import math
 from typing import Any
 
 import jax
@@ -73,10 +73,8 @@ from fleetx_tpu.models.swa_moe import model as M
 from fleetx_tpu.models.swa_moe.config import FULL, WINDOW, SWAMoEConfig
 from fleetx_tpu.observability.trace import device_scope
 from fleetx_tpu.ops import paged_attention as PA
-from fleetx_tpu.serving.decode import (SamplingParams, _sample,
-                                       merge_fresh)
-
-_NEG = -1e30
+from fleetx_tpu.serving import programs
+from fleetx_tpu.serving.programs import SamplingParams
 
 
 # -------------------------------------------------------------------- caches
@@ -111,130 +109,43 @@ def init_cache(cfg: SWAMoEConfig, *, num_pages: int, page_size: int,
     return z(full), z(full), z(ring), z(ring)
 
 
-def gather_fallbacks(cfg: SWAMoEConfig, *, page_size: int,
-                     pages_per_req: int) -> list:
-    """The layer kinds whose decode attention ``ops/paged_attention.py``
-    does not admit at this geometry, each with the bound that refused it:
-    ``[(kind, reason), ...]``, empty when the kernel serves every layer.
-    One refused kind puts the whole decode program on the gathered view
-    (one attention path a program), so the engine logs these when it is
-    built."""
-    out = []
-    for kind in cfg.kinds():
-        why = PA.paged_attention_refusal(
-            num_heads=cfg.heads_of(kind), head_dim=cfg.head_dim,
-            page_size=page_size, pages_per_req=pages_per_req,
-            dtype=cfg.dtype, num_kv_heads=cfg.num_key_value_heads)
-        if why:
-            out.append((kind, why))
-    return out
+def describe(cfg: SWAMoEConfig, serving: Any, cache: list) -> str:
+    """The caches of one engine, in words (its start-up line)."""
+    return "%d full layers paged, %d window layers a ring of %d pages a " \
+        "slot" % (cfg.layers_of("full"), cfg.layers_of("window"),
+                  ring_pages(cfg, serving.page_size, serving.prefill_chunk))
 
 
-def paged_kernel_enabled(cfg: SWAMoEConfig, *, page_size: int,
-                         pages_per_req: int) -> bool:
-    """Whether ``ops/paged_attention.py`` admits every layer's geometry."""
-    return not gather_fallbacks(cfg, page_size=page_size,
-                                pages_per_req=pages_per_req)
+def kernel_refusal(cfg: SWAMoEConfig, *, page_size: int,
+                   pages_per_req: int) -> str:
+    """Why ``ops/paged_attention.py`` does not admit the decode attention
+    of this geometry — every refused layer kind with the bound that refused
+    it, ``"<kind> layers: <bound>; …"`` — or "" when the kernel serves all
+    layer. One refused kind puts the whole decode program on the gathered
+    view (one attention path a program), so the engine's build logs it."""
+    why = {kind: PA.paged_attention_refusal(
+        num_heads=cfg.heads_of(kind), head_dim=cfg.head_dim,
+        page_size=page_size, pages_per_req=pages_per_req, dtype=cfg.dtype,
+        num_kv_heads=cfg.num_key_value_heads) for kind in cfg.kinds()}
+    return "; ".join(f"{kind} layers: {bound}"
+                     for kind, bound in why.items() if bound)
 
 
-# ---------------------------------------------------------------- parameters
-def _unserved(params: Any, cfg: Any, served_dtype=M.served_dtype) -> list:
-    flat, _ = jax.tree_util.tree_flatten_with_path(params)
-    return [i for i, (path, leaf) in enumerate(flat)
-            if leaf.dtype != served_dtype(path, cfg)]
-
-
-def serving_params(params: Any, cfg: Any, served_dtype=M.served_dtype) -> Any:
-    """The tree both programs take: every leaf in ``cfg.dtype`` but the
-    norms' scales and the router (float32) — ``served_dtype(path, cfg)``
-    says which (another family passes its own). One jitted cast of the
-    leaves that need it; a leaf already served comes back as the object it
-    was."""
-    todo = _unserved(params, cfg, served_dtype)
-    if not todo:
-        return params
-    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
-    want = [served_dtype(flat[i][0], cfg) for i in todo]
-    cast = jax.jit(lambda xs: [x.astype(d) for x, d in zip(xs, want)])(
-        [flat[i][1] for i in todo])
-    leaves = [leaf for _, leaf in flat]
-    for i, leaf in zip(todo, cast):
-        leaves[i] = leaf
-    return treedef.unflatten(leaves)
-
-
-# ----------------------------------------------------------------- attention
-def _gathered_attention(q, k, v, key_pos, q_pos, window, dtype):
-    """``q`` [B, S, H, hd] against gathered keys ``k``/``v`` [B, K, kv, hd]
-    that hold the tokens at absolute positions ``key_pos`` [B, K] (< 0: no
-    token): softmax over the keys at ``q_pos − window < p ≤ q_pos``."""
-    B, S, H, hd = q.shape
-    kv = k.shape[2]
-    qg = q.reshape(B, S, kv, H // kv, hd)
-    s = jnp.einsum("bskgd,btkd->bkgst", qg, k,
-                   preferred_element_type=jnp.float32) / math.sqrt(hd)
-    kp, qp = key_pos[:, None, :], q_pos[:, :, None]
-    seen = (kp >= 0) & (kp <= qp)
-    if window is not None:
-        seen = seen & (kp > qp - window)
-    s = jnp.where(seen[:, None, None], s, _NEG)
-    p = jax.nn.softmax(s, axis=-1).astype(dtype)
-    o = jnp.einsum("bkgst,btkd->bskgd", p, v,
-                   preferred_element_type=jnp.float32)
-    return o.reshape(B, S, H, hd).astype(dtype)
-
-
-def _prefill_blocked_attention(q, pool_k, pool_v, layer, table, q_pos,
-                               n_keys, key_block: int, dtype, window=None):
-    """One chunk's queries ``q`` [1, C, H, hd] against the pages ``table``
-    [1, P] names in layer ``layer`` of a cache (a request's pages in the
-    full pool, or a slot's ring as a table: logical page *j* → ring page
-    ``j mod ring_pages``), ``key_block`` keys at a time, up to key
-    ``n_keys`` (online softmax in float32). Without a ``window`` the loop
-    is as long as the context; with one it starts at the block that holds
-    the first query's oldest key, ``q_pos[0, 0] − window + 1``, so it is as
-    long as window + chunk whatever the context, and the keys of that
-    block the ring has since overwritten lie before every query's window
-    and are masked."""
-    _, C, H, hd = q.shape
-    ps, width = pool_k.shape[2], pool_k.shape[3]
-    kv = width // hd
-    per = key_block // ps
-    cols = -(-table.shape[1] // per) * per
-    row = jnp.pad(table[0], (0, cols - table.shape[1]))    # null pages
-    qg = q[0].reshape(C, kv, H // kv, hd)
-    qp = q_pos[0][None, None, :, None]
-
-    def body(j, state):
-        m, l, acc = state
-        pages = jax.lax.dynamic_slice(row, (j * per,), (per,))
-        k = pool_k[layer, pages].reshape(key_block, kv, hd)
-        v = pool_v[layer, pages].reshape(key_block, kv, hd)
-        s = jnp.einsum("ckgd,tkd->kgct", qg, k,
-                       preferred_element_type=jnp.float32) / math.sqrt(hd)
-        kp = (j * key_block + jnp.arange(key_block, dtype=jnp.int32)
-              )[None, None, None, :]
-        seen = kp <= qp
-        if window is not None:
-            seen = seen & (kp > qp - window)
-        s = jnp.where(seen, s, _NEG)
-        m_new = jnp.maximum(m, s.max(-1))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[..., None])
-        acc = acc * alpha[..., None] + jnp.einsum(
-            "kgct,tkd->kgcd", p.astype(dtype), v,
-            preferred_element_type=jnp.float32)
-        return m_new, l * alpha + p.sum(-1), acc
-
-    shape = (kv, H // kv, C)
-    first = 0 if window is None else \
-        jnp.maximum(q_pos[0, 0] - (window - 1), 0) // key_block
-    m, l, acc = jax.lax.fori_loop(
-        first, (n_keys + key_block - 1) // key_block, body,
-        (jnp.full(shape, _NEG, jnp.float32), jnp.zeros(shape, jnp.float32),
-         jnp.zeros(shape + (hd,), jnp.float32)))
-    o = acc / jnp.where(l == 0.0, 1.0, l)[..., None]
-    return jnp.transpose(o, (2, 0, 1, 3)).reshape(1, C, H, hd).astype(dtype)
+def kernel_walk(cfg: SWAMoEConfig, *, page_size: int, pages_per_req: int,
+                prefill_chunk: int) -> tuple:
+    """``(walk shape, folds by cache kind)`` of a geometry the kernel
+    admits (``ops/paged_attention.py:page_walk_shape`` / ``fold_shape``):
+    the full layers' fold takes a copy a page through the block table, the
+    window layers' one copy for a ring's run of pages."""
+    ring = ring_pages(cfg, page_size, prefill_chunk)
+    geometry = dict(num_heads=max(cfg.num_attention_heads_per_layer),
+                    head_dim=cfg.head_dim, page_size=page_size,
+                    dtype=cfg.dtype, num_kv_heads=cfg.num_key_value_heads)
+    paged = dict(geometry, pages_per_req=pages_per_req)
+    return PA.page_walk_shape(**paged), {
+        "full": PA.fold_shape(**paged),
+        "window": PA.fold_shape(pages_per_req=ring, ring_pages=ring,
+                                **geometry)}
 
 
 # ------------------------------------------------------------------- forward
@@ -251,11 +162,7 @@ def _forward(params: Any, cfg: SWAMoEConfig, tokens, positions, cache,
     summed over the expert layers, (token, expert) pairs on held experts,
     the rows of the fullest held expert over the mean (worst layer) and the
     passes the held experts' loops took (all layers)."""
-    unserved = _unserved(params, cfg)
-    if unserved:
-        raise TypeError(
-            "the serving programs take the tree serving_params() makes: "
-            f"{len(unserved)} leaves are not in their served dtype")
+    programs.refuse_unserved(params, cfg, M.served_dtype)
     B, S = tokens.shape
     dt = cfg.dtype
     hd, kv = cfg.head_dim, cfg.num_key_value_heads
@@ -340,9 +247,9 @@ def _forward(params: Any, cfg: SWAMoEConfig, tokens, positions, cache,
             vd = full_v[at, block_tables].reshape(B, -1, kv, hd)
             kp = jnp.broadcast_to(jnp.arange(P * ps, dtype=jnp.int32),
                                   (B, P * ps))
-            return _gathered_attention(q, kd, vd, kp, q_pos, None, dt)
+            return programs.gathered_attention(q, kd, vd, kp, q_pos, None, dt)
         if kind_type == FULL:
-            return _prefill_blocked_attention(
+            return programs.prefill_blocked_attention(
                 q, full_k, full_v, at, block_tables, q_pos, last[0] + 1,
                 key_block, dt)
         if decode and paged_kernel:
@@ -352,24 +259,21 @@ def _forward(params: Any, cfg: SWAMoEConfig, tokens, positions, cache,
                                       positions[:, 0], at, window=window,
                                       ring_pages=rp)[:, None]
         if not decode:
-            return _prefill_blocked_attention(
+            return programs.prefill_blocked_attention(
                 q, ring_k, ring_v, at, ring_table, q_pos, last[0] + 1,
                 key_block, dt, window=window)
         kd = ring_k[at, view_pages].reshape(B, -1, kv, hd)
         vd = ring_v[at, view_pages].reshape(B, -1, kv, hd)
-        return _gathered_attention(q, kd, vd, view_pos, q_pos, window, dt)
+        return programs.gathered_attention(q, kd, vd, view_pos, q_pos, window, dt)
 
-    def run(kind, lo, n, cache_lo, carry):
+    def layer_of(kind, lo, cache_lo):
         stack = params[kind]
         kind_type = WINDOW if kind.startswith("window") else FULL
         dense = kind.endswith("dense")
-        per_layer = {k: v for k, v in stack.items() if k != "moe"}
-        if not dense:
-            per_layer["moe"] = {k: v for k, v in stack["moe"].items()
-                                if not k.startswith("experts_")}
+        per_layer = programs.per_layer_leaves(stack)
 
         def layer(i, carry):
-            x, cache, hit, pairs, load, passes = carry
+            x, cache, counters = carry
             lp = jax.tree.map(lambda w: w[i], per_layer)
             with device_scope("norm"):
                 u = M.rms_norm(x, lp["attn_norm"]["scale"], cfg.rms_norm_eps,
@@ -404,99 +308,49 @@ def _forward(params: Any, cfg: SWAMoEConfig, tokens, positions, cache,
                                             lp["moe"]["shared_up"],
                                             lp["moe"]["shared_down"], act)
                     y = y.astype(dt).reshape(B, S, -1)
-                with device_scope("moe.route"):     # the step's counters
-                    hit = hit + (rows > 0).sum().astype(jnp.float32)
-                    pairs = pairs + rows.sum().astype(jnp.int32)
-                    held = rows.astype(jnp.float32)
-                    load = jnp.maximum(
-                        load, held.max() / jnp.maximum(held.mean(), 1e-9))
-                    passes = passes + turns.astype(jnp.int32)
+                counters = programs.count_held(counters, rows, turns)
             with device_scope("mlp"):
-                return x + y, cache, hit, pairs, load, passes
+                return x + y, cache, counters
 
-        with device_scope("stack"):
-            if n == 1:  # a static index: the layer is a view of its stack
-                return layer(lo, carry)
-            return jax.lax.fori_loop(lo, lo + n, layer, carry)
+        return layer
 
-    carry = (x, tuple(cache), jnp.float32(0.0), jnp.int32(0),
-             jnp.float32(0.0), jnp.int32(0))
-    for kind, lo, n, cache_lo in cfg.runs():
-        carry = run(kind, lo, n, cache_lo, carry)
-    x, cache, hit, pairs, load, passes = carry
+    x, cache, stats = programs.walk_runs(cfg, x, cache, layer_of)
     with device_scope("head"):
         x = M.rms_norm(x, params["final_norm"]["scale"], cfg.rms_norm_eps,
                        dt)
-    return x, cache, {"hit": hit, "pairs_held": pairs,
-                      "load_max_over_mean": load, "passes": passes}
-
-
-@device_scope("head")
-def _logits(params: Any, x_last: jax.Array) -> jax.Array:
-    """The (untied) head on the selected positions -> float32 ``[B, V]``."""
-    return jnp.einsum("bh,hv->bv", x_last, params["head"]["kernel"],
-                      preferred_element_type=jnp.float32)
+    return x, cache, stats
 
 
 def make_step_fns(cfg: SWAMoEConfig, *, prefill_chunk: int, page_size: int,
                   sampling: SamplingParams,
                   paged_kernel: bool = False) -> dict:
-    """The two jitted programs of one engine, ``{"prefill", "decode"}``.
-
-    Both take ``(params, full_k, full_v, ring_k, ring_v, ...)``, donate the
-    four cache buffers and return them first. ``prefill`` then takes what
-    GPT's takes and the slot whose ring the request owns; ``decode`` what
-    GPT's takes (the last tokens as the device holds them, the one fresh
-    row, tables, lengths, the base key and the draw count). After the
-    caches come the sampled token(s), the float32
-    logits and, from ``decode``, the step's expert counters (held experts
-    hit, summed over the expert layers; pairs on held experts; the fullest
-    held expert's rows over the mean, worst layer; the passes the held
-    experts' loops took, all layers): they ride to the host with the
-    tokens. Shapes are static (``max_batch`` /
-    ``pages_per_req`` / ``prefill_chunk`` arrive with the arrays), so each
-    jit cache holds one entry for the engine's lifetime."""
+    """The two jitted programs of one engine, ``{"prefill", "decode"}``:
+    ``serving/programs.py:step_fns`` around ``_forward`` over ``(full_k,
+    full_v, ring_k, ring_v)``. ``prefill`` takes the slot whose ring the
+    request owns after the draw count; ``decode`` returns the step's expert
+    counters after its logits (held experts hit, summed over the expert
+    layers; pairs on held experts; the fullest held expert's rows over the
+    mean, worst layer; the passes the held experts' loops took, all
+    layers). ``paged_kernel``: the decode kernel (else the gathered
+    view)."""
     rp = ring_pages(cfg, page_size, prefill_chunk)
 
-    def prefill(params, full_k, full_v, ring_k, ring_v, tokens, block_table,
-                start, n_valid, rng, draw, slot):
-        """One prompt chunk of the request in slot ``slot``: ``tokens``
-        ``[1, C]`` with ``n_valid`` real entries from position ``start``."""
-        idx = jnp.arange(prefill_chunk, dtype=jnp.int32)[None, :]
-        positions = jnp.where(idx < n_valid, start + idx, -1)
+    def prefill(params, cache, tokens, positions, block_table, start,
+                n_valid, slot):
         last = jnp.reshape(start + n_valid - 1, (1,)).astype(jnp.int32)
-        x, cache, _ = _forward(
-            params, cfg, tokens, positions, (full_k, full_v, ring_k, ring_v),
-            block_table, jnp.reshape(slot, (1,)).astype(jnp.int32), last,
-            rp=rp, decode=False, paged_kernel=False,
-            moe_kernel="moe_gmm_prefill")
-        with device_scope("head"):
-            at = jnp.clip(n_valid - 1, 0, prefill_chunk - 1)
-            x_last = jax.lax.dynamic_index_in_dim(x[0], at, axis=0,
-                                                  keepdims=False)[None]
-        logits = _logits(params, x_last)
-        return (*cache, _sample(logits, rng, draw, sampling), logits)
+        return _forward(
+            params, cfg, tokens, positions, cache, block_table,
+            jnp.reshape(slot, (1,)).astype(jnp.int32), last, rp=rp,
+            decode=False, paged_kernel=False, moe_kernel="moe_gmm_prefill")
 
-    def decode(params, full_k, full_v, ring_k, ring_v, tokens, fresh_slot,
-               fresh_tok, block_tables, lens, rng, draw):
-        """One token for every slot: ``tokens`` / ``lens`` ``[max_batch]``
-        (an empty slot carries ``lens < 0`` and a null-page table);
-        ``tokens`` is the previous call's sampled tokens, ``merge_fresh``
-        puts the one request that left prefill this tick in its slot."""
-        tokens = merge_fresh(tokens, fresh_slot, fresh_tok)
-        positions = jnp.where(lens >= 0, lens, -1)[:, None]
+    def decode(params, cache, tokens, positions, block_tables, lens):
         slots = jnp.arange(tokens.shape[0], dtype=jnp.int32)
-        x, cache, stats = _forward(
-            params, cfg, tokens[:, None], positions,
-            (full_k, full_v, ring_k, ring_v), block_tables, slots,
-            jnp.maximum(lens, -1).astype(jnp.int32), rp=rp, decode=True,
-            paged_kernel=paged_kernel, moe_kernel="moe_gmm_decode")
-        logits = _logits(params, x[:, 0])
-        with device_scope("moe.route"):     # rides with the counters
-            stats["rows"] = (lens >= 0).sum().astype(jnp.int32)
-        return (*cache, _sample(logits, rng, draw, sampling), logits,
-                stats)
+        return _forward(
+            params, cfg, tokens[:, None], positions, cache, block_tables,
+            slots, jnp.maximum(lens, -1).astype(jnp.int32), rp=rp,
+            decode=True, paged_kernel=paged_kernel,
+            moe_kernel="moe_gmm_decode")
 
-    donate = (1, 2, 3, 4)
-    return {"prefill": jax.jit(prefill, donate_argnums=donate),
-            "decode": jax.jit(decode, donate_argnums=donate)}
+    return programs.step_fns(prefill, decode, programs.untied_logits, caches=4,
+                             prefill_chunk=prefill_chunk, sampling=sampling,
+                             blocks=True)

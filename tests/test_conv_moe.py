@@ -31,8 +31,7 @@ from fleetx_tpu.models.conv_moe.config import (PUBLISHED_KEYS,  # noqa: E402
                                                config_from_dict)
 from fleetx_tpu.observability import schema  # noqa: E402
 from fleetx_tpu.ops import paged_attention as PA  # noqa: E402
-from fleetx_tpu.serving import conv_moe as S, registry  # noqa: E402
-from fleetx_tpu.serving import swa_moe as windowed  # noqa: E402
+from fleetx_tpu.serving import conv_moe as S, programs, registry  # noqa: E402
 from fleetx_tpu.serving.decode import SamplingParams  # noqa: E402
 from fleetx_tpu.serving.engine import (ServingConfig,  # noqa: E402
                                        ServingEngine)
@@ -220,7 +219,7 @@ def test_paged_decode_at_32_over_8_heads_of_64_is_the_gathered_view(dtype,
     vd = pool_v[1][tables].reshape(B, P * ps, kv, hd)
     kp = jnp.broadcast_to(jnp.arange(P * ps), (B, P * ps))
     with jax.default_matmul_precision("highest"):
-        want = windowed._gathered_attention(
+        want = programs.gathered_attention(
             q[:, None], kd, vd, kp, jnp.maximum(lens, 0)[:, None], None,
             dtype)[:, 0]
     live = np.asarray(lens) >= 0
@@ -456,8 +455,7 @@ def test_the_built_tree_is_5178_m_parameters_served_in_bfloat16():
     assert pool == (2, 32769, 16, 512) and tail == (7, 2, 256, 2048)
     # a token's keys and values, a slot's tails: the issue's 4,096 and 57 KB
     assert 2 * 2 * 512 * 2 == 4096 and 7 * 2 * 2048 * 2 == 57_344
-    assert not PA.paged_attention_refusal(**S.kernel_geometry(
-        model_cfg, page_size=16, pages_per_req=224))
+    assert not S.kernel_refusal(model_cfg, page_size=16, pages_per_req=224)
 
 
 @pytest.mark.parametrize("missing", ["layer_types", "conv_L_cache",

@@ -252,8 +252,8 @@ def test_the_latent_kernel_serves_what_the_gathered_view_serves():
     reference's."""
     sizes = dict(toy.PUBLISHED, kv_lora_rank=128)
     cfg, params, w, sizes = _built(sizes, kv_lora_rank=128)
-    assert not S.latent_kernel_refusal(cfg, page_size=8)
-    assert S.latent_kernel_refusal(cfg, page_size=4)
+    assert not S.kernel_refusal(cfg, page_size=8)
+    assert S.kernel_refusal(cfg, page_size=4)
     prompt = _prompt(21)
     runs = [_serve(cfg, params, prompt, 10, page=8, latent_kernel=on,
                    kernels=on) for on in (False, True)]

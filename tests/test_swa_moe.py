@@ -64,7 +64,7 @@ def _seeded(cfg, seed=0):
         if {getattr(k, "key", None) for k in path} & M.F32_GROUPS:
             return x + 0.1 * jax.random.normal(key, x.shape)
         return x * 5.0
-    return S.serving_params(
+    return registry.family("SWAMoEModule").serving_params(
         jax.tree_util.tree_map_with_path(spread, params), cfg)
 
 
@@ -450,7 +450,7 @@ def test_the_kernel_path_serves_what_the_gather_serves():
     toy = dict(TOY, head_dim=128, num_hidden_layers=5, sliding_window=16,
                rope_parameters=SHIPPED["rope_parameters"])
     cfg = config_from_dict(toy)
-    assert S.paged_kernel_enabled(cfg, page_size=8, pages_per_req=8)
+    assert not S.kernel_refusal(cfg, page_size=8, pages_per_req=8)
     params = _seeded(cfg, 1)
     prompt = np.random.default_rng(2).integers(0, 64, size=21).tolist()
     kw = dict(max_seq=64, page=8, chunk=8)
@@ -1236,8 +1236,8 @@ def test_the_second_members_built_tree_is_3967_m_parameters():
     from fleetx_tpu.ops import paged_attention as PA
 
     per_req = -(-sc["max_seq_len"] // sc["page_size"])
-    assert S.gather_fallbacks(model_cfg, page_size=16,
-                              pages_per_req=per_req) == []
+    assert S.kernel_refusal(model_cfg, page_size=16,
+                            pages_per_req=per_req) == ""
     assert PA.pick_head_block(4, 128, jnp.bfloat16) == 4
     geometry = dict(num_heads=28, head_dim=128, page_size=16,
                     dtype=jnp.bfloat16, num_kv_heads=4)

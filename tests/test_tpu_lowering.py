@@ -162,9 +162,8 @@ def _compile_serving_programs(topo, monkeypatch, model, dist, kernel, *,
 
     from fleetx_tpu.models.gpt.model import (GPTForPretraining,
                                              config_from_dict)
-    from fleetx_tpu.serving.decode import (SamplingParams, make_step_fns,
-                                           paged_kernel_enabled,
-                                           serving_params)
+    from fleetx_tpu.serving.decode import (SamplingParams, kernel_refusal,
+                                           make_step_fns, serving_params)
     from fleetx_tpu.serving.paged_cache import init_pool, pool_shardings
 
     monkeypatch.setattr(ops, "interpret", lambda: False)
@@ -190,9 +189,9 @@ def _compile_serving_programs(topo, monkeypatch, model, dist, kernel, *,
     pool_k, _ = jax.eval_shape(lambda: init_pool(cfg, pages, page))
     pool = arr(pool_k.shape, pool_k.dtype, pool_sh or rep)
     if kernel:
-        assert paged_kernel_enabled(cfg, page_size=page, num_pages=pages,
-                                    pages_per_req=per_req,
-                                    pool_sharding=pool_sh)
+        assert not kernel_refusal(cfg, page_size=page, num_pages=pages,
+                                  pages_per_req=per_req,
+                                  pool_sharding=pool_sh)
     fns = make_step_fns(cfg, max_batch=batch, pages_per_req=per_req,
                         prefill_chunk=chunk, sampling=SamplingParams(),
                         pool_sharding=pool_sh, paged_kernel=kernel)
@@ -548,7 +547,7 @@ def test_swa_moe_programs_keep_both_caches_one_buffer(topo, monkeypatch,
     # caches too large for the compiler to stage in on-chip memory, as the
     # real ones are (abstract shapes: nothing is allocated)
     batch, page, per_req, chunk, pages = 64, 16, 128, 64, 8194
-    assert S.paged_kernel_enabled(cfg, page_size=page, pages_per_req=per_req)
+    assert not S.kernel_refusal(cfg, page_size=page, pages_per_req=per_req)
     params = jax.tree.map(lambda a: arr(a.shape, a.dtype),
                           M.served_template(cfg))
     full, ring = S.cache_shapes(cfg, num_pages=pages, page_size=page,
@@ -628,7 +627,7 @@ def test_gdn_mla_programs_keep_every_cache_one_buffer(topo, monkeypatch):
     # caches too large for the compiler to stage in on-chip memory, as the
     # real ones are (abstract shapes: nothing is allocated)
     batch, page, per_req, chunk, pages = 64, 16, 256, 128, 8194
-    assert not S.latent_kernel_refusal(cfg, page_size=page)
+    assert not S.kernel_refusal(cfg, page_size=page)
     params = jax.tree.map(lambda a: arr(a.shape, a.dtype),
                           M.served_template(cfg))
     pool, state, tail = S.cache_shapes(cfg, num_pages=pages, page_size=page,
@@ -703,8 +702,7 @@ def test_conv_moe_programs_keep_both_caches_one_buffer(topo, monkeypatch):
     # a pool too large for the compiler to stage in on-chip memory, as the
     # real one is (abstract shapes: nothing is allocated)
     batch, page, per_req, chunk, pages = 64, 16, 224, 128, 8193
-    assert not PA.paged_attention_refusal(**S.kernel_geometry(
-        cfg, page_size=page, pages_per_req=per_req))
+    assert not S.kernel_refusal(cfg, page_size=page, pages_per_req=per_req)
     params = jax.tree.map(lambda a: arr(a.shape, a.dtype),
                           M.served_template(cfg))
     pool, tail = S.cache_shapes(cfg, num_pages=pages, page_size=page,

@@ -904,7 +904,7 @@ def test_page_walk_share_gauge_counts_what_the_kernel_folds(small_model,
                       max_seq_len=64, prefill_chunk=8),
         eos_token_id=EOS)
     assert eng.paged_kernel_active
-    span, folds = eng._walk_shape
+    span, folds = eng._programs.kernel.walk_shape
     assert (span, folds) == (16, 4)          # 8 pages of 2 tokens, 32 pages
     gauge = eng.metrics.gauge("serving_page_walk_share")
     eng.submit(list(range(1, 14)), 30, request_id="long")

@@ -30,7 +30,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from fleetx_tpu.ops import paged_attention as PA  # noqa: E402
-from fleetx_tpu.serving import swa_moe as windowed  # noqa: E402
+from fleetx_tpu.serving import programs  # noqa: E402
 
 ROWS, HEADS, KV, HD, PAGE, PER_ROW, LAYERS = 256, 32, 8, 64, 16, 224, 2
 #: largest absolute difference allowed. float32 differs by the order of its
@@ -76,7 +76,7 @@ def gap(dtype) -> float:
         vd = pool_v[layer, view].reshape(ROWS, -1, KV, HD)
         kp = jnp.broadcast_to(jnp.arange(kd.shape[1]), (ROWS, kd.shape[1]))
         with jax.default_matmul_precision("highest"):
-            return windowed._gathered_attention(
+            return programs.gathered_attention(
                 q[:, None], kd, vd, kp, jnp.maximum(lens, 0)[:, None], None,
                 dtype)[:, 0]
 
