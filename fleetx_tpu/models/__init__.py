@@ -60,6 +60,11 @@ def get_registry():
         modules["ConvMoEModule"] = ConvMoEModule
     except ImportError:
         pass
+    try:
+        from fleetx_tpu.models.samba_y.module import SambaYModule
+        modules["SambaYModule"] = SambaYModule
+    except ImportError:
+        pass
     return modules
 
 

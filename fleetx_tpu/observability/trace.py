@@ -204,6 +204,22 @@ DEVICE_SCOPES: dict = {
                 "(triangular system, gdn_chunk kernel), the one-token "
                 "step (gdn_decode), the heads' L2 norms, the state's read "
                 "and write",
+    "attn.cross": "a walk of the one key-value pool that several layers "
+                  "read, as a decode row reads it: the full layer's own in "
+                  "a decode step, every cross layer's in both programs "
+                  "(their products are attn.proj's; the window layers' "
+                  "rings and a prefill chunk's fold are attn.core's)",
+    "ssm.proj": "a selective-scan layer's products: into the scan's input "
+                "and its gate, into the step, B and C, the step's "
+                "projection with its softplus, the gate and the out product",
+    "ssm.conv": "the causal depth-wise convolution over the scan's input "
+                "with its bias and SiLU, and the read and write of the "
+                "slot's last inputs (the tail)",
+    "ssm.core": "the selective scan: a chunk's tokens from the slot's state "
+                "(ssm_chunk), the one-token step over the live slots "
+                "(ssm_decode), the state's read and write",
+    "gmu": "a gated memory unit: the gate's product, its SiLU times the "
+           "last scan layer's output, the out product",
     "conv.proj": "a short-convolution layer's two products: into the two "
                  "gates and the convolution's input, and out",
     "conv.mix": "the gates' products with the input and the output, the "

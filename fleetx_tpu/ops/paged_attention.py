@@ -645,7 +645,8 @@ def _decode_kernel(tables_ref, lens_ref, layer_ref, q_ref, *refs,
 def _paged_call(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                 tables: jax.Array, lens: jax.Array, layer: jax.Array,
                 window: Optional[int] = None,
-                ring_pages: Optional[int] = None):
+                ring_pages: Optional[int] = None,
+                scale: Optional[float] = None):
     """Raw kernel invocation on one device's shard.
 
     ``q`` ``[B, nh, hd]``, pools ``[layers, pages, page_size, kv·hd]``
@@ -655,7 +656,8 @@ def _paged_call(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     inactive row), ``layer`` an int32 scalar: which layer of the pool the
     K/V tiles are fetched from; ``window``: a query sees the keys at the
     last ``window`` positions only; ``ring_pages``: ``tables`` is ``[B]``,
-    the first page of each row's ring of that many pages. Returns the
+    the first page of each row's ring of that many pages; ``scale``: what
+    the scores are multiplied by (None: ``1 / sqrt(hd)``). Returns the
     UNnormalized ``(acc [B,nh,hd] f32, m [B,nh], l [B,nh])`` triple so
     sharded callers can run the cross-shard softmax combine before
     dividing.
@@ -734,8 +736,9 @@ def _paged_call(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     )
     acc, m, l = pl.pallas_call(
         functools.partial(_decode_kernel, pages=g, page_size=ps,
-                          head_dim=hd, scale=1.0 / math.sqrt(hd),
-                          group=group, window=window,
+                          head_dim=hd,
+                          scale=1.0 / math.sqrt(hd) if scale is None
+                          else float(scale), group=group, window=window,
                           ring_pages=ring_pages),
         grid_spec=grid_spec,
         out_shape=[
@@ -772,7 +775,8 @@ def _normalize(acc: jax.Array, l: jax.Array, dtype) -> jax.Array:
 def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                     block_tables: jax.Array, lens: jax.Array,
                     layer: jax.Array, window: Optional[int] = None,
-                    ring_pages: Optional[int] = None) -> jax.Array:
+                    ring_pages: Optional[int] = None,
+                    scale: Optional[float] = None) -> jax.Array:
     """Single-shard paged decode attention over layer ``layer`` of the
     pool.
 
@@ -796,6 +800,13 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     masked, so it has to be finite (a ring is zeros until a program
     writes it). Through a block table a page that does not count is never
     read.
+
+    ``scale`` (None: ``1 / sqrt(head_dim)``): what the scores are
+    multiplied by, for a caller whose ``head_dim`` here is not the width its
+    scores are scaled by — ``serving/samba_y.py`` scores 64-wide heads
+    through 128-lane key-value pairs, each query zero in the half it does
+    not score. The output is in ``q``'s dtype (float32 queries, which the
+    products take in the pool's dtype, get the float32 quotient back).
     """
     if ring_pages is None:
         block_tables = _localize_tables(block_tables, 0, pool_k.shape[1])
@@ -803,7 +814,7 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
         assert window is not None and block_tables.ndim == 1 and \
             window <= ring_pages * pool_k.shape[2], "a ring holds its window"
     acc, _, l = _paged_call(q, pool_k, pool_v, block_tables.astype(jnp.int32),
-                            lens, layer, window, ring_pages)
+                            lens, layer, window, ring_pages, scale)
     return _normalize(acc, l, q.dtype)
 
 

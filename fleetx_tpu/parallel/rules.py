@@ -11,7 +11,7 @@ stage shards which term, and the serving KV pool hand-wired its own
 hardware. Here the whole mapping is *data*:
 
 - ``PARTITION_RULES``: per model family (``gpt``, ``gpt_moe``,
-  ``gpt_lora``, ``vision``, ``ernie``, ``imagen``, ``mla_moe``, ``swa_moe``, ``gdn_mla``, ``conv_moe``, plus the serving KV
+  ``gpt_lora``, ``vision``, ``ernie``, ``imagen``, ``mla_moe``, ``swa_moe``, ``gdn_mla``, ``conv_moe``, ``samba_y``, plus the serving KV
   pool as ``serving_kv``), an
   ORDERED tuple of ``(regex, logical-axes template)`` rules matched against
   slash-joined parameter-tree paths, first match wins — the
@@ -382,6 +382,28 @@ PARTITION_RULES: dict[str, tuple] = {
         (r"embed/tokens$", ("vocab", "embed")),
         (r"(^|/)\w*norm/scale$", ("norm",)),
     ),
+    # the decoder-hybrid-decoder family (models/samba_y; served on one
+    # chip): the scan's channels, the memory units' and the attention's
+    # heads over the Megatron axis (a scan channel needs no neighbour: its
+    # taps, step, decay and skip go with it), the small step and B / C
+    # projections with their channel side, the lambda vectors and the
+    # sub-norm replicated; no head leaf (tied to the embedding)
+    "samba_y": (
+        (r"ssm/in$", ("embed", "heads")),
+        (r"ssm/(taps|dt|A_log)$", (None, "heads")),
+        (r"ssm/(conv_bias|dt_bias|D)$", ("heads",)),
+        (r"ssm/x$", ("heads", None)),
+        (r"(ssm|gmu)/out$", ("heads", "embed")),
+        (r"gmu/in$", ("embed", "heads")),
+        (r"attn/qkv$", ("embed", "heads")),
+        (r"attn/qkv_bias$", ("heads",)),
+        (r"attn/out$", ("heads", "embed")),
+        (r"attn/(out_bias|lambda_[qk][12]|subln)$", (None,)),
+        (r"mlp/gate_up$", ("embed", "mlp")),
+        (r"mlp/down$", ("mlp", "embed")),
+        (r"embed/tokens$", ("vocab", "embed")),
+        (r"(^|/)\w*norm\d?/(scale|bias)$", ("norm",)),
+    ),
     # the serving KV page pool (serving/paged_cache.py): pages over the
     # ZeRO axis (capacity scales with fsdp), heads over the Megatron axis
     # (heads and head_dim share the pool's minor dim, heads major)
@@ -403,6 +425,7 @@ STACK_MARKERS: dict[str, str] = {
     "swa_moe": r"(^|/)(full|window)_(dense|moe)/",
     "gdn_mla": r"(^|/)(linear|latent)_(dense|moe)/",
     "conv_moe": r"(^|/)(conv|full)_(dense|moe)/",
+    "samba_y": r"(^|/)(scan|window|full|gmu|cross)/",
 }
 
 #: families whose fully-replicated leaves are accepted at ANY size by the

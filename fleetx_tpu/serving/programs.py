@@ -1,13 +1,16 @@
 """What the served families' device programs share, owned by none of them.
 
-``serving/decode.py`` (GPT), ``serving/swa_moe.py``, ``serving/gdn_mla.py``
-and ``serving/conv_moe.py`` each hold ONE family's caches and forward; what
-more than one of them needs is here, under public names, so that no family
-imports another: the tail of every program (``SamplingParams``, ``sample``,
-``merge_fresh``: all four); and for the three expert families the one-jit
-cast (``serving_params``, told the family's ``served_dtype``; GPT's rule
-reads the leaf it is given and stays in ``decode.py``), attention over
-gathered keys and a chunk's fold over a cache's pages, the walk over a
+``serving/decode.py`` (GPT), ``serving/swa_moe.py``, ``serving/gdn_mla.py``,
+``serving/conv_moe.py`` and ``serving/samba_y.py`` each hold ONE family's
+caches and forward; what more than one of them needs is here, under public
+names, so that no family imports another: the tail of every program
+(``SamplingParams``, ``sample``, ``merge_fresh``: all five); and for the
+four families after GPT the one-jit cast (``serving_params``, told the
+family's ``served_dtype``; GPT's rule reads the leaf it is given and stays
+in ``decode.py``), attention over gathered keys and a chunk's fold over a
+cache's pages, a slot's ring of window keys (``ring_pages``, where a row is
+written — ``ring_targets`` — and how a ring is read: ``ring_table`` folded
+as a block table, ``ring_view`` gathered; two families), the walk over a
 config's layer runs with the step's expert counters, and ``step_fns``: the
 ``prefill`` / ``decode`` pair around a family's forward. GPT keeps its own
 pair (``decode.py:make_step_fns``): its forward returns no counters and its
@@ -20,6 +23,13 @@ start, n_valid, rng, draw, *extra)`` and ``decode(params, *cache, tokens
 draw)``; both donate the cache buffers and return them first, then the
 sampled token(s) and the float32 logits, and ``decode`` last the step's
 counters. A change to that contract is made here, once.
+
+**What a prefill forward hands back** (``step_fns``): the chunk's hidden
+rows, of which the shell picks the last valid one — or, told so
+(``last_row=True``), that ONE row, already picked: a family whose upper
+layers keep no cache of their own runs them on the chunk's last valid row
+alone (no earlier token's pass through them is ever read again) and has no
+chunk of hidden states to return.
 """
 
 from __future__ import annotations
@@ -116,15 +126,20 @@ def serving_params(params: Any, cfg: Any, served_dtype: Callable) -> Any:
 
 
 # ----------------------------------------------------------------- attention
-def gathered_attention(q, k, v, key_pos, q_pos, window, dtype):
+def gathered_attention(q, k, v, key_pos, q_pos, window, dtype, scale=None,
+                       out_dtype=None):
     """``q`` [B, S, H, hd] against gathered keys ``k``/``v`` [B, K, kv, hd]
     that hold the tokens at absolute positions ``key_pos`` [B, K] (< 0: no
-    token): softmax over the keys at ``q_pos − window < p ≤ q_pos``."""
+    token): softmax over the keys at ``q_pos − window < p ≤ q_pos`` of the
+    scores times ``scale`` (None: ``1 / sqrt(hd)``); the probabilities
+    enter the value product in ``dtype``, the result comes out in
+    ``out_dtype`` (None: ``dtype``)."""
     B, S, H, hd = q.shape
     kv = k.shape[2]
     qg = q.reshape(B, S, kv, H // kv, hd)
     s = jnp.einsum("bskgd,btkd->bkgst", qg, k,
-                   preferred_element_type=jnp.float32) / math.sqrt(hd)
+                   preferred_element_type=jnp.float32)
+    s = s / math.sqrt(hd) if scale is None else s * scale
     kp, qp = key_pos[:, None, :], q_pos[:, :, None]
     seen = (kp >= 0) & (kp <= qp)
     if window is not None:
@@ -133,11 +148,12 @@ def gathered_attention(q, k, v, key_pos, q_pos, window, dtype):
     p = jax.nn.softmax(s, axis=-1).astype(dtype)
     o = jnp.einsum("bkgst,btkd->bskgd", p, v,
                    preferred_element_type=jnp.float32)
-    return o.reshape(B, S, H, hd).astype(dtype)
+    return o.reshape(B, S, H, hd).astype(out_dtype or dtype)
 
 
 def prefill_blocked_attention(q, pool_k, pool_v, layer, table, q_pos,
-                              n_keys, key_block: int, dtype, window=None):
+                              n_keys, key_block: int, dtype, window=None,
+                              scale=None, out_dtype=None):
     """One chunk's queries ``q`` [1, C, H, hd] against the pages ``table``
     [1, P] names in layer ``layer`` of a cache (a request's pages in the
     full pool, or a slot's ring as a table: logical page *j* → ring page
@@ -147,7 +163,7 @@ def prefill_blocked_attention(q, pool_k, pool_v, layer, table, q_pos,
     the first query's oldest key, ``q_pos[0, 0] − window + 1``, so it is as
     long as window + chunk whatever the context, and the keys of that
     block the ring has since overwritten lie before every query's window
-    and are masked."""
+    and are masked. ``scale`` / ``out_dtype``: as ``gathered_attention``."""
     _, C, H, hd = q.shape
     ps, width = pool_k.shape[2], pool_k.shape[3]
     kv = width // hd
@@ -163,7 +179,8 @@ def prefill_blocked_attention(q, pool_k, pool_v, layer, table, q_pos,
         k = pool_k[layer, pages].reshape(key_block, kv, hd)
         v = pool_v[layer, pages].reshape(key_block, kv, hd)
         s = jnp.einsum("ckgd,tkd->kgct", qg, k,
-                       preferred_element_type=jnp.float32) / math.sqrt(hd)
+                       preferred_element_type=jnp.float32)
+        s = s / math.sqrt(hd) if scale is None else s * scale
         kp = (j * key_block + jnp.arange(key_block, dtype=jnp.int32)
               )[None, None, None, :]
         seen = kp <= qp
@@ -186,7 +203,8 @@ def prefill_blocked_attention(q, pool_k, pool_v, layer, table, q_pos,
         (jnp.full(shape, _NEG, jnp.float32), jnp.zeros(shape, jnp.float32),
          jnp.zeros(shape + (hd,), jnp.float32)))
     o = acc / jnp.where(l == 0.0, 1.0, l)[..., None]
-    return jnp.transpose(o, (2, 0, 1, 3)).reshape(1, C, H, hd).astype(dtype)
+    return jnp.transpose(o, (2, 0, 1, 3)).reshape(1, C, H, hd).astype(
+        out_dtype or dtype)
 
 
 @device_scope("attn.cache")
@@ -206,6 +224,59 @@ def row_targets(positions: jax.Array, block_tables: jax.Array,
     pages = jnp.where(valid, jnp.take_along_axis(
         tables, page_slot[:, None], axis=1)[:, 0], 0)
     return valid, q_pos, offs, pages
+
+
+# ------------------------------------------------------------ a slot's ring
+def ring_pages(cfg: Any, page_size: int, prefill_chunk: int) -> int:
+    """Pages of one slot's ring: the window (``cfg.sliding_window``) plus
+    one prefill chunk. A window layer's buffer is ``[layers, 1 + slots ·
+    ring_pages, page_size, lanes]``: page 0 the null page, slot *s*'s ring
+    the ``ring_pages`` pages from ``1 + s · ring_pages``, the token at
+    position *p* in ring page ``(p // page_size) mod ring_pages``. A
+    chunk's keys are written before its queries read, and the
+    ``prefill_chunk`` tokens they overwrite are older than the window of
+    every query in the chunk: that is what the extra chunk is for."""
+    return -(-(cfg.sliding_window + int(prefill_chunk)) // int(page_size))
+
+
+@device_scope("attn.cache")
+def ring_targets(positions: jax.Array, slots: jax.Array, rp: int,
+                 page_size: int) -> tuple:
+    """Where a window layer WRITES: ``positions`` [B, S] (< 0: no token,
+    the null page) of rows whose rings are ``slots`` [B] -> ``(ring_first
+    [B]`` — the first page of each row's ring —``, ring_at [B, S])``; the
+    offset inside the page is the paged pool's (``positions mod
+    page_size``)."""
+    ring_first = 1 + slots * rp
+    ring_at = jnp.where(
+        positions >= 0,
+        ring_first[:, None] + (jnp.maximum(positions, 0) // page_size) % rp,
+        0)
+    return ring_first, ring_at
+
+
+@device_scope("attn.cache")
+def ring_table(ring_first: jax.Array, pages_per_req: int, rp: int
+               ) -> jax.Array:
+    """A ring FOLDED as a block table (``prefill_blocked_attention``): [B,
+    pages_per_req], logical page *j* -> ring page ``j mod rp``."""
+    return ring_first[:, None] + \
+        jnp.arange(pages_per_req, dtype=jnp.int32)[None, :] % rp
+
+
+@device_scope("attn.cache")
+def ring_view(ring_first: jax.Array, last: jax.Array, rp: int,
+              page_size: int) -> tuple:
+    """A ring GATHERED (``gathered_attention``): the ring's ``rp`` logical
+    pages that end at the page of ``last`` [B] (the last position each row
+    holds), in order -> ``(pages [B, rp], the position of each key in them
+    [B, rp · page_size])``; a position < 0 holds no token."""
+    B = last.shape[0]
+    view_first = (jnp.maximum(last, 0) // page_size - (rp - 1))[:, None] \
+        + jnp.arange(rp, dtype=jnp.int32)[None, :]              # [B, rp]
+    view_pos = (view_first[:, :, None] * page_size + jnp.arange(
+        page_size, dtype=jnp.int32)[None, None, :]).reshape(B, rp * page_size)
+    return ring_first[:, None] + view_first % rp, view_pos
 
 
 @device_scope("head")
@@ -265,9 +336,16 @@ def walk_runs(cfg: Any, x: jax.Array, cache: tuple,
 
 
 # ----------------------------------------------------------------- the shell
+def last_valid_row(rows: jax.Array, n_valid: jax.Array) -> jax.Array:
+    """Row ``n_valid − 1`` of a chunk's ``rows`` [C, ...] -> [1, ...]."""
+    at = jnp.clip(n_valid - 1, 0, rows.shape[0] - 1)
+    return jax.lax.dynamic_index_in_dim(rows, at, axis=0, keepdims=True)
+
+
 def step_fns(prefill_forward: Callable, decode_forward: Callable,
              logits_of: Callable, *, caches: int, prefill_chunk: int,
-             sampling: SamplingParams, blocks: bool = False) -> dict:
+             sampling: SamplingParams, blocks: bool = False,
+             last_row: bool = False) -> dict:
     """The two jitted programs of one engine, ``{"prefill", "decode"}``,
     around a family's forward (the module docstring has their contract).
 
@@ -279,7 +357,9 @@ def step_fns(prefill_forward: Callable, decode_forward: Callable,
     ``blocks``: the forward takes positions, and returns hidden states, as
     ``[B, S]`` blocks (``[1, C]`` a chunk, ``[B, 1]`` a decode step) where
     the others take a row a token (``[C]``, ``[B]``). ``positions < 0``: no
-    token (a ragged chunk's tail; an empty slot, ``lens < 0``). ``decode``
+    token (a ragged chunk's tail; an empty slot, ``lens < 0``).
+    ``last_row``: ``prefill_forward`` returns the chunk's last valid row
+    ``[1, h]`` alone, not the chunk, and the shell picks nothing. ``decode``
     adds the live rows to ``stats`` (``walk_runs``'s), which ride to the
     host with the tokens. Shapes are static (``max_batch`` /
     ``pages_per_req`` arrive with the arrays), so each jit cache holds one
@@ -296,11 +376,10 @@ def step_fns(prefill_forward: Callable, decode_forward: Callable,
         positions = jnp.where(idx < n_valid, start + idx, -1)
         x, cache, _ = prefill_forward(params, cache, tokens, positions,
                                       block_table, start, n_valid, *extra)
-        with device_scope("head"):
-            at = jnp.clip(n_valid - 1, 0, prefill_chunk - 1)
-            x_last = jax.lax.dynamic_index_in_dim(
-                x[0] if blocks else x, at, axis=0, keepdims=False)[None]
-        logits = logits_of(params, x_last)
+        if not last_row:
+            with device_scope("head"):
+                x = last_valid_row(x[0] if blocks else x, n_valid)
+        logits = logits_of(params, x)
         return (*cache, sample(logits, rng, draw, sampling), logits)
 
     def decode(params, *args):

@@ -373,7 +373,62 @@ class ConvMoEFamily(Family):
         return {"latent": 0, "state": int(cache[2].nbytes)}
 
 
-_FAMILIES = (GPTFamily(), SWAMoEFamily(), GDNMLAFamily(), ConvMoEFamily())
+class SambaYFamily(Family):
+    """Selective-scan layers alternating with differential attention over a
+    window, one full attention layer whose keys and values every later
+    attention layer reads, gated memory units (``models/samba_y``,
+    ``serving/samba_y.py``; ``docs/samba_y.md``): one paged pool that one
+    layer writes and eight read, a ring a slot for the window layers, a
+    float32 state and a convolution tail a slot for the scan layers. No
+    experts."""
+
+    modules = ("SambaYModule",)
+    model_package = "fleetx_tpu.models.samba_y"
+    serving_module = "fleetx_tpu.serving.samba_y"
+    decode_attention = "decode attention and the selective scan"
+    unplaced = "none of its four caches"
+
+    def programs(self, model_cfg, serving, sampling, mesh,
+                 pages_per_req: int) -> Programs:
+        """See ``Family.programs``."""
+        S, sc = self._serving(), serving
+        self._one_chip_unquantized(sc, mesh)
+        geometry = dict(page_size=sc.page_size, pages_per_req=pages_per_req,
+                        prefill_chunk=sc.prefill_chunk)
+        cache = S.init_cache(
+            model_cfg, num_pages=sc.num_pages, page_size=sc.page_size,
+            max_batch=sc.max_batch, prefill_chunk=sc.prefill_chunk)
+        kernels = self._kernel_serves(
+            sc, S.kernel_refusal(model_cfg, **geometry))
+        fns = S.make_step_fns(
+            model_cfg, prefill_chunk=sc.prefill_chunk, page_size=sc.page_size,
+            sampling=sampling, kernels=kernels)
+        walk = KernelWalk(*S.kernel_walk(model_cfg, **geometry)) \
+            if kernels else None
+        return Programs(cache=list(cache), fns=fns, kernel=walk,
+                        tokens=jnp.zeros((sc.max_batch,), jnp.int32))
+
+    def cache_bytes(self, cache: list) -> dict:
+        return {"latent": 0, "state": int(cache[4].nbytes + cache[5].nbytes)}
+
+    def kv_tokens(self, model_cfg, lens) -> tuple:
+        """See ``Family.kv_tokens``: the one paged layer holds every token,
+        a ring the window's at most."""
+        live = lens[lens >= 0]
+        return int(live.sum()), int(
+            np.minimum(live, model_cfg.sliding_window).sum())
+
+    def stats_recorder(self, model_cfg):
+        """No experts: nothing of what ``decode`` returns after its logits
+        is recorded."""
+        return lambda metrics, counters: None
+
+    def stats_snapshot(self, metrics) -> dict:
+        return {}
+
+
+_FAMILIES = (GPTFamily(), SWAMoEFamily(), GDNMLAFamily(), ConvMoEFamily(),
+             SambaYFamily())
 
 
 def families() -> dict:

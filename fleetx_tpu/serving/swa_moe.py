@@ -74,15 +74,10 @@ from fleetx_tpu.models.swa_moe.config import FULL, WINDOW, SWAMoEConfig
 from fleetx_tpu.observability.trace import device_scope
 from fleetx_tpu.ops import paged_attention as PA
 from fleetx_tpu.serving import programs
-from fleetx_tpu.serving.programs import SamplingParams
+from fleetx_tpu.serving.programs import SamplingParams, ring_pages
 
 
 # -------------------------------------------------------------------- caches
-def ring_pages(cfg: SWAMoEConfig, page_size: int, prefill_chunk: int) -> int:
-    """Pages of one slot's ring: the window plus one prefill chunk."""
-    return -(-(cfg.sliding_window + int(prefill_chunk)) // int(page_size))
-
-
 def cache_shapes(cfg: SWAMoEConfig, *, num_pages: int, page_size: int,
                  max_batch: int, prefill_chunk: int) -> tuple:
     """``(full pool shape, ring shape)``; each exists twice, K and V."""
@@ -185,28 +180,18 @@ def _forward(params: Any, cfg: SWAMoEConfig, tokens, positions, cache,
         page_slot = jnp.clip(positions // ps, 0, P - 1)
         full_pages = jnp.where(
             valid, jnp.take_along_axis(block_tables, page_slot, axis=1), 0)
-        ring_first = 1 + slots * rp                               # [B]
-        ring_at = jnp.where(
-            valid, ring_first[:, None] + (q_pos // ps) % rp, 0)
         valid_tok = valid.reshape(B * S)
+    ring_first, ring_at = programs.ring_targets(positions, slots, rp, ps)
     with device_scope("attn.proj"):
         tables = {t: M.rotary_tables(cfg, t, q_pos) for t in (FULL, WINDOW)}
     act = M.activation(cfg)
     router_first = cfg.router_input == "pre_attention"
-    with device_scope("attn.cache"):    # how a window layer reads its ring
-        if not decode:
-            # folded as a block table: logical page j -> ring page j mod rp
-            ring_table = ring_first[:, None] + \
-                jnp.arange(P, dtype=jnp.int32)[None, :] % rp
-        elif not paged_kernel:
-            # the gathered view: the ring's ``rp`` logical pages that end at
-            # the page of ``last``, in order, and the position of each key
-            # in them
-            view_first = (jnp.maximum(last, 0) // ps - (rp - 1))[:, None] \
-                + jnp.arange(rp, dtype=jnp.int32)[None, :]        # [B, rp]
-            view_pages = ring_first[:, None] + view_first % rp
-            view_pos = (view_first[:, :, None] * ps + jnp.arange(
-                ps, dtype=jnp.int32)[None, None, :]).reshape(B, rp * ps)
+    # how a window layer reads its ring: folded as a block table (a
+    # chunk), or the gathered view (a decode step without the kernel)
+    if not decode:
+        ring_table = programs.ring_table(ring_first, P, rp)
+    elif not paged_kernel:
+        view_pages, view_pos = programs.ring_view(ring_first, last, rp, ps)
 
     def attention(kind_type, u, lp, cache, at):
         with device_scope("attn.proj"):

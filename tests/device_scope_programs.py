@@ -259,9 +259,37 @@ def lfm2_serve(batch=3, page=4, chunk=8, max_seq=64) -> list:
           _arr((batch, per_req)), _arr((batch,)), rng, _arr((), U32)))]
 
 
+def phi4flash_serve(batch=3, page=4, chunk=8, max_seq=64) -> list:
+    """The decoder-hybrid-decoder family (selective scan, differential
+    attention, one shared key-value layer) at toy widths."""
+    import samba_y_toy
+
+    from fleetx_tpu.models.samba_y import model as M
+    from fleetx_tpu.models.samba_y.config import config_from_dict
+    from fleetx_tpu.serving import samba_y as S
+    from fleetx_tpu.serving.decode import SamplingParams
+
+    cfg = config_from_dict(samba_y_toy.model_section())
+    per_req = max_seq // page
+    params = M.served_template(cfg)
+    cache = _abstract(jax.eval_shape(lambda: S.init_cache(
+        cfg, num_pages=1 + batch * per_req, page_size=page,
+        max_batch=batch, prefill_chunk=chunk)))
+    fns = S.make_step_fns(cfg, prefill_chunk=chunk, page_size=page,
+                          sampling=SamplingParams())
+    rng = _arr((2,), U32)
+    return [
+        ("serving prefill", fns["prefill"],
+         (params, *cache, _arr((1, chunk)), _arr((1, per_req)), _arr(()),
+          _arr(()), rng, _arr((), U32), _arr(()))),
+        ("serving decode", fns["decode"],
+         (params, *cache, _arr((batch,)), _arr(()), _arr((1,)),
+          _arr((batch, per_req)), _arr((batch,)), rng, _arr((), U32)))]
+
+
 #: family -> the programs' builder; a train builder takes the devices
 TRAIN = {"gpt": gpt_train, "gpt_zero2": gpt_train_zero2,
          "joyai": joyai_train}
 SERVE = {"gpt": gpt_serve, "laguna": laguna_serve,
          "smallthinker": smallthinker_serve, "gigachat": gigachat_serve,
-         "lfm2": lfm2_serve}
+         "lfm2": lfm2_serve, "phi4flash": phi4flash_serve}

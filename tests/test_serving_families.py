@@ -20,6 +20,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import conv_moe_toy  # noqa: E402
 import device_scope_programs as toys  # noqa: E402
 import gdn_mla_toy  # noqa: E402
+import samba_y_toy  # noqa: E402
 
 from fleetx_tpu.observability.metrics import get_registry  # noqa: E402
 from fleetx_tpu.serving import registry  # noqa: E402
@@ -51,6 +52,11 @@ GEOMETRIES = {
                       conv_moe_toy.model_section(), 8,
                       "head_dim 32 is neither whole 128-lane tiles nor "
                       "half of one"),
+    # (a key-value PAIR of two 8-wide heads is what the kernel is asked)
+    "SambaYFamily": (samba_y_toy.model_section(),
+                     samba_y_toy.model_section(**samba_y_toy.KERNEL_WIDTHS),
+                     8, "attention: head_dim 16 is neither whole 128-lane "
+                     "tiles nor half of one"),
 }
 FAMILIES = pytest.mark.parametrize(
     "family", registry._FAMILIES, ids=lambda f: type(f).__name__)
