@@ -11,8 +11,14 @@ here without its exchange. Nothing stands in for the other chips.
 How the held part is computed:
 
 - every (token, expert) pair whose expert is held here is a *row*; rows
-  are sorted by expert, each expert's run padded to whole tiles of
-  ``moe_tile_rows`` so that a tile belongs to one expert;
+  lie in expert order, each expert's run padded to whole tiles of
+  ``moe_tile_rows`` so that a tile belongs to one expert. ``plan_rows``
+  places them by counting (the order is a stable sort's, yet nothing is
+  sorted or searched): a running count down the pairs' one-hot over the
+  held experts gives each pair its place in its expert's run, two
+  cumulative sums over ``[held]`` give the runs' padded starts, a compare
+  of the tiles' first rows with the runs' ends gives each tile its
+  expert, and one scatter of the pairs' rows tells the rows their pairs;
 - rows are multiplied in passes of ``moe_chunk_rows``: gather the rows'
   tokens, one grouped product into the gate and up widths, one back
   (``ops/grouped_matmul.py``: a tile's weights are read by its expert's
@@ -92,46 +98,67 @@ def buffer_rows(n_pairs_max: int, held: int, tile: int, chunk: int) -> int:
     return -(-rows // chunk) * chunk
 
 
+def _seen_so_far(onehot: jax.Array) -> jax.Array:
+    """``onehot`` [P, held] -> how many of the rows up to and including each
+    have each column set, int32: a cumulative sum down P, made in two
+    levels. Inside blocks of 128 rows it is one product with a triangle of
+    ones (operands of 0 and 1, float32 sums: exact below 2^24 rows), across
+    blocks a cumulative sum of the blocks' totals. The chip runs
+    ``jnp.cumsum`` down thousands of rows as a 128-wide window a lane: at
+    1,024 x 64 the whole plan is 138 us with it and 35 with this (PERF.md
+    section 6, PR 49)."""
+    rows, held = onehot.shape
+    blocks = jnp.pad(onehot, ((0, -rows % 128), (0, 0))).astype(
+        jnp.bfloat16).reshape(-1, 128, held)
+    at = jnp.arange(128, dtype=jnp.int32)
+    within = jnp.einsum("ij,bjh->bih",
+                        (at[:, None] >= at[None]).astype(jnp.bfloat16),
+                        blocks, preferred_element_type=jnp.float32)
+    before = jnp.cumsum(within[:, -1], axis=0) - within[:, -1]
+    return (within + before[:, None]).reshape(-1, held)[:rows].astype(
+        jnp.int32)
+
+
 @device_scope("moe.route")
 def plan_rows(ids: jax.Array, first: int, held: int, tile: int,
               chunk: int) -> dict:
-    """Where each held (token, expert) pair goes in the sorted buffer.
+    """Where each held (token, expert) pair goes in the sorted buffer: rows
+    in the order a stable sort of the pairs by local expert gives, each
+    expert's run padded to whole tiles.
 
     ``row_pair`` [R]: the flat pair index of each row (0 where the row is
     padding, ``row_valid`` false); ``tile_expert`` [R / tile]: the local
     expert of each tile; ``pair_row`` [P]: the row of each pair (0 where
     the pair's expert is not held, ``pair_held`` false); ``n_tiles`` and
     ``n_passes``: the tiles and passes that hold rows.
+
+    Nothing is sorted or searched: a pair's row is its expert's padded
+    start plus the pairs of that expert before it (``_seen_so_far`` down
+    the one-hot), a tile's expert is the number of padded runs that end at
+    or before it, and the rows learn their pairs from one scatter of the
+    pairs' rows. No loop, and no lookup in a ``[held]`` table.
     """
     n, k = ids.shape
     pairs = n * k
     rows = buffer_rows(n * min(k, held), held, tile, chunk)
     local = ids.reshape(pairs) - first
     is_held = (local >= 0) & (local < held)
-    key = jnp.where(is_held, local, held).astype(jnp.int32)
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    onehot = key[:, None] == jnp.arange(held, dtype=jnp.int32)[None]
-    count = onehot.sum(0).astype(jnp.int32)
-    start = jnp.cumsum(count) - count
+    onehot = local[:, None] == jnp.arange(held, dtype=jnp.int32)[None]
+    seen = _seen_so_far(onehot)
+    count = seen[-1]
     padded = -(-count // tile) * tile
     a_end = jnp.cumsum(padded)
-    a_start = a_end - padded
-    # rows -> pairs
-    r = jnp.arange(rows, dtype=jnp.int32)
-    g = jnp.minimum(jnp.searchsorted(a_end, r, side="right"),
-                    held - 1).astype(jnp.int32)
-    off = r - a_start[g]
-    row_valid = (off < count[g]) & (r < a_end[-1])
-    row_pair = jnp.where(
-        row_valid, order[jnp.clip(start[g] + off, 0, pairs - 1)], 0)
-    # pairs -> rows: a pair's place among the pairs of its expert
-    rank = jnp.take_along_axis(
-        jnp.cumsum(onehot.astype(jnp.int32), axis=0),
-        jnp.minimum(key, held - 1)[:, None], axis=1)[:, 0] - 1
-    pair_row = jnp.where(is_held, a_start[jnp.minimum(key, held - 1)] + rank,
-                         0)
-    return {"row_pair": row_pair, "row_valid": row_valid,
-            "tile_expert": g[::tile], "pair_row": pair_row,
+    # pairs -> rows (a pair that is not held matches no column: row 0)
+    pair_row = jnp.where(onehot, (a_end - padded)[None] + seen - 1, 0).sum(-1)
+    # rows -> pairs: the inverse, written by the held pairs (pair + 1; the
+    # others aim past the buffer and are dropped)
+    slot = jnp.zeros((rows,), jnp.int32).at[
+        jnp.where(is_held, pair_row, rows)].set(
+            jnp.arange(1, pairs + 1, dtype=jnp.int32), mode="drop")
+    at = jnp.arange(rows // tile, dtype=jnp.int32) * tile
+    tile_expert = jnp.minimum((at[:, None] >= a_end[None]).sum(-1), held - 1)
+    return {"row_pair": jnp.maximum(slot - 1, 0), "row_valid": slot > 0,
+            "tile_expert": tile_expert, "pair_row": pair_row,
             "pair_held": is_held, "rows_held": count,
             "n_tiles": a_end[-1] // tile, "n_passes": -(-a_end[-1] // chunk)}
 

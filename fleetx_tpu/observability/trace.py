@@ -226,8 +226,8 @@ DEVICE_SCOPES: dict = {
                 "depth-wise taps, and the read and write of the slot's "
                 "last inputs (the tail)",
     "mlp": "dense MLP, shared expert, and the block's closing residual add",
-    "moe.route": "router product and scoring, top-k, plan_rows' sort and "
-                 "search, the gathers into expert order, the combine's "
+    "moe.route": "router product and scoring, top-k, plan_rows' counts and "
+                 "its scatter, the gathers into expert order, the combine's "
                  "scatter-add, the load-bias step",
     "moe.experts": "the grouped products over the held experts and the "
                    "activation between them",

@@ -313,6 +313,118 @@ def test_no_row_is_dropped_when_the_router_picks_one_expert(shipped, chunk,
                                atol=1e-5)
 
 
+# ----------------------------------------------------------------- the plan
+# (tokens, k, held, tile, pass rows, first held, routed experts): the six
+# serve programs' plans as the recipes size them, and a reduced training
+# layer whose share starts past the first expert
+PLAN_SHAPES = {
+    "laguna.decode": (64, 10, 32, 16, 512, 0, 256),
+    "laguna.chunk": (512, 10, 32, 16, 1024, 0, 256),
+    "smallthinker.decode": (48, 6, 64, 16, 1024, 0, 64),
+    "smallthinker.chunk": (512, 6, 64, 16, 4096, 0, 64),
+    "lfm2.decode": (256, 4, 64, 16, 2048, 0, 64),
+    "lfm2.chunk": (512, 4, 64, 16, 3072, 0, 64),
+    "joyai.train.reduced": (1024, 8, 16, 256, 2048, 32, 256),
+}
+JOYAI_TRAIN_PLAN = (16384, 8, 16, 256, 16384, 32, 256)
+
+
+def _plan_route(route, shape):
+    """``ids`` [tokens, k] of one of the four routes the plan must bear."""
+    n, k, held, _, _, first, routed = shape
+    rng = np.random.default_rng(49)
+    ids = np.stack([rng.permutation(routed)[:k] for _ in range(n)])
+    if route == "one_held_expert":  # every pair of every token
+        ids[:] = first + held // 2
+    elif route == "no_pair_held":
+        ids[:] = (first + held) % routed if routed > held else -1
+    elif route == "half_nowhere":   # empty decode slots, a ragged chunk's tail
+        ids[rng.permutation(n)[: n // 2]] = -1
+    return ids.astype(np.int32)
+
+
+def _plan_oracle(ids, first, held, tile, chunk):
+    """The docstring's definition, spelled out: a stable sort of the pairs
+    by local expert, each expert's run padded to whole tiles."""
+    n, k = ids.shape
+    local = ids.reshape(-1) - first
+    is_held = (local >= 0) & (local < held)
+    order = np.argsort(np.where(is_held, local, held), kind="stable")
+    rows = moe.buffer_rows(n * min(k, held), held, tile, chunk)
+    row_pair = np.zeros(rows, np.int32)
+    row_valid = np.zeros(rows, bool)
+    pair_row = np.zeros(n * k, np.int32)
+    count = np.zeros(held, np.int32)
+    tile_expert, at, taken = [], 0, 0
+    for e in range(held):
+        count[e] = (is_held & (local == e)).sum()
+        run = order[taken:taken + count[e]]
+        row_pair[at:at + count[e]] = run
+        row_valid[at:at + count[e]] = True
+        pair_row[run] = at + np.arange(count[e])
+        tiles = -(-count[e] // tile)
+        tile_expert += [e] * tiles
+        at, taken = at + tiles * tile, taken + count[e]
+    return {"row_pair": row_pair, "row_valid": row_valid,
+            "tile_expert": np.asarray(tile_expert, np.int32),
+            "pair_row": pair_row, "pair_held": is_held, "rows_held": count,
+            "n_tiles": np.int32(at // tile),
+            "n_passes": np.int32(-(-at // chunk))}
+
+
+@pytest.mark.parametrize("route", ["random", "one_held_expert",
+                                   "no_pair_held", "half_nowhere"])
+@pytest.mark.parametrize("program", sorted(PLAN_SHAPES))
+def test_the_plan_is_the_stable_sort_padded_to_tiles(program, route):
+    """Every field of ``plan_rows``' dict against the oracle, at the sizes
+    the served programs and the training layer run it at."""
+    shape = PLAN_SHAPES[program]
+    _, _, held, tile, chunk, first, _ = shape
+    ids = _plan_route(route, shape)
+    got = jax.jit(moe.plan_rows, static_argnums=(1, 2, 3, 4))(
+        jnp.asarray(ids), first, held, tile, chunk)
+    want = _plan_oracle(ids, first, held, tile, chunk)
+    assert sorted(got) == sorted(want)
+    n_tiles = int(want["n_tiles"])
+    if route == "no_pair_held":
+        assert n_tiles == 0 and not want["pair_held"].any()
+    else:
+        assert n_tiles > 0
+        assert want["row_valid"].sum() == want["pair_held"].sum()
+    for name, value in want.items():
+        have = np.asarray(got[name])
+        if name == "tile_expert":   # past the last tile nothing reads it
+            assert have.shape == (len(want["row_pair"]) // tile,)
+            have = have[:n_tiles]
+        assert have.dtype == value.dtype and have.shape == value.shape, name
+        np.testing.assert_array_equal(have, value, err_msg=name)
+
+
+def _primitives(jaxpr) -> set:
+    """Names of every primitive of ``jaxpr``, the nested ones' too."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names |= _primitives(sub)
+    return names
+
+
+@pytest.mark.parametrize("shape", [PLAN_SHAPES["smallthinker.decode"],
+                                   JOYAI_TRAIN_PLAN],
+                         ids=["serve", "train"])
+def test_the_plan_holds_no_loop_and_no_sort(shape):
+    """A library's default method (``searchsorted`` is a ``while`` of
+    gathers and selects) cannot bring a loop back unseen."""
+    n, k, held, tile, chunk, first, _ = shape
+    jaxpr = jax.make_jaxpr(
+        lambda ids: moe.plan_rows(ids, first, held, tile, chunk))(
+            jax.ShapeDtypeStruct((n, k), jnp.int32))
+    names = _primitives(jaxpr.jaxpr)
+    assert "cumsum" in names and "scatter" in names, sorted(names)
+    assert not names & {"while", "scan", "sort", "gather"}, sorted(names)
+
+
 # ------------------------------------------------------------ the bias step
 def test_the_bias_step_follows_its_rule_on_a_hand_made_load():
     params = {"moe": {"selection_bias": jnp.array([0.5, -0.25, 0.0, 0.1]),
