@@ -201,9 +201,9 @@ DEVICE_SCOPES: dict = {
     "gdn.conv": "the causal depth-wise convolution over [q; k; v] with its "
                 "SiLU, and the read and write of the slot's last inputs",
     "gdn.core": "the gated delta rule: the chunked form of a prefill chunk "
-                "(triangular system, gdn_chunk kernel), the one-token "
-                "step (gdn_decode), the heads' L2 norms, the state's read "
-                "and write",
+                "(the gdn_chunk kernel: triangular systems and carried "
+                "state), the one-token step (gdn_decode), the heads' L2 "
+                "norms, the state's read and write",
     "attn.cross": "a walk of the one key-value pool that several layers "
                   "read, as a decode row reads it: the full layer's own in "
                   "a decode step, every cross layer's in both programs "

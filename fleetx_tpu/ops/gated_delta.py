@@ -1,5 +1,5 @@
 """The gated delta rule (Gated DeltaNet, arXiv:2412.06464): one-token step
-and chunked prefill, each a Pallas kernel with an XLA path of the same
+and chunked prefill, each a Pallas kernel with a plain form of the same
 arithmetic.
 
 A value head keeps a state ``S`` and sees, a token, a key ``k`` and a query
@@ -26,14 +26,24 @@ accumulates over 10⁴ steps.
 - ``chunk_rule``: ``T`` tokens of one sequence, state in, state out, in
   chunks of ``CHUNK`` tokens (the WY form of the paper, as the Qwen3-Next
   modelling code's ``torch_chunk_gated_delta_rule`` has it): within a chunk
-  the rule is a unit lower-triangular system, solved by forward
-  substitution in float32; between chunks the state is carried. The
-  kernel (trace name ``gdn_chunk``) runs the carried part, one value head a
-  grid step with the state in VMEM over all the chunks; what has no
-  sequential dependence (the triangular system, the products with the
-  decays) is batched XLA in front of it. Decays enter as ``exp`` of
-  DIFFERENCES of the cumulated log-decay, never as a quotient of two
-  exponentials: a chunk of strong decays cannot overflow.
+  the rule is a unit lower-triangular system, between chunks the state is
+  carried. The kernel (trace name ``gdn_chunk``) runs ALL of it, one value
+  head a grid step with the state in VMEM over all the chunks, from ``q``,
+  ``k``, ``v``, ``g`` and ``β`` as they come: a value head's block of
+  ``q`` / ``k`` is its key head's columns of the ``[T, Hk · dk]`` view (no
+  copy a value head), and nothing a chunk needs is written to HBM on the
+  way. The system is solved by BLOCKED forward substitution in float32
+  (`_inverse_unit_lower`): the 16-row diagonal blocks by 15 steps on the
+  vector unit, two levels of joins on the MXU — the row-by-row
+  substitution's solution, never a series in the matrix's powers (it
+  cancels where keys repeat inside a chunk). The solves do not read the
+  state, so those of `_AHEAD` chunks are made together, step by step in
+  turn, ahead of the state that reaches them. The plain form
+  (``kernel=False``) is the same two functions (`_chunk_operands`,
+  `_carry_chunk`) over every head at once in XLA. Every product is float32
+  at ``Precision.HIGHEST``. Decays enter as ``exp`` of DIFFERENCES of the
+  cumulated log-decay, never as a quotient of two exponentials: a chunk of
+  strong decays cannot overflow.
 """
 
 from __future__ import annotations
@@ -50,6 +60,11 @@ from fleetx_tpu import ops
 
 #: tokens a chunk of the chunked rule holds
 CHUNK = 64
+#: rows of a diagonal block of a chunk's triangular system
+_BLOCK = 16
+#: chunks of a value head whose triangular systems the kernel solves
+#: together, a step of each in turn, ahead of the state that reaches them
+_AHEAD = 4
 _HI = jax.lax.Precision.HIGHEST
 _VMEM_LIMIT = 64 * 1024 * 1024
 
@@ -161,74 +176,133 @@ def gdn_decode(state_buf, layer, q, k, v, alpha, beta, live, *,
 
 
 # -------------------------------------------------------------- chunked rule
-def _chunk_operands(q, k, v, g, beta, c: int):
-    """What the carried part of the chunked rule reads, every chunk and
-    value head at once. ``q``/``k`` [T, Hk, dk] (normalised, ``q`` scaled),
-    ``v`` [T, Hv, dv], ``g`` (log decay ≤ 0) / ``beta`` [T, Hv], ``T`` a
-    multiple of the chunk ``c``. Returns, each ``[Hv, chunks, ...]`` float32:
-    ``qg`` (queries times the decay since the chunk's start) [C, dk],
-    ``kdt`` (keys times the decay up to the chunk's end, TRANSPOSED) [dk,
-    C], ``w`` and ``u`` (the triangular system's solutions against the
-    decayed keys and the values) [C, dk] / [C, dv], ``intra`` (queries
-    against the chunk's own keys, decayed, causal) [C, C], ``d`` (the whole
-    chunk's decay, along the value lanes) [1, dv]."""
-    T, hk, dk = k.shape
-    hv, dv = v.shape[1], v.shape[2]
-    n, rep = T // c, v.shape[1] // k.shape[1]
-    f32 = jnp.float32
-
-    def heads_first(x, per_key_head=False):     # [T, H, d] -> [Hv, n, C, d]
-        x = x.astype(f32).reshape(n, c, x.shape[1], -1)
-        x = jnp.transpose(x, (2, 0, 1, 3))
-        return jnp.repeat(x, rep, axis=0) if per_key_head else x
-
-    qh, kh = heads_first(q, True), heads_first(k, True)
-    vh = heads_first(v)
-    gh = jnp.cumsum(heads_first(g[..., None])[..., 0], axis=-1)  # [Hv, n, C]
-    bh = heads_first(beta[..., None])                           # [.., C, 1]
-    lower = jnp.tril(jnp.ones((c, c), bool))
-    # exp of a difference, and only where it is ≤ 0
-    decay = jnp.where(lower, jnp.exp(jnp.where(
-        lower, gh[..., :, None] - gh[..., None, :], 0.0)), 0.0)
-    kb = kh * bh
-    a = -jnp.einsum("hncd,hned->hnce", kb, kh, precision=_HI) * decay
-    a = jnp.where(jnp.tril(jnp.ones((c, c), bool), -1), a, 0.0)
-
-    def substitute(i, a):
-        # row i of (I + A)⁻¹ − I from the rows above it: exact float32
-        row = jax.lax.dynamic_index_in_dim(a, i, axis=2, keepdims=False)
-        row = row + jnp.einsum("hnj,hnjk->hnk", row, a, precision=_HI)
-        return jax.lax.dynamic_update_index_in_dim(a, row, i, axis=2)
-
-    t = jax.lax.fori_loop(1, c, substitute, a) + jnp.eye(c, dtype=f32)
-    u = jnp.einsum("hnce,hned->hncd", t, vh * bh, precision=_HI)
-    w = jnp.einsum("hnce,hned->hncd", t, kb * jnp.exp(gh)[..., None],
-                   precision=_HI)
-    intra = jnp.einsum("hncd,hned->hnce", qh, kh, precision=_HI) * decay
-    qg = qh * jnp.exp(gh)[..., None]
-    kdt = jnp.swapaxes(kh * jnp.exp(gh[..., -1:] - gh)[..., None], -1, -2)
-    d = jnp.broadcast_to(jnp.exp(gh[..., -1])[..., None, None],
-                         (hv, n, 1, dv))
-    return qg, kdt, w, u, intra, d
+def _dot(a, b, contract=((1,), (0,))):
+    """A float32 product of two matrices at full precision; ``contract``
+    names the summed axis of each."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())), precision=_HI,
+                               preferred_element_type=jnp.float32)
 
 
-def _carry_chunk(s, qg, kdt, w, u, intra, d):
+def _inverse_unit_lower(lows):
+    """``(I + low)⁻¹`` for each ``low`` [C, C] (strictly lower triangular,
+    ``C`` a power of two) of a list, by blocked forward substitution. The
+    diagonal blocks of `_BLOCK` rows are inverted all at once, side by side
+    along the lanes, a column a step (row ``i`` of a block loses ``low[i,
+    j]`` times the block's finished row ``j``: the row-by-row
+    substitution's own sums); then each level joins two neighbours, ``[[A,
+    0], [C, B]]⁻¹ = [[A⁻¹, 0], [−B⁻¹ C A⁻¹, B⁻¹]]``: with ``X`` the
+    block-diagonal inverse so far and ``E`` the part of ``low`` that joins
+    neighbours, ``X − X E X``, on the rows that change. No series in powers
+    of ``low``: it cancels where a key repeats through the chunk. The
+    matrices of the list take each step in turn, so their chains of
+    dependent steps interleave (a kernel's schedule follows the order it
+    is written in)."""
+    c = lows[0].shape[0]
+    b = min(_BLOCK, c)
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    diagonal = row // b == col // b
+    blocks = range(0, c, b)
+
+    def side_by_side(x):    # the diagonal blocks [b, b] along the lanes
+        x = jnp.where(diagonal, x, 0.0)
+        return functools.reduce(jnp.add, [x[m:m + b] for m in blocks])
+
+    lows_b = [side_by_side(low) for low in lows]
+    row_b = jax.lax.broadcasted_iota(jnp.int32, (b, c), 0)
+    col_b = jax.lax.broadcasted_iota(jnp.int32, (b, c), 1)
+    first = col_b // b * b              # a lane's block's first lane
+    xs = [(row_b == col_b - first).astype(jnp.float32)] * len(lows)
+    for j in range(b - 1):
+        # row i of every block loses low[i, j] times the block's row j
+        xs = [x - jnp.take_along_axis(low, first + j, axis=1) * x[j:j + 1]
+              for x, low in zip(xs, lows_b)]
+    xs = [jnp.where(diagonal, jnp.concatenate([x] * len(blocks), axis=0), 0.0)
+          for x in xs]
+    while b < c:
+        # the lower neighbour of each pair alone changes: its rows only
+        pairs = range(b, c, 2 * b)
+
+        def lower_rows(x):
+            return jnp.concatenate([x[m:m + b] for m in pairs], axis=0)
+
+        def among(x):       # those rows back where they lie, zeros between
+            zero = jnp.zeros((b, c), jnp.float32)
+            return jnp.concatenate(
+                [part for i in range(0, c // 2, b)
+                 for part in (zero, x[i:i + b])], axis=0)
+
+        join = lower_rows(col // b == row // b - 1)
+        ys = [_dot(jnp.where(join, lower_rows(low), 0.0), x)
+              for x, low in zip(xs, lows)]
+        xs = [x - among(_dot(lower_rows(x), among(y)))
+              for x, y in zip(xs, ys)]
+        b *= 2
+    return xs
+
+
+def _chunk_operands(chunks):
+    """What the carried part reads, for each chunk of a list of ``(q, k, v,
+    g, beta)``: ``q``/``k`` [C, dk] (normalised, ``q`` scaled), ``v`` [C,
+    dv], ``g`` (log decay ≤ 0) / ``beta`` [1, C]. Returns a list of ``(qg,
+    kd, w, u, intra, d)``: queries times the decay since the chunk's start
+    [C, dk], keys times the decay up to its end [C, dk], the triangular
+    system's solutions against the decayed keys and the values [C, dk] /
+    [C, dv], queries against the chunk's own keys, decayed and causal [C,
+    C], the whole chunk's decay [1, 1]. Nothing here reads the state."""
+    c = chunks[0][0].shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    lower, eye = row >= col, row == col
+    nt = ((1,), (1,))
+    lows, rest = [], []
+    for q, k, v, g, beta in chunks:
+        # the cumulated log-decay down the rows, the same numbers along
+        # the lanes; write strengths down the rows
+        gc = jnp.where(lower, g, 0.0).sum(1, keepdims=True)         # [C, 1]
+        gr = jnp.where(eye, gc, 0.0).sum(0, keepdims=True)          # [1, C]
+        b = jnp.where(eye, beta, 0.0).sum(1, keepdims=True)
+        # exp of a difference, and only where it is ≤ 0
+        decay = jnp.where(lower, jnp.exp(jnp.where(lower, gc - gr, 0.0)),
+                          0.0)
+        since = jnp.exp(gc)
+        kb = k * b
+        both = _dot(jnp.concatenate([kb, q], axis=0), k, nt)    # one kᵀ
+        lows.append(jnp.where(row > col, both[:c] * decay, 0.0))
+        rest.append((q * since, k * jnp.exp(gc[c - 1:] - gc), kb * since,
+                     v * b, both[c:] * decay, since[c - 1:]))
+    return [(qg, kd, _dot(t, kbg), _dot(t, vb), intra, d)
+            for t, (qg, kd, kbg, vb, intra, d)
+            in zip(_inverse_unit_lower(lows), rest)]
+
+
+def _carry_chunk(s, qg, kd, w, u, intra, d):
     """One chunk of the carried part: state ``s`` [dk, dv] in, ``(o [C,
     dv], state)`` out."""
-    dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32,
-                            precision=_HI)
-    v_new = u - dot(w, s)
-    o = dot(qg, s) + dot(intra, v_new)
-    return o, s * d + dot(kdt, v_new)
+    c = w.shape[0]
+    both = _dot(jnp.concatenate([w, qg], axis=0), s)            # one state
+    v_new = u - both[:c]
+    o = both[c:] + _dot(intra, v_new)
+    return o, s * d + _dot(kd, v_new, ((0,), (0,)))
 
 
-def _chunk_kernel(qg_ref, kdt_ref, w_ref, u_ref, intra_ref, d_ref, s_ref,
-                  o_ref, so_ref, *, chunks: int):
+def _chunk_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, o_ref, so_ref, *,
+                  c: int):
+    """One value head, all its chunks, the state in VMEM throughout:
+    ``q_ref``/``k_ref`` [T, dk] are the columns of the head's KEY head,
+    ``v_ref`` [T, dv] / ``o_ref`` [1, T, dv] the value head's,
+    ``g_ref``/``b_ref`` [1, chunks, C] a chunk a row. The operands of
+    `_AHEAD` chunks are made together, then carried one by one."""
     s = s_ref[0]
-    for c in range(chunks):
-        o, s = _carry_chunk(s, qg_ref[0, c], kdt_ref[0, c], w_ref[0, c],
-                            u_ref[0, c], intra_ref[0, c], d_ref[0, c])
-        o_ref[0, c] = o
+    n = g_ref.shape[1]
+    for first in range(0, n, _AHEAD):
+        group = range(first, min(first + _AHEAD, n))
+        operands = _chunk_operands([
+            (q_ref[i * c:(i + 1) * c, :], k_ref[i * c:(i + 1) * c, :],
+             v_ref[i * c:(i + 1) * c, :], g_ref[0, i:i + 1, :],
+             b_ref[0, i:i + 1, :]) for i in group])
+        for i, xs in zip(group, operands):
+            o_ref[0, i * c:(i + 1) * c, :], s = _carry_chunk(s, *xs)
     so_ref[0] = s
 
 
@@ -238,39 +312,63 @@ def chunk_rule(q, k, v, g, beta, state, *, kernel: bool = True):
     ``(o [T, Hv, dv] float32, state)``, in chunks of `CHUNK` tokens (of
     their greatest common divisor with ``T``, where that is smaller); a
     token past the sequence's end carries ``g = 0, beta = 0`` and changes
-    nothing. ``kernel=False``: the carried part as a ``lax.scan``."""
+    nothing. Value head *h* reads key head ``h // (Hv / Hk)`` where it
+    lies. ``kernel=False``: the same chunks, every head at once in XLA."""
     T, hv, dv = v.shape
-    dk = k.shape[2]
+    hk, dk = k.shape[1:]
+    rep = hv // hk
     c = math.gcd(T, CHUNK)
     n = T // c
-    operands = _chunk_operands(q, k, v, g, beta, c)
-    state = state.astype(jnp.float32)
+    f32 = jnp.float32
+    q, k, v = q.astype(f32), k.astype(f32), v.astype(f32)
+    state = state.astype(f32)
+    # a chunk a row, a head's rows together
+    g, beta = (jnp.transpose(x.astype(f32)).reshape(hv, n, c)
+               for x in (g, beta))
     if kernel:
-        def head(h):
-            return h, 0, 0, 0
+        def key_head(h):
+            return 0, h // rep
 
-        blocks = [(1, n) + x.shape[2:] for x in operands]
+        def value_head(h):
+            return 0, h
+
+        def head(h):
+            return h, 0, 0
+
         o, state = pl.pallas_call(
-            functools.partial(_chunk_kernel, chunks=n),
+            functools.partial(_chunk_kernel, c=c),
             grid=(hv,),
-            in_specs=[pl.BlockSpec(b, head) for b in blocks]
-            + [pl.BlockSpec((1, dk, dv), lambda h: (h, 0, 0))],
-            out_specs=[pl.BlockSpec((1, n, c, dv), head),
-                       pl.BlockSpec((1, dk, dv), lambda h: (h, 0, 0))],
-            out_shape=[jax.ShapeDtypeStruct((hv, n, c, dv), jnp.float32),
-                       jax.ShapeDtypeStruct((hv, dk, dv), jnp.float32)],
+            in_specs=[pl.BlockSpec((T, dk), key_head),
+                      pl.BlockSpec((T, dk), key_head),
+                      pl.BlockSpec((T, dv), value_head),
+                      pl.BlockSpec((1, n, c), head),
+                      pl.BlockSpec((1, n, c), head),
+                      pl.BlockSpec((1, dk, dv), head)],
+            out_specs=[pl.BlockSpec((1, T, dv), head),
+                       pl.BlockSpec((1, dk, dv), head)],
+            out_shape=[jax.ShapeDtypeStruct((hv, T, dv), f32),
+                       jax.ShapeDtypeStruct((hv, dk, dv), f32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",),
                 vmem_limit_bytes=_VMEM_LIMIT),
             interpret=ops.interpret(),
             name="gdn_chunk",
-        )(*operands, state)
-    else:
-        def step(s, xs):
-            o, s = jax.vmap(_carry_chunk)(s, *xs)
-            return s, o
-
-        state, o = jax.lax.scan(
-            step, state, tuple(jnp.swapaxes(x, 0, 1) for x in operands))
-        o = jnp.swapaxes(o, 0, 1)                       # [Hv, n, C, dv]
-    return jnp.transpose(o, (1, 2, 0, 3)).reshape(T, hv, dv), state
+        )(q.reshape(T, hk * dk), k.reshape(T, hk * dk),
+          v.reshape(T, hv * dv), g, beta, state)
+        return jnp.swapaxes(o, 0, 1), state
+    # every head's chunks at once; a key head's q and k serve its value heads
+    per_head = jax.vmap(
+        lambda q, k, v, g, b: _chunk_operands([(q, k, v, g, b)])[0],
+        in_axes=(None, None, 1, 0, 0))
+    per_key_head = jax.vmap(per_head, in_axes=(1, 1, 1, 0, 0))
+    state = state.reshape(hk, rep, dk, dv)
+    carry = jax.vmap(jax.vmap(_carry_chunk))
+    outs = []
+    for i in range(n):
+        rows = slice(i * c, (i + 1) * c)
+        o, state = carry(state, *per_key_head(
+            q[rows], k[rows], v[rows].reshape(c, hk, rep, dv),
+            g[:, i:i + 1].reshape(hk, rep, 1, c),
+            beta[:, i:i + 1].reshape(hk, rep, 1, c)))
+        outs.append(jnp.moveaxis(o.reshape(hv, c, dv), 0, 1))
+    return jnp.concatenate(outs), state.reshape(hv, dk, dv)

@@ -174,6 +174,108 @@ def test_tokens_past_a_ragged_chunks_end_change_nothing():
     np.testing.assert_allclose(s, want_s, atol=5e-6)
 
 
+def _published_widths():
+    """Two value heads to a key head at the published head sizes."""
+    return _rule_inputs(128, hk=2, hv=4, dk=128, dv=128, seed=1)
+
+
+def _strong_decays():
+    """``g = -30`` a token: a chunk's cumulated log-decay reaches -1,920,
+    whose exponential's inverse overflows float32."""
+    q, k, v, g, beta, s0 = _rule_inputs(128)
+    return q, k, v, jnp.full_like(g, -30.0), beta, s0
+
+
+def _one_key():
+    """One key through whole chunks with beta -> 1 and no decay: the
+    chunk's triangular system has ``-beta`` at every place under its
+    diagonal, the powers of that matrix grow like binomial coefficients (to
+    10^18 at 64 rows) and cancel."""
+    q, k, v, g, beta, s0 = _rule_inputs(128)
+    return (q, jnp.broadcast_to(k[:1], k.shape), v, jnp.zeros_like(g),
+            jnp.full_like(beta, 1.0 - 1e-3), s0)
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["xla", "pallas"])
+@pytest.mark.parametrize("inputs,atol_o,atol_s", [
+    (_published_widths, 5e-6, 5e-6),
+    (_strong_decays, 5e-6, 5e-6),
+    # what the row-by-row substitution of PR 49 met on these inputs: 6.85e-7
+    # on outputs of size 1.05, 2.86e-6 on a state of size 3.8
+    (_one_key, 6.9e-7, 2.9e-6),
+    (lambda: _rule_inputs(96, seed=4), 5e-6, 5e-6),     # chunks of 32
+    (lambda: _rule_inputs(24, seed=2), 5e-6, 5e-6),     # chunks of 8
+    (lambda: _rule_inputs(8, seed=3), 5e-6, 5e-6),      # less than a block
+], ids=["two-value-heads-a-key-head-at-128", "strong-decays",
+        "one-key-through-a-chunk", "T96", "T24", "T8"])
+def test_the_triangular_systems_inside_the_rule(kernel, inputs, atol_o,
+                                                atol_s):
+    """From a non-zero state, the chunked form is the recurrence where the
+    chunk's triangular system is what can go wrong: a value head finds its
+    key head where the key heads lie (no copy a value head); decays enter
+    as differences alone (finite outputs); a key repeated through a chunk
+    (blocked substitution, no series in the matrix's powers); a chunk of
+    two blocks (one level of joins), of half a block."""
+    with jax.default_matmul_precision("highest"):
+        q, k, v, g, beta, s0 = inputs()
+        want_o, want_s = _recurrence(q, k, v, g, beta, s0)
+        o, s = GD.chunk_rule(q, k, v, g, beta, s0, kernel=kernel)
+    assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(s).all())
+    np.testing.assert_allclose(o, want_o, atol=atol_o, rtol=0)
+    np.testing.assert_allclose(s, want_s, atol=atol_s, rtol=0)
+
+
+def _scoped_eqns(jaxpr, scope=""):
+    """``(equation, the named scopes it lies under)`` for every equation of
+    a jaxpr and of the jaxprs its equations hold (a loop's body, a Pallas
+    kernel's): an inner jaxpr's names are relative to its equation's."""
+    for eqn in jaxpr.eqns:
+        here = f"{scope}/{eqn.source_info.name_stack}"
+        yield eqn, here
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _scoped_eqns(sub, here)
+
+
+_LOOPS = ("while", "scan")      # a ``fori_loop`` is one or the other
+
+
+def test_the_chunked_rule_is_one_kernel_and_no_loop(built):
+    """Guards what PR 50 took out of the prefill chunk: the triangular
+    systems' 63-turn substitution loop, batched XLA over an 8 MB tensor in
+    front of the kernel. At the published chunk the rule with its kernel is
+    ONE ``pallas_call`` and no loop, inside the kernel or around it; the
+    family's prefill step has no loop under its ``gdn.core`` scope on
+    either path (the loops over runs of layers lie above it)."""
+    q, k, v, g, beta, s0 = _rule_inputs(512, hk=1, hv=2, dk=128, dv=128)
+    eqns = [e.primitive.name for e, _ in _scoped_eqns(jax.make_jaxpr(
+        lambda *a: GD.chunk_rule(*a, kernel=True))(q, k, v, g, beta, s0
+                                                   ).jaxpr)]
+    assert eqns.count("pallas_call") == 1
+    assert not [name for name in eqns if name in _LOOPS]
+    cfg, params, _, _ = built
+    P, max_batch = 96 // PAGE, 3
+    cache = S.init_cache(cfg, num_pages=1 + max_batch * P, page_size=PAGE,
+                         max_batch=max_batch)
+    for kernels in (False, True):
+        fns = S.make_step_fns(cfg, prefill_chunk=CHUNK,
+                              sampling=SamplingParams(), kernels=kernels,
+                              latent_kernel=False)
+        jaxpr = jax.make_jaxpr(fns["prefill"])(
+            params, *cache, np.zeros((1, CHUNK), np.int32),
+            np.zeros((1, P), np.int32), np.int32(0), np.int32(CHUNK),
+            jax.random.PRNGKey(0), np.uint32(0), np.int32(1))
+        under = [(e.primitive.name, scope)
+                 for e, scope in _scoped_eqns(jaxpr.jaxpr)
+                 if "fx.gdn.core" in scope]
+        assert under, "the prefill step opens no gdn.core scope"
+        assert not [u for u in under if u[0] in _LOOPS]
+        # (a scanned run of layers holds its body once)
+        assert ("pallas_call" in [u[0] for u in under]) == kernels
+
+
 @pytest.mark.parametrize("kernel", [False, True], ids=["xla", "pallas"])
 def test_the_one_token_rule_is_the_recurrence_and_touches_live_rows_only(
         kernel):
