@@ -65,6 +65,11 @@ def get_registry():
         modules["SambaYModule"] = SambaYModule
     except ImportError:
         pass
+    try:
+        from fleetx_tpu.models.ssm_mqa.module import SSMMQAModule
+        modules["SSMMQAModule"] = SSMMQAModule
+    except ImportError:
+        pass
     return modules
 
 

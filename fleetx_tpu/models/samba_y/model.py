@@ -49,18 +49,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# the depth-wise taps over a tail: the short-convolution family's, as they are
-from fleetx_tpu.models.conv_moe.model import (conv_sequence,  # noqa: F401
-                                              conv_taps)
+from fleetx_tpu.models import scan_mixer
 from fleetx_tpu.models.samba_y.config import (CROSS, FULL, GMU, SCAN, WINDOW,
                                               SambaYConfig)
-from fleetx_tpu.ops import selective_scan as SS
+# the scan mixer is one definition (``models/scan_mixer.py``), shared with
+# the family whose scan layers norm their step, ``B`` and ``C``; its parts
+# under the names this module has always had
+from fleetx_tpu.models.scan_mixer import (conv_act, conv_sequence,  # noqa: F401
+                                          conv_taps, ssm_decay, ssm_in,
+                                          ssm_out, ssm_params)
 
 #: leaves kept in float32 whatever ``cfg.dtype`` is: every norm's weight
 #: and bias, the scan's own vectors, the λ vectors
 F32_GROUPS = frozenset({"norm1", "norm2", "final_norm"})
-F32_LEAVES = frozenset({"A_log", "D", "dt_bias", "conv_bias", "subln",
-                        "lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"})
+F32_LEAVES = scan_mixer.F32_LEAVES | {
+    "subln", "lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"}
 _NEG = -1e30
 
 
@@ -71,7 +74,7 @@ def param_shapes(cfg: SambaYConfig) -> dict:
     inner]: ``ops/selective_scan.py`` has the reason)."""
     h, hd, f = cfg.hidden_size, cfg.head_dim, cfg.intermediate_size
     q, kv = cfg.num_attention_heads * hd, cfg.kv_lanes
-    di, n, r = cfg.d_inner, cfg.d_state, cfg.dt_rank
+    di = cfg.d_inner
     tree = {"embed": {"tokens": (cfg.vocab_size, h)},
             "final_norm": {"scale": (h,), "bias": (h,)}}
     for kind, layers in cfg.kinds().items():
@@ -80,11 +83,7 @@ def param_shapes(cfg: SambaYConfig) -> dict:
                  "norm2": {"scale": (L, h), "bias": (L, h)},
                  "mlp": {"gate_up": (L, h, 2 * f), "down": (L, f, h)}}
         if kind == SCAN:
-            layer["ssm"] = {
-                "in": (L, h, 2 * di), "taps": (L, cfg.d_conv, di),
-                "conv_bias": (L, di), "x": (L, di, r + 2 * n),
-                "dt": (L, r, di), "dt_bias": (L, di), "A_log": (L, n, di),
-                "D": (L, di), "out": (L, di, h)}
+            layer["ssm"] = scan_mixer.leaf_shapes(L, h, cfg)
         elif kind == GMU:
             layer["gmu"] = {"in": (L, h, di), "out": (L, di, h)}
         else:
@@ -149,23 +148,12 @@ def init_params(cfg: SambaYConfig, key: jax.Array,
         if names & F32_GROUPS or "subln" in names:
             value = jnp.ones(shape) if "bias" not in names \
                 else jnp.zeros(shape)
-        elif "A_log" in names:
-            value = jnp.broadcast_to(jnp.log(jnp.arange(
-                1, shape[1] + 1, dtype=jnp.float32))[None, :, None], shape)
-        elif "D" in names:
-            value = jnp.ones(shape)
-        elif "dt_bias" in names:
-            # the normal draw's quantile is uniform: steps log-uniform
-            u = 0.5 * (1.0 + jax.lax.erf(noise / math.sqrt(2.0)))
-            step = jnp.exp(u * (math.log(1e-1) - math.log(1e-3))
-                           + math.log(1e-3))
-            value = step + jnp.log(-jnp.expm1(-step))    # softplus⁻¹
-        elif "taps" in names:
-            value = 1.0 / math.sqrt(shape[1]) + 0.1 * noise
         elif any(n.startswith("lambda_") for n in names):
             value = 0.1 * noise
         else:
-            value = 0.02 * noise
+            value = scan_mixer.init_leaf(names, shape, noise)
+            if value is None:
+                value = 0.02 * noise
         return value.astype(dtype)
 
     return treedef.unflatten([make(p, s, k)
@@ -190,44 +178,6 @@ def gated_mlp(f: jax.Array, lp: dict) -> jax.Array:
     half = gu.shape[-1] // 2
     a = (jax.nn.silu(gu[:, :half]) * gu[:, half:]).astype(f.dtype)
     return jnp.einsum("sf,fh->sh", a, lp["down"])
-
-
-def ssm_in(u: jax.Array, lp: dict) -> tuple:
-    """``u`` [rows, h] -> ``(x, z)`` [rows, inner] each (ASSUMED: ``x``
-    first), in ``u``'s dtype."""
-    xz = jnp.einsum("sh,hc->sc", u, lp["in"])
-    half = xz.shape[-1] // 2
-    return xz[:, :half], xz[:, half:]
-
-
-def conv_act(c: jax.Array, lp: dict, dtype) -> jax.Array:
-    """``silu(conv + b_c)`` in ``dtype``: what the scan and its three
-    products read."""
-    return jax.nn.silu(c + lp["conv_bias"]).astype(dtype)
-
-
-def ssm_params(xc: jax.Array, lp: dict, cfg: SambaYConfig) -> tuple:
-    """``xc`` [rows, inner] -> ``(Δ [rows, inner], B, C [rows, N])``
-    float32: ``[δ; B; C] = W_x xc``, ``Δ = softplus(W_Δ δ + b_Δ)``."""
-    r, n = cfg.dt_rank, cfg.d_state
-    dbc = jnp.einsum("sc,cr->sr", xc, lp["x"],
-                     preferred_element_type=jnp.float32)
-    delta = jnp.einsum("sr,rc->sc", dbc[:, :r].astype(xc.dtype), lp["dt"],
-                       preferred_element_type=jnp.float32)
-    return (jax.nn.softplus(delta + lp["dt_bias"]), dbc[:, r:r + n],
-            dbc[:, r + n:])
-
-
-def ssm_decay(lp: dict) -> jax.Array:
-    """``A = −exp(A_log)`` [N, inner] float32."""
-    return -jnp.exp(lp["A_log"])
-
-
-def ssm_out(y: jax.Array, z: jax.Array, lp: dict) -> jax.Array:
-    """``W_out(y ⊙ silu(z))``: ``y`` float32 (with the ``D`` skip), the
-    gate in float32, the product in ``z``'s dtype."""
-    g = (y * jax.nn.silu(z.astype(jnp.float32))).astype(z.dtype)
-    return jnp.einsum("sc,ch->sh", g, lp["out"])
 
 
 def memory_unit(u: jax.Array, m: jax.Array, lp: dict) -> jax.Array:
@@ -319,18 +269,9 @@ def forward(params: dict, cfg: SambaYConfig, tokens: jax.Array
         lp = jax.tree.map(lambda w: w[at[kind]], params[kind])
         u = layer_norm(x, lp["norm1"], eps, dt)
         if kind == SCAN:
-            xs, z = ssm_in(u, lp["ssm"])
-            ext = jnp.concatenate([jnp.zeros(
-                (cfg.d_conv - 1, xs.shape[1]), xs.dtype), xs])
-            xc = conv_act(conv_sequence(ext, lp["ssm"]["taps"]), lp["ssm"],
-                          dt)
-            delta, b, c = ssm_params(xc, lp["ssm"], cfg)
-            y, _ = SS.scan_rule(xc, delta, ssm_decay(lp["ssm"]), b, c,
-                                lp["ssm"]["D"], jnp.zeros(
-                                    (cfg.d_state, cfg.d_inner), jnp.float32))
+            mixed, y = scan_mixer.mix_sequence(u, lp["ssm"], cfg, dt)
             if l == cfg.half:
                 m = y.astype(dt)
-            mixed = ssm_out(y, z, lp["ssm"])
         elif kind == GMU:
             mixed = memory_unit(u, m, lp["gmu"])
         else:

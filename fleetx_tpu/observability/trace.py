@@ -218,6 +218,9 @@ DEVICE_SCOPES: dict = {
     "ssm.core": "the selective scan: a chunk's tokens from the slot's state "
                 "(ssm_chunk), the one-token step over the live slots "
                 "(ssm_decode), the state's read and write",
+    "ssm.norm": "the RMS norms INSIDE a selective-scan layer's input path: "
+                "on the step's input, B and C, between the product that "
+                "makes them and the ones that use them",
     "gmu": "a gated memory unit: the gate's product, its SiLU times the "
            "last scan layer's output, the out product",
     "conv.proj": "a short-convolution layer's two products: into the two "

@@ -154,6 +154,11 @@ _PAGED_VMEM_BUDGET_BYTES = 4 * 1024 * 1024
 #: stays narrow; decode attention is DMA-bound and the MXU otherwise idle
 _MAX_HEAD_BLOCK = 16
 
+#: what the block tables, a scalar-prefetch operand, may take of the core's
+#: 1 MB of scalar memory (the lengths, the layer and the compiler's own
+#: scalars share it)
+_TABLE_SMEM_BYTES = 960 * 1024
+
 #: what one fold moves, a pool: 16 pages of ``[16, 1024]`` bfloat16 (the 345M
 #: serving geometry and Laguna's full layers), 32 of ``[16, 512]``
 #: (SmallThinker's). A fold's cost is the core's own and is not hidden behind
@@ -279,7 +284,8 @@ def fold_shape(*, ring_pages: Optional[int] = None, **geometry) -> tuple:
 def paged_attention_refusal(*, num_heads: int, head_dim: int,
                             page_size: int, pages_per_req: int,
                             dtype: Any = jnp.float32,
-                            num_kv_heads: Optional[int] = None) -> str:
+                            num_kv_heads: Optional[int] = None,
+                            batch: Optional[int] = None) -> str:
     """The bound that keeps the in-kernel page walk from this engine
     geometry, in words, or "" when the kernel applies.
 
@@ -299,7 +305,15 @@ def paged_attention_refusal(*, num_heads: int, head_dim: int,
     HALF of one (``head_dim`` 64: 4 query heads to each of 8 key-value
     heads is one block of 32 rows over 512 lanes); a grouped head of any
     other width is refused — nothing here has compiled one.
+    ``batch`` (a caller that knows its rows): the block tables are a scalar
+    prefetch operand, ``batch · pages_per_req`` 4-byte entries that have to
+    fit the core's scalar memory — 256 rows of 18,432 tokens in 16-token
+    pages are 1.18 MB and the compiler refuses them (v5e compile, PR 51).
     """
+    if batch is not None and batch * pages_per_req * 4 > _TABLE_SMEM_BYTES:
+        return f"block tables of {batch} rows x {pages_per_req} pages do " \
+               f"not fit the {_TABLE_SMEM_BYTES >> 10} KB of scalar " \
+               f"memory a scalar-prefetch operand may take"
     if num_heads < 1 or pages_per_req < 1 or page_size < 1:
         return "no heads, pages or page rows"
     kv = num_kv_heads or num_heads

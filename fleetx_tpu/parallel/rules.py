@@ -11,7 +11,7 @@ stage shards which term, and the serving KV pool hand-wired its own
 hardware. Here the whole mapping is *data*:
 
 - ``PARTITION_RULES``: per model family (``gpt``, ``gpt_moe``,
-  ``gpt_lora``, ``vision``, ``ernie``, ``imagen``, ``mla_moe``, ``swa_moe``, ``gdn_mla``, ``conv_moe``, ``samba_y``, plus the serving KV
+  ``gpt_lora``, ``vision``, ``ernie``, ``imagen``, ``mla_moe``, ``swa_moe``, ``gdn_mla``, ``conv_moe``, ``samba_y``, ``ssm_mqa``, plus the serving KV
   pool as ``serving_kv``), an
   ORDERED tuple of ``(regex, logical-axes template)`` rules matched against
   slash-joined parameter-tree paths, first match wins — the
@@ -404,6 +404,26 @@ PARTITION_RULES: dict[str, tuple] = {
         (r"embed/tokens$", ("vocab", "embed")),
         (r"(^|/)\w*norm\d?/(scale|bias)$", ("norm",)),
     ),
+    # the scan / multi-query family (models/ssm_mqa; served on one chip):
+    # the scan's channels and the attention's QUERY heads over the Megatron
+    # axis; the small step and B / C projection with its channel side and
+    # the three inner norms over its outputs replicated; the one key-value
+    # head's columns ride the fused qkv product's head axis (a mesh that
+    # divides 20 query heads would replicate that head: not written)
+    "ssm_mqa": (
+        (r"ssm/in$", ("embed", "heads")),
+        (r"ssm/(taps|dt|A_log)$", (None, "heads")),
+        (r"ssm/(conv_bias|dt_bias|D)$", ("heads",)),
+        (r"ssm/x$", ("heads", None)),
+        (r"ssm/(dt|b|c)_norm$", (None,)),
+        (r"ssm/out$", ("heads", "embed")),
+        (r"attn/qkv$", ("embed", "heads")),
+        (r"attn/out$", ("heads", "embed")),
+        (r"mlp/(gate|up)$", ("embed", "mlp")),
+        (r"mlp/down$", ("mlp", "embed")),
+        (r"embed/tokens$", ("vocab", "embed")),
+        (r"(^|/)\w*norm\d?/scale$", ("norm",)),
+    ),
     # the serving KV page pool (serving/paged_cache.py): pages over the
     # ZeRO axis (capacity scales with fsdp), heads over the Megatron axis
     # (heads and head_dim share the pool's minor dim, heads major)
@@ -426,6 +446,7 @@ STACK_MARKERS: dict[str, str] = {
     "gdn_mla": r"(^|/)(linear|latent)_(dense|moe)/",
     "conv_moe": r"(^|/)(conv|full)_(dense|moe)/",
     "samba_y": r"(^|/)(scan|window|full|gmu|cross)/",
+    "ssm_mqa": r"(^|/)(scan|full)/",
 }
 
 #: families whose fully-replicated leaves are accepted at ANY size by the

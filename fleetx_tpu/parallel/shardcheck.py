@@ -103,7 +103,8 @@ def _sample_batch(module: Any, family: str) -> dict:
         s = int(module.model_cfg.max_position_embeddings)
         tok = np.zeros((1, s), np.int32)
         return {"tokens": tok, "position_ids": tok.copy()}
-    if family in ("mla_moe", "swa_moe", "gdn_mla", "conv_moe", "samba_y"):
+    if family in ("mla_moe", "swa_moe", "gdn_mla", "conv_moe", "samba_y",
+                  "ssm_mqa"):
         tok = np.zeros((1, int(module.tokens_per_sample)), np.int32)
         return {"tokens": tok, "position_ids": tok.copy()}
     if family == "ernie":

@@ -41,8 +41,10 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
+from fleetx_tpu.models import scan_mixer as SM
 from fleetx_tpu.models.gpt import generation as G
 from fleetx_tpu.observability.trace import device_scope
+from fleetx_tpu.ops import selective_scan as SS
 
 _NEG = -1e30
 
@@ -284,6 +286,64 @@ def untied_logits(params: Any, x_last: jax.Array) -> jax.Array:
     """The (untied) head on the selected positions -> float32 ``[B, V]``."""
     return jnp.einsum("bh,hv->bv", x_last, params["head"]["kernel"],
                       preferred_element_type=jnp.float32)
+
+
+# ------------------------------------------------- a selective-scan layer
+def scan_mixer(u: jax.Array, sp: dict, cfg: Any, state: jax.Array,
+               tail: jax.Array, i, *, decode: bool, kernels: bool,
+               valid: jax.Array, slot=None, first=None, n_valid=None,
+               eps: float = 0.0) -> tuple:
+    """Layer ``i`` of a family's scan stack through its two caches: ``u``
+    [rows, h] the block's normed input, ``sp`` the layer's leaves
+    (``models/scan_mixer.py``; a layer that holds the inner norms' weights
+    norms its step, ``B`` and ``C`` with ``eps``), ``state`` [scan layers,
+    slots, N, inner] float32 and ``tail`` [scan layers, d_conv − 1, slots,
+    inner] the WHOLE buffers -> ``(the mixer's output [rows, h], y [rows,
+    inner] float32 — the scan's output with the skip —, state, tail)``.
+
+    Decode: a row a slot; the live rows (``valid``) shift their tails and
+    step their states (``ssm_decode``, in place), every other row keeps
+    both. Prefill: the rows are one chunk of the request in ``slot``,
+    ``n_valid`` of them real; a request's ``first`` chunk reads zeros in
+    place of the state and the tail, a row past the chunk's end carries
+    ``Δ = 0``, and the tail keeps the last REAL tokens' inputs. ``kernels``:
+    the Pallas kernels (else the plain forms)."""
+    dt = u.dtype
+    with device_scope("ssm.proj"):
+        xs, z = SM.ssm_in(u, sp)
+    with device_scope("ssm.conv"):
+        if decode:
+            old = tail[i]                                   # [K-1, B, inner]
+            ext = jnp.concatenate([old, xs[None]], axis=0)
+            c = SM.conv_taps(ext, sp["taps"][:, None, :], axis=0)
+            tail = tail.at[i].set(jnp.where(
+                valid[None, :, None], ext[1:], old))
+        else:
+            old = jnp.where(first, jnp.zeros_like(tail[i, :, slot]),
+                            tail[i, :, slot])               # [K-1, inner]
+            ext = jnp.concatenate([old, xs], axis=0)
+            c = SM.conv_sequence(ext, sp["taps"])
+            # the last inputs of the chunk's REAL tokens
+            tail = tail.at[i, :, slot].set(jax.lax.dynamic_slice(
+                ext, (n_valid, 0), (old.shape[0], ext.shape[1])))
+        xc = SM.conv_act(c, sp, dt)
+    with device_scope("ssm.proj"):
+        delta, b, c = SM.ssm_params(xc, sp, cfg, eps)
+    with device_scope("ssm.core"):
+        a = SM.ssm_decay(sp)
+        if decode:
+            y, state = SS.scan_step(state, i, xc, delta, a, b, c, sp["D"],
+                                    valid, kernel=kernels)
+        else:
+            # a row past the chunk's end leaves the state as it was
+            delta = jnp.where(valid[:, None], delta, 0.0)
+            h0 = jnp.where(first, jnp.zeros_like(state[i, slot]),
+                           state[i, slot])
+            y, h = SS.scan_chunk(xc, delta, a, b, c, sp["D"], h0,
+                                 kernel=kernels)
+            state = state.at[i, slot].set(h)
+    with device_scope("ssm.proj"):
+        return SM.ssm_out(y, z, sp), y, state, tail
 
 
 # ------------------------------------------------------------ the layer walk

@@ -427,8 +427,54 @@ class SambaYFamily(Family):
         return {}
 
 
+class SSMMQAFamily(Family):
+    """Selective-scan layers whose step, ``B`` and ``C`` are normed, beside
+    a few multi-query attention layers (``models/ssm_mqa``,
+    ``serving/ssm_mqa.py``; ``docs/ssm_mqa.md``): a paged key-value pool
+    one lane tile wide for the attention layers only, a float32 state and a
+    convolution tail a slot for the scan layers. No experts."""
+
+    modules = ("SSMMQAModule",)
+    model_package = "fleetx_tpu.models.ssm_mqa"
+    serving_module = "fleetx_tpu.serving.ssm_mqa"
+    decode_attention = "decode attention and the selective scan"
+    unplaced = "none of its three caches"
+
+    def programs(self, model_cfg, serving, sampling, mesh,
+                 pages_per_req: int) -> Programs:
+        """See ``Family.programs``."""
+        S, sc = self._serving(), serving
+        self._one_chip_unquantized(sc, mesh)
+        geometry = dict(page_size=sc.page_size, pages_per_req=pages_per_req)
+        cache = S.init_cache(model_cfg, num_pages=sc.num_pages,
+                             page_size=sc.page_size, max_batch=sc.max_batch)
+        kernels = self._kernel_serves(sc, S.kernel_refusal(
+            model_cfg, prefill_chunk=sc.prefill_chunk,
+            max_batch=sc.max_batch, **geometry))
+        fns = S.make_step_fns(model_cfg, prefill_chunk=sc.prefill_chunk,
+                              sampling=sampling, kernels=kernels)
+        walk = None
+        if kernels:
+            asked = S.kernel_geometry(model_cfg, **geometry)
+            walk = KernelWalk(PA.page_walk_shape(**asked),
+                              {"full": PA.fold_shape(**asked)})
+        return Programs(cache=list(cache), fns=fns, kernel=walk,
+                        tokens=jnp.zeros((sc.max_batch,), jnp.int32))
+
+    def cache_bytes(self, cache: list) -> dict:
+        return {"latent": 0, "state": int(cache[2].nbytes + cache[3].nbytes)}
+
+    def stats_recorder(self, model_cfg):
+        """No experts: nothing of what ``decode`` returns after its logits
+        is recorded."""
+        return lambda metrics, counters: None
+
+    def stats_snapshot(self, metrics) -> dict:
+        return {}
+
+
 _FAMILIES = (GPTFamily(), SWAMoEFamily(), GDNMLAFamily(), ConvMoEFamily(),
-             SambaYFamily())
+             SambaYFamily(), SSMMQAFamily())
 
 
 def families() -> dict:

@@ -179,7 +179,7 @@ def _forward(params: Any, cfg: SambaYConfig, tokens, positions, cache,
     dt, eps, hd = cfg.dtype, cfg.layer_norm_eps, cfg.head_dim
     pairs, wide = cfg.num_key_value_heads // 2, 2 * cfg.head_dim
     ps, P = cache[0].shape[2], block_tables.shape[1]
-    window, taps = cfg.sliding_window, cfg.d_conv
+    window = cfg.sliding_window
     scale = 1.0 / math.sqrt(hd)
     # keys a block of the prefill's attention scores at once: as many as
     # the chunk has queries, in whole pages
@@ -226,41 +226,10 @@ def _forward(params: Any, cfg: SambaYConfig, tokens, positions, cache,
         sp = lp["ssm"]
         with device_scope("norm"):
             u = M.layer_norm(x, lp["norm1"], eps, dt)
-        with device_scope("ssm.proj"):
-            xs, z = M.ssm_in(u, sp)
-        with device_scope("ssm.conv"):
-            if decode:
-                old = tail[i]                               # [K-1, B, inner]
-                ext = jnp.concatenate([old, xs[None]], axis=0)
-                c = M.conv_taps(ext, sp["taps"][:, None, :], axis=0)
-                tail = tail.at[i].set(jnp.where(
-                    valid[None, :, None], ext[1:], old))
-            else:
-                old = jnp.where(first, jnp.zeros_like(tail[i, :, slot]),
-                                tail[i, :, slot])           # [K-1, inner]
-                ext = jnp.concatenate([old, xs], axis=0)
-                c = M.conv_sequence(ext, sp["taps"])
-                # the last inputs of the chunk's REAL tokens
-                tail = tail.at[i, :, slot].set(jax.lax.dynamic_slice(
-                    ext, (n_valid, 0), (taps - 1, ext.shape[1])))
-            xc = M.conv_act(c, sp, dt)
-        with device_scope("ssm.proj"):
-            delta, b, c = M.ssm_params(xc, sp, cfg)
-        with device_scope("ssm.core"):
-            a = M.ssm_decay(sp)
-            if decode:
-                y, state = SS.scan_step(state, i, xc, delta, a, b, c,
-                                        sp["D"], valid, kernel=kernels)
-            else:
-                # a row past the chunk's end leaves the state as it was
-                delta = jnp.where(valid[:, None], delta, 0.0)
-                h0 = jnp.where(first, jnp.zeros_like(state[i, slot]),
-                               state[i, slot])
-                y, h = SS.scan_chunk(xc, delta, a, b, c, sp["D"], h0,
-                                     kernel=kernels)
-                state = state.at[i, slot].set(h)
-        with device_scope("ssm.proj"):
-            mixed = M.ssm_out(y, z, sp)
+        mixed, y, state, tail = programs.scan_mixer(
+            u, sp, cfg, state, tail, i, decode=decode, kernels=kernels,
+            valid=valid, slot=slot, first=None if decode else first,
+            n_valid=n_valid)
         return close(x, mixed, lp), \
             (pool_k, pool_v, ring_k, ring_v, state, tail), y
 

@@ -2,9 +2,9 @@
 args)`` for ``utils.env.log_compile`` or for ``jitted.lower(*args)``: the
 three train steps (GPT on one device, GPT under ZeRO stage 2 with the
 overlapped update, the latent-attention expert family) and ``prefill`` +
-``decode`` of the five served ones (GPT, the two members of the
+``decode`` of the served ones (GPT, the two members of the
 windowed-attention expert family, the linear-attention / latent-attention
-family, the short-convolution family). Tests only; arguments are abstract
+family, the short-convolution family, the two selective-scan families). Tests only; arguments are abstract
 wherever nothing has to be initialised."""
 
 from __future__ import annotations
@@ -287,9 +287,38 @@ def phi4flash_serve(batch=3, page=4, chunk=8, max_seq=64) -> list:
           _arr((batch, per_req)), _arr((batch,)), rng, _arr((), U32)))]
 
 
+def jamba2_serve(batch=3, page=4, chunk=8, max_seq=64) -> list:
+    """The scan / multi-query family (selective scan with normed step, B
+    and C; multi-query attention) at toy widths."""
+    import ssm_mqa_toy
+
+    from fleetx_tpu.models.ssm_mqa import model as M
+    from fleetx_tpu.models.ssm_mqa.config import config_from_dict
+    from fleetx_tpu.serving import ssm_mqa as S
+    from fleetx_tpu.serving.decode import SamplingParams
+
+    cfg = config_from_dict(ssm_mqa_toy.model_section())
+    per_req = max_seq // page
+    params = M.served_template(cfg)
+    cache = _abstract(jax.eval_shape(lambda: S.init_cache(
+        cfg, num_pages=1 + batch * per_req, page_size=page,
+        max_batch=batch)))
+    fns = S.make_step_fns(cfg, prefill_chunk=chunk,
+                          sampling=SamplingParams())
+    rng = _arr((2,), U32)
+    return [
+        ("serving prefill", fns["prefill"],
+         (params, *cache, _arr((1, chunk)), _arr((1, per_req)), _arr(()),
+          _arr(()), rng, _arr((), U32), _arr(()))),
+        ("serving decode", fns["decode"],
+         (params, *cache, _arr((batch,)), _arr(()), _arr((1,)),
+          _arr((batch, per_req)), _arr((batch,)), rng, _arr((), U32)))]
+
+
 #: family -> the programs' builder; a train builder takes the devices
 TRAIN = {"gpt": gpt_train, "gpt_zero2": gpt_train_zero2,
          "joyai": joyai_train}
 SERVE = {"gpt": gpt_serve, "laguna": laguna_serve,
          "smallthinker": smallthinker_serve, "gigachat": gigachat_serve,
-         "lfm2": lfm2_serve, "phi4flash": phi4flash_serve}
+         "lfm2": lfm2_serve, "phi4flash": phi4flash_serve,
+         "jamba2": jamba2_serve}

@@ -349,7 +349,7 @@ def test_a_mesh_is_refused_with_a_sentence(built):
             cfg, ServingConfig(max_batch=2, page_size=PAGE, num_pages=20,
                                max_seq_len=32, prefill_chunk=CHUNK),
             SamplingParams(), mesh, 8)
-    with pytest.raises(ValueError, match="the 5 served families"):
+    with pytest.raises(ValueError, match="the 6 served families"):
         registry.family("NoSuchModule")
 
 

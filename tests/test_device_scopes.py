@@ -64,7 +64,7 @@ def test_the_vocabulary_holds_both_ways():
     assert None not in opened, "a device_scope whose name is not a literal"
     assert opened == set(DEVICE_SCOPES), (
         opened - set(DEVICE_SCOPES), set(DEVICE_SCOPES) - opened)
-    assert len(DEVICE_SCOPES) <= 24
+    assert len(DEVICE_SCOPES) <= 25
     assert all(isinstance(what, str) and what
                for what in DEVICE_SCOPES.values())
     with pytest.raises(KeyError, match="optimizer_update"):
@@ -244,6 +244,9 @@ def test_a_serving_programs_products_and_fusions_carry_a_scope(family):
         if family == "phi4flash":       # no experts; its own scopes
             assert {"ssm.proj", "ssm.conv", "ssm.core", "gmu",
                     "attn.cross"} <= got, (what, got)
+        elif family == "jamba2":        # no experts; the scan's inner norms
+            assert {"ssm.proj", "ssm.conv", "ssm.core",
+                    "ssm.norm"} <= got, (what, got)
         else:
             assert ("moe.route" in got) == (family != "gpt")
         assert {d for _, d in device_scope_table(text).values()} <= \

@@ -21,6 +21,7 @@ import conv_moe_toy  # noqa: E402
 import device_scope_programs as toys  # noqa: E402
 import gdn_mla_toy  # noqa: E402
 import samba_y_toy  # noqa: E402
+import ssm_mqa_toy  # noqa: E402
 
 from fleetx_tpu.observability.metrics import get_registry  # noqa: E402
 from fleetx_tpu.serving import registry  # noqa: E402
@@ -55,6 +56,11 @@ GEOMETRIES = {
     # (a key-value PAIR of two 8-wide heads is what the kernel is asked)
     "SambaYFamily": (samba_y_toy.model_section(),
                      samba_y_toy.model_section(**samba_y_toy.KERNEL_WIDTHS),
+                     8, "attention: head_dim 16 is neither whole 128-lane "
+                     "tiles nor half of one"),
+    # (4 query heads over ONE key-value head)
+    "SSMMQAFamily": (ssm_mqa_toy.model_section(),
+                     ssm_mqa_toy.model_section(**ssm_mqa_toy.KERNEL_WIDTHS),
                      8, "attention: head_dim 16 is neither whole 128-lane "
                      "tiles nor half of one"),
 }

@@ -812,3 +812,77 @@ def test_samba_y_programs_keep_every_cache_one_buffer(topo, monkeypatch):
             assert holders, f"{name}: the cache {shape} is not in the program"
             stray = [h for h in holders if h[0] not in in_place]
             assert not stray, (name, stray)
+
+
+def test_ssm_mqa_programs_keep_every_cache_one_buffer(topo, monkeypatch):
+    """``decode`` and ``prefill`` of the scan / multi-query family, compiled
+    for the v5e at the recipe's head and scan geometry — 20 query heads in
+    ONE block over one key-value head of 128, a pool one lane tile wide in
+    pages of 128 tokens, 256 slots; 5,120 scan channels of 16 states with
+    the three inner norms — and a narrow MLP: the pool, the states and the
+    tails aliased from input to output; pool and state held by nothing but
+    what enters, the loops' carries, the in-place writes and the kernels that
+    read them (no copy, no layer cut out); ``ssm_chunk`` in the prefill
+    program, ``ssm_decode`` and ``paged_decode`` in the decode program,
+    under the names the trace finds them by; the scan layers walked in
+    loops (a run of one layer is unrolled)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from fleetx_tpu.models.ssm_mqa import model as M
+    from fleetx_tpu.models.ssm_mqa.config import SSMMQAConfig
+    from fleetx_tpu.serving import ssm_mqa as S
+    from fleetx_tpu.serving.decode import SamplingParams
+
+    monkeypatch.setattr(ops, "interpret", lambda: False)
+    # period 4, offset 1: scan, full, scan x 3, full, scan x 2
+    cfg = SSMMQAConfig(vocab_size=VOCAB, intermediate_size=512,
+                       num_hidden_layers=8, attn_layer_period=4,
+                       attn_layer_offset=1)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one)
+
+    # a pool too large for the compiler to stage in on-chip memory, as the
+    # real one is (abstract shapes: nothing is allocated)
+    batch, page, per_req, chunk, pages = 256, 128, 144, 512, 8193
+    assert not S.kernel_refusal(cfg, page_size=page, pages_per_req=per_req,
+                                prefill_chunk=chunk, max_batch=batch)
+    params = jax.tree.map(lambda a: arr(a.shape, a.dtype),
+                          M.served_template(cfg))
+    pool, state, tail = S.cache_shapes(cfg, num_pages=pages, page_size=page,
+                                       max_batch=batch)
+    assert pool == (2, pages, page, 128) and state == (6, batch, 16, 5120)
+    assert tail == (6, 3, batch, 5120)
+    cache = [arr(pool, jnp.bfloat16), arr(pool, jnp.bfloat16),
+             arr(state, jnp.float32), arr(tail, jnp.bfloat16)]
+    fns = S.make_step_fns(cfg, prefill_chunk=chunk, sampling=SamplingParams(),
+                          kernels=True)
+    rng = arr((2,), jnp.uint32)
+    programs = {
+        "prefill": (params, *cache, arr((1, chunk)), arr((1, per_req)),
+                    arr(()), arr(()), rng, arr((), jnp.uint32), arr(())),
+        "decode": (params, *cache, arr((batch,)), arr(()), arr((1,)),
+                   arr((batch, per_req)), arr((batch,)), rng,
+                   arr((), jnp.uint32)),
+    }
+    kernels = {"prefill": {"ssm_chunk"},
+               "decode": {"ssm_decode", "paged_decode"}}
+    in_place = _POOL_CARRIERS | {"custom-call", "dynamic-update-slice",
+                                 "bitcast"}
+    n_params = len(jax.tree.leaves(params))
+    for name, args in programs.items():
+        lowered = fns[name].lower(*args)
+        assert set(mosaic_kernels(lowered.as_text())) == kernels[name], name
+        hlo = lowered.compile().as_text()
+        alias = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo)
+        assert alias, f"{name}: no input-output aliasing at all"
+        for out in range(S.CACHES):
+            assert f"{{{out}}}: ({n_params + out}, {{}}," in alias.group(1), \
+                (name, out, alias.group(1))
+        for shape in (pool, state):
+            holders = _pool_holders(hlo, shape)
+            assert holders, f"{name}: the cache {shape} is not in the program"
+            stray = [h for h in holders if h[0] not in in_place]
+            assert not stray, (name, stray)
+
